@@ -111,7 +111,4 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     raise SystemExit(main())

@@ -182,7 +182,4 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     raise SystemExit(main())

@@ -16,16 +16,13 @@ import sys
 sys.path.insert(0, os.path.abspath(os.path.join(os.path.dirname(__file__), "..")))
 
 
-def force_cpu_if_no_tpu():
-    """Examples default to CPU so they run anywhere; set
-    IPEX_LLM_TPU_EXAMPLE_TPU=1 to use the real chip."""
-    if os.environ.get("IPEX_LLM_TPU_EXAMPLE_TPU") != "1":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
+# Synthetic checkpoints are generated once and reused from a directory of the
+# checkout that .gitignore lists.  Examples take the device JAX gives them;
+# ``JAX_PLATFORMS=cpu`` is how to ask for the CPU.
+CACHE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), ".cache")
 
 
-def tiny_checkpoint(path: str = "/tmp/ipex_llm_tpu_tiny") -> str:
+def tiny_checkpoint(path: str = os.path.join(CACHE_DIR, "tiny")) -> str:
     if os.path.exists(os.path.join(path, "config.json")):
         return path
     import torch

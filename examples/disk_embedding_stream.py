@@ -16,9 +16,7 @@ import argparse
 
 import numpy as np
 
-from _tiny_model import force_cpu_if_no_tpu, tiny_checkpoint
-
-force_cpu_if_no_tpu()
+from _tiny_model import tiny_checkpoint
 
 
 def main():

@@ -10,12 +10,10 @@ no --model is given.
 
 import os
 
-from _tiny_model import force_cpu_if_no_tpu, model_arg
-
-force_cpu_if_no_tpu()
+from _tiny_model import CACHE_DIR, model_arg
 
 
-def _tiny_bert(path="/tmp/ipex_llm_tpu_tiny_bert"):
+def _tiny_bert(path=os.path.join(CACHE_DIR, "tiny_bert")):
     if os.path.exists(os.path.join(path, "config.json")):
         return path
     import torch

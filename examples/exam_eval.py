@@ -10,9 +10,8 @@ import argparse
 import json
 import tempfile
 
-from _tiny_model import force_cpu_if_no_tpu, tiny_checkpoint
+from _tiny_model import tiny_checkpoint
 
-force_cpu_if_no_tpu()
 
 _DEMO = [
     {"subject": "astronomy", "question": "Which planet is largest?",

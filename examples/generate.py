@@ -7,9 +7,7 @@ python/llm/example/GPU/HuggingFace/LLM/*/generate.py — the canonical
     python examples/generate.py [--model PATH] [--prompt TEXT] [--n-predict N]
 """
 
-from _tiny_model import force_cpu_if_no_tpu, model_arg
-
-force_cpu_if_no_tpu()
+from _tiny_model import model_arg
 
 
 def main():

@@ -12,9 +12,7 @@ import argparse
 import os
 import sys
 
-from _tiny_model import force_cpu_if_no_tpu
-
-force_cpu_if_no_tpu()
+from _tiny_model import CACHE_DIR
 
 
 def _synthesize_tiny_gguf(path: str) -> str:
@@ -44,7 +42,8 @@ def main():
 
     from ipex_llm_tpu.transformers import AutoModelForCausalLM
 
-    gguf = args.gguf or _synthesize_tiny_gguf("/tmp/tiny_example.gguf")
+    gguf = args.gguf or _synthesize_tiny_gguf(
+        os.path.join(CACHE_DIR, "tiny_example.gguf"))
     model, _tok = AutoModelForCausalLM.from_gguf(gguf)
     out = model.generate(np.array([[2, 4, 6, 8]], np.int32), max_new_tokens=8)
     print("loaded", gguf)

@@ -7,11 +7,13 @@ same dataset-of-dicts shape, the TPU-native step functions underneath.
     python examples/hf_trainer_finetune.py
 """
 
+import os
+
 import numpy as np
 
-from _tiny_model import force_cpu_if_no_tpu, tiny_checkpoint
+from _tiny_model import CACHE_DIR, tiny_checkpoint
 
-force_cpu_if_no_tpu()
+OUT_DIR = os.path.join(CACHE_DIR, "tpu-finetune")
 
 
 def main():
@@ -32,13 +34,13 @@ def main():
         from transformers import TrainingArguments
 
         args = TrainingArguments(
-            output_dir="/tmp/tpu-finetune", per_device_train_batch_size=4,
+            output_dir=OUT_DIR, per_device_train_batch_size=4,
             num_train_epochs=2, learning_rate=2e-3, logging_steps=2,
             report_to=[],
         )
     except Exception:
         class args:  # noqa: N801 — duck-typed TrainingArguments
-            output_dir = "/tmp/tpu-finetune"
+            output_dir = OUT_DIR
             per_device_train_batch_size = 4
             num_train_epochs = 2
             learning_rate = 2e-3

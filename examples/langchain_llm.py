@@ -6,9 +6,7 @@ Works with langchain installed or falls back to the duck-typed adapter.
     python examples/langchain_llm.py [--model PATH]
 """
 
-from _tiny_model import force_cpu_if_no_tpu, model_arg
-
-force_cpu_if_no_tpu()
+from _tiny_model import model_arg
 
 
 def main():

@@ -8,9 +8,7 @@ cache budget far below the expert footprint, printing the cache hit rate.
     python examples/moe_expert_offload.py
 """
 
-from _tiny_model import force_cpu_if_no_tpu
-
-force_cpu_if_no_tpu()
+import _tiny_model  # noqa: F401 — puts the repo root on sys.path
 
 
 def main():

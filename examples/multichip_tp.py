@@ -15,9 +15,7 @@ os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
 ).strip()
 
-from _tiny_model import force_cpu_if_no_tpu, model_arg  # noqa: E402
-
-force_cpu_if_no_tpu()
+from _tiny_model import model_arg  # noqa: E402
 
 
 def main():

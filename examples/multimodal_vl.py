@@ -9,12 +9,10 @@ given, so the script runs with zero downloads.
 
 import os
 
-from _tiny_model import force_cpu_if_no_tpu, model_arg
-
-force_cpu_if_no_tpu()
+from _tiny_model import CACHE_DIR, model_arg
 
 
-def _tiny_llava(path="/tmp/ipex_llm_tpu_tiny_llava"):
+def _tiny_llava(path=os.path.join(CACHE_DIR, "tiny_llava")):
     if os.path.exists(os.path.join(path, "config.json")):
         return path
     import torch

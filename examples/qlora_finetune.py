@@ -8,9 +8,7 @@ error-compensated requantization back into the packed format.
     python examples/qlora_finetune.py [--model PATH]
 """
 
-from _tiny_model import force_cpu_if_no_tpu, model_arg
-
-force_cpu_if_no_tpu()
+from _tiny_model import model_arg
 
 
 def main():

@@ -19,9 +19,7 @@ then (the surface is the same as a single replica):
 
 import sys
 
-from _tiny_model import force_cpu_if_no_tpu, tiny_checkpoint
-
-force_cpu_if_no_tpu()
+from _tiny_model import tiny_checkpoint
 
 
 def main():

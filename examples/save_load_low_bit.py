@@ -8,9 +8,7 @@ Reference counterpart: example/GPU/HuggingFace/More-Data-Types +
 
 import tempfile
 
-from _tiny_model import force_cpu_if_no_tpu, model_arg
-
-force_cpu_if_no_tpu()
+from _tiny_model import model_arg
 
 
 def main():

@@ -16,9 +16,7 @@ counters including paged-KV ``pages_in_use`` and prefix-cache hits.
 
 import sys
 
-from _tiny_model import force_cpu_if_no_tpu, tiny_checkpoint
-
-force_cpu_if_no_tpu()
+from _tiny_model import tiny_checkpoint
 
 
 def main():

@@ -8,9 +8,7 @@ the auto-tuned ``th_stop_draft``.
     python examples/speculative_decoding.py [--model PATH]
 """
 
-from _tiny_model import force_cpu_if_no_tpu, model_arg
-
-force_cpu_if_no_tpu()
+from _tiny_model import model_arg
 
 
 def main():

@@ -9,9 +9,8 @@ keeps the output a prefix of a conforming document.
 
 import json
 
-from _tiny_model import force_cpu_if_no_tpu, model_arg
+from _tiny_model import model_arg
 
-force_cpu_if_no_tpu()
 
 SCHEMA = {
     "type": "object",

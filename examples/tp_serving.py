@@ -20,9 +20,7 @@ os.environ.setdefault(
      + " --xla_force_host_platform_device_count=4").strip(),
 )
 
-from _tiny_model import force_cpu_if_no_tpu, tiny_checkpoint
-
-force_cpu_if_no_tpu()
+from _tiny_model import tiny_checkpoint
 
 
 def main():

@@ -7,9 +7,7 @@ vLLM install needed).
     python examples/vllm_offline_inference.py [--model PATH]
 """
 
-from _tiny_model import force_cpu_if_no_tpu, model_arg
-
-force_cpu_if_no_tpu()
+from _tiny_model import model_arg
 
 
 def main():

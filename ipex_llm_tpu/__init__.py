@@ -23,58 +23,47 @@ Public API mirrors the reference's compatibility contract:
     out = model.generate(input_ids, max_new_tokens=32)
 """
 
+import os
+
 __version__ = "0.1.0"
 
 __all__ = ["optimize_model", "load_low_bit", "low_memory_init",
            "llm_patch", "llm_unpatch", "__version__"]
 
 
-def _init_compilation_cache() -> None:
-    """Point JAX at a persistent on-disk compilation cache.
+# The one place jax's persistent compile cache is pointed anywhere: a fixed
+# directory of the checkout (listed in .gitignore), so every process of a
+# command shares it and its path, which is part of the cache key, never moves.
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_compile_cache")
 
-    The reference's users get first tokens in seconds because SYCL kernels
-    are prebuilt; XLA instead compiles per (shape-bucket, capacity) — ~2 min
-    cold for a 7B decode program.  A persistent cache makes every process
-    after the first start warm.  Opt out / relocate with
-    IPEX_LLM_TPU_COMPILE_CACHE (empty string disables); an explicit
-    ``jax.config`` setting by the user wins because this only fills the
-    default in via env, which jax reads at first use.
+
+def compile_cache_dir(environ=os.environ) -> str | None:
+    """The directory this package points jax's persistent compile cache at,
+    or None where it sets none.
+
+    - ``JAX_COMPILATION_CACHE_DIR`` set: the cache was placed from outside;
+      jax reads the variable itself and code sets nothing.
+    - a process held to the CPU (``JAX_PLATFORMS=cpu``: the tests, a CPU
+      rehearsal, CPU replicas): no cache.  The checkout is copied to other
+      hosts with whatever it holds, and XLA:CPU entries compiled for one
+      host's CPU features are not safe to load on another.
+    - otherwise: the fixed in-checkout directory.
     """
-    import os
+    if environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    if environ.get("JAX_PLATFORMS", "").split(",")[0].strip().lower() == "cpu":
+        return None
+    return _CHECKOUT_CACHE_DIR
 
-    path = os.environ.get(
-        "IPEX_LLM_TPU_COMPILE_CACHE",
-        os.path.join(
-            os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-            "ipex_llm_tpu", "xla_cache",
-        ),
-    )
-    if not path:
-        return
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", path)
-    # cache every compilation regardless of compile time / program size
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.1")
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
-    import sys
 
-    if "jax" in sys.modules:  # jax read its env already: set via config API
+def _init_compilation_cache() -> None:
+    path = compile_cache_dir()
+    if path is not None:
         import jax
 
-        try:
-            if jax.config.jax_compilation_cache_dir is None:
-                jax.config.update("jax_compilation_cache_dir", path)
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0.1
-                )
-                jax.config.update(
-                    "jax_persistent_cache_min_entry_size_bytes", 0
-                )
-        except Exception:  # never let cache setup break import
-            pass
-    try:
-        os.makedirs(path, exist_ok=True)
-    except OSError:
-        pass
+        jax.config.update("jax_compilation_cache_dir", path)
 
 
 _init_compilation_cache()

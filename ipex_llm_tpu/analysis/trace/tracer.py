@@ -24,14 +24,12 @@ from typing import Any
 import jax
 from jax.tree_util import keystr, tree_leaves_with_path
 
-try:  # jax >= 0.4.33 moves core types under jax.extend
-    from jax.extend.core import ClosedJaxpr, Jaxpr
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import ClosedJaxpr, Jaxpr  # type: ignore
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 # primitives that re-enter the host from inside a lowered program (JP103)
 CALLBACK_PRIMITIVES = frozenset({
     "pure_callback", "io_callback", "debug_callback", "callback",
+    "debug_print",          # what jax.debug.print traces to since jax 0.9
     "infeed", "outfeed", "host_callback_call",
 })
 
@@ -115,11 +113,13 @@ def parse_compiled_aliases(hlo_text: str) -> dict[int, int]:
     """MLIR-arg-position -> output index, from the COMPILED module's
     ``input_output_alias`` header — the sharded-program fallback.
 
-    jax 0.4.37 omits the ``tf.aliasing_output`` attrs from the StableHLO
-    whenever an input carries a sharding (manual-mesh programs: the tp
-    tick's head-sharded KV pools), yet the donation is real — XLA
-    establishes the alias at compile time and stamps it on the entry
-    module as ``{out_idx}: (param, {}, may-alias)``.  Parsing that header
+    When an input carries a sharding (manual-mesh programs: the tp
+    tick's head-sharded KV pools) jax marks the donation in the StableHLO
+    as ``jax.buffer_donor = true`` instead of naming an output with
+    ``tf.aliasing_output`` (checked under jax 0.9.0: a sharded donated
+    input lowers with the former, an unsharded one with the latter), so
+    which output it aliases is only decided by XLA at compile time and
+    stamped on the entry module as ``{out_idx}: (param, {}, may-alias)``.  Parsing that header
     is the only way to verify a sharded program's donation contract, and
     compiling costs ~1 s on top of the (already-paid) lowering."""
     return {int(m.group(2)): int(m.group(1) or 0)

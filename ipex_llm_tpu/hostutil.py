@@ -55,6 +55,24 @@ def d2h(x: Any) -> np.ndarray:
     return np.asarray(x)
 
 
+# Host arrays of at least this many elements are worth splitting over a
+# thread pool (numpy releases the GIL inside its copy and random-draw loops)
+PARALLEL_MIN_ELEMS = 1 << 22
+ROW_BLOCKS = 16
+
+
+def for_row_blocks(n_rows: int, fn: "Callable[[int, int, int], Any]") -> None:
+    """Run ``fn(block_index, lo, hi)`` over ``ROW_BLOCKS`` contiguous row
+    ranges covering ``[0, n_rows)`` on a thread pool.  The block count is
+    fixed, so what a block-indexed ``fn`` writes does not depend on the
+    machine's core count."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    bounds = np.linspace(0, n_rows, ROW_BLOCKS + 1).astype(int)
+    with ThreadPoolExecutor() as pool:
+        list(pool.map(fn, range(ROW_BLOCKS), bounds[:-1], bounds[1:]))
+
+
 class HostLRU:
     """Byte-budgeted host-RAM LRU — ONE implementation of the
     evict-to-fit bookkeeping shared by every host-side cache tier

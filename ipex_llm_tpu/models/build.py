@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ipex_llm_tpu.hostutil import PARALLEL_MIN_ELEMS, for_row_blocks
 from ipex_llm_tpu.models.config import ModelConfig
 from ipex_llm_tpu.models.families import WeightScheme
 from ipex_llm_tpu.quantize import core as qcore
@@ -23,6 +24,19 @@ from ipex_llm_tpu.quantize.core import QTensor
 from ipex_llm_tpu.quantize.qtypes import resolve as qtypes_resolve
 
 NORM_DTYPE = jnp.float32
+
+
+def _transposed(w: np.ndarray) -> np.ndarray:
+    """HF ``[out, in]`` -> contiguous ``[in, out]``.  A strided copy of
+    10^8 elements on one core is most of what is left of a 7B load once the
+    quantizer is native, so large weights are copied in row blocks by a
+    thread pool."""
+    if w.size < PARALLEL_MIN_ELEMS:
+        return np.ascontiguousarray(w.T)
+    out = np.empty(w.shape[::-1], w.dtype)
+    for_row_blocks(out.shape[0],
+                   lambda _, lo, hi: np.copyto(out[lo:hi], w.T[lo:hi]))
+    return out
 
 
 def quantize_weight(w: np.ndarray, qtype: str,
@@ -35,7 +49,7 @@ def quantize_weight(w: np.ndarray, qtype: str,
     ``imatrix`` is a per-input-channel importance vector enabling the
     reference's weighted quantization (ggml_quantize_tensor_with_weights).
     """
-    wt = np.ascontiguousarray(w.T)
+    wt = _transposed(w)
     if qtype in ("mixed_fp4", "mixed_fp8"):
         fp = "fp4" if qtype == "mixed_fp4" else "fp8_e4m3"
         alt = "sym_int4" if qtype == "mixed_fp4" else "sym_int8"

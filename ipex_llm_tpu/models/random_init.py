@@ -14,6 +14,8 @@ from typing import Iterator
 
 import numpy as np
 
+from ipex_llm_tpu.hostutil import (PARALLEL_MIN_ELEMS, ROW_BLOCKS,
+                                   for_row_blocks)
 from ipex_llm_tpu.models.build import build_params
 from ipex_llm_tpu.models.config import ModelConfig
 from ipex_llm_tpu.models.families import FAMILIES
@@ -84,6 +86,20 @@ def _moe_tensor_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
     return shapes
 
 
+def _standard_normal(rng: np.random.Generator, shape) -> np.ndarray:
+    """float32 normals.  One core's ziggurat is most of the wall time of a
+    7B build, so large tensors are filled in fixed row blocks by a thread
+    pool, each block from its own child stream (the values do not depend on
+    the core count); smaller tensors keep the parent's stream."""
+    if int(np.prod(shape)) < PARALLEL_MIN_ELEMS:
+        return rng.standard_normal(shape, dtype=np.float32)
+    out = np.empty(shape, np.float32)
+    children = rng.spawn(ROW_BLOCKS)
+    for_row_blocks(shape[0], lambda i, lo, hi: children[i].standard_normal(
+        dtype=np.float32, out=out[lo:hi]))
+    return out
+
+
 def random_params(cfg: ModelConfig, qtype: str = "sym_int4", seed: int = 0) -> dict:
     """Random params built through ``build_params`` (streamed: each tensor is
     generated on demand, never the whole checkpoint at once).  MoE configs
@@ -104,8 +120,9 @@ def random_params(cfg: ModelConfig, qtype: str = "sym_int4", seed: int = 0) -> d
             return np.ones(s, np.float32) + 0.05 * rng.standard_normal(s).astype(
                 np.float32
             )
-        scale = np.float32(0.3 / np.sqrt(max(s[-1], 1)) * 4)
-        return rng.standard_normal(s, dtype=np.float32) * scale
+        w = _standard_normal(rng, s)
+        w *= np.float32(0.3 / np.sqrt(max(s[-1], 1)) * 4)
+        return w
 
     fam = FAMILIES["mixtral" if moe else "llama"]
     return build_params(cfg, fam.scheme, gen, lambda n: n in shapes,

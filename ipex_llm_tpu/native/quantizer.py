@@ -3,7 +3,9 @@
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 
@@ -14,34 +16,74 @@ _LIB: ctypes.CDLL | None | bool = None  # None=untried, False=unavailable
 
 _SRC = os.path.join(os.path.dirname(__file__), "..", "..", "csrc",
                     "quantize.cpp")
-_OUT = os.path.join(os.path.dirname(__file__), "_libquantize.so")
 
 
-def build(force: bool = False) -> str | None:
-    """Compile the shared library (g++ -O3 -march=native -fopenmp)."""
+class NativeBuildError(RuntimeError):
+    """The compiler is missing or refused csrc/quantize.cpp."""
+
+
+def _cpu_identity() -> str:
+    """What ``-march=native`` specializes the library for: the machine type
+    and this CPU's feature flags."""
+    ident = platform.machine()
+    try:
+        with open("/proc/cpuinfo", encoding="utf-8") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    ident += line
+                    break
+    except OSError:
+        pass
+    return ident
+
+
+def _out_path(src: str) -> str:
+    """The library's path carries a digest of the source and of the host
+    CPU: the tree is copied between machines with whatever it holds, and a
+    ``-march=native`` build for another CPU (or an older source) must never
+    be the one loaded."""
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read() + _cpu_identity().encode())
+    return os.path.join(os.path.dirname(__file__),
+                        f"_libquantize-{digest.hexdigest()[:16]}.so")
+
+
+def build(force: bool = False) -> str:
+    """Compile the shared library (g++ -O3 -march=native -fopenmp) unless
+    this host already built this source; raises NativeBuildError."""
     src = os.path.abspath(_SRC)
     if not os.path.exists(src):
-        return None
-    if os.path.exists(_OUT) and not force and (
-        os.path.getmtime(_OUT) >= os.path.getmtime(src)
-    ):
-        return _OUT
+        raise NativeBuildError(f"{src} is missing")
+    out = _out_path(src)
+    if os.path.exists(out) and not force:
+        return out
+    tmp = f"{out}.{os.getpid()}.tmp"   # concurrent builders never see half
     cmd = ["g++", "-O3", "-march=native", "-fopenmp", "-shared", "-fPIC",
-           src, "-o", _OUT]
+           src, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except (subprocess.SubprocessError, FileNotFoundError):
-        return None
-    return _OUT
+        os.replace(tmp, out)
+    except FileNotFoundError as e:
+        raise NativeBuildError("g++ not found") from e
+    except subprocess.CalledProcessError as e:
+        raise NativeBuildError(
+            f"g++ failed: {e.stderr.decode(errors='replace')}") from e
+    except subprocess.TimeoutExpired as e:
+        raise NativeBuildError("g++ timed out") from e
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    return out
 
 
 def _load() -> ctypes.CDLL | None:
     global _LIB
     with _LOCK:
         if _LIB is None:
-            path = build()
-            if path is None:
-                _LIB = False
+            try:
+                path = build()
+            except NativeBuildError:
+                _LIB = False   # callers fall back to the jnp codec
             else:
                 try:
                     lib = ctypes.CDLL(path)

@@ -16,6 +16,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ipex_llm_tpu.ops.pallas._compat import heads_shardable
+
 NEG_INF = -1e30
 
 
@@ -109,50 +111,52 @@ def sdpa(
             softcap=kwargs.get("softcap"),
         )
 
+    # every route below is decided from the shapes and the mesh before the
+    # call: a kernel that raises is an error, never a reason to fall back
+    flash_ok = q.shape[1] >= 128 and kwargs.get("bias") is None
     mesh = dispatch.spmd_mesh()
     if (
-        mesh is not None
+        flash_ok
+        and mesh is not None
         and mesh.shape.get("tp", 1) > 1
         and dispatch.use_pallas_sharded()
-        and q.shape[1] >= 128
-        and kwargs.get("bias") is None
         and kwargs.get("causal", True)
+        and q.shape[2] % mesh.shape["tp"] == 0
+        and k.shape[2] % mesh.shape["tp"] == 0
     ):
-        try:
-            from ipex_llm_tpu.ops.pallas import flash_attention
+        # (kernel modules import pallas, ~1 s: only where a kernel runs)
+        from ipex_llm_tpu.ops.pallas import flash_attention
 
-            kw = dict(kwargs)
-            kw.pop("causal", None)
-            return flash_attention.flash_sdpa_sharded(
-                q, k, v, mesh,
-                q_positions=kw.pop("q_positions", None),
-                kv_len=kw.pop("kv_len", None),
-                kv_start=kw.pop("kv_start", None),
-                window_on=kw.pop("window_on", True),
-                causal=True, **kw,
-            )
-        except (ImportError, NotImplementedError):
-            pass
-    if dispatch.use_pallas() and q.shape[1] >= 128 and kwargs.get("bias") is None:
-        try:
-            from ipex_llm_tpu.ops.pallas import flash_attention
+        kw = dict(kwargs)
+        kw.pop("causal", None)
+        return flash_attention.flash_sdpa_sharded(
+            q, k, v, mesh,
+            q_positions=kw.pop("q_positions", None),
+            kv_len=kw.pop("kv_len", None),
+            kv_start=kw.pop("kv_start", None),
+            window_on=kw.pop("window_on", True),
+            causal=True, **kw,
+        )
+    if flash_ok and dispatch.use_pallas():
+        from ipex_llm_tpu.ops.pallas import flash_attention
 
-            return flash_attention.flash_sdpa(q, k, v, **kwargs)
-        except (ImportError, NotImplementedError):
-            pass
+        return flash_attention.flash_sdpa(q, k, v, **kwargs)
     return sdpa_reference(q, k, v, **kwargs)
 
 
-def _decode_kernel_mode(dispatch, op: str | None = None) -> str | None:
-    """Which decode-kernel variant the active dispatch state allows:
-    'single' (no mesh, Pallas on), 'sharded' (tp mesh with shard_map
-    wrappers), or None (jnp/gather fallback).  One policy for both the
-    paged and dense decode ladders in :func:`cached_sdpa`; ``op`` keys
-    the measured-ladder lookup (dispatch.use_pallas)."""
+def _decode_kernel_mode(dispatch, op: str, hq: int, hkv: int) -> str | None:
+    """Which decode-kernel variant the active dispatch state and the head
+    counts allow: 'single' (no mesh, Pallas on), 'sharded' (tp mesh whose
+    degree the shard_map wrappers can split these heads over), or None
+    (jnp/gather path).  One policy for both the paged and dense decode
+    ladders in :func:`cached_sdpa`; ``op`` keys the measured-ladder lookup
+    (dispatch.use_pallas)."""
     mesh = dispatch.spmd_mesh()
     if mesh is None:
         return "single" if dispatch.use_pallas(op) else None
-    if mesh.shape.get("tp", 1) > 1 and dispatch.use_pallas_sharded(op):
+    tp = mesh.shape.get("tp", 1)
+    if (tp > 1 and dispatch.use_pallas_sharded(op)
+            and heads_shardable(hq, hkv, tp)):
         return "sharded"
     return None
 
@@ -209,23 +213,20 @@ def cached_sdpa(
             # kernel-vs-XLA here.
             op = ("ragged_attn_fp8" if "float8" in str(kl.dtype)
                   else "ragged_attn")
-            mode = _decode_kernel_mode(dispatch, op)
+            mode = _decode_kernel_mode(dispatch, op, q.shape[2],
+                                       kl.shape[1])
             if mode is not None:
-                try:
-                    from ipex_llm_tpu.ops.pallas import \
-                        ragged_paged_attention
-
-                    if mode == "single":
-                        return ragged_paged_attention.ragged_paged_sdpa(
-                            q, kl, vl, cache.tables, kwargs.get("kv_len"),
-                            chunk_lens, scale=kwargs.get("scale"))
-                    # TP serving: per-shard kernel over the kv-head split
-                    return ragged_paged_attention.ragged_paged_sdpa_sharded(
-                        q, kl, vl, cache.tables, kwargs.get("kv_len"),
-                        dispatch.spmd_mesh(), chunk_lens,
-                        scale=kwargs.get("scale"))
-                except (ImportError, NotImplementedError):
-                    pass
+                from ipex_llm_tpu.ops.pallas import ragged_paged_attention
+            if mode == "single":
+                return ragged_paged_attention.ragged_paged_sdpa(
+                    q, kl, vl, cache.tables, kwargs.get("kv_len"),
+                    chunk_lens, scale=kwargs.get("scale"))
+            if mode == "sharded":
+                # TP serving: per-shard kernel over the kv-head split
+                return ragged_paged_attention.ragged_paged_sdpa_sharded(
+                    q, kl, vl, cache.tables, kwargs.get("kv_len"),
+                    dispatch.spmd_mesh(), chunk_lens,
+                    scale=kwargs.get("scale"))
         # fallback: gather the rows' pages into the head-major
         # [B, Hkv, S, D] view; tail pages beyond kv_len are garbage and
         # masked exactly like dense-cache slack (per-row chunk lens are
@@ -252,18 +253,14 @@ def cached_sdpa(
         )
         op = ("decode_attn_fp8" if "float8" in str(kl.dtype)
               else "decode_attn")
-        mode = _decode_kernel_mode(dispatch, op)
+        mode = _decode_kernel_mode(dispatch, op, q.shape[2], kl.shape[1])
         if mode is not None:
-            try:
-                from ipex_llm_tpu.ops.pallas import decode_attention
-
-                if mode == "single":
-                    return decode_attention.decode_sdpa(q, kl, vl, **dk)
-                return decode_attention.decode_sdpa_sharded(
-                    q, kl, vl, dispatch.spmd_mesh(), **dk
-                )
-            except (ImportError, NotImplementedError):
-                pass
+            from ipex_llm_tpu.ops.pallas import decode_attention
+        if mode == "single":
+            return decode_attention.decode_sdpa(q, kl, vl, **dk)
+        if mode == "sharded":
+            return decode_attention.decode_sdpa_sharded(
+                q, kl, vl, dispatch.spmd_mesh(), **dk)
     kd = cache.decode_layer(kl, compute_dtype).transpose(0, 2, 1, 3)
     vd = cache.decode_layer(vl, compute_dtype).transpose(0, 2, 1, 3)
     return sdpa(q, kd, vd, **kwargs)

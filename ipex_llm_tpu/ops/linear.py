@@ -27,23 +27,19 @@ def qmatmul_reference(x: jnp.ndarray, qt: QTensor, compute_dtype=jnp.bfloat16) -
     )
 
 
-_PALLAS_QTYPES = ("sym_int4", "asym_int4", "nf4", "fp4", "sym_int8",
-                  "sym_int5", "asym_int5", "fp6", "fp8_e4m3", "fp8_e5m2")
-
-
 def qmatmul(x: jnp.ndarray, qt: QTensor, compute_dtype=jnp.bfloat16) -> jnp.ndarray:
     """Quantized matmul with backend dispatch.
 
-    The Pallas path currently covers the 4-bit packed formats (sym_int4 /
-    asym_int4 / nf4 / fp4) and sym_int8 — the formats the reference routes to
-    ``xe_linear``/``xe_batch`` — and the backend choice is data-driven per
-    qtype family from the measured microbench ladder
-    (``dispatch.use_pallas("qmatmul_<qtype>")``: the recorded decode-shape
-    rows show CPU-interpret losing to the XLA block-dequant path, TPU has
-    no recorded loss so the compiled kernel stands).  Under an active SPMD
-    mesh, TP-sharded weights (``qt.tp_mode`` stamped by parallel/shard.py)
-    run the shard_map-wrapped kernel; everything else falls back to the
-    XLA dequant path which GSPMD partitions itself.
+    The Pallas path covers the formats ops/pallas/qmatmul.py lists as
+    supported, and the backend choice per qtype family comes from
+    ``dispatch.use_pallas("qmatmul_<qtype>")``: the compiled kernel on a
+    TPU, the XLA block-dequant path elsewhere, unless a measured ladder
+    row says otherwise.  Under an active SPMD mesh, TP-sharded weights
+    (``qt.tp_mode`` stamped by parallel/shard.py) whose shape the
+    shard_map-wrapped kernel covers run it; everything else takes the XLA
+    dequant path, which GSPMD partitions itself.  Every route is decided
+    from the weight and the mesh before the call: a kernel that raises is
+    an error, never a reason to take another path.
     """
     fam = f"qmatmul_{qt.qtype}"
     mt = dispatch.manual_tp_state()
@@ -73,26 +69,20 @@ def qmatmul(x: jnp.ndarray, qt: QTensor, compute_dtype=jnp.bfloat16) -> jnp.ndar
         mesh = dispatch.spmd_mesh()
         if (
             mesh is not None
-            and qt.tp_mode in ("col", "row")
             and mesh.shape.get("tp", 1) > 1
             and dispatch.use_pallas_sharded(fam)
-            and qt.qtype in _PALLAS_QTYPES
         ):
-            try:
-                from ipex_llm_tpu.ops.pallas import qmatmul as pallas_qmatmul
-
-                return pallas_qmatmul.qmatmul_pallas_sharded(
-                    x, qt, mesh, compute_dtype
-                )
-            except (ImportError, NotImplementedError):
-                pass
-    if dispatch.use_pallas(fam) and qt.qtype in _PALLAS_QTYPES:
-        try:
+            # (kernel modules import pallas, ~1 s: only where a kernel runs)
             from ipex_llm_tpu.ops.pallas import qmatmul as pallas_qmatmul
 
+            if pallas_qmatmul.sharded_supported(qt, mesh.shape["tp"]):
+                return pallas_qmatmul.qmatmul_pallas_sharded(
+                    x, qt, mesh, compute_dtype)
+    if dispatch.use_pallas(fam):
+        from ipex_llm_tpu.ops.pallas import qmatmul as pallas_qmatmul
+
+        if pallas_qmatmul.supported(qt):
             return pallas_qmatmul.qmatmul_pallas(x, qt, compute_dtype)
-        except (ImportError, NotImplementedError):
-            pass  # fall through to the XLA reference path
     return qmatmul_reference(x, qt, compute_dtype)
 
 

@@ -36,12 +36,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ipex_llm_tpu.ops.pallas._compat import (
-    COMPILER_PARAMS as _COMPILER_PARAMS,
     NEG_INF,
+    heads_shardable,
     interpret as _interpret,
     round_up as _round_up,
 )
-from ipex_llm_tpu.parallel.compat import shard_map as _shard_map
 
 
 def _kernel(len_ref, start_ref, won_ref, q_ref, k_ref, v_ref, o_ref,
@@ -146,7 +145,7 @@ def _decode(q, k, v, kv_len, kv_start, won, *, scale, window, softcap,
             pltpu.VMEM((g_pad, 1), jnp.float32),
             pltpu.VMEM((g_pad, dv_pad), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -158,6 +157,7 @@ def _decode(q, k, v, kv_len, kv_start, won, *, scale, window, softcap,
             transcendentals=b * hkv * g_pad * sp,
         ),
         interpret=_interpret(),
+        name="decode_attention",
     )(kv_len.astype(jnp.int32), kv_start.astype(jnp.int32),
       won.astype(jnp.int32), q, k, v)
     return out[:, :, :g, :dv]
@@ -217,11 +217,11 @@ def decode_sdpa_sharded(q, k_raw, v_raw, mesh, **kwargs):
 
     tp = mesh.shape["tp"]
     hq, hkv = q.shape[2], k_raw.shape[1]
-    if hq % tp:
-        raise NotImplementedError("q heads must divide tp")
+    if not heads_shardable(hq, hkv, tp):
+        raise NotImplementedError(
+            f"unsupported head/tp factorization: {hq} q / {hkv} kv heads "
+            f"over tp={tp}")
     if hkv % tp:
-        if tp % hkv or (hq // hkv) % (tp // hkv):
-            raise NotImplementedError("unsupported head/tp factorization")
         # GQA with fewer kv heads than chips (70B north-star: 8 kv heads,
         # tp=16): repeat kv heads up to tp — repeat-of-replicated feeding a
         # head-sharded consumer lowers to a local per-shard slice, so each
@@ -235,7 +235,7 @@ def decode_sdpa_sharded(q, k_raw, v_raw, mesh, **kwargs):
 
     q_spec = P(None, None, "tp", None)
     kv_spec = P(None, "tp", None, None)
-    return _shard_map(
+    return jax.shard_map(
         run, mesh=mesh, axis_names={"tp"},
         in_specs=(q_spec, kv_spec, kv_spec), out_specs=q_spec,
         check_vma=False,

@@ -26,12 +26,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ipex_llm_tpu.ops.pallas._compat import (
-    COMPILER_PARAMS as _COMPILER_PARAMS,
     NEG_INF,
     interpret as _interpret,
     round_up as _round_up,
 )
-from ipex_llm_tpu.parallel.compat import shard_map as _shard_map
 
 
 def _kernel(qpos_ref, kvlen_ref, kvstart_ref, won_ref, q_ref, k_ref, v_ref,
@@ -148,7 +146,7 @@ def _flash(q, k, v, qpos, kv_len, kv_start, won, *,
             pltpu.VMEM((bt, 1), jnp.float32),
             pltpu.VMEM((bt, dv_pad), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -157,6 +155,7 @@ def _flash(q, k, v, qpos, kv_len, kv_start, won, *,
             transcendentals=bh * tp * sp,
         ),
         interpret=_interpret(),
+        name="flash_attention",
     )(qpos, kv_len.reshape(-1, 1).astype(jnp.int32),
       kv_start.reshape(-1, 1).astype(jnp.int32),
       won.reshape(-1, 1).astype(jnp.int32), q, k, v)
@@ -241,7 +240,7 @@ def flash_sdpa_sharded(q, k, v, mesh, *, q_positions=None, kv_len=None,
 
     hspec = P(None, None, "tp", None)
     rep2, rep1 = P(None, None), P(None)
-    return _shard_map(
+    return jax.shard_map(
         run, mesh=mesh, axis_names={"tp"},
         in_specs=(hspec, hspec, hspec, rep2, rep1, rep1, rep1),
         out_specs=hspec, check_vma=False,

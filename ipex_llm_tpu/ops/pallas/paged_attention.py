@@ -31,12 +31,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ipex_llm_tpu.ops.pallas._compat import (
-    COMPILER_PARAMS as _COMPILER_PARAMS,
     NEG_INF,
     interpret as _interpret,
     round_up as _round_up,
 )
-from ipex_llm_tpu.parallel.compat import shard_map as _shard_map
 
 
 def _kernel(tables_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -139,7 +137,7 @@ def _paged(q, k_pool, v_pool, tables, kv_len, *, scale, out_dtype, chunk=1):
                           compute_dtype=jnp.bfloat16),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, hkv, g_pad, dv_pad), out_dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
@@ -226,7 +224,7 @@ def paged_prefill_sdpa_sharded(q, k_pool, v_pool, tables, kv_len, mesh, *,
 
     q_spec = P(None, None, "tp", None)
     pool_spec = P(None, "tp", None, None)
-    return _shard_map(
+    return jax.shard_map(
         run, mesh=mesh, axis_names={"tp"},
         in_specs=(q_spec, pool_spec, pool_spec, P(None, None), P(None)),
         out_specs=q_spec,
@@ -275,7 +273,7 @@ def paged_decode_sdpa_sharded(q, k_pool, v_pool, tables, kv_len, mesh, *,
 
     q_spec = P(None, None, "tp", None)
     pool_spec = P(None, "tp", None, None)
-    return _shard_map(
+    return jax.shard_map(
         run, mesh=mesh, axis_names={"tp"},
         in_specs=(q_spec, pool_spec, pool_spec, P(None, None), P(None)),
         out_specs=q_spec,

@@ -20,8 +20,8 @@ formats nf4 / fp4 (16-entry lookup unrolled as a select chain on the VPU),
 the minifloats fp8_e4m3 / fp8_e5m2 / fp6 (exponent/mantissa decoded
 arithmetically in-kernel — ``exp2`` on the VPU, no 256-entry table), and
 sym/asym_int5 (dual-plane unpack of the _pack_5bit layout: nibble plane +
-bit plane).  Anything else falls back to the XLA reference path in
-ops/linear.py.
+bit plane).  ops/linear.py routes every other format to the XLA reference
+path before the call.
 """
 
 from __future__ import annotations
@@ -35,11 +35,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ipex_llm_tpu.ops.pallas._compat import (
-    COMPILER_PARAMS as _COMPILER_PARAMS,
     interpret as _interpret,
     round_up as _round_up,
 )
-from ipex_llm_tpu.parallel.compat import shard_map as _shard_map
 
 from ipex_llm_tpu.quantize import numerics
 from ipex_llm_tpu.quantize.core import QTensor
@@ -218,7 +216,7 @@ def _qmatmul_2d(x, data, scales, zeros, *, qtype: str, bs: int,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -229,6 +227,7 @@ def _qmatmul_2d(x, data, scales, zeros, *, qtype: str, bs: int,
             transcendentals=0,
         ),
         interpret=_interpret(),
+        name="qmatmul",
     )(*args)
     return out[:m, :logical_out]
 
@@ -240,7 +239,7 @@ def qmatmul_pallas(x: jnp.ndarray, qt: QTensor, compute_dtype=jnp.bfloat16,
     ``keep_f32`` returns the fp32 accumulator untouched (the row-parallel
     shard_map wrapper psums partial products in fp32 before the final cast).
     """
-    if qt.qtype not in _SUPPORTED:
+    if not supported(qt):
         raise NotImplementedError(qt.qtype)
     lead = x.shape[:-1]
     k = x.shape[-1]
@@ -256,6 +255,24 @@ def qmatmul_pallas(x: jnp.ndarray, qt: QTensor, compute_dtype=jnp.bfloat16,
     )
     out = out.reshape(*lead, qt.out_features)
     return out if keep_f32 else out.astype(x.dtype)
+
+
+def supported(qt: QTensor) -> bool:
+    """Whether :func:`qmatmul_pallas` covers this weight's format."""
+    return qt.qtype in _SUPPORTED
+
+
+def sharded_supported(qt: QTensor, tp: int) -> bool:
+    """Whether :func:`qmatmul_pallas_sharded` covers this weight at this
+    tp degree; dispatch asks BEFORE routing to it."""
+    if not supported(qt):
+        return False
+    if qt.tp_mode == "col":
+        return qt.out_features % tp == 0
+    if qt.tp_mode == "row":
+        return (qt.qtype not in _BIT5
+                and qt.in_features % ((qt.block_size or 1) * tp) == 0)
+    return False
 
 
 def qmatmul_pallas_sharded(x: jnp.ndarray, qt: QTensor, mesh,
@@ -274,29 +291,23 @@ def qmatmul_pallas_sharded(x: jnp.ndarray, qt: QTensor, mesh,
     """
     from jax.sharding import PartitionSpec as P
 
-    if qt.qtype not in _SUPPORTED:
-        raise NotImplementedError(qt.qtype)
     tp = mesh.shape["tp"]
+    if not sharded_supported(qt, tp):
+        raise NotImplementedError(
+            f"{qt!r} tp_mode={qt.tp_mode} does not shard over tp={tp}")
     lead = (None,) * (x.ndim - 1)
     has_zeros = qt.zeros is not None
 
     if qt.tp_mode == "col":
-        if qt.out_features % tp:
-            raise NotImplementedError("out_features not divisible by tp")
         local_shape = (qt.in_features, qt.out_features // tp)
         w_spec = P(None, "tp")
         x_spec = P(*lead, None)
         out_spec = P(*lead, "tp")
-    elif qt.tp_mode == "row":
-        bs = qt.block_size or 1
-        if qt.in_features % (bs * tp) or qt.qtype in _BIT5:
-            raise NotImplementedError("in_features not divisible by bs*tp")
+    else:
         local_shape = (qt.in_features // tp, qt.out_features)
         w_spec = P("tp", None)
         x_spec = P(*lead, "tp")
         out_spec = P(*lead, None)
-    else:
-        raise NotImplementedError(f"tp_mode={qt.tp_mode}")
 
     def run(xl, data, scales, zeros=None):
         lqt = QTensor(data, scales, zeros, qt.qtype, local_shape,
@@ -308,7 +319,7 @@ def qmatmul_pallas_sharded(x: jnp.ndarray, qt: QTensor, mesh,
 
     in_specs = [x_spec, w_spec, w_spec] + ([w_spec] if has_zeros else [])
     args = [x, qt.data, qt.scales] + ([qt.zeros] if has_zeros else [])
-    return _shard_map(
+    return jax.shard_map(
         run, mesh=mesh, axis_names={"tp"},
         in_specs=tuple(in_specs), out_specs=out_spec, check_vma=False,
     )(*args)

@@ -46,12 +46,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ipex_llm_tpu.ops.pallas._compat import (
-    COMPILER_PARAMS as _COMPILER_PARAMS,
     NEG_INF,
+    heads_shardable,
     interpret as _interpret,
     round_up as _round_up,
 )
-from ipex_llm_tpu.parallel.compat import shard_map as _shard_map
 
 
 def _kernel(tables_ref, len_ref, chunk_ref, q_ref, k_ref, v_ref, o_ref,
@@ -181,10 +180,11 @@ def _ragged(q, k_pool, v_pool, tables, kv_len, chunk_len, *, scale,
                           compute_dtype=jnp.bfloat16),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, hkv, gc_pad, dv_pad), out_dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name="ragged_paged_attention",
     )(tables, kv_len.astype(jnp.int32), chunk_len.astype(jnp.int32),
       q, k_pool, v_pool)
     return out[:, :, :gc, :dv]
@@ -244,11 +244,11 @@ def ragged_paged_sdpa_sharded(q, k_pool, v_pool, tables, kv_len, mesh,
 
     tp = mesh.shape["tp"]
     hq, hkv = q.shape[2], k_pool.shape[1]
-    if hq % tp:
-        raise NotImplementedError("q heads must divide tp")
+    if not heads_shardable(hq, hkv, tp):
+        raise NotImplementedError(
+            f"unsupported head/tp factorization: {hq} q / {hkv} kv heads "
+            f"over tp={tp}")
     if hkv % tp:
-        if tp % hkv or (hq // hkv) % (tp // hkv):
-            raise NotImplementedError("unsupported head/tp factorization")
         rep = tp // hkv
         k_pool = jnp.repeat(k_pool, rep, axis=1)
         v_pool = jnp.repeat(v_pool, rep, axis=1)
@@ -260,7 +260,7 @@ def ragged_paged_sdpa_sharded(q, k_pool, v_pool, tables, kv_len, mesh,
 
     q_spec = P(None, None, "tp", None)
     pool_spec = P(None, "tp", None, None)
-    return _shard_map(
+    return jax.shard_map(
         run, mesh=mesh, axis_names={"tp"},
         in_specs=(q_spec, pool_spec, pool_spec, P(None, None), P(None),
                   P(None)),

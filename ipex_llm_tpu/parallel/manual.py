@@ -1,15 +1,12 @@
 """Manual-mesh tensor parallelism for the fused serving tick.
 
-jax 0.4.37's GSPMD cannot be trusted to COMPOSE this engine across chips:
-the XLA:CPU partitioner deterministically miscompiles tp=4 composed with a
-second >1 mesh axis (tests/test_parallel.py documents the
-characterization), partial-auto shard_map regions check-fail on exactly
-the graphs the engine emits, and even where GSPMD is correct it is free to
-insert reshards between ops.  This module takes the compiler out of the
-loop for the serving hot path: the ENTIRE fused tick —
-ragged prefill chunk, on-device first-token merge, the speculative and
-plain decode-horizon loops — executes inside ONE ``shard_map`` region with
-every mesh axis manual, per-shard paged-KV pools, and EXPLICIT collectives
+Even where GSPMD partitions this engine correctly it is free to insert
+reshards between ops, and what it inserts changes with the compiler.  This
+module takes the compiler out of the loop for the serving hot path: the
+ENTIRE fused tick — ragged prefill chunk, on-device first-token merge, the
+speculative and plain decode-horizon loops — executes inside ONE
+``shard_map`` region with every mesh axis manual, per-shard paged-KV
+pools, and EXPLICIT collectives
 (ops/collectives.py) at exactly the two row-parallel combine points per
 layer plus one lm-head all-gather per sampled position.  Per-shard compute
 is the UNMODIFIED single-chip decoder over a shard-local ``ModelConfig``
@@ -53,7 +50,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ipex_llm_tpu.models.config import ModelConfig
-from ipex_llm_tpu.parallel.compat import shard_map
 from ipex_llm_tpu.quantize.core import QTensor
 
 # column-parallel packed projections and their section widths (cfg-derived)
@@ -244,6 +240,22 @@ def _qt_spec(qt: QTensor) -> QTensor:
                        zeros=None if qt.zeros is None else sp)
 
 
+def _local_qtensors(params: dict, tp: int) -> dict:
+    """Inside the manual region a placed QTensor's planes are this shard's
+    slice; its static logical shape must say so too, because the Pallas
+    kernel sizes its output from it (the XLA path reads the planes)."""
+    def local(leaf):
+        if isinstance(leaf, QTensor) and leaf.tp_mode in ("col", "row"):
+            n_in, n_out = leaf.shape
+            return _dc_replace(leaf, shape=(
+                (n_in, n_out // tp) if leaf.tp_mode == "col"
+                else (n_in // tp, n_out)))
+        return leaf
+
+    return jax.tree_util.tree_map(
+        local, params, is_leaf=lambda x: isinstance(x, QTensor))
+
+
 def param_specs(params: dict, tp: int):
     """in_specs pytree for the manual region, mirroring
     :func:`shard_params_manual`'s placement (derived from the stamped
@@ -306,6 +318,7 @@ def tp_tick(body, cfg: ModelConfig, mesh, collective_qtype: str,
     ks_spec = None if spec_ks is None else rep
 
     def inner(p, ck, cv, ctab, clen, st, pf, hs, sk):
+        p = _local_qtensors(p, tp)
         cache_l = PagedKVCache(ck, cv, ctab, clen, storage=storage)
         with dispatch.manual_tp("tp", collective_qtype):
             out = body(lcfg, p, cache_l, *st, prefill=pf, horizon=horizon,
@@ -325,7 +338,7 @@ def tp_tick(body, cfg: ModelConfig, mesh, collective_qtype: str,
          rep, rep, rep, rep, rep, rep)          # toks..remain, key
         + (rep,) * n_tail)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(p_specs, pool, pool, rep, rep, state_specs,
                   prefill_specs, hist_spec, ks_spec),

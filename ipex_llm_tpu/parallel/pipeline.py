@@ -36,25 +36,6 @@ from jax.sharding import PartitionSpec as P
 
 from ipex_llm_tpu.models.config import ModelConfig
 from ipex_llm_tpu.ops import collectives
-from ipex_llm_tpu.parallel.compat import shard_map as _shard_map
-
-
-def _reject_composed_mesh(mesh, entry: str):
-    """jax 0.4.37 env limit: ``ppermute`` inside a partial-auto shard_map
-    region on a mesh with a second >1 axis CHECK-CRASHES the XLA SPMD
-    partitioner (spmd_partitioner.cc ``IsManualSubgroup`` — a process
-    ABORT, not an exception; tests/test_serving_tp.py holds the
-    characterization).  The GPipe entries therefore accept pure-pp meshes
-    only and refuse composed ones up front with a catchable error; the
-    serving engine routes composed meshes through the fused GSPMD tick
-    instead."""
-    others = {a: n for a, n in mesh.shape.items() if a != "pp" and n > 1}
-    if others:
-        raise ValueError(
-            f"{entry} needs a pure-pp mesh: composed axes {others} would "
-            "abort the jax 0.4.37 SPMD partitioner (ppermute in a "
-            "partial-auto region) — serve composed meshes through the "
-            "GSPMD tick instead")
 
 
 def _stage_specs(tree) -> object:
@@ -91,7 +72,6 @@ def pipeline_forward(
         raise NotImplementedError(
             "dense-prefix MoE models don't pipeline yet (two stacks)"
         )
-    _reject_composed_mesh(mesh, "pipeline_forward")
     pp = mesh.shape["pp"]
     b, t = tokens.shape
     if b % n_micro:
@@ -118,15 +98,9 @@ def pipeline_forward(
         [cfg.layer_is_sliding(l) for l in range(cfg.num_layers)], dtype=bool
     )
 
-    def stages(layer_tree, flags, mb_all, stage_ids):
-        """Runs on every pp stage with its local L/pp layer chunk.
-
-        ``stage_ids`` is a pp-sharded iota whose local element IS the
-        stage index — jax 0.4.37's SPMD pipeline cannot lower
-        ``axis_index`` inside a partial-auto region (the PartitionId
-        instruction is rejected when auto axes are present), so the
-        stage id arrives as data instead of an instruction."""
-        stage = stage_ids[0]
+    def stages(layer_tree, flags, mb_all):
+        """Runs on every pp stage with its local L/pp layer chunk."""
+        stage = jax.lax.axis_index("pp")
         n_local = cfg.num_layers // pp
         # scratch cache for the local chunk (cacheless full-seq attention)
         cache = KVCache.init(n_local, bm, t, cfg.num_kv_heads, cfg.head_dim,
@@ -177,19 +151,16 @@ def pipeline_forward(
         # family instead of a blanket promotion at every call site.
         return collectives.psum_exact(outs, "pp")
 
-    out = _shard_map(
+    out = jax.shard_map(
         stages,
         mesh=mesh,
-        in_specs=(_stage_specs(params["layers"]), P("pp"), P(),
-                  P("pp")),
+        in_specs=(_stage_specs(params["layers"]), P("pp"), P()),
         out_specs=P(),
         check_vma=False,
-        # pp manual, the (size-1, by the composed-mesh guard above) other
-        # axes nominally auto — composed tp x pp is rejected up front,
-        # see _reject_composed_mesh
+        # only pp is manual: on a composed mesh the other axes stay
+        # under GSPMD inside the region
         axis_names={"pp"},
-    )(params["layers"], sliding_flags, mbs,
-      jnp.arange(pp, dtype=jnp.int32))
+    )(params["layers"], sliding_flags, mbs)
 
     return logits_tail(cfg, params, out.reshape(b, t, -1))
 
@@ -242,7 +213,6 @@ def pp_decode_step(
 
     if "layers_dense" in params:
         raise NotImplementedError("dense-prefix MoE models don't pipeline yet")
-    _reject_composed_mesh(mesh, "pp_decode_step")
     pp = mesh.shape["pp"]
     wide = toks.ndim == 2
     tokens = toks if wide else toks[:, None]     # [R, T]
@@ -271,8 +241,8 @@ def pp_decode_step(
         [cfg.layer_is_sliding(l) for l in range(cfg.num_layers)], dtype=bool
     )
 
-    def stages(layer_tree, flags, k_loc, v_loc, aux, stage_ids):
-        stage = stage_ids[0]   # data, not axis_index: see pipeline_forward
+    def stages(layer_tree, flags, k_loc, v_loc, aux):
+        stage = jax.lax.axis_index("pp")
 
         def pick(name, mi):
             a = aux.get(name)
@@ -322,20 +292,15 @@ def pp_decode_step(
 
     pool_spec = P("pp", None, None, None, None)
     aux_specs = jax.tree_util.tree_map(lambda _: P(), aux)
-    out, k_new, v_new = _shard_map(
+    out, k_new, v_new = jax.shard_map(
         stages,
         mesh=mesh,
         in_specs=(_stage_specs(params["layers"]), P("pp"), pool_spec,
-                  pool_spec, aux_specs, P("pp")),
+                  pool_spec, aux_specs),
         out_specs=(P(), pool_spec, pool_spec),
         check_vma=False,
-        # pp manual; composed tp x pp is rejected up front (the jax
-        # 0.4.37 partitioner aborts on ppermute in partial-auto regions
-        # with a >1 auto axis — see _reject_composed_mesh), so the
-        # engine serves tp x pp meshes through the fused GSPMD tick
-        axis_names={"pp"},
-    )(params["layers"], sliding_flags, cache.k, cache.v, aux,
-      jnp.arange(pp, dtype=jnp.int32))
+        axis_names={"pp"},   # only pp is manual (see pipeline_forward)
+    )(params["layers"], sliding_flags, cache.k, cache.v, aux)
 
     logits = logits_tail(cfg, params, out.reshape(r, t_w, -1))
     if not wide:

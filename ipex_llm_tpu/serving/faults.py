@@ -1,11 +1,11 @@
-"""Fault taxonomy + deterministic fault injection for the serving engine.
+"""Fault classes + deterministic fault injection for the serving engine.
 
 The engine's unit of failure is a REQUEST, not the engine (the blast-radius
 contract tests/test_serving_faults.py enforces).  Two failure classes drive
 the recovery policy in ``ServingEngine._recover``:
 
-- **transient** — the device call would succeed if repeated: preempted
-  tunnel, ``RESOURCE_EXHAUSTED``/``UNAVAILABLE`` from the runtime, a
+- **transient** — the device call would succeed if repeated: a preempted
+  device, ``RESOURCE_EXHAUSTED``/``UNAVAILABLE`` from the runtime, a
   dropped connection.  Recovery: roll host bookkeeping back to the last
   committed tick, re-upload device state (``_dirty = True`` — the same
   epoch mechanism admission uses), back off exponentially, and re-run the
@@ -34,7 +34,7 @@ Classification: exception TYPE first (the marker classes below, used by
 tests and by code that knows its failure mode), then RUNTIME MESSAGE
 markers — the gRPC-style status names JAX runtimes embed in
 ``XlaRuntimeError`` text (``RESOURCE_EXHAUSTED: ...``), plus OS-level
-connection failures from a device tunnel.
+connection failures.
 
 ``FaultInjector`` is the deterministic test harness for all of the above:
 it raises scripted exceptions at named SITES inside the engine step
@@ -89,7 +89,7 @@ __all__ = [
 
 class TransientFault(RuntimeError):
     """A step failure expected to succeed on retry (device preemption,
-    pool pressure in the runtime, tunnel hiccup)."""
+    pool pressure in the runtime, a dropped connection)."""
 
 
 class DeterministicFault(RuntimeError):
@@ -122,7 +122,7 @@ class ReplicaConnectRefused(ReplicaFault):
 
 class ReplicaStreamHang(ReplicaFault):
     """The replica stops producing SSE events mid-stream (wedged engine
-    thread, dead tunnel with the socket still open); the backend stalls
+    thread, dead peer with the socket still open); the backend stalls
     until the router's stall timeout trips."""
 
 
@@ -132,7 +132,7 @@ class ReplicaSlowHealth(ReplicaFault):
 
 
 # Status markers JAX device runtimes embed in XlaRuntimeError messages
-# (absl::Status names), plus tunnel/transport failures: all are
+# (absl::Status names), plus transport failures: all are
 # retry-worthy.  Deliberately NOT here: INVALID_ARGUMENT, INTERNAL,
 # FAILED_PRECONDITION — those recur on identical inputs.
 _TRANSIENT_MARKERS = (

@@ -58,9 +58,8 @@ new syncs — JP106's one-dispatch tick is untouched):
   estimate multiplies by the tick's executed iteration count — which the
   engine already syncs as ``n_exec``).  Reported per tick class:
   achieved flops/s, achieved bytes/s, and MFU = achieved / peak, where
-  peak comes from ``IPEX_LLM_TPU_PEAK_FLOPS`` /
-  ``IPEX_LLM_TPU_PEAK_BYTES_PER_S`` (falling back to documented nominal
-  per-platform defaults an operator should pin for real hardware).
+  peak comes from the ``device_kind``-keyed ``DEVICE_PEAKS`` table (an
+  unknown TPU raises; off the TPU platform MFU is not measured).
 
 Engines whose grid point the manifest does not cover (bigger row counts,
 wider buckets than the audit sampled) still get full attribution and
@@ -72,7 +71,6 @@ out-of-grid compiles flag, which is the message: extend the audit grid
 from __future__ import annotations
 
 import logging
-import os
 import threading
 import time
 from contextlib import contextmanager
@@ -88,6 +86,7 @@ __all__ = [
     "locked_points",
     "point_in_grid",
     "resolve_peaks",
+    "DEVICE_PEAKS",
 ]
 
 log = logging.getLogger("ipex_llm_tpu.perfwatch")
@@ -100,7 +99,7 @@ BUCKETS = ("dispatch", "device", "sync", "bookkeep")
 # like every other histogram here.
 PLAN_ERROR_BUCKETS = (0.01, 0.02, 0.05, 0.1, 0.2, 0.5, 1.0, 2.0, 5.0)
 
-# jax.monitoring event names (jax 0.4.37): one backend_compile per
+# jax.monitoring event names: one backend_compile per
 # compiled program — THE unit the sentinel counts — while the trace/
 # lowering events fire per (possibly nested) jaxpr and would overcount.
 _COMPILE_COUNT_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -122,32 +121,34 @@ _MAG_AXES = ("rows", "width", "horizon")
 # false warm flag, and the membership check ignores them.
 _UNLOCKED_AXES = ("pb", "maxp", "ew")
 
-# nominal roofline peaks per platform — deliberately round numbers an
-# operator overrides via env for their real part (a v5p, a Sapphire
-# Rapids socket...).  MFU is a ratio; the honest denominator is yours.
-_DEFAULT_PEAKS = {
-    "tpu": (275e12, 1.2e12),   # bf16 flops/s, HBM bytes/s (v4-class)
-    "cpu": (5e10, 2e10),       # one-core XLA CPU ballpark
+# Published per-chip peaks, keyed by the ``device_kind`` jax reports:
+# (bf16 FLOP/s, HBM bytes/s).  The one table MFU and roofline numbers are
+# taken against; a TPU that is not in it is an error, not a default.
+DEVICE_PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+    "TPU v5 lite": (197e12, 819e9),
 }
 
 
-def resolve_peaks(platform: str | None = None) -> tuple[float, float]:
-    """(peak_flops_per_s, peak_bytes_per_s) — env override first, then
-    the nominal per-platform default."""
-    if platform is None:
-        try:
-            from ipex_llm_tpu.ops.dispatch import backend_platform
-            platform = backend_platform()
-        except Exception:
-            platform = "cpu"
-    flops, byps = _DEFAULT_PEAKS.get(platform, _DEFAULT_PEAKS["cpu"])
+def resolve_peaks(device=None) -> tuple[float, float] | None:
+    """(peak_flops_per_s, peak_bytes_per_s) of ``device`` (default: the
+    first device jax reports).  None off the TPU platform: utilization of
+    a CPU running the XLA:CPU backend or the Pallas interpreter is not a
+    number this system reports, so MFU is "not measured" there.  A TPU
+    whose ``device_kind`` has no row raises."""
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return None
     try:
-        flops = float(os.environ.get("IPEX_LLM_TPU_PEAK_FLOPS", "") or flops)
-        byps = float(os.environ.get("IPEX_LLM_TPU_PEAK_BYTES_PER_S", "")
-                     or byps)
-    except ValueError:
-        pass
-    return flops, byps
+        return DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for TPU device_kind "
+            f"{device.device_kind!r}: add a row, with its source, to "
+            "serving/perfwatch.py::DEVICE_PEAKS") from None
 
 
 def model_flops_per_token(cfg) -> float:
@@ -281,10 +282,11 @@ def _on_event(event, duration=0.0, **_kw):
 
 
 def _install_listener():
-    """Register the module's single jax.monitoring listener (jax 0.4.37
-    has no per-listener unregister, so one global hook fans out to the
-    thread-local active watch — engines on different threads, in-process
-    fleets included, attribute their own compiles)."""
+    """Register the module's single jax.monitoring listener: one global
+    hook fans out to the thread-local active watch, so engines on
+    different threads — in-process fleets included — attribute their own
+    compiles, and the listener list does not grow with every engine a
+    process builds."""
     global _installed
     with _install_lock:
         if _installed:
@@ -352,9 +354,12 @@ class PerfWatch:
         # "sym_int4" (the widened int4 audit model), "tp" (the tp audit
         # model).  Missing key -> 1.0 (the caller IS the audit model).
         self.flops_scales = dict(flops_scales or {})
-        pf, pb = resolve_peaks()
-        self.peak_flops = float(peak_flops) if peak_flops else pf
-        self.peak_bytes_s = float(peak_bytes_s) if peak_bytes_s else pb
+        # None = not on a TPU: achieved flops/bytes are still joined, MFU
+        # and roofline shares are not measured
+        pf, pb = ((peak_flops, peak_bytes_s) if peak_flops and peak_bytes_s
+                  else resolve_peaks() or (None, None))
+        self.peak_flops = float(pf) if pf else None
+        self.peak_bytes_s = float(pb) if pb else None
         self._lock = threading.Lock()
         # sentinel state: points already compiled (the warm/cold line),
         # monotonic counters, the last out-of-grid evidence for /health
@@ -701,7 +706,8 @@ class PerfWatch:
                 joined = True
         dev_s = self._device_view(buckets, scratch["compile_s"])
         if joined and dev_s > 0:
-            out["mfu"] = round(flops / dev_s / self.peak_flops, 6)
+            if self.peak_flops:
+                out["mfu"] = round(flops / dev_s / self.peak_flops, 6)
             out["bytes_per_s"] = round(byts / dev_s, 1)
         if scratch["compiles"]:
             out["compiles"] = scratch["compiles"]
@@ -766,8 +772,9 @@ class PerfWatch:
             if f["joined"] and f["device_s"] > 0:
                 row["flops_per_s"] = round(f["flops"] / f["device_s"], 1)
                 row["bytes_per_s"] = round(f["bytes"] / f["device_s"], 1)
-                row["mfu"] = round(
-                    f["flops"] / f["device_s"] / self.peak_flops, 6)
+                if self.peak_flops:
+                    row["mfu"] = round(
+                        f["flops"] / f["device_s"] / self.peak_flops, 6)
             fams[name] = row
         return {
             "families": fams,
@@ -787,7 +794,7 @@ class PerfWatch:
                     else list(self._fam.values()))
         flops = sum(f["flops"] for f in fams if f)
         dev = sum(f["device_s"] for f in fams if f and f["joined"])
-        if not flops or dev <= 0:
+        if not flops or dev <= 0 or not self.peak_flops:
             return None
         return round(flops / dev / self.peak_flops, 6)
 

@@ -279,8 +279,8 @@ class MPCPlanner(_PlannerBase):
             return 0.0
         point = eng._perf_point(horizon, width=0, spec=spec_on)
         cost = perf.cost_for(point, max(horizon, 1))
-        if cost is None:
-            return 0.0
+        if cost is None or not perf.peak_flops:
+            return 0.0      # no cost entry, or no published peaks (off-TPU)
         flops, byts = cost
         sec = max(flops / perf.peak_flops, byts / perf.peak_bytes_s)
         return sec / max(horizon, 1)

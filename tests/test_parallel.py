@@ -67,23 +67,16 @@ def test_tp_logits_match_single_device(cfg_params, tp):
     np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
 
 
-def test_dp_tp_combined_logits(cfg_params):
-    """dp x tp composition over the full 8-device mesh.
-
-    KNOWN ENV LIMIT (jax 0.4.37): XLA:CPU's SPMD partitioner miscompiles
-    graphs that compose a tp=4 axis with any second >1 mesh axis (2x4 /
-    4x2-with-tp-innermost-4) — deterministically wrong numerics under BOTH
-    the GSPMD and shardy partitioners, both CPU runtimes, with all params
-    replicated and only the KV cache head-sharded (so it is not a sharding-
-    rule bug here).  tp=2 composes correctly at every tested shape (2x2,
-    4x2, 2x2x2).  The composed grid therefore pins tp=2; pure-tp meshes
-    (tp in {2,4,8}, covered above and by the manual shard_map serving
-    tick) are unaffected."""
+@pytest.mark.parametrize("spec", [MeshSpec(dp=4, tp=2),
+                                  MeshSpec(dp=2, tp=4)])
+def test_dp_tp_combined_logits(cfg_params, spec):
+    """dp x tp composition over the full 8-device mesh, at both tp
+    degrees."""
     cfg, params = cfg_params
     tokens = RNG.integers(0, cfg.vocab_size, (4, 7)).astype(np.int32)
     want = _logits(cfg, params, tokens)
 
-    mesh = make_mesh(MeshSpec(dp=4, tp=2))
+    mesh = make_mesh(spec)
     sharded = shard_params(params, mesh)
     got = _logits(cfg, sharded, tokens, mesh)
     np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
@@ -122,13 +115,8 @@ def test_pipeline_parallel_logits(cfg_params, spec):
 
 
 def test_pp_generate_matches(cfg_params):
-    """3-axis dp x pp x tp generate over all 8 devices.
-
-    The composed grid pins tp=2 — jax 0.4.37's XLA:CPU SPMD partitioner
-    miscompiles tp=4 composed with any second >1 axis (see
-    test_dp_tp_combined_logits for the characterization); 2x2x2 exercises
-    a STRONGER composition (all three parallel axes at once) and compiles
-    correctly in this environment."""
+    """3-axis dp x pp x tp generate over all 8 devices: all three
+    parallel axes at once."""
     cfg, params = cfg_params
     gen = GenerationConfig(max_new_tokens=8, do_sample=False)
     prompts = [list(RNG.integers(0, cfg.vocab_size, 11)),

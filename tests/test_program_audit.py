@@ -305,7 +305,7 @@ def test_jp103_fires_on_debug_print():
     spec = mkspec(_fx_callback, _vec_build, ("x",))
     found = list(jp.check_callbacks(spec, _entry(spec)))
     assert [f.rule for f in found] == ["JP103"]
-    assert "debug_callback" in found[0].message
+    assert "debug_print" in found[0].message
 
 
 def test_jp103_quiet_on_callback_free_program():

@@ -270,7 +270,7 @@ def test_is_transient_classification():
     assert is_transient(TransientFault("x"))
     assert not is_transient(DeterministicFault("x"))
     assert is_transient(RuntimeError("RESOURCE_EXHAUSTED: oom on chip"))
-    assert is_transient(ConnectionError("tunnel dropped"))
+    assert is_transient(ConnectionError("connection dropped"))
     assert not is_transient(RuntimeError("INVALID_ARGUMENT: bad shape"))
 
 

@@ -350,27 +350,6 @@ def test_transient_fault_rollback_over_int4_tick(cfg_params):
 
 # -- dispatch ladder ---------------------------------------------------------
 
-def test_qmatmul_ladder_selects_xla_on_cpu_interpret(monkeypatch):
-    """The recorded decode-shape qmatmul rows (M=1..8, interpret vs XLA —
-    BENCH_r12) must provably select the XLA block-dequant path on this
-    CPU environment, instead of a blanket platform rule."""
-    from ipex_llm_tpu.ops import dispatch
-
-    monkeypatch.delenv("IPEX_LLM_TPU_FORCE_PALLAS", raising=False)
-    monkeypatch.delenv("IPEX_LLM_TPU_DISABLE_PALLAS", raising=False)
-    monkeypatch.delenv("IPEX_LLM_TPU_DISPATCH_LADDER", raising=False)
-    dispatch.clear_cache()
-    try:
-        assert dispatch.backend_platform() == "cpu"
-        assert dispatch.ladder_prefers_pallas("qmatmul_sym_int4") is False
-        assert dispatch.use_pallas("qmatmul_sym_int4") is False
-        # a qtype family the ladder is silent on: platform default
-        assert dispatch.ladder_prefers_pallas("qmatmul_nf4") is None
-        assert dispatch.use_pallas("qmatmul_nf4") is False
-    finally:
-        dispatch.clear_cache()
-
-
 def test_qmatmul_ladder_is_data_driven(monkeypatch, tmp_path):
     """A re-measured collect() dump re-decides the qmatmul backend —
     recording the kernel faster turns the Pallas path on — and the

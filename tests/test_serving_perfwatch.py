@@ -209,6 +209,24 @@ def test_mfu_join_hand_computed_manifest_entry():
     assert w.mfu("tick.steady") == pytest.approx(out["mfu"], rel=0.02)
 
 
+def test_peaks_come_from_the_device_kind_table():
+    """One table keyed by device_kind: the v5e row carries the published
+    numbers, an unknown TPU kind raises instead of taking a default, and
+    off the TPU platform there are no peaks (MFU is not measured)."""
+    from types import SimpleNamespace as Dev
+
+    from ipex_llm_tpu.serving.perfwatch import DEVICE_PEAKS, resolve_peaks
+
+    assert resolve_peaks(Dev(platform="tpu", device_kind="TPU v5 lite")) \
+        == DEVICE_PEAKS["TPU v5 lite"] == (197e12, 819e9)
+    with pytest.raises(ValueError, match="TPU v99"):
+        resolve_peaks(Dev(platform="tpu", device_kind="TPU v99"))
+    assert resolve_peaks(Dev(platform="cpu", device_kind="cpu")) is None
+    assert resolve_peaks() is None            # this suite runs on the CPU
+    w = PerfWatch(hists={}, manifest=_toy_manifest())
+    assert w.peak_flops is None and w.mfu() is None
+
+
 def test_model_flops_scale_basis():
     from ipex_llm_tpu.analysis.trace.registry import audit_cfg
 
@@ -228,8 +246,9 @@ def test_model_flops_scale_basis():
 
 def test_engine_attribution_sums_to_tick_wall(cfg_params):
     """The acceptance bound: every committed working tick's buckets sum
-    to within 5% of its measured wall clock, the steady family reports a
-    nonzero manifest-joined MFU, and the grid point rides the record."""
+    to within 5% of its measured wall clock, the steady family reports
+    nonzero manifest-joined achieved rates, and the grid point rides the
+    record."""
     cfg, params = cfg_params
     eng = ServingEngine(cfg, params, EngineConfig(
         max_rows=4, max_seq_len=256, page_size=16, prefill_bucket=16,
@@ -247,10 +266,14 @@ def test_engine_attribution_sums_to_tick_wall(cfg_params):
         assert rec["perf_family"].startswith("tick.")
     steady = [r for r in ring if r["perf_family"] == "tick.steady"]
     assert steady
-    assert any(r.get("mfu", 0) > 0 for r in steady)
+    # this suite runs on the CPU: achieved rates are joined, but MFU is
+    # not measured off the TPU platform (no peaks row applies)
+    assert all("mfu" not in r for r in steady)
+    assert any(r.get("bytes_per_s", 0) > 0 for r in steady)
     assert all("rows=4" in r["grid_point"] for r in steady)
     pv = eng.perf_view()
-    assert pv["families"]["tick.steady"]["mfu"] > 0
+    assert "mfu" not in pv["families"]["tick.steady"]
+    assert pv["roofline"]["peak_flops"] is None
     assert pv["families"]["tick.steady"]["flops_per_s"] > 0
     assert pv["families"]["tick.steady"]["bytes_per_s"] > 0
     assert pv["ticks_attributed"] == len(ring)
@@ -261,7 +284,7 @@ def test_engine_attribution_sums_to_tick_wall(cfg_params):
     # numeric counters for the exposition
     nm = eng.perf_numeric()
     assert nm["perf_ticks_attributed"] == len(ring)
-    assert nm["perf_mfu"] > 0
+    assert "perf_mfu" not in nm
 
 
 def test_sentinel_quiet_on_locked_grid(cfg_params):
@@ -401,8 +424,6 @@ def test_ladder_provenance_stamps(tmp_path, monkeypatch):
         if prov["platform"] == "cpu":
             fams = prov["families"]
             assert fams["decode_attn"]["recorded"] == "BENCH_r05"
-            assert fams["qmatmul_sym_int4"]["recorded"] == "BENCH_r12"
-            assert fams["ragged_attn"]["recorded"] == "BENCH_r06"
             assert fams["decode_attn"]["prefers"] == "xla"
         # an override dump gets stamped from its own round field, or the
         # dump file's mtime date when it carries none
@@ -429,26 +450,19 @@ def test_ladder_provenance_stamps(tmp_path, monkeypatch):
         dispatch.clear_cache()
 
 
-def test_ladder_tpu_rows_and_tpu_dump_keying(tmp_path, monkeypatch):
-    """On-TPU ladder rows (ROADMAP item 5 follow-up): the builtin tpu
-    table carries a measured pair + bench-round stamp for every compiled
-    qmatmul/attention/ragged/spec family, and a list-form collect() dump
-    with NO interpret flags is a compiled-TPU recording — it keys under
-    "tpu" (replacing the snapshot wholesale) and stays invisible to CPU
-    lookups, which fall back to the platform default instead of applying
-    TPU wins to the interpreter."""
+def test_ladder_tpu_dump_keying(tmp_path, monkeypatch):
+    """The builtin ladder holds no tpu rows (none was ever measured on a
+    chip, so every family takes the platform default there), and a
+    list-form collect() dump with NO interpret flags is a compiled-TPU
+    recording — it keys under "tpu" and stays invisible to CPU lookups,
+    which fall back to the platform default instead of applying TPU wins
+    to the interpreter."""
     from ipex_llm_tpu.ops import dispatch
 
     monkeypatch.delenv("IPEX_LLM_TPU_DISPATCH_LADDER", raising=False)
     dispatch.clear_cache()
     try:
-        tpu = dispatch._BUILTIN_LADDER["tpu"]
-        for fam in ("qmatmul_sym_int4", "decode_attn", "decode_attn_fp8",
-                    "paged_gather", "paged_decode_attn", "ragged_attn",
-                    "ragged_attn_fp8", "spec_verify"):
-            assert fam in tpu, fam
-            assert tpu[fam]["pallas_us"] < tpu[fam]["xla_us"], fam
-            assert str(tpu[fam]["recorded"]).startswith("BENCH_r"), fam
+        assert "tpu" not in dispatch._BUILTIN_LADDER
         # synthetic tpu-keyed dump: list rows without "interpret"
         p = tmp_path / "tpu_ladder.json"
         p.write_text(json.dumps([
@@ -559,7 +573,7 @@ def test_health_metrics_perf_surface_e2e(cfg_params):
         assert perf["sentinel"]["grid_locked"] >= 30
         assert perf["ticks_attributed"] >= 1
         assert any(f.startswith("tick.") for f in perf["families"])
-        assert perf["roofline"]["peak_flops"] > 0
+        assert perf["roofline"]["peak_flops"] is None    # CPU: no peaks
         disp = h["dispatch"]
         assert disp["source"] == "builtin"
         assert all("recorded" in f for f in disp["families"].values())

@@ -380,7 +380,7 @@ def test_stale_device_lens_cannot_corrupt_neighbors(cfg_params):
 def test_dispatch_policy_selects_faster_backend_from_ladder(monkeypatch):
     """On this CPU-interpret environment the recorded ladder (BENCH_r05's
     interpret-vs-XLA rows) must provably select the XLA backend for every
-    paged/ragged decode op — and flipping the recorded numbers flips the
+    measured decode op — and flipping the recorded numbers flips the
     choice, proving the policy reads the data, not a hardcoded rule."""
     from ipex_llm_tpu.ops import dispatch
 
@@ -390,12 +390,14 @@ def test_dispatch_policy_selects_faster_backend_from_ladder(monkeypatch):
     dispatch.clear_cache()
     try:
         assert dispatch.backend_platform() == "cpu"
-        for op in ("ragged_attn", "ragged_attn_fp8", "decode_attn",
-                   "decode_attn_fp8", "paged_decode_attn"):
+        for op in ("decode_attn", "decode_attn_fp8", "paged_decode_attn"):
             assert dispatch.ladder_prefers_pallas(op) is False
             assert dispatch.use_pallas(op) is False
-        # an op the ladder is silent on falls back to the platform rule
-        assert dispatch.use_pallas("unmeasured_op") is False
+        # ops the ladder is silent on (no pair was ever measured for the
+        # ragged family) fall back to the platform rule
+        for op in ("ragged_attn", "ragged_attn_fp8", "unmeasured_op"):
+            assert dispatch.ladder_prefers_pallas(op) is None
+            assert dispatch.use_pallas(op) is False
     finally:
         dispatch.clear_cache()
 

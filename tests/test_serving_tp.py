@@ -10,9 +10,8 @@ engine runs under a tp mesh via SPMD.  Invariants:
   (per-shard single-device form inside the manual tick on pure-tp meshes,
   the shard_map-wrapped form on the GSPMD fallback) — not the gather path;
 - the OpenAI HTTP surface works end-to-end over a meshed engine;
-- composed tp x pp meshes DO NOT take the GPipe pipelined path (jax
-  0.4.37 aborts on ppermute in composed partial-auto regions — the
-  characterization tests below) and serve via the fused GSPMD tick.
+- composed tp x pp meshes take the GPipe pipelined path too (only pp is
+  manual inside the pipeline's region; tp stays under GSPMD there).
 """
 
 import json
@@ -300,17 +299,10 @@ def test_pp_engine_row_churn(cfg_params):
         _assert_greedy_stream(cfg, params, p, g)
 
 
-def test_tp_pp_engine_serves_via_fused_tick(cfg_params):
-    """tp=2 x pp=2 serving.
-
-    KNOWN ENV LIMIT (jax 0.4.37): ppermute inside a partial-auto
-    shard_map region on a composed mesh CHECK-CRASHES the XLA SPMD
-    partitioner (spmd_partitioner.cc IsManualSubgroup — a process abort,
-    not an exception), so the GPipe pipelined step cannot compose with a
-    tp axis here.  The engine must therefore NOT take the pipelined path
-    on a composed mesh — it serves through the fused GSPMD tick (tp=2
-    compositions are the characterized-safe GSPMD grid, see
-    tests/test_parallel.py) with greedy streams matching single-device."""
+def test_tp_pp_engine_takes_the_pipelined_path(cfg_params):
+    """tp=2 x pp=2 serving rides the GPipe pipelined step: the pipeline's
+    region makes only pp manual, so on a composed mesh the tp axis stays
+    under GSPMD inside it.  Greedy streams match single-device."""
     cfg, params = cfg_params
     mesh = make_mesh(MeshSpec(tp=2, pp=2))
     eng = ServingEngine(
@@ -318,8 +310,7 @@ def test_tp_pp_engine_serves_via_fused_tick(cfg_params):
         EngineConfig(max_rows=2, max_seq_len=256, prefill_bucket=32),
         mesh=mesh,
     ).start()
-    assert not eng._pp_mode, \
-        "composed tp x pp must not take the GPipe path (env abort)"
+    assert eng._pp_mode
     try:
         prompts = [list(RNG.integers(0, cfg.vocab_size, n)) for n in (9, 23)]
         reqs = [eng.submit(Request(prompt_ids=p, max_new_tokens=8))
@@ -332,22 +323,24 @@ def test_tp_pp_engine_serves_via_fused_tick(cfg_params):
         _assert_greedy_stream(cfg, params, p, g)
 
 
-def test_tp_pp_pipeline_forward_rejects_composed_mesh(cfg_params):
-    """pipeline_forward on a composed tp x pp mesh must refuse with a
-    catchable error UP FRONT: lowering it would ABORT the process (jax
-    0.4.37 partitioner CHECK on ppermute in a partial-auto region with a
-    >1 auto axis — see parallel/pipeline._reject_composed_mesh)."""
+def test_tp_pp_pipeline_forward_matches_pure_pp(cfg_params):
+    """pipeline_forward on a composed tp x pp mesh computes what it
+    computes on the pure-pp mesh."""
     import jax.numpy as jnp
 
     from ipex_llm_tpu.parallel.pipeline import pipeline_forward
     from ipex_llm_tpu.parallel.shard import shard_params
 
     cfg, params = cfg_params
-    tokens = RNG.integers(0, cfg.vocab_size, (4, 16)).astype(np.int32)
-    mesh = make_mesh(MeshSpec(tp=2, pp=2))
-    sp = shard_params(params, mesh)
-    with pytest.raises(ValueError, match="pure-pp mesh"):
-        pipeline_forward(cfg, sp, jnp.asarray(tokens), mesh, n_micro=2)
+    tokens = jnp.asarray(
+        RNG.integers(0, cfg.vocab_size, (4, 16)).astype(np.int32))
+    outs = []
+    for spec in (MeshSpec(pp=2), MeshSpec(tp=2, pp=2)):
+        mesh = make_mesh(spec)
+        outs.append(np.asarray(pipeline_forward(
+            cfg, shard_params(params, mesh), tokens, mesh, n_micro=2),
+            np.float32))
+    np.testing.assert_allclose(outs[1], outs[0], atol=2e-2, rtol=2e-2)
 
 
 def test_pp_speculative_pipelined_verify(cfg_params, monkeypatch):

@@ -14,13 +14,11 @@ points.  Gates:
 - quantized wire families (EQuARX e5m2/int8) pass a bounded-error gate:
   sliding-ppl ratio < 1.25 vs the exact family, greedy token-match rate
   reported;
-- the compat shim (parallel/compat.py) translates the pinned modern
-  shard_map surface onto jax 0.4.37, and the engine's eligibility
-  routing falls back to GSPMD with a recorded reason where the manual
-  layout does not apply.
+- the engine's eligibility routing falls back to GSPMD with a recorded
+  reason where the manual layout does not apply.
 
 Engine-level tests are slow-tier (each compiles the sharded tick on the
-8-virtual-device mesh); the collective/shim/relayout unit tests ride the
+8-virtual-device mesh); the collective/relayout unit tests ride the
 fast tier — scripts/run-fast-tests names this split.
 """
 
@@ -217,7 +215,7 @@ def test_spec_and_horizon_ride_the_manual_tick(cfg_params):
 
 
 # --------------------------------------------------------------------------
-# unit tier (fast): collectives, shim, relayout, eligibility routing
+# unit tier (fast): collectives, relayout, eligibility routing
 # --------------------------------------------------------------------------
 
 def _psum_families(x, tp):
@@ -225,12 +223,11 @@ def _psum_families(x, tp):
     from jax.sharding import PartitionSpec as P
 
     from ipex_llm_tpu.ops import collectives
-    from ipex_llm_tpu.parallel.compat import shard_map
 
     mesh = make_mesh(MeshSpec(tp=tp))
     out = {}
     for q in collectives.ALLREDUCE_QTYPES:
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda v, q=q: collectives.all_reduce(v, "tp", qtype=q),
             mesh=mesh, in_specs=P("tp", None), out_specs=P(),
             axis_names={"tp"}, check_vma=False))
@@ -309,34 +306,6 @@ def test_resolve_qtype_precedence(cfg_params, monkeypatch):
     assert ServingEngine(
         cfg, params, _dc_replace(ec, collective_qtype="e5m2"),
     )._collective_qtype == "e5m2"
-
-
-def test_compat_shim_pinned_surface():
-    """The parallel/compat.py shim: modern keyword surface on jax 0.4.37 —
-    fully-manual and partial-auto regions both lower; unknown axis names
-    raise instead of silently mistranslating."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from ipex_llm_tpu.parallel.compat import shard_map
-
-    mesh = make_mesh(MeshSpec(dp=2, tp=4))
-    x = jnp.arange(8.0, dtype=jnp.float32)
-
-    full = shard_map(lambda v: jax.lax.psum(v, "tp"),
-                     mesh=mesh, in_specs=P("tp"), out_specs=P(),
-                     axis_names={"dp", "tp"}, check_vma=False)
-    # arange(8) over 4 tp shards of 2: psum = [0+2+4+6, 1+3+5+7]
-    np.testing.assert_allclose(np.asarray(jax.jit(full)(x)), [12.0, 16.0])
-    # partial-auto: only tp manual, dp left to GSPMD
-    part = shard_map(lambda v: jax.lax.psum(v, "tp"),
-                     mesh=mesh, in_specs=P("tp"), out_specs=P(),
-                     axis_names={"tp"}, check_vma=True)
-    jax.jit(part)(x)   # lowers and runs: check_vma downgraded, not a crash
-    with pytest.raises(ValueError, match="not in mesh axes"):
-        shard_map(lambda v: v, mesh=mesh, in_specs=P(), out_specs=P(),
-                  axis_names={"zz"})
 
 
 def test_relayout_packed_is_a_column_permutation(cfg_params):
