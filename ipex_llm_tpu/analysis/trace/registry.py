@@ -433,7 +433,7 @@ def real_registry() -> tuple[ProgramSpec, ...]:
             # loop, steady-state at both horizons plus the admission-wave
             # joiner tick), each over bf16 and fp8 pools
             name="serving.ragged_tick",
-            fn=engine._ragged_tick_fn,
+            fn=engine._ragged_tick,
             build=_build_ragged_tick,
             grid=(_grid(rows=(4, 8), width=(0,), horizon=(1, 8),
                         kv=kv_axis)

@@ -42,7 +42,7 @@ class TickSpec:
 
 def mixed_tick_spec() -> TickSpec:
     """The mixed prefill+decode tick: ONE fused program
-    (``_ragged_tick_fn`` — ragged prefill, on-device first-token merge,
+    (``_ragged_tick`` — ragged prefill, on-device first-token merge,
     and the decode horizon in a single jitted entry; the steady-state
     tick is the same entry with no prefill block).  The gate is EXACTLY
     1 dispatch per tick — the ragged paged-attention superkernel
@@ -53,7 +53,7 @@ def mixed_tick_spec() -> TickSpec:
         name="mixed",
         module="ipex_llm_tpu.serving.engine",
         entries=("_mixed_step", "_horizon_step"),
-        programs=("_ragged_tick_fn",),
+        programs=("_ragged_tick",),
         alternates=("_pp_decode_sample",),   # pp engines route H=1 here
         max_dispatches=1,
     )

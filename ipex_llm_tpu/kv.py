@@ -107,6 +107,7 @@ class KVCache:
     def decode_layer(self, kl: jnp.ndarray, compute_dtype=jnp.bfloat16) -> jnp.ndarray:
         return kl.astype(compute_dtype)
 
+    @jax.named_scope("layer/kv_write")
     def update_layer(self, kl: jnp.ndarray, vl: jnp.ndarray,
                      new_k: jnp.ndarray, new_v: jnp.ndarray, pos: jnp.ndarray):
         """Write new_k/new_v [B, T, H, D] into layer slices [B, H, S, D] at
@@ -285,6 +286,7 @@ class PagedKVCache:
         for at most the rows it advanced)."""
         return replace(self, tables=self.tables.at[rows].set(table_rows))
 
+    @jax.named_scope("layer/kv_write")
     def update_layer(self, kl: jnp.ndarray, vl: jnp.ndarray,
                      new_k: jnp.ndarray, new_v: jnp.ndarray, pos: jnp.ndarray):
         """Scatter new_k/new_v [B, T, H, D] into pool layer [P, H, page, D]
@@ -334,6 +336,7 @@ class PagedKVCache:
             v=self.v.at[:, ids].set(v_pages.astype(self.v.dtype)),
         )
 
+    @jax.named_scope("layer/kv_read")
     def gather_layer(self, kl: jnp.ndarray) -> jnp.ndarray:
         """Pool layer [P, H, page, D] -> head-major rows [R, H, maxP*page, D]
         (the raw layout cached_sdpa's decode path consumes)."""

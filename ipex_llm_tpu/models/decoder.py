@@ -75,9 +75,9 @@ def _attention_block(cfg: ModelConfig, lp: dict, x, kl, vl, cos, sin, slot0,
     # olmo2-style reordered norm: attention sees the raw residual stream
     # and attn_norm applies to the block OUTPUT instead; pre_normed: the
     # caller already normed x (glm_alpha residual needs the normed input)
-    h = (x if cfg.norm_after or pre_normed
-         else _in_norm(x, lp, "attn_norm", cfg))
-    q_dim, kv_dim = cfg.q_dim, cfg.kv_dim
+    with jax.named_scope("layer/qkv"):
+        h = (x if cfg.norm_after or pre_normed
+             else _in_norm(x, lp, "attn_norm", cfg))
     if cfg.is_mla:
         # DeepSeek MLA (reference deepseek.py:274-343): low-rank q, a
         # compressed KV latent with a shared (MQA-like) rope slice, and an
@@ -125,6 +125,47 @@ def _attention_block(cfg: ModelConfig, lp: dict, x, kl, vl, cos, sin, slot0,
         if cfg.post_attn_norm:
             out = _norm(out, lp["post_attn_norm"], cfg)
         return out, kl, vl, obs_q
+    with jax.named_scope("layer/qkv"):
+        q, k, v, obs_q = _qkv_rope(cfg, lp, x, h, cos, sin, collect_obs)
+    kl, vl = cache.update_layer(kl, vl, k, v, slot0)
+
+    # the cache layer stays in storage dtype: decode steps read it directly
+    # through the specialized kernel (fp8 dequant in-kernel); other shapes
+    # cast once inside cached_sdpa
+    with jax.named_scope("layer/attn"):
+        attn = cached_sdpa(
+            q,
+            kl,
+            vl,
+            cache,
+            compute_dtype=COMPUTE_DTYPE,
+            causal=True,
+            q_positions=q_slots,
+            kv_len=kv_len,
+            kv_start=kv_start,
+            window=cfg.sliding_window,
+            window_on=sliding,
+            softcap=cfg.attn_softcap,
+            scale=cfg.attn_scale,
+            bias=bias,
+            chunk_lens=chunk_lens,
+        )
+    with jax.named_scope("layer/o_proj"):
+        attn = attn.reshape(b, t, cfg.num_heads * cfg.head_dim)
+        out = linear_ops.linear(attn, lp["o"], lp.get("o_bias"))
+        if cfg.norm_after:
+            out = _norm(out, lp["attn_norm"], cfg, lp.get("attn_norm_bias"))
+        if cfg.post_attn_norm:
+            out = _norm(out, lp["post_attn_norm"], cfg)
+    return out, kl, vl, obs_q
+
+
+def _qkv_rope(cfg: ModelConfig, lp: dict, x, h, cos, sin, collect_obs):
+    """The ``layer/qkv`` stage of :func:`_attention_block` (non-MLA):
+    projections from the normed input ``h``, q/k norms, rope.  Returns
+    (q [B,T,Hq,D], k, v [B,T,Hkv,D], obs_q)."""
+    b, t, _ = x.shape
+    q_dim, kv_dim = cfg.q_dim, cfg.kv_dim
     if "qkv" in lp:
         qkv = linear_ops.linear(h, lp["qkv"], lp.get("qkv_bias"))
         q = qkv[..., :q_dim]
@@ -177,38 +218,10 @@ def _attention_block(cfg: ModelConfig, lp: dict, x, kl, vl, cos, sin, slot0,
             )
 
     obs_q = q[:, -collect_obs:] if collect_obs else jnp.zeros((0,), x.dtype)
-
-    kl, vl = cache.update_layer(kl, vl, k, v, slot0)
-
-    # the cache layer stays in storage dtype: decode steps read it directly
-    # through the specialized kernel (fp8 dequant in-kernel); other shapes
-    # cast once inside cached_sdpa
-    attn = cached_sdpa(
-        q,
-        kl,
-        vl,
-        cache,
-        compute_dtype=COMPUTE_DTYPE,
-        causal=True,
-        q_positions=q_slots,
-        kv_len=kv_len,
-        kv_start=kv_start,
-        window=cfg.sliding_window,
-        window_on=sliding,
-        softcap=cfg.attn_softcap,
-        scale=cfg.attn_scale,
-        bias=bias,
-        chunk_lens=chunk_lens,
-    )
-    attn = attn.reshape(b, t, cfg.num_heads * cfg.head_dim)
-    out = linear_ops.linear(attn, lp["o"], lp.get("o_bias"))
-    if cfg.norm_after:
-        out = _norm(out, lp["attn_norm"], cfg, lp.get("attn_norm_bias"))
-    if cfg.post_attn_norm:
-        out = _norm(out, lp["post_attn_norm"], cfg)
-    return out, kl, vl, obs_q
+    return q, k, v, obs_q
 
 
+@jax.named_scope("layer/mlp")
 def _moe_block(cfg: ModelConfig, lp: dict, x):
     """Sparse-MoE FFN (mixtral/qwen-moe), reference deepseek.py:274-343 +
     common.py:342-375 ``moe_group_topk``/``moe_forward_vec``.
@@ -307,6 +320,7 @@ def _moe_block(cfg: ModelConfig, lp: dict, x):
     return out
 
 
+@jax.named_scope("layer/mlp")
 def _mlp_block(cfg: ModelConfig, lp: dict, x, pre_normed=False):
     h = (x if cfg.norm_after or pre_normed
          else _in_norm(x, lp, "mlp_norm", cfg))
@@ -419,6 +433,7 @@ def alibi_bias_for(cfg: ModelConfig, q_slots, s: int):
     return slopes[None, :, None, None] * dist
 
 
+@jax.named_scope("head")
 def logits_tail(cfg: ModelConfig, params, x):
     """Final norm + lm head + logit scale/softcap — the post-stack tail
     shared by decoder_forward and the pipeline scheduler."""

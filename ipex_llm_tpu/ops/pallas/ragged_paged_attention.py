@@ -7,7 +7,7 @@ rows where each row is either a **decode row** (1 query token at slot
 to the chunk width, causally masked against its own history), or an **idle
 row** (``chunk_len == 0`` — fully masked, zero output).  It subsumes both
 ``paged_attention.paged_decode_sdpa`` and ``paged_prefill_sdpa``, so the
-serving engine's fused tick program (``serving/engine.py::_ragged_tick_fn``)
+serving engine's fused tick program (``serving/engine.py::_ragged_tick``)
 carries exactly one attention kernel family regardless of the admission mix.
 
 What it fixes over the split kernels (the BENCH_r05 per-op losses):

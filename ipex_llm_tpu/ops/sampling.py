@@ -82,6 +82,7 @@ def _top_k_mask_rows(logits: jnp.ndarray, ks: jnp.ndarray) -> jnp.ndarray:
     return jnp.where((ks > 0)[:, None], masked, logits)
 
 
+@jax.named_scope("sample")
 def sample_rows_with_logprobs(logits: jnp.ndarray, temps: jnp.ndarray,
                               top_ps: jnp.ndarray, key: jax.Array,
                               seeds: jnp.ndarray | None = None,

@@ -32,7 +32,7 @@ import threading
 import time
 import uuid
 from collections import OrderedDict, deque
-from contextlib import nullcontext
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field, replace
 from functools import partial
 from typing import Any
@@ -170,7 +170,7 @@ class EngineConfig:
     decode_horizon: int = 1
     # mixed prefill+decode step: per-tick prefill token budget for the
     # admission wave.  While ANY row is prefilling, the engine runs
-    # ``_mixed_step`` — ONE fused program (``_ragged_tick_fn``) advances
+    # ``_mixed_step`` — ONE fused program (``_ragged_tick``) advances
     # EVERY prefilling row by a ragged chunk, samples-and-merges first
     # tokens on device for prompts that complete, and runs the decode
     # step for every active row, all in a single dispatch (the TPU
@@ -323,6 +323,7 @@ class Request:
     finish_reason: str | None = None
     first_token_s: float = 0.0
     submitted_s: float = field(default_factory=time.perf_counter)
+    queue_wait_s: float = 0.0   # submit -> row assigned (set by _admit)
     cancelled: bool = False  # set via ServingEngine.abort (client disconnect)
     stop_strings: list[str] = field(default_factory=list)
     # None = engine default (on when EngineConfig.spec_k > 0); False opts a
@@ -464,7 +465,7 @@ def _decode_horizon_loop(cfg: ModelConfig, params, cache, toks, row_lens,
     ``lax.scan``, because the loop must exit early the moment every row
     is dead).  ONE definition, traced into BOTH jitted entries —
     ``_decode_multi_step`` (the historical fused-decode program, kept as
-    the equivalence oracle) and ``_ragged_tick_fn`` (the single-dispatch
+    the equivalence oracle) and ``_ragged_tick`` (the single-dispatch
     tick) — so the two programs cannot drift and the superkernel tick
     stays bit-identical to the chained path by construction.
 
@@ -710,7 +711,7 @@ def _decode_multi_step(cfg: ModelConfig, params, cache, toks, row_lens,
                        eos, remain, horizon: int = 1, mesh=None):
     """The historical fused-decode program: ``_decode_horizon_loop`` as
     its own jitted entry.  The live tick path now routes through
-    ``_ragged_tick_fn`` (which traces the SAME loop body, so outputs are
+    ``_ragged_tick`` (which traces the SAME loop body, so outputs are
     bit-identical); this entry remains for the pre-superkernel callers
     and as the chained-path oracle the equivalence tests drive.
 
@@ -894,7 +895,7 @@ def _mixed_prefill_fn(cfg: ModelConfig, params, cache, tokens, base_lens,
     trip.  Returns ([P] tokens, [P] logprobs, cache, key).
 
     HISTORICAL NOTE: the live tick no longer dispatches this program —
-    ``_ragged_tick_fn`` fuses the same prefill stage with the decode
+    ``_ragged_tick`` fuses the same prefill stage with the decode
     horizon into ONE dispatch (stage 1 there is this function's body
     with per-row ``chunk_lens`` threaded into attention).  It remains
     module-level-jitted as half of the chained two-program oracle the
@@ -932,7 +933,7 @@ def _tick_body(cfg: ModelConfig, params, cache, toks, row_lens,
                eos, remain, prefill=None, horizon: int = 1,
                with_decode: bool = True, hist=None, spec_ks=None,
                spec_k: int = 0, spec_ngram: int = 3):
-    """The fused tick BODY (see ``_ragged_tick_fn`` for the contract):
+    """The fused tick BODY (see ``_ragged_tick`` for the contract):
     traced either directly under GSPMD dispatch, or — the manual-mesh
     serving form — once per shard inside ``parallel/manual.tp_tick``'s
     fully-manual shard_map region with a shard-local cfg/params/pool."""
@@ -944,62 +945,65 @@ def _tick_body(cfg: ModelConfig, params, cache, toks, row_lens,
         (p_tokens, p_tables, p_base, p_nvalid, p_emit, p_canjoin,
          p_rowmap) = prefill
         w = p_tokens.shape[1]
-        row_cache = replace(cache, tables=p_tables)
-        pos = p_base[:, None] + jnp.arange(w)[None, :]
-        logits, row_cache = decoder_forward(
-            cfg, params, p_tokens, row_cache, pos,
-            slot_offsets=p_base,
-            gather_positions=jnp.maximum(p_nvalid - 1, 0),
-            chunk_lens=p_nvalid,
-        )
-        cache = replace(cache, k=row_cache.k, v=row_cache.v)
-        key, sub = jax.random.split(key)
-        first_t, first_lp = sample_rows_with_logprobs(
-            logits, temps[p_rowmap], top_ps[p_rowmap], sub,
-            seeds=seeds[p_rowmap], steps=jnp.zeros_like(p_nvalid),
-            top_ks=top_ks[p_rowmap], active=p_emit)
-        # merge the wave into the decode state (pad slots drop):
-        # lengths advance for EVERY prefill row, completing rows join
-        # with their first token pre-published — the on-device form
-        # of the epoch upload the chained path paid here
-        new_len = p_base + p_nvalid
-        row_lens = row_lens.at[p_rowmap].set(new_len, mode="drop")
-        hit_eos = (first_t[:, None] == eos[p_rowmap]).any(axis=1)
-        rem_after = remain[p_rowmap] - 1
-        join = p_emit & p_canjoin & ~hit_eos & (rem_after > 0)
-        toks = toks.at[p_rowmap].set(
-            jnp.where(p_emit, first_t, toks[p_rowmap]), mode="drop")
-        steps = steps.at[p_rowmap].set(
-            jnp.where(p_emit, 1, steps[p_rowmap]), mode="drop")
-        remain = remain.at[p_rowmap].set(
-            jnp.where(p_emit, rem_after, remain[p_rowmap]),
-            mode="drop")
-        active = active.at[p_rowmap].set(
-            jnp.where(p_emit, join, active[p_rowmap]), mode="drop")
-        if spec_k > 0:
-            # a completing row's history gains its first token ON
-            # DEVICE (the prompt itself landed with the admission
-            # epoch upload), so the decode stage below can already
-            # draft for it; pad slots and non-emitting rows drop
-            hpos = jnp.where(p_emit, new_len, hist.shape[1])
-            hist = hist.at[p_rowmap, hpos].set(first_t, mode="drop")
-    if with_decode and spec_k > 0:
-        (tok_block, lp_block, n_exec, cache, toks, row_lens, active,
-         steps, remain, key, take_block, hist, prop,
-         acc) = _decode_horizon_loop(
-            cfg, params, cache, toks, row_lens, active, temps,
-            top_ps, key, seeds, steps, top_ks, eos, remain, horizon,
-            hist=hist, spec_ks=spec_ks, spec_k=spec_k,
-            spec_ngram=spec_ngram)
-    elif with_decode:
-        (tok_block, lp_block, n_exec, cache, toks, row_lens, active,
-         steps, remain, key) = _decode_horizon_loop(
-            cfg, params, cache, toks, row_lens, active, temps,
-            top_ps, key, seeds, steps, top_ks, eos, remain, horizon)
-    else:
-        tok_block = jnp.zeros((r, horizon), jnp.int32)
-        lp_block = jnp.zeros((r, horizon), jnp.float32)
-        n_exec = jnp.asarray(0, jnp.int32)
+        with jax.named_scope("tick/prefill"):
+            row_cache = replace(cache, tables=p_tables)
+            pos = p_base[:, None] + jnp.arange(w)[None, :]
+            logits, row_cache = decoder_forward(
+                cfg, params, p_tokens, row_cache, pos,
+                slot_offsets=p_base,
+                gather_positions=jnp.maximum(p_nvalid - 1, 0),
+                chunk_lens=p_nvalid,
+            )
+            cache = replace(cache, k=row_cache.k, v=row_cache.v)
+            key, sub = jax.random.split(key)
+            first_t, first_lp = sample_rows_with_logprobs(
+                logits, temps[p_rowmap], top_ps[p_rowmap], sub,
+                seeds=seeds[p_rowmap], steps=jnp.zeros_like(p_nvalid),
+                top_ks=top_ks[p_rowmap], active=p_emit)
+        with jax.named_scope("tick/merge"):
+            # merge the wave into the decode state (pad slots drop):
+            # lengths advance for EVERY prefill row, completing rows join
+            # with their first token pre-published — the on-device form
+            # of the epoch upload the chained path paid here
+            new_len = p_base + p_nvalid
+            row_lens = row_lens.at[p_rowmap].set(new_len, mode="drop")
+            hit_eos = (first_t[:, None] == eos[p_rowmap]).any(axis=1)
+            rem_after = remain[p_rowmap] - 1
+            join = p_emit & p_canjoin & ~hit_eos & (rem_after > 0)
+            toks = toks.at[p_rowmap].set(
+                jnp.where(p_emit, first_t, toks[p_rowmap]), mode="drop")
+            steps = steps.at[p_rowmap].set(
+                jnp.where(p_emit, 1, steps[p_rowmap]), mode="drop")
+            remain = remain.at[p_rowmap].set(
+                jnp.where(p_emit, rem_after, remain[p_rowmap]),
+                mode="drop")
+            active = active.at[p_rowmap].set(
+                jnp.where(p_emit, join, active[p_rowmap]), mode="drop")
+            if spec_k > 0:
+                # a completing row's history gains its first token ON
+                # DEVICE (the prompt itself landed with the admission
+                # epoch upload), so the decode stage below can already
+                # draft for it; pad slots and non-emitting rows drop
+                hpos = jnp.where(p_emit, new_len, hist.shape[1])
+                hist = hist.at[p_rowmap, hpos].set(first_t, mode="drop")
+    with jax.named_scope("tick/decode"):
+        if with_decode and spec_k > 0:
+            (tok_block, lp_block, n_exec, cache, toks, row_lens, active,
+             steps, remain, key, take_block, hist, prop,
+             acc) = _decode_horizon_loop(
+                cfg, params, cache, toks, row_lens, active, temps,
+                top_ps, key, seeds, steps, top_ks, eos, remain, horizon,
+                hist=hist, spec_ks=spec_ks, spec_k=spec_k,
+                spec_ngram=spec_ngram)
+        elif with_decode:
+            (tok_block, lp_block, n_exec, cache, toks, row_lens, active,
+             steps, remain, key) = _decode_horizon_loop(
+                cfg, params, cache, toks, row_lens, active, temps,
+                top_ps, key, seeds, steps, top_ks, eos, remain, horizon)
+        else:
+            tok_block = jnp.zeros((r, horizon), jnp.int32)
+            lp_block = jnp.zeros((r, horizon), jnp.float32)
+            n_exec = jnp.asarray(0, jnp.int32)
     if spec_k > 0:
         return (first_t, first_lp, tok_block, lp_block, n_exec, cache,
                 toks, row_lens, active, steps, remain, key, take_block,
@@ -1014,7 +1018,7 @@ def _tick_body(cfg: ModelConfig, params, cache, toks, row_lens,
                           "collective_qtype"),
          donate_argnums=(2, 3, 4, 5, 10, 13), donate_argnames=("hist",),
          compiler_options=_EXACT_ROUNDING)
-def _ragged_tick_fn(cfg: ModelConfig, params, cache, toks, row_lens,
+def _ragged_tick(cfg: ModelConfig, params, cache, toks, row_lens,
                     active, temps, top_ps, key, seeds, steps, top_ks,
                     eos, remain, prefill=None, horizon: int = 1,
                     with_decode: bool = True, hist=None, spec_ks=None,
@@ -1085,6 +1089,15 @@ def _ragged_tick_fn(cfg: ModelConfig, params, cache, toks, row_lens,
             key, seeds, steps, top_ks, eos, remain, prefill=prefill,
             horizon=horizon, with_decode=with_decode, hist=hist,
             spec_ks=spec_ks, spec_k=spec_k, spec_ngram=spec_ngram)
+
+
+# The entry's name until PR 25, kept for what imports it from outside the
+# package (chip_smoke.py, perfbench/rehearse_memory.py, tests).  The entry
+# was renamed because the compiled program's name is part of the persistent
+# compile cache's key and the names of its parts (the scopes above, which
+# are op metadata) are not: under the old name an executable cached before
+# the scopes existed would be loaded again without them.
+_ragged_tick_fn = _ragged_tick
 
 
 class ServingEngine:
@@ -1305,7 +1318,7 @@ class ServingEngine:
                              else int(self.ec.step_token_budget))
         self._mixed_mode = self._step_budget > 0 and not self._pp_mode
         # on-device speculative decode inside the fused tick: the mixed/
-        # horizon engine threads spec through _ragged_tick_fn (draft +
+        # horizon engine threads spec through _ragged_tick (draft +
         # verify + accept in the device horizon loop, still one dispatch).
         # The sequential engine (step_token_budget=0) and the pp engine
         # keep the host-walk _spec_step — the former is the seeded
@@ -1510,6 +1523,14 @@ class ServingEngine:
                         "mixed_steps": 0, "mixed_prefill_tokens": 0,
                         "prefill_tokens_per_step": 0.0,
                         "table_row_syncs": 0, "ttft_p95_s": 0.0,
+                        # where a first token's wait went, as sums a
+                        # scraper divides: submit -> row assigned (at
+                        # _admit), row assigned -> first token on the
+                        # host (at _finish_prompt), and the count of the
+                        # latter
+                        "queue_wait_s_total": 0.0,
+                        "admit_to_first_token_s_total": 0.0,
+                        "first_tokens": 0,
                         # fault-domain observability: per-request failures
                         # isolated by bisection, transient step retries,
                         # load-shed submissions, expired deadlines, and
@@ -1728,14 +1749,37 @@ class ServingEngine:
         return self._hists_committed
 
     def _perf_dispatch(self, family: str, point: dict | None = None,
-                       tick: bool = True):
+                       tick: bool = True, **attrs):
         """Perfwatch timing window around ONE device dispatch (no-op
         context when the observatory is off).  ``tick=True`` windows
         count toward the JP106 runtime cross-check against the
-        hand-maintained ``_tick_dispatches`` counter."""
+        hand-maintained ``_tick_dispatches`` counter.  ``attrs`` ride on
+        the window's ``engine/dispatch`` span."""
         if self.perf is None:
             return nullcontext()
-        return self.perf.dispatch(family, point=point, tick=tick)
+        return self.perf.dispatch(family, point=point, tick=tick, **attrs)
+
+    def _phase(self, name: str, **attrs):
+        """One host phase of the tick (``perfwatch.PHASES``): a span on
+        the profiler's timeline and a ``perf_phase_<name>_s`` counter."""
+        if self.perf is None:
+            return nullcontext()
+        return self.perf.phase(name, **attrs)
+
+    def _phase_to(self, name: str | None):
+        """The step functions' form of ``_phase``: from here on the tick
+        is in phase ``name``, until the next ``_phase_to``."""
+        if self.perf is not None:
+            self.perf.phase_to(name)
+
+    @contextmanager
+    def _sync_window(self):
+        """THE tick's blocking device->host materialization: the ``sync``
+        phase, counted by ``_count_sync`` when it returns."""
+        with self._phase("sync"):
+            t0 = time.perf_counter()
+            yield
+            self._count_sync(time.perf_counter() - t0)
 
     def _perf_point(self, horizon: int, width: int = 0,
                     with_decode: bool = True, spec: bool = False,
@@ -2138,6 +2182,11 @@ class ServingEngine:
         if self._drain_abort.is_set():
             self._shed_remaining()
             self._drain_abort.clear()
+        with self._phase("tick", tick=self.metrics.get("ticks", 0) + 1):
+            return self._tick_phases()
+
+    def _tick_phases(self):
+        """``_tick`` inside its ``engine/tick`` span, phase by phase."""
         # plan the tick BEFORE the checkpoint: the plan snapshots with
         # the tick state, so a transient-retry re-run and every bisection
         # probe replay the SAME plan — recovery reproduces the failed
@@ -2146,9 +2195,11 @@ class ServingEngine:
         # monotonic for the same reason compile counters are: a
         # rolled-back tick's planning really happened.)
         if self._retries == 0 or self._plan is None:
-            self._plan = self.planner.plan(self)
+            with self._phase("plan"):
+                self._plan = self.planner.plan(self)
         self._plan_overrun = False
-        snap = self._checkpoint()
+        with self._phase("checkpoint"):
+            snap = self._checkpoint()
         self._staging = []
         self._span_staging = []
         self._tick_arrivals = []
@@ -2158,24 +2209,27 @@ class ServingEngine:
         t_wall = time.time()
         try:
             self._step_once()
+            self._phase_to(None)
         except Exception as exc:
             if self.perf is not None:
                 self.perf.tick_abort()   # a rolled-back tick measures nothing
             self._rollback(snap)
             self._recover(exc)
             return False
-        self._commit()
+        with self._phase("commit"):
+            self._commit()
         self._retries = 0
         # post-commit on purpose: a rolled-back tick never advances the
         # liveness counter, so `ticks` moves iff the engine makes progress
         self.metrics["ticks"] = self.metrics.get("ticks", 0) + 1
-        self._flight_record(snap["metrics"], snap, t_wall)
-        if self.perf is not None:
-            # the attribution observations land in _flight_record (post-
-            # commit, committed ticks only) — republish so the scrape
-            # view includes THIS tick's buckets, not last tick's
-            self._hists_committed = {k: h.copy()
-                                     for k, h in self.hists.items()}
+        with self._phase("record"):
+            self._flight_record(snap["metrics"], snap, t_wall)
+            if self.perf is not None:
+                # the attribution observations land in _flight_record
+                # (post-commit, committed ticks only) — republish so the
+                # scrape view includes THIS tick's buckets, not last tick's
+                self._hists_committed = {k: h.copy()
+                                         for k, h in self.hists.items()}
         return True
 
     def _recover(self, exc: BaseException):
@@ -2228,6 +2282,7 @@ class ServingEngine:
         except Exception as e:
             return e
         finally:
+            self._phase_to(None)    # a probe's spans show; it counts nothing
             self._masked = set()
             self._rollback(snap)
 
@@ -2886,6 +2941,9 @@ class ServingEngine:
                 self._trace(req, "queue", t0=sub_w, t1=now_w,
                             queue_depth=self.queue_depth,
                             prompt_tokens=n_p, shared_pages=shared)
+            req.queue_wait_s = time.perf_counter() - req.submitted_s
+            self.metrics["queue_wait_s_total"] = round(
+                self.metrics["queue_wait_s_total"] + req.queue_wait_s, 6)
             self.rows[row] = req
             self.row_lens[row] = base
             self.row_budget[row] = req.max_new_tokens
@@ -2943,6 +3001,7 @@ class ServingEngine:
                 mesh=self.mesh,
             )
         self._tick_dispatches += 1
+        self._phase_to("drain")
         self.row_lens[row] = base + n_valid
         self._trace(req, "prefill_chunk", t0=t0_w, t1=time.time(),
                     tokens=n_valid, base=base)
@@ -2964,11 +3023,10 @@ class ServingEngine:
             top_ks=h2d([max(0, int(req.top_k or 0))], jnp.int32),
         )
         self._fault_point("sample", rows=(row,))
-        t0 = time.perf_counter()
-        # jaxlint: disable=JL002 -- designed sync: the first token must reach the host to emit (TTFT); counted via _count_sync
-        first = int(d2h(first_t)[0])
-        first_lp = d2h(first_lp)  # jaxlint: disable=JL002 -- same designed first-token sync; already blocked on first_t above
-        self._count_sync(time.perf_counter() - t0)  # blocking materialization
+        with self._sync_window():   # blocking materialization
+            # jaxlint: disable=JL002 -- designed sync: the first token must reach the host to emit (TTFT); counted via _count_sync
+            first = int(d2h(first_t)[0])
+            first_lp = d2h(first_lp)  # jaxlint: disable=JL002 -- same designed first-token sync; already blocked on first_t above
         self._finish_prompt(row, first, float(first_lp[0]))
 
     def _finish_prompt(self, row: int, first: int, logprob: float):
@@ -2984,6 +3042,10 @@ class ServingEngine:
         if req is None:
             return
         req.first_token_s = time.perf_counter() - req.submitted_s
+        self.metrics["admit_to_first_token_s_total"] = round(
+            self.metrics["admit_to_first_token_s_total"]
+            + req.first_token_s - req.queue_wait_s, 6)
+        self.metrics["first_tokens"] += 1
         self._record_ttft(req.first_token_s)
         self._trace(req, "first_token",
                     ttft_s=round(req.first_token_s, 6))
@@ -3223,10 +3285,10 @@ class ServingEngine:
                 h2d(self.top_ks), k=k, mesh=self.mesh, **extra,
             )
         self._tick_dispatches += 1
-        t0 = time.perf_counter()
-        # jaxlint: disable=JL002 -- designed sync: the verify round's accepted tokens must reach the host to walk acceptance chains; counted via _count_sync
-        t_all, lp_all = d2h(t_all), d2h(lp_all)
-        self._count_sync(time.perf_counter() - t0)
+        self._phase_to("drain")
+        with self._sync_window():
+            # jaxlint: disable=JL002 -- designed sync: the verify round's accepted tokens must reach the host to walk acceptance chains; counted via _count_sync
+            t_all, lp_all = d2h(t_all), d2h(lp_all)
         self.metrics["steps"] += 1
         self.metrics["pages_in_use"] = self.alloc.pages_in_use
         self._dirty = True  # host walks acceptance chains: state diverges
@@ -3312,19 +3374,23 @@ class ServingEngine:
         """Scheduler: three regimes, ONE dispatch per tick.  Admission
         wave (any row prefilling) → ``_mixed_step`` fuses every prefill
         chunk, on-device first-token merge, and the decode step into the
-        single ``_ragged_tick_fn`` program; steady state → the fused
+        single ``_ragged_tick`` program; steady state → the fused
         decode horizon through the SAME entry (bit-identical to the
         historical ``_decode_multi_step``).  ``spec_k`` rides INSIDE that
         one program on the fused engine (on-device draft+verify+accept);
         only the sequential (budget=0) oracle and pp engines keep the
         one-row-one-chunk admission path with the host-walk verify."""
-        self._drain_inbox()
-        self._expire_deadlines()
-        self.metrics["queue_depth"] = self.queue_depth
-        self._admit()
-        for i, req in enumerate(self.rows):  # drop disconnected clients
-            if req is not None and req.cancelled:
-                self._finish(i, "abort")
+        with self._phase("admit"):
+            self._drain_inbox()
+            self._expire_deadlines()
+            self.metrics["queue_depth"] = self.queue_depth
+            self._admit()
+            for i, req in enumerate(self.rows):  # drop disconnected clients
+                if req is not None and req.cancelled:
+                    self._finish(i, "abort")
+        # every step function below builds its inputs (prepare), makes its
+        # one dispatch, and walks what came back (drain)
+        self._phase_to("prepare")
         if self._prefilling and self._mixed_mode:
             self._mixed_step()
             return
@@ -3333,6 +3399,7 @@ class ServingEngine:
         if not active.any():
             if self._prefilling:
                 return  # keep chunking
+            self._phase_to(None)    # the idle wait is no phase's work
             self._wait_for_work()
             return
         if self.ec.spec_k > 0 and not self._fused_spec:
@@ -3355,7 +3422,7 @@ class ServingEngine:
 
     def _mixed_step(self):
         """One admission-wave tick = ONE device program
-        (``_ragged_tick_fn``): ragged prefill chunks for ALL prefilling
+        (``_ragged_tick``): ragged prefill chunks for ALL prefilling
         rows, on-device first-token sampling AND state merge for prompts
         completing this tick, and the decode step for every active row —
         all inside a single jitted entry, so a mixed tick pays one
@@ -3559,12 +3626,14 @@ class ServingEngine:
         perf_pt = self._perf_point(
             1, width=width, with_decode=with_decode, spec=tick_spec,
             pb=p_b, maxp=maxp_b, ew=int(dev["eos"].shape[1]))
+        rows_active = int(active.sum())
         if tick_spec:
-            with self._perf_dispatch("tick.spec", point=perf_pt):
+            with self._perf_dispatch("tick.spec", point=perf_pt,
+                                     rows_active=rows_active):
                 (first_t, first_lp, tok_block, lp_block, n_exec,
                  self.cache, dev["toks"], dev["row_lens"], dev["active"],
                  dev["steps"], dev["remain"], self.key, take_block,
-                 dev["hist"], s_prop, s_acc) = _ragged_tick_fn(
+                 dev["hist"], s_prop, s_acc) = _ragged_tick(
                     self.cfg, self.params, self.cache, dev["toks"],
                     dev["row_lens"], dev["active"], dev["temps"],
                     dev["top_ps"], self.key, dev["seeds"], dev["steps"],
@@ -3576,10 +3645,11 @@ class ServingEngine:
                     collective_qtype=self._collective_qtype)
             self._tick_dispatches += 1
         else:
-            with self._perf_dispatch("tick.admission", point=perf_pt):
+            with self._perf_dispatch("tick.admission", point=perf_pt,
+                                     rows_active=rows_active):
                 (first_t, first_lp, tok_block, lp_block, n_exec,
                  self.cache, dev["toks"], dev["row_lens"], dev["active"],
-                 dev["steps"], dev["remain"], self.key) = _ragged_tick_fn(
+                 dev["steps"], dev["remain"], self.key) = _ragged_tick(
                     self.cfg, self.params, self.cache, dev["toks"],
                     dev["row_lens"], dev["active"], dev["temps"],
                     dev["top_ps"], self.key, dev["seeds"], dev["steps"],
@@ -3589,6 +3659,7 @@ class ServingEngine:
                     tp_manual=self._tp_manual,
                     collective_qtype=self._collective_qtype)
             self._tick_dispatches += 1
+        self._phase_to("drain")
         # advance bookkeeping; completed prompts run the shared
         # completion path (_finish_prompt) once their token arrives
         completing: list[tuple[int, int]] = []   # (slot, row)
@@ -3619,14 +3690,13 @@ class ServingEngine:
             self._dirty = True
             self._fault_point("sample",
                               rows=[row for _, row in completing])
-        t0 = time.perf_counter()
-        if completing:
-            # jaxlint: disable=JL002 -- designed sync: first tokens of prompts completing this tick must reach the host to emit; rides THE one per-tick sync, counted via _count_sync
-            nxt, lp = d2h(first_t), d2h(first_lp)
-        tok_np = d2h(tok_block)  # jaxlint: disable=JL002 -- THE per-tick designed sync: one blocking materialization for the whole fused tick
-        lp_np = d2h(lp_block)  # jaxlint: disable=JL002 -- rides THE per-tick sync above (same dispatched program)
-        executed = int(d2h(n_exec))  # jaxlint: disable=JL002 -- rides THE per-tick sync: 0 only when no row decoded
-        self._count_sync(time.perf_counter() - t0)
+        with self._sync_window():
+            if completing:
+                # jaxlint: disable=JL002 -- designed sync: first tokens of prompts completing this tick must reach the host to emit; rides THE one per-tick sync, counted via _count_sync
+                nxt, lp = d2h(first_t), d2h(first_lp)
+            tok_np = d2h(tok_block)  # jaxlint: disable=JL002 -- THE per-tick designed sync: one blocking materialization for the whole fused tick
+            lp_np = d2h(lp_block)  # jaxlint: disable=JL002 -- rides THE per-tick sync above (same dispatched program)
+            executed = int(d2h(n_exec))  # jaxlint: disable=JL002 -- rides THE per-tick sync: 0 only when no row decoded
         for i, row in completing:
             self._finish_prompt(row, int(nxt[i]), float(lp[i]))
             if not canjoin[i] and self.rows[row] is not None:
@@ -3668,6 +3738,7 @@ class ServingEngine:
         # already-planned horizon (streams stay bit-identical either way
         # — the H8==H1 contract).  pp meshes cannot fuse a horizon
         # (GPipe pipelines T=1 steps only).
+        self._phase_to("prepare")   # again, after a sequential chunk's drain
         plan = self._plan
         H = 1 if self._pp_mode else (plan.horizon if plan is not None
                                      else self.ec.decode_horizon)
@@ -3734,8 +3805,9 @@ class ServingEngine:
         dev = self._sync_device_state()
         perf_pt = self._perf_point(h, width=0, spec=fused_spec,
                                    ew=int(dev["eos"].shape[1]))
+        rows_active = int(active.sum())
         if self._pp_mode:
-            with self._perf_dispatch("tick.pp"):
+            with self._perf_dispatch("tick.pp", rows_active=rows_active):
                 nxt, lp, self.cache, self.key = _pp_decode_sample(
                     self.cfg, self.params, self.cache, dev["toks"],
                     dev["row_lens"], dev["active"], dev["temps"],
@@ -3753,11 +3825,12 @@ class ServingEngine:
             # the spec-enabled form of the SAME single entry: drafting,
             # the [R, k+1] verify, and acceptance all ride inside the
             # horizon loop — still one dispatch (JP106 unchanged)
-            with self._perf_dispatch("tick.spec", point=perf_pt):
+            with self._perf_dispatch("tick.spec", point=perf_pt,
+                                     rows_active=rows_active):
                 (_, _, tok_block, lp_block, n_exec, self.cache,
                  dev["toks"], dev["row_lens"], dev["active"],
                  dev["steps"], dev["remain"], self.key, take_block,
-                 dev["hist"], s_prop, s_acc) = _ragged_tick_fn(
+                 dev["hist"], s_prop, s_acc) = _ragged_tick(
                     self.cfg, self.params, self.cache, dev["toks"],
                     dev["row_lens"], dev["active"], dev["temps"],
                     dev["top_ps"], self.key, dev["seeds"], dev["steps"],
@@ -3775,10 +3848,11 @@ class ServingEngine:
             # to exactly 1 (the decode stage traces _decode_horizon_loop,
             # so output is bit-identical to the historical
             # _decode_multi_step program)
-            with self._perf_dispatch("tick.steady", point=perf_pt):
+            with self._perf_dispatch("tick.steady", point=perf_pt,
+                                     rows_active=rows_active):
                 (_, _, tok_block, lp_block, n_exec, self.cache,
                  dev["toks"], dev["row_lens"], dev["active"],
-                 dev["steps"], dev["remain"], self.key) = _ragged_tick_fn(
+                 dev["steps"], dev["remain"], self.key) = _ragged_tick(
                     self.cfg, self.params, self.cache, dev["toks"],
                     dev["row_lens"], dev["active"], dev["temps"],
                     dev["top_ps"], self.key, dev["seeds"], dev["steps"],
@@ -3788,13 +3862,13 @@ class ServingEngine:
                     collective_qtype=self._collective_qtype)
             self._tick_dispatches += 1
             # the returned cache owns the (donated) tables buffer now
-        t0 = time.perf_counter()
-        tok_block = d2h(tok_block)   # jaxlint: disable=JL002 -- THE per-horizon designed sync: h tokens per host round trip, counted via _count_sync
-        lp_block = d2h(lp_block)  # jaxlint: disable=JL002 -- rides THE per-horizon sync above (same dispatched program)
-        if not self._pp_mode:
-            # jaxlint: disable=JL002 -- rides THE per-horizon sync: < h only if every row died early
-            executed = int(d2h(n_exec))
-        self._count_sync(time.perf_counter() - t0)
+        self._phase_to("drain")
+        with self._sync_window():
+            tok_block = d2h(tok_block)   # jaxlint: disable=JL002 -- THE per-horizon designed sync: h tokens per host round trip, counted via _count_sync
+            lp_block = d2h(lp_block)  # jaxlint: disable=JL002 -- rides THE per-horizon sync above (same dispatched program)
+            if not self._pp_mode:
+                # jaxlint: disable=JL002 -- rides THE per-horizon sync: < h only if every row died early
+                executed = int(d2h(n_exec))
         if self.perf is not None:
             # the MFU join's loop multiplier: XLA's cost analysis counts
             # the horizon body once, the tick executed it `executed` times

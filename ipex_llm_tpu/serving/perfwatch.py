@@ -22,7 +22,7 @@ new syncs — JP106's one-dispatch tick is untouched):
   Buckets accumulate into rollback-covered :class:`observe.Histogram`
   objects keyed ``perf_<family>_<bucket>_s`` per program family
   (``tick.steady`` / ``tick.admission`` / ``tick.spec`` for the
-  ``_ragged_tick_fn`` forms, plus ``swap_in`` and ``handoff`` epoch
+  ``_ragged_tick`` forms, plus ``swap_in`` and ``handoff`` epoch
   windows and the sequential/pp oracles), ride the engine's committed
   /metrics exposition (the router fleet-sums them), and stamp per-tick
   fields into the flight-recorder record.
@@ -61,6 +61,20 @@ new syncs — JP106's one-dispatch tick is untouched):
   peak comes from the ``device_kind``-keyed ``DEVICE_PEAKS`` table (an
   unknown TPU raises; off the TPU platform MFU is not measured).
 
+- **Host phase spans**: ``phase(name, **attrs)`` brackets one phase of
+  the engine thread's tick (``PHASES``).  Each phase is a
+  ``jax.profiler.TraceAnnotation`` named ``engine/<phase>`` — so inside a
+  profiler capture (the benchmark's, or an operator's ``/debug/profile``)
+  it lies on the host plane of the same trace as the device events, on
+  one clock — and a monotonic counter ``perf_phase_<phase>_s`` of its
+  ``perf_counter`` SELF time (nested phases subtracted, so the counters
+  of the phases inside ``tick`` partition ``perf_phase_tick_s``, the
+  tick's whole wall clock).  Counters fold in at ``tick_finish`` under
+  the attribution rule above: committed working ticks only.  What closes
+  after ``tick_finish`` has closed the books (the rest of
+  ``engine/record`` and of ``engine/tick``) is counted into the NEXT
+  committed tick.
+
 Engines whose grid point the manifest does not cover (bigger row counts,
 wider buckets than the audit sampled) still get full attribution and
 sentinel compile counting — only the MFU join reports None, and
@@ -75,16 +89,20 @@ import threading
 import time
 from contextlib import contextmanager
 
+from jax.profiler import TraceAnnotation
+
 from ipex_llm_tpu.serving.observe import FAST_LATENCY_BUCKETS_S, Histogram
 
 __all__ = [
     "PerfWatch",
     "BUCKETS",
+    "PHASES",
     "PLAN_ERROR_BUCKETS",
     "model_flops_per_token",
     "parse_point_key",
     "locked_points",
     "point_in_grid",
+    "outside_axes",
     "resolve_peaks",
     "DEVICE_PEAKS",
 ]
@@ -92,6 +110,18 @@ __all__ = [
 log = logging.getLogger("ipex_llm_tpu.perfwatch")
 
 BUCKETS = ("dispatch", "device", "sync", "bookkeep")
+
+# the engine thread's tick, as host phase spans (``engine/<phase>`` on the
+# profiler's timeline) and ``perf_phase_<phase>_s`` counters: ``tick`` is
+# the parent, the rest partition it in the order a tick visits them
+PHASES = ("tick", "plan", "checkpoint", "admit", "prepare", "dispatch",
+          "sync", "drain", "commit", "record")
+
+# the ``variant`` attribute of an ``engine/dispatch`` span, by what the
+# dispatched tick program holds: a decode stage alone, prefill chunks with
+# a decode stage, prefill chunks alone, or the speculative loop
+_VARIANT = {"tick.steady": "steady", "tick.admission": "mixed",
+            "tick.spec": "spec"}
 
 # planner prediction error, |actual - predicted| / predicted: RATIO
 # buckets, not seconds — a 10ms tick mispredicted by 5ms and a 1s tick
@@ -265,6 +295,25 @@ def point_in_grid(point: dict, locked: list[dict] | None) -> bool:
     return True
 
 
+def outside_axes(point: dict, locked: list[dict] | None) -> str:
+    """Which axis puts ``point`` outside the locked grid, for the
+    sentinel's warning: the magnitude axes (and ``spec``) that pass the
+    largest value the structurally matching entries were audited at or
+    are no power of two, else the structural form itself."""
+    if not locked or not any(_structure(p) == _structure(point)
+                             for p in locked):
+        return "its structural form (" + ",".join(
+            f"{k}={v}" for k, v in _structure(point)[0]) + ")"
+    group = [p for p in locked if _mag_group(p) == _mag_group(point)]
+    out = []
+    for ax in (*_MAG_AXES, "spec"):
+        v = int(point.get(ax, 0) or 0)
+        top = max(int(p.get(ax, 0) or 0) for p in group)
+        if v > top or (ax != "spec" and v and not _pow2(v)):
+            out.append(f"{ax}={v} (locked up to {top})")
+    return ", ".join(out) or "no single axis"
+
+
 # ---------------------------------------------------------------------------
 # the jax.monitoring listener (module-global, installed once)
 
@@ -375,6 +424,16 @@ class PerfWatch:
         self.dispatch_mismatches = 0
         self._tick = None               # open tick scratch
         self._windows: list[dict] = []  # open window stack (tick + epoch)
+        # host phase spans: committed counters, the open tick's phases,
+        # what closed after the last commit (folded into the next one),
+        # where a closing phase adds (None: nowhere), the nested-time
+        # accumulators of the open phases, the open phase_to() phase
+        self.phase_s = dict.fromkeys(PHASES, 0.0)
+        self._phase_open: dict = {}
+        self._phase_tail: dict = {}
+        self._phase_sink: dict | None = None
+        self._phase_stack: list[list] = []
+        self._phase_linear = None
         _install_listener()
 
     # -- window / tick lifecycle (engine thread) ----------------------------
@@ -391,52 +450,109 @@ class PerfWatch:
         """Discard the rolled-back tick's scratch: nothing it measured
         was committed, so nothing it measured is kept (sentinel compile
         counters already landed — compiles are real either way)."""
+        self.phase_to(None)
         self._tick = None
         self._windows = []
+        self._phase_sink = None     # its phases, and the probes', count nowhere
+
+    @contextmanager
+    def phase(self, name: str, **attrs):
+        """One host phase of the tick (``PHASES``): a profiler span
+        ``engine/<name>`` carrying ``attrs`` (outside a capture a
+        TraceAnnotation is a flag test) and its ``perf_counter`` self
+        time — nested phases subtracted; ``tick``, the parent, whole —
+        towards ``perf_phase_<name>_s``.  The time counts when
+        ``tick_finish`` commits the tick; a phase that closes after it
+        (``record``, ``tick``) counts into the next committed tick."""
+        if name == "tick":
+            self._phase_open = self._phase_sink = {}
+        with TraceAnnotation("engine/" + name, **attrs):
+            entry = [name, time.perf_counter(), 0.0]
+            self._phase_stack.append(entry)
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - entry[1]
+                self._phase_stack.pop()
+                if self._phase_stack:
+                    self._phase_stack[-1][2] += dt
+                self._phase_book(name, dt, entry[2])
+                if name == "tick":
+                    self._phase_sink = None   # between ticks counts nowhere
+
+    def _phase_book(self, name: str, seconds: float, inside: float):
+        """``seconds`` of phase ``name``, ``inside`` of them in the phases
+        nested in it, to where a closing phase counts now."""
+        sink = self._phase_sink
+        if sink is not None:
+            sink[name] = sink.get(name, 0.0) + (
+                seconds if name == "tick" else seconds - inside)
+
+    def phase_to(self, name: str | None):
+        """``phase`` for straight-line code too long to indent: close the
+        phase the last ``phase_to`` opened and open ``name`` at the same
+        point of the code (None: only close; the open one again: stay)."""
+        if self._phase_linear is not None:
+            if self._phase_linear[0] == name:
+                return
+            self._phase_linear[1].__exit__(None, None, None)
+            self._phase_linear = None
+        if name is not None:
+            cm = self.phase(name)
+            cm.__enter__()
+            self._phase_linear = (name, cm)
 
     @contextmanager
     def dispatch(self, family: str, point: dict | None = None,
-                 tick: bool = True):
+                 tick: bool = True, **attrs):
         """Timing window around ONE jitted call.  Runs the sentinel on
         any compile events that fire inside (they fire synchronously on
         this thread), stamps the family/point on the open tick scratch,
-        and counts toward the tick-dispatch cross-check when ``tick``."""
-        prev = getattr(_tls, "watch", None)
-        _tls.watch = self
-        n0 = self.compiles["compiles_total"]
-        s0 = self.compiles["compile_s_total"]
-        self._window_point = point
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter()
-            _tls.watch = prev
-            self._window_point = None
-            for w in self._windows:
-                w["dispatch"].append((t0, t1))
-                w["families"].append(family)
-                if point is not None:
-                    w["points"].append(point)
-                if tick and w is self._tick:
-                    w["tick_dispatches"] += 1
-                w["compiles"] += self.compiles["compiles_total"] - n0
-                w["compile_s"] += self.compiles["compile_s_total"] - s0
-            with self._lock:
-                fc = self._per_family_compiles.setdefault(
-                    family, {"compiles": 0, "compile_s": 0.0,
-                             "dispatches": 0})
-                fc["dispatches"] += 1
-                dn = self.compiles["compiles_total"] - n0
-                if dn:
-                    fc["compiles"] += dn
-                    fc["compile_s"] += round(
-                        self.compiles["compile_s_total"] - s0, 6)
+        and counts toward the tick-dispatch cross-check when ``tick``.
+        The window is the ``dispatch`` phase: its span says which tick
+        variant was dispatched, at which grid point (plus ``attrs``)."""
+        pt = point or {}
+        variant = ("chunk" if pt.get("wd") is False
+                   else _VARIANT.get(family, family))
+        with self.phase("dispatch", variant=variant, family=family,
+                        p_b=pt.get("pb", 0), width=pt.get("width", 0),
+                        maxp=pt.get("maxp", 0), **attrs):
+            prev = getattr(_tls, "watch", None)
+            _tls.watch = self
+            n0 = self.compiles["compiles_total"]
+            s0 = self.compiles["compile_s_total"]
+            self._window_point = point
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                t1 = time.perf_counter()
+                _tls.watch = prev
+                self._window_point = None
+                for w in self._windows:
+                    w["dispatch"].append((t0, t1))
+                    w["families"].append(family)
+                    if point is not None:
+                        w["points"].append(point)
+                    if tick and w is self._tick:
+                        w["tick_dispatches"] += 1
+                    w["compiles"] += self.compiles["compiles_total"] - n0
+                    w["compile_s"] += self.compiles["compile_s_total"] - s0
+                with self._lock:
+                    fc = self._per_family_compiles.setdefault(
+                        family, {"compiles": 0, "compile_s": 0.0,
+                                 "dispatches": 0})
+                    fc["dispatches"] += 1
+                    dn = self.compiles["compiles_total"] - n0
+                    if dn:
+                        fc["compiles"] += dn
+                        fc["compile_s"] += round(
+                            self.compiles["compile_s_total"] - s0, 6)
 
     def note_sync(self, seconds: float):
         """One blocking device->host materialization ending NOW (the
-        caller just measured it) — recorded as a [start, end] window on
-        every open scratch."""
+        caller just measured it, inside its ``sync`` phase) — recorded as
+        a [start, end] window on every open scratch."""
         t1 = time.perf_counter()
         for w in self._windows:
             w["sync"].append((t1 - seconds, t1))
@@ -516,13 +632,21 @@ class PerfWatch:
                 if key not in self.out_of_grid_points:
                     self.out_of_grid_points.append(key)
                     del self.out_of_grid_points[:-16]
-                log.warning(
-                    "compile for grid point %s OUTSIDE the manifest-"
-                    "locked recompile surface (analysis/programs.lock."
-                    "json): the JP104 static audit never priced this "
-                    "program — extend the registry grid and rerun "
-                    "`scripts/jaxprcheck --update`, or this engine pays "
-                    "unbudgeted compiles", key)
+                if self.compiles["compiles_out_of_grid"] == 1:
+                    log.warning(
+                        "compile for grid point %s OUTSIDE the manifest-"
+                        "locked recompile surface (analysis/programs.lock."
+                        "json) on %s: the JP104 static audit never priced "
+                        "this program — extend the registry grid and rerun "
+                        "`scripts/jaxprcheck --update`, or this engine "
+                        "pays unbudgeted compiles (further out-of-grid "
+                        "compiles of this engine log at debug level; "
+                        "/health perf.sentinel counts them)",
+                        key, outside_axes(point, self.grid))
+                else:
+                    log.debug("compile for grid point %s outside the "
+                              "locked grid on %s", key,
+                              outside_axes(point, self.grid))
 
     # -- bucket math ---------------------------------------------------------
 
@@ -675,8 +799,25 @@ class PerfWatch:
         scratch, self._tick = self._tick, None
         self._windows = []
         if scratch is None or not working:
+            self._phase_sink = None     # an idle tick's phases count nowhere
             return {}
         t1 = time.perf_counter()
+        # book the phases still open (``record``, ``tick``) up to HERE,
+        # innermost first, and restart their clocks: what is booked for
+        # this tick partitions its wall clock up to now.  This tick's
+        # phases, plus what closed after the last committed tick's books
+        # did, are what it commits; from here on a closing phase adds to
+        # the tail the next committed tick folds in
+        inside = 0.0
+        for entry in reversed(self._phase_stack):
+            dt = t1 - entry[1]
+            self._phase_book(entry[0], dt, entry[2] + inside)
+            entry[1], entry[2], inside = t1, 0.0, dt
+        phases, tail = self._phase_open, self._phase_tail
+        self._phase_open = {}
+        self._phase_sink = self._phase_tail = {}
+        for name, v in tail.items():
+            phases[name] = phases.get(name, 0.0) + v
         buckets, wall = self._classify(scratch, t1)
         fams = scratch["families"]
         if "tick.spec" in fams:
@@ -720,6 +861,8 @@ class PerfWatch:
             p = scratch["points"][-1]
             out["grid_point"] = ",".join(
                 f"{k}={p[k]}" for k in sorted(p))
+        if phases:
+            out["phases"] = {k: round(v, 6) for k, v in phases.items()}
         if mismatch:
             self.dispatch_mismatches += 1
             out["dispatch_mismatch"] = {"observed": observed,
@@ -740,6 +883,8 @@ class PerfWatch:
             self._fam_update(e_fam, e_buckets, e_wall, device_s=e_dev)
         with self._lock:
             self.ticks_attributed += 1
+            for name, v in phases.items():
+                self.phase_s[name] = self.phase_s.get(name, 0.0) + v
         # the debug ASSERT lives in the engine, AFTER the flight ring has
         # recorded this dict — the mismatch evidence must survive the
         # raise (and survive `-O` builds, where only the field remains)
@@ -809,6 +954,8 @@ class PerfWatch:
             for name, f in self._fam.items():
                 out[f"{_fam_key(name)}_ticks"] = f["ticks"]
                 out[f"{_fam_key(name)}_device_s"] = round(f["device_s"], 6)
+            for name, v in self.phase_s.items():
+                out[f"phase_{name}_s"] = round(v, 6)
         m = self.mfu()
         if m is not None:
             out["mfu"] = m

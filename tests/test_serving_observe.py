@@ -667,6 +667,48 @@ def test_replica_http_surface_trace_flight_metrics(cfg_params):
         eng.stop()
 
 
+def test_phase_and_wait_counters_on_metrics_json_advance_monotonically(
+        cfg_params):
+    """The ten ``perf_phase_*_s`` counters and the three wait counters
+    reach ``/metrics?format=json`` (and the text exposition); scraped
+    between requests none of them ever goes back, and every one of them
+    has advanced once two requests were served."""
+    pytest.importorskip("aiohttp")
+    from ipex_llm_tpu.serving.api_server import OpenAIServer
+    from ipex_llm_tpu.serving.perfwatch import PHASES
+
+    cfg, params = cfg_params
+    eng = ServingEngine(cfg, params, EngineConfig(**EC)).start()
+    port, _ = _serve(OpenAIServer(eng, _Tok(), "tiny"))
+    keys = [f"perf_phase_{p}_s" for p in PHASES] + [
+        "queue_wait_s_total", "admit_to_first_token_s_total",
+        "first_tokens"]
+    try:
+        scrapes = []
+        for i in range(3):
+            m = json.loads(_get(port, "/metrics?format=json"))["metrics"]
+            scrapes.append({k: m[k] for k in keys})   # all there, from 0
+            if i == 2:
+                break
+            body = json.dumps({"prompt": " ".join(str(7 * i + j)
+                                                  for j in range(1, 20)),
+                               "max_tokens": 5,
+                               "temperature": 0.0}).encode()
+            urllib.request.urlopen(urllib.request.Request(
+                f"http://127.0.0.1:{port}/v1/completions", data=body,
+                headers={"Content-Type": "application/json"}), timeout=60)
+        assert all(v == 0 for v in scrapes[0].values())
+        for a, b in zip(scrapes, scrapes[1:]):
+            assert all(b[k] >= a[k] for k in keys), (a, b)
+        assert all(scrapes[2][k] > scrapes[0][k] for k in keys), scrapes[2]
+        assert scrapes[2]["first_tokens"] == 2
+        text = _get(port, "/metrics")
+        assert "ipex_llm_tpu_perf_phase_dispatch_s" in text
+        assert "ipex_llm_tpu_queue_wait_s_total" in text
+    finally:
+        eng.stop()
+
+
 def test_router_assembles_disagg_failover_trace_e2e(cfg_params):
     """THE acceptance gate: one request through the router with a
     disaggregated handoff and one injected failover yields ONE

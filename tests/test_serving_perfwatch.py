@@ -20,6 +20,11 @@ The contracts under test:
 - **JP106 runtime cross-check**: a dispatch the hand-maintained counter
   sees but perfwatch does not (or vice versa) records a
   ``dispatch_mismatch`` flight field and raises the debug assert;
+- **host phase spans**: the ``perf_phase_*_s`` counters partition the
+  tick's wall clock and agree with the four buckets; a rolled-back tick,
+  a bisection probe and an idle tick add nothing to them or to the wait
+  counters; a profiler capture holds ``engine/tick`` spans with the
+  phases nested inside; the lowered tick names every device scope;
 - **surfaces**: /health carries the ``perf`` block and the
   ``dispatch`` ladder-provenance block (recorded-at bench-round
   stamps), /metrics carries the ``perf_*`` counters and per-family
@@ -38,10 +43,12 @@ import pytest
 
 from ipex_llm_tpu.serving.engine import (EngineConfig, Request,
                                          ServingEngine, stream_tokens)
-from ipex_llm_tpu.serving.faults import FaultInjector, TransientFault
-from ipex_llm_tpu.serving.perfwatch import (PerfWatch, locked_points,
+from ipex_llm_tpu.serving.faults import (DeterministicFault, FaultInjector,
+                                         TransientFault)
+from ipex_llm_tpu.serving.perfwatch import (PHASES, PerfWatch, locked_points,
                                             model_flops_per_token,
-                                            parse_point_key, point_in_grid)
+                                            outside_axes, parse_point_key,
+                                            point_in_grid)
 from tests.test_decoder import rand_params, tiny_cfg
 
 RNG = np.random.default_rng(29)
@@ -645,3 +652,295 @@ def test_router_fleet_sums_perf_counters(cfg_params):
     finally:
         for eng in engines:
             eng.stop()
+
+
+# -- host phase spans, wait counters, device scopes (PR 25) ------------------
+
+WAIT_COUNTERS = ("queue_wait_s_total", "admit_to_first_token_s_total",
+                 "first_tokens")
+
+
+def _phase_counters(eng):
+    nm = eng.perf_numeric()
+    return {p: nm[f"perf_phase_{p}_s"] for p in PHASES}
+
+
+def _bucket_sum(eng, bucket):
+    return sum(h.sum for k, h in eng.hists.items()
+               if k.startswith("perf_tick_") and k.endswith(f"_{bucket}_s"))
+
+
+def test_phase_unit_self_times_and_carry(monkeypatch):
+    """Nested phases book self times, ``tick`` its whole span; what is
+    open when ``tick_finish`` closes the books is booked up to there and
+    the rest counts into the NEXT committed tick; an aborted or idle
+    tick books nothing and keeps the committed tail.  On a clock the
+    test moves by hand (ms), so every number is exact."""
+    import types
+
+    from ipex_llm_tpu.serving import perfwatch
+
+    now = [0.0]
+    monkeypatch.setattr(perfwatch, "time", types.SimpleNamespace(
+        perf_counter=lambda: now[0] * 1e-3))
+
+    def spend(ms):
+        now[0] += ms
+
+    w = PerfWatch()
+
+    def tick(finish):
+        with w.phase("tick", tick=1):
+            spend(1)                        # in no phase
+            w.tick_begin()
+            with w.phase("admit"):
+                spend(2)
+                with w.dispatch("tick.steady"):
+                    spend(3)
+                spend(4)
+            w.phase_to("drain")
+            w.phase_to("drain")             # the open one again: stays
+            spend(5)
+            with w.phase("sync"):
+                spend(6)
+            w.phase_to(None)
+            with w.phase("record"):
+                spend(7)
+                finish()
+                spend(8)                    # after the books closed
+            spend(9)
+
+    def ms(d):
+        return {k: round(v * 1e3, 6) for k, v in d.items() if v}
+
+    tick(lambda: w.tick_finish(1, working=True))
+    first = {"tick": 28, "admit": 6, "dispatch": 3, "drain": 5, "sync": 6,
+             "record": 7}
+    assert ms(w.phase_s) == first           # 28 = 1+2+3+4+5+6+7
+    tick(w.tick_abort)                      # rolled back: nothing
+    tick(lambda: w.tick_finish(0, working=False))   # idle: nothing
+    assert ms(w.phase_s) == first
+    tick(lambda: w.tick_finish(1, working=True))
+    # the first tick's tail (8 of record; 8 + 9 of tick) arrived with the
+    # next COMMITTED tick, through the aborted and the idle one
+    assert ms(w.phase_s) == {"tick": 28 + 28 + 17, "admit": 12,
+                             "dispatch": 6, "drain": 10, "sync": 12,
+                             "record": 7 + 7 + 8}
+    assert w.metrics_numeric()["phase_record_s"] == 0.022
+    assert w.metrics_numeric()["phase_plan_s"] == 0.0
+
+
+def test_phase_counters_partition_the_tick_and_agree_with_buckets(cfg_params):
+    """Over committed ticks the nine phases partition ``engine/tick``;
+    ``dispatch`` and ``sync`` are the buckets of those names; and
+    ``bookkeep`` (+ ``device``: host work that overlaps the device) is
+    admit + prepare + drain + commit + the part of record before the
+    books close."""
+    cfg, params = cfg_params
+    eng = ServingEngine(cfg, params, EngineConfig(
+        max_rows=4, max_seq_len=256, page_size=16, prefill_bucket=16,
+        decode_horizon=4))
+    outs = _drive(eng, [Request(prompt_ids=p, max_new_tokens=10)
+                        for p in _prompts(3, 24)])
+    assert all(len(o) == 10 for o in outs)
+    ph = _phase_counters(eng)
+    ring = eng.flight.view()["ring"]
+    n = len(ring)
+    assert all(v > 0 for v in ph.values()), ph
+    kids = sum(v for k, v in ph.items() if k != "tick")
+    assert kids <= ph["tick"] + 1e-9
+    # what is in no phase is a few statements between two of them; the
+    # bound is wide because a loaded test machine can preempt the thread
+    # right there (the unit test above has the exact partition)
+    assert ph["tick"] - kids <= 0.1 * ph["tick"] + 1e-3 * n
+    # a phase opens just before its bucket's window and closes just after:
+    # never shorter, and longer by the span's own cost only (well under a
+    # ms a tick even on a loaded test machine)
+    for name in ("dispatch", "sync"):
+        extra = ph[name] - _bucket_sum(eng, name)
+        assert -1e-6 * n <= extra < 1e-3 * n, (name, extra)
+    book = _bucket_sum(eng, "bookkeep") + _bucket_sum(eng, "device")
+    inner = ph["admit"] + ph["prepare"] + ph["drain"] + ph["commit"]
+    assert inner - 1e-3 * n <= book <= inner + ph["record"] + 1e-3 * n
+    # every committed tick's record carries its own split
+    for rec in ring:
+        assert set(rec["phases"]) <= set(PHASES)
+        extra = rec["phases"]["dispatch"] - rec["attrib"]["dispatch"]
+        assert -2e-6 <= extra < 1e-3, rec
+    # the wait counters: three requests, each waited and was prefilled
+    m = eng.metrics
+    assert m["first_tokens"] == 3
+    assert m["queue_wait_s_total"] > 0
+    assert m["admit_to_first_token_s_total"] > 0
+
+
+@pytest.mark.parametrize("kind", ["rollback", "probe", "idle"])
+def test_phase_and_wait_counters_skip_uncommitted_ticks(cfg_params, kind):
+    """A rolled-back tick, a tick whose recovery runs bisection probes,
+    and an idle tick add nothing to any ``perf_phase_*`` counter nor to
+    the wait counters (the retried, committed tick counts once)."""
+    cfg, params = cfg_params
+    inj = FaultInjector()
+    if kind == "rollback":
+        inj.inject("sample", TransientFault)
+    elif kind == "probe":
+        inj.inject("decode-dispatch", DeterministicFault, nth=3, times=None,
+                   request_id="poison")
+    eng = ServingEngine(cfg, params, EngineConfig(
+        max_rows=4, max_seq_len=256, page_size=32, prefill_bucket=8,
+        decode_horizon=2, retry_backoff_s=0.001, max_step_retries=1),
+        fault_injector=inj)
+    reqs = [Request(prompt_ids=p, max_new_tokens=6, request_id=rid)
+            for p, rid in zip(_prompts(2, 12), ("poison", "ok"))]
+    for r in reqs:
+        eng.submit(r)
+    uncommitted = 0
+    for _ in range(400):
+        before = (_phase_counters(eng),
+                  {k: eng.metrics[k] for k in WAIT_COUNTERS})
+        idle = all(r.finish_reason is not None for r in reqs)
+        committed = eng._tick()
+        if not committed or idle:
+            uncommitted += 1
+            after = (_phase_counters(eng),
+                     {k: eng.metrics[k] for k in WAIT_COUNTERS})
+            assert after == before, (kind, committed, idle)
+        if idle and uncommitted >= 3:
+            break
+    assert uncommitted >= 3
+    if kind == "rollback":
+        assert eng.metrics["retries"] >= 1
+    if kind == "probe":
+        assert eng.metrics["errors_isolated"] == 1
+        assert reqs[0].finish_reason == "error"
+    # each request that reached its first token counted exactly once
+    done = sum(1 for r in reqs if r.first_token_s > 0)
+    assert eng.metrics["first_tokens"] == done
+    assert eng.metrics["queue_wait_s_total"] == pytest.approx(
+        sum(r.queue_wait_s for r in reqs), abs=1e-5)
+
+
+def test_profiler_capture_holds_the_tick_spans(cfg_params, tmp_path):
+    """A jax.profiler capture with the benchmark's options (host tracer
+    level 1, no python tracer) holds ``engine/tick`` events on the host
+    plane, numbered, and nested inside them the phases, with
+    ``engine/dispatch`` carrying the variant and the grid point."""
+    import glob
+
+    import jax
+
+    cfg, params = cfg_params
+    eng = ServingEngine(cfg, params, EngineConfig(
+        max_rows=4, max_seq_len=256, page_size=16, prefill_bucket=16))
+    _drive(eng, Request(prompt_ids=_prompts(1, 20)[0], max_new_tokens=4))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _drive(eng, [Request(prompt_ids=p, max_new_tokens=6)
+                     for p in _prompts(2, 20)])
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    spans = []
+    for plane in data.planes:
+        for line in plane.lines:
+            mine = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                     dict(e.stats)) for e in line.events
+                    if e.name.startswith("engine/")]
+            if mine:
+                assert plane.name == "/host:CPU"
+                spans.append(mine)
+    assert len(spans) == 1, "one thread ticks the engine"
+    spans = spans[0]
+    ticks = [s for s in spans if s[0] == "engine/tick"]
+    assert len(ticks) >= 4
+    numbers = [t[3]["tick"] for t in ticks]
+    assert numbers == sorted(numbers) and numbers[0] >= 1
+    seen, variants = set(), set()
+    for name, a, b, stats in spans:
+        if name == "engine/tick":
+            continue
+        assert any(t[1] <= a and b <= t[2] for t in ticks), name
+        seen.add(name.split("/", 1)[1])
+        if name == "engine/dispatch":
+            variants.add(stats["variant"])
+            assert stats["family"].startswith("tick.")
+            assert {"p_b", "width", "maxp", "rows_active"} <= set(stats)
+    assert seen == set(PHASES) - {"tick"}
+    assert "steady" in variants and variants & {"mixed", "chunk"}
+    assert variants <= {"steady", "mixed", "chunk", "spec"}
+
+
+def test_lowered_tick_names_every_device_scope():
+    """The mixed tick's lowered text (debug info, no execution) holds
+    every named scope of the program: the tick's stages, the layer's
+    stages, the head and the sampler."""
+    from ipex_llm_tpu.analysis.trace.registry import _build_ragged_tick
+    from ipex_llm_tpu.serving.engine import _ragged_tick_fn
+
+    args, kw = _build_ragged_tick({"rows": 4, "width": 8, "horizon": 1,
+                                   "kv": "bf16"})
+    text = _ragged_tick_fn.lower(*args, **kw).as_text(debug_info=True)
+    for scope in ("tick/prefill", "tick/merge", "tick/decode", "layer/qkv",
+                  "layer/kv_write", "layer/kv_read", "layer/attn",
+                  "layer/o_proj", "layer/mlp", "head", "sample"):
+        # a scope opens a name: at the start of one (a scan body's names
+        # start anew) or after the scopes around it
+        assert f'"{scope}/' in text or f"/{scope}/" in text, scope
+
+
+def test_perfwatch_disabled_records_no_phase(cfg_params, tmp_path):
+    """``perfwatch=False`` turns all of it off: no ``perf_phase_*``
+    counter, no ``engine/*`` span in a capture, no ``phases`` in the
+    flight ring.  (The wait counters are engine metrics and stay.)"""
+    import glob
+
+    import jax
+
+    cfg, params = cfg_params
+    eng = ServingEngine(cfg, params, EngineConfig(
+        max_rows=4, max_seq_len=256, page_size=32, prefill_bucket=32,
+        perfwatch=False))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _drive(eng, Request(prompt_ids=_prompts(1, 8)[0], max_new_tokens=4))
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    assert not [e.name for plane in data.planes for line in plane.lines
+                for e in line.events if e.name.startswith("engine/")]
+    assert not any(k.startswith("perf_") for k in eng.perf_numeric())
+    assert all("phases" not in r for r in eng.flight.view()["ring"])
+    assert eng.metrics["first_tokens"] == 1
+
+
+def test_sentinel_warns_once_in_full_and_names_the_axis(cfg_params, caplog):
+    """An engine's first out-of-grid compile warns in full and names the
+    axis that is outside the locked grid; later ones log at debug level.
+    The counters count every one of them as before."""
+    import logging
+
+    cfg, params = cfg_params
+    eng = ServingEngine(cfg, params, EngineConfig(
+        max_rows=6, max_seq_len=256, page_size=16, prefill_bucket=16))
+    with caplog.at_level(logging.DEBUG, logger="ipex_llm_tpu.perfwatch"):
+        _drive(eng, [Request(prompt_ids=p, max_new_tokens=6)
+                     for p in _prompts(2, 20)])
+    s = eng.perf.sentinel_view()
+    assert s["compiles_out_of_grid"] >= 2
+    full = [r for r in caplog.records if r.levelno == logging.WARNING
+            and "OUTSIDE the manifest-locked" in r.getMessage()]
+    assert len(full) == 1
+    assert "rows=6 (locked up to 8)" in full[0].getMessage()
+    later = [r for r in caplog.records if r.levelno == logging.DEBUG
+             and "outside the locked grid" in r.getMessage()]
+    assert len(later) == s["compiles_out_of_grid"] - 1
+    grid = eng.perf.grid
+    assert "structural form" in outside_axes(
+        {"rows": 4, "width": 0, "horizon": 1, "kv": "int3"}, grid)
+    assert outside_axes({"rows": 4, "width": 0, "horizon": 32,
+                         "kv": "bf16"}, grid).startswith("horizon=32")
