@@ -254,6 +254,32 @@ def kernel_cases(cfg, params, engine: dict, chunk: int = 128,
                                 np.where(valid, want, 0))
             yield f"ragged_paged_sdpa.{storage}.c{c}", ATTN_TOL, ragged
 
+    # the in-place write: the same rows into a two-layer stack by the kernel
+    # and by the XLA scatter, every byte of the live pages alike (row 0's
+    # chunk crosses a page boundary, the last row is idle at the sentinel
+    # slot, whose rows go to scratch page 0 in both)
+    for c in (1, chunk):
+        for storage, dtype in KV_STORAGE_DTYPES.items():
+            def write(c=c, storage=storage, dtype=dtype):
+                from ipex_llm_tpu.ops.pallas.paged_kv_write import (
+                    paged_kv_write)
+
+                pos = rng.integers(0, (maxp - 1) * ps - c, r).astype(np.int32)
+                pos[0] = ps - 1
+                pos[-1] = maxp * ps
+                stack = (2, r * maxp + 1, hkv, ps, d)
+                cache = PagedKVCache(
+                    bf16(*stack).astype(dtype), bf16(*stack).astype(dtype),
+                    jnp.asarray(tables), jnp.zeros((), jnp.int32),
+                    storage=storage)
+                args = (cache.k, cache.v, jnp.asarray(1), bf16(r, c, hkv, d),
+                        bf16(r, c, hkv, d), jnp.asarray(pos))
+                got = paged_kv_write(cache, *args)
+                want = cache.write_layer(*args)
+                return max(_rel_err(g[:, 1:], w[:, 1:])
+                           for g, w in zip(got, want))
+            yield f"paged_kv_write.{storage}.c{c}", 0.0, write
+
     # generate() path: flash prefill over a dense cache, then the T=1 decode
     # kernel over the head-major cache in both storage widths
     def flash():

@@ -13,7 +13,7 @@ Rule catalog (docs/quickstart/static_analysis.md has the long form):
 - JL007 missing-donation  hot-path jit wrapper with cache/pool args and no
                           donate_argnums (the AST companion to JP101)
 
-The trace tier's rules (JP100-JP106, ``analysis/trace/``) are registered
+The trace tier's rules (JP100-JP108, ``analysis/trace/``) are registered
 here as catalog stubs so ``--list-rules`` shows the full inventory and
 suppression comments naming JP codes validate; their checks run in the
 jaxprcheck runner, not per source file.

@@ -45,6 +45,12 @@ TRACE_RULES: dict[str, tuple[str, str, str]] = {
         "a stacked packed-weight plane (the 4/5/8-bit block serving "
         "formats) is dequantized wholesale inside the lowered program "
         "instead of per-layer next to its matmul (a 4x HBM regression)"),
+    "JP108": (
+        "paged-pool-by-value", ERROR,
+        "the paged KV pool moves through the program by value: a scan "
+        "takes or returns it layer by layer as xs/ys, or a whole layer of "
+        "it is dynamically sliced out or written back (the pool is "
+        "addressed in place by (layer, page))"),
 }
 
 

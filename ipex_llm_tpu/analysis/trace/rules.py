@@ -1,4 +1,4 @@
-"""JP101-JP106: rules over lowered programs (tracer.TracedEntry facts).
+"""JP101-JP108: rules over lowered programs (tracer.TracedEntry facts).
 
 Each rule yields ``core.Finding`` objects with ``tier="trace"``, anchored
 at the jitted function's def site so findings are clickable.  Spec-level
@@ -149,6 +149,62 @@ def check_weight_integrity(spec, entry: TracedEntry):
                 "bytes the packing bought); dequantize per layer inside "
                 "the scan body, next to the consuming matmul",
                 entry.point_key)
+
+
+def check_pool_in_place(spec, entry: TracedEntry):
+    """JP108: the paged KV pool is addressed in place by (layer, page) —
+    it never moves through the program by value.
+
+    The pool is the pair of 5-D ``[L, P, Hkv, page, D]`` leaves of a
+    ``cache`` input that also carries a 2-D int32 block table (a dense
+    cache has none and is not this rule's: it is per-row, and its layer
+    scan has nothing to address through).  The layer scan carries the
+    stacks whole and hands each layer its index, so two shapes of
+    program must not come back: a ``scan`` whose ``xs`` or ``ys`` hold
+    the stack (one layer sliced out per step, one stacked back: a copy
+    of every layer, every tick), and a ``dynamic_slice`` /
+    ``dynamic_update_slice`` that takes or puts ONE WHOLE LAYER of the
+    stack.  A value is a layer of the pool when its trailing dims are
+    the pool's ``[P, h, page, D]`` with ``h`` dividing ``Hkv`` (a tp
+    shard holds its heads of every page), whatever leads them: a pp
+    stage's chunk of the stack is still the stack."""
+    tables = any(leaf.arg == "cache" and len(leaf.shape) == 2
+                 and leaf.dtype == "int32" for leaf in entry.leaves)
+    pools = {leaf.shape for leaf in entry.leaves
+             if leaf.arg == "cache" and len(leaf.shape) == 5} \
+        if tables else set()
+    if not pools:
+        return
+
+    def layer_like(shape) -> bool:
+        return any(len(shape) >= 4 and shape[-4] == p[1]
+                   and shape[-2:] == p[3:] and p[2] % shape[-3] == 0
+                   for p in pools)
+
+    def stack_like(shape) -> bool:
+        return len(shape) == 5 and layer_like(shape)
+
+    def one_layer(shape) -> bool:
+        return layer_like(shape) and (len(shape) == 4 or shape[0] == 1)
+
+    seen: set[tuple[str, tuple[int, ...]]] = set()
+    for prim, shapes in entry.slab_eqns:
+        if prim == "scan":
+            hit = next((s for s in shapes if stack_like(s)), None)
+            why = ("rides a scan as xs/ys — every step slices a layer out "
+                   "of the stack or stacks one back, a copy of the whole "
+                   "pool per pass; carry the stacks and index by layer")
+        else:
+            operand, part = shapes
+            hit = part if stack_like(operand) and one_layer(part) else None
+            why = (f"is moved by {prim} — a whole layer of the pool copied "
+                   "out of or back into the stack; write and read through "
+                   "(layer, page) instead")
+        if hit is not None and (prim, hit) not in seen:
+            seen.add((prim, hit))
+            yield finding(
+                spec, "JP108",
+                f"pool-shaped value {list(hit)} {why}", entry.point_key)
 
 
 def check_callbacks(spec, entry: TracedEntry):

@@ -129,6 +129,8 @@ def audit(specs=None, ticks=None, manifest_path=None, update: bool = False,
                 trace_rules.check_fp8_integrity(spec, entry))
             spec_findings.extend(
                 trace_rules.check_weight_integrity(spec, entry))
+            spec_findings.extend(
+                trace_rules.check_pool_in_place(spec, entry))
             spec_findings.extend(trace_rules.check_callbacks(spec, entry))
             spec_findings.extend(
                 trace_rules.check_constant_bloat(spec, entry))

@@ -57,6 +57,10 @@ class TracedEntry:
     flops: int
     bytes_accessed: int
     eqn_avals: tuple[tuple[tuple[int, ...], str], ...]   # every eqn output
+    # where a stacked buffer can move by value (JP108): ("scan", shapes
+    # of its xs and ys), ("dynamic_slice", (operand, result)),
+    # ("dynamic_update_slice", (operand, update)), in program order
+    slab_eqns: tuple[tuple[str, tuple[tuple[int, ...], ...]], ...] = ()
 
 
 def point_key(point: dict) -> str:
@@ -126,18 +130,33 @@ def parse_compiled_aliases(hlo_text: str) -> dict[int, int]:
             for m in _COMPILED_ALIAS_RE.finditer(hlo_text)}
 
 
+def _shapes(variables) -> tuple[tuple[int, ...], ...]:
+    return tuple(tuple(v.aval.shape) for v in variables
+                 if hasattr(getattr(v, "aval", None), "shape"))
+
+
 def _walk_jaxpr(jaxpr: Jaxpr, callbacks: list[str],
-                avals: list[tuple[tuple[int, ...], str]]):
+                avals: list[tuple[tuple[int, ...], str]],
+                slabs: list[tuple[str, tuple[tuple[int, ...], ...]]]):
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name in CALLBACK_PRIMITIVES:
-            callbacks.append(eqn.primitive.name)
+        name = eqn.primitive.name
+        if name in CALLBACK_PRIMITIVES:
+            callbacks.append(name)
+        if name == "scan":
+            n_fixed = eqn.params["num_consts"] + eqn.params["num_carry"]
+            slabs.append((name, _shapes(eqn.invars[n_fixed:])
+                          + _shapes(eqn.outvars[eqn.params["num_carry"]:])))
+        elif name == "dynamic_slice":
+            slabs.append((name, _shapes([eqn.invars[0], eqn.outvars[0]])))
+        elif name == "dynamic_update_slice":
+            slabs.append((name, _shapes(eqn.invars[:2])))
         for v in eqn.outvars:
             aval = getattr(v, "aval", None)
             if aval is not None and hasattr(aval, "shape"):
                 avals.append((tuple(aval.shape), str(aval.dtype)))
         for sub in eqn.params.values():
             for j in _iter_subjaxprs(sub):
-                _walk_jaxpr(j, callbacks, avals)
+                _walk_jaxpr(j, callbacks, avals, slabs)
 
 
 def _iter_subjaxprs(v: Any):
@@ -205,8 +224,9 @@ def trace_entry(spec, point: dict, prebuilt=None) -> TracedEntry:
 
     callbacks: list[str] = []
     eqn_avals: list[tuple[tuple[int, ...], str]] = []
+    slab_eqns: list[tuple[str, tuple[tuple[int, ...], ...]]] = []
     closed = traced.jaxpr
-    _walk_jaxpr(closed.jaxpr, callbacks, eqn_avals)
+    _walk_jaxpr(closed.jaxpr, callbacks, eqn_avals, slab_eqns)
     const_bytes = sum(int(getattr(c, "nbytes", 0)) for c in closed.consts)
 
     cost = lowered.cost_analysis() or {}
@@ -223,4 +243,5 @@ def trace_entry(spec, point: dict, prebuilt=None) -> TracedEntry:
         flops=int(cost.get("flops", 0) or 0),
         bytes_accessed=int(cost.get("bytes accessed", 0) or 0),
         eqn_avals=tuple(eqn_avals),
+        slab_eqns=tuple(slab_eqns),
     )

@@ -169,9 +169,16 @@ class PagedKVCache:
 
     - ONE pool per k/v of shape ``[L, P, Hkv, page, D]`` shared by all rows,
     - a per-row block table ``[R, maxP]`` of page ids (-1 = unallocated),
-    - writes scatter into ``(table[r, slot//page], slot % page)``,
-    - reads gather the row's pages back into the head-major ``[R, H, S, D]``
-      view the decode kernel consumes; invalid tail pages are masked by
+    - the pool is addressed IN PLACE by ``(layer, page)``: the decoder's
+      layer scan carries the two stacks whole (never a per-layer slice) and
+      hands every layer its index, so a tick moves the bytes it writes and
+      the pages it reads and nothing else,
+    - writes (:meth:`write_layer`) scatter into ``(layer, table[r,
+      slot//page], :, slot % page)`` of the carried stack,
+    - reads go through the ragged kernel's index map ``(layer, table[r,
+      pi], head)`` (ops/pallas/ragged_paged_attention.py); the XLA fallback
+      (:meth:`gather_layer`) gathers the row's pages of that layer into the
+      head-major ``[R, H, S, D]`` view; invalid tail pages are masked by
       ``kv_len`` exactly like dense-cache slack.
 
     Page allocation, refcounts, and prefix sharing are host-side concerns
@@ -286,29 +293,51 @@ class PagedKVCache:
         for at most the rows it advanced)."""
         return replace(self, tables=self.tables.at[rows].set(table_rows))
 
-    @jax.named_scope("layer/kv_write")
-    def update_layer(self, kl: jnp.ndarray, vl: jnp.ndarray,
-                     new_k: jnp.ndarray, new_v: jnp.ndarray, pos: jnp.ndarray):
-        """Scatter new_k/new_v [B, T, H, D] into pool layer [P, H, page, D]
-        through the block table at per-row slot offsets ``pos`` [B]."""
-        ps = self.page_size
-        b, t = new_k.shape[:2]
+    def route_pages(self, page_idx: jnp.ndarray) -> jnp.ndarray:
+        """Page indexes [B, N] of every row -> pool pages through the block
+        table.  Page 0 is the engine's scratch page (never allocated to a
+        row): an index past the table width (the right-padded prefill
+        tail) or an unallocated one lands there instead of corrupting
+        live pages."""
         maxp = self.tables.shape[1]
+        pages = self.tables[jnp.arange(page_idx.shape[0])[:, None],
+                            jnp.clip(page_idx, 0, maxp - 1)]
+        return jnp.where((page_idx < maxp) & (pages >= 0), pages, 0)
+
+    def _route(self, b: int, t: int, pos: jnp.ndarray):
+        """(pages, offs) [B, T]: where tokens ``pos + [0, T)`` of every row
+        land."""
         if getattr(pos, "ndim", 0) == 0:
             pos = jnp.broadcast_to(pos, (b,))
         slots = pos[:, None] + jnp.arange(t)[None, :]           # [B, T]
-        page_idx = slots // ps
-        pages = self.tables[jnp.arange(b)[:, None],
-                            jnp.clip(page_idx, 0, maxp - 1)]
-        # page 0 is the engine's scratch page (never allocated to a row):
-        # writes past the table width (right-padded prefill tail) or into
-        # unallocated slots land there instead of corrupting live pages
-        valid = (page_idx < maxp) & (pages >= 0)
-        pages = jnp.where(valid, pages, 0)
-        offs = slots % ps
+        ps = self.page_size
+        return self.route_pages(slots // ps), slots % ps
+
+    @jax.named_scope("layer/kv_write")
+    def update_layer(self, kl: jnp.ndarray, vl: jnp.ndarray,
+                     new_k: jnp.ndarray, new_v: jnp.ndarray, pos: jnp.ndarray):
+        """Scatter new_k/new_v [B, T, H, D] into ONE pool layer [P, H,
+        page, D] through the block table at per-row slot offsets ``pos``
+        [B].  The per-layer form: what callers holding a layer slice use,
+        and the reference :meth:`write_layer` is tested against."""
+        pages, offs = self._route(*new_k.shape[:2], pos)
         kl = kl.at[pages, :, offs].set(self.encode(new_k))
         vl = vl.at[pages, :, offs].set(self.encode(new_v))
         return kl, vl
+
+    def write_layer(self, k: jnp.ndarray, v: jnp.ndarray, layer,
+                    new_k: jnp.ndarray, new_v: jnp.ndarray, pos: jnp.ndarray):
+        """:meth:`update_layer` addressed in place: write new_k/new_v
+        [B, T, H, D] into the WHOLE stacks ``k``/``v`` [L, P, H, page, D]
+        at ``(layer, page, :, offset)`` and return the stacks.  The same
+        bytes in the same places as the per-layer form (storage-dtype
+        encode, pad tail and unallocated slots to scratch page 0), but
+        no layer is sliced out or written back: the decoder's scan
+        carries the stacks, so the scatter updates the carried buffer."""
+        pages, offs = self._route(*new_k.shape[:2], pos)
+        k = k.at[layer, pages, :, offs].set(self.encode(new_k))
+        v = v.at[layer, pages, :, offs].set(self.encode(new_v))
+        return k, v
 
     def gather_pages(self, page_ids) -> tuple[jnp.ndarray, jnp.ndarray]:
         """Pull whole pages out of the pool: ``page_ids`` [N] ->
@@ -337,12 +366,16 @@ class PagedKVCache:
         )
 
     @jax.named_scope("layer/kv_read")
-    def gather_layer(self, kl: jnp.ndarray) -> jnp.ndarray:
-        """Pool layer [P, H, page, D] -> head-major rows [R, H, maxP*page, D]
-        (the raw layout cached_sdpa's decode path consumes)."""
+    def gather_layer(self, kl: jnp.ndarray, layer=None) -> jnp.ndarray:
+        """The rows' pages as head-major rows [R, H, maxP*page, D] (the raw
+        layout cached_sdpa's decode path consumes): from a pool layer
+        [P, H, page, D], or — ``layer`` given — from the whole stack
+        [L, P, H, page, D] by ``(layer, page)``, one gather with no
+        whole-layer slice in between (the XLA fallback of the in-place
+        addressing; no benchmark cell runs it)."""
         r, maxp = self.tables.shape
-        t = jnp.clip(self.tables, 0, kl.shape[0] - 1)
-        g = kl[t]                                   # [R, maxP, H, page, D]
+        t = jnp.clip(self.tables, 0, kl.shape[-4] - 1)
+        g = kl[t] if layer is None else kl[layer, t]   # [R, maxP, H, page, D]
         g = g.transpose(0, 2, 1, 3, 4)
         return g.reshape(r, g.shape[1], maxp * self.page_size, g.shape[4])
 

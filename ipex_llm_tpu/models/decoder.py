@@ -28,7 +28,7 @@ from ipex_llm_tpu.models.config import ModelConfig
 from ipex_llm_tpu.ops import linear as linear_ops
 from ipex_llm_tpu.ops import mlp as mlp_ops
 from ipex_llm_tpu.ops import rope as rope_ops
-from ipex_llm_tpu.ops.attention import cached_sdpa
+from ipex_llm_tpu.ops.attention import cached_sdpa, paged_kv_write
 from ipex_llm_tpu.ops.norms import layer_norm, rms_norm
 
 COMPUTE_DTYPE = jnp.bfloat16
@@ -70,8 +70,18 @@ def _in_norm(x, lp, key, cfg):
 def _attention_block(cfg: ModelConfig, lp: dict, x, kl, vl, cos, sin, slot0,
                      q_slots, kv_len, kv_start, sliding, cache: KVCache,
                      collect_obs: int = 0, bias=None, pre_normed=False,
-                     chunk_lens=None):
+                     chunk_lens=None, layer=None):
+    """One layer's attention.  ``kl``/``vl`` are that layer's cache slice;
+    with ``layer`` (a paged pool under :func:`run_layers`) they are the
+    WHOLE stacks [L, P, Hkv, page, D], written and read at that layer in
+    place, and come back whole."""
     b, t, _ = x.shape
+
+    def write(k, v):
+        if layer is None:
+            return cache.update_layer(kl, vl, k, v, slot0)
+        return paged_kv_write(cache, kl, vl, layer, k, v, slot0)
+
     # olmo2-style reordered norm: attention sees the raw residual stream
     # and attn_norm applies to the block OUTPUT instead; pre_normed: the
     # caller already normed x (glm_alpha residual needs the normed input)
@@ -112,13 +122,13 @@ def _attention_block(cfg: ModelConfig, lp: dict, x, kl, vl, cos, sin, slot0,
         )
 
         obs_q = q[:, -collect_obs:] if collect_obs else jnp.zeros((0,), x.dtype)
-        kl, vl = cache.update_layer(kl, vl, k, v, slot0)
+        kl, vl = write(k, v)
         attn = cached_sdpa(
             q, kl, vl, cache,
             compute_dtype=COMPUTE_DTYPE, causal=True, q_positions=q_slots,
             kv_len=kv_len, kv_start=kv_start, window=None, window_on=sliding,
             softcap=cfg.attn_softcap, scale=cfg.attn_scale,
-            chunk_lens=chunk_lens,
+            chunk_lens=chunk_lens, layer=layer,
         )
         attn = attn.reshape(b, t, cfg.num_heads * cfg.v_dim)
         out = linear_ops.linear(attn, lp["o"], lp.get("o_bias"))
@@ -127,7 +137,7 @@ def _attention_block(cfg: ModelConfig, lp: dict, x, kl, vl, cos, sin, slot0,
         return out, kl, vl, obs_q
     with jax.named_scope("layer/qkv"):
         q, k, v, obs_q = _qkv_rope(cfg, lp, x, h, cos, sin, collect_obs)
-    kl, vl = cache.update_layer(kl, vl, k, v, slot0)
+    kl, vl = write(k, v)
 
     # the cache layer stays in storage dtype: decode steps read it directly
     # through the specialized kernel (fp8 dequant in-kernel); other shapes
@@ -149,6 +159,7 @@ def _attention_block(cfg: ModelConfig, lp: dict, x, kl, vl, cos, sin, slot0,
             scale=cfg.attn_scale,
             bias=bias,
             chunk_lens=chunk_lens,
+            layer=layer,
         )
     with jax.named_scope("layer/o_proj"):
         attn = attn.reshape(b, t, cfg.num_heads * cfg.head_dim)
@@ -468,17 +479,29 @@ def logits_tail(cfg: ModelConfig, params, x):
 def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
                x, cos, sin, slot0, q_slots, kv_len, kv_start, cache,
                collect_obs: int = 0, alibi_bias=None,
-               cos_local=None, sin_local=None, chunk_lens=None):
-    """Scan one stacked layer tree over its cache slice.
+               cos_local=None, sin_local=None, chunk_lens=None,
+               layer_offset: int = 0):
+    """Scan one stacked layer tree over its layers of the cache.
 
     The single compiled layer body shared by decoder_forward and the
     pipeline-parallel microbatch scheduler (parallel/pipeline.py), which
     runs each stage's LOCAL chunk of layers through this same function
-    inside shard_map.  Returns (x, k_new, v_new, obs_q).
-    """
+    inside shard_map.  ``k_stack``/``v_stack`` are the cache's stacks, of
+    which the tree's ``n`` layers start at ``layer_offset`` (a two-stack
+    model's second tree starts past the first).  Returns (x, k_new, v_new,
+    obs_q) with the stacks whole again.
 
-    def body(x, xs):
-        lp, kl, vl, sliding = xs
+    How the cache rides the scan follows its class.  A dense cache
+    [L, B, H, S, D] is per-row, so the scan runs over layer slices: the
+    tree's layers are the scan's ``xs`` and come back as its ``ys``.  A
+    paged pool [L, P, H, page, D] (``cache.tables``) is addressed through
+    the block table, so nothing needs slicing: the stacks ride the scan's
+    carry beside ``x`` and are written in place, and the ``xs`` hold only
+    the tree, the sliding flag and each layer's index.
+    """
+    n = sliding_flags.shape[0]
+
+    def layer_step(x, lp, kl, vl, sliding, layer):
         if cos_local is not None:
             # gemma3 dual rope: sliding layers use the local table
             c = jnp.where(sliding, cos_local, cos)
@@ -494,16 +517,16 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
             attn_out, kl, vl, obs_q = _attention_block(
                 cfg, lp, a_in, kl, vl, c, s_, slot0, q_slots, kv_len,
                 kv_start, sliding, cache, collect_obs, bias=alibi_bias,
-                pre_normed=True, chunk_lens=chunk_lens,
+                pre_normed=True, chunk_lens=chunk_lens, layer=layer,
             )
             x = a_in * alpha + attn_out
             m_in = _in_norm(x, lp, "mlp_norm", cfg)
             x = m_in * alpha + _mlp_block(cfg, lp, m_in, pre_normed=True)
-            return x, (kl, vl, obs_q)
+            return x, kl, vl, obs_q
         attn_out, kl, vl, obs_q = _attention_block(
             cfg, lp, x, kl, vl, c, s_, slot0, q_slots, kv_len, kv_start,
             sliding, cache, collect_obs, bias=alibi_bias,
-            chunk_lens=chunk_lens,
+            chunk_lens=chunk_lens, layer=layer,
         )
         ffn = _moe_block if "moe_gate_up" in lp else _mlp_block
         # minicpm depth scaling (cfg.residual_multiplier, 1.0 elsewhere)
@@ -519,11 +542,35 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
         else:
             x = add(x, attn_out)
             x = add(x, ffn(cfg, lp, x))
+        return x, kl, vl, obs_q
+
+    if hasattr(cache, "tables"):
+        def paged_body(carry, xs):
+            x, k, v = carry
+            lp, layer, sliding = xs
+            x, k, v, obs_q = layer_step(x, lp, k, v, sliding, layer)
+            return (x, k, v), obs_q
+
+        layers = layer_offset + jnp.arange(n, dtype=jnp.int32)
+        (x, k_stack, v_stack), obs_q = jax.lax.scan(
+            paged_body, (x, k_stack, v_stack), (tree, layers, sliding_flags)
+        )
+        return x, k_stack, v_stack, obs_q
+
+    def body(x, xs):
+        lp, kl, vl, sliding = xs
+        x, kl, vl, obs_q = layer_step(x, lp, kl, vl, sliding, None)
         return x, (kl, vl, obs_q)
 
+    whole = n == k_stack.shape[0]
+    ours = slice(layer_offset, layer_offset + n)
     x, (k_new, v_new, obs_q) = jax.lax.scan(
-        body, x, (tree, k_stack, v_stack, sliding_flags)
+        body, x, (tree, k_stack if whole else k_stack[ours],
+                  v_stack if whole else v_stack[ours], sliding_flags)
     )
+    if not whole:
+        k_new = k_stack.at[ours].set(k_new)
+        v_new = v_stack.at[ours].set(v_new)
     return x, k_new, v_new, obs_q
 
 
@@ -599,26 +646,23 @@ def decoder_forward(
 
     # deepseek-style dense-prefix models carry two layer stacks (plain-MLP
     # prefix + MoE rest, models/build.py); each runs its own scan over its
-    # cache slice so every stack still compiles one layer body
+    # layers of the cache (the stacks pass through whole, addressed from
+    # layer ``lo`` on) so every stack still compiles one layer body
     if "layers_dense" in params:
         nd = cfg.moe_layer_start
         stacks = [(params["layers_dense"], 0, nd),
                   (params["layers"], nd, cfg.num_layers)]
     else:
         stacks = [(params["layers"], 0, cfg.num_layers)]
-    k_parts, v_parts, obs_parts = [], [], []
+    k_new, v_new, obs_parts = cache.k, cache.v, []
     for tree, lo, hi in stacks:
-        x, kp, vp, op = run_layers(
-            cfg, tree, cache.k[lo:hi], cache.v[lo:hi], sliding_flags[lo:hi],
+        x, k_new, v_new, op = run_layers(
+            cfg, tree, k_new, v_new, sliding_flags[lo:hi],
             x, cos, sin, slot0, q_slots, kv_len, kv_start, cache,
             collect_obs, alibi_bias, cos_local=cos_l, sin_local=sin_l,
-            chunk_lens=chunk_lens,
+            chunk_lens=chunk_lens, layer_offset=lo,
         )
-        k_parts.append(kp)
-        v_parts.append(vp)
         obs_parts.append(op)
-    k_new = jnp.concatenate(k_parts) if len(k_parts) > 1 else k_parts[0]
-    v_new = jnp.concatenate(v_parts) if len(v_parts) > 1 else v_parts[0]
     obs_q = (jnp.concatenate(obs_parts) if len(obs_parts) > 1
              else obs_parts[0])
 

@@ -161,6 +161,37 @@ def _decode_kernel_mode(dispatch, op: str, hq: int, hkv: int) -> str | None:
     return None
 
 
+def _paged_op(kl: jnp.ndarray) -> str:
+    """The measured-ladder family of the paged pool's kernels, by the
+    pool's storage dtype."""
+    return "ragged_attn_fp8" if "float8" in str(kl.dtype) else "ragged_attn"
+
+
+@jax.named_scope("layer/kv_write")
+def paged_kv_write(cache, k, v, layer, new_k, new_v, pos):
+    """Write a tick's new K/V [B, T, Hkv, D] into the paged pool's stacks
+    ``k``/``v`` [L, P, Hkv, page, D] at ``(layer, page, :, offset)``,
+    in place, and return the stacks.
+
+    Chosen by the rule that chooses the paged attention kernel (the same
+    ladder family, so pool reads and writes change backend together): the
+    Pallas kernel (ops/pallas/paged_kv_write.py) where kernels run,
+    ``cache.write_layer``'s XLA scatter elsewhere (the CPU; a GSPMD mesh
+    the kv heads do not divide over).  Both put the same bytes in the same
+    places."""
+    from ipex_llm_tpu.ops import dispatch
+
+    hkv = k.shape[2]
+    mode = _decode_kernel_mode(dispatch, _paged_op(k), hkv, hkv)
+    if mode is None:
+        return cache.write_layer(k, v, layer, new_k, new_v, pos)
+    from ipex_llm_tpu.ops.pallas import paged_kv_write as kernel
+
+    return kernel.paged_kv_write(
+        cache, k, v, layer, new_k, new_v, pos,
+        mesh=dispatch.spmd_mesh() if mode == "sharded" else None)
+
+
 def cached_sdpa(
     q: jnp.ndarray,            # [B, T, Hq, D]
     kl: jnp.ndarray,           # [B, Hkv, S, D] raw cache layer (maybe fp8)
@@ -183,10 +214,15 @@ def cached_sdpa(
     from ipex_llm_tpu.ops import dispatch
 
     chunk_lens = kwargs.pop("chunk_lens", None)
+    layer = kwargs.pop("layer", None)
     if hasattr(cache, "tables"):
-        # paged pool layer (serving engine; rows right-aligned from slot 0,
+        # paged pool (serving engine; rows right-aligned from slot 0,
         # queries at slots [kv_len - T, kv_len) — the engine's invariant).
-        # The layer arrives in STORAGE dtype: an fp8(e5m2) pool streams its
+        # With ``layer`` (the decoder's scan) kl/vl are the WHOLE stacks
+        # [L, P, Hkv, page, D] and that layer of them is read in place,
+        # by the kernel's index map or by the fallback's gather; without,
+        # they are one layer [P, Hkv, page, D].
+        # The pool arrives in STORAGE dtype: an fp8(e5m2) pool streams its
         # tiles into the Pallas kernels, which widen to bf16 in-kernel (the
         # ``xe_addons.sdp_fp8`` equivalent — HBM reads stay half-width),
         # and the gather fallback gathers the fp8 codes (still half the
@@ -203,7 +239,7 @@ def cached_sdpa(
             and kwargs.get("softcap") is None
             and kwargs.get("kv_start") is None
             and kwargs.get("kv_len") is not None
-            and q.shape[2] % kl.shape[1] == 0
+            and q.shape[2] % kl.shape[-3] == 0
         ):
             # read ONLY the row's own pages through the scalar-prefetched
             # block table — no table-width gather.  The op family key
@@ -211,29 +247,27 @@ def cached_sdpa(
             # microbench ladder (dispatch._BUILTIN_LADDER / the env
             # override): the same rows microbench records are what decide
             # kernel-vs-XLA here.
-            op = ("ragged_attn_fp8" if "float8" in str(kl.dtype)
-                  else "ragged_attn")
-            mode = _decode_kernel_mode(dispatch, op, q.shape[2],
-                                       kl.shape[1])
+            mode = _decode_kernel_mode(dispatch, _paged_op(kl), q.shape[2],
+                                       kl.shape[-3])
             if mode is not None:
                 from ipex_llm_tpu.ops.pallas import ragged_paged_attention
             if mode == "single":
                 return ragged_paged_attention.ragged_paged_sdpa(
                     q, kl, vl, cache.tables, kwargs.get("kv_len"),
-                    chunk_lens, scale=kwargs.get("scale"))
+                    chunk_lens, scale=kwargs.get("scale"), layer=layer)
             if mode == "sharded":
                 # TP serving: per-shard kernel over the kv-head split
                 return ragged_paged_attention.ragged_paged_sdpa_sharded(
                     q, kl, vl, cache.tables, kwargs.get("kv_len"),
                     dispatch.spmd_mesh(), chunk_lens,
-                    scale=kwargs.get("scale"))
+                    scale=kwargs.get("scale"), layer=layer)
         # fallback: gather the rows' pages into the head-major
         # [B, Hkv, S, D] view; tail pages beyond kv_len are garbage and
         # masked exactly like dense-cache slack (per-row chunk lens are
         # already folded into kv_len by the caller, so the reference mask
         # needs no extra input)
-        kl = cache.gather_layer(kl)
-        vl = cache.gather_layer(vl)
+        kl = cache.gather_layer(kl, layer)
+        vl = cache.gather_layer(vl, layer)
 
     t = q.shape[1]
     decode_ok = (

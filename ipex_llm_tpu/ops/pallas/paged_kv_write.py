@@ -1,0 +1,165 @@
+"""Paged KV write: a tick's new K/V rows into the stacked pool, in place.
+
+The write half of addressing the pool by ``(layer, page)`` (kv.py
+``PagedKVCache``; the read half is ragged_paged_attention.py's index map).
+The XLA form, ``PagedKVCache.write_layer``'s scatter on the carried stack,
+is the fallback and the reference; on the chip it is not usable: XLA's
+scatter wants the pool in a token-major layout and the attention kernel in
+the default one, so the compiler re-lays the whole pool out around every
+layer's write (the third rehearsal, PR 26).  This kernel keeps the pool in
+its one layout: both stacks are aliased in to out
+(``input_output_aliases``), and each grid step reads, patches and writes
+back one ``[Hkv, sb, D]`` block of one page, the ``sb`` token slots (32, or
+the page where it is smaller) that hold some of a row's new tokens, found
+through scalar-prefetched ``(layer, page, block in page)``.  Everything
+else of the pool is never touched.
+
+Same bytes in the same places as the XLA form: rows arrive encoded to the
+storage dtype (bf16 or e5m2), every one of a right-padded chunk's ``T``
+tokens is written (the pad tail too), and a block past the table's width
+or in an unallocated page goes to scratch page 0.  Several rows' blocks
+can land on the scratch page in one call; which of them it keeps is not
+defined (the scatter's own rule for duplicates), and nothing reads it.
+
+Inside a block the new tokens sit at a traced offset.  They are moved
+there by a rotation along the token axis (``pltpu.roll``) in f32, which
+holds every bf16 and e5m2 value exactly, and merged under a mask, so the
+kernel needs no store at a traced sublane offset.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ipex_llm_tpu.ops.pallas._compat import (
+    interpret as _interpret,
+    round_up as _round_up,
+)
+
+# token slots a grid step patches: a whole tile of either storage dtype
+# (bf16 packs 16 rows a tile, e5m2 32)
+_SLOT_BLOCK = 32
+
+
+def _kernel(layer_ref, page_ref, blk_ref, t0_ref, shift_ref, kn_ref, vn_ref,
+            k_in, v_in, k_out, v_out, *, t, sb, m, nsub):
+    """Grid step (row, j): patch the j-th slot block this row's chunk
+    touches.  Row s of the block takes token ``t0 + s`` of the chunk where
+    that is one of its ``t`` tokens, and keeps what it held elsewhere."""
+    i = pl.program_id(0) * nsub + pl.program_id(1)
+    tok = t0_ref[i] + jax.lax.broadcasted_iota(jnp.int32, (1, sb, 1), 1)
+    mask = (tok >= 0) & (tok < t)
+    for new_ref, src, dst in ((kn_ref, k_in, k_out), (vn_ref, v_in, v_out)):
+        new = new_ref[0]                                  # [Hkv, T8, D] f32
+        if new.shape[1] < m:
+            new = jnp.concatenate(
+                [new, jnp.zeros((new.shape[0], m - new.shape[1],
+                                 new.shape[2]), new.dtype)], axis=1)
+        # rolled[s] = new[(s + t0) mod m]: the chunk's tokens moved to
+        # where they sit in this block
+        cand = pltpu.roll(new, shift_ref[i], axis=1)[:, :sb, :]
+        tile = src[0].astype(jnp.float32)                 # [Hkv, sb, D]
+        dst[0] = jnp.where(mask, cand, tile).astype(dst.dtype)
+
+
+@jax.jit
+def _write(k, v, layer, pages, pos, new_k, new_v):
+    """k/v [L, P, Hkv, ps, D(v)] stacks; ``layer`` int32 scalar; ``pages``
+    [B, nP] the routed page of each page index a row's chunk can touch
+    (0 = scratch), first its page ``pos // ps``; ``pos`` [B] the chunk's
+    first slot; new_k/new_v [B, T, Hkv, D(v)] in the storage dtype."""
+    b, t = new_k.shape[:2]
+    _, _, hkv, ps, _ = k.shape
+    sb = _SLOT_BLOCK if ps % _SLOT_BLOCK == 0 else ps
+    nsub = -(-(t - 1) // sb) + 1          # slot blocks a chunk can span
+    t8 = _round_up(t, 8)
+    m = max(t8, sb)
+
+    # slot block g of a row covers slots [g*sb, (g+1)*sb); past the chunk's
+    # last block the walk stays on it (same block index: Pallas moves
+    # nothing, and the step rewrites what the last one wrote)
+    g = jnp.minimum(pos[:, None] // sb + jnp.arange(nsub)[None, :],
+                    ((pos + t - 1) // sb)[:, None])              # [B, nsub]
+    page = jnp.take_along_axis(pages, g * sb // ps - (pos // ps)[:, None],
+                               axis=1)
+    t0 = g * sb - pos[:, None]            # chunk token in the block's row 0
+
+    def rows(new):                        # [B, T, H, D] -> [B, H, T8, D] f32
+        new = new.astype(jnp.float32).transpose(0, 2, 1, 3)
+        return jnp.pad(new, ((0, 0), (0, 0), (0, t8 - t), (0, 0)))
+
+    def flat(a):
+        return a.reshape(-1).astype(jnp.int32)
+
+    def pool_map(bi, ji, layer_ref, page_ref, blk_ref, t0_ref, shift_ref):
+        i = bi * nsub + ji
+        return (layer_ref[0], page_ref[i], 0, blk_ref[i], 0)
+
+    def new_map(bi, ji, *_):
+        return (bi, 0, 0, 0)
+
+    def pool_spec(d):
+        return pl.BlockSpec((None, 1, hkv, sb, d), pool_map)
+
+    dk, dv = k.shape[-1], v.shape[-1]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(b, nsub),
+        in_specs=[
+            pl.BlockSpec((1, hkv, t8, dk), new_map),
+            pl.BlockSpec((1, hkv, t8, dv), new_map),
+            pool_spec(dk), pool_spec(dv),
+        ],
+        out_specs=[pool_spec(dk), pool_spec(dv)],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, t=t, sb=sb, m=m, nsub=nsub),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        # operands count the scalar prefetch: the stacks are 7 and 8
+        input_output_aliases={7: 0, 8: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+        ),
+        interpret=_interpret(),
+        name="paged_kv_write",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), flat(page),
+      flat(g % (ps // sb)), flat(t0), flat((-t0) % m), rows(new_k),
+      rows(new_v), k, v)
+
+
+def paged_kv_write(cache, k, v, layer, new_k, new_v, pos, mesh=None):
+    """``cache.write_layer`` as a kernel: write new_k/new_v [B, T, Hkv, D]
+    into the stacks ``k``/``v`` [L, P, Hkv, page, D] at ``(layer, page, :,
+    offset)`` through ``cache.tables`` and return the stacks, which the
+    call updates in place.  With ``mesh`` (tensor parallelism under GSPMD)
+    the stacks and the new rows split over their kv heads and every shard
+    patches its heads of the same blocks; the routing is replicated."""
+    b, t = new_k.shape[:2]
+    ps = cache.page_size
+    if getattr(pos, "ndim", 0) == 0:
+        pos = jnp.broadcast_to(pos, (b,))
+    n_pages = -(-(t - 1) // ps) + 1       # pages a chunk can touch
+    pages = cache.route_pages(
+        (pos // ps)[:, None] + jnp.arange(n_pages)[None, :])
+    args = (k, v, jnp.asarray(layer, jnp.int32), pages, pos,
+            cache.encode(new_k), cache.encode(new_v))
+    if mesh is None:
+        return _write(*args)
+    from jax.sharding import PartitionSpec as P
+
+    pool_spec = P(None, None, "tp", None, None)
+    new_spec = P(None, None, "tp", None)
+    return jax.shard_map(
+        _write, mesh=mesh, axis_names={"tp"},
+        in_specs=(pool_spec, pool_spec, P(), P(None, None), P(None),
+                  new_spec, new_spec),
+        out_specs=(pool_spec, pool_spec),
+        check_vma=False,
+    )(*args)

@@ -38,11 +38,11 @@ def test_kernels_match_references_in_interpret_mode(tiny_model):
                                     TINY_ENGINE, chunk=16, flash_len=128)
     families = {name.split(".")[0] for name in errs}
     assert families == {"qmatmul_sym_int4", "ragged_paged_sdpa",
-                        "flash_sdpa", "decode_sdpa"}
-    # both pool widths of the ragged kernel, decode and chunk widths
-    assert {n for n in errs if n.startswith("ragged")} == {
-        f"ragged_paged_sdpa.{s}.c{c}" for s in ("bf16", "fp8")
-        for c in (1, 16)}
+                        "paged_kv_write", "flash_sdpa", "decode_sdpa"}
+    # both pool widths of the pool's two kernels, decode and chunk widths
+    assert {n for n in errs if n.startswith(("ragged", "paged"))} == {
+        f"{k}.{s}.c{c}" for k in ("ragged_paged_sdpa", "paged_kv_write")
+        for s in ("bf16", "fp8") for c in (1, 16)}
 
 
 def test_server_answers_the_wave_over_http(tiny_model):

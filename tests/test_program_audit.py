@@ -298,6 +298,116 @@ def test_jp107_covers_5bit_plane_ratio():
 
 
 # --------------------------------------------------------------------------
+# JP108 paged-pool-by-value
+# --------------------------------------------------------------------------
+
+def _paged(k, v, tables):
+    from ipex_llm_tpu.kv import PagedKVCache
+
+    return PagedKVCache(k, v, tables, jnp.zeros((), jnp.int32))
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _fx_pool_scanned(cache, x):
+    # the pool rides the layer scan BY VALUE: every step gets a layer
+    # sliced out of the stack as xs and stacks it back as ys
+    def body(x, layer):
+        kl, vl = layer
+        kl = kl.at[cache.tables[0, 0], :, 0].set(x.astype(kl.dtype))
+        return x + kl[1, :, 0].astype(x.dtype) + vl[1, :, 0].astype(x.dtype), \
+            (kl, vl)
+
+    x, (k, v) = jax.lax.scan(body, x, (cache.k, cache.v))
+    return _paged(k, v, cache.tables), x
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _fx_pool_layer_sliced(cache, x):
+    # the stack is carried, but each step still takes its whole layer out
+    # (dynamic_slice) and puts it back (dynamic_update_slice)
+    def body(carry, layer):
+        x, k = carry
+        kl = jax.lax.dynamic_index_in_dim(k, layer, 0, keepdims=False)
+        kl = kl.at[cache.tables[0, 0], :, 0].set(x.astype(kl.dtype))
+        k = jax.lax.dynamic_update_index_in_dim(k, kl, layer, 0)
+        return (x + kl[1, :, 0].astype(x.dtype), k), None
+
+    (x, k), _ = jax.lax.scan(body, (x, cache.k),
+                             jnp.arange(_POOL_SHAPE[0]))
+    return _paged(k, cache.v, cache.tables), x
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _fx_pool_in_place(cache, x):
+    # the design: the stacks ride the carry, every step writes and reads
+    # through (layer, page); xs hold the layer's index alone
+    def body(carry, layer):
+        x, k = carry
+        page = cache.tables[0, 0]
+        k = k.at[layer, page, :, 0].set(x.astype(k.dtype))
+        return (x + k[layer, page + 1, :, 0].astype(x.dtype), k), None
+
+    (x, k), _ = jax.lax.scan(body, (x, cache.k),
+                             jnp.arange(_POOL_SHAPE[0]))
+    return _paged(k, cache.v, cache.tables), x
+
+
+def _paged_build(pt):
+    pool = sds(*_POOL_SHAPE, dtype=jnp.bfloat16)
+    return (_paged(pool, pool, sds(4, 3, dtype=jnp.int32)),
+            sds(_POOL_SHAPE[2], _POOL_SHAPE[4])), {}
+
+
+PAGED_SPEC = dict(build=_paged_build, arg_names=("cache", "x"),
+                  dead=frozenset({"cache"}))
+
+
+def test_jp108_fires_on_pool_scanned_by_value():
+    spec = mkspec(_fx_pool_scanned, **PAGED_SPEC)
+    found = list(jp.check_pool_in_place(spec, _entry(spec)))
+    assert [f.rule for f in found] == ["JP108"]
+    assert "xs/ys" in found[0].message
+
+
+def test_jp108_fires_on_whole_layer_slice_and_write_back():
+    spec = mkspec(_fx_pool_layer_sliced, **PAGED_SPEC)
+    found = list(jp.check_pool_in_place(spec, _entry(spec)))
+    assert [f.rule for f in found] == ["JP108", "JP108"]
+    assert "dynamic_slice" in found[0].message
+    assert "dynamic_update_slice" in found[1].message
+
+
+def test_jp108_quiet_on_pool_addressed_in_place():
+    spec = mkspec(_fx_pool_in_place, **PAGED_SPEC)
+    assert list(jp.check_pool_in_place(spec, _entry(spec))) == []
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _fx_dense_scanned(cache, x):
+    def body(x, layer):
+        kl, vl = layer
+        kl = kl.at[0, :, 0].set(x.astype(kl.dtype))
+        return x + vl[1, :, 0].astype(x.dtype), (kl, vl)
+
+    x, (k, v) = jax.lax.scan(body, x, (cache.k, cache.v))
+    return type(cache)(k, v, cache.length), x
+
+
+def test_jp108_quiet_on_a_dense_cache_scan():
+    """A dense cache has no block table to address through: its layer
+    scan over xs/ys is the design, not this rule's finding."""
+    from ipex_llm_tpu.kv import KVCache
+
+    pool = sds(*_POOL_SHAPE, dtype=jnp.bfloat16)
+    spec = mkspec(
+        _fx_dense_scanned,
+        build=lambda pt: ((KVCache(pool, pool, sds(dtype=jnp.int32)),
+                           sds(_POOL_SHAPE[2], _POOL_SHAPE[4])), {}),
+        arg_names=("cache", "x"), dead=frozenset({"cache"}))
+    assert list(jp.check_pool_in_place(spec, _entry(spec))) == []
+
+
+# --------------------------------------------------------------------------
 # JP103 host callbacks / JP105 constant bloat
 # --------------------------------------------------------------------------
 
