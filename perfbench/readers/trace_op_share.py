@@ -1,5 +1,5 @@
 """The share (%) of the device's busy time spent in operations whose name
-matches {"ops": regex}; with "complement": true, in all the others."""
+matches {"ops": regex}."""
 
 import re
 
@@ -10,9 +10,7 @@ def read(params: dict, ctx: dict):
         return None
     pat = re.compile(params["ops"])
     total = sum(sec for _, sec in tr["ops"].values())
-    hit = sum(sec for name, (_, sec) in tr["ops"].items() if pat.search(name))
     if total <= 0:
         return None
-    if params.get("complement"):
-        hit = total - hit
+    hit = sum(sec for name, (_, sec) in tr["ops"].items() if pat.search(name))
     return 100.0 * hit / total

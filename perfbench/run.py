@@ -35,21 +35,19 @@ sys.path[:0] = [HERE, os.path.dirname(HERE)]
 
 import stats  # noqa: E402
 import trafficgen  # noqa: E402
+from failure import BenchFailure  # noqa: E402
 
 _T0 = time.monotonic()
 # keys of a configuration file that are the benchmark's, not the model's
-BENCH_KEYS = ("source", "reduced", "assumed", "withheld", "weight_qtype",
-              "chips", "tensor_parallel_size", "engine", "notes", "rehearsal")
+BENCH_KEYS = ("source", "reduced", "assumed", "deployment", "withheld",
+              "weight_qtype", "chips", "tensor_parallel_size", "engine",
+              "notes", "rehearsal")
 PROBE_NEW_TOKENS = 8
 
 
 def log(msg: str) -> None:
     print(f"[perfbench +{time.monotonic() - _T0:6.1f}s] {msg}",
           file=sys.stderr, flush=True)
-
-
-class BenchFailure(RuntimeError):
-    """The run cannot give a result; main() exits non-zero without one."""
 
 
 def load_json(path: str) -> dict:
@@ -427,6 +425,9 @@ def run_cell(root: str, cell: dict, seed: int, seconds: float,
     log(f"cell {workload}: config {cell['cell']['config']}, traffic "
         f"{cell['cell']['traffic']}, compile cache "
         f"{ipex_llm_tpu.compile_cache_dir() or os.environ.get('JAX_COMPILATION_CACHE_DIR')}")
+    if config.get("deployment"):
+        # the cut this configuration stands for (README: `deployment`)
+        log(f"deployment: {json.dumps(config['deployment'])}")
     t = time.monotonic()
     model = weights.build_model(hf_config(config), config["weight_qtype"], seed)
     setup["model_build_s"] = time.monotonic() - t

@@ -50,8 +50,9 @@ def test_every_name_resolves_and_moves_are_reported():
             assert os.path.exists(os.path.join(
                 BENCH, "readers", m["reader"]["kind"] + ".py")), m["name"]
         hf = run.hf_config(cell["config"])
-        assert os.path.exists(os.path.join(
-            BENCH, "references", hf["model_type"] + ".py"))
+        for found_by_model_type in ("references", "generators"):
+            assert os.path.exists(os.path.join(
+                BENCH, found_by_model_type, hf["model_type"] + ".py"))
         assert "eos_token_id" not in hf
     for c in bench["configs"]:
         assert any(w["config"] == c["name"] for w in bench["workloads"])
@@ -161,7 +162,7 @@ def test_no_tpu_means_no_result():
     assert "no CPU mode" in p.stderr
 
 
-# -- the whole run at a tiny size, and a throw-away cell added by files alone ----
+# -- the whole run at a tiny size, and throw-away cells added by files alone -----
 
 TINY = {
     "source": "test only", "model_type": "mistral", "hidden_size": 128,
@@ -174,6 +175,15 @@ TINY = {
     "weight_qtype": "sym_int4", "chips": 1, "tensor_parallel_size": 1,
     "engine": {"max_rows": 2, "max_seq_len": 256, "page_size": 16},
 }
+# a second FAMILY, whose tree the dense generator cannot express (a router and
+# [layers, experts, ...] stacks): its generator and its plain reference are
+# tests/families/{generators,references}/mixtral.py, found by model_type
+FAMILIES_DIR = os.path.join(HERE, "families")
+TINY_MOE = dict(
+    TINY, model_type="mixtral", num_local_experts=4, num_experts_per_tok=2,
+    reduced=["num_local_experts"],
+    deployment={"chips_sharing_a_layer": 2, "how": "experts",
+                "published": {"num_local_experts": 8}})
 TINY_TRAFFIC = {
     "why": "test only", "loop": "closed", "clients": 2,
     "prompt_len": {"dist": "uniform", "min": 8, "max": 28},
@@ -187,45 +197,54 @@ TINY_TRAFFIC = {
 
 @pytest.fixture(scope="module")
 def tiny_root(tmp_path_factory):
-    """A copy of the checkout's benchmark data plus one configuration, one
-    traffic mix, one cell and one per-layer metric over an existing reader
-    kind: new files and new BENCHMARK.json entries, no file edited."""
+    """A copy of the checkout's benchmark data plus two configurations (one
+    of a family the benchmark does not have), one traffic mix, a cell for
+    each and one per-layer metric over an existing reader kind: new files
+    and new BENCHMARK.json entries, no file edited."""
     root = str(tmp_path_factory.mktemp("root"))
     data = os.path.join(root, "perfbench")
     for sub in ("configs", "traffic", "layer_metrics"):
         shutil.copytree(os.path.join(BENCH, sub), os.path.join(data, sub))
     bench = bench_json()
-    with open(os.path.join(data, "configs", "tiny.json"), "w") as f:
-        json.dump(TINY, f)
+    for name, config in (("tiny", TINY), ("tinymoe", TINY_MOE)):
+        with open(os.path.join(data, "configs", name + ".json"), "w") as f:
+            json.dump(config, f)
+        bench["configs"].append({
+            "name": name, "source": "test only", "why": "test only",
+            "file": f"perfbench/configs/{name}.json",
+            "reduced": config["reduced"]})
+        bench["workloads"].append({
+            "name": name + ".closed", "config": name,
+            "traffic": "tiny-closed", "chips": 1, "why": "test only"})
     with open(os.path.join(data, "traffic", "tiny-closed.json"), "w") as f:
         json.dump(TINY_TRAFFIC, f)
     with open(os.path.join(data, "layer_metrics", "queue_rows.json"), "w") as f:
         json.dump({"reader": {"kind": "counter_ratio", "num": "requests",
                               "den": "ticks"}}, f)
-    bench["configs"].append({"name": "tiny", "source": "test only",
-                             "file": "perfbench/configs/tiny.json",
-                             "reduced": [], "why": "test only"})
-    bench["workloads"].append({"name": "tiny.closed", "config": "tiny",
-                               "traffic": "tiny-closed", "chips": 1,
-                               "why": "test only"})
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m:
-            m["workloads"].append("tiny.closed")
+            m["workloads"] += ["tiny.closed", "tinymoe.closed"]
     bench["per_layer"].append({
         "name": "queue_rows", "unit": "rows", "better": "higher",
         "source": "program_counter", "layer": "scheduler / tick transaction",
-        "moves": "output_tok_s", "workloads": ["tiny.closed"]})
+        "moves": "output_tok_s",
+        "workloads": ["tiny.closed", "tinymoe.closed"]})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
-    return root
+    # the second family's modules: on the path the harness searches
+    sys.path.insert(0, FAMILIES_DIR)
+    yield root
+    sys.path.remove(FAMILIES_DIR)
 
 
-@pytest.mark.parametrize("trace", [0, 1])
-def test_whole_run_at_a_tiny_size(tiny_root, trace, capfd):
+@pytest.mark.parametrize("workload,trace", [
+    ("tiny.closed", 0), ("tiny.closed", 1), ("tinymoe.closed", 0)])
+def test_whole_run_at_a_tiny_size(tiny_root, workload, trace, capfd):
     """run.py end to end on the CPU (the first rehearsal): server, probes
     against the reference, warm-up to convergence, window, result line."""
-    cell = run.resolve_cell(tiny_root, "tiny.closed")
+    cell = run.resolve_cell(tiny_root, workload)
     assert any(m["name"] == "queue_rows" for m in cell["per_layer"])
+    assert "deployment" not in run.hf_config(cell["config"])
     device = run.device_info()
     assert device["platform"] == "cpu"
     try:

@@ -307,6 +307,32 @@ def test_gaps_are_booked_to_the_innermost_span(small):
     assert (lo, hi) == (0.457347755, 0.464858815)
 
 
+def test_the_breakdown_names_idle_time_by_host_span(small):
+    """The result line's ``idle_gaps``: time between programs by the
+    innermost span; ``host_between_programs`` only for what no span holds,
+    which is all of it in a trace without the program's spans."""
+    import trace_reduce
+
+    by_span = xs.idle_by_span(small)
+    assert by_span["host_between_programs"] == pytest.approx(0.002529137,
+                                                             abs=1e-9)
+    assert by_span["engine/sync"] == pytest.approx(BY_PHASE["sync"], abs=1e-9)
+    between = sum(by_span.values())
+    assert between == pytest.approx(0.046854789, abs=1e-9)
+    reduced = {"ops": {}, "gap_total_s": between + 0.01,
+               "gap_between_s": between, "idle_by_span": by_span}
+    gaps = dict(trace_reduce.breakdown(reduced)["idle_gaps"])
+    assert len(gaps) <= 10 and gaps["inside_a_program"] == pytest.approx(0.01)
+    longest = max(by_span, key=by_span.get)
+    assert longest.startswith("engine/") and longest in gaps
+    assert all(v > 0 for v in gaps.values())
+    assert xs.idle_by_span(dict(small, spans=[])) is None
+    reduced["idle_by_span"] = None
+    assert dict(trace_reduce.breakdown(reduced)["idle_gaps"]) == {
+        "host_between_programs": pytest.approx(between),
+        "inside_a_program": pytest.approx(0.01)}
+
+
 def test_idle_reader_writes_the_whole_table_once(small, monkeypatch, capsys):
     ctx = ctx_for(small, monkeypatch)
     read = reader("span_idle_ms").read

@@ -16,7 +16,11 @@ The reduced form is plain JSON (kept small enough to store as test data):
    "ops": {name: [count, self seconds]},  # chip 0, by operation name; an
                                       # operation's self time leaves out the
                                       # operations nested inside it
-   "gaps": [[start_s, dur_s, label]]} # longest idle gaps on chip 0
+   "gaps": [[start_s, dur_s, label]], # longest idle gaps on chip 0
+   "gap_total_s", "gap_between_s",    # all idle time on chip 0; the part of
+                                      # it between two programs
+   "idle_by_span": {label: seconds}}  # reduce_dir only: gap_between_s by the
+                                      # innermost host span (xplane_spans.py)
 """
 
 from __future__ import annotations
@@ -128,37 +132,46 @@ def reduce_events(planes: dict[int, dict[str, list]]) -> dict | None:
         modules.append({"name": name, "start_s": s - t_lo, "dur_s": d,
                         "ops": inside, "busy_s": _union(spans)[0]})
     _, gaps = _union([(s, s + d) for _, s, d in ops])
-    labelled = []
-    for g_s, g_d in sorted(gaps, key=lambda g: -g[1])[:50]:
-        inside_mod = any(s <= g_s and g_s + g_d <= s + d for _, s, d in mods)
-        labelled.append([g_s - t_lo, g_d,
-                         "inside_a_program" if inside_mod
-                         else "host_between_programs"])
+    gaps = [(g_s, g_d, "inside_a_program"
+             if any(s <= g_s and g_s + g_d <= s + d for _, s, d in mods)
+             else "host_between_programs") for g_s, g_d in gaps]
     return {"window_s": t_hi - t_lo,
             "busy_s": sum(busy_per_chip) / len(busy_per_chip),
             "chips": len(busy_per_chip), "modules": modules, "ops": by_name,
-            "gap_total_s": sum(d for _, d in gaps), "gaps": labelled}
+            "gap_total_s": sum(d for _, d, _ in gaps),
+            "gap_between_s": sum(d for _, d, label in gaps
+                                 if label == "host_between_programs"),
+            "gaps": [[g_s - t_lo, g_d, label] for g_s, g_d, label
+                     in sorted(gaps, key=lambda g: -g[1])[:50]]}
 
 
 def reduce_dir(logdir: str) -> dict | None:
     path = find_xplane(logdir)
     if path is None:
         return None
-    return reduce_events(device_events(path))
+    reduced = reduce_events(device_events(path))
+    if reduced is not None:
+        import xplane_spans     # here: it imports this module
+
+        reduced["idle_by_span"] = xplane_spans.idle_by_span(
+            xplane_spans.parsed_file(path))
+    return reduced
 
 
 def breakdown(reduced: dict) -> dict:
     """The contract's optional ``breakdown``: the device operations that took
-    most time, and the longest idle gaps by what the host was doing (as far
-    as the trace alone can say: between two programs the device waits for
-    the host's next dispatch; inside one it waits on the program itself)."""
+    most time, and ALL the device's idle time by what the host was doing.
+    Inside a program the device waits on the program itself; between two
+    programs it waits for the host, and that time goes by the innermost of
+    the program's host spans that holds it (``engine/sync``,
+    ``engine/prepare``, ...).  ``host_between_programs`` is what no span
+    holds: all of it in a trace without the program's spans."""
     ops = sorted(reduced["ops"].items(), key=lambda kv: -kv[1][1])[:10]
-    by_label: dict[str, float] = {}
-    for _, d, label in reduced["gaps"]:
-        by_label[label] = by_label.get(label, 0.0) + d
-    rest = reduced["gap_total_s"] - sum(by_label.values())
-    if rest > 0:
-        by_label["unattributed_short_gaps"] = rest
-    gaps = sorted(by_label.items(), key=lambda kv: -kv[1])[:10]
+    by_label = dict(reduced.get("idle_by_span")
+                    or {"host_between_programs": reduced["gap_between_s"]})
+    by_label["inside_a_program"] = (reduced["gap_total_s"]
+                                    - reduced["gap_between_s"])
+    gaps = sorted(((n, d) for n, d in by_label.items() if d > 0),
+                  key=lambda kv: -kv[1])[:10]
     return {"device_ops": [[n, c[1]] for n, c in ops],
             "idle_gaps": [[n, d] for n, d in gaps]}

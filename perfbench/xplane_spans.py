@@ -270,6 +270,11 @@ def _parsed(path: str, mtime: float) -> dict:
     return parse_xplane(path)
 
 
+def parsed_file(path: str) -> dict:
+    """:func:`parse_xplane` of ``path``, once per process and writing."""
+    return _parsed(path, os.path.getmtime(path))
+
+
 def load(ctx: dict) -> dict | None:
     """This run's parsed trace, once per process; None where run.py reduced
     no trace, or none written by this process is found."""
@@ -280,7 +285,7 @@ def load(ctx: dict) -> dict | None:
                           process_start_s())
     if path is None:
         return None
-    return _parsed(path, os.path.getmtime(path))
+    return parsed_file(path)
 
 
 # -- host spans --------------------------------------------------------------------
@@ -482,6 +487,20 @@ def gap_table(parsed: dict) -> dict | None:
                   if any(n["name"] == "dispatch" for n in _walk(t["kids"])))
     return {"by_phase": by_phase, "tick": own, "none": total - covered,
             "total": total, "working_ticks": working}
+
+
+def idle_by_span(parsed: dict) -> dict | None:
+    """:func:`gap_table` as the labels of the result line's
+    ``breakdown.idle_gaps``: seconds by ``engine/<innermost span>``, and
+    ``host_between_programs`` for what lies inside no span.  None without
+    engine/tick spans."""
+    table = gap_table(parsed)
+    if table is None:
+        return None
+    out = {SPAN_PREFIX + phase: s for phase, s in table["by_phase"].items()}
+    out[SPAN_PREFIX + "tick"] = table["tick"]
+    out["host_between_programs"] = table["none"]
+    return out
 
 
 def group_idle_ms(table: dict, group: str):
