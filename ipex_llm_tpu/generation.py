@@ -284,6 +284,9 @@ def generate(
     tokens, lengths, tpad = pad_batch(input_ids, gen.pad_token_id)
     b = tokens.shape[0]
 
+    if cfg.ssm_layers and kv_kind in ("auto", "compress"):
+        # SnapKV compression renumbers slots; recurrent state has none
+        kv_kind = "fp8" if kv_mod.use_quantize_kv_cache() else "normal"
     if host_embed is not None and kv_kind == "auto":
         # SnapKV's prefill_collect path has no input_embeds form; the
         # streamed-embedding user trades that optimization away
@@ -326,9 +329,11 @@ def generate(
     else:
         capacity = tpad + _round_up(gen.max_new_tokens + 1, DECODE_BLOCK)
         cache = kv_mod.make_cache(
-            kv_kind, cfg.num_layers, b, capacity, cfg.num_kv_heads,
+            kv_kind, cfg.kv_layers, b, capacity, cfg.num_kv_heads,
             cfg.head_dim, v_head_dim=cfg.v_dim,
         )
+        # a hybrid model's recurrent state rides the same cache object
+        cache = kv_mod.with_recurrent_state(cache, cfg, b)
 
     from ipex_llm_tpu.ops import dispatch as _dispatch
 

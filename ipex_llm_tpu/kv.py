@@ -73,14 +73,17 @@ class KVCache:
     length: jnp.ndarray  # scalar int32: filled prefix length
 
     storage: str = "bf16"  # static: bf16 | fp8
+    # recurrent state beside the KV (:func:`recurrent_state`), one slot a
+    # batch row; None for a model whose layers all attend
+    state: Any = None
 
     def tree_flatten(self):
-        return (self.k, self.v, self.length), (self.storage,)
+        return (self.k, self.v, self.length, self.state), (self.storage,)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        k, v, length = children
-        return cls(k, v, length, storage=aux[0])
+        k, v, length, state = children
+        return cls(k, v, length, storage=aux[0], state=state)
 
     # -- construction -------------------------------------------------------
 
@@ -191,14 +194,21 @@ class PagedKVCache:
     length: jnp.ndarray  # scalar int32 (engines drive per-row slot_offsets)
 
     storage: str = "bf16"
+    # the second kind of serving state, beside the pool in the SAME object:
+    # a hybrid model's recurrent state (:func:`recurrent_state`), addressed
+    # by ROW and not through the block table.  Carried through the tick like
+    # the pool (each layer's slots rewritten in place, never copied whole).
+    # None for a model whose layers all attend: no leaf, the same program
+    state: Any = None
 
     def tree_flatten(self):
-        return (self.k, self.v, self.tables, self.length), (self.storage,)
+        return ((self.k, self.v, self.tables, self.length, self.state),
+                (self.storage,))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        k, v, tables, length = children
-        return cls(k, v, tables, length, storage=aux[0])
+        k, v, tables, length, state = children
+        return cls(k, v, tables, length, storage=aux[0], state=state)
 
     @classmethod
     def init(cls, n_layers: int, n_pages: int, n_rows: int, max_pages: int,
@@ -381,6 +391,43 @@ class PagedKVCache:
 
     def advanced(self, n):
         return replace(self, length=self.length + n)
+
+
+# counters a hybrid tick accumulates ON THE DEVICE, in ``state["stats"]``
+# (they ride the state, so a rolled-back tick's are dropped with it); the
+# engine reads them on the fetch it already makes and publishes them under
+# these names with ``_total`` appended
+STATE_COUNTERS = ("moe_pairs_routed", "moe_pairs_local", "moe_experts_hit",
+                  "moe_layer_calls", "ssm_state_resets")
+
+
+def recurrent_state(cfg, rows: int) -> dict | None:
+    """The recurrent state of ``cfg``'s Mamba-2 blocks for ``rows`` rows,
+    zeroed: ``h`` [M blocks, rows, H, P, N] float32, ``conv`` [M blocks,
+    rows, K-1, conv width] bf16 (the last K-1 conv inputs) and the
+    ``stats`` counters (STATE_COUNTERS).  None where the model has none."""
+    if not cfg.ssm_layers:
+        return None
+    return {
+        "h": jnp.zeros((cfg.ssm_layers, rows, cfg.ssm_num_heads,
+                        cfg.ssm_head_dim, cfg.ssm_state_size), jnp.float32),
+        "conv": jnp.zeros((cfg.ssm_layers, rows, cfg.ssm_conv_kernel - 1,
+                           cfg.ssm_conv_dim), jnp.bfloat16),
+        "stats": jnp.zeros((len(STATE_COUNTERS),), jnp.int32),
+    }
+
+
+def with_recurrent_state(cache, cfg, rows: int):
+    """``cache`` with ``cfg``'s zeroed recurrent state for ``rows`` rows as
+    its ``state`` (the cache itself for a model without one)."""
+    if not cfg.ssm_layers:
+        return cache
+    return replace(cache, state=recurrent_state(cfg, rows))
+
+
+def state_bytes(state) -> int:
+    """Bytes of a cache's recurrent state (0 without one)."""
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(state))
 
 
 # cache-kind registry: name -> constructor.  Dense kinds take the KVCache

@@ -240,6 +240,82 @@ def param_bytes(params: dict[str, Any]) -> tuple[int, int]:
     return packed, dense
 
 
+def build_hybrid_params(cfg: ModelConfig, get: Callable[[str], np.ndarray],
+                        qtype: str = "sym_int4", head_qtype: str | None = None,
+                        progress: Callable[[str], None] | None = None
+                        ) -> dict[str, Any]:
+    """The tree of a hybrid model (``cfg.hybrid_pattern``; nemotron_h): one
+    stack a block KIND under ``params["hybrid"]`` (``mamba`` ``[M blocks,
+    ...]``, ``moe`` ``[E blocks, experts held, ...]``, ``attn`` ``[*
+    blocks, ...]``), which ``models/decoder.py`` walks in the pattern's
+    order.  An expert layer holds ``cfg.moe_experts_held`` experts from
+    ``cfg.moe_first_expert`` on and the whole router; embedding and head
+    hold the first ``cfg.vocab_size`` rows.  Every 4-bit plane is padded
+    here, once, to the dequant-matmul kernel's tiles
+    (``quantize/core.pad_planes``), so no call pads a weight."""
+    pre = "backbone.layers.{i}."
+
+    def packed(w: np.ndarray, as_qtype: str = qtype) -> QTensor:
+        return qcore.pad_planes(quantize_weight(w, as_qtype))
+
+    def q(name: str) -> QTensor:
+        return packed(get(name))
+
+    def f32(name: str):
+        return jnp.asarray(get(name), NORM_DTYPE)
+
+    kinds: dict[str, list[dict[str, Any]]] = {"mamba": [], "moe": [],
+                                              "attn": []}
+    for i, kind in enumerate(cfg.hybrid_pattern):
+        if progress:
+            progress(f"layer {i + 1}/{cfg.num_layers}")
+        m = pre.format(i=i) + "mixer."
+        norm = f32(pre.format(i=i) + "norm.weight")
+        if kind == "M":
+            conv_w = np.asarray(get(m + "conv1d.weight"))[:, 0, :]   # [C, K]
+            kinds["mamba"].append({
+                "norm": norm, "in_proj": q(m + "in_proj.weight"),
+                "conv_w": jnp.asarray(conv_w.T, NORM_DTYPE),         # [K, C]
+                "conv_b": f32(m + "conv1d.bias"),
+                "dt_bias": f32(m + "dt_bias"), "A_log": f32(m + "A_log"),
+                "D": f32(m + "D"), "gate_norm": f32(m + "norm.weight"),
+                "out_proj": q(m + "out_proj.weight")})
+        elif kind == "E":
+            first, held = cfg.moe_first_expert, cfg.moe_experts_held
+            experts = range(first, first + held)
+            kinds["moe"].append({
+                "mlp_norm": norm,
+                "router": jnp.asarray(
+                    np.ascontiguousarray(get(m + "gate.weight").T),
+                    jnp.float32),
+                "router_bias": f32(m + "gate.e_score_correction_bias"),
+                "moe_gate_up": stack_layer_trees(
+                    [q(m + f"experts.{e}.up_proj.weight") for e in experts]),
+                "moe_down": stack_layer_trees(
+                    [q(m + f"experts.{e}.down_proj.weight")
+                     for e in experts]),
+                "shared_gate_up": q(m + "shared_experts.up_proj.weight"),
+                "shared_down": q(m + "shared_experts.down_proj.weight")})
+        else:
+            qkv = np.concatenate([get(m + f"{p}_proj.weight")
+                                  for p in "qkv"], axis=0)
+            kinds["attn"].append({
+                "attn_norm": norm,
+                "qkv": packed(qkv),
+                "o": q(m + "o_proj.weight")})
+    params: dict[str, Any] = {
+        "hybrid": {k: stack_layer_trees(v) for k, v in kinds.items() if v},
+        "embed": jnp.asarray(
+            get("backbone.embeddings.weight")[: cfg.vocab_size],
+            jnp.bfloat16),
+        "final_norm": f32("backbone.norm_f.weight"),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = packed(
+            get("lm_head.weight")[: cfg.vocab_size], head_qtype or qtype)
+    return params
+
+
 def build_params(
     cfg: ModelConfig,
     scheme: WeightScheme,
@@ -260,6 +336,13 @@ def build_params(
     mixed_precision mirrors the reference's flag (model.py kwargs): quantize
     the lm_head at sym_int8 rather than the 4-bit body qtype.
     """
+
+    if cfg.hybrid_pattern is not None:
+        return build_hybrid_params(
+            cfg, get, qtype=qtype,
+            head_qtype=lm_head_qtype or ("sym_int8" if mixed_precision
+                                         else qtype),
+            progress=progress)
 
     def name(t: str | None, i: int | None = None, p: str = "weight") -> str | None:
         if t is None:

@@ -120,6 +120,23 @@ class ModelConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int | None = None     # defaults to head_dim
 
+    # hybrid stacks (nemotron_h): a block is ONE mixer, named by a letter of
+    # the pattern: 'M' Mamba-2 (ops/ssm.py), 'E' experts, '*' attention.
+    # The weights are stacked per KIND and the decoder walks the pattern
+    hybrid_pattern: str | None = None
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_n_groups: int = 0
+    ssm_state_size: int = 0
+    ssm_conv_kernel: int = 4
+    moe_shared_intermediate_size: int = 0   # an always-on, ungated shared expert
+    # an expert layer that holds PART of its experts (one chip's share of a
+    # deployment that divides them): the router scores all ``num_experts``,
+    # this layer computes experts [first, first + held) and leaves the
+    # others' part out
+    moe_first_expert: int = 0
+    moe_experts_held: int = 0
+
     def layer_is_sliding(self, layer_idx: int) -> bool:
         if self.layer_types is not None:
             return self.layer_types[layer_idx] == "sliding_attention"
@@ -127,6 +144,23 @@ class ModelConfig:
 
     def layer_is_moe(self, layer_idx: int) -> bool:
         return self.num_experts > 0 and layer_idx >= self.moe_layer_start
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers the KV cache holds: the attention blocks."""
+        if self.hybrid_pattern is not None:
+            return self.hybrid_pattern.count("*")
+        return self.num_layers
+
+    @property
+    def ssm_layers(self) -> int:
+        """Blocks that carry recurrent state (0: the cache is KV alone)."""
+        return (self.hybrid_pattern or "").count("M")
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        return (self.ssm_num_heads * self.ssm_head_dim
+                + 2 * self.ssm_n_groups * self.ssm_state_size)
 
     @property
     def q_dim(self) -> int:

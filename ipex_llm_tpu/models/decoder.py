@@ -232,23 +232,22 @@ def _qkv_rope(cfg: ModelConfig, lp: dict, x, h, cos, sin, collect_obs):
     return q, k, v, obs_q
 
 
-@jax.named_scope("layer/mlp")
-def _moe_block(cfg: ModelConfig, lp: dict, x):
-    """Sparse-MoE FFN (mixtral/qwen-moe), reference deepseek.py:274-343 +
-    common.py:342-375 ``moe_group_topk``/``moe_forward_vec``.
+def _sum_chosen(w):
+    """The sum over the k chosen experts (the last axis), kept: term by
+    term in index order.  As a reduction XLA orders it by the shape of the
+    block: measured on a v5e, a token's six weights normalised as part of a
+    [1, 128, 6] block and of a [4, 32, 6] one differ by one float32 ulp in
+    a fifth of the cases, which is enough to give a served token other bits
+    when its prompt shares a prefill block (PERF.md section 6, PR 28)."""
+    total = w[..., 0]
+    for j in range(1, w.shape[-1]):
+        total = total + w[..., j]
+    return total[..., None]
 
-    Router in fp32, then sparse dispatch (ops/moe.py): decode-shaped
-    batches gather only the top-k experts' packed weights from HBM; larger
-    batches run capacity-bucketed dispatch with one vmapped expert matmul
-    (ep-shardable).  IPEX_LLM_TPU_DENSE_MOE=1 selects the dense
-    all-experts scan (the oracle used by the sparse-vs-dense tests).
-    """
-    h = _norm(x, lp["mlp_norm"], cfg)
-    router_logits = jnp.matmul(
-        h.astype(jnp.float32), lp["router"]
-    )  # [B,T,E]
+
+def _route(cfg: ModelConfig, lp: dict, router_logits):
+    """Router logits [B, T, E] -> (weights, expert ids), both [B, T, k]."""
     k = cfg.num_experts_per_tok
-    n_e = cfg.num_experts
     if cfg.moe_softmax_before_topk:
         if cfg.moe_score_func == "sigmoid":  # deepseek-v3 noaux_tc
             scores = jax.nn.sigmoid(router_logits)
@@ -274,12 +273,34 @@ def _moe_block(cfg: ModelConfig, lp: dict, x):
         _, idx = jax.lax.top_k(sel, k)
         w = jnp.take_along_axis(scores, idx, axis=-1)
         if cfg.moe_norm_topk_prob:
-            w = w / (w.sum(-1, keepdims=True) + 1e-20)
+            w = w / (_sum_chosen(w) + 1e-20)
     else:  # mixtral: top-k logits, softmax over the k
         lg, idx = jax.lax.top_k(router_logits, k)
-        w = jax.nn.softmax(lg, axis=-1)
+        w = jnp.exp(lg - lg[..., :1])       # top_k sorts: the first is the max
+        w = w / _sum_chosen(w)
     if cfg.moe_router_scale != 1.0:
         w = w * cfg.moe_router_scale
+
+    return w, idx
+
+
+@jax.named_scope("layer/mlp")
+def _moe_block(cfg: ModelConfig, lp: dict, x):
+    """Sparse-MoE FFN (mixtral/qwen-moe), reference deepseek.py:274-343 +
+    common.py:342-375 ``moe_group_topk``/``moe_forward_vec``.
+
+    Router in fp32, then sparse dispatch (ops/moe.py): decode-shaped
+    batches gather only the top-k experts' packed weights from HBM; larger
+    batches run capacity-bucketed dispatch with one vmapped expert matmul
+    (ep-shardable).  IPEX_LLM_TPU_DENSE_MOE=1 selects the dense
+    all-experts scan (the oracle used by the sparse-vs-dense tests).
+    """
+    h = _norm(x, lp["mlp_norm"], cfg)
+    router_logits = jnp.matmul(
+        h.astype(jnp.float32), lp["router"]
+    )  # [B,T,E]
+    n_e = cfg.num_experts
+    w, idx = _route(cfg, lp, router_logits)
 
     from ipex_llm_tpu.ops import moe as moe_ops
 
@@ -354,6 +375,174 @@ def _mlp_block(cfg: ModelConfig, lp: dict, x, pre_normed=False):
     if cfg.post_mlp_norm:
         out = _norm(out, lp["post_mlp_norm"], cfg)
     return out
+
+
+@jax.named_scope("layer/mlp")
+def _held_moe_block(cfg: ModelConfig, lp: dict, x, valid, shared: bool = True,
+                    layer=None):
+    """An expert layer that is told WHICH experts it holds (a hybrid
+    model's ``E`` block; ``cfg.moe_first_expert``, and as many as its stacks
+    hold): it routes over all ``cfg.num_experts`` in float32, computes its
+    own experts' part for the pairs that land on them (ops/moe.py
+    ``moe_held``: nothing dropped, nothing dequantized at full width) and
+    leaves the absent experts' part out: no stand-in for the chip that
+    holds them.  The shared expert, ungated like the routed ones, runs for
+    every token.  ``valid`` [B, T] keeps padding from routing anywhere.
+    With ``layer`` the two expert stacks are the whole model's ``[E blocks,
+    held, ...]`` and that block's experts are read in place.
+
+    Returns (out, [pairs routed, pairs local, experts hit, 1])."""
+    from ipex_llm_tpu.ops import moe as moe_ops
+
+    b, t, hidden = x.shape
+    h = _norm(x, lp["mlp_norm"], cfg)
+    with jax.named_scope("moe/router"):
+        logits = jnp.matmul(h.astype(jnp.float32), lp["router"],
+                            precision=jax.lax.Precision.HIGHEST)
+        w, idx = _route(cfg, lp, logits)                     # [B, T, k]
+        held = jax.tree_util.tree_leaves(lp["moe_gate_up"])[0].shape[
+            0 if layer is None else 1]
+        local = idx - cfg.moe_first_expert
+        mine = (local >= 0) & (local < held) & valid[..., None]
+        gate = jnp.sum(
+            jnp.where(mine, w, 0.0)[..., None]
+            * jax.nn.one_hot(jnp.clip(local, 0, held - 1), held,
+                             dtype=jnp.float32), axis=-2)    # [B, T, held]
+    with jax.named_scope("moe/experts"):
+        out, n_hit = moe_ops.moe_held(
+            h.reshape(b * t, hidden), gate.reshape(b * t, held),
+            lp["moe_gate_up"], lp["moe_down"], cfg.act, gated=cfg.mlp_gated,
+            layer=layer)
+        out = out.reshape(b, t, hidden)
+    if shared and "shared_gate_up" in lp:
+        with jax.named_scope("moe/shared"):
+            inner = linear_ops.linear(h, lp["shared_gate_up"])
+            if cfg.mlp_gated:
+                inner = mlp_ops.gated_act_mul(
+                    *mlp_ops.split_gate_up(inner), cfg.act)
+            else:
+                inner = mlp_ops.act(inner, cfg.act)
+            out = out + linear_ops.linear(
+                inner, lp["shared_down"]).astype(jnp.float32)
+    stats = jnp.stack([
+        jnp.sum(valid).astype(jnp.int32) * cfg.num_experts_per_tok,
+        jnp.sum(mine).astype(jnp.int32), n_hit,
+        jnp.asarray(1, jnp.int32)])
+    return out.astype(x.dtype), stats
+
+
+def hybrid_schedule(pattern: str):
+    """How a hybrid pattern is walked.  Read as units ``M [*] E`` (every
+    Nemotron-3-Nano block sequence is 23 of them, six holding an attention
+    block) it compiles as ONE scanned body: ``("units", [has attention, a
+    unit])``.  A pattern that is not of that form is walked block by block:
+    ``("blocks", [(kind, index among its kind)])``."""
+    import re
+
+    if re.fullmatch(r"(M\*?E)+", pattern):
+        return "units", ["*" in u for u in re.findall(r"M\*?E", pattern)]
+    seen: dict[str, int] = {}
+    blocks = []
+    for kind in pattern:
+        blocks.append((kind, seen.get(kind, 0)))
+        seen[kind] = seen.get(kind, 0) + 1
+    return "blocks", blocks
+
+
+def run_hybrid(cfg: ModelConfig, tree: dict, cache, x, slot0, q_slots,
+               kv_len, kv_start, valid, chunk_lens=None, state_rows=None):
+    """Walk a hybrid model's blocks: ``x <- x + Mixer(norm(x))``, one mixer
+    a block, over the per-KIND stacks of ``tree`` (``mamba``, ``moe``,
+    ``attn``).  Attention block ``a`` addresses layer ``a`` of the KV cache
+    (a paged pool in place by ``(layer, page)``, a dense cache by its layer
+    slice); Mamba block ``m`` rewrites slot ``m`` of ``cache.state``.
+    ``state_rows`` [B]: the batch is a prefill block whose entry i is state
+    row ``state_rows[i]`` (a row starting at slot 0 starts from a zero
+    state); None: entry i is row i.  Returns (x, k, v, state)."""
+    from ipex_llm_tpu.ops import ssm as ssm_ops
+
+    paged = hasattr(cache, "tables")
+    fresh = None if state_rows is None else slot0 == 0
+    state = dict(cache.state)
+    # the tick's read-only source state (engine._tick_body): handed to the
+    # Mamba mixer beside the state it writes, never carried or returned
+    src = state.pop("src", None)
+    if fresh is not None:
+        resets = jnp.sum(fresh & (jnp.sum(valid, axis=1) > 0))
+        state["stats"] = state["stats"].at[4].add(resets.astype(jnp.int32))
+    no_window = jnp.asarray(False)
+
+    def mamba(x, lp, state, m):
+        with jax.named_scope("layer/ssm"):
+            out, state = ssm_ops.mamba2_mixer(
+                cfg, lp, _norm(x, lp["norm"], cfg),
+                state if src is None else dict(state, src=src), m, valid,
+                rows=state_rows, fresh=fresh)
+            state.pop("src", None)
+            return x + out, state
+
+    def attention(x, k, v, lp, a):
+        if paged:
+            out, k, v, _ = _attention_block(
+                cfg, lp, x, k, v, None, None, slot0, q_slots, kv_len,
+                kv_start, no_window, cache, chunk_lens=chunk_lens, layer=a)
+            return x + out, k, v
+        kl = jax.lax.dynamic_index_in_dim(k, a, 0, False)
+        vl = jax.lax.dynamic_index_in_dim(v, a, 0, False)
+        out, kl, vl, _ = _attention_block(
+            cfg, lp, x, kl, vl, None, None, slot0, q_slots, kv_len, kv_start,
+            no_window, cache, chunk_lens=chunk_lens)
+        return (x + out, jax.lax.dynamic_update_index_in_dim(k, kl, a, 0),
+                jax.lax.dynamic_update_index_in_dim(v, vl, a, 0))
+
+    def experts(x, lp, state, layer=None):
+        out, stats = _held_moe_block(cfg, lp, x, valid, layer=layer)
+        return x + out, dict(
+            state, stats=state["stats"].at[:4].add(stats))
+
+    def entry(stack, i):
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False), stack)
+
+    kind, plan = hybrid_schedule(cfg.hybrid_pattern)
+    k, v = cache.k, cache.v
+    if kind == "blocks":
+        for block, i in plan:
+            if block == "M":
+                x, state = mamba(x, entry(tree["mamba"], i), state, i)
+            elif block == "E":
+                x, state = experts(x, entry(tree["moe"], i), state)
+            else:
+                x, k, v = attention(x, k, v, entry(tree["attn"], i), i)
+        return x, k, v, state
+
+    has_attn = jnp.asarray(plan)
+    attn_idx = jnp.maximum(jnp.cumsum(has_attn) - 1, 0).astype(jnp.int32)
+    # the expert stacks stay whole and are addressed by (block, expert): as
+    # the scan's ``xs`` a block's stack would be sliced out, every held
+    # expert's planes copied a block a tick
+    stacks = ("moe_gate_up", "moe_down")
+    moe_whole = {k_: tree["moe"][k_] for k_ in stacks}
+    moe_xs = {k_: v_ for k_, v_ in tree["moe"].items() if k_ not in stacks}
+
+    def unit(carry, xs):
+        x, k, v, state = carry
+        m_lp, e_lp, m, attn_here, a = xs
+        x, state = mamba(x, m_lp, state, m)
+        if "attn" in tree:
+            x, k, v = jax.lax.cond(
+                attn_here,
+                lambda x, k, v: attention(x, k, v, entry(tree["attn"], a), a),
+                lambda x, k, v: (x, k, v), x, k, v)
+        x, state = experts(x, dict(e_lp, **moe_whole), state, layer=m)
+        return (x, k, v, state), None
+
+    n = len(plan)
+    (x, k, v, state), _ = jax.lax.scan(
+        unit, (x, k, v, state),
+        (tree["mamba"], moe_xs, jnp.arange(n, dtype=jnp.int32), has_attn,
+         attn_idx))
+    return x, k, v, state
 
 
 def embed_prelude(cfg: ModelConfig, params, tokens, rope_positions,
@@ -587,6 +776,7 @@ def decoder_forward(
     input_embeds: jnp.ndarray | None = None,  # [B, T, H] bypasses the lookup
     gather_positions: jnp.ndarray | None = None,  # [B] per-row logits index
     chunk_lens: jnp.ndarray | None = None,    # [B] valid tokens this call
+    state_rows: jnp.ndarray | None = None,    # [B] recurrent-state row map
 ):
     """Run the decoder; returns (logits, updated cache).
 
@@ -640,6 +830,29 @@ def decoder_forward(
     if cfg.alibi:
         alibi_bias = alibi_bias_for(cfg, q_slots, cache.max_len)
 
+    if cfg.hybrid_pattern is not None:
+        if cache.state is None:
+            raise ValueError(
+                f"{cfg.model_type} carries recurrent state: build the cache "
+                "with kv.recurrent_state(cfg, rows) as its `state`")
+        if collect_obs:
+            raise NotImplementedError(
+                "SnapKV observation windows on a hybrid model")
+        if chunk_lens is not None:
+            valid = jnp.arange(t)[None, :] < chunk_lens[:, None]
+        elif kv_start is not None:
+            valid = q_slots >= kv_start[:, None]
+        else:
+            valid = jnp.ones((b, t), bool)
+        x, k_new, v_new, state = run_hybrid(
+            cfg, params["hybrid"], cache, x, slot0, q_slots, kv_len,
+            kv_start, valid, chunk_lens=chunk_lens, state_rows=state_rows)
+        logits = _chosen_logits(cfg, params, x, last_token_only,
+                                gather_positions)
+        new_len = cache.length if slot_offsets is not None else slot0 + t
+        return logits, replace(cache, k=k_new, v=v_new, length=new_len,
+                               state=state)
+
     sliding_flags = jnp.array(
         [cfg.layer_is_sliding(l) for l in range(cfg.num_layers)], dtype=bool
     )
@@ -666,6 +879,20 @@ def decoder_forward(
     obs_q = (jnp.concatenate(obs_parts) if len(obs_parts) > 1
              else obs_parts[0])
 
+    logits = _chosen_logits(cfg, params, x, last_token_only,
+                            gather_positions)
+
+    new_len = cache.length if slot_offsets is not None else slot0 + t
+    new_cache = replace(cache, k=k_new, v=v_new, length=new_len)
+    if collect_obs:
+        return logits, new_cache, obs_q
+    return logits, new_cache
+
+
+def _chosen_logits(cfg: ModelConfig, params, x, last_token_only: bool,
+                   gather_positions):
+    """The logits tail over the positions a caller wants: all of them
+    [B, T, V], or one a row [B, V]."""
     if last_token_only:
         # left-padding puts every sequence's last token at T-1; slice BEFORE
         # the norm+head tail so decode steps never project the full window
@@ -673,13 +900,9 @@ def decoder_forward(
     elif gather_positions is not None:
         # ragged chunk: each row's last valid token sits at its own index
         x = jnp.take_along_axis(
-            x, jnp.clip(gather_positions, 0, t - 1)[:, None, None], axis=1)
+            x, jnp.clip(gather_positions, 0, x.shape[1] - 1)[:, None, None],
+            axis=1)
     logits = logits_tail(cfg, params, x)
     if last_token_only or gather_positions is not None:
         logits = logits[:, 0]
-
-    new_len = cache.length if slot_offsets is not None else slot0 + t
-    new_cache = replace(cache, k=k_new, v=v_new, length=new_len)
-    if collect_obs:
-        return logits.astype(jnp.float32), new_cache, obs_q
-    return logits.astype(jnp.float32), new_cache
+    return logits.astype(jnp.float32)

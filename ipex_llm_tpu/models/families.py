@@ -810,6 +810,64 @@ def _minicpm3(hf: dict) -> ModelConfig:
     return ModelConfig(**d)
 
 
+def _nemotron_h(hf: dict) -> ModelConfig:
+    """Nemotron-H / Nemotron-3-Nano: one mixer a block by
+    ``hybrid_override_pattern`` (Mamba-2, experts, attention), no rotary
+    embedding in the attention blocks (the published modeling code applies
+    none; position comes from the Mamba blocks), a sigmoid router with a
+    correction bias and ungated relu2 experts.  ``n_router_experts`` and
+    ``first_routed_expert`` describe a chip's share of a deployment that
+    divides the experts: the router's width and the first expert held
+    (``n_routed_experts`` of them).  Weights: models/build.py
+    ``build_hybrid_params``."""
+    pattern = hf["hybrid_override_pattern"]
+    if len(pattern) != hf["num_hidden_layers"] or set(pattern) - set("ME*"):
+        raise ValueError(
+            f"nemotron_h: hybrid_override_pattern {pattern!r} must name "
+            f"{hf['num_hidden_layers']} blocks with the letters M, E, * "
+            "(a dense '-' MLP block is not supported)")
+    if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
+        raise ValueError("nemotron_h: grouped routing is not supported")
+    held = hf["n_routed_experts"]
+    return ModelConfig(
+        model_type="nemotron_h",
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf.get("head_dim")
+        or hf["hidden_size"] // hf["num_attention_heads"],
+        max_position_embeddings=hf.get("max_position_embeddings", 4096),
+        act=hf.get("mlp_hidden_act", "relu2"),
+        norm_eps=hf.get("layer_norm_epsilon", hf.get("norm_eps", 1e-5)),
+        rope=None,
+        tie_word_embeddings=hf.get("tie_word_embeddings", False),
+        attention_bias=hf.get("attention_bias", False),
+        mlp_gated=False,
+        hybrid_pattern=pattern,
+        ssm_num_heads=hf["mamba_num_heads"],
+        ssm_head_dim=hf["mamba_head_dim"],
+        ssm_n_groups=hf["n_groups"],
+        ssm_state_size=hf["ssm_state_size"],
+        ssm_conv_kernel=hf.get("conv_kernel", 4),
+        num_experts=hf.get("n_router_experts", held),
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        moe_shared_intermediate_size=hf.get(
+            "moe_shared_expert_intermediate_size", 0),
+        num_shared_experts=hf.get("n_shared_experts", 0),
+        moe_norm_topk_prob=hf.get("norm_topk_prob", True),
+        moe_router_scale=hf.get("routed_scaling_factor", 1.0),
+        moe_softmax_before_topk=True,
+        moe_score_func="sigmoid",
+        moe_score_bias=True,
+        moe_first_expert=hf.get("first_routed_expert", 0),
+        moe_experts_held=held,
+    )
+
+
 def _neox_qkv(w, cfg: ModelConfig):
     """GPT-NeoX query_key_value: per-head [q_i;k_i;v_i] interleave ->
     [q_all; k_all; v_all]."""
@@ -1157,6 +1215,7 @@ FAMILIES: dict[str, Family] = {
                           _DEEPSEEK_MOE),
     "deepseek_v3": Family("deepseek_v3", _deepseek_v3, _DEEPSEEK_SCHEME,
                           _DEEPSEEK_V3_MOE),
+    "nemotron_h": Family("nemotron_h", _nemotron_h),
     "mixtral": Family("mixtral", _mixtral, WeightScheme(), _MIXTRAL_MOE),
     "qwen2_moe": Family("qwen2_moe", _qwen2_moe, WeightScheme(), _QWEN2_MOE),
     "qwen3_moe": Family(

@@ -207,6 +207,85 @@ def moe_capacity(h, w, idx, gate_up, down, act, n_experts: int,
     return out.reshape(b, t, hidden)
 
 
+def _grouped_kernel_covers(stack) -> bool:
+    """Whether the chip's grouped dequant-matmul serves this expert stack
+    (ops/pallas/qmatmul.py ``qmatmul_pallas_grouped``)."""
+    from ipex_llm_tpu.ops import dispatch
+    from ipex_llm_tpu.quantize.core import QTensor
+
+    if not (isinstance(stack, QTensor)
+            and dispatch.use_pallas(f"qmatmul_{stack.qtype}")):
+        return False
+    from ipex_llm_tpu.ops.pallas import qmatmul as pallas_qmatmul
+
+    return pallas_qmatmul.grouped_supported(stack)
+
+
+def _expert_act(inner, act, gated):
+    if gated:
+        return mlp_ops.gated_act_mul(*mlp_ops.split_gate_up(inner), act)
+    return mlp_ops.act(inner, act)
+
+
+def moe_held(h, gate, gate_up, down, act, gated=True, layer=None):
+    """The part of an expert layer that the experts HELD here contribute:
+    h [N, H]; gate [N, E_held] float32, token n's weight for held expert e
+    and 0 where it is not routed there (a pair that lands on an absent
+    expert, a padding position).  ``gate_up``/``down`` are the layer's
+    stacks [E_held, ...], or with ``layer`` (traced) the whole model's [L,
+    E_held, ...], of which that layer is read in place: a scan that sliced
+    a layer's stack out as its ``xs`` would copy every held expert's planes
+    every call.  Returns (out [N, H] float32, experts hit).
+
+    One pass over the experts that are HIT, in ascending order, each
+    computed for every token and weighed by its gate column: no pair is
+    dropped whatever the imbalance, the stack is never dequantized at full
+    width, and the weight traffic is the hit experts' planes once.  A
+    token's sum runs over its own experts in ascending order with exact
+    zeros between, so it does not depend on which other tokens share the
+    call.  On the chip the pass is two calls of the grouped dequant-matmul
+    kernel (every hit expert's planes read in place through its index
+    maps, the experts that are not hit skipped); elsewhere a loop that
+    slices one expert's planes at a time."""
+    n_held = gate.shape[1]
+    first = 0
+    if layer is not None:
+        gate_up, down = (jax.tree_util.tree_map(
+            lambda a: a.reshape((-1,) + a.shape[2:]), w)
+            for w in (gate_up, down))
+        first = layer * n_held
+    hit = jnp.any(gate != 0, axis=0)                     # [E_held]
+    n_hit = jnp.sum(hit).astype(jnp.int32)
+    order = jnp.argsort(~hit, stable=True).astype(jnp.int32)   # hit ones first
+
+    if _grouped_kernel_covers(gate_up) and _grouped_kernel_covers(down):
+        from ipex_llm_tpu.ops.pallas.qmatmul import qmatmul_pallas_grouped
+
+        # slot g computes expert order[g]; the dead slots name the last
+        # live one again (nothing is fetched for them) and give zeros
+        slots = jnp.minimum(jnp.arange(n_held), jnp.maximum(n_hit - 1, 0))
+        ids = first + order[slots]
+        inner = qmatmul_pallas_grouped(h, gate_up, ids, n_hit)
+        a = _expert_act(inner.astype(h.dtype), act, gated)
+        y = qmatmul_pallas_grouped(a, down, ids, n_hit).astype(h.dtype)
+        g_slots = gate[:, order].T                       # [E_held, N]
+        out = g_slots[0][:, None] * y[0].astype(jnp.float32)
+        for s in range(1, n_held):                       # one fusion, in order
+            out = out + g_slots[s][:, None] * y[s].astype(jnp.float32)
+        return out, n_hit
+
+    def one(i, acc):
+        e = order[i]
+        inner = linear_ops.linear(h, _take_expert(gate_up, first + e))
+        y = linear_ops.linear(_expert_act(inner, act, gated),
+                              _take_expert(down, first + e))
+        g_e = jax.lax.dynamic_slice_in_dim(gate, e, 1, axis=1)   # [N, 1]
+        return acc + g_e * y.astype(jnp.float32)
+
+    acc = jnp.zeros((h.shape[0], down.shape[-1]), jnp.float32)
+    return jax.lax.fori_loop(0, n_hit, one, acc), n_hit
+
+
 def moe_ffn(h, w, idx, gate_up, down, act, n_experts: int, gated=True,
             up_bias=None, down_bias=None):
     """Route by static pair count and mesh: gather (decode), ragged

@@ -118,6 +118,30 @@ def _dequant_tile(codes, scales, zeros, qtype: str, bs: int, bk: int, bn: int,
     return w.reshape(bk, bn)
 
 
+def tile_plan(k: int, n: int, bs: int) -> tuple[int, int, int, int]:
+    """``(bk, kp, bn, np_)``: the K and N tiles of a ``[k, n]`` weight and
+    the sizes its planes are padded to so that the tiles cover them exactly.
+
+    N is padded to the lane width only and tiled by the widest of 512, 384,
+    256, 128 that divides it.  K up to 2048 is one tile; past that the tile
+    is the largest multiple of 256 (a whole number of scale sublanes) between
+    1024 and 2048 that divides K, K being padded by as little as gives one.
+    Widths that are multiples of 2048 and 512 keep the tiles they had.  The
+    planes are padded ONCE, when the tree is built (``quantize/core.py``
+    ``pad_planes``); a weight that arrives unpadded is padded in the call."""
+    n128 = _round_up(n, 128)
+    bn = n128 if n128 <= 512 else next(
+        c for c in (512, 384, 256, 128) if n128 % c == 0)
+    if k <= 2048:
+        return k, k, bn, n128
+    kp = _round_up(k, 256)
+    while True:
+        for bk in range(2048, 1023, -256):
+            if kp % bk == 0:
+                return bk, kp, bn, n128
+        kp += 256
+
+
 def _make_kernel(qtype, bs, bk, bn, compute_dtype, has_high, has_zeros):
     def kern(*refs):
         x_ref, d_ref = refs[0], refs[1]
@@ -160,14 +184,14 @@ def _qmatmul_2d(x, data, scales, zeros, *, qtype: str, bs: int,
     num, den = _data_row_factor(qtype)
 
     bm = min(128, _round_up(m, 16))
-    bn = min(512, _round_up(n, 128))
     # K tile: whole quantization blocks, target ~2048 contraction rows
-    bk = min(k, _round_up(min(k, 2048), bs))
+    bk, kp, bn, np_ = tile_plan(k, n, bs)
 
     # pad every dim so grid blocks tile exactly (zero scale rows/cols are
     # numerically inert: dequant yields w=0 there for all supported formats
-    # except asym_int4/5, whose zero-point plane is also zero-padded)
-    mp, kp, np_ = _round_up(m, bm), _round_up(k, bk), _round_up(n, bn)
+    # except asym_int4/5, whose zero-point plane is also zero-padded).
+    # Planes padded at build (core.pad_planes) arrive at (kp, np_) already.
+    mp = _round_up(m, bm)
     if mp != m or kp != k:
         x = jnp.pad(x, ((0, mp - m), (0, kp - k)))
     high = None
@@ -230,6 +254,139 @@ def _qmatmul_2d(x, data, scales, zeros, *, qtype: str, bs: int,
         name="qmatmul",
     )(*args)
     return out[:m, :logical_out]
+
+
+def _f16_bits_to_f32(h):
+    """fp16 bit patterns (int32 in [0, 65536)) -> float32, by integer
+    arithmetic: Mosaic loads no f16 vector on this chip, so a kernel that
+    reads the fp16 scale planes where they lie takes them as uint16."""
+    sign = jnp.where((h & 0x8000) != 0, -1.0, 1.0)
+    mag = h & 0x7FFF
+    normal = jax.lax.bitcast_convert_type((mag << 13) + (112 << 23),
+                                          jnp.float32)
+    subnormal = (mag & 0x3FF).astype(jnp.float32) * (2.0 ** -24)
+    return sign * jnp.where(mag >= 0x400, normal, subnormal)
+
+
+def _grouped_kernel(ids_ref, live_ref, x_ref, d_ref, s_ref, o_ref, *, qtype,
+                    bs, bk, bn, compute_dtype):
+    del ids_ref
+    g, ki = pl.program_id(0), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _():
+        o_ref[:] = jnp.zeros_like(o_ref)
+
+    @pl.when(g < live_ref[0])
+    def _():
+        scales = _f16_bits_to_f32(s_ref[:].astype(jnp.int32))
+        w = _dequant_tile(d_ref[:], scales, None, qtype, bs, bk,
+                          bn).astype(compute_dtype)
+        o_ref[:] += jnp.dot(x_ref[:].astype(compute_dtype), w,
+                            preferred_element_type=jnp.float32)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("qtype", "bs", "logical_out", "compute_dtype")
+)
+def _qmatmul_grouped(x, data, scale_bits, ids, n_live, *, qtype: str, bs: int,
+                     logical_out: int, compute_dtype):
+    """One call over a GROUP of entries of a stack of planes (an expert
+    layer's hit experts): ``data`` [E, K/2, N] stays whole in HBM and slot
+    ``g`` of the grid's first axis reads entry ``ids[g]`` of it through the
+    index maps; ``scale_bits`` [G, K/bs, N] are the slots' own fp16 scales
+    as uint16 bit patterns (Mosaic takes no fp16 argument on this chip, and
+    a bitcast of the WHOLE scale stack is a pass over it every tick, so the
+    caller gathers the slots' planes, a twelfth of the codes' bytes).
+    Only the first ``n_live`` slots are live: a dead slot computes nothing,
+    writes zeros, and its index maps name the block the step before it
+    held, so nothing is fetched for it.  ``x`` is [M, K] (every slot reads
+    it) or [G, M, K] (slot g reads its own).  Returns [G, M, logical_out]
+    float32.  The planes tile exactly (padded at build)."""
+    per_slot = x.ndim == 3
+    m, k = x.shape[-2:]
+    slots, n = ids.shape[0], data.shape[2]
+    bm = min(128, _round_up(m, 16))
+    bk, kp, bn, np_ = tile_plan(k, n, bs)
+    if (kp, np_) != (k, n) or data.shape[1] * 2 != k:
+        raise ValueError(
+            f"stacked planes [{data.shape[1] * 2}, {n}] are not padded to "
+            f"the kernel's tiles ({kp}, {np_}): pad them at build "
+            "(quantize.core.pad_planes)")
+    mp = _round_up(m, bm)
+    if mp != m:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, mp - m), (0, 0)])
+    nk, nn = kp // bk, np_ // bn
+
+    def held(g, live, i, last):
+        # a dead slot stays on the last block of the last live one
+        return jnp.where(g < live[0], i, last)
+
+    def plane(g, mi, ni, ki, ids, live):
+        return ids[g], held(g, live, ki, nk - 1), held(g, live, ni, nn - 1)
+
+    def slot_plane(g, mi, ni, ki, ids, live):
+        return (jnp.minimum(g, jnp.maximum(live[0] - 1, 0)),
+                held(g, live, ki, nk - 1), held(g, live, ni, nn - 1))
+
+    if per_slot:
+        x_spec = pl.BlockSpec(
+            (None, bm, bk), lambda g, mi, ni, ki, ids, live: (
+                jnp.minimum(g, jnp.maximum(live[0] - 1, 0)), mi,
+                held(g, live, ki, nk - 1)))
+    else:
+        x_spec = pl.BlockSpec(
+            (bm, bk), lambda g, mi, ni, ki, ids, live: (
+                mi, held(g, live, ki, nk - 1)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(slots, mp // bm, nn, nk),
+        in_specs=[x_spec,
+                  pl.BlockSpec((None, bk // 2, bn), plane),
+                  pl.BlockSpec((None, bk // bs, bn), slot_plane)],
+        out_specs=pl.BlockSpec(
+            (None, bm, bn), lambda g, mi, ni, ki, ids, live: (g, mi, ni)),
+    )
+    out = pl.pallas_call(
+        functools.partial(_grouped_kernel, qtype=qtype, bs=bs, bk=bk, bn=bn,
+                          compute_dtype=compute_dtype),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((slots, mp, np_), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "parallel", "parallel",
+                                 "arbitrary"),
+        ),
+        interpret=_interpret(),
+        name="qmatmul_expert",
+    )(ids.astype(jnp.int32), jnp.reshape(n_live, (1,)).astype(jnp.int32), x,
+      data, scale_bits)
+    return out[:, :m, :logical_out]
+
+
+def grouped_supported(qt: QTensor) -> bool:
+    """Whether :func:`qmatmul_pallas_grouped` covers this stack: sym_int4
+    planes [E, K/2, N] with fp16 scales, padded to the kernel's tiles."""
+    if (qt.qtype != "sym_int4" or qt.data.ndim != 3
+            or qt.scales.dtype != jnp.float16):
+        return False
+    k, n = qt.data.shape[1] * 2, qt.data.shape[2]
+    _, kp, _, np_ = tile_plan(k, n, qt.block_size)
+    return (kp, np_) == (k, n)
+
+
+def qmatmul_pallas_grouped(x: jnp.ndarray, qt: QTensor, ids, n_live,
+                           compute_dtype=jnp.bfloat16):
+    """x @ dequant(qt[ids[g]]) for the first ``n_live`` slots g of ``ids``
+    (traced int32 [G]; the rest give zeros): x [M, in] for every slot, or
+    [G, M, in] a slot's own.  ``qt`` is a stacked weight (an expert layer's
+    ``[E, ...]`` planes), read in place.  Returns [G, M, out] float32."""
+    k_pad = qt.data.shape[1] * 2
+    if x.shape[-1] != k_pad:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, k_pad - x.shape[-1])])
+    return _qmatmul_grouped(
+        x, qt.data, jax.lax.bitcast_convert_type(qt.scales[ids], jnp.uint16),
+        ids, n_live, qtype=qt.qtype, bs=qt.block_size,
+        logical_out=qt.out_features, compute_dtype=compute_dtype)
 
 
 def qmatmul_pallas(x: jnp.ndarray, qt: QTensor, compute_dtype=jnp.bfloat16,

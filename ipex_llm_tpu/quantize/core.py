@@ -680,6 +680,41 @@ def quantize(w: Any, qtype: str, block_size: int | None = None, *,
     return QTensor(data, scales, zeros, info.name, tuple(w.shape), bs)
 
 
+def kernel_padded_shape(k: int, n: int, bs: int) -> tuple[int, int]:
+    """The (in, out) a 4-bit weight's planes are padded to at build so that
+    the dequant-matmul kernel's tiles (ops/pallas/qmatmul.py ``tile_plan``)
+    cover them exactly and no call pads a weight: out to the lane width, in
+    to 512 (16 rows of fp16 scales, one tile of them: the TPU lays an array
+    whose second-minor extent is no whole tile out with another axis there,
+    and a stacked scale plane [.., 60, N] was re-laid out, whole, every
+    tick) and then to a whole number of K tiles."""
+    from ipex_llm_tpu.ops.pallas.qmatmul import tile_plan
+
+    kb = k if k <= 128 else -(-k // 512) * 512
+    _, kp, _, np_ = tile_plan(kb, n, bs)
+    return kp, np_
+
+
+def pad_planes(qt: QTensor) -> QTensor:
+    """``qt`` with its planes zero-padded to :func:`kernel_padded_shape`;
+    the logical ``shape`` stays.  Padded scale rows and columns are zero, so
+    a padded code dequantizes to 0 whatever its bits; ``dequantize`` and the
+    kernel cut the logical weight back out.  Sym 4-bit formats only (the
+    formats with no zero plane); any leading axes (layer, expert) ride."""
+    if qt.qtype not in ("sym_int4", "nf4", "fp4"):
+        return qt
+    k, n = qt.data.shape[-2] * 2, qt.data.shape[-1]
+    kp, np_ = kernel_padded_shape(k, n, qt.block_size)
+    if (kp, np_) == (k, n):
+        return qt
+    lead = [(0, 0)] * (qt.data.ndim - 2)
+    data = jnp.pad(qt.data, lead + [(0, (kp - k) // 2), (0, np_ - n)])
+    scales = jnp.pad(qt.scales, lead + [
+        (0, kp // qt.block_size - qt.scales.shape[-2]), (0, np_ - n)])
+    return QTensor(data, scales, None, qt.qtype, qt.shape, qt.block_size,
+                   qt.tp_mode)
+
+
 @partial(jax.jit, static_argnames=("dtype",))
 def dequantize(qt: QTensor, dtype=jnp.float32) -> jnp.ndarray:
     """Reconstruct the float weight ``[in_features, out_features]``."""
@@ -705,4 +740,7 @@ def dequantize(qt: QTensor, dtype=jnp.float32) -> jnp.ndarray:
         out = kquants.dequantize(qt)
     else:
         raise ValueError(f"cannot dequantize {qt.qtype}")
-    return out[: qt.in_features].astype(dtype)
+    out = out[: qt.in_features]
+    if out.shape[1] != qt.out_features:   # planes padded for the kernel
+        out = out[:, : qt.out_features]
+    return out.astype(dtype)

@@ -42,7 +42,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ipex_llm_tpu.hostutil import d2h, h2d
-from ipex_llm_tpu.kv import PagedKVCache, paged_page_bytes
+from ipex_llm_tpu.kv import (STATE_COUNTERS, PagedKVCache, paged_page_bytes,
+                             recurrent_state, state_bytes,
+                             with_recurrent_state)
 from ipex_llm_tpu.models.config import ModelConfig
 from ipex_llm_tpu.models.decoder import decoder_forward
 from ipex_llm_tpu.serving.faults import (EngineOverloaded, FaultInjector,
@@ -525,6 +527,9 @@ def _decode_horizon_loop(cfg: ModelConfig, params, cache, toks, row_lens,
         logits, cache = decoder_forward(
             cfg, params, toks[:, None], cache, row_lens[:, None],
             last_token_only=True, slot_offsets=write_at,
+            # recurrent state must not advance for a dead row (KV has the
+            # scratch page for that; a state has no scratch slot)
+            chunk_lens=alive.astype(jnp.int32) if cfg.ssm_layers else None,
         )
         key, sub = jax.random.split(key)
         nxt, lp = sample_rows_with_logprobs(logits, temps, top_ps, sub,
@@ -932,7 +937,7 @@ def _tick_body(cfg: ModelConfig, params, cache, toks, row_lens,
                active, temps, top_ps, key, seeds, steps, top_ks,
                eos, remain, prefill=None, horizon: int = 1,
                with_decode: bool = True, hist=None, spec_ks=None,
-               spec_k: int = 0, spec_ngram: int = 3):
+               spec_k: int = 0, spec_ngram: int = 3, state_src=None):
     """The fused tick BODY (see ``_ragged_tick`` for the contract):
     traced either directly under GSPMD dispatch, or — the manual-mesh
     serving form — once per shard inside ``parallel/manual.tp_tick``'s
@@ -941,6 +946,23 @@ def _tick_body(cfg: ModelConfig, params, cache, toks, row_lens,
 
     r = toks.shape[0]
     first_t = first_lp = None
+    if state_src is not None:
+        # a hybrid model's recurrent state: READ from the committed buffer
+        # (``state_src``, not donated: the tick's checkpoint holds it for a
+        # rollback) and WRITTEN to the spare one (``cache.state``,
+        # donated).  A steady tick touches every layer's slots exactly
+        # once, so each layer reads one buffer and writes the other and
+        # nothing is copied; any other tick copies once, then works in
+        # place.
+        if prefill is None and horizon == 1 and with_decode:
+            cache = replace(cache, state=dict(
+                cache.state, stats=state_src["stats"], src=state_src))
+        else:
+            # INTO the donated buffers (a fresh copy would be a third state
+            # on the device beside the source and the unused spare)
+            cache = replace(cache, state=jax.tree_util.tree_map(
+                lambda dst, src: jax.lax.dynamic_update_slice(
+                    dst, src, (0,) * dst.ndim), cache.state, state_src))
     if prefill is not None:
         (p_tokens, p_tables, p_base, p_nvalid, p_emit, p_canjoin,
          p_rowmap) = prefill
@@ -953,8 +975,10 @@ def _tick_body(cfg: ModelConfig, params, cache, toks, row_lens,
                 slot_offsets=p_base,
                 gather_positions=jnp.maximum(p_nvalid - 1, 0),
                 chunk_lens=p_nvalid,
+                state_rows=p_rowmap if cfg.ssm_layers else None,
             )
-            cache = replace(cache, k=row_cache.k, v=row_cache.v)
+            cache = replace(cache, k=row_cache.k, v=row_cache.v,
+                            state=row_cache.state)
             key, sub = jax.random.split(key)
             first_t, first_lp = sample_rows_with_logprobs(
                 logits, temps[p_rowmap], top_ps[p_rowmap], sub,
@@ -1004,6 +1028,9 @@ def _tick_body(cfg: ModelConfig, params, cache, toks, row_lens,
             tok_block = jnp.zeros((r, horizon), jnp.int32)
             lp_block = jnp.zeros((r, horizon), jnp.float32)
             n_exec = jnp.asarray(0, jnp.int32)
+    if cfg.ssm_layers:
+        # the state's counters ride the fetch the host already makes
+        n_exec = jnp.concatenate([n_exec[None], cache.state["stats"]])
     if spec_k > 0:
         return (first_t, first_lp, tok_block, lp_block, n_exec, cache,
                 toks, row_lens, active, steps, remain, key, take_block,
@@ -1024,7 +1051,7 @@ def _ragged_tick(cfg: ModelConfig, params, cache, toks, row_lens,
                     with_decode: bool = True, hist=None, spec_ks=None,
                     spec_k: int = 0, spec_ngram: int = 3, mesh=None,
                     tp_manual: bool = False,
-                    collective_qtype: str = "bf16"):
+                    collective_qtype: str = "bf16", state_src=None):
     """ONE device program per engine tick, whatever the admission mix —
     the ragged-paged-attention superkernel tick (ROADMAP item 1; the
     JP106 gate counts exactly this entry).
@@ -1070,6 +1097,11 @@ def _ragged_tick(cfg: ModelConfig, params, cache, toks, row_lens,
     very first decode iteration.  The return tuple then extends to
     (..., key, take_block [R, H], hist, draft_proposed, draft_accepted)
     with [R, H, spec_k+1] token/logprob blocks.
+
+    ``state_src``: a hybrid model's committed recurrent state (kv.py), the
+    one argument of the tick that is neither donated nor rebound: the tick
+    reads it and writes ``cache.state`` (see ``_tick_body``); the n_exec
+    slot then carries [steps executed, the state's counters].
     """
     from ipex_llm_tpu.ops import dispatch
 
@@ -1088,7 +1120,8 @@ def _ragged_tick(cfg: ModelConfig, params, cache, toks, row_lens,
             cfg, params, cache, toks, row_lens, active, temps, top_ps,
             key, seeds, steps, top_ks, eos, remain, prefill=prefill,
             horizon=horizon, with_decode=with_decode, hist=hist,
-            spec_ks=spec_ks, spec_k=spec_k, spec_ngram=spec_ngram)
+            spec_ks=spec_ks, spec_k=spec_k, spec_ngram=spec_ngram,
+            state_src=state_src)
 
 
 # The entry's name until PR 25, kept for what imports it from outside the
@@ -1156,7 +1189,7 @@ class ServingEngine:
         # storage width — the unit kv_pool_bytes divides by (validates
         # kv_storage, raising with the valid names)
         self.page_bytes = paged_page_bytes(
-            cfg.num_layers, cfg.num_kv_heads, self.ec.page_size,
+            cfg.kv_layers, cfg.num_kv_heads, self.ec.page_size,
             cfg.head_dim, v_head_dim=cfg.v_dim,
             storage=self.ec.kv_storage)
         if self.ec.kv_pool_bytes:
@@ -1254,10 +1287,23 @@ class ServingEngine:
         self.mesh = mesh if (mesh is not None and mesh.size > 1) else None
         r = self.ec.max_rows
         cache = PagedKVCache.init(
-            cfg.num_layers, self.ec.n_pages, r, self.ec.max_pages,
+            cfg.kv_layers, self.ec.n_pages, r, self.ec.max_pages,
             cfg.num_kv_heads, self.ec.page_size, cfg.head_dim,
             v_head_dim=cfg.v_dim, storage=self.ec.kv_storage,
         )
+        # a hybrid model's second kind of state, beside the pool in the
+        # same cache object: per-ROW recurrent slots.  Two buffers: the
+        # committed one in ``cache.state`` and a spare the next tick writes
+        # (``_state_call``), so the state a tick started from stays whole
+        # until the tick commits.  What recurrent state cannot do yet is
+        # refused here by name (derived from the model, no config field)
+        self._recurrent = cfg.ssm_layers > 0
+        self._state_spare = self._state_prev = None
+        self._stats_seen = np.zeros((len(STATE_COUNTERS),), np.uint32)
+        if self._recurrent:
+            self._refuse_for_recurrent_state(cfg, mesh)
+            cache = with_recurrent_state(cache, cfg, r)
+            self._state_spare = recurrent_state(cfg, r)
         # multi-chip serving: on a PURE-tp mesh whose shapes divide, the
         # engine takes the MANUAL tick — the whole fused tick inside one
         # fully-manual shard_map region (parallel/manual.py), per-shard
@@ -1560,6 +1606,68 @@ class ServingEngine:
 
     # -- public API ---------------------------------------------------------
 
+    def _refuse_for_recurrent_state(self, cfg: ModelConfig, mesh):
+        """Engine features a model with recurrent state (Mamba-2 blocks)
+        cannot have yet, each refused by name at construction."""
+        why = (f"model_type {cfg.model_type!r} carries recurrent state "
+               f"({cfg.ssm_layers} Mamba-2 blocks): ")
+        if self.ec.spec_k > 0:
+            raise ValueError(
+                why + "spec_k > 0 is not supported: a rejected draft has "
+                "already advanced the state, and there is no snapshot to "
+                "roll it back to")
+        if self.ec.kv_spill_bytes > 0:
+            raise ValueError(
+                why + "kv_spill_bytes > 0 is not supported: a page of KV "
+                "is useless without the state that follows it, and the "
+                "spill tier holds no state")
+        if mesh is not None and mesh.size > 1:
+            raise ValueError(
+                why + "a mesh is not supported: neither the state nor the "
+                "expert layer is sharded (parallel/manual.py)")
+        budget = (self.ec.prefill_bucket
+                  if self.ec.step_token_budget is None
+                  else int(self.ec.step_token_budget))
+        if budget <= 0:
+            raise ValueError(
+                why + "step_token_budget=0 (the sequential engine) is not "
+                "supported: the state rides the fused tick alone")
+
+    def _state_call(self) -> tuple:
+        """(the cache to hand the tick, its ``state_src``) for a model
+        with recurrent state: the committed state goes in as the read-only
+        source and the spare buffer, in the donated cache, is written.
+        ``_state_done`` rebinds after the call."""
+        if not self._recurrent:
+            return self.cache, None
+        src = self.cache.state
+        if self._state_spare is None or self._state_spare["h"].is_deleted():
+            # a dispatch that raised took the spare with it
+            self._state_spare = recurrent_state(self.cfg, self.ec.max_rows)
+        return replace(self.cache, state=self._state_spare), src
+
+    def _state_done(self, src):
+        """After a hybrid tick's dispatch: the written buffer is in
+        ``self.cache`` already; the one the tick started from is kept for
+        a rollback and becomes the spare at commit."""
+        if src is not None:
+            self._state_spare = None      # donated: gone
+            self._state_prev = src
+
+    def _count_state(self, n_exec) -> int:
+        """Steps executed from the tick's n_exec slot; a hybrid tick's
+        also carries the state's device counters, published as deltas (the
+        device's are int32 and wrap)."""
+        arr = np.asarray(n_exec).reshape(-1)
+        if arr.size > 1:
+            seen = arr[1:].astype(np.uint32)
+            delta = (seen - self._stats_seen).astype(np.uint32)
+            self._stats_seen = seen
+            for name, d in zip(STATE_COUNTERS, delta):
+                self.metrics[name + "_total"] = (
+                    self.metrics.get(name + "_total", 0) + int(d))
+        return int(arr[0])
+
     def start(self):
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
@@ -1661,6 +1769,12 @@ class ServingEngine:
             "prefix_evictions": a.prefix_evictions,
             "alloc_fail_clamps": self.metrics.get("alloc_fail_clamps", 0),
             "horizon_clamped": self.metrics.get("horizon_clamped", 0),
+            # a hybrid model's recurrent state beside the pool (both
+            # buffers); with one, a page of KV is useless without the
+            # state that follows it, so the prefix cache is off
+            "state_cache_bytes": 2 * state_bytes(self.cache.state),
+            "prefix_cache": ("off: recurrent state" if self._recurrent
+                             else "on"),
         }
         # spill-tier block (flat numeric keys ride the replica /metrics
         # exposition and the router's fleet aggregation unchanged; the
@@ -2056,6 +2170,11 @@ class ServingEngine:
                       dict(self.alloc._page_key),
                       self.alloc.prefix_evictions),
             "key": self.key,
+            # recurrent state is covered BY REFERENCE: the tick reads this
+            # buffer and writes the spare one (_state_call), so restoring
+            # the reference is the whole rollback
+            "state": self.cache.state,
+            "stats_seen": self._stats_seen.copy(),
             "metrics": dict(self.metrics),
             "ttfts": list(self._ttfts),
             "spec_window": list(self._spec_window),
@@ -2106,6 +2225,13 @@ class ServingEngine:
             # tick's KV writes past the committed row_lens
             self.pagestore.restore(snap["pagestore"])
         self.key = snap["key"]
+        if self._recurrent:
+            if self._state_prev is not None:
+                # the doomed tick wrote the other buffer: it is the spare
+                self._state_spare = self.cache.state
+                self._state_prev = None
+            self.cache = replace(self.cache, state=snap["state"])
+            self._stats_seen = snap["stats_seen"].copy()
         # the rolling TTFT window reverts too: a first token recorded by
         # the doomed tick (or a bisection probe) was never emitted, and the
         # retried tick will record it again
@@ -2157,6 +2283,9 @@ class ServingEngine:
         staged, self._staging = self._staging, None
         staged_spans, self._span_staging = self._span_staging, []
         self._tick_arrivals = []
+        if self._state_prev is not None:
+            # the state this tick started from is the next tick's spare
+            self._state_spare, self._state_prev = self._state_prev, None
         for q, item in staged:
             q.put(item)
         if self.tracer is not None:
@@ -2591,6 +2720,10 @@ class ServingEngine:
         handoff).  Returns None when no full page is cached.  Runs on
         the engine thread between ticks (epoch-boundary gathers, not
         tick work — JP106 unchanged)."""
+        if self._recurrent:
+            raise NotImplementedError(
+                "KV handoff for a model with recurrent state: a page of KV "
+                "is useless without the state that follows it")
         ids = np.asarray(list(prompt_ids), np.int32)
         return self.run_on_engine(lambda: self._export_prefix_op(ids, wire))
 
@@ -2658,6 +2791,10 @@ class ServingEngine:
         fused tick with only the uncovered tail left to prefill.  A dry
         pool stops the import early (what fit is registered).  Runs on
         the engine thread between ticks."""
+        if self._recurrent:
+            raise NotImplementedError(
+                "KV handoff for a model with recurrent state: a page of KV "
+                "is useless without the state that follows it")
         return self.run_on_engine(lambda: self._import_pages_op(blob))
 
     def _import_pages_op(self, blob: bytes) -> dict:
@@ -2871,7 +3008,10 @@ class ServingEngine:
             # prefix cache: reuse the longest chain of full pages covering
             # at most the first n_p - 1 tokens (at least one token must run
             # through the model to produce logits)
-            keys = _chain_hashes(prompt, ps)
+            # (off for a model with recurrent state: no lookup here and,
+            # with no keys kept, no registration at _finish_prompt; every
+            # prompt prefills from position 0)
+            keys = [] if self._recurrent else _chain_hashes(prompt, ps)
             shareable = min(len(keys), (n_p - 1) // ps)
             # plan the chain: device prefix hits take their row ref
             # immediately (protecting them from the batched promotion's
@@ -3645,19 +3785,22 @@ class ServingEngine:
                     collective_qtype=self._collective_qtype)
             self._tick_dispatches += 1
         else:
+            cache_in, state_src = self._state_call()
             with self._perf_dispatch("tick.admission", point=perf_pt,
                                      rows_active=rows_active):
                 (first_t, first_lp, tok_block, lp_block, n_exec,
                  self.cache, dev["toks"], dev["row_lens"], dev["active"],
                  dev["steps"], dev["remain"], self.key) = _ragged_tick(
-                    self.cfg, self.params, self.cache, dev["toks"],
+                    self.cfg, self.params, cache_in, dev["toks"],
                     dev["row_lens"], dev["active"], dev["temps"],
                     dev["top_ps"], self.key, dev["seeds"], dev["steps"],
                     dev["top_ks"], dev["eos"], dev["remain"],
                     prefill=prefill, horizon=1,
                     with_decode=with_decode, mesh=self.mesh,
                     tp_manual=self._tp_manual,
-                    collective_qtype=self._collective_qtype)
+                    collective_qtype=self._collective_qtype,
+                    state_src=state_src)
+            self._state_done(state_src)
             self._tick_dispatches += 1
         self._phase_to("drain")
         # advance bookkeeping; completed prompts run the shared
@@ -3696,7 +3839,7 @@ class ServingEngine:
                 nxt, lp = d2h(first_t), d2h(first_lp)
             tok_np = d2h(tok_block)  # jaxlint: disable=JL002 -- THE per-tick designed sync: one blocking materialization for the whole fused tick
             lp_np = d2h(lp_block)  # jaxlint: disable=JL002 -- rides THE per-tick sync above (same dispatched program)
-            executed = int(d2h(n_exec))  # jaxlint: disable=JL002 -- rides THE per-tick sync: 0 only when no row decoded
+            executed = self._count_state(d2h(n_exec))  # jaxlint: disable=JL002 -- rides THE per-tick sync: 0 only when no row decoded
         for i, row in completing:
             self._finish_prompt(row, int(nxt[i]), float(lp[i]))
             if not canjoin[i] and self.rows[row] is not None:
@@ -3848,18 +3991,21 @@ class ServingEngine:
             # to exactly 1 (the decode stage traces _decode_horizon_loop,
             # so output is bit-identical to the historical
             # _decode_multi_step program)
+            cache_in, state_src = self._state_call()
             with self._perf_dispatch("tick.steady", point=perf_pt,
                                      rows_active=rows_active):
                 (_, _, tok_block, lp_block, n_exec, self.cache,
                  dev["toks"], dev["row_lens"], dev["active"],
                  dev["steps"], dev["remain"], self.key) = _ragged_tick(
-                    self.cfg, self.params, self.cache, dev["toks"],
+                    self.cfg, self.params, cache_in, dev["toks"],
                     dev["row_lens"], dev["active"], dev["temps"],
                     dev["top_ps"], self.key, dev["seeds"], dev["steps"],
                     dev["top_ks"], dev["eos"], dev["remain"],
                     prefill=None, horizon=h, mesh=self.mesh,
                     tp_manual=self._tp_manual,
-                    collective_qtype=self._collective_qtype)
+                    collective_qtype=self._collective_qtype,
+                    state_src=state_src)
+            self._state_done(state_src)
             self._tick_dispatches += 1
             # the returned cache owns the (donated) tables buffer now
         self._phase_to("drain")
@@ -3868,7 +4014,7 @@ class ServingEngine:
             lp_block = d2h(lp_block)  # jaxlint: disable=JL002 -- rides THE per-horizon sync above (same dispatched program)
             if not self._pp_mode:
                 # jaxlint: disable=JL002 -- rides THE per-horizon sync: < h only if every row died early
-                executed = int(d2h(n_exec))
+                executed = self._count_state(d2h(n_exec))
         if self.perf is not None:
             # the MFU join's loop multiplier: XLA's cost analysis counts
             # the horizon body once, the tick executed it `executed` times

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ipex_llm_tpu.generation import GenerationConfig, generate
-from ipex_llm_tpu.kv import make_cache
+from ipex_llm_tpu.kv import make_cache, with_recurrent_state
 from ipex_llm_tpu.models import serialize
 from ipex_llm_tpu.models.build import build_params
 from ipex_llm_tpu.models.config import ModelConfig
@@ -289,11 +289,11 @@ class TPUModelForCausalLM:
         if tokens.ndim == 1:
             tokens = tokens[None]
         b, t = tokens.shape
-        cache = make_cache(
-            "normal", self.config.num_layers, b, max(t, 1),
+        cache = with_recurrent_state(make_cache(
+            "normal", self.config.kv_layers, b, max(t, 1),
             self.config.num_kv_heads, self.config.head_dim,
             v_head_dim=self.config.v_dim,
-        )
+        ), self.config, b)
         pos = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
         tokens_j = jnp.asarray(tokens)
         from ipex_llm_tpu.ops import dispatch as _dispatch
