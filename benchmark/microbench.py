@@ -212,6 +212,49 @@ def bench_ragged_attn(r, hq, hkv, maxp, ps, d, width, dtype=jnp.bfloat16,
             "xla_gbs": round(nbytes / tr / 1e9, 1)}
 
 
+def bench_ragged_walk(kv_len, width, dead=0, r=16, hq=32, hkv=8, ps=128,
+                      d=128, layers=8, dtype=jnp.bfloat16, iters=50):
+    """The decode stage's attention as a tick runs it: ``r`` rows of one
+    query each, ``dead`` of them idle (``chunk_len`` 0 at the scratch
+    sentinel), every live row ``kv_len`` slots long in a table ``width``
+    pages wide, over a stack of ``layers`` read one layer after another.
+    What the page walk must show on the chip: the time follows the live
+    pages (``kv_len``), not the table's ``width`` and not the dead rows;
+    the bytes are the live pages' K and V, against the peak HBM rate."""
+    rng = np.random.default_rng(4)
+    live_pages = -(-kv_len // ps)
+    n_pages = 1 + r * live_pages
+    tables = np.full((r, width), -1, np.int32)
+    tables[:, :live_pages] = 1 + rng.permutation(
+        r * live_pages).reshape(r, live_pages)
+    shape = (layers, n_pages, hkv, ps, d)
+    k = jnp.asarray(rng.standard_normal(shape, np.float32)).astype(dtype)
+    v = jnp.asarray(rng.standard_normal(shape, np.float32)).astype(dtype)
+    q = jnp.asarray(rng.standard_normal((r, 1, hq, d)), jnp.bfloat16)
+    alive = np.arange(r) >= dead
+    lens = jnp.asarray(np.where(alive, kv_len, width * ps), jnp.int32)
+    chunk = jnp.asarray(alive, jnp.int32)
+    tables = jnp.asarray(tables)
+    nbytes = (2 * int(alive.sum()) * live_pages * ps * hkv * d
+              * k.dtype.itemsize)
+
+    @jax.jit
+    def stack_walk(q, k, v):
+        def one(acc, layer):
+            return acc + ragged_paged_sdpa(q, k, v, tables, lens, chunk,
+                                           layer=layer), None
+        return jax.lax.scan(one, jnp.zeros_like(q), jnp.arange(layers))[0]
+
+    t = timeit(stack_walk, q, k, v, iters=iters) / layers
+    print(f"ragged_walk R={r} dead={dead} Hq={hq} Hkv={hkv} kv_len={kv_len} "
+          f"table={width}x{ps} D={d} {k.dtype}: {t*1e6:8.1f}us a layer "
+          f"({nbytes/t/1e9:6.1f} GB/s of live pages)")
+    return {"op": (f"ragged_walk_r{r}_dead{dead}_h{hq}/{hkv}_len{kv_len}"
+                   f"_w{width}_d{d}_{k.dtype.name}"),
+            "pallas_us": round(t * 1e6, 1),
+            "pallas_gbs": round(nbytes / t / 1e9, 1)}
+
+
 def bench_spec_verify(r, hq, hkv, maxp, ps, d, k_spec, dtype=jnp.bfloat16,
                       iters=50):
     """The on-device speculative verify shape: ONE [R, k+1] ragged
@@ -331,6 +374,8 @@ def collect(iters: int = 20) -> list[dict]:
              {"iters": iters}),
             (bench_ragged_attn, (16, 32, 8, 16, 128, 128, 32),
              {"dtype": jnp.float8_e5m2, "iters": iters}),
+            # the tick's decode stage: short rows in a full-width table
+            (bench_ragged_walk, (512, 32), {"iters": iters}),
             # speculative verify: one [R, k+1] pass vs k+1 decode passes
             (bench_spec_verify, (16, 32, 8, 16, 128, 128, 4),
              {"iters": iters}),
@@ -363,6 +408,9 @@ def collect(iters: int = 20) -> list[dict]:
             (bench_ragged_attn, (2, 8, 4, 4, 32, 64, 8), {"iters": 2}),
             (bench_ragged_attn, (2, 8, 4, 4, 32, 64, 8),
              {"dtype": jnp.float8_e5m2, "iters": 2}),
+            (bench_ragged_walk, (40, 4),
+             {"dead": 1, "r": 2, "hq": 8, "hkv": 4, "ps": 32, "d": 64,
+              "layers": 2, "iters": 2}),
             # speculative verify (interpret record)
             (bench_spec_verify, (2, 8, 4, 4, 32, 64, 3), {"iters": 2}),
         ]
@@ -409,6 +457,12 @@ if __name__ == "__main__":
     # ragged superkernel batch (mixed decode + prefill rows), bf16 vs fp8
     bench_ragged_attn(16, 32, 8, 16, 128, 128, 32)
     bench_ragged_attn(16, 32, 8, 16, 128, 128, 32, jnp.float8_e5m2)
+    # the page walk at Mistral's decode shapes: the same rows in a table of
+    # 8 and of 32 pages, cache lengths (the long-cache stand-in), dead rows
+    for kv_len, width, dead in ((512, 8, 0), (512, 32, 0), (1024, 8, 0),
+                                (1024, 32, 0), (4096, 32, 0), (512, 32, 10),
+                                (4096, 32, 10)):
+        bench_ragged_walk(kv_len, width, dead)
     # speculative verify: one [R, k+1] pass vs the k+1-step decode chain
     bench_spec_verify(16, 32, 8, 16, 128, 128, 4)
     bench_spec_verify(16, 32, 8, 16, 128, 128, 4, jnp.float8_e5m2)

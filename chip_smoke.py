@@ -216,19 +216,35 @@ def kernel_cases(cfg, params, engine: dict, chunk: int = 128,
                    f"{qt.out_features}].m{m}", QMATMUL_TOL, qmatmul)
 
     # ragged paged attention: every row owns a shuffled set of pages; rows
-    # mix long and short histories, one row is idle (chunk_len 0)
+    # mix long and short histories, one row is idle (chunk_len 0); then
+    # short rows in the same wide table with several dead rows among them
     r, ps = engine["max_rows"], engine["page_size"]
     maxp = engine["max_seq_len"] // ps
     hq, hkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     tables = (1 + rng.permutation(r * maxp)).reshape(r, maxp).astype(np.int32)
-    for c in (1, chunk):
+    def full_range(c):
+        kv_len = rng.integers(c, maxp * ps + 1, r).astype(np.int32)
+        kv_len[0] = maxp * ps              # one row at full capacity
+        chunk_len = rng.integers(1, c + 1, r).astype(np.int32)
+        chunk_len[1] = c
+        chunk_len[-1] = 0                  # idle row
+        return kv_len, chunk_len
+
+    def short_wide(c):
+        # a dense model's decode stage: rows a few pages long in the
+        # full-width table, one ending exactly on a page, one of a single
+        # token, every third row dead at the scratch sentinel
+        kv_len = rng.integers(1, min(4, maxp) * ps + 1, r).astype(np.int32)
+        kv_len[0], kv_len[1] = ps, 1
+        chunk_len = np.where(np.arange(r) % 3 == 2, 0, c).astype(np.int32)
+        return np.where(chunk_len > 0, kv_len, maxp * ps), chunk_len
+
+    mixes = [(f"c{c}", c, full_range) for c in (1, chunk)]
+    mixes.append(("short_wide", 1, short_wide))
+    for mix, c, lengths in mixes:
         for storage, dtype in KV_STORAGE_DTYPES.items():
-            def ragged(c=c, storage=storage, dtype=dtype):
-                kv_len = rng.integers(c, maxp * ps + 1, r).astype(np.int32)
-                kv_len[0] = maxp * ps              # one row at full capacity
-                chunk_len = rng.integers(1, c + 1, r).astype(np.int32)
-                chunk_len[1] = c
-                chunk_len[-1] = 0                  # idle row
+            def ragged(c=c, storage=storage, dtype=dtype, lengths=lengths):
+                kv_len, chunk_len = lengths(c)
                 q = bf16(r, c, hq, d)
                 kp = bf16(r * maxp + 1, hkv, ps, d).astype(dtype)
                 vp = bf16(r * maxp + 1, hkv, ps, d).astype(dtype)
@@ -246,13 +262,14 @@ def kernel_cases(cfg, params, engine: dict, chunk: int = 128,
                         q, kd.transpose(0, 2, 1, 3), vd.transpose(0, 2, 1, 3),
                         causal=True, q_positions=jnp.asarray(qpos),
                         kv_len=jnp.asarray(kv_len)), np.float32)
-                check(not got[-1].any(), "ragged: the idle row is not zero")
+                check(not got[chunk_len == 0].any(),
+                      "ragged: an idle row is not zero")
                 # pad queries past a row's chunk_len are garbage by contract
                 valid = (np.arange(c)[None] < chunk_len[:, None]
                          )[:, :, None, None]
                 return _rel_err(np.where(valid, got, 0),
                                 np.where(valid, want, 0))
-            yield f"ragged_paged_sdpa.{storage}.c{c}", ATTN_TOL, ragged
+            yield f"ragged_paged_sdpa.{storage}.{mix}", ATTN_TOL, ragged
 
     # the in-place write: the same rows into a two-layer stack by the kernel
     # and by the XLA scatter, every byte of the live pages alike (row 0's

@@ -178,8 +178,9 @@ class PagedKVCache:
       the pages it reads and nothing else,
     - writes (:meth:`write_layer`) scatter into ``(layer, table[r,
       slot//page], :, slot % page)`` of the carried stack,
-    - reads go through the ragged kernel's index map ``(layer, table[r,
-      pi], head)`` (ops/pallas/ragged_paged_attention.py); the XLA fallback
+    - reads go through the ragged kernel's page walk, one copy of
+      ``pool[layer, table[r, trip]]`` a live page and none for a dead one
+      (ops/pallas/ragged_paged_attention.py); the XLA fallback
       (:meth:`gather_layer`) gathers the row's pages of that layer into the
       head-major ``[R, H, S, D]`` view; invalid tail pages are masked by
       ``kv_len`` exactly like dense-cache slack.

@@ -220,7 +220,7 @@ def cached_sdpa(
         # queries at slots [kv_len - T, kv_len) — the engine's invariant).
         # With ``layer`` (the decoder's scan) kl/vl are the WHOLE stacks
         # [L, P, Hkv, page, D] and that layer of them is read in place,
-        # by the kernel's index map or by the fallback's gather; without,
+        # by the kernel's page copies or by the fallback's gather; without,
         # they are one layer [P, Hkv, page, D].
         # The pool arrives in STORAGE dtype: an fp8(e5m2) pool streams its
         # tiles into the Pallas kernels, which widen to bf16 in-kernel (the

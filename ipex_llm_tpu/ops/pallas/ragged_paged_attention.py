@@ -16,31 +16,49 @@ What it fixes over the split kernels (the BENCH_r05 per-op losses):
   scalar prefetch, so one compiled program serves every (decode, prefill,
   idle) row mix — the causal mask is keyed off ``(kv_len, chunk_len)`` per
   row instead of a uniform static chunk;
-- **fewer pool round-trips per page**: the K/V BlockSpec index maps clamp
-  the page-grid index to the row's LAST VALID page, so the tail of the
-  static ``maxP`` grid re-maps to an already-resident block and Pallas
-  elides the DMA entirely (the old kernel streamed every dead tail page
-  from HBM just to skip its compute);
 - **query-row tiling**: the whole GQA group x chunk tile ``[G*C, D]`` feeds
-  ONE MXU dot per page tile, amortizing each K/V page fetch across every
-  query row that needs it (the split decode kernel issued [G, D] slivers);
+  ONE MXU dot per head and page, amortizing each K/V page fetch across
+  every query row that needs it (the split decode kernel issued [G, D]
+  slivers);
 - **no full-width accumulator re-materialization**: the online-softmax
   state (m/l/acc) lives in VMEM scratch across the page walk and the
-  output tile is written exactly once, at the last page step — versus the
+  output tile is written exactly once, after the last page — versus the
   XLA fallback materializing fp32 ``[R, H, C, maxP*ps]`` score/prob
   tensors over the row's full table width per layer.
 
+**The walk is bounded by what is live** (PR 29).  The grid is rows x
+blocks of kv heads; the table's width is no axis of it.  A grid step reads
+its row's ``kv_len`` and ``chunk_len`` from scalar prefetch and makes
+``cdiv(kv_len, page_size)`` trips of an in-kernel loop, none for an idle
+row: a page past a row's last live one costs no step, and a dead row
+costs one empty step and writes zeros.  The pools stay in HBM
+(``memory_space=pl.ANY``); each trip copies ONE page of the step's kv
+heads — contiguous in ``[L, P, Hkv, ps, D]``, 256 KB of K and as much of V
+for 8 heads of 128 in bf16 — into one of two VMEM slots with
+``make_async_copy`` while the page before it is computed.  How many heads
+a step takes (``_heads_per_step``) follows from the shapes the call sees
+(``Hkv``, ``G*C``, ``D``, the storage dtype) against a VMEM plan: a decode
+tile takes a whole page of heads, a 128-wide prefill chunk fewer; heads
+are independent, so a row's bits do not depend on it.  Pages are visited
+in order 0..last and a row's arithmetic touches nothing of another row:
+its output bits are a function of its own q, pages, ``kv_len`` and
+``chunk_len`` only — not of the number of rows, its neighbours, or the
+table's width (the engine's bit-identity invariants rest on this).  The
+QK dot takes q and K in the compute dtype (bf16) and accumulates in
+float32: the same exact products as the float32 x float32 dot it
+replaces; scores, softmax state and accumulator stay float32.
+
 The pool is addressed by ``(layer, page)``: the kernel takes the WHOLE
 stacked pools ``[L, P, Hkv, ps, D]`` and the layer index as a fourth
-scalar-prefetch operand, and the K/V index map returns ``(layer,
-table[row, page step], head, 0, 0)`` — so the decoder's layer scan never
-slices a layer out of the stack to feed it (kv.py ``PagedKVCache``).  A
-caller holding one layer ``[P, Hkv, ps, D]`` passes it as a stack of one.
-Head sizes that need lane padding (``D % 128``, MLA's 192) pad a copy of
-the pool: for those the one layer is sliced out first, a choice by shape,
-so the pad never touches the stack.
+scalar-prefetch operand, and a page's copy reads ``pool[layer, table[row,
+trip]]`` — so the decoder's layer scan never slices a layer out of the
+stack to feed it (kv.py ``PagedKVCache``).  A caller holding one layer
+``[P, Hkv, ps, D]`` passes it as a stack of one.  Head sizes that need
+lane padding (``D % 128``, MLA's 192) pad a copy of the pool: for those
+the one layer is sliced out first, a choice by shape, so the pad never
+touches the stack.
 
-K/V tiles stream in the pool's STORAGE dtype and widen to the compute
+K/V pages stream in the pool's STORAGE dtype and widen to the compute
 dtype in-kernel: an fp8(e5m2) pool (``EngineConfig.kv_storage="fp8"``)
 costs half the HBM bytes end to end — the paged, ragged form of the
 reference's ``xe_addons.sdp_fp8`` contract (PR 5).
@@ -63,9 +81,64 @@ from ipex_llm_tpu.ops.pallas._compat import (
 )
 
 
-def _kernel(tables_ref, len_ref, chunk_ref, layer_ref, q_ref, k_ref, v_ref,
-            o_ref, m_ref, l_ref, acc_ref, *, scale, ps, c, compute_dtype):
-    """One (row, kv-head, page) grid step of the ragged walk.
+# VMEM a grid step may plan for (K/V page buffers, the q and o blocks, the
+# softmax state): half of the 16 MiB a kernel is given by default, the rest
+# left to the compiler's temporaries (scores and probabilities of one head)
+_VMEM_PLAN_BYTES = 8 * 2**20
+# heads a step walks are unrolled in the kernel's body
+_MAX_HEADS_PER_STEP = 8
+
+
+def _heads_per_step(hkv, gc, d, dv, ps, kv_bytes, q_bytes):
+    """How many kv heads of a page one grid step takes: the largest divisor
+    of ``hkv`` whose buffers fit the plan.  From the call's shapes alone:
+    a decode tile takes every head of a page in one copy, a wide prefill
+    chunk fewer."""
+    per_head = (2 * ps * (d + dv) * kv_bytes        # K and V pages, 2 slots
+                + 2 * gc * (d + dv) * q_bytes       # q and o blocks, pipelined
+                + gc * dv * 4 + 2 * gc * 128 * 4)   # acc; m and l, lane-padded
+    fit = max(1, min(_VMEM_PLAN_BYTES // per_head, _MAX_HEADS_PER_STEP))
+    return max(h for h in range(1, hkv + 1) if hkv % h == 0 and h <= fit)
+
+
+def _attend_page(q, k, v, mask, h, m_ref, l_ref, acc_ref, *, scale,
+                 compute_dtype):
+    """One head's online-softmax step over one page: q [G*C, D] against
+    the page's K and V tiles [ps, D(v)] in the pool's storage dtype, the
+    running max / sum / accumulator of head ``h`` updated in place."""
+    # the storage-dtype tile (possibly e5m2) widens HERE, inside the
+    # kernel, so an fp8 pool streams half the bytes
+    k = k.astype(compute_dtype)
+    if q.dtype != compute_dtype:
+        q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+    # compute-dtype operands, float32 accumulation: the same exact
+    # products as a float32 x float32 dot
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale                                            # [G*C, ps]
+    s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_ref[h]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    m_safe = jnp.maximum(m_new, -1e29)
+    p = jnp.exp(s - m_safe)
+    alpha = jnp.exp(jnp.maximum(m_prev, -1e29) - m_safe)
+    l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+        p.astype(compute_dtype), v.astype(compute_dtype),
+        (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    m_ref[h] = m_new
+
+
+def _kernel(tables_ref, len_ref, chunk_ref, layer_ref, q_ref, k_hbm, v_hbm,
+            o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *, scale, ps, c,
+            hb, compute_dtype):
+    """One (row, block of ``hb`` kv heads) grid step: the row's whole page
+    walk, ``cdiv(kv_len, ps)`` trips (none for an idle row), each trip one
+    page of ``hb`` heads copied HBM -> VMEM while the page before it is
+    computed.
 
     q rows are the ``[G, C]`` group x chunk tile flattened chunk-minor:
     flat row j is the query at absolute slot
@@ -74,59 +147,69 @@ def _kernel(tables_ref, len_ref, chunk_ref, layer_ref, q_ref, k_ref, v_ref,
     classic single query at ``kv_len - 1``, and a prefill row's valid
     queries are causal against their own history.  Pad query rows
     (``j % c >= chunk_len``) land past ``kv_len`` and read only valid
-    slots — bounded garbage the caller discards.  ``chunk_len == 0`` rows
-    never enter the live branch and emit exact zeros.
+    slots: bounded garbage the caller discards.  ``chunk_len == 0`` rows
+    make no trip and emit exact zeros.
     """
     r = pl.program_id(0)
-    pi = pl.program_id(2)
-
-    @pl.when(pi == 0)
-    def _():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
+    hkv = k_hbm.shape[2]
+    heads = (slice(None) if hb == hkv
+             else pl.ds(pl.program_id(1) * hb, hb))
     kv_len = len_ref[r]
     chunk = chunk_ref[r]
-    lo = pi * ps
-    tile_live = (lo < kv_len) & (chunk > 0)
+    layer = layer_ref[0]
+    # a length past the table (a stale or sentinel one) walks the table
+    # and no further
+    n_pages = jnp.where(
+        chunk > 0, jnp.minimum(pl.cdiv(kv_len, ps), tables_ref.shape[1]), 0)
 
-    @pl.when(tile_live)
-    def _():
-        q = q_ref[0, 0].astype(jnp.float32)              # [G*C, D]
-        # storage-dtype tile (possibly e5m2) widens HERE, inside the
-        # kernel, so fp8 pools stream half the HBM bytes
-        k = k_ref[0, 0].astype(compute_dtype).astype(jnp.float32)  # [ps, D]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                        # [G*C, ps]
-        g = s.shape[0]
-        kpos = lo + jax.lax.broadcasted_iota(jnp.int32, (g, ps), 1)
-        qpos = (kv_len - chunk
-                + jax.lax.broadcasted_iota(jnp.int32, (g, ps), 0) % c)
-        # per-row causal mask keyed off (kv_len, chunk_len): a pad query
-        # (qpos >= kv_len) still needs the kv_len bound — unlike the
-        # uniform-chunk kernel, its own position no longer subsumes it
-        s = jnp.where((kpos <= qpos) & (kpos < kv_len), s, NEG_INF)
-
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        m_safe = jnp.maximum(m_new, -1e29)
-        p = jnp.exp(s - m_safe)
-        alpha = jnp.exp(jnp.maximum(m_prev, -1e29) - m_safe)
-        v = v_ref[0, 0].astype(compute_dtype)            # [ps, Dv]
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(compute_dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+    def page_copies(i, slot):
+        page = tables_ref[r, i]
+        return (
+            pltpu.make_async_copy(k_hbm.at[layer, page, heads],
+                                  kbuf.at[slot], sem.at[0, slot]),
+            pltpu.make_async_copy(v_hbm.at[layer, page, heads],
+                                  vbuf.at[slot], sem.at[1, slot]),
         )
-        m_ref[:] = m_new
 
-    @pl.when(pi == pl.num_programs(2) - 1)
+    @pl.when(n_pages == 0)
     def _():
-        denom = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
-        o_ref[0, 0] = (acc_ref[:] / denom).astype(o_ref.dtype)
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_pages > 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        for cp in page_copies(0, 0):
+            cp.start()
+
+        def page_step(i, carry):
+            slot = jax.lax.rem(i, 2)
+
+            @pl.when(i + 1 < n_pages)
+            def _():
+                for cp in page_copies(i + 1, 1 - slot):
+                    cp.start()
+
+            for cp in page_copies(i, slot):
+                cp.wait()
+            lo = i * ps
+            gc = q_ref.shape[2]
+            kpos = lo + jax.lax.broadcasted_iota(jnp.int32, (gc, ps), 1)
+            qpos = (kv_len - chunk
+                    + jax.lax.broadcasted_iota(jnp.int32, (gc, ps), 0) % c)
+            # per-row causal mask keyed off (kv_len, chunk_len): a pad
+            # query (qpos >= kv_len) still needs the kv_len bound
+            mask = (kpos <= qpos) & (kpos < kv_len)
+            for h in range(hb):
+                _attend_page(q_ref[0, h], kbuf[slot, h], vbuf[slot, h], mask,
+                             h, m_ref, l_ref, acc_ref, scale=scale,
+                             compute_dtype=compute_dtype)
+            return carry
+
+        jax.lax.fori_loop(0, n_pages, page_step, None)
+        denom = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "out_dtype", "c"))
@@ -156,53 +239,42 @@ def _ragged(q, k_pool, v_pool, tables, kv_len, chunk_len, layer, *, scale,
     if dv_pad != dv:
         v_pool = jnp.pad(v_pool, ((0, 0),) * 4 + ((0, dv_pad - dv),))
     # unallocated (-1) table slots clip to the engine scratch page 0; the
-    # index-map clamp below keeps them from ever being streamed for rows
-    # whose kv_len ends earlier
+    # walk ends at the row's last live page, so they are never copied for
+    # a row whose kv_len ends earlier
     tables = jnp.clip(tables, 0, n_pages - 1).astype(jnp.int32)
-    maxp = tables.shape[1]
+    hb = _heads_per_step(hkv, gc_pad, d_pad, dv_pad, ps,
+                         k_pool.dtype.itemsize, q.dtype.itemsize)
 
-    def kv_map(ri, hi, pi, tables_ref, len_ref, chunk_ref, layer_ref):
-        # clamp the page walk to the row's last valid page: every tail
-        # grid step re-maps to the block already resident from the
-        # previous step, so Pallas skips its DMA — dead table width costs
-        # no pool round-trips (the page axis is the innermost grid dim).
-        # Idle slots (chunk_len 0 — batch pads, ensure-failed rows) clamp
-        # to page 0 outright: their kv_len is the scratch-routing
-        # sentinel (past the table width), which would otherwise walk
-        # the whole grid of someone else's table for a row that computes
-        # nothing.
-        last = jnp.where(chunk_ref[ri] > 0,
-                         jnp.maximum((len_ref[ri] - 1) // ps, 0), 0)
-        return (layer_ref[0], tables_ref[ri, jnp.minimum(pi, last)], hi,
-                0, 0)
-
-    def q_map(ri, hi, pi, tables_ref, len_ref, chunk_ref, layer_ref):
+    def q_map(ri, hi, tables_ref, len_ref, chunk_ref, layer_ref):
         return (ri, hi, 0, 0)
 
+    # the grid is rows x head blocks: the table's width is no axis of it,
+    # and the pools stay in HBM, read a page at a time by the walk's copies
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(r, hkv, maxp),
+        grid=(r, hkv // hb),
         in_specs=[
-            pl.BlockSpec((1, 1, gc_pad, d_pad), q_map),
-            # the layer axis is squeezed: the kernel sees [1, 1, ps, D]
-            # tiles exactly as it did over a per-layer pool
-            pl.BlockSpec((None, 1, 1, ps, d_pad), kv_map),
-            pl.BlockSpec((None, 1, 1, ps, dv_pad), kv_map),
+            pl.BlockSpec((1, hb, gc_pad, d_pad), q_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, gc_pad, dv_pad), q_map),
+        out_specs=pl.BlockSpec((1, hb, gc_pad, dv_pad), q_map),
         scratch_shapes=[
-            pltpu.VMEM((gc_pad, 1), jnp.float32),
-            pltpu.VMEM((gc_pad, 1), jnp.float32),
-            pltpu.VMEM((gc_pad, dv_pad), jnp.float32),
+            pltpu.VMEM((2, hb, ps, d_pad), k_pool.dtype),
+            pltpu.VMEM((2, hb, ps, dv_pad), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((hb, gc_pad, 1), jnp.float32),
+            pltpu.VMEM((hb, gc_pad, 1), jnp.float32),
+            pltpu.VMEM((hb, gc_pad, dv_pad), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, ps=ps, c=c,
+        functools.partial(_kernel, scale=scale, ps=ps, c=c, hb=hb,
                           compute_dtype=jnp.bfloat16),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, hkv, gc_pad, dv_pad), out_dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel"),
         ),
         interpret=_interpret(),
         name="ragged_paged_attention",

@@ -527,9 +527,12 @@ def _decode_horizon_loop(cfg: ModelConfig, params, cache, toks, row_lens,
         logits, cache = decoder_forward(
             cfg, params, toks[:, None], cache, row_lens[:, None],
             last_token_only=True, slot_offsets=write_at,
-            # recurrent state must not advance for a dead row (KV has the
-            # scratch page for that; a state has no scratch slot)
-            chunk_lens=alive.astype(jnp.int32) if cfg.ssm_layers else None,
+            # a dead row is an idle row (chunk_len 0): its attention walks
+            # no page and emits zeros, and recurrent state does not advance
+            # for it (KV has the scratch page for a dead row's write; a
+            # state has no scratch slot).  A live row's kv_len is the same
+            # ``row_lens + 1`` either way.
+            chunk_lens=alive.astype(jnp.int32),
         )
         key, sub = jax.random.split(key)
         nxt, lp = sample_rows_with_logprobs(logits, temps, top_ps, sub,
@@ -617,6 +620,9 @@ def _spec_horizon_loop(cfg: ModelConfig, params, cache, toks, row_lens,
         pos = write_at[:, None] + jnp.arange(k1)[None, :]
         logits, cache = decoder_forward(
             cfg, params, tokens, cache, pos, slot_offsets=write_at,
+            # a dead row's window is an idle row of the attention kernel,
+            # as in the plain body; a live row's is all k+1 positions
+            chunk_lens=alive.astype(jnp.int32) * k1,
         )
         t_all, lp_all, key = _sample_verify_positions(
             logits, alive, temps, top_ps, key, seeds, steps, top_ks,
@@ -1577,6 +1583,11 @@ class ServingEngine:
                         "queue_wait_s_total": 0.0,
                         "admit_to_first_token_s_total": 0.0,
                         "first_tokens": 0,
+                        # what the attention kernel had to read against
+                        # the table width it was handed, in pages, summed
+                        # over dispatched ticks (_count_attn_pages)
+                        "attn_pages_live_total": 0,
+                        "attn_pages_table_total": 0,
                         # fault-domain observability: per-request failures
                         # isolated by bisection, transient step retries,
                         # load-shed submissions, expired deadlines, and
@@ -1861,6 +1872,30 @@ class ServingEngine:
         state: mid-tick observations a rollback would subtract are never
         scrape-visible, so the exposed series stay monotonic."""
         return self._hists_committed
+
+    def _count_attn_pages(self, active, decode: bool = True, chunks=(),
+                          base=None, n_valid=None,
+                          prefill_table: int = 0) -> dict:
+        """What the tick about to be dispatched asks of the attention
+        kernel, in pages, from lengths the host holds: ``attn_pages_live``
+        the sum over the rows that attend of ``cdiv(kv_len, page_size)``
+        (decode rows at ``row_lens + 1``, prefill chunks at their end),
+        ``attn_pages_table`` the rows of each stage times the table width
+        handed to it (``prefill_table`` the prefill stage's).  A lower bound
+        on what is read: a row that joins the decode stage inside the tick
+        and the later steps of a horizon are left out.  Added to the
+        ``attn_pages_*_total`` counters (rollback-covered with the metrics)
+        and returned as the ``engine/dispatch`` span's stats."""
+        ps = self.ec.page_size
+        live, table = 0, prefill_table
+        if decode:
+            live = int((-(-(self.row_lens[active] + 1) // ps)).sum())
+            table += len(self.rows) * self.ec.max_pages
+        live += sum(-(-(int(base[i]) + int(n_valid[i])) // ps)
+                    for i, _, _ in chunks)
+        self.metrics["attn_pages_live_total"] += live
+        self.metrics["attn_pages_table_total"] += table
+        return {"attn_pages_live": live, "attn_pages_table": table}
 
     def _perf_dispatch(self, family: str, point: dict | None = None,
                        tick: bool = True, **attrs):
@@ -3767,9 +3802,12 @@ class ServingEngine:
             1, width=width, with_decode=with_decode, spec=tick_spec,
             pb=p_b, maxp=maxp_b, ew=int(dev["eos"].shape[1]))
         rows_active = int(active.sum())
+        attn_pages = self._count_attn_pages(
+            active, with_decode, chunks, base, n_valid,
+            prefill_table=p_b * maxp_b)
         if tick_spec:
             with self._perf_dispatch("tick.spec", point=perf_pt,
-                                     rows_active=rows_active):
+                                     rows_active=rows_active, **attn_pages):
                 (first_t, first_lp, tok_block, lp_block, n_exec,
                  self.cache, dev["toks"], dev["row_lens"], dev["active"],
                  dev["steps"], dev["remain"], self.key, take_block,
@@ -3787,7 +3825,7 @@ class ServingEngine:
         else:
             cache_in, state_src = self._state_call()
             with self._perf_dispatch("tick.admission", point=perf_pt,
-                                     rows_active=rows_active):
+                                     rows_active=rows_active, **attn_pages):
                 (first_t, first_lp, tok_block, lp_block, n_exec,
                  self.cache, dev["toks"], dev["row_lens"], dev["active"],
                  dev["steps"], dev["remain"], self.key) = _ragged_tick(
@@ -3949,8 +3987,10 @@ class ServingEngine:
         perf_pt = self._perf_point(h, width=0, spec=fused_spec,
                                    ew=int(dev["eos"].shape[1]))
         rows_active = int(active.sum())
+        attn_pages = self._count_attn_pages(active)
         if self._pp_mode:
-            with self._perf_dispatch("tick.pp", rows_active=rows_active):
+            with self._perf_dispatch("tick.pp", rows_active=rows_active,
+                                     **attn_pages):
                 nxt, lp, self.cache, self.key = _pp_decode_sample(
                     self.cfg, self.params, self.cache, dev["toks"],
                     dev["row_lens"], dev["active"], dev["temps"],
@@ -3969,7 +4009,7 @@ class ServingEngine:
             # the [R, k+1] verify, and acceptance all ride inside the
             # horizon loop — still one dispatch (JP106 unchanged)
             with self._perf_dispatch("tick.spec", point=perf_pt,
-                                     rows_active=rows_active):
+                                     rows_active=rows_active, **attn_pages):
                 (_, _, tok_block, lp_block, n_exec, self.cache,
                  dev["toks"], dev["row_lens"], dev["active"],
                  dev["steps"], dev["remain"], self.key, take_block,
@@ -3993,7 +4033,7 @@ class ServingEngine:
             # _decode_multi_step program)
             cache_in, state_src = self._state_call()
             with self._perf_dispatch("tick.steady", point=perf_pt,
-                                     rows_active=rows_active):
+                                     rows_active=rows_active, **attn_pages):
                 (_, _, tok_block, lp_block, n_exec, self.cache,
                  dev["toks"], dev["row_lens"], dev["active"],
                  dev["steps"], dev["remain"], self.key) = _ragged_tick(

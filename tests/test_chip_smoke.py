@@ -39,10 +39,12 @@ def test_kernels_match_references_in_interpret_mode(tiny_model):
     families = {name.split(".")[0] for name in errs}
     assert families == {"qmatmul_sym_int4", "ragged_paged_sdpa",
                         "paged_kv_write", "flash_sdpa", "decode_sdpa"}
-    # both pool widths of the pool's two kernels, decode and chunk widths
+    # both pool widths of the pool's two kernels, decode and chunk widths,
+    # and the attention's short rows in a wide table among dead rows
     assert {n for n in errs if n.startswith(("ragged", "paged"))} == {
         f"{k}.{s}.c{c}" for k in ("ragged_paged_sdpa", "paged_kv_write")
-        for s in ("bf16", "fp8") for c in (1, 16)}
+        for s in ("bf16", "fp8") for c in (1, 16)} | {
+        f"ragged_paged_sdpa.{s}.short_wide" for s in ("bf16", "fp8")}
 
 
 def test_server_answers_the_wave_over_http(tiny_model):

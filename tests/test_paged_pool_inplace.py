@@ -4,7 +4,7 @@ The decoder's layer scan carries the stacked pool ``[L, P, Hkv, page, D]``
 whole: a layer's new rows are written into the stack at ``(layer, page, :,
 offset)`` (``PagedKVCache.write_layer``, the XLA form, and
 ops/pallas/paged_kv_write.py, the kernel) and the ragged attention kernel
-reads ``(layer, table[row, page])`` through its index map.  Each half must
+reads ``(layer, table[row, page])`` by its page walk's copies.  Each half must
 put and find the bytes exactly where the per-layer forms did
 (``update_layer`` on ``pool[l]``, the kernel over ``pool[l]``), for both
 storage widths.  The kernels run in interpret mode here.
@@ -51,6 +51,9 @@ READS = {
     "decode": (1, [5, 16, 33, 48], [1, 1, 1, 1]),
     "chunk": (8, [8, 21, 40, 5], [8, 5, 8, 3]),
     "idle": (4, [20, MAXP * PS + 7, 9, MAXP * PS + 7], [4, 0, 1, 0]),
+    # the ends of the page walk: a row at its table's capacity, one ending
+    # exactly on a page, one of a single token, one idle at the sentinel
+    "edges": (1, [MAXP * PS, 2 * PS, 1, MAXP * PS], [1, 1, 1, 0]),
 }
 
 
@@ -70,8 +73,8 @@ def test_ragged_kernel_over_stack_equals_over_layer(kv, case):
         got = ragged_paged_sdpa(q, cache.k, cache.v, cache.tables, kv_len,
                                 chunk_len, layer=jnp.asarray(layer))
         np.testing.assert_array_equal(_bytes(got), _bytes(want))
-    if case == "idle":
-        assert not np.asarray(got, np.float32)[1].any()     # exact zeros
+    idle = np.asarray(chunk_len) == 0
+    assert not np.asarray(got, np.float32)[idle].any()      # exact zeros
 
 
 # -- the write: into the carried stack at (layer, page, :, offset) -------------

@@ -17,6 +17,7 @@ from ipex_llm_tpu.ops.pallas.decode_attention import decode_sdpa
 from ipex_llm_tpu.ops.pallas.flash_attention import flash_sdpa
 from ipex_llm_tpu.ops.pallas.qmatmul import qmatmul_pallas
 from ipex_llm_tpu.quantize import quantize
+from tests.test_paged_pool_inplace import _bytes as _bits
 
 RNG = np.random.default_rng(7)
 
@@ -314,3 +315,196 @@ def test_serving_engine_uses_paged_kernel(monkeypatch):
     finally:
         monkeypatch.delenv("IPEX_LLM_TPU_FORCE_PALLAS", raising=False)
         dispatch.clear_cache()
+
+
+# -- the ragged page walk (ops/pallas/ragged_paged_attention.py) ---------------
+#
+# The kernel walks each row's live pages and nothing else: the grid is rows x
+# blocks of kv heads, the table's width no axis of it.  Held here: the values
+# against the jnp oracle over the shapes the engine's callers bring, that a
+# row's bits depend on that row alone (not on the table's width nor on its
+# neighbours), and the grid itself.
+
+_PS, _MAXP = 16, 8           # a row holds at most 128 slots
+_KV_DTYPES = {"bf16": jnp.bfloat16, "fp8": jnp.float8_e5m2}
+
+
+def _ragged_rows(c: int):
+    """(kv_len, chunk_len) of the rows every case walks: one at the
+    table's full capacity, one that ends exactly on a page boundary, one of
+    a single token, one idle (at the engine's scratch sentinel)."""
+    cap = _PS * _MAXP
+    kv_len = np.asarray([cap, 2 * _PS, 1, cap], np.int32)
+    chunk_len = np.asarray([min(c, cap), min(c, 2 * _PS), 1, 0], np.int32)
+    return kv_len, chunk_len
+
+
+def _ragged_fixture(hkv, c, kv, d=128, dv=None, layers=2, width=_MAXP,
+                    seed=0, g=2):
+    """Random stacked pools, a shuffled table of ``width`` pages a row (the
+    first ``_MAXP`` of them allocated), and q for the rows of
+    ``_ragged_rows``."""
+    rng = np.random.default_rng(seed)
+    dv = dv or d
+    kv_len, chunk_len = _ragged_rows(c)
+    r = len(kv_len)
+    n_pages = 1 + r * _MAXP
+    dtype = _KV_DTYPES[kv]
+    k = jnp.asarray(rng.standard_normal((layers, n_pages, hkv, _PS, d)),
+                    jnp.float32).astype(dtype)
+    v = jnp.asarray(rng.standard_normal((layers, n_pages, hkv, _PS, dv)),
+                    jnp.float32).astype(dtype)
+    tables = np.full((r, width), -1, np.int32)
+    tables[:, :_MAXP] = 1 + rng.permutation(r * _MAXP).reshape(r, _MAXP)
+    q = jnp.asarray(rng.standard_normal((r, c, g * hkv, d)), jnp.bfloat16)
+    return q, k, v, tables, kv_len, chunk_len
+
+
+def _ragged_oracle(q, kl, vl, tables, kv_len, chunk_len):
+    """sdpa_reference over the rows' gathered pages of ONE layer."""
+    r, c = q.shape[:2]
+    t = np.clip(tables, 0, kl.shape[0] - 1)
+
+    def rows(pool):       # [P, H, ps, D] -> [R, S, H, D]
+        g = pool[t].astype(jnp.bfloat16)          # [R, maxP, H, ps, D]
+        return g.transpose(0, 1, 3, 2, 4).reshape(
+            r, -1, pool.shape[1], pool.shape[3])
+    qpos = (kv_len - chunk_len)[:, None] + np.arange(c)[None]
+    return np.asarray(sdpa_reference(
+        q, rows(kl), rows(vl), causal=True, q_positions=jnp.asarray(qpos),
+        kv_len=jnp.asarray(kv_len)), np.float32)
+
+
+def _assert_rows_match(got, want, chunk_len):
+    """Valid queries against the oracle; pad queries (past a row's
+    chunk_len) are garbage by contract, an idle row exact zeros."""
+    got = np.asarray(got, np.float32)
+    c = got.shape[1]
+    valid = (np.arange(c)[None] < chunk_len[:, None])[:, :, None, None]
+    np.testing.assert_allclose(np.where(valid, got, 0),
+                               np.where(valid, want, 0),
+                               rtol=3e-2, atol=3e-2)
+    assert not got[chunk_len == 0].any()
+
+
+@pytest.mark.parametrize("kv", sorted(_KV_DTYPES))
+@pytest.mark.parametrize("c", [1, 8, 128])
+@pytest.mark.parametrize("hkv", [1, 2, 8])
+def test_ragged_walk_matches_reference(hkv, c, kv):
+    """Both forms a caller holds: the stack read in place at ``layer``, and
+    one layer alone (a stack of one)."""
+    from ipex_llm_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_sdpa)
+
+    q, k, v, tables, kv_len, chunk_len = _ragged_fixture(hkv, c, kv)
+    args = (jnp.asarray(tables), jnp.asarray(kv_len), jnp.asarray(chunk_len))
+    want = _ragged_oracle(q, k[1], v[1], tables, kv_len, chunk_len)
+    stacked = ragged_paged_sdpa(q, k, v, *args, layer=jnp.asarray(1))
+    alone = ragged_paged_sdpa(q, k[1], v[1], *args)
+    _assert_rows_match(stacked, want, chunk_len)
+    np.testing.assert_array_equal(_bits(stacked), _bits(alone))
+
+
+@pytest.mark.parametrize("kv", sorted(_KV_DTYPES))
+@pytest.mark.parametrize("c", [1, 8])
+def test_ragged_walk_lane_padded_heads(c, kv):
+    """MLA's unbalanced pool (K at 192, V at 128): the one layer is sliced
+    out and lane-padded, a stack of one."""
+    from ipex_llm_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_sdpa)
+
+    q, k, v, tables, kv_len, chunk_len = _ragged_fixture(
+        2, c, kv, d=192, dv=128)
+    got = ragged_paged_sdpa(q, k, v, jnp.asarray(tables),
+                            jnp.asarray(kv_len), jnp.asarray(chunk_len),
+                            layer=jnp.asarray(1))
+    assert got.shape == (4, c, 4, 128)
+    _assert_rows_match(got, _ragged_oracle(q, k[1], v[1], tables, kv_len,
+                                           chunk_len), chunk_len)
+
+
+@pytest.mark.parametrize("kv", sorted(_KV_DTYPES))
+@pytest.mark.parametrize("c", [1, 8])
+def test_ragged_walk_row_bits_are_the_rows_own(c, kv):
+    """The SAME rows through tables 8 and 32 pages wide, alone and among
+    other rows: identical bits.  A row's output is a function of its own q,
+    pages, kv_len and chunk_len."""
+    from ipex_llm_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_sdpa)
+
+    q, k, v, narrow, kv_len, chunk_len = _ragged_fixture(2, c, kv)
+    wide = np.full((len(narrow), 32), -1, np.int32)
+    wide[:, :_MAXP] = narrow
+    # the idle row sits at the sentinel of ITS table's width, as the engine
+    # places it
+    lens = {8: kv_len, 32: np.where(chunk_len > 0, kv_len, 32 * _PS)}
+
+    def run(rows, tables):
+        return _bits(ragged_paged_sdpa(
+            q[rows], k, v, jnp.asarray(tables[rows]),
+            jnp.asarray(lens[tables.shape[1]][rows]),
+            jnp.asarray(chunk_len[rows]), layer=jnp.asarray(1)))
+
+    every = np.arange(len(narrow))
+    base = run(every, narrow)
+    np.testing.assert_array_equal(run(every, wide), base)
+    for row in every:
+        for tables in (narrow, wide):
+            np.testing.assert_array_equal(run(every[row:row + 1], tables),
+                                          base[row:row + 1])
+    # and in other company: reversed order, beside a copy of itself
+    np.testing.assert_array_equal(run(every[::-1], wide), base[::-1])
+
+
+def _pallas_grids(jaxpr) -> list[tuple]:
+    """The grid of every pallas_call under a jaxpr, nested calls included."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    grids += _pallas_grids(inner)
+    return grids
+
+
+@pytest.mark.parametrize("c,hkv", [(1, 8), (128, 8), (1, 2)])
+def test_ragged_walk_grid_has_no_table_width_axis(c, hkv):
+    """No step for dead table width: the lowered pallas_call's grid is rows
+    x head blocks, the same for tables 8 and 32 pages wide."""
+    from ipex_llm_tpu.ops.pallas import ragged_paged_attention as rpa
+
+    q, k, v, tables, kv_len, chunk_len = _ragged_fixture(hkv, c, "bf16")
+    grids = []
+    for width in (8, 32):
+        wide = np.full((len(tables), width), -1, np.int32)
+        wide[:, :_MAXP] = tables
+        jaxpr = jax.make_jaxpr(
+            lambda q, k, v, t: rpa.ragged_paged_sdpa(
+                q, k, v, t, jnp.asarray(kv_len), jnp.asarray(chunk_len),
+                layer=jnp.asarray(0)))(q, k, v, jnp.asarray(wide))
+        grids.append(_pallas_grids(jaxpr.jaxpr))
+    assert grids[0] == grids[1] and len(grids[0]) == 1
+    rows, head_blocks = grids[0][0]
+    assert rows == len(tables) and hkv % head_blocks == 0
+    gc_pad = -(-2 * c // 8) * 8         # g = 2 query heads a kv head
+    assert head_blocks == hkv // rpa._heads_per_step(
+        hkv, gc_pad, 128, 128, _PS, 2, 2)
+
+
+def test_ragged_walk_heads_a_step_follow_the_shapes():
+    """Heads a step come from the call's shapes against VMEM: a decode
+    tile takes a whole page of kv heads, a 128-wide prefill chunk fewer, a
+    model of 2 kv heads both; always a divisor of the head count."""
+    from ipex_llm_tpu.ops.pallas.ragged_paged_attention import (
+        _heads_per_step)
+
+    assert _heads_per_step(8, 8, 128, 128, 128, 2, 2) == 8      # decode
+    assert _heads_per_step(8, 8, 128, 128, 128, 1, 2) == 8      # fp8 pool
+    assert 1 <= _heads_per_step(8, 512, 128, 128, 128, 2, 2) < 8
+    assert _heads_per_step(2, 16, 128, 128, 128, 2, 2) == 2     # 2 kv heads
+    assert _heads_per_step(6, 2048, 128, 128, 128, 2, 2) == 1
+    for hkv in (1, 2, 3, 6, 8, 32):
+        assert hkv % _heads_per_step(hkv, 64, 256, 128, 128, 2, 2) == 0

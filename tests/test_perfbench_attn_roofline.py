@@ -1,0 +1,15 @@
+"""The benchmark's ``attn_roofline`` reader in tier-1: the cases of
+perfbench/tests/test_attn_roofline.py (a synthetic spans / events file, no
+jax), collected here because perfbench's own tests are run by hand."""
+
+import importlib.util
+import os
+
+_PATH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))), "perfbench", "tests", "test_attn_roofline.py")
+_spec = importlib.util.spec_from_file_location("perfbench_attn_roofline",
+                                               _PATH)
+_cases = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_cases)
+globals().update({name: obj for name, obj in vars(_cases).items()
+                  if name.startswith("test_")})

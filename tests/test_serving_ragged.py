@@ -375,6 +375,138 @@ def test_stale_device_lens_cannot_corrupt_neighbors(cfg_params):
             f"page {page} slot {slot} corrupted by a dead row's stale len")
 
 
+# -- a dense model's dead decode rows are idle rows of the kernel -------------
+
+def test_dense_dead_rows_are_idle_and_live_rows_keep_their_bits(monkeypatch):
+    """The decode stage hands EVERY model's dead rows to the attention
+    kernel as idle rows (``chunk_len`` 0: no page walked, zero output), as
+    it always did for a hybrid model; live rows get ``chunk_len`` 1 and the
+    ``kv_len`` they always had, so their tokens and log-probabilities are
+    bit-identical to the old call (``chunk_lens=None``: every row a decode
+    row, a dead one walking its whole table) on the same requests."""
+    from ipex_llm_tpu.ops import dispatch
+    from ipex_llm_tpu.ops.pallas import ragged_paged_attention
+    from ipex_llm_tpu.serving import engine as engine_mod
+
+    # a configuration of its own: its tick programs are traced here, under
+    # the spy, and never found in another test's jit cache
+    cfg = tiny_cfg(vocab_size=113, hidden_size=48, intermediate_size=96,
+                   num_heads=4, num_kv_heads=2, head_dim=12,
+                   max_position_embeddings=512)
+    params = rand_params(cfg, qtype="bf16")
+    rng = np.random.default_rng(5)
+    specs = [dict(prompt_ids=list(rng.integers(0, 113, n)), max_new_tokens=m,
+                  **kw)
+             for n, m, kw in ((20, 3, {}), (33, 9, {}),
+                              (12, 6, dict(temperature=0.8, top_p=0.9,
+                                           top_k=40, seed=11)))]
+    seen = []          # (chunk_len, kv_len) of every decode-stage call
+    real_sdpa = ragged_paged_attention.ragged_paged_sdpa
+
+    def spy(q, k, v, tables, kv_len, chunk_len=None, **kw):
+        if q.shape[1] == 1:
+            cl = (jnp.ones_like(kv_len) if chunk_len is None else chunk_len)
+            jax.debug.callback(
+                lambda c, n: seen.append((np.asarray(c), np.asarray(n))),
+                cl, kv_len)
+        return real_sdpa(q, k, v, tables, kv_len, chunk_len, **kw)
+
+    def serve():
+        engine_mod._ragged_tick_fn.clear_cache()
+        reqs = [Request(**s) for s in specs]
+        eng = ServingEngine(cfg, params, EngineConfig(**EC))
+        streams = _drive(eng, {0: reqs})
+        jax.effects_barrier()
+        return streams, [list(r.logprobs) for r in reqs]
+
+    monkeypatch.setattr(ragged_paged_attention, "ragged_paged_sdpa", spy)
+    monkeypatch.setenv("IPEX_LLM_TPU_FORCE_PALLAS", "1")
+    dispatch.clear_cache()
+    try:
+        new = serve()
+        calls_new, seen[:] = list(seen), []
+        # the old call: the decode step's chunk_lens dropped again
+        real_forward = engine_mod.decoder_forward
+
+        def old_forward(cfg_, params_, tokens, *a, **kw):
+            if tokens.shape[1] == 1 and kw.get("last_token_only"):
+                kw["chunk_lens"] = None
+            return real_forward(cfg_, params_, tokens, *a, **kw)
+
+        monkeypatch.setattr(engine_mod, "decoder_forward", old_forward)
+        old = serve()
+        calls_old = list(seen)
+    finally:
+        monkeypatch.delenv("IPEX_LLM_TPU_FORCE_PALLAS", raising=False)
+        dispatch.clear_cache()
+        engine_mod._ragged_tick_fn.clear_cache()
+    assert new == old
+    assert [len(t) for t in new[0]] == [3, 9, 6]
+    table_slots = EC["max_seq_len"]
+    dead_new = [c == 0 for c, _ in calls_new]
+    assert any(d.any() for d in dead_new), "no tick held a dead row"
+    for (c, n), dead in zip(calls_new, dead_new):
+        assert set(c.tolist()) <= {0, 1}
+        # a dead row sits AT the scratch sentinel and asks for no page; a
+        # live one inside its table
+        assert (n[dead] == table_slots).all() and (n[~dead] < table_slots
+                                                   ).all()
+    # what the old call handed the kernel: every row a decode row, the dead
+    # ones one past the sentinel (every page of the table live to them)
+    assert all((c == 1).all() for c, _ in calls_old)
+    assert any((n == table_slots + 1).any() for _, n in calls_old)
+
+
+def _attn_pages_of(prompt: int, new_tokens: int, width: int, ps: int,
+                   rows: int, maxp: int) -> tuple[int, int]:
+    """(live, table) page counts of ONE request served alone: its prompt in
+    chunks of ``width`` (each chunk's rows attend up to its end), the first
+    decode step inside the completion tick (a joiner: left out), then one
+    steady tick a token, the row ``prompt + emitted`` slots long."""
+    ends = list(range(width, prompt, width)) + [prompt]
+    live = sum(-(-e // ps) for e in ends)
+    table = maxp * len(ends) + rows * maxp       # + the completion's decode
+    for emitted in range(2, new_tokens):
+        live += -(-(prompt + emitted) // ps)
+        table += rows * maxp
+    return live, table
+
+
+def test_attn_page_counters_follow_the_lengths_and_roll_back(cfg_params):
+    """``attn_pages_live_total`` is the sum, over the rows that attend, of
+    the pages their lengths hold; ``attn_pages_table_total`` the table
+    width handed over.  A tick rolled back after its dispatch leaves both
+    as the retried tick alone counts them."""
+    from ipex_llm_tpu.serving.faults import FaultInjector, TransientFault
+
+    cfg, params = cfg_params
+    prompt = list(RNG.integers(0, cfg.vocab_size, 70))
+    want = _attn_pages_of(70, 7, EC["prefill_bucket"], EC["page_size"],
+                          EC["max_rows"],
+                          EC["max_seq_len"] // EC["page_size"])
+
+    def counters(injector=None):
+        eng = ServingEngine(cfg, params, EngineConfig(**EC),
+                            fault_injector=injector)
+        req = eng.submit(Request(prompt_ids=prompt, max_new_tokens=7))
+        for _ in range(200):       # the transaction: checkpoint, retry
+            eng._tick()
+            if req.finish_reason is not None:
+                break
+        assert len(list(stream_tokens(req, timeout=10))) == 7
+        return (eng.metrics["attn_pages_live_total"],
+                eng.metrics["attn_pages_table_total"]), eng
+
+    got, _ = counters()
+    assert got == want
+    # the "sample" site fires after the completion tick's dispatch was
+    # counted: the rollback takes the counts back, the retry adds them once
+    inj = FaultInjector().inject("sample", TransientFault, nth=1)
+    again, eng = counters(inj)
+    assert inj.fired == 1 and eng.metrics["retries"] == 1
+    assert again == want
+
+
 # -- measured-ladder dispatch policy ----------------------------------------
 
 def test_dispatch_policy_selects_faster_backend_from_ladder(monkeypatch):
