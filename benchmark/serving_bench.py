@@ -1363,7 +1363,7 @@ def bench_churn(cfg, params, engine_config, concurrency: int = 4,
             # the AUDITED per-tick dispatch count (jaxprcheck JP106 gate,
             # analysis/trace/tickaudit.py): how many device programs one
             # mixed prefill+decode tick can issue — EXACTLY 1 since the
-            # ragged paged-attention superkernel tick (_ragged_tick_fn);
+            # ragged paged-attention superkernel tick (_ragged_tick);
             # BENCH rounds track the value next to the throughput it buys
             "tick_dispatches": _audited_tick_dispatches(),
             "completed": sum(
@@ -1593,11 +1593,8 @@ def collect(cfg=None, params=None, levels=(1, 4, 16), n_in: int | None = None,
     same prompts, same engine shape — reporting ``steps_per_sync``
     alongside ``agg_tok_s`` so the H=1 row in the sweep is the in-run
     baseline the H>1 rows are judged against; and the admission-churn
-    workload (staggered mixed-length arrivals at concurrency 4) run twice
-    — ``step_token_budget=0`` (the sequential chunk-then-decode engine)
-    vs the default mixed prefill+decode step — so TTFT p95 and
-    syncs-per-token under churn are tracked against their own in-run
-    baseline from this BENCH round on."""
+    workload (staggered mixed-length arrivals at concurrency 4), reporting
+    TTFT p95 and syncs-per-token under churn."""
     from dataclasses import replace as _dc_replace
 
     from ipex_llm_tpu.serving.engine import EngineConfig
@@ -1643,36 +1640,28 @@ def collect(cfg=None, params=None, levels=(1, 4, 16), n_in: int | None = None,
         row = runs[len(runs) // 2]
         row["agg_tok_s_all"] = [r["agg_tok_s"] for r in runs]
         out.append(row)
-    # admission-churn section: sequential (budget 0) vs mixed (default
-    # budget), median-of-reps like the horizon sweep — the two rows are
-    # judged against each other, not across rounds/hosts
+    # admission-churn section: median-of-reps like the horizon sweep
     churn_reqs = int(os.environ.get("BENCH_CHURN_REQS", "8"))
     churn_out = int(os.environ.get("BENCH_CHURN_OUT", str(sweep_out // 4)))
     churn_gap = float(os.environ.get("BENCH_CHURN_GAP", "0.05"))
     # multi-chunk prompts (1x..4x the prefill chunk) — single-chunk
     # prompts would measure admission with nothing to batch; the engine
     # gets the headroom the longest prompt + output needs.  The churn
-    # runs at the sweep's top horizon: the admission-wave pathology being
-    # measured is the H>1 engine collapsing to tiny alternating programs
-    # while any row prefills, which the mixed step fixes by batching the
-    # wave and ending it sooner
+    # runs at the sweep's top horizon
     lens = tuple(n_in * k for k in (1, 2, 3, 4))
     churn_h = int(os.environ.get("BENCH_CHURN_HORIZON",
                                  str(max(horizons) if horizons else 1)))
     churn_ec = _dc_replace(ec, decode_horizon=churn_h, max_seq_len=max(
         ec.max_seq_len, 1 << (4 * n_in + churn_out).bit_length()))
-    for budget in (0, None):
-        runs = [bench_churn(cfg, params,
-                            _dc_replace(churn_ec,
-                                        step_token_budget=budget),
-                            concurrency=c, n_reqs=churn_reqs,
-                            n_out=churn_out, prompt_lens=lens,
-                            gap_s=churn_gap, seed=3 + rep)
-                for rep in range(reps)]
-        runs.sort(key=lambda r: r["ttft_p95_s"])
-        row = runs[len(runs) // 2]
-        row["ttft_p95_s_all"] = [r["ttft_p95_s"] for r in runs]
-        out.append(row)
+    runs = [bench_churn(cfg, params, churn_ec,
+                        concurrency=c, n_reqs=churn_reqs,
+                        n_out=churn_out, prompt_lens=lens,
+                        gap_s=churn_gap, seed=3 + rep)
+            for rep in range(reps)]
+    runs.sort(key=lambda r: r["ttft_p95_s"])
+    row = runs[len(runs) // 2]
+    row["ttft_p95_s_all"] = [r["ttft_p95_s"] for r in runs]
+    out.append(row)
     # observability price row (BENCH_r13+): the churn workload traced vs
     # untraced — the tracing-enabled engine must stay within ~3% agg
     # tok/s of the plain one (flight recorder + histograms are on in

@@ -510,7 +510,7 @@ def check_compiled_tick(eng, expect_mosaic: bool) -> dict:
     import jax
 
     from ipex_llm_tpu.ops.pallas import _compat
-    from ipex_llm_tpu.serving.engine import _ragged_tick_fn
+    from ipex_llm_tpu.serving.engine import _ragged_tick
 
     def sds(tree):
         return jax.tree_util.tree_map(
@@ -525,7 +525,7 @@ def check_compiled_tick(eng, expect_mosaic: bool) -> dict:
             sds(dev["steps"]), sds(dev["top_ks"]), sds(dev["eos"]),
             sds(dev["remain"]))
     def lower():
-        return _ragged_tick_fn.lower(
+        return _ragged_tick.lower(
             *args, prefill=None, horizon=1, mesh=eng.mesh,
             tp_manual=eng._tp_manual,
             collective_qtype=eng._collective_qtype)

@@ -265,7 +265,7 @@ class DeviceFlow:
                 names.extend(e.id for e in t.elts if isinstance(e, ast.Name))
         if not names:
             return
-        # function-valued alias: `verify_fn = _verify_step` (a jit-bound
+        # function-valued alias: `tick_fn = _ragged_tick` (a jit-bound
         # name, bare or self.-qualified) makes calls through the alias
         # device-producing too — otherwise a sync on the aliased call's
         # result escapes JL002 via one level of indirection

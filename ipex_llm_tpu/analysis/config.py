@@ -82,7 +82,6 @@ class Config:
         "ipex_llm_tpu/speculative.py",
         "ipex_llm_tpu/structured.py",
         "ipex_llm_tpu/transformers/multimodal.py",
-        "ipex_llm_tpu/parallel/pipeline.py",
     )
     # parameter names that mark a large persistent device buffer (JL007)
     donation_hint_params: frozenset = frozenset({

@@ -1,7 +1,7 @@
 """JL002 hidden-host-sync: blocking device syncs in hot paths.
 
 The serving tick loop's whole performance model is "one designed sync
-per horizon" (see ``_decode_multi_step``); an accidental ``int(x)``,
+per horizon" (see ``_decode_horizon_loop``); an accidental ``int(x)``,
 ``.item()``, ``np.asarray(device_value)`` or ``.block_until_ready()``
 anywhere in the tick/decode/mixed-step path collapses pipelining and is
 invisible in review — the code *works*, just 10x slower under load.

@@ -238,14 +238,6 @@ def _grid(**axes) -> tuple[dict, ...]:
 # builders — one per registered program, mirroring the real call sites
 # --------------------------------------------------------------------------
 
-def _build_decode_multi_step(pt):
-    cfg, params = audit_model(pt.get("wq", "bf16"))
-    r = pt["rows"]
-    return (cfg, params, _paged_cache(r, pt["kv"]), _i32(r), _i32(r),
-            _bool(r), _f32(r), _f32(r), _key(), _i32(r), _i32(r), _i32(r),
-            _i32(r, 2), _i32(r)), {"horizon": pt["horizon"], "mesh": None}
-
-
 def _tp_stamped_params(tp: int):
     """The abstract audit_model_tp tree with the manual layout's
     ``tp_mode`` stamps (the static aux parallel/manual.py's in_specs are
@@ -318,51 +310,6 @@ def _build_ragged_tick(pt):
     return base, kw
 
 
-def _build_mixed_prefill(pt):
-    cfg, params = audit_model()
-    p = 2   # pow2-padded prefilling-row batch
-    return (cfg, params, _paged_cache(p, pt["kv"], max_pages=2),
-            _i32(p, pt["width"]), _i32(p), _i32(p), _bool(p), _f32(p),
-            _f32(p), _key(), _i32(p), _i32(p)), {"mesh": None}
-
-
-def _build_prefill_chunk(pt):
-    cfg, params = audit_model()
-    return (cfg, params, _paged_cache(4, pt["kv"]), _i32(1, pt["bucket"]),
-            _i32(1, _MAXP), _i32(), _i32()), {"mesh": None}
-
-
-def _build_verify_step(pt):
-    cfg, params = audit_model()
-    r, k = 4, 3
-    return (cfg, params, _paged_cache(r, pt["kv"]), _i32(r), _i32(r, k),
-            _i32(r), _bool(r), _f32(r), _f32(r), _key(), _i32(r), _i32(r),
-            _i32(r)), {"k": k, "mesh": None}
-
-
-def _pp_mesh():
-    import numpy as np
-    from jax.sharding import Mesh
-
-    return Mesh(np.array(jax.devices()[:2]).reshape(1, 2), ("tp", "pp"))
-
-
-def _build_pp_decode_sample(pt):
-    cfg, params = audit_model()
-    r = 4
-    return (cfg, params, _paged_cache(r, "bf16"), _i32(r), _i32(r),
-            _bool(r), _f32(r), _f32(r), _key(), _i32(r), _i32(r),
-            _i32(r)), {"mesh": _pp_mesh(), "n_micro": 2}
-
-
-def _build_pp_verify_step(pt):
-    cfg, params = audit_model()
-    r, k = 4, 3
-    return (cfg, params, _paged_cache(r, "bf16"), _i32(r), _i32(r, k),
-            _i32(r), _bool(r), _f32(r), _f32(r), _key(), _i32(r), _i32(r),
-            _i32(r)), {"k": k, "mesh": _pp_mesh(), "n_micro": 2}
-
-
 def _build_gen_prefill(pt):
     cfg, params = audit_model()
     b = pt["batch"]
@@ -423,9 +370,9 @@ def real_registry() -> tuple[ProgramSpec, ...]:
         # -- serving/engine.py ------------------------------------------
         ProgramSpec(
             # THE tick program (JP106's one allowed dispatch): the grid
-            # covers the steady-state form (width=0: pure decode horizon,
-            # the _decode_multi_step-shaped program), the admission-wave
-            # form (prefill block at both pow2 chunk widths), the
+            # covers the steady-state form (width=0: pure decode
+            # horizon), the admission-wave form (prefill block at both
+            # pow2 chunk widths), the
             # pure-chunk form (wd=False: prefill+merge with the decode
             # stage statically skipped — a distinct jit variant with the
             # same donation contract), AND the speculative forms
@@ -514,87 +461,6 @@ def real_registry() -> tuple[ProgramSpec, ...]:
             held=frozenset({"params", "temps", "top_ps", "seeds",
                             "top_ks", "eos", "key"}),
             max_lowerings=38,
-        ),
-        ProgramSpec(
-            name="serving.decode_multi_step",
-            fn=engine._decode_multi_step,
-            build=_build_decode_multi_step,
-            # + one int4-weight point: the chained-program oracle the
-            # low-bit equivalence suite drives must lower (and keep its
-            # donation map) over packed planes too
-            grid=(_grid(rows=(4, 8), horizon=(1, 8), kv=kv_axis)
-                  + _grid(rows=(4,), horizon=(1,), wq=("sym_int4",),
-                          kv=("bf16",))),
-            arg_names=("params", "cache", "toks", "row_lens", "active",
-                       "temps", "top_ps", "key", "seeds", "steps",
-                       "top_ks", "eos", "remain"),
-            dead=frozenset({"cache", "toks", "row_lens", "active",
-                            "steps", "remain"}),
-            # key is HELD, not dead: the engine's _checkpoint snapshots
-            # self.key by reference for bit-identical transient retry —
-            # donating it would let a rollback restore a deleted buffer
-            held=frozenset({"params", "temps", "top_ps", "seeds", "top_ks",
-                            "eos", "key"}),
-            max_lowerings=9,
-        ),
-        ProgramSpec(
-            name="serving.mixed_prefill",
-            fn=engine._mixed_prefill_fn,
-            build=_build_mixed_prefill,
-            grid=_grid(width=(8, 128), kv=kv_axis),
-            arg_names=("params", "cache", "tokens", "base_lens", "n_valid",
-                       "emit", "temps", "top_ps", "key", "seeds", "top_ks"),
-            dead=frozenset({"cache"}),
-            held=frozenset({"params", "key"}),   # key: checkpoint-held
-            max_lowerings=4,
-        ),
-        ProgramSpec(
-            name="serving.prefill_chunk",
-            fn=engine._prefill_chunk,
-            build=_build_prefill_chunk,
-            grid=_grid(bucket=(128,), kv=kv_axis),
-            arg_names=("params", "cache", "tokens", "table_row", "base_len",
-                       "n_valid"),
-            dead=frozenset({"cache"}),
-            held=frozenset({"params"}),
-            max_lowerings=2,
-        ),
-        ProgramSpec(
-            name="serving.verify_step",
-            fn=engine._verify_step,
-            build=_build_verify_step,
-            grid=_grid(kv=kv_axis),
-            arg_names=("params", "cache", "toks", "drafts", "row_lens",
-                       "active", "temps", "top_ps", "key", "seeds", "steps",
-                       "top_ks"),
-            dead=frozenset({"cache"}),
-            held=frozenset({"params", "temps", "top_ps", "seeds",
-                            "top_ks", "key"}),   # key: checkpoint-held
-            max_lowerings=2,
-        ),
-        ProgramSpec(
-            name="serving.pp_decode_sample",
-            fn=engine._pp_decode_sample,
-            build=_build_pp_decode_sample,
-            grid=_grid(kv=("bf16",)),
-            arg_names=("params", "cache", "toks", "row_lens", "active",
-                       "temps", "top_ps", "key", "seeds", "steps",
-                       "top_ks"),
-            dead=frozenset({"cache"}),
-            held=frozenset({"params", "key"}),   # key: checkpoint-held
-            max_lowerings=1,
-        ),
-        ProgramSpec(
-            name="serving.pp_verify_step",
-            fn=engine._pp_verify_step,
-            build=_build_pp_verify_step,
-            grid=_grid(kv=("bf16",)),
-            arg_names=("params", "cache", "toks", "drafts", "row_lens",
-                       "active", "temps", "top_ps", "key", "seeds", "steps",
-                       "top_ks"),
-            dead=frozenset({"cache"}),
-            held=frozenset({"params", "key"}),   # key: checkpoint-held
-            max_lowerings=1,
         ),
         # -- generation.py ----------------------------------------------
         ProgramSpec(

@@ -251,7 +251,7 @@ def check_constant_bloat(spec, entry: TracedEntry):
 def check_tick_dispatches(tick, discovered: set[str]):
     """JP106: the tick's reachable jitted-callee set equals the declared
     program chain and stays within the dispatch gate."""
-    effective = discovered - set(tick.alternates)
+    effective = set(discovered)
     declared = set(tick.programs)
     if effective != declared:
         extra = sorted(effective - declared)

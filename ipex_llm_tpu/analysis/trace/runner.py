@@ -153,7 +153,7 @@ def audit(specs=None, ticks=None, manifest_path=None, update: bool = False,
             trace_rules.check_tick_dispatches(tick, discovered))
         findings.extend(_apply_spec_suppressions(
             _TickShim(tick), tick_findings))
-        tick_results.append((tick, discovered - set(tick.alternates)))
+        tick_results.append((tick, discovered))
 
     built = manifest_mod.build(program_results, tick_results)
     if update:

@@ -27,15 +27,12 @@ class TickSpec:
 
     ``entries`` are the scheduler functions that make up the tick (host
     Python, searched by name anywhere in the module — methods included);
-    ``programs`` the jitted callees that ARE the tick's dispatch chain;
-    ``alternates`` jitted callees reachable from the same source but on a
-    different engine mode's path (they don't count against this tick).
+    ``programs`` the jitted callees that ARE the tick's dispatch chain.
     """
     name: str
     module: str                       # import path of the engine module
     entries: tuple[str, ...]
     programs: tuple[str, ...]
-    alternates: tuple[str, ...] = ()
     max_dispatches: int = 2
     suppress: tuple[tuple[str, str], ...] = ()   # (code, reason)
 
@@ -46,15 +43,12 @@ def mixed_tick_spec() -> TickSpec:
     and the decode horizon in a single jitted entry; the steady-state
     tick is the same entry with no prefill block).  The gate is EXACTLY
     1 dispatch per tick — the ragged paged-attention superkernel
-    invariant (ROADMAP item 1, landed); the chained
-    ``_mixed_prefill_fn`` + ``_decode_multi_step`` pair survives only as
-    the equivalence oracle, unreachable from the tick entries."""
+    invariant."""
     return TickSpec(
         name="mixed",
         module="ipex_llm_tpu.serving.engine",
         entries=("_mixed_step", "_horizon_step"),
         programs=("_ragged_tick",),
-        alternates=("_pp_decode_sample",),   # pp engines route H=1 here
         max_dispatches=1,
     )
 
@@ -68,7 +62,7 @@ def _module_source(module: str) -> str:
 def discover_tick_dispatches(spec: TickSpec,
                              source: str | None = None) -> set[str]:
     """Module-level jit-bound names callable from the tick's entry
-    functions (alternates included — the caller subtracts them)."""
+    functions."""
     src = source if source is not None else _module_source(spec.module)
     tree = ast.parse(src)
     aliases = astutil.import_aliases(tree)
@@ -91,8 +85,7 @@ def discover_tick_dispatches(spec: TickSpec,
 
 
 def mixed_tick_dispatch_count(source: str | None = None) -> int:
-    """Dispatches one mixed tick issues on the non-pp path — the number
-    serving_bench stamps into its rows so BENCH artifacts track it
-    against the JP106 gate."""
-    spec = mixed_tick_spec()
-    return len(discover_tick_dispatches(spec, source) - set(spec.alternates))
+    """Dispatches one mixed tick issues — the number serving_bench
+    stamps into its rows so BENCH artifacts track it against the JP106
+    gate."""
+    return len(discover_tick_dispatches(mixed_tick_spec(), source))

@@ -1,25 +1,23 @@
 """On-device speculative-decoding helpers for the fused engine tick.
 
-The serving engine's speculative path used to draft on the HOST: an
-n-gram table scan over ``prompt_ids + output_ids`` per row per step
-(``serving/engine.py::_propose_ngram``), a per-step draft upload, and a
-blocking d2h sync to walk the acceptance chain.  This module is the
-device-resident half of moving that loop inside the one-dispatch tick
+Drafting on the host would cost an n-gram table scan over ``prompt_ids +
+output_ids`` per row per step, a per-step draft upload, and a blocking
+d2h sync to walk the acceptance chain.  This module is the
+device-resident half of keeping that loop inside the one-dispatch tick
 (the Medusa/EAGLE observation — PAPERS.md arXiv 2401.10774 / 2401.15077:
 speculative decoding pays off when draft+verify+accept stay resident on
 the accelerator): the proposer below scans each row's device-resident
 token history inside the traced program, so a speculative horizon step
 needs no host n-gram table, no draft upload, and no per-step sync.
 
-Bit-exactness contract: :func:`propose_ngram_rows` computes, per row,
-EXACTLY what ``engine._propose_ngram`` computes on the host (longest
+Reference: :func:`propose_ngram_rows` computes, per row, EXACTLY what the
+plain numpy prompt-lookup proposer (reference lookup.py:145-273: longest
 n-gram first, most recent earlier occurrence wins, continuation clipped
-at the history end) — locked by
-``tests/test_serving_spec.py::test_device_proposer_matches_host``.  The
-token streams themselves never depend on the drafts (acceptance only
-emits tokens sampled from the true conditionals), but keeping the
-proposers identical makes accept-rate telemetry comparable between the
-fused tick and the host-walk oracle.
+at the history end) computes — that proposer lives in
+``tests/test_serving_spec.py`` beside
+``test_device_proposer_matches_host``.  The token streams themselves
+never depend on the drafts (acceptance only emits tokens sampled from
+the true conditionals).
 """
 
 from __future__ import annotations
@@ -35,12 +33,12 @@ def propose_ngram_rows(hist: jnp.ndarray, lens: jnp.ndarray, k: int,
     zero-padded); ``lens`` [R] the valid history length per row (the
     current token sits at ``hist[r, lens[r] - 1]``).  For each row, find
     the most recent earlier occurrence of the trailing n-gram (longest
-    ``n <= ngram`` first — the host ``_propose_ngram`` order) and propose
+    ``n <= ngram`` first) and propose
     the ``k`` tokens that followed it.
 
     Returns ``(drafts [R, k] int32, n_prop [R] int32)``: ``drafts[r, j]``
-    is valid for ``j < n_prop[r]`` and zero-filled beyond (the value the
-    host path feeds the verify forward at unproposed positions), and
+    is valid for ``j < n_prop[r]`` and zero-filled beyond (what the verify
+    forward is fed at unproposed positions), and
     ``n_prop`` counts the proposed run — ``min(k, continuation length)``,
     0 when no n-gram of any length matches.  A row whose history is too
     short for even a 1-gram match (``lens < 2``) proposes nothing.

@@ -60,8 +60,8 @@ _COL_BIAS = ("qkv_bias", "gate_up_bias")
 # eligibility
 # --------------------------------------------------------------------------
 
-def ineligible_reason(cfg: ModelConfig, params: dict, mesh,
-                      step_budget: int) -> str | None:
+def ineligible_reason(cfg: ModelConfig, params: dict,
+                      mesh) -> str | None:
     """Why the manual tick CANNOT serve this (cfg, params, mesh) — None
     when it can.  The engine falls back to the GSPMD tick on any reason,
     so this is a routing decision, never an error."""
@@ -72,8 +72,6 @@ def ineligible_reason(cfg: ModelConfig, params: dict, mesh,
     others = {a: n for a, n in axes.items() if a != "tp" and n > 1}
     if others:
         return f"composed mesh (non-tp axes {others})"
-    if step_budget <= 0:
-        return "sequential engine (step_token_budget=0)"
     if cfg.num_heads % tp or cfg.num_kv_heads % tp:
         return (f"heads do not divide tp ({cfg.num_heads}q/"
                 f"{cfg.num_kv_heads}kv over tp={tp})")

@@ -211,22 +211,17 @@ def shard_paged_cache(cache, mesh: Mesh):
 
     Pool layers ``[L, P, Hkv, page, D]`` shard kv heads over ``tp`` (the
     same head split cache_sharding uses for dense caches; GQA with fewer kv
-    heads than tp replicates) and the layer axis over ``pp`` (each pipeline
-    stage holds its layers' pages, parallel/pipeline.py::pp_decode_step);
-    block tables and lengths are host-driven control state and stay
-    replicated.  This is the serving-side peer of the reference's vLLM TP
-    workers each holding their head slice of the paged pool (SURVEY §2.1
-    vllm/) and PPModelWorker's per-rank KV (pipeline_parallel.py:482).
+    heads than tp replicates); block tables and lengths are host-driven
+    control state and stay replicated.  This is the serving-side peer of
+    the reference's vLLM TP workers each holding their head slice of the
+    paged pool (SURVEY §2.1 vllm/).
     """
     from dataclasses import replace as _replace
 
     tp = mesh.shape.get("tp", 1)
-    pp = mesh.shape.get("pp", 1)
     n_kv_heads = cache.k.shape[2]
-    n_layers = cache.k.shape[0]
     head_axis = "tp" if tp > 1 and _divisible(n_kv_heads, tp) else None
-    layer_axis = "pp" if pp > 1 and _divisible(n_layers, pp) else None
-    pool = NamedSharding(mesh, P(layer_axis, None, head_axis, None, None))
+    pool = NamedSharding(mesh, P(None, None, head_axis, None, None))
     rep = NamedSharding(mesh, P())
     return _replace(
         cache,

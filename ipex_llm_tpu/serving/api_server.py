@@ -1077,9 +1077,8 @@ def main(argv=None):
                     help="mixed prefill+decode step: per-tick token budget "
                          "during admission waves — prefill chunks for ALL "
                          "joining requests batch with the decode step in "
-                         "one device program.  Default: the prefill "
-                         "bucket; 0 reverts to sequential one-row-one-"
-                         "chunk admission")
+                         "one device program.  Positive; default: the "
+                         "prefill bucket")
     ap.add_argument("--weight-qtype", default=None, metavar="QTYPE",
                     help="serving weight width (default: --low-bit), "
                          "authoritative end to end: the checkpoint loads "

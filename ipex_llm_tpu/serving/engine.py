@@ -11,13 +11,13 @@ pipeline_parallel.py:482-928 which rely on vLLM's paged attention):
   hash, so a page's identity covers everything before it); a new request
   reuses matching pages from earlier requests with refcounts and prefills
   only the remainder — the vLLM prefix-cache equivalent;
-- **chunked prefill**: admission runs the prompt through fixed-size chunks,
-  at most ONE chunk between decode steps, so a 2k-token prefill never stalls
-  in-flight streams by more than one chunk forward (reference gap: r2's
-  engine ran whole prefills synchronously on the engine thread);
-- every step decodes ALL rows in one jitted call; inactive rows are masked,
-  so join/leave never recompiles; per-row temperature/top-p ride as traced
-  vectors.
+- **chunked prefill**: every prefilling row advances by one ragged chunk
+  of a per-tick token budget INSIDE the tick that also decodes, so a
+  2k-token prefill never stalls in-flight streams by more than one chunk
+  forward;
+- **one program per tick** (``_ragged_tick``): it decodes ALL rows in one
+  jitted call; inactive rows are masked, so join/leave never recompiles;
+  per-row temperature/top-p ride as traced vectors.
 
 The engine thread owns the device; asyncio handlers talk to it through
 queues (reference fastapi server uses the same queue pattern,
@@ -65,9 +65,10 @@ _FAULT_VANISHED = object()
 # it fuses two ops, and whether it fuses depends on the shape of the batch:
 # measured on a v5e, ``x + linear(x)`` differs in 29% of its elements between
 # the same 128 rows as [1, 128, H] and as [8, 16, H].  A token's value would
-# then depend on the batch it rides in, and the mixed tick, a prefix hit or a
-# different admission wave would stop being bit-identical to the sequential
-# engine.  (ops/norms.py pins the one shape-dependent reduction the same way.)
+# then depend on the batch it rides in: a mixed tick, a prefix hit or a
+# different admission wave would give a request other bits than it gets
+# served alone.  (ops/norms.py pins the one shape-dependent reduction the same
+# way.)
 _EXACT_ROUNDING = {"xla_allow_excess_precision": False}
 
 # The copying upload helper was born here (PR 2's stream-corruption fix:
@@ -91,7 +92,7 @@ class EngineConfig:
     # also doubles the page count — fewer pool-contention horizon clamps,
     # fewer prefix-cache LRU evictions, higher sustainable concurrency.
     # e5m2 storage is lossy vs bf16 (engine output stays bit-identical
-    # ACROSS engine paths — mixed/sequential, H8/H1 — for a given
+    # ACROSS tick shapes — alone or in a wave, H8/H1 — for a given
     # storage); see docs/quickstart/serving.md "KV storage & memory
     # budget" for the quality expectations.
     kv_storage: str = "bf16"
@@ -140,26 +141,21 @@ class EngineConfig:
     # step verifies spec_k n-gram candidates per row in ONE batched
     # T=spec_k+1 forward.  Every position samples with the row's own
     # params, so greedy AND sampled rows emit the accepted prefix with
-    # the plain engine's distribution (seeded rows bit-identically; see
-    # _verify_step).  Decode is bandwidth-bound, so the wider step costs
-    # ~one weight pass but can emit up to spec_k+1 tokens.
+    # the spec-off engine's distribution (seeded rows bit-identically; see
+    # ``_spec_horizon_loop``).  Decode is bandwidth-bound, so the wider
+    # step costs ~one weight pass but can emit up to spec_k+1 tokens.
     #
-    # On the fused engine (the default: step_token_budget > 0, no pp
-    # mesh) the WHOLE loop is on-device and composes with the decode
-    # horizon: a jitted prompt-lookup proposer scans each row's
-    # device-resident token history (ops/speculate.py), the [R, spec_k+1]
-    # verify forward and the acceptance walk ride INSIDE
-    # ``_decode_horizon_loop``'s while_loop, and a horizon step emits
-    # 1..spec_k+1 tokens per iteration with no extra dispatch (JP106
-    # still gates the tick at ==1) and no per-step sync.  The sequential
-    # engine (step_token_budget=0) keeps the host-walk ``_spec_step`` —
-    # the seeded bit-identity oracle — and a pp mesh keeps the
-    # stage-sequential ``_pp_verify_step`` (GPipe pipelines only T=1
-    # steps at H=1; the fused tick is a single-program engine path).
+    # The WHOLE loop is on-device and composes with the decode horizon: a
+    # jitted prompt-lookup proposer scans each row's device-resident
+    # token history (ops/speculate.py), the [R, spec_k+1] verify forward
+    # and the acceptance walk ride INSIDE ``_decode_horizon_loop``'s
+    # while_loop, and a horizon step emits 1..spec_k+1 tokens per
+    # iteration with no extra dispatch (JP106 still gates the tick at
+    # ==1) and no per-step sync.
     spec_k: int = 0
     spec_ngram: int = 3         # n-gram length for the lookup proposer
     # fused decode horizon: >1 runs up to H decode+sample steps in ONE
-    # jitted on-device loop (``_decode_multi_step``: ``lax.while_loop``
+    # jitted on-device loop (``_decode_horizon_loop``: ``lax.while_loop``
     # that exits early once every row is dead) with device-resident engine
     # state, so the host pays ONE blocking sync per H tokens instead
     # of per token — the fix for the dispatch-bound regime BENCH_r05
@@ -179,9 +175,7 @@ class EngineConfig:
     # ragged-paged-attention superkernel tick; JP106 locks it).  The budget
     # fair-shares across joining rows in power-of-two per-row chunk
     # widths (bounded retraces); decode rows keep their ordinary [R, 1]
-    # step cost.  None = auto (prefill_bucket); 0 disables the mixed step
-    # (the sequential one-row-one-chunk admission path, kept for pp/spec
-    # and as the equivalence baseline).
+    # step cost.  None = auto (prefill_bucket); otherwise positive.
     step_token_budget: int | None = None
     # fault domain (PR 3): the unit of failure is a request, not the
     # engine.  Transient step faults (device preemption, pool pressure,
@@ -262,6 +256,12 @@ class EngineConfig:
     # construction.  The plan is computed BEFORE the tick checkpoint, so
     # a rollback/retry (and every bisection probe) replays the same plan.
     planner: str = "mpc"
+
+    def __post_init__(self):
+        if self.step_token_budget is not None and self.step_token_budget <= 0:
+            raise ValueError(
+                f"step_token_budget={self.step_token_budget}: the per-tick "
+                "prefill budget must be positive or None (= prefill_bucket)")
 
     @property
     def n_pages(self) -> int:
@@ -465,11 +465,7 @@ def _decode_horizon_loop(cfg: ModelConfig, params, cache, toks, row_lens,
     """The fused decode horizon BODY: up to ``horizon`` decode+sample
     steps over the whole row pool (a ``lax.while_loop`` — not
     ``lax.scan``, because the loop must exit early the moment every row
-    is dead).  ONE definition, traced into BOTH jitted entries —
-    ``_decode_multi_step`` (the historical fused-decode program, kept as
-    the equivalence oracle) and ``_ragged_tick`` (the single-dispatch
-    tick) — so the two programs cannot drift and the superkernel tick
-    stays bit-identical to the chained path by construction.
+    is dead), traced into ``_ragged_tick`` as its decode stage.
 
     toks [R] current token per row; row_lens [R] slots already in cache;
     eos [R, E] per-row stop ids (-1 pad); remain [R] output-token budget
@@ -485,14 +481,14 @@ def _decode_horizon_loop(cfg: ModelConfig, params, cache, toks, row_lens,
     ``spec_k > 0`` (static) selects the SPECULATIVE loop body: each
     iteration proposes up to ``spec_k`` prompt-lookup drafts per row from
     the device-resident token history ``hist`` [R, S] (``hist[r,
-    row_lens[r]]`` is the row's current token — ops/speculate.py, the
-    jitted twin of the host ``_propose_ngram``), runs ONE [R, spec_k+1]
-    verify forward, samples every position with the row's params keyed by
-    OUTPUT INDEX (``_sample_verify_positions`` — the same definition the
-    host-walk ``_verify_step`` traces), and walks the acceptance chain ON
-    DEVICE: emit s_0; while the draft fed at position j equals the token
-    just emitted, s_j is a draw from the true conditional — so every
-    emitted token has the plain engine's distribution (seeded rows
+    row_lens[r]]`` is the row's current token — ops/speculate.py), runs
+    ONE [R, spec_k+1] verify forward, samples every position with the
+    row's params keyed by OUTPUT INDEX (``_sample_verify_positions``), and
+    walks the acceptance chain ON DEVICE: emit s_0; while the draft fed at
+    position j equals the token just emitted, s_j is a draw from the true
+    conditional (the reference's speculative.py:805 distribution-
+    preservation contract, generalized to temperature > 0) — so every
+    emitted token has the spec-off engine's distribution (seeded rows
     bit-identically, greedy rows token-identically), and an iteration
     emits 1..spec_k+1 tokens.  EOS/budget truncation happens inside the
     accepted window; rejected drafts' KV slots are dead until overwritten
@@ -552,8 +548,7 @@ def _decode_horizon_loop(cfg: ModelConfig, params, cache, toks, row_lens,
                 nxt, lp)
 
     if horizon == 1:
-        # the H=1 program is the loop body inlined — structurally the
-        # same XLA program as the historical single-step decode
+        # the H=1 program is the loop body inlined
         (n, cache, toks, row_lens, active, key, steps, remain, nxt,
          lp) = step(jnp.asarray(0, jnp.int32), cache, toks, row_lens,
                     active, key, steps, remain)
@@ -627,9 +622,9 @@ def _spec_horizon_loop(cfg: ModelConfig, params, cache, toks, row_lens,
         t_all, lp_all, key = _sample_verify_positions(
             logits, alive, temps, top_ps, key, seeds, steps, top_ks,
             spec_k)
-        # acceptance chain (the host walk's exact rule): position j+1's
-        # sample is a draw from the true conditional only while the draft
-        # fed there equals the token just emitted
+        # acceptance chain: position j+1's sample is a draw from the true
+        # conditional only while the draft fed there equals the token just
+        # emitted
         okm = (drafts == t_all[:, :spec_k]) & (
             jnp.arange(spec_k)[None, :] < n_prop[:, None])
         n_acc = jnp.argmin(jnp.concatenate(
@@ -703,72 +698,12 @@ def _spec_horizon_loop(cfg: ModelConfig, params, cache, toks, row_lens,
             remain, key, take_block, hist, prop, acc)
 
 
-# donation covers the cache AND every dead-after-call piece of the
-# device-resident row state (toks/row_lens/active/steps/remain): the host
-# rebinds its _dev handles to the returned arrays each call, so the
-# inputs alias their advanced outputs instead of being copied per tick.
-# temps/top_ps/seeds/top_ks/eos are HELD — the host re-passes the same
-# buffers until the next epoch upload — and must never be donated.  The
-# PRNG key is held too, less obviously: _checkpoint snapshots self.key BY
-# REFERENCE for the bit-identical transient-retry contract, so donating
-# it would hand _rollback a deleted buffer whenever a fault lands after
-# the dispatch (the d2h sync is exactly where async XLA faults surface).
-# The trace audit (JP101 in analysis/trace/) locks both directions.
-@partial(jax.jit, static_argnames=("cfg", "horizon", "mesh"),
-         donate_argnums=(2, 3, 4, 5, 10, 13),
-         compiler_options=_EXACT_ROUNDING)
-def _decode_multi_step(cfg: ModelConfig, params, cache, toks, row_lens,
-                       active, temps, top_ps, key, seeds, steps, top_ks,
-                       eos, remain, horizon: int = 1, mesh=None):
-    """The historical fused-decode program: ``_decode_horizon_loop`` as
-    its own jitted entry.  The live tick path now routes through
-    ``_ragged_tick`` (which traces the SAME loop body, so outputs are
-    bit-identical); this entry remains for the pre-superkernel callers
-    and as the chained-path oracle the equivalence tests drive.
-
-    ``mesh`` (static) marks TP serving: op dispatch then emits
-    shard_map-wrapped kernels, and its presence in the jit key keeps
-    single-device and sharded engines in one process from sharing a trace.
-    Returns ([R, H] tokens, [R, H] logprobs, the number of steps actually
-    executed (the horizon early-exits once EVERY row is dead — tail
-    quantization never pays for h-1 dead forwards), cache, and the
-    advanced device state: toks, row_lens, active, steps, remain, key).
-    """
-    from ipex_llm_tpu.ops import dispatch
-
-    with dispatch.spmd(mesh):
-        return _decode_horizon_loop(
-            cfg, params, cache, toks, row_lens, active, temps, top_ps,
-            key, seeds, steps, top_ks, eos, remain, horizon)
-
-
-@partial(jax.jit, static_argnames=("cfg", "mesh", "n_micro"),
-         donate_argnums=(2,), compiler_options=_EXACT_ROUNDING)
-def _pp_decode_sample(cfg: ModelConfig, params, cache, toks, row_lens,
-                      active, temps, top_ps, key, seeds, steps, top_ks,
-                      mesh=None, n_micro=2):
-    """Pipelined decode step + sampling (PPModelWorker peer): request
-    groups flow through the pp stages in the GPipe schedule
-    (parallel/pipeline.py::pp_decode_step) instead of the stage-sequential
-    GSPMD execution _decode_step would produce on a pp mesh."""
-    from ipex_llm_tpu.ops.sampling import sample_rows_with_logprobs
-    from ipex_llm_tpu.parallel.pipeline import pp_decode_step
-
-    logits, cache = pp_decode_step(cfg, params, cache, toks, row_lens,
-                                   mesh, n_micro)
-    key, sub = jax.random.split(key)
-    nxt, lp = sample_rows_with_logprobs(logits, temps, top_ps, sub,
-                                        seeds=seeds, steps=steps,
-                                        top_ks=top_ks, active=active)
-    return nxt, lp, cache, key
-
-
 def _sample_verify_positions(logits, active, temps, top_ps, key, seeds,
                              steps, top_ks, k: int):
-    """Per-position sampling shared by BOTH verify steps: position j draws
-    from p(.|ctx, d_1..d_j) with the row's params, seeded rows keyed by
-    fold_in(seed, output_index).  ONE definition — the pp and single-mesh
-    paths must stay bit-identical for the seeded-stream contract."""
+    """Per-position sampling of the speculative verify window: position j
+    draws from p(.|ctx, d_1..d_j) with the row's params, seeded rows keyed
+    by fold_in(seed, output_index) — the key a spec-off step would use for
+    that output, which is what makes spec on bit-identical to spec off."""
     from ipex_llm_tpu.ops.sampling import sample_rows_with_logprobs
 
     key, sub = jax.random.split(key)
@@ -783,162 +718,21 @@ def _sample_verify_positions(logits, active, temps, top_ps, key, seeds,
     return t_all, lp_all, key
 
 
-@partial(jax.jit, static_argnames=("cfg", "k", "mesh", "n_micro"),
-         donate_argnums=(2,), compiler_options=_EXACT_ROUNDING)
-def _pp_verify_step(cfg: ModelConfig, params, cache, toks, drafts, row_lens,
-                    active, temps, top_ps, key, seeds, steps, top_ks,
-                    k: int, mesh=None, n_micro=2):
-    """Speculative verify step through the GPipe pipeline: the [R, k+1]
-    window rides the request-group microbatches (pp_decode_step's wide
-    form), then every position samples exactly like _verify_step."""
-    from ipex_llm_tpu.parallel.pipeline import pp_decode_step
-
-    tokens = jnp.concatenate([toks[:, None], drafts], axis=1)   # [R, k+1]
-    logits, cache = pp_decode_step(cfg, params, cache, tokens, row_lens,
-                                   mesh, n_micro)
-    t_all, lp_all, key = _sample_verify_positions(
-        logits, active, temps, top_ps, key, seeds, steps, top_ks, k)
-    return t_all, lp_all, cache, key
-
-
-@partial(jax.jit, static_argnames=("cfg", "k", "mesh"), donate_argnums=(2,),
-         compiler_options=_EXACT_ROUNDING)
-def _verify_step(cfg: ModelConfig, params, cache, toks, drafts, row_lens,
-                 active, temps, top_ps, key, seeds, steps, top_ks, k: int,
-                 mesh=None):
-    """Speculative decode step: ONE [R, k+1] forward over [cur_tok; drafts].
-
-    EVERY position samples with the row's full sampling params (position j
-    from p(.|ctx, d_1..d_j), with the row's seeded stream keyed by OUTPUT
-    INDEX).  The host walks the acceptance chain: emit s_0; while the draft
-    fed at position j equals the token just emitted, the j-th continuation
-    s_j is a valid sample from the true conditional — emit it and continue.
-    Each emitted token is therefore distributed exactly as plain decoding
-    (the reference's speculative.py:805 distribution-preservation contract,
-    generalized to temperature>0 — at T=0 this reduces to the greedy
-    token-identical chain, lookup.py:274).  Seeded rows reproduce the plain
-    engine's stream bit-for-bit because fold_in(seed, output_index) is the
-    same key either way.  KV for accepted tokens was already written by this
-    forward; rejected slots are dead until overwritten (paged rollback is
-    free, the r3 speculative.py design note).
-    """
-    from ipex_llm_tpu.ops import dispatch
-
-    with dispatch.spmd(mesh):
-        tokens = jnp.concatenate([toks[:, None], drafts], axis=1)  # [R,k+1]
-        pos = row_lens[:, None] + jnp.arange(k + 1)[None, :]
-        logits, cache = decoder_forward(
-            cfg, params, tokens, cache, pos, slot_offsets=row_lens,
-        )
-        t_all, lp_all, key = _sample_verify_positions(
-            logits, active, temps, top_ps, key, seeds, steps, top_ks, k)
-    return t_all, lp_all, cache, key
-
-
-def _propose_ngram(history: np.ndarray, k: int, ngram: int) -> np.ndarray:
-    """Prompt-lookup candidates (reference lookup.py:145-273): find the most
-    recent earlier occurrence of the trailing n-gram (longest n first) and
-    propose the k tokens that followed it.  Returns [k] int32, -1-padded."""
-    out = np.full((k,), -1, np.int32)
-    ln = len(history)
-    for n in range(min(ngram, ln - 1), 0, -1):
-        tail = history[ln - n:]
-        wins = np.lib.stride_tricks.sliding_window_view(history, n)
-        hits = np.nonzero((wins[: ln - n] == tail).all(axis=1))[0]
-        if len(hits):
-            s = int(hits[-1])  # most recent earlier occurrence
-            nxt = history[s + n: s + n + k]
-            out[: len(nxt)] = nxt
-            return out
-    return out
-
-
-@partial(jax.jit, static_argnames=("cfg", "mesh"), donate_argnums=(2,),
-         compiler_options=_EXACT_ROUNDING)
-def _prefill_chunk(cfg: ModelConfig, params, cache, tokens, table_row,
-                   base_len, n_valid, mesh=None):
-    """Run one right-padded prompt chunk for a single row.
-
-    tokens [1, C]; table_row [1, maxP] (that row's block table); base_len
-    scalar: slots already filled.  Pad positions write garbage K/V into the
-    row's own future slots — subsequent chunks/decode steps overwrite them
-    in order, and causal masking keeps valid queries from seeing them.
-    Returns (last-valid-position logits [1, V], updated cache).
-    """
-    from ipex_llm_tpu.ops import dispatch
-
-    with dispatch.spmd(mesh):
-        row_cache = replace(cache, tables=table_row)
-        pos = base_len + jnp.arange(tokens.shape[1])[None, :]
-        logits, row_cache = decoder_forward(
-            cfg, params, tokens, row_cache, pos,
-            slot_offsets=jnp.reshape(base_len, (1,)),
-        )
-        last = jnp.take_along_axis(
-            logits, (n_valid - 1).reshape(1, 1, 1).astype(jnp.int32), axis=1
-        )[:, 0]
-    return last, replace(row_cache, tables=cache.tables)
-
-
-@partial(jax.jit, static_argnames=("cfg", "mesh"), donate_argnums=(2,),
-         compiler_options=_EXACT_ROUNDING)
-def _mixed_prefill_fn(cfg: ModelConfig, params, cache, tokens, base_lens,
-                      n_valid, emit, temps, top_ps, key, seeds, top_ks,
-                      mesh=None):
-    """Batched ragged prefill over the PREFILLING rows: one device program
-    advances every joining row by a chunk, replacing the per-row
-    ``_prefill_chunk`` dispatch loop (O(rows x chunks) tiny programs).
-
-    The caller passes a cache whose ``tables`` view is row-sliced to the
-    prefilling rows (power-of-two padded), so batch position i is
-    prefilling row ``rows[i]``: tokens [P, W] right-padded chunks,
-    base_lens [P] slots already filled (pad rows carry base past the
-    table width so every write routes to the scratch page), n_valid [P]
-    real tokens this tick.  Chunk K/V scatters exactly like the
-    single-row chunk — right-pad garbage lands on the row's own future
-    slots or the scratch page, hidden from valid queries by causal
-    masking — so chunk values are bitwise those of the sequential path.
-
-    ``emit`` marks rows whose prompt completes this tick: their FIRST
-    token is sampled here, on device, from the last valid position
-    (fold_in(seed, 0) for seeded rows — the sequential engine's exact
-    first-token stream), eliminating the per-chunk host sampling round
-    trip.  Returns ([P] tokens, [P] logprobs, cache, key).
-
-    HISTORICAL NOTE: the live tick no longer dispatches this program —
-    ``_ragged_tick`` fuses the same prefill stage with the decode
-    horizon into ONE dispatch (stage 1 there is this function's body
-    with per-row ``chunk_lens`` threaded into attention).  It remains
-    module-level-jitted as half of the chained two-program oracle the
-    equivalence suite (tests/test_serving_ragged.py) drives against the
-    fused tick.
-    """
-    from ipex_llm_tpu.ops import dispatch
-    from ipex_llm_tpu.ops.sampling import sample_rows_with_logprobs
-
-    with dispatch.spmd(mesh):
-        pos = base_lens[:, None] + jnp.arange(tokens.shape[1])[None, :]
-        logits, cache = decoder_forward(
-            cfg, params, tokens, cache, pos, slot_offsets=base_lens,
-            gather_positions=jnp.maximum(n_valid - 1, 0),
-        )
-        key, sub = jax.random.split(key)
-        nxt, lp = sample_rows_with_logprobs(
-            logits, temps, top_ps, sub, seeds=seeds,
-            steps=jnp.zeros_like(n_valid), top_ks=top_ks, active=emit)
-    return nxt, lp, cache, key
-
-
-# Donation contract identical to _decode_multi_step (same positions):
-# cache/toks/row_lens/active/steps/remain are dead after the call — the
-# host rebinds its _dev handles to the returned arrays — while temps/
-# top_ps/seeds/top_ks/eos are held across epochs and the PRNG key is
-# checkpoint-held BY REFERENCE for bit-identical transient retry (PR 6's
-# rule), so neither may be donated.  ``hist`` (the speculative token
-# history, spec_k > 0 only) is device-resident dead-after-call state like
-# toks — the host rebinds _dev["hist"] to the returned buffer — so it
-# donates by name.  The prefill block's arrays and ``spec_ks`` are fresh
-# per-tick uploads, too small to matter.  JP101 locks both directions.
+# Donation covers the cache AND every dead-after-call piece of the
+# device-resident row state (toks/row_lens/active/steps/remain): the host
+# rebinds its _dev handles to the returned arrays each call, so the
+# inputs alias their advanced outputs instead of being copied per tick.
+# temps/top_ps/seeds/top_ks/eos are HELD — the host re-passes the same
+# buffers until the next epoch upload — and must never be donated.  The
+# PRNG key is held too, less obviously: _checkpoint snapshots self.key BY
+# REFERENCE for the bit-identical transient-retry contract, so donating
+# it would hand _rollback a deleted buffer whenever a fault lands after
+# the dispatch (the d2h sync is exactly where async XLA faults surface).
+# ``hist`` (the speculative token history, spec_k > 0 only) is
+# device-resident dead-after-call state like toks — the host rebinds
+# _dev["hist"] to the returned buffer — so it donates by name.  The
+# prefill block's arrays and ``spec_ks`` are fresh per-tick uploads, too
+# small to matter.  JP101 locks both directions.
 def _tick_body(cfg: ModelConfig, params, cache, toks, row_lens,
                active, temps, top_ps, key, seeds, steps, top_ks,
                eos, remain, prefill=None, horizon: int = 1,
@@ -993,8 +787,8 @@ def _tick_body(cfg: ModelConfig, params, cache, toks, row_lens,
         with jax.named_scope("tick/merge"):
             # merge the wave into the decode state (pad slots drop):
             # lengths advance for EVERY prefill row, completing rows join
-            # with their first token pre-published — the on-device form
-            # of the epoch upload the chained path paid here
+            # with their first token pre-published, so no epoch upload
+            # stands between a prompt's last chunk and its first decode
             new_len = p_base + p_nvalid
             row_lens = row_lens.at[p_rowmap].set(new_len, mode="drop")
             hit_eos = (first_t[:, None] == eos[p_rowmap]).any(axis=1)
@@ -1075,25 +869,23 @@ def _ragged_tick(cfg: ModelConfig, params, cache, toks, row_lens,
        hidden gather (``gather_positions``) is fused in.
     2. **first-token sampling + state merge**: rows whose prompt
        completes this tick (``p_emit``) sample their first token here —
-       fold_in(seed, 0), the sequential stream — and join the decode
-       state ON DEVICE exactly as the host's epoch upload would have
-       published them (toks=first, steps=1, remain-=1, active unless the
+       fold_in(seed, 0), output index 0 of the row's stream — and join
+       the decode state ON DEVICE exactly as a host epoch upload would
+       publish them (toks=first, steps=1, remain-=1, active unless the
        first token hit EOS / exhausted the budget / ``p_canjoin`` says
        the host could not back the decode KV slot).  Every prefill row's
        device length advances to its true value, so pure-chunk ticks
        still need no epoch upload.
-    3. **the fused decode horizon** (``with_decode``): the SAME
-       ``_decode_horizon_loop`` body ``_decode_multi_step`` traces, over
-       the merged state — so decode output is bit-identical to the
-       chained two-program tick, and a steady-state tick (prefill=None)
-       lowers to structurally the historical fused-decode program.
+    3. **the fused decode horizon** (``with_decode``):
+       ``_decode_horizon_loop`` over the merged state; a steady-state
+       tick (prefill=None) is this stage alone.
 
     ``with_decode=False`` (a pure-chunk tick with no decoding rows)
     skips stage 3 entirely: no wasted all-masked forward, and the key
-    chain only advances by the prefill split — the chained path's exact
-    behaviour.  Returns (first_t [P], first_lp [P] — None without a
-    prefill block —, [R, H] tokens, [R, H] logprobs, steps executed,
-    cache, toks, row_lens, active, steps, remain, key).
+    chain only advances by the prefill split.  Returns (first_t [P],
+    first_lp [P] — None without a prefill block —, [R, H] tokens, [R, H]
+    logprobs, steps executed, cache, toks, row_lens, active, steps,
+    remain, key).
 
     ``spec_k > 0`` (static) runs stage 3 as the SPECULATIVE horizon loop
     (``_spec_horizon_loop``: on-device draft from ``hist``, [R, spec_k+1]
@@ -1130,12 +922,7 @@ def _ragged_tick(cfg: ModelConfig, params, cache, toks, row_lens,
             state_src=state_src)
 
 
-# The entry's name until PR 25, kept for what imports it from outside the
-# package (chip_smoke.py, perfbench/rehearse_memory.py, tests).  The entry
-# was renamed because the compiled program's name is part of the persistent
-# compile cache's key and the names of its parts (the scopes above, which
-# are op metadata) are not: under the old name an executable cached before
-# the scopes existed would be loaded again without them.
+# The entry's name until PR 25; perfbench/rehearse_memory.py still imports it.
 _ragged_tick_fn = _ragged_tick
 
 
@@ -1181,10 +968,11 @@ class ServingEngine:
             raise ValueError("spec_ngram must be >= 1 when spec_k > 0")
         if self.ec.decode_horizon < 1:
             raise ValueError("decode_horizon must be >= 1")
-        if (self.ec.step_token_budget is not None
-                and self.ec.step_token_budget < 0):
-            raise ValueError("step_token_budget must be >= 0 (0 disables "
-                             "the mixed prefill+decode step)")
+        if mesh is not None and mesh.shape.get("pp", 1) > 1:
+            raise ValueError(
+                f"mesh has pp={mesh.shape['pp']}: serving shards over tp "
+                "(tensor_parallel_size); parallel/pipeline.py's "
+                "pipeline_forward remains for training and generate()")
         if self.ec.kv_pool_bytes < 0:
             raise ValueError("kv_pool_bytes must be >= 0 (0 = size the "
                              "pool in pages via pool_pages)")
@@ -1315,9 +1103,8 @@ class ServingEngine:
         # fully-manual shard_map region (parallel/manual.py), per-shard
         # pools and explicit quantized collectives, GSPMD out of the loop.
         # Anything the manual layout does not cover (composed meshes, MoE,
-        # MLA, non-dividing heads, the sequential oracle engine) falls
-        # back to the per-op GSPMD path, with the reason recorded for
-        # /health-side debugging.
+        # MLA, non-dividing heads) falls back to the per-op GSPMD path,
+        # with the reason recorded for /health-side debugging.
         self._tp_manual = False
         self._tp_fallback_reason: str | None = None
         from ipex_llm_tpu.ops import collectives
@@ -1331,11 +1118,7 @@ class ServingEngine:
             from ipex_llm_tpu.parallel.shard import (shard_paged_cache,
                                                      shard_params)
 
-            budget = (self.ec.prefill_bucket
-                      if self.ec.step_token_budget is None
-                      else int(self.ec.step_token_budget))
-            reason = manual.ineligible_reason(cfg, params, self.mesh,
-                                              budget)
+            reason = manual.ineligible_reason(cfg, params, self.mesh)
             if reason is None:
                 params = manual.shard_params_manual(params, cfg,
                                                     self.mesh)
@@ -1348,45 +1131,10 @@ class ServingEngine:
             cache = shard_paged_cache(cache, self.mesh)
         self.params = params
         self.cache = cache
-        # pipelined decode (PPModelWorker peer): GPipe request groups over
-        # the pp axis (on a composed mesh the other axes stay under GSPMD
-        # inside the pipeline's pp-manual region); speculative verify
-        # steps ride the pipeline's wide (T=k+1) form.  What it can't
-        # serve (MoE dual stack, non-dividing shapes) falls back to GSPMD
-        # decode, which is correct but leaves chips idle.
-        pp = self.mesh.shape.get("pp", 1) if self.mesh is not None else 1
-        self._pp_mode = (
-            pp > 1
-            and cfg.num_layers % pp == 0
-            and r % pp == 0
-            and "layers_dense" not in params
-        )
-        # mixed prefill+decode step (admission-wave regime): resolved token
-        # budget per tick; 0 = sequential one-row-one-chunk admission.  The
-        # pp engine keeps the sequential path (the mixed forward would run
-        # GSPMD stage-sequential instead of the GPipe schedule).
+        # the per-tick prefill token budget of the admission wave
         self._step_budget = (self.ec.prefill_bucket
                              if self.ec.step_token_budget is None
                              else int(self.ec.step_token_budget))
-        self._mixed_mode = self._step_budget > 0 and not self._pp_mode
-        # on-device speculative decode inside the fused tick: the mixed/
-        # horizon engine threads spec through _ragged_tick (draft +
-        # verify + accept in the device horizon loop, still one dispatch).
-        # The sequential engine (step_token_budget=0) and the pp engine
-        # keep the host-walk _spec_step — the former is the seeded
-        # bit-identity oracle the fused path is tested against, the
-        # latter genuinely cannot fuse (GPipe pipelines T=1 steps only).
-        self._fused_spec = self.ec.spec_k > 0 and self._mixed_mode
-        if (self.ec.spec_k > 0 and self.ec.decode_horizon > 1
-                and not self._fused_spec):
-            # the host-walk paths run ONE verify round per tick and would
-            # silently drop the requested horizon — refuse loudly (the
-            # genuinely unsupported combos: a pp mesh, or the sequential
-            # budget=0 oracle engine)
-            raise ValueError(
-                "decode_horizon > 1 with spec_k > 0 needs the fused "
-                "engine (step_token_budget > 0 and no pp mesh); the "
-                "host-walk verify path cannot fuse horizons")
         # host-RAM KV spill tier: evicted prefix pages (and finished
         # rows' decode pages) demote here instead of being lost, and
         # swap back on a prefix hit (serving/pagestore.py)
@@ -1636,13 +1384,6 @@ class ServingEngine:
             raise ValueError(
                 why + "a mesh is not supported: neither the state nor the "
                 "expert layer is sharded (parallel/manual.py)")
-        budget = (self.ec.prefill_bucket
-                  if self.ec.step_token_budget is None
-                  else int(self.ec.step_token_budget))
-        if budget <= 0:
-            raise ValueError(
-                why + "step_token_budget=0 (the sequential engine) is not "
-                "supported: the state rides the fused tick alone")
 
     def _state_call(self) -> tuple:
         """(the cache to hand the tick, its ``state_src``) for a model
@@ -1831,7 +1572,6 @@ class ServingEngine:
         return {
             "spec_k": self.ec.spec_k,
             "spec_ngram": self.ec.spec_ngram,
-            "fused": self._fused_spec,
             "draft_proposed": m.get("draft_proposed", 0),
             "draft_accepted": m.get("draft_accepted", 0),
             "accept_rate": round(w_acc / w_prop, 4) if w_prop else 0.0,
@@ -2923,7 +2663,7 @@ class ServingEngine:
             ("top_ps", self.top_ps), ("seeds", self.seeds),
             ("top_ks", self.top_ks), ("steps", steps), ("remain", remain),
             ("eos", eos))}
-        if self._fused_spec:
+        if self.ec.spec_k > 0:
             # device-resident token history for the on-device prompt-
             # lookup proposer: the FULL prompt lands at the admission
             # epoch (it is known in whole then, so mid-prefill rows need
@@ -2953,9 +2693,8 @@ class ServingEngine:
 
     def _flush_dirty_tables(self) -> PagedKVCache:
         """Scatter only the dirty block-table rows into the device-resident
-        tables (kv.with_table_rows) and return the current cache — the
-        per-chunk full-table re-upload the sequential prefill used to pay,
-        reduced to the rows that actually changed."""
+        tables (kv.with_table_rows) and return the current cache: a
+        whole-table upload reduced to the rows that actually changed."""
         if self._dirty_tables:
             rows = np.array(sorted(self._dirty_tables), np.int32)
             self.cache = self.cache.with_table_rows(
@@ -3050,10 +2789,9 @@ class ServingEngine:
             shareable = min(len(keys), (n_p - 1) // ps)
             # plan the chain: device prefix hits take their row ref
             # immediately (protecting them from the batched promotion's
-            # evictions, exactly like the old sequential addref), store
-            # misses are take()n so the spill-tier promotion — a PCIe
-            # copy instead of re-prefilling the chunk — lands as ONE
-            # batched scatter + barrier for the whole chain
+            # evictions), store misses are take()n so the spill-tier
+            # promotion — a PCIe copy instead of re-prefilling the chunk —
+            # lands as ONE batched scatter + barrier for the whole chain
             plan: list[tuple] = []      # ("dev", pid) | ("host", key, entry)
             for i in range(shareable):
                 pid = self.alloc.lookup_prefix(keys[i])
@@ -3132,83 +2870,9 @@ class ServingEngine:
             admitted += 1
             self._dirty = True  # admission epoch: new row state to upload
 
-    def _prefill_one_chunk(self):
-        """Advance ONE prefilling row by one chunk (bounded stall)."""
-        if not self._prefilling:
-            return
-        # first prefilling row not masked out by a bisection probe (stale
-        # None-row entries still picked so their cleanup path runs)
-        row = next((r for r in self._prefilling
-                    if self.rows[r] is None
-                    or self.rows[r].request_id not in self._masked), None)
-        if row is None:
-            return
-        req = self.rows[row]
-        if req is None or req.cancelled:
-            self._prefilling.pop(row, None)
-            if req is not None:
-                self._finish(row, "abort")
-            return
-        remaining = self._prefilling[row]
-        cp = self.ec.prefill_bucket
-        chunk = remaining[:cp]
-        n_valid = len(chunk)
-        base = int(self.row_lens[row])
-        # pages are needed only for real tokens; the right-pad tail
-        # lands on the scratch page via update_layer's valid mask
-        if not self._ensure_pages(row, base + n_valid):
-            self._finish(row, "error")  # pool exhausted mid-prefill
-            self._prefilling.pop(row, None)
-            return
-        toks = np.zeros((1, cp), np.int32)
-        toks[0, :n_valid] = chunk
-        self._fault_point("prefill-chunk", rows=(row,))
-        t0_w = time.time()
-        # dirty-row table sync: only the rows whose tables changed since
-        # the last device call are scattered in (this row's new pages),
-        # not the whole [R, maxP] table per chunk
-        cache = self._flush_dirty_tables()
-        with self._perf_dispatch("tick.seq_prefill"):
-            logits, self.cache = _prefill_chunk(
-                self.cfg, self.params, cache, h2d(toks),
-                h2d(self.tables[row : row + 1]),
-                h2d(base, jnp.int32), h2d(n_valid, jnp.int32),
-                mesh=self.mesh,
-            )
-        self._tick_dispatches += 1
-        self._phase_to("drain")
-        self.row_lens[row] = base + n_valid
-        self._trace(req, "prefill_chunk", t0=t0_w, t1=time.time(),
-                    tokens=n_valid, base=base)
-        self._dirty = True  # prefill epoch: row_lens advanced
-        if n_valid < len(remaining):
-            self._prefilling[row] = remaining[n_valid:]
-            return
-        # prompt complete: sample the first token, enter decode
-        self._prefilling.pop(row, None)
-        from ipex_llm_tpu.ops.sampling import sample_rows_with_logprobs
-
-        self.key, sub = jax.random.split(self.key)
-        first_t, first_lp = sample_rows_with_logprobs(
-            logits, h2d([req.temperature], jnp.float32),
-            h2d([req.top_p], jnp.float32), sub,
-            seeds=h2d([-1 if req.seed is None else int(req.seed)],
-                              jnp.int32),
-            steps=jnp.zeros((1,), jnp.int32),
-            top_ks=h2d([max(0, int(req.top_k or 0))], jnp.int32),
-        )
-        self._fault_point("sample", rows=(row,))
-        with self._sync_window():   # blocking materialization
-            # jaxlint: disable=JL002 -- designed sync: the first token must reach the host to emit (TTFT); counted via _count_sync
-            first = int(d2h(first_t)[0])
-            first_lp = d2h(first_lp)  # jaxlint: disable=JL002 -- same designed first-token sync; already blocked on first_t above
-        self._finish_prompt(row, first, float(first_lp[0]))
-
     def _finish_prompt(self, row: int, first: int, logprob: float):
-        """Prompt-completion bookkeeping shared by the sequential and
-        mixed admission paths — ONE definition (prefix-page registration
-        bound, TTFT record, first-token emission), so the two paths
-        cannot drift apart under the bit-identity contract."""
+        """Prompt-completion bookkeeping: prefix-page registration bound,
+        TTFT record, first-token emission."""
         n_p = int(self.row_lens[row])
         keys = self._row_keys.pop(row, [])
         for j in range(min(len(keys), (n_p - 1) // self.ec.page_size)):
@@ -3337,7 +3001,7 @@ class ServingEngine:
         return plan.spec_cap
 
     def _spec_widths(self, active: np.ndarray) -> np.ndarray:
-        """Per-row draft width for a fused-spec tick — the per-request
+        """Per-row draft width for a speculative tick — the per-request
         knobs AND the plan's caps as TRACED MASKS, so one compiled
         program serves every opt-out mix."""
         ks = np.zeros((len(self.rows),), np.int32)
@@ -3349,9 +3013,10 @@ class ServingEngine:
 
     def _spec_metrics(self, take_block: np.ndarray, s_prop, s_acc,
                       executed: int):
-        """Fused-spec tick accounting: the verify-round counters the
-        sequential host walk kept (spec_steps/spec_emitted/accept_rate —
-        one loop iteration is one verify round) plus the draft-economics
+        """Spec tick accounting: the verify-round counters
+        (spec_steps/spec_emitted/accept_rate — one loop iteration is one
+        verify round; normalized by ACTIVE ROW-STEPS, so concurrent rows
+        cannot push the rate past 1.0) plus the draft-economics
         pair the /health spec block and the bench sweep report.  All of
         it lives in the checkpointed metrics dict / rolling window, so a
         rolled-back tick never double-counts."""
@@ -3393,135 +3058,6 @@ class ServingEngine:
                 if self.rows[i] is None:
                     break
 
-    def _spec_step(self, active: np.ndarray):
-        """One speculative (prompt-lookup verify) step over the active rows."""
-        k = self.ec.spec_k
-        n_rows = len(self.rows)
-        # each row may write up to k+1 fresh KV slots this step; a row that
-        # can't get the k+1 slots under pool contention falls back to a
-        # plain single-token step (advisor r4 finding #4: finishing with
-        # 'length' truncated requests the plain engine could still serve) —
-        # its draft KV writes past the allocated page land on the scratch
-        # page via update_layer's valid mask
-        no_spec = np.zeros((n_rows,), bool)
-        for i in range(n_rows):
-            if not active[i]:
-                continue
-            if not self._ensure_pages(i, int(self.row_lens[i]) + k + 1):
-                if self._ensure_pages(i, int(self.row_lens[i]) + 1):
-                    no_spec[i] = True
-                else:
-                    self._finish(i, "length")
-                    active[i] = False
-        if not active.any():
-            return
-        drafts = np.zeros((n_rows, k), np.int32)
-        n_prop = np.zeros((n_rows,), np.int32)
-        for i in range(n_rows):
-            req = self.rows[i]
-            if not active[i] or req is None:
-                continue
-            # acceptance covers ALL temperatures (every verify position
-            # samples with the row's params — see _verify_step); a request
-            # can opt out (speculative=False) or cap its own draft width
-            # (spec_k), the reference ipex_llm_worker.py:57 per-load knobs
-            # made per-request
-            if req.speculative is not False and not no_spec[i]:
-                k_req = k if req.spec_k is None else max(
-                    0, min(int(req.spec_k), k))
-                if k_req == 0:
-                    continue
-                hist = np.concatenate([
-                    np.asarray(req.prompt_ids, np.int32),
-                    np.asarray(req.output_ids, np.int32),
-                ])
-                d = _propose_ngram(hist, k_req, self.ec.spec_ngram)
-                valid = d >= 0
-                n_prop[i] = k_req if valid.all() else int(valid.argmin())
-                drafts[i, :k_req] = np.where(valid, d, 0)
-        self._fault_point("decode-dispatch",
-                          rows=[i for i in range(n_rows) if active[i]])
-        t0_w = time.time()
-        cache = self._flush_dirty_tables()
-        steps = np.asarray([
-            len(r.output_ids) if r is not None else 0 for r in self.rows
-        ], np.int32)
-        verify_fn, extra = _verify_step, {}
-        if self._pp_mode:
-            verify_fn = _pp_verify_step
-            extra = {"n_micro": self.mesh.shape["pp"]}
-        with self._perf_dispatch("tick.spec_host"):
-            t_all, lp_all, self.cache, self.key = verify_fn(
-                self.cfg, self.params, cache,
-                h2d(self.toks), h2d(drafts),
-                h2d(self.row_lens), h2d(active),
-                h2d(self.temps), h2d(self.top_ps), self.key,
-                h2d(self.seeds), h2d(steps),
-                h2d(self.top_ks), k=k, mesh=self.mesh, **extra,
-            )
-        self._tick_dispatches += 1
-        self._phase_to("drain")
-        with self._sync_window():
-            # jaxlint: disable=JL002 -- designed sync: the verify round's accepted tokens must reach the host to walk acceptance chains; counted via _count_sync
-            t_all, lp_all = d2h(t_all), d2h(lp_all)
-        self.metrics["steps"] += 1
-        self.metrics["pages_in_use"] = self.alloc.pages_in_use
-        self._dirty = True  # host walks acceptance chains: state diverges
-        emitted_total = 0
-        for i in range(n_rows):
-            if not active[i] or self.rows[i] is None:
-                continue
-            req_i = self.rows[i]
-            emitted = [(int(t_all[i, 0]), float(lp_all[i, 0]))]
-            for j in range(int(n_prop[i])):
-                # the draft fed at position j+1 must equal the token just
-                # emitted for logits[j+1] (and thus sample s_{j+1}) to be a
-                # draw from the true conditional
-                if int(drafts[i, j]) != emitted[-1][0]:
-                    break
-                emitted.append((int(t_all[i, j + 1]),
-                                float(lp_all[i, j + 1])))
-            # KV for every emitted token except the last is already in the
-            # pool (the forward wrote slots row_len..row_len+k); the last
-            # emitted token is the next step's input, written then
-            self.row_lens[i] += len(emitted)
-            self.toks[i] = emitted[-1][0]
-            emitted_total += len(emitted)
-            for tok, lp in emitted:
-                self._emit(i, tok, lp)
-                if self.rows[i] is None:  # finished (eos/length/abort) mid-chain
-                    break
-            self._trace(req_i, "spec_round", t0=t0_w, t1=time.time(),
-                        rounds=1, tokens=len(emitted),
-                        accepted=len(emitted) - 1)
-        self.metrics["spec_steps"] = self.metrics.get("spec_steps", 0) + 1
-        self.metrics["spec_emitted"] = (
-            self.metrics.get("spec_emitted", 0) + emitted_total
-        )
-        # normalize by ACTIVE ROW-STEPS, not steps: with concurrent rows a
-        # per-step divisor both overstated the rate (could exceed 1.0) and
-        # understated it when rows sat idle (advisor r4 finding #2)
-        self.metrics["spec_row_steps"] = (
-            self.metrics.get("spec_row_steps", 0) + int(active.sum())
-        )
-        self.metrics["spec_accept_rate"] = round(
-            self.metrics["spec_emitted"]
-            / ((k + 1) * max(self.metrics["spec_row_steps"], 1)), 4)
-        # draft economics: the host walk feeds the SAME counters and
-        # rolling window the fused tick feeds (_spec_metrics), so
-        # /health's spec block is meaningful on the oracle/pp engines too
-        prop = int(n_prop.sum())
-        acc = emitted_total - int(active.sum())   # each row's free token
-        m = self.metrics
-        m["spec_ticks"] = m.get("spec_ticks", 0) + 1
-        m["draft_proposed"] = m.get("draft_proposed", 0) + prop
-        m["draft_accepted"] = m.get("draft_accepted", 0) + acc
-        m["spec_tokens_per_dispatch"] = round(
-            m["spec_emitted"] / max(m["spec_ticks"], 1), 2)
-        self._spec_window.append((prop, acc))
-        self.metrics["tokens_per_sync"] = round(
-            self.metrics["tokens"] / self.metrics["host_syncs"], 2)
-
     def _loop(self):
         while not self._stop.is_set():
             self._drain_host_ops()
@@ -3546,15 +3082,12 @@ class ServingEngine:
                 "engine stopped before servicing the host operation")))
 
     def _step_once(self):
-        """Scheduler: three regimes, ONE dispatch per tick.  Admission
-        wave (any row prefilling) → ``_mixed_step`` fuses every prefill
-        chunk, on-device first-token merge, and the decode step into the
-        single ``_ragged_tick`` program; steady state → the fused
-        decode horizon through the SAME entry (bit-identical to the
-        historical ``_decode_multi_step``).  ``spec_k`` rides INSIDE that
-        one program on the fused engine (on-device draft+verify+accept);
-        only the sequential (budget=0) oracle and pp engines keep the
-        one-row-one-chunk admission path with the host-walk verify."""
+        """Scheduler: admit, then ONE ``_ragged_tick`` dispatch.
+        Admission wave (any row prefilling) → ``_mixed_step`` fuses every
+        prefill chunk, on-device first-token merge, and the decode step;
+        steady state → the fused decode horizon through the SAME entry.
+        ``spec_k`` rides INSIDE that program (on-device
+        draft+verify+accept)."""
         with self._phase("admit"):
             self._drain_inbox()
             self._expire_deadlines()
@@ -3566,21 +3099,13 @@ class ServingEngine:
         # every step function below builds its inputs (prepare), makes its
         # one dispatch, and walks what came back (drain)
         self._phase_to("prepare")
-        if self._prefilling and self._mixed_mode:
+        if self._prefilling:
             self._mixed_step()
             return
-        self._prefill_one_chunk()
         active = self._active_mask()
         if not active.any():
-            if self._prefilling:
-                return  # keep chunking
             self._phase_to(None)    # the idle wait is no phase's work
             self._wait_for_work()
-            return
-        if self.ec.spec_k > 0 and not self._fused_spec:
-            # the host-walk verify step: the sequential (budget=0) oracle
-            # and the pp engine's stage-sequential wide step
-            self._spec_step(active)
             return
         self._horizon_step(active)
 
@@ -3603,18 +3128,14 @@ class ServingEngine:
         all inside a single jitted entry, so a mixed tick pays one
         dispatch and at most one blocking sync (completion ticks fetch
         first tokens and the decode block from the same program).  The
-        JP106 trace gate locks the one-dispatch invariant; the chained
-        two-program tick survives only as the equivalence oracle.
+        JP106 trace gate locks the one-dispatch invariant.
 
         Budget split: the per-tick token budget divides across prefilling
         rows in a power-of-two per-row chunk width (so every joining row
         advances every tick and the tick program retraces at most once
         per width), decode rows keep their [R, 1] step cost inside the
-        fused program — one token per tick, the sequential engine's exact
-        pace and loop body, so their streams stay trivially
-        bit-identical."""
-        if not self._prefilling:
-            return
+        fused program — one token per tick, the steady tick's loop body,
+        so their streams do not depend on who is joining."""
         rows = sorted(r for r in self._prefilling
                       if self.rows[r] is not None
                       and self.rows[r].request_id not in self._masked)
@@ -3672,12 +3193,11 @@ class ServingEngine:
         # a completing row's first decode step runs INSIDE this same
         # program and writes KV at slot b+n_i — back it now, or the row
         # sits the decode stage out and finishes 'length' after its
-        # first token (the old second dispatch's dry-pool behaviour,
-        # decided pre-dispatch).  This runs AFTER every row's chunk
-        # pages are ensured: under pool pressure the extra decode page
-        # must never starve a later row's prefill chunk (which would
+        # first token (decided pre-dispatch).  This runs AFTER every row's
+        # chunk pages are ensured: under pool pressure the extra decode
+        # page must never starve a later row's prefill chunk (which would
         # turn that request's graceful progress into a hard 'error').
-        # Fused-spec joiners additionally reserve their draft window
+        # Speculating joiners additionally reserve their draft window
         # (min(k+1, budget after the first token) slots) so a prompt
         # completing this tick can speculate on its first decode
         # iteration; a pool that can back only the plain slot zeroes the
@@ -3685,7 +3205,7 @@ class ServingEngine:
         # can mask speculation off for the tick (draft economics): the
         # spec-free program variant dispatches instead — a locked grid
         # point, not a new trace.
-        fused = (self._fused_spec
+        fused = (self.ec.spec_k > 0
                  and (self._plan.spec_on if self._plan is not None
                       else True))
         spec_ks = (np.zeros((len(self.rows),), np.int32)
@@ -3706,11 +3226,10 @@ class ServingEngine:
             if spec_ks is not None:
                 spec_ks[row] = k_i
         # decode participants need their next KV slot backed BEFORE the
-        # single dispatch (the old second dispatch's pre-allocation): a
-        # row the pool cannot back finishes 'length' here and is
-        # excluded from the uploaded active mask.  (No horizon clamp
-        # like _horizon_step's: at want=1 a failed ensure always means
-        # zero backed slots remain.)  Fused-spec rows reserve their
+        # single dispatch: a row the pool cannot back finishes 'length'
+        # here and is excluded from the uploaded active mask.  (No horizon
+        # clamp like _horizon_step's: at want=1 a failed ensure always
+        # means zero backed slots remain.)  Speculating rows reserve their
         # draft window first and drop to the plain width under pressure.
         active = self._active_mask()
         for i in range(len(self.rows)):
@@ -3734,19 +3253,17 @@ class ServingEngine:
                 spec_ks[i] = k_i
         # pure-chunk ticks with nothing decoding skip the decode stage
         # entirely (statically): no all-masked forward, and the key chain
-        # advances only by the prefill split — the chained path's exact
-        # behaviour when it skipped the second dispatch.  ONE known
-        # deviation: emit is decidable pre-dispatch but the first token's
-        # EOS/budget fate is not, so a completion whose row dies at its
-        # first token (no other rows active) still runs an all-dead
-        # decode stage and splits the key once more than the chained
-        # path did.  Greedy and seeded streams are untouched (seeded
+        # advances only by the prefill split.  emit is decidable
+        # pre-dispatch but the first token's EOS/budget fate is not, so a
+        # completion whose row dies at its first token (no other rows
+        # active) still runs an all-dead decode stage and splits the key
+        # once more.  Greedy and seeded streams are untouched (seeded
         # rows key on fold_in(seed, step), never the engine chain); only
         # unseeded temperature>0 draws after that corner differ, and
         # those carry no reproducibility contract (same distribution,
         # different stream).
         with_decode = bool(active.any() or emit.any())
-        if self._fused_spec and with_decode and not fused:
+        if self.ec.spec_k > 0 and with_decode and not fused:
             # decode emits through the spec-free variant (the plan masked
             # speculation off): the device-resident token history is not
             # maintained this tick
@@ -3760,8 +3277,8 @@ class ServingEngine:
         # decode participants = rows already decoding PLUS completions
         # that can join the decode stage this tick: a request-scoped
         # fault at this site must fire on the tick its request first
-        # decodes (the chained path fired it post-completion), or the
-        # rollback contract would let its first tokens commit
+        # decodes, or the rollback contract would let its first tokens
+        # commit
         decode_rows = [i for i in range(len(self.rows)) if active[i]]
         decode_rows += [row for s, row, _ in chunks
                         if emit[s] and canjoin[s]]
@@ -3841,8 +3358,8 @@ class ServingEngine:
             self._state_done(state_src)
             self._tick_dispatches += 1
         self._phase_to("drain")
-        # advance bookkeeping; completed prompts run the shared
-        # completion path (_finish_prompt) once their token arrives
+        # advance bookkeeping; completed prompts run _finish_prompt once
+        # their token arrives
         completing: list[tuple[int, int]] = []   # (slot, row)
         for i, row, n_i in chunks:
             self.row_lens[row] += n_i
@@ -3882,8 +3399,7 @@ class ServingEngine:
             self._finish_prompt(row, int(nxt[i]), float(lp[i]))
             if not canjoin[i] and self.rows[row] is not None:
                 # the pool could not back its decode slot: the program
-                # kept it out of the decode stage; finish like the old
-                # second dispatch's dry-pool path
+                # kept it out of the decode stage
                 self._finish(row, "length")
         self.metrics["steps"] += executed
         self.metrics["decode_horizon_effective"] = 1
@@ -3917,28 +3433,25 @@ class ServingEngine:
         # One visible difference from the inline era: an arrival racing
         # into the inbox AFTER planning waits out at most one
         # already-planned horizon (streams stay bit-identical either way
-        # — the H8==H1 contract).  pp meshes cannot fuse a horizon
-        # (GPipe pipelines T=1 steps only).
-        self._phase_to("prepare")   # again, after a sequential chunk's drain
+        # — the H8==H1 contract).
         plan = self._plan
-        H = 1 if self._pp_mode else (plan.horizon if plan is not None
-                                     else self.ec.decode_horizon)
+        H = plan.horizon if plan is not None else self.ec.decode_horizon
         # pre-allocate pages for the whole horizon; a tight pool shortens
         # the horizon for the step (power-of-two buckets bound recompiles)
         # instead of truncating requests the plain engine could still
         # serve — the mid-tick safety clamp under the planner: page-pool
         # reality outranks any prediction, and a cut planned horizon is
         # recorded for the flight ring (plan_clamped).
-        # A fused-spec row wants min(H * (k_row+1), remaining budget)
+        # A speculating row wants min(H * (k_row+1), remaining budget)
         # slots — accepted tokens never outrun the budget, and writes past
         # the backed range are rejected drafts the scratch page absorbs —
-        # and falls back to the plain width (spec off for this tick, the
-        # traced-mask form of _spec_step's no_spec fallback) before the
-        # whole tick's horizon is clamped on its account.
+        # and falls back to the plain width (spec off for this tick, as a
+        # traced mask) before the whole tick's horizon is clamped on its
+        # account.
         h = H
-        fused_spec = (self._fused_spec
+        fused_spec = (self.ec.spec_k > 0
                       and (plan.spec_on if plan is not None else True))
-        if self._fused_spec and not fused_spec:
+        if self.ec.spec_k > 0 and not fused_spec:
             # the plan masked speculation off: this tick emits through
             # the plain steady program, which does not maintain the
             # device-resident token history
@@ -3988,23 +3501,7 @@ class ServingEngine:
                                    ew=int(dev["eos"].shape[1]))
         rows_active = int(active.sum())
         attn_pages = self._count_attn_pages(active)
-        if self._pp_mode:
-            with self._perf_dispatch("tick.pp", rows_active=rows_active,
-                                     **attn_pages):
-                nxt, lp, self.cache, self.key = _pp_decode_sample(
-                    self.cfg, self.params, self.cache, dev["toks"],
-                    dev["row_lens"], dev["active"], dev["temps"],
-                    dev["top_ps"], self.key, dev["seeds"], dev["steps"],
-                    dev["top_ks"],
-                    mesh=self.mesh, n_micro=self.mesh.shape["pp"])  # jaxlint: disable=JL003 -- pp mesh shape is fixed for the engine lifetime: exactly one compiled program
-            self._tick_dispatches += 1
-            tok_block, lp_block = nxt[:, None], lp[:, None]
-            # the pp schedule stays H=1 for now (a horizon scan would nest
-            # the GPipe fill/drain per step); it still routes through this
-            # entry but re-uploads per step until it learns the epoch sync
-            self._dirty = True
-            executed = 1
-        elif fused_spec:
+        if fused_spec:
             # the spec-enabled form of the SAME single entry: drafting,
             # the [R, k+1] verify, and acceptance all ride inside the
             # horizon loop — still one dispatch (JP106 unchanged)
@@ -4028,9 +3525,7 @@ class ServingEngine:
             # the steady-state tick is the SAME single jitted entry the
             # mixed tick uses, with no prefill block: one program either
             # way, which is what lets JP106 pin the tick dispatch count
-            # to exactly 1 (the decode stage traces _decode_horizon_loop,
-            # so output is bit-identical to the historical
-            # _decode_multi_step program)
+            # to exactly 1
             cache_in, state_src = self._state_call()
             with self._perf_dispatch("tick.steady", point=perf_pt,
                                      rows_active=rows_active, **attn_pages):
@@ -4052,9 +3547,8 @@ class ServingEngine:
         with self._sync_window():
             tok_block = d2h(tok_block)   # jaxlint: disable=JL002 -- THE per-horizon designed sync: h tokens per host round trip, counted via _count_sync
             lp_block = d2h(lp_block)  # jaxlint: disable=JL002 -- rides THE per-horizon sync above (same dispatched program)
-            if not self._pp_mode:
-                # jaxlint: disable=JL002 -- rides THE per-horizon sync: < h only if every row died early
-                executed = self._count_state(d2h(n_exec))
+            # jaxlint: disable=JL002 -- rides THE per-horizon sync: < h only if every row died early
+            executed = self._count_state(d2h(n_exec))
         if self.perf is not None:
             # the MFU join's loop multiplier: XLA's cost analysis counts
             # the horizon body once, the tick executed it `executed` times
@@ -4063,7 +3557,7 @@ class ServingEngine:
         self.metrics["decode_horizon_effective"] = h
         self.metrics["pages_in_use"] = self.alloc.pages_in_use
         parts = self._decode_parts(active)
-        if fused_spec and not self._pp_mode:
+        if fused_spec:
             take_block = d2h(take_block)  # jaxlint: disable=JL002 -- rides THE per-horizon sync: per-iteration accepted counts for the drain walk
             self._spec_metrics(take_block, s_prop, s_acc, executed)
             self._drain_spec_block(tok_block, lp_block, take_block,

@@ -38,7 +38,7 @@ connection failures.
 
 ``FaultInjector`` is the deterministic test harness for all of the above:
 it raises scripted exceptions at named SITES inside the engine step
-(page-alloc, prefill-chunk, mixed-step, decode-dispatch, sample) on the
+(page-alloc, mixed-step, decode-dispatch, sample) on the
 Nth hit of the site, optionally only when a given request participates in
 the step — which is exactly the shape of a poisoned-request fault, and
 what makes bisection observable.  Sites fire BEFORE the device call they
@@ -164,9 +164,8 @@ def is_transient(exc: BaseException) -> bool:
 # in that operation.
 FAULT_SITES = (
     "page-alloc",        # PageAllocator growth for a row / admission
-    "prefill-chunk",     # sequential per-row prefill chunk dispatch
     "mixed-step",        # batched ragged prefill dispatch (admission wave)
-    "decode-dispatch",   # fused decode / pp / verify step dispatch
+    "decode-dispatch",   # the tick's decode stage (plain or speculative)
     "sample",            # first-token sampling / blocking result fetch
     # spill-tier / transport sites (host-RAM page store + kv_transport):
     "spill-store",       # page demotion to the host store (pre-gather)

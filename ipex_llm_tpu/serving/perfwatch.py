@@ -23,7 +23,7 @@ new syncs — JP106's one-dispatch tick is untouched):
   objects keyed ``perf_<family>_<bucket>_s`` per program family
   (``tick.steady`` / ``tick.admission`` / ``tick.spec`` for the
   ``_ragged_tick`` forms, plus ``swap_in`` and ``handoff`` epoch
-  windows and the sequential/pp oracles), ride the engine's committed
+  windows), ride the engine's committed
   /metrics exposition (the router fleet-sums them), and stamp per-tick
   fields into the flight-recorder record.
 
@@ -835,8 +835,7 @@ class PerfWatch:
             "attrib": {b: round(buckets[b], 6) for b in BUCKETS},
             "wall_s": round(wall, 6),
         }
-        # MFU join over the tick's dispatched points (one per tick on
-        # the fused engine; the sequential oracle sums its chunk+sample)
+        # MFU join over the tick's dispatched points (one per tick)
         flops = byts = 0.0
         joined = False
         for point in scratch["points"]:

@@ -112,7 +112,7 @@ class TickPlan:
     @property
     def spec_on(self) -> bool:
         """Whether this tick's fused program carries the spec stage at
-        all — the per-tick form of the engine's ``_fused_spec``."""
+        all — the per-tick form of ``EngineConfig.spec_k > 0``."""
         return self.spec_cap > 0 or any(self.spec_ks)
 
     def flight_fields(self) -> dict:
@@ -219,8 +219,8 @@ class _PlannerBase:
 class StaticPlanner(_PlannerBase):
     """The escape hatch: today's decisions, verbatim, as one plan.
 
-    Horizon folds the admission-wave clamp (streams joining ⇒ 1, a pp
-    mesh ⇒ 1, else ``decode_horizon``); the chunk budget is the resolved
+    Horizon folds the admission-wave clamp (streams joining ⇒ 1, else
+    ``decode_horizon``); the chunk budget is the resolved
     ``step_token_budget``; spec caps are the no-op ``spec_k`` everywhere
     (per-request opt-outs stay where they always were, in
     ``_row_spec_k``); admission is unbounded.  No prediction, no grid
@@ -230,12 +230,9 @@ class StaticPlanner(_PlannerBase):
 
     def plan(self, eng) -> TickPlan:
         ec = self.ec
-        if eng._pp_mode:
+        h = ec.decode_horizon
+        if h > 1 and self._streams_joining(eng):
             h = 1
-        else:
-            h = ec.decode_horizon
-            if h > 1 and self._streams_joining(eng):
-                h = 1
         return self._record(TickPlan(
             horizon=max(h, 1),
             chunk_budget=eng._step_budget,
@@ -309,7 +306,7 @@ class MPCPlanner(_PlannerBase):
         spec re-probes periodically so the window keeps tracking the
         workload."""
         k = self.ec.spec_k
-        if not eng._fused_spec or k <= 0:
+        if k <= 0:
             return k, None
         window = list(eng._spec_window)
         prop = sum(p for p, _ in window)
@@ -384,9 +381,7 @@ class MPCPlanner(_PlannerBase):
         joining = (bool(eng._prefilling) if admit_max == 0
                    else self._streams_joining(eng))
 
-        if eng._pp_mode:
-            cands = [1]
-        elif joining:
+        if joining:
             cands = [1]
             if reason == "steady":
                 reason = "joining"
@@ -414,7 +409,7 @@ class MPCPlanner(_PlannerBase):
         # escalation; widths stay pow2 <= prefill_bucket, so no new
         # program shapes)
         budget = eng._step_budget
-        if eng._mixed_mode and eng._prefilling:
+        if eng._prefilling:
             n_join = len(eng._prefilling)
             share = max(1, budget // max(n_join, 1))
             tight = False

@@ -581,7 +581,7 @@ def test_the_same_prompt_twice_prefills_twice(model):
 
 
 @pytest.mark.parametrize("field,value", [
-    ("kv_spill_bytes", 1 << 20), ("spec_k", 2), ("step_token_budget", 0)])
+    ("kv_spill_bytes", 1 << 20), ("spec_k", 2)])
 def test_what_recurrent_state_cannot_do_is_refused_by_name(model, field,
                                                             value):
     _, cfg, params = model

@@ -479,10 +479,6 @@ def _prog_a(x):
 def _prog_b(x):
     return x
 
-@partial(jax.jit)
-def _prog_alt(x):
-    return x
-
 {extra_def}
 
 def _mixed_step(self):
@@ -491,8 +487,6 @@ def _mixed_step(self):
     return _horizon_step(y)
 
 def _horizon_step(y):
-    if y:
-        return _prog_alt(y)
     return _prog_b(y)
 '''
 
@@ -500,7 +494,7 @@ def _horizon_step(y):
 def _tick_spec(**over):
     kw = dict(name="mixed", module="fixture", programs=("_prog_a", "_prog_b"),
               entries=("_mixed_step", "_horizon_step"),
-              alternates=("_prog_alt",), max_dispatches=2)
+              max_dispatches=2)
     kw.update(over)
     return TickSpec(**kw)
 
@@ -530,7 +524,7 @@ def test_jp106_fires_on_a_third_dispatch_sneaking_in():
 
 def test_real_mixed_tick_issues_one_dispatch():
     # the serving_bench row stamps this number; the ragged superkernel
-    # tick (_ragged_tick_fn) drove it from 2 to exactly 1, and JP106
+    # tick (_ragged_tick) drove it from 2 to exactly 1, and JP106
     # keeps it there
     assert mixed_tick_dispatch_count() == 1
 
@@ -631,16 +625,37 @@ def test_real_registry_zero_unsuppressed_errors(real_audit):
 
 
 def test_real_registry_covers_fp8_and_bf16_grids():
-    pool_programs = {"serving.decode_multi_step", "serving.mixed_prefill",
-                     "serving.prefill_chunk", "serving.verify_step"}
-    for spec in real_registry():
-        if spec.name in pool_programs:
-            kvs = {pt["kv"] for pt in spec.grid}
-            assert kvs == {"bf16", "fp8"}, spec.name
+    """Every single-chip form of the tick (steady, admission wave, pure
+    chunk, speculative, int4 weights) is audited over BOTH pool storages."""
+    (tick,) = [s for s in real_registry() if s.name == "serving.ragged_tick"]
+    forms: dict = {}
+    for pt in tick.grid:
+        if "tp" in pt:
+            continue
+        form = tuple(sorted((k, v) for k, v in pt.items() if k != "kv"))
+        forms.setdefault(form, set()).add(pt["kv"])
+    assert len(forms) >= 10
+    for form, kvs in forms.items():
+        assert kvs == {"bf16", "fp8"}, form
+
+
+def test_the_registry_audits_only_programs_an_entry_point_reaches():
+    """Every ``serving.*`` program the audit locks IS the tick: a spec
+    registered for a jitted entry no engine path dispatches (an oracle, a
+    retired mode) would make every device-side PR regenerate manifest
+    entries for programs nobody runs."""
+    from ipex_llm_tpu.serving import engine
+
+    serving = [s for s in real_registry() if s.name.startswith("serving.")]
+    assert [s.name for s in serving] == ["serving.ragged_tick"]
+    assert all(s.fn is engine._ragged_tick for s in serving)
+    lock = json.loads(manifest_mod.DEFAULT_PATH.read_text())
+    assert [n for n in lock["programs"] if n.startswith("serving.")] == [
+        "serving.ragged_tick"]
 
 
 def test_real_registry_covers_weight_qtype_axis():
-    """The tick (and its chained oracle) audit over stacked int4-packed
+    """The tick audits over stacked int4-packed
     weight planes too: the wq axis covers steady decode at both horizons
     on bf16+fp8 pools plus the admission-wave joiner tick — the grid
     JP107's packed-weight protection actually runs on."""
@@ -650,8 +665,7 @@ def test_real_registry_covers_weight_qtype_axis():
     assert {(pt["width"], pt["horizon"]) for pt in tick_wq} == {
         (0, 1), (0, 8), (8, 1)}
     assert {pt["kv"] for pt in tick_wq} == {"bf16", "fp8"}
-    assert any(pt.get("wq") == "sym_int4"
-               for pt in specs["serving.decode_multi_step"].grid)
+    assert {pt.get("wd", True) for pt in tick_wq} == {True, False}
 
 
 def test_manifest_locks_int4_tick_donation_map():
@@ -673,9 +687,7 @@ def test_manifest_locks_int4_tick_donation_map():
 
 def test_real_registry_names_every_issue_entry():
     names = {s.name for s in real_registry()}
-    assert {"serving.decode_multi_step", "serving.mixed_prefill",
-            "serving.prefill_chunk", "serving.verify_step",
-            "serving.pp_decode_sample", "serving.pp_verify_step",
+    assert {"serving.ragged_tick",
             "generation.prefill_step", "generation.decode_loop",
             "generation.decode_one", "multimodal.mm_prefill",
             "multimodal.mm_decode",
@@ -692,8 +704,11 @@ def test_checked_in_manifest_matches_tree(real_audit):
 
 def test_manifest_locks_engine_donation_map():
     lock = json.loads(manifest_mod.DEFAULT_PATH.read_text())
-    entries = lock["programs"]["serving.decode_multi_step"]["entries"]
-    for key, entry in entries.items():
+    entries = lock["programs"]["serving.ragged_tick"]["entries"]
+    plain = {k: v for k, v in entries.items()
+             if "spec=" not in k and "wq=" not in k}   # those: tests below
+    assert len(plain) >= 14
+    for key, entry in plain.items():
         aliased_args = {a.split("[")[0] for a in entry["aliases"]}
         # the full dead set aliases; the held set never does — including
         # the PRNG key, which _checkpoint snapshots by reference for the

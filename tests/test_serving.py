@@ -575,51 +575,6 @@ def test_speculative_sampled_seeded_matches_plain_engine(cfg_params):
     assert m["spec_emitted"] >= m["spec_steps"]
 
 
-def test_speculative_per_request_spec_k(cfg_params, monkeypatch):
-    """Request.spec_k caps the draft width per request: spec_k=0 rides the
-    wide step but never accepts drafts (one token per verify step).  To
-    make acceptance DETERMINISTIC (prompt-lookup hit rates depend on the
-    random model), the second phase feeds the proposer the first run's own
-    greedy stream — every draft then matches, so an unlimited request must
-    finish in ~1/(k+1) of the steps.  Pinned to the sequential engine
-    (step_token_budget=0): that is the path whose HOST proposer the
-    monkeypatch below can substitute — the fused engine drafts on device
-    (tests/test_serving_spec.py covers its per-request caps)."""
-    cfg, params = cfg_params
-    prompt = [3, 5, 7, 9, 11, 13]
-    eng = ServingEngine(
-        cfg, params,
-        EngineConfig(max_rows=2, max_seq_len=256, prefill_bucket=32,
-                     spec_k=3, step_token_budget=0),
-    ).start()
-    try:
-        r0 = eng.submit(Request(prompt_ids=prompt, max_new_tokens=12,
-                                spec_k=0))
-        g0 = list(stream_tokens(r0))
-        steps_solo = eng.metrics["spec_steps"]
-        # spec_k=0: no drafts proposed -> one token per verify step
-        assert steps_solo >= 11, eng.metrics
-        assert len(g0) == 12
-
-        from ipex_llm_tpu.serving import engine as eng_mod
-
-        def oracle_propose(history, k, ngram):
-            done = len(history) - len(prompt)
-            nxt = g0[done:done + k]
-            out = np.full((k,), -1, np.int32)
-            out[:len(nxt)] = nxt
-            return out
-
-        monkeypatch.setattr(eng_mod, "_propose_ngram", oracle_propose)
-        r1 = eng.submit(Request(prompt_ids=prompt, max_new_tokens=12))
-        g1 = list(stream_tokens(r1))
-    finally:
-        eng.stop()
-    assert g0 == g1  # greedy: same engine program, same tokens
-    # perfect drafts: 11 decode tokens in <= ceil(11/4)+1 verify steps
-    assert eng.metrics["spec_steps"] - steps_solo <= 5, eng.metrics
-
-
 # slow tier: long churn over an overcommitted pool — compile-dominated
 # under the fused tick's (P, W) variants; fast contention coverage rides
 # test_serving_mixed::test_mixed_respects_page_pool_contention and

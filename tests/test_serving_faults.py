@@ -104,8 +104,8 @@ def baseline(cfg_params):
 
 # -- transient faults: rollback + retry, bit-identical ----------------------
 
-# sites hit by the default (mixed-step) engine; prefill-chunk only fires on
-# the sequential admission path (budget=0), tested separately below
+# the tick's own sites (faults.FAULT_SITES' first four; the rest guard the
+# spill tier and KV transport)
 _MIXED_SITES = ("page-alloc", "mixed-step", "decode-dispatch", "sample")
 
 
@@ -126,21 +126,6 @@ def test_transient_fault_retried_bit_identical(cfg_params, baseline, site):
         np.testing.assert_array_equal(
             np.asarray(got.logprobs, np.float32),
             np.asarray(want, np.float32))
-
-
-def test_transient_fault_sequential_prefill_site(cfg_params):
-    """The sequential (budget=0) admission path retries its own sites."""
-    cfg, params = cfg_params
-    reqs0 = _wave(cfg)
-    eng0 = ServingEngine(cfg, params,
-                         EngineConfig(step_token_budget=0, **EC))
-    base = _drive(eng0, reqs0)
-    inj = FaultInjector().inject("prefill-chunk", TransientFault, nth=2)
-    eng = ServingEngine(cfg, params, EngineConfig(step_token_budget=0, **EC),
-                        fault_injector=inj)
-    reqs = _wave(cfg)
-    assert _drive(eng, reqs) == base
-    assert inj.fired == 1 and eng.metrics["retries"] == 1
 
 
 def test_retries_exhausted_escalates_to_isolation(cfg_params, baseline):
@@ -260,8 +245,8 @@ def test_fail_all_only_when_bisection_fails(cfg_params):
 def test_injector_validates_sites():
     with pytest.raises(ValueError):
         FaultInjector().inject("not-a-site", TransientFault)
-    # 5 engine-step sites + the PR 11 spill/transport sites
-    assert len(FAULT_SITES) == 9
+    # 4 engine-step sites + the PR 11 spill/transport sites
+    assert len(FAULT_SITES) == 8
     for site in ("spill-store", "swap-in", "kv-export", "kv-import"):
         assert site in FAULT_SITES
 

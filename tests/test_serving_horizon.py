@@ -167,24 +167,28 @@ def test_no_param_reupload_between_epochs(cfg_params, monkeypatch):
 
 
 def test_spec_k_composes_with_horizon(cfg_params):
-    """The PR 1 mutual-exclusion guard is gone: spec_k rides INSIDE the
-    fused horizon loop now (on-device draft/verify/accept,
-    tests/test_serving_spec.py carries the equivalence suite).  The
-    fused engine constructs and routes spec through the tick; the
-    sequential (budget=0) oracle keeps the host-walk path at H=1, and a
-    horizon it cannot fuse is refused loudly instead of silently
-    dropped (the one genuinely unsupported combo besides a pp mesh)."""
+    """spec_k rides INSIDE the fused horizon loop (on-device
+    draft/verify/accept; tests/test_serving_spec.py carries the
+    spec-on == spec-off suite): H=8 with spec_k emits H=1 with spec_k's
+    exact token AND logprob streams, greedy and seeded sampled."""
     cfg, params = cfg_params
-    eng = ServingEngine(cfg, params,
-                        EngineConfig(spec_k=2, decode_horizon=4))
-    assert eng._fused_spec
-    seq = ServingEngine(cfg, params,
-                        EngineConfig(spec_k=2, step_token_budget=0))
-    assert not seq._fused_spec
-    with pytest.raises(ValueError, match="fused engine"):
-        ServingEngine(cfg, params,
-                      EngineConfig(spec_k=2, decode_horizon=4,
-                                   step_token_budget=0))
+    rng = np.random.default_rng(11)
+    cycle = list(np.tile(rng.integers(0, cfg.vocab_size, 4), 8).astype(int))
+    specs = [dict(prompt_ids=cycle, max_new_tokens=16),
+             dict(prompt_ids=list(RNG.integers(0, cfg.vocab_size, 17)),
+                  max_new_tokens=16, temperature=0.8, top_p=0.9, top_k=40,
+                  seed=123)]
+    r1, s1, m1 = _run(cfg, params, 1, specs, spec_k=2)
+    r8, s8, m8 = _run(cfg, params, 8, specs, spec_k=2)
+    assert s1 == s8
+    for a, b in zip(r1, r8):
+        assert a.finish_reason == b.finish_reason
+        np.testing.assert_array_equal(
+            np.asarray(a.logprobs, np.float32),
+            np.asarray(b.logprobs, np.float32))
+    assert m8["spec_steps"] > 0 and m8["draft_proposed"] > 0
+    assert m8["decode_horizon_effective"] == 8
+    assert m8["spec_ticks"] < m1["spec_ticks"]    # the horizon fused
 
 
 def test_pool_dry_requeue_drops_horizon_to_single_steps(cfg_params):

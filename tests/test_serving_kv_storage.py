@@ -6,8 +6,8 @@ The contracts under test (the fp8-paged-pool PR):
   exactly the direct e5m2 cast chain (``x -> e5m2 -> bf16``) — the paged
   scatter/gather machinery adds no numerics of its own;
 - **engine-path bit-identity under fp8**: e5m2 storage is lossy vs bf16,
-  but the engine stays bit-identical to ITSELF across paths — the
-  mixed-vs-sequential and fused-horizon H8≡H1 equivalence suites re-run
+  but the engine stays bit-identical to ITSELF across tick shapes — the
+  row-in-a-wave ≡ row-alone and fused-horizon H8≡H1 suites re-run
   under ``kv_storage="fp8"``;
 - **byte-budget capacity**: at a fixed ``kv_pool_bytes``, fp8 storage
   yields exactly 2x the pages of bf16 (half the bytes per slot), visible
@@ -47,7 +47,8 @@ from ipex_llm_tpu.serving.engine import (
 )
 from ipex_llm_tpu.serving.faults import FaultInjector, TransientFault
 from tests.test_decoder import rand_params, tiny_cfg
-from tests.test_serving_mixed import _drive
+from tests.test_serving_mixed import (_assert_rows_do_not_depend_on_batch,
+                                      _drive)
 
 RNG = np.random.default_rng(91)
 
@@ -210,37 +211,17 @@ def _wave_specs(cfg):
     ]
 
 
-def test_mixed_vs_sequential_bit_identical_fp8(cfg_params):
-    """The PR-2 equivalence contract survives the storage change: mixed
-    admission over an fp8 pool emits the exact token AND logprob streams
-    of the sequential fp8 engine (both lossy vs bf16 in the same way)."""
+def test_a_row_in_a_wave_matches_the_row_alone_fp8(cfg_params):
+    """Batch invariance survives the storage change: over an fp8 pool a
+    request inside a staggered wave emits the exact token AND logprob
+    streams it emits served alone (both lossy vs bf16 in the same way)."""
     cfg, params = cfg_params
     specs = _wave_specs(cfg)
-    schedule = lambda: {0: [Request(**specs[0])], 1: [Request(**specs[1])],
-                        3: [Request(**specs[2])]}
-
-    sched_m = schedule()
-    eng_m = ServingEngine(cfg, params,
-                          EngineConfig(kv_storage="fp8", **EC))
-    streams_m = _drive(eng_m, sched_m)
-    sched_s = schedule()
-    eng_s = ServingEngine(
-        cfg, params,
-        EngineConfig(kv_storage="fp8", step_token_budget=0, **EC))
-    streams_s = _drive(eng_s, sched_s)
-
-    assert eng_m.metrics["mixed_steps"] > 0
-    assert eng_s.metrics["mixed_steps"] == 0
-    assert eng_m.cache.k.dtype == jnp.float8_e5m2
-    for a, b in zip(streams_m, streams_s):
-        assert a == b, (a, b)
-    reqs_m = [r for rs in sched_m.values() for r in rs]
-    reqs_s = [r for rs in sched_s.values() for r in rs]
-    for a, b in zip(reqs_m, reqs_s):
-        assert a.finish_reason == b.finish_reason
-        np.testing.assert_array_equal(
-            np.asarray(a.logprobs, np.float32),
-            np.asarray(b.logprobs, np.float32))
+    eng, _ = _assert_rows_do_not_depend_on_batch(
+        cfg, params, EngineConfig(kv_storage="fp8", **EC),
+        {0: [specs[0]], 1: [specs[1]], 3: [specs[2]]})
+    assert eng.metrics["mixed_steps"] > 0
+    assert eng.cache.k.dtype == jnp.float8_e5m2
 
 
 def test_fused_h8_bit_identical_to_h1_fp8(cfg_params):

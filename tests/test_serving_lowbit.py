@@ -11,9 +11,9 @@ The contracts under test (the low-bit-serving PR, ROADMAP item 3):
   deterministic (two independently-built engines hold bit-identical
   planes);
 - **engine-path bit-identity under int4** (the PR 5 fp8 pattern: lossy
-  vs bf16, self-consistent across paths): mixed admission ≡ sequential,
-  and fused H8 ≡ H1, both over int4 weights — token streams AND
-  logprobs;
+  vs bf16, self-consistent across tick shapes): a row in a wave ≡ the
+  row alone, and fused H8 ≡ H1, both over int4 weights — token streams
+  AND logprobs;
 - **qmatmul ≡ dequant-reference on the real layer body**: the decoder
   forward over packed planes is bitwise the forward over the
   pre-dequantized bf16 tree (the packing moved bytes, not math);
@@ -55,7 +55,8 @@ from ipex_llm_tpu.serving.engine import (
 )
 from ipex_llm_tpu.serving.faults import FaultInjector, TransientFault
 from tests.test_decoder import rand_params, tiny_cfg
-from tests.test_serving_mixed import _drive
+from tests.test_serving_mixed import (_assert_rows_do_not_depend_on_batch,
+                                      _drive)
 
 RNG = np.random.default_rng(93)
 
@@ -244,37 +245,17 @@ def _wave_specs(cfg):
     ]
 
 
-def test_mixed_vs_sequential_bit_identical_int4(cfg_params):
-    """The PR 2 equivalence contract survives the weight width: mixed
-    admission over int4 weights emits the exact token AND logprob streams
-    of the sequential int4 engine (both lossy vs bf16 in the same way)."""
+def test_a_row_in_a_wave_matches_the_row_alone_int4(cfg_params):
+    """Batch invariance survives the weight width: over int4 weights a
+    request inside a staggered wave emits the exact token AND logprob
+    streams it emits served alone (both lossy vs bf16 in the same way)."""
     cfg, params = cfg_params
     specs = _wave_specs(cfg)
-    schedule = lambda: {0: [Request(**specs[0])], 1: [Request(**specs[1])],
-                        3: [Request(**specs[2])]}
-
-    sched_m = schedule()
-    eng_m = ServingEngine(cfg, params,
-                          EngineConfig(weight_qtype="sym_int4", **EC))
-    streams_m = _drive(eng_m, sched_m)
-    sched_s = schedule()
-    eng_s = ServingEngine(
-        cfg, params,
-        EngineConfig(weight_qtype="sym_int4", step_token_budget=0, **EC))
-    streams_s = _drive(eng_s, sched_s)
-
-    assert eng_m.metrics["mixed_steps"] > 0
-    assert eng_s.metrics["mixed_steps"] == 0
-    assert eng_m.params["layers"]["qkv"].qtype == "sym_int4"
-    for a, b in zip(streams_m, streams_s):
-        assert a == b, (a, b)
-    reqs_m = [r for rs in sched_m.values() for r in rs]
-    reqs_s = [r for rs in sched_s.values() for r in rs]
-    for a, b in zip(reqs_m, reqs_s):
-        assert a.finish_reason == b.finish_reason
-        np.testing.assert_array_equal(
-            np.asarray(a.logprobs, np.float32),
-            np.asarray(b.logprobs, np.float32))
+    eng, _ = _assert_rows_do_not_depend_on_batch(
+        cfg, params, EngineConfig(weight_qtype="sym_int4", **EC),
+        {0: [specs[0]], 1: [specs[1]], 3: [specs[2]]})
+    assert eng.metrics["mixed_steps"] > 0
+    assert eng.params["layers"]["qkv"].qtype == "sym_int4"
 
 
 def test_fused_h8_bit_identical_to_h1_int4(cfg_params):

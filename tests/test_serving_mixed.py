@@ -3,15 +3,16 @@
 The contract under test: while any row is prefilling, the engine runs ONE
 jitted program per tick — ragged batched prefill chunks for every joining
 row, the decode step for every active row, and on-device first-token
-sampling for rows whose prompt completes — and the resulting token AND
-logprob streams are bit-identical to the sequential (one-row-one-chunk,
-chunk-then-decode) engine under the seeded-stream contract, across greedy
-rows, seeded sampled rows, and rows that finish prefill mid-wave while
-others are still prefilling.
+sampling for rows whose prompt completes — and a request's token AND
+logprob bits do not depend on what else is in the batch: served inside a
+staggered wave (narrow chunks, neighbours joining and leaving) it emits
+what it emits served alone on the same engine configuration (its chunks
+take the whole budget), across greedy rows, seeded sampled rows, and rows
+that finish prefill mid-wave while others are still prefilling.
 
 Plus the dispatch-economics tier-1 guard: an admission wave of R rows must
 issue O(total_prompt_tokens / budget) device programs and host syncs, not
-O(R x chunks) — the sequential engine's alternation cost under churn.
+O(R x chunks).
 
 Engines are driven synchronously through ``_step_once`` (never started),
 so submission timing is deterministic tick-for-tick.
@@ -58,6 +59,33 @@ def _drive(eng, schedule, max_ticks=3000):
     return [list(stream_tokens(r, timeout=10)) for r in reqs]
 
 
+def _served(req, stream):
+    """What a client saw of ``req``: tokens, logprob BITS, finish."""
+    return (stream, np.asarray(req.logprobs, np.float32).tobytes(),
+            req.finish_reason)
+
+
+def _assert_rows_do_not_depend_on_batch(cfg, params, ec, schedule):
+    """THE batch-invariance oracle.  ``schedule`` maps tick -> request
+    kwargs; the requests are served once as that staggered wave and once
+    each ALONE on a fresh engine of the same configuration, and every
+    request must emit the same tokens, the same logprob bits and the same
+    finish reason both ways.  (Greedy and seeded rows only: an unseeded
+    temperature>0 row draws from the engine's key chain, which carries no
+    reproducibility contract.)  Returns (wave engine, wave streams)."""
+    wave = {t: [Request(**kw) for kw in kws] for t, kws in schedule.items()}
+    eng = ServingEngine(cfg, params, ec)
+    streams = _drive(eng, wave)
+    reqs = [r for _, rs in sorted(wave.items()) for r in rs]
+    kws = [kw for _, ks in sorted(schedule.items()) for kw in ks]
+    for i, (req, stream, kw) in enumerate(zip(reqs, streams, kws)):
+        solo = Request(**kw)
+        (alone,) = _drive(ServingEngine(cfg, params, ec), {0: [solo]})
+        assert _served(req, stream) == _served(solo, alone), (
+            f"request {i} depends on its batch", stream, alone)
+    return eng, streams
+
+
 def _wave_specs(cfg):
     """Greedy long row, seeded sampled longer row, greedy short row that
     finishes prefill mid-wave (while the seeded row is still consuming its
@@ -73,44 +101,25 @@ def _wave_specs(cfg):
     ]
 
 
-def test_mixed_bit_identical_to_sequential_staggered(cfg_params):
-    """Staggered admissions through the mixed engine emit the exact token
-    and logprob streams of the sequential chunk-then-decode engine —
-    greedy, seeded sampled, and a row finishing prefill mid-wave."""
+def test_a_row_in_a_staggered_wave_matches_the_row_alone(cfg_params):
+    """Staggered admissions emit, request by request, the exact token and
+    logprob streams each request gets served alone — greedy, seeded
+    sampled, and a row finishing prefill mid-wave."""
     cfg, params = cfg_params
     specs = _wave_specs(cfg)
-    schedule = lambda: {0: [Request(**specs[0])], 1: [Request(**specs[1])],
-                        3: [Request(**specs[2])]}
-
-    sched_m = schedule()
-    eng_m = ServingEngine(cfg, params, EngineConfig(**EC))
-    streams_m = _drive(eng_m, sched_m)
-    sched_s = schedule()
-    eng_s = ServingEngine(cfg, params,
-                          EngineConfig(step_token_budget=0, **EC))
-    streams_s = _drive(eng_s, sched_s)
-
-    assert eng_m.metrics["mixed_steps"] > 0       # the mixed path ran
-    assert eng_s.metrics["mixed_steps"] == 0      # the baseline didn't
-    reqs_m = [r for rs in sched_m.values() for r in rs]
-    reqs_s = [r for rs in sched_s.values() for r in rs]
-    for a, b in zip(streams_m, streams_s):
-        assert a == b, (a, b)
-    for a, b in zip(reqs_m, reqs_s):
-        assert a.finish_reason == b.finish_reason
-        np.testing.assert_array_equal(
-            np.asarray(a.logprobs, np.float32),
-            np.asarray(b.logprobs, np.float32))
-    _assert_greedy_stream(cfg, params, specs[0]["prompt_ids"], streams_m[0])
-    # first tokens were sampled on device inside mixed ticks, not via the
-    # sequential per-chunk host sampling path
-    assert eng_m.metrics["prefill_tokens_per_step"] > 0
+    eng, streams = _assert_rows_do_not_depend_on_batch(
+        cfg, params, EngineConfig(**EC),
+        {0: [specs[0]], 1: [specs[1]], 3: [specs[2]]})
+    assert eng.metrics["mixed_steps"] > 0       # the wave really mixed
+    _assert_greedy_stream(cfg, params, specs[0]["prompt_ids"], streams[0])
+    # first tokens were sampled on device inside mixed ticks
+    assert eng.metrics["prefill_tokens_per_step"] > 0
 
 
-def test_mixed_first_token_eos_and_mid_wave_finish(cfg_params):
+def test_first_token_eos_mid_wave_matches_the_row_alone(cfg_params):
     """A row whose FIRST sampled token is its EOS finishes from inside a
     mixed tick with reason 'stop' while the other row keeps prefilling —
-    and both engines agree on every stream."""
+    and both rows emit what they emit alone."""
     cfg, params = cfg_params
     p_short = list(RNG.integers(0, cfg.vocab_size, 20))
     p_long = list(RNG.integers(0, cfg.vocab_size, 60))
@@ -120,52 +129,30 @@ def test_mixed_first_token_eos_and_mid_wave_finish(cfg_params):
                                           max_new_tokens=2)]})
     eos = int(ptoks[0])
 
-    def schedule():
-        return {0: [Request(prompt_ids=p_long, max_new_tokens=8)],
-                1: [Request(prompt_ids=p_short, max_new_tokens=8,
-                            eos_token_id=(eos,))]}
-
-    sched_m = schedule()
-    streams_m = _drive(ServingEngine(cfg, params, EngineConfig(**EC)),
-                       sched_m)
-    sched_s = schedule()
-    streams_s = _drive(
-        ServingEngine(cfg, params, EngineConfig(step_token_budget=0, **EC)),
-        sched_s)
-    assert streams_m == streams_s
-    short_m = [r for rs in sched_m.values() for r in rs][1]
-    assert short_m.finish_reason == "stop"
-    assert streams_m[1] == [eos]
+    eng, streams = _assert_rows_do_not_depend_on_batch(
+        cfg, params, EngineConfig(**EC),
+        {0: [dict(prompt_ids=p_long, max_new_tokens=8)],
+         1: [dict(prompt_ids=p_short, max_new_tokens=8,
+                  eos_token_id=(eos,))]})
+    assert eng.metrics["mixed_steps"] > 0
+    assert streams[1] == [eos]
 
 
 def test_admission_wave_sync_budget_tier1(cfg_params):
     """Tier-1 dispatch-economics guard: a simultaneous 3-row admission
-    wave through the mixed engine must stay under the budgeted ceiling of
-    blocking host syncs and device programs — and strictly under the
-    sequential engine's count for the same wave.  A regression to per-row
-    per-chunk dispatch (O(R x chunks)) blows both bounds."""
+    wave must stay under the budgeted ceiling of blocking host syncs and
+    device programs.  A regression to per-row per-chunk dispatch
+    (O(R x chunks)) blows both bounds."""
     cfg, params = cfg_params
     prompts = [list(RNG.integers(0, cfg.vocab_size, 64)) for _ in range(3)]
-
-    def run(budget):
-        reqs = [Request(prompt_ids=p, max_new_tokens=4) for p in prompts]
-        eng = ServingEngine(cfg, params,
-                            EngineConfig(step_token_budget=budget, **EC))
-        _drive(eng, {0: reqs})
-        return dict(eng.metrics)
-
-    m_mixed = run(None)   # auto: budget = prefill_bucket = 32
-    m_seq = run(0)
+    reqs = [Request(prompt_ids=p, max_new_tokens=4) for p in prompts]
+    eng = ServingEngine(cfg, params, EngineConfig(**EC))  # budget = 32
+    _drive(eng, {0: reqs})
+    m = dict(eng.metrics)
     # 192 prompt tokens / (3 rows x 8-token pow2 share) = 8 prefill ticks;
     # only the completion tick and the 3 decode steps block on the device
-    assert m_mixed["mixed_steps"] <= 10, m_mixed
-    assert m_mixed["host_syncs"] <= 6, m_mixed
-    # the sequential engine pays per-chunk dispatch + per-completion sync
-    assert m_mixed["host_syncs"] < m_seq["host_syncs"], (m_mixed, m_seq)
-    # O(tokens/budget), not O(R x chunks): 3 rows x 2 chunks = 6 per-row
-    # programs in the baseline vs <= 10 whole-pool mixed programs covering
-    # prefill AND decode
-    assert m_seq["mixed_steps"] == 0
+    assert m["mixed_steps"] <= 10, m
+    assert m["host_syncs"] <= 6, m
 
 
 def test_mixed_respects_page_pool_contention(cfg_params):
@@ -194,19 +181,22 @@ def test_mixed_respects_page_pool_contention(cfg_params):
         assert refs == 0 or (pid in cached and refs == 1), (pid, refs)
 
 
-def test_step_token_budget_zero_disables_mixed(cfg_params):
-    """budget=0 keeps the sequential admission path (the pp/spec regime)
-    and still serves correctly."""
+def test_step_token_budget_zero_is_refused(cfg_params):
+    """The budget is the positive per-tick prefill budget (None = the
+    prefill bucket): 0, which used to select a sequential engine, and
+    negative values are refused by name; a positive one serves."""
     cfg, params = cfg_params
+    for bad in (0, -1):
+        with pytest.raises(ValueError, match="step_token_budget.*positive"):
+            ServingEngine(cfg, params,
+                          EngineConfig(step_token_budget=bad, **EC))
     prompt = list(RNG.integers(0, cfg.vocab_size, 40))
     eng = ServingEngine(cfg, params,
-                        EngineConfig(step_token_budget=0, **EC))
+                        EngineConfig(step_token_budget=16, **EC))
     (stream,) = _drive(eng, {0: [Request(prompt_ids=prompt,
                                          max_new_tokens=8)]})
-    assert eng.metrics["mixed_steps"] == 0
+    assert eng.metrics["mixed_steps"] == 3      # 40 tokens, 16 a tick
     _assert_greedy_stream(cfg, params, prompt, stream)
-    with pytest.raises(ValueError, match="step_token_budget"):
-        ServingEngine(cfg, params, EngineConfig(step_token_budget=-1, **EC))
 
 
 def test_inbox_peek_preserves_fifo(cfg_params):

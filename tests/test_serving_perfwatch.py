@@ -879,11 +879,11 @@ def test_lowered_tick_names_every_device_scope():
     every named scope of the program: the tick's stages, the layer's
     stages, the head and the sampler."""
     from ipex_llm_tpu.analysis.trace.registry import _build_ragged_tick
-    from ipex_llm_tpu.serving.engine import _ragged_tick_fn
+    from ipex_llm_tpu.serving.engine import _ragged_tick
 
     args, kw = _build_ragged_tick({"rows": 4, "width": 8, "horizon": 1,
                                    "kv": "bf16"})
-    text = _ragged_tick_fn.lower(*args, **kw).as_text(debug_info=True)
+    text = _ragged_tick.lower(*args, **kw).as_text(debug_info=True)
     for scope in ("tick/prefill", "tick/merge", "tick/decode", "layer/qkv",
                   "layer/kv_write", "layer/kv_read", "layer/attn",
                   "layer/o_proj", "layer/mlp", "head", "sample"):

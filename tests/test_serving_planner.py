@@ -258,7 +258,6 @@ def test_spec_masked_off_then_reprobed(cfg_params):
 
     eng = ServingEngine(cfg, params, EngineConfig(
         decode_horizon=4, spec_k=2, **EC))
-    assert eng._fused_spec
     r = Request(prompt_ids=list(prompt), max_new_tokens=48)
     eng.submit(r)
     for _ in range(200):      # admit + reach steady decode

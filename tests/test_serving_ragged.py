@@ -1,11 +1,12 @@
 """Ragged paged-attention superkernel tick (ONE dispatch per engine tick).
 
 The contract under test: every engine tick — admission wave or steady
-state — lowers to the SINGLE jitted entry ``_ragged_tick_fn`` (ragged
-prefill + on-device first-token merge + fused decode horizon), and the
-resulting token AND logprob streams are bit-identical to the sequential
-engine and to the chained two-program tick it replaced, under bf16 AND
-fp8 KV storage.  Plus: the dead-row scratch-route regression (stale
+state — lowers to the SINGLE jitted entry ``_ragged_tick`` (ragged
+prefill + on-device first-token merge + fused decode horizon), the only
+jitted program the engine module holds, and a request's token AND
+logprob bits do not depend on what else rides the tick (greedy and
+seeded sampled rows, H=1 and H=8), under bf16 AND fp8 KV storage.
+Plus: the dead-row scratch-route regression (stale
 device lens on masked rows must never corrupt live pages), the tightened
 host-sync budget, and the measured-ladder dispatch policy
 (ops/dispatch.py): on CPU-interpret environments the recorded microbench
@@ -25,14 +26,13 @@ from ipex_llm_tpu.serving.engine import (
     EngineConfig,
     Request,
     ServingEngine,
-    _decode_multi_step,
-    _mixed_prefill_fn,
-    _ragged_tick_fn,
+    _ragged_tick,
     stream_tokens,
 )
 from tests.test_decoder import rand_params, tiny_cfg
 from tests.test_serving import _assert_greedy_stream
-from tests.test_serving_mixed import _drive
+from tests.test_serving_mixed import (_assert_rows_do_not_depend_on_batch,
+                                      _drive)
 
 RNG = np.random.default_rng(47)
 
@@ -61,45 +61,63 @@ def _wave_specs(cfg):
 
 # -- bit-identity through the superkernel tick ------------------------------
 #
-# Tier note: the engine routes EVERY tick through _ragged_tick_fn now, so
-# the fast tier's existing suites already gate bit-identity through the
-# superkernel (test_serving_mixed: mixed==sequential bf16 + first-token
-# EOS + contention; test_serving_horizon: H8==H1; test_serving_kv_storage:
-# both under fp8).  The re-statements below are the ragged suite's own
-# end-to-end forms — slow tier, where the 870 s tier-1 wall stays intact.
+# Tier note: the fast tier's other suites gate the same invariant on
+# their own axes (test_serving_mixed: a greedy/seeded wave at bf16 +
+# first-token EOS + contention; test_serving_horizon: H8==H1;
+# test_serving_kv_storage: fp8).  The slow re-statements below are the
+# ragged suite's own end-to-end forms.
+
+@pytest.mark.parametrize("horizon", (1, 8))
+@pytest.mark.parametrize("kv", ("bf16", "fp8"))
+def test_a_seeded_sampled_row_does_not_depend_on_its_batch(cfg_params, kv,
+                                                           horizon):
+    """Temperature > 0 with a per-request seed: every row of a staggered
+    wave of SAMPLED requests (top-p, top-k, plain temperature) draws the
+    tokens and logprob bits it draws served alone — its stream is keyed
+    by fold_in(seed, output index), never by the tick's key chain, the
+    chunk widths or the neighbours.  At horizon 8 the wave's joining
+    ticks run H=1 and the row alone runs H=8 blocks."""
+    cfg, params = cfg_params
+    rng = np.random.default_rng(7)
+    sampled = (dict(temperature=0.8, top_p=0.9, top_k=40, seed=321),
+               dict(temperature=1.1, seed=5),
+               dict(temperature=0.6, top_k=8, seed=77))
+    specs = [dict(prompt_ids=list(rng.integers(0, cfg.vocab_size, n)),
+                  max_new_tokens=11, **kw)
+             for n, kw in zip((40, 70, 24), sampled)]
+    eng, streams = _assert_rows_do_not_depend_on_batch(
+        cfg, params,
+        EngineConfig(kv_storage=kv, decode_horizon=horizon, **EC),
+        {0: [specs[0]], 1: [specs[1]], 3: [specs[2]]})
+    assert eng.metrics["mixed_steps"] > 0
+    assert eng.metrics["decode_horizon_effective"] == horizon
+    assert [len(s) for s in streams] == [11, 11, 11]
+    # the draws are draws: a greedy run of the same prompt differs
+    (greedy,) = _drive(
+        ServingEngine(cfg, params,
+                      EngineConfig(kv_storage=kv, decode_horizon=horizon,
+                                   **EC)),
+        {0: [Request(prompt_ids=specs[1]["prompt_ids"],
+                     max_new_tokens=11)]})
+    assert greedy != streams[1]
+
 
 @pytest.mark.slow
 @pytest.mark.parametrize("kv", ("bf16", "fp8"))
-def test_mixed_bit_identical_to_sequential(cfg_params, kv):
-    """Staggered admissions through the one-dispatch tick emit the exact
-    token and logprob streams of the sequential chunk-then-decode engine
-    — greedy, seeded sampled, and a mid-wave finish — under both KV
+def test_a_row_in_a_wave_matches_the_row_alone(cfg_params, kv):
+    """Staggered admissions through the one-dispatch tick emit, request by
+    request, the token and logprob streams each gets served alone —
+    greedy, seeded sampled, and a mid-wave finish — under both KV
     storages."""
     cfg, params = cfg_params
     specs = _wave_specs(cfg)
-    schedule = lambda: {0: [Request(**specs[0])], 1: [Request(**specs[1])],
-                        3: [Request(**specs[2])]}
-
-    sched_m = schedule()
-    eng_m = ServingEngine(cfg, params, EngineConfig(kv_storage=kv, **EC))
-    streams_m = _drive(eng_m, sched_m)
-    sched_s = schedule()
-    eng_s = ServingEngine(
-        cfg, params, EngineConfig(kv_storage=kv, step_token_budget=0, **EC))
-    streams_s = _drive(eng_s, sched_s)
-
-    assert eng_m.metrics["mixed_steps"] > 0
-    assert streams_m == streams_s
-    reqs_m = [r for rs in sched_m.values() for r in rs]
-    reqs_s = [r for rs in sched_s.values() for r in rs]
-    for a, b in zip(reqs_m, reqs_s):
-        assert a.finish_reason == b.finish_reason
-        np.testing.assert_array_equal(
-            np.asarray(a.logprobs, np.float32),
-            np.asarray(b.logprobs, np.float32))
+    eng, streams = _assert_rows_do_not_depend_on_batch(
+        cfg, params, EngineConfig(kv_storage=kv, **EC),
+        {0: [specs[0]], 1: [specs[1]], 3: [specs[2]]})
+    assert eng.metrics["mixed_steps"] > 0
     if kv == "bf16":
         _assert_greedy_stream(cfg, params, specs[0]["prompt_ids"],
-                              streams_m[0])
+                              streams[0])
 
 
 @pytest.mark.slow
@@ -131,8 +149,8 @@ def test_h8_bit_identical_to_h1(cfg_params, kv):
 def test_first_token_eos_finishes_inside_the_tick(cfg_params):
     """A row whose FIRST sampled token is its EOS finishes 'stop' from
     inside the fused tick (the on-device join must keep it OUT of the
-    decode stage) while another row keeps prefilling — and the sequential
-    engine agrees on every stream."""
+    decode stage) while another row keeps prefilling — and both rows emit
+    what they emit alone."""
     cfg, params = cfg_params
     p_short = list(RNG.integers(0, cfg.vocab_size, 20))
     p_long = list(RNG.integers(0, cfg.vocab_size, 60))
@@ -141,22 +159,13 @@ def test_first_token_eos_finishes_inside_the_tick(cfg_params):
                                           max_new_tokens=2)]})
     eos = int(ptoks[0])
 
-    def schedule():
-        return {0: [Request(prompt_ids=p_long, max_new_tokens=8)],
-                1: [Request(prompt_ids=p_short, max_new_tokens=8,
-                            eos_token_id=(eos,))]}
-
-    sched_m = schedule()
-    streams_m = _drive(ServingEngine(cfg, params, EngineConfig(**EC)),
-                       sched_m)
-    sched_s = schedule()
-    streams_s = _drive(
-        ServingEngine(cfg, params, EngineConfig(step_token_budget=0, **EC)),
-        sched_s)
-    assert streams_m == streams_s
-    short_m = [r for rs in sched_m.values() for r in rs][1]
-    assert short_m.finish_reason == "stop"
-    assert streams_m[1] == [eos]
+    eng, streams = _assert_rows_do_not_depend_on_batch(
+        cfg, params, EngineConfig(**EC),
+        {0: [dict(prompt_ids=p_long, max_new_tokens=8)],
+         1: [dict(prompt_ids=p_short, max_new_tokens=8,
+                  eos_token_id=(eos,))]})
+    assert eng.metrics["mixed_steps"] > 0
+    assert streams[1] == [eos]
 
 
 @pytest.mark.slow
@@ -189,8 +198,8 @@ def test_pool_contention_clamp(cfg_params):
 def test_one_sync_per_tick_tier1(cfg_params):
     """Tier-1 dispatch-economics guard, tightened for the superkernel: a
     simultaneous 3-row wave pays ONE blocking sync per tick that emits
-    (completion tick + per-decode-tick), and pure-chunk ticks pay none —
-    the two-dispatch tick's separate first-token sync is gone."""
+    (completion tick + per-decode-tick), and pure-chunk ticks pay none:
+    first tokens ride the tick's one sync."""
     cfg, params = cfg_params
     prompts = [list(RNG.integers(0, cfg.vocab_size, 64)) for _ in range(3)]
     reqs = [Request(prompt_ids=p, max_new_tokens=4) for p in prompts]
@@ -205,11 +214,24 @@ def test_one_sync_per_tick_tier1(cfg_params):
     assert m["tokens_per_sync"] >= 2.0, m
 
 
-# -- the superkernel program == the chained two-program tick ----------------
+# -- one jitted program, driven directly ------------------------------------
+
+def test_the_engine_module_holds_one_jitted_tick():
+    """The only ``jax.jit``-wrapped callables ``serving.engine`` exports are
+    ``_ragged_tick`` and its alias: a second engine (a sequential prefill
+    program, a host-walk verify step, a pp step) cannot come back
+    unnoticed.  Oracles live in the tests."""
+    from ipex_llm_tpu.serving import engine as engine_mod
+
+    jitted = {name: obj for name, obj in vars(engine_mod).items()
+              if hasattr(obj, "lower") and hasattr(obj, "clear_cache")}
+    assert set(jitted) == {"_ragged_tick", "_ragged_tick_fn"}, sorted(jitted)
+    assert jitted["_ragged_tick_fn"] is jitted["_ragged_tick"]
+
 
 def _random_pool_state(cfg, kv: str, seed: int = 0):
-    """A filled 4-row paged pool with rows 0/1 mid-decode, row 2 about to
-    complete its prompt, row 3 idle — the canonical mixed-tick state."""
+    """A filled 4-row paged pool with rows 0/1 mid-decode, rows 2/3
+    idle, and the device row state beside it."""
     rng = np.random.default_rng(seed)
     r, ps, maxp, pages = 4, 16, 4, 24
     cache = PagedKVCache.init(
@@ -236,113 +258,11 @@ def _random_pool_state(cfg, kv: str, seed: int = 0):
         eos=np.asarray([[1, -1], [1, -1], [1, -1], [1, -1]], np.int32),
         remain=np.asarray([4, 5, 6, 0], np.int32),
     )
-    # prefill block: row 2 completes a 5-token chunk this tick; pad slot
-    # carries base past the table width (scratch) and rowmap=R (dropped)
-    w = 8
-    p_tokens = np.zeros((2, w), np.int32)
-    p_tokens[0, :5] = rng.integers(0, cfg.vocab_size, 5)
-    prefill = dict(
-        p_tokens=p_tokens,
-        p_tables=tables[[2, 0]],            # pad slot gathers row 0 (old
-        p_base=np.asarray([8, maxp * ps], np.int32),   # row_idx=0 policy)
-        p_nvalid=np.asarray([5, 0], np.int32),
-        p_emit=np.asarray([True, False]),
-        p_canjoin=np.asarray([True, True]),
-        p_rowmap=np.asarray([2, 4], np.int32),
-    )
-    return cache, state, prefill
+    return cache, state
 
 
 def _dev_state(state):
     return {k: h2d(v) for k, v in state.items()}
-
-
-@pytest.mark.parametrize("kv", [
-    "bf16",
-    # the fp8 form re-proves the same program pair at twice the compile
-    # cost; slow tier keeps the tier-1 wall
-    pytest.param("fp8", marks=pytest.mark.slow),
-])
-def test_ragged_tick_equals_chained_programs(cfg_params, kv):
-    """THE oracle: one `_ragged_tick_fn` dispatch == `_mixed_prefill_fn`
-    chained with `_decode_multi_step` on identical state — first tokens,
-    decode blocks, logprobs, the advanced device state, the key chain,
-    and every byte of the KV pool."""
-    cfg, params = cfg_params
-    key = jax.random.PRNGKey(11)
-
-    # --- fused single-dispatch tick -----------------------------------
-    cache_a, st, pf = _random_pool_state(cfg, kv)
-    dev = _dev_state(st)
-    prefill = (h2d(pf["p_tokens"]), h2d(pf["p_tables"]),
-               h2d(pf["p_base"]), h2d(pf["p_nvalid"]), h2d(pf["p_emit"]),
-               h2d(pf["p_canjoin"]), h2d(pf["p_rowmap"]))
-    (first_t, first_lp, tok_a, lp_a, n_a, cache_a, toks_a, lens_a,
-     act_a, steps_a, rem_a, key_a) = _ragged_tick_fn(
-        cfg, params, cache_a, dev["toks"], dev["row_lens"], dev["active"],
-        dev["temps"], dev["top_ps"], key, dev["seeds"], dev["steps"],
-        dev["top_ks"], dev["eos"], dev["remain"], prefill=prefill,
-        horizon=1, with_decode=True)
-
-    # --- chained two-program tick (the pre-superkernel path) ----------
-    cache_b, st, pf = _random_pool_state(cfg, kv)
-    dev = _dev_state(st)
-    # the old host built [P] sampling-param slices for the prefill batch
-    rm = np.clip(pf["p_rowmap"], 0, 3)
-    nxt, lp, cache_b, key_b = _mixed_prefill_fn(
-        cfg, params, cache_b.with_tables(h2d(pf["p_tables"])),
-        h2d(pf["p_tokens"]), h2d(pf["p_base"]), h2d(pf["p_nvalid"]),
-        h2d(pf["p_emit"]), h2d(st["temps"][rm]), h2d(st["top_ps"][rm]),
-        key, h2d(st["seeds"][rm]), h2d(st["top_ks"][rm]))
-    cache_b = cache_b.with_tables(h2d(np.asarray(
-        1 + np.arange(16, dtype=np.int32).reshape(4, 4))))
-    nxt, lp = np.asarray(nxt), np.asarray(lp)
-    # the old host merge: completing row 2 joins decode with its first
-    # token published (toks/steps/remain/active), lens advanced
-    first = int(nxt[0])
-    st["row_lens"][2] = 8 + 5
-    st["toks"][2] = first
-    st["steps"][2] = 1
-    st["remain"][2] -= 1
-    st["active"][2] = (first not in st["eos"][2]) and st["remain"][2] > 0
-    dev = _dev_state(st)
-    (tok_b, lp_b, n_b, cache_b, toks_b, lens_b, act_b, steps_b, rem_b,
-     key_b) = _decode_multi_step(
-        cfg, params, cache_b, dev["toks"], dev["row_lens"], dev["active"],
-        dev["temps"], dev["top_ps"], key_b, dev["seeds"], dev["steps"],
-        dev["top_ks"], dev["eos"], dev["remain"], horizon=1)
-
-    # --- bitwise equivalence ------------------------------------------
-    np.testing.assert_array_equal(np.asarray(first_t)[:1], nxt[:1])
-    np.testing.assert_array_equal(np.asarray(first_lp, np.float32)[:1],
-                                  lp.astype(np.float32)[:1])
-    np.testing.assert_array_equal(np.asarray(tok_a), np.asarray(tok_b))
-    np.testing.assert_array_equal(np.asarray(lp_a, np.float32),
-                                  np.asarray(lp_b, np.float32))
-    for name, a, b in (("toks", toks_a, toks_b), ("lens", lens_a, lens_b),
-                       ("active", act_a, act_b),
-                       ("steps", steps_a, steps_b), ("rem", rem_a, rem_b),
-                       ("key", key_a, key_b)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                      err_msg=name)
-    # every LIVE byte of the pool: the superkernel's chunk scatter +
-    # decode write == the chained path's.  Excluded by contract: the
-    # scratch page 0 (dead/pad writes route there) and row 2's right-pad
-    # slack written this tick (slots past its decode write — layer>0 pad
-    # K/V depends on pad-query attention, which the tighter chunk_lens
-    # bound legitimately changes; those slots are overwritten before any
-    # valid query can see them, which the masked equality below proves
-    # for every other byte, fp8 e5m2 codes included).
-    live = np.ones((cache_a.k.shape[1], cache_a.k.shape[3]), bool)
-    live[0] = False                    # scratch page
-    live[9, 14:] = False               # row 2's pad slack after its
-    #                                    decode write at slot 13
-    mask = live[None, :, None, :, None]
-    for ca, cb in ((cache_a.k, cache_b.k), (cache_a.v, cache_b.v)):
-        a = np.asarray(ca.astype(jnp.float32))
-        b = np.asarray(cb.astype(jnp.float32))
-        np.testing.assert_array_equal(np.where(mask, a, 0.0),
-                                      np.where(mask, b, 0.0))
 
 
 def test_stale_device_lens_cannot_corrupt_neighbors(cfg_params):
@@ -352,14 +272,14 @@ def test_stale_device_lens_cannot_corrupt_neighbors(cfg_params):
     the scratch page.  After the tick, no page except scratch (page 0)
     and the live row's own write slot may change."""
     cfg, params = cfg_params
-    cache, st, _ = _random_pool_state(cfg, "bf16", seed=3)
+    cache, st = _random_pool_state(cfg, "bf16", seed=3)
     # row 1 is DEAD this tick but its stale device len points straight
     # into row 0's history (row 0's pages are 1..4, slots 0..63)
     st["active"] = np.asarray([True, False, False, False])
     st["row_lens"] = np.asarray([20, 10, 0, 0], np.int32)
     k_before = np.asarray(cache.k.astype(jnp.float32)).copy()
     dev = _dev_state(st)
-    (_, _, _, _, _, cache, *_rest) = _ragged_tick_fn(
+    (_, _, _, _, _, cache, *_rest) = _ragged_tick(
         cfg, params, cache, dev["toks"], dev["row_lens"], dev["active"],
         dev["temps"], dev["top_ps"], jax.random.PRNGKey(0), dev["seeds"],
         dev["steps"], dev["top_ks"], dev["eos"], dev["remain"],
@@ -412,7 +332,7 @@ def test_dense_dead_rows_are_idle_and_live_rows_keep_their_bits(monkeypatch):
         return real_sdpa(q, k, v, tables, kv_len, chunk_len, **kw)
 
     def serve():
-        engine_mod._ragged_tick_fn.clear_cache()
+        engine_mod._ragged_tick.clear_cache()
         reqs = [Request(**s) for s in specs]
         eng = ServingEngine(cfg, params, EngineConfig(**EC))
         streams = _drive(eng, {0: reqs})
@@ -439,7 +359,7 @@ def test_dense_dead_rows_are_idle_and_live_rows_keep_their_bits(monkeypatch):
     finally:
         monkeypatch.delenv("IPEX_LLM_TPU_FORCE_PALLAS", raising=False)
         dispatch.clear_cache()
-        engine_mod._ragged_tick_fn.clear_cache()
+        engine_mod._ragged_tick.clear_cache()
     assert new == old
     assert [len(t) for t in new[0]] == [3, 9, 6]
     table_slots = EC["max_seq_len"]

@@ -10,8 +10,8 @@ engine runs under a tp mesh via SPMD.  Invariants:
   (per-shard single-device form inside the manual tick on pure-tp meshes,
   the shard_map-wrapped form on the GSPMD fallback) — not the gather path;
 - the OpenAI HTTP surface works end-to-end over a meshed engine;
-- composed tp x pp meshes take the GPipe pipelined path too (only pp is
-  manual inside the pipeline's region; tp stays under GSPMD there).
+- a mesh with a pp axis is refused by name (serving shards over tp), and
+  ``pipeline_forward`` computes the same on pure-pp and tp x pp meshes.
 """
 
 import json
@@ -250,77 +250,19 @@ def test_http_server_over_tp_engine(cfg_params):
     assert out["usage"]["completion_tokens"] == 6
 
 
-def test_pp_engine_matches_single_device(cfg_params):
-    """Pipelined decode serving (PPModelWorker peer): pp=2 mesh engine with
-    GPipe request groups must match single-device tokens exactly; the
-    engine must actually select the pipelined path."""
+@pytest.mark.parametrize("spec", [MeshSpec(pp=2), MeshSpec(tp=2, pp=2)],
+                         ids=("pp2", "tp2xpp2"))
+def test_a_pp_mesh_is_refused_by_name(cfg_params, spec):
+    """Serving shards over tp.  A mesh with a pp axis, which used to select
+    a pipelined decode step, is refused at construction with a message
+    that names the axis and what to use; ``pipeline_forward`` keeps the
+    GPipe schedule for training (the test below)."""
     cfg, params = cfg_params
-    prompts = [list(RNG.integers(0, cfg.vocab_size, n))
-               for n in (7, 15, 23, 31)]
-    mesh = make_mesh(MeshSpec(pp=2))
-    eng = ServingEngine(
-        cfg, params,
-        EngineConfig(max_rows=4, max_seq_len=256, prefill_bucket=32),
-        mesh=mesh,
-    ).start()
-    try:
-        assert eng._pp_mode, "engine did not select pipelined decode"
-        reqs = [eng.submit(Request(prompt_ids=p, max_new_tokens=8))
-                for p in prompts]
-        got = [list(stream_tokens(r, timeout=300)) for r in reqs]
-    finally:
-        eng.stop()
-    for g, p in zip(got, prompts):
-        assert len(g) == 8
-        _assert_greedy_stream(cfg, params, p, g)
-
-
-def test_pp_engine_row_churn(cfg_params):
-    """Rows joining/leaving mid-flight under the pipelined step must stay
-    isolated (drain ticks write only the scratch page)."""
-    cfg, params = cfg_params
-    mesh = make_mesh(MeshSpec(pp=2))
-    eng = ServingEngine(
-        cfg, params,
-        EngineConfig(max_rows=2, max_seq_len=256, prefill_bucket=32),
-        mesh=mesh,
-    ).start()
-    try:
-        prompts = [list(RNG.integers(0, cfg.vocab_size, 6 + 5 * i))
-                   for i in range(5)]
-        reqs = [eng.submit(Request(prompt_ids=p, max_new_tokens=6))
-                for p in prompts]
-        got = [list(stream_tokens(r, timeout=300)) for r in reqs]
-    finally:
-        eng.stop()
-    # tie-tolerant oracle check: the pipelined step is a different XLA
-    # program than dense generate (see test_serving._assert_greedy_stream)
-    for g, p in zip(got, prompts):
-        _assert_greedy_stream(cfg, params, p, g)
-
-
-def test_tp_pp_engine_takes_the_pipelined_path(cfg_params):
-    """tp=2 x pp=2 serving rides the GPipe pipelined step: the pipeline's
-    region makes only pp manual, so on a composed mesh the tp axis stays
-    under GSPMD inside it.  Greedy streams match single-device."""
-    cfg, params = cfg_params
-    mesh = make_mesh(MeshSpec(tp=2, pp=2))
-    eng = ServingEngine(
-        cfg, params,
-        EngineConfig(max_rows=2, max_seq_len=256, prefill_bucket=32),
-        mesh=mesh,
-    ).start()
-    assert eng._pp_mode
-    try:
-        prompts = [list(RNG.integers(0, cfg.vocab_size, n)) for n in (9, 23)]
-        reqs = [eng.submit(Request(prompt_ids=p, max_new_tokens=8))
-                for p in prompts]
-        got = [list(stream_tokens(r, timeout=300)) for r in reqs]
-    finally:
-        eng.stop()
-    for g, p in zip(got, prompts):
-        assert len(g) == 8
-        _assert_greedy_stream(cfg, params, p, g)
+    with pytest.raises(ValueError, match=r"pp=2.*tensor_parallel_size"):
+        ServingEngine(
+            cfg, params,
+            EngineConfig(max_rows=4, max_seq_len=256, prefill_bucket=32),
+            mesh=make_mesh(spec))
 
 
 def test_tp_pp_pipeline_forward_matches_pure_pp(cfg_params):
@@ -341,49 +283,3 @@ def test_tp_pp_pipeline_forward_matches_pure_pp(cfg_params):
             cfg, shard_params(params, mesh), tokens, mesh, n_micro=2),
             np.float32))
     np.testing.assert_allclose(outs[1], outs[0], atol=2e-2, rtol=2e-2)
-
-
-def test_pp_speculative_pipelined_verify(cfg_params, monkeypatch):
-    """Speculative serving rides the pipeline's wide (T=k+1) step on a pp
-    mesh (r5: previously spec forced the GSPMD fallback).  Greedy streams
-    must satisfy the tie-tolerant oracle; a second run whose proposer is
-    fed the first run's own stream must accept (near-)everything — the
-    deterministic acceptance check (prompt-lookup hit rates vary with the
-    random model)."""
-    cfg, params = cfg_params
-    prompt = [3, 5, 7, 9, 11, 13]
-
-    def run(proposer=None):
-        if proposer is not None:
-            from ipex_llm_tpu.serving import engine as eng_mod
-
-            monkeypatch.setattr(eng_mod, "_propose_ngram", proposer)
-        eng = ServingEngine(
-            cfg, params,
-            EngineConfig(max_rows=2, max_seq_len=256, prefill_bucket=32,
-                         spec_k=3),
-            mesh=make_mesh(MeshSpec(pp=2)),
-        ).start()
-        assert eng._pp_mode
-        try:
-            req = eng.submit(Request(prompt_ids=prompt, max_new_tokens=16))
-            return list(stream_tokens(req, timeout=600)), dict(eng.metrics)
-        finally:
-            eng.stop()
-
-    g1, m1 = run()
-    assert len(g1) == 16 and m1["spec_steps"] > 0
-    _assert_greedy_stream(cfg, params, prompt, g1)
-
-    def oracle_propose(history, k, ngram):
-        done = len(history) - len(prompt)
-        nxt = g1[done:done + k]
-        out = np.full((k,), -1, np.int32)
-        out[:len(nxt)] = nxt
-        return out
-
-    g2, m2 = run(oracle_propose)
-    assert g2 == g1  # same wide program, same tokens
-    # perfect drafts through the pipelined verify: 15 decode tokens in
-    # <= ceil(15/4)+1 steps
-    assert m2["spec_steps"] <= 5, m2

@@ -336,14 +336,13 @@ def test_ineligible_reasons(cfg_params):
 
     cfg, params = cfg_params
     tp8 = make_mesh(MeshSpec(tp=8))
-    assert ineligible_reason(cfg, params, tp8, 32) is None
+    assert ineligible_reason(cfg, params, tp8) is None
     assert "no tp axis" in ineligible_reason(
-        cfg, params, make_mesh(MeshSpec(tp=1)), 32)
+        cfg, params, make_mesh(MeshSpec(tp=1)))
     assert "composed mesh" in ineligible_reason(
-        cfg, params, make_mesh(MeshSpec(dp=2, tp=4)), 32)
-    assert "sequential engine" in ineligible_reason(cfg, params, tp8, 0)
+        cfg, params, make_mesh(MeshSpec(dp=2, tp=4)))
     odd = _dc_replace(cfg, num_heads=6, num_kv_heads=6)
-    assert "divide tp" in ineligible_reason(odd, params, tp8, 32)
+    assert "divide tp" in ineligible_reason(odd, params, tp8)
 
 
 def test_engine_records_fallback_reason(cfg_params):
