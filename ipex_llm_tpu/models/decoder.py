@@ -30,6 +30,7 @@ from ipex_llm_tpu.ops import mlp as mlp_ops
 from ipex_llm_tpu.ops import rope as rope_ops
 from ipex_llm_tpu.ops.attention import cached_sdpa, paged_kv_write
 from ipex_llm_tpu.ops.norms import layer_norm, rms_norm
+from ipex_llm_tpu.quantize.core import layer_views
 
 COMPUTE_DTYPE = jnp.bfloat16
 
@@ -501,8 +502,10 @@ def run_hybrid(cfg: ModelConfig, tree: dict, cache, x, slot0, q_slots,
             state, stats=state["stats"].at[:4].add(stats))
 
     def entry(stack, i):
-        return jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False), stack)
+        # the quantized weights as views of their stacks, the rest sliced
+        xs, view = layer_views(stack)
+        return view(jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False), xs), i)
 
     kind, plan = hybrid_schedule(cfg.hybrid_pattern)
     k, v = cache.k, cache.v
@@ -523,11 +526,16 @@ def run_hybrid(cfg: ModelConfig, tree: dict, cache, x, slot0, q_slots,
     # expert's planes copied a block a tick
     stacks = ("moe_gate_up", "moe_down")
     moe_whole = {k_: tree["moe"][k_] for k_ in stacks}
-    moe_xs = {k_: v_ for k_, v_ in tree["moe"].items() if k_ not in stacks}
+    # likewise every quantized linear: whole, and handed on as a view of its
+    # stack at the unit's index (quantize/core.py ``layer_views``)
+    mamba_xs, mamba_view = layer_views(tree["mamba"])
+    moe_xs, moe_view = layer_views(
+        {k_: v_ for k_, v_ in tree["moe"].items() if k_ not in stacks})
 
     def unit(carry, xs):
         x, k, v, state = carry
         m_lp, e_lp, m, attn_here, a = xs
+        m_lp, e_lp = mamba_view(m_lp, m), moe_view(e_lp, m)
         x, state = mamba(x, m_lp, state, m)
         if "attn" in tree:
             x, k, v = jax.lax.cond(
@@ -540,7 +548,7 @@ def run_hybrid(cfg: ModelConfig, tree: dict, cache, x, slot0, q_slots,
     n = len(plan)
     (x, k, v, state), _ = jax.lax.scan(
         unit, (x, k, v, state),
-        (tree["mamba"], moe_xs, jnp.arange(n, dtype=jnp.int32), has_attn,
+        (mamba_xs, moe_xs, jnp.arange(n, dtype=jnp.int32), has_attn,
          attn_idx))
     return x, k, v, state
 
@@ -682,13 +690,24 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
 
     How the cache rides the scan follows its class.  A dense cache
     [L, B, H, S, D] is per-row, so the scan runs over layer slices: the
-    tree's layers are the scan's ``xs`` and come back as its ``ys``.  A
+    cache's layers are the scan's ``xs`` and come back as its ``ys``.  A
     paged pool [L, P, H, page, D] (``cache.tables``) is addressed through
     the block table, so nothing needs slicing: the stacks ride the scan's
-    carry beside ``x`` and are written in place, and the ``xs`` hold only
-    the tree, the sliding flag and each layer's index.
+    carry beside ``x`` and are written in place.
+
+    Of the tree, the ``xs`` hold what XLA operations read and a slice
+    fuses into: norm weights, biases, native-width and expert stacks,
+    beside the sliding flag and each layer's index.  The QUANTIZED linears
+    do not ride: their stacks stay whole, closed over by the body, and
+    ``lp`` holds a view "this stack at this layer" of each (``QLayer``),
+    which the dequant-matmul kernel reads in place.  As ``xs`` every packed
+    plane would be copied out of its stack before the kernel call that
+    reads it, every layer, every pass (a quarter of the device's time, PR
+    30's ledger).
     """
     n = sliding_flags.shape[0]
+    tree_xs, view = layer_views(tree)
+    local = jnp.arange(n, dtype=jnp.int32)    # a layer's index in ``tree``
 
     def layer_step(x, lp, kl, vl, sliding, layer):
         if cos_local is not None:
@@ -736,25 +755,25 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
     if hasattr(cache, "tables"):
         def paged_body(carry, xs):
             x, k, v = carry
-            lp, layer, sliding = xs
-            x, k, v, obs_q = layer_step(x, lp, k, v, sliding, layer)
+            lp, i, sliding = xs
+            x, k, v, obs_q = layer_step(x, view(lp, i), k, v, sliding,
+                                        layer_offset + i)
             return (x, k, v), obs_q
 
-        layers = layer_offset + jnp.arange(n, dtype=jnp.int32)
         (x, k_stack, v_stack), obs_q = jax.lax.scan(
-            paged_body, (x, k_stack, v_stack), (tree, layers, sliding_flags)
+            paged_body, (x, k_stack, v_stack), (tree_xs, local, sliding_flags)
         )
         return x, k_stack, v_stack, obs_q
 
     def body(x, xs):
-        lp, kl, vl, sliding = xs
-        x, kl, vl, obs_q = layer_step(x, lp, kl, vl, sliding, None)
+        lp, i, kl, vl, sliding = xs
+        x, kl, vl, obs_q = layer_step(x, view(lp, i), kl, vl, sliding, None)
         return x, (kl, vl, obs_q)
 
     whole = n == k_stack.shape[0]
     ours = slice(layer_offset, layer_offset + n)
     x, (k_new, v_new, obs_q) = jax.lax.scan(
-        body, x, (tree, k_stack if whole else k_stack[ours],
+        body, x, (tree_xs, local, k_stack if whole else k_stack[ours],
                   v_stack if whole else v_stack[ours], sliding_flags)
     )
     if not whole:

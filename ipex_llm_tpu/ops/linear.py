@@ -7,6 +7,11 @@ is a jittable function over a ``QTensor``; on TPU the packed-int4 path runs a
 Pallas kernel that streams packed bytes from HBM and unpacks them in VMEM next
 to the MXU (see ops/pallas/qmatmul.py), every other format falls back to an
 XLA dequantize→matmul which the compiler fuses.
+
+A layer scan hands its linears not a slice of a stacked weight but the stack
+and the layer's index (``quantize/core.py`` ``QLayer``): the kernel reads the
+layer's packed codes where they lie, and only the routes made of XLA
+operations, into which a slice fuses, slice the layer out (``qmatmul``).
 """
 
 from __future__ import annotations
@@ -16,7 +21,7 @@ import jax.numpy as jnp
 
 from ipex_llm_tpu.ops import dispatch
 from ipex_llm_tpu.quantize import core as qcore
-from ipex_llm_tpu.quantize.core import QTensor
+from ipex_llm_tpu.quantize.core import QLayer, QTensor
 
 
 def qmatmul_reference(x: jnp.ndarray, qt: QTensor, compute_dtype=jnp.bfloat16) -> jnp.ndarray:
@@ -27,8 +32,9 @@ def qmatmul_reference(x: jnp.ndarray, qt: QTensor, compute_dtype=jnp.bfloat16) -
     )
 
 
-def qmatmul(x: jnp.ndarray, qt: QTensor, compute_dtype=jnp.bfloat16) -> jnp.ndarray:
-    """Quantized matmul with backend dispatch.
+def qmatmul(x: jnp.ndarray, qt: QTensor | QLayer,
+            compute_dtype=jnp.bfloat16) -> jnp.ndarray:
+    """Quantized matmul with backend dispatch: the single entry.
 
     The Pallas path covers the formats ops/pallas/qmatmul.py lists as
     supported, and the backend choice per qtype family comes from
@@ -40,6 +46,13 @@ def qmatmul(x: jnp.ndarray, qt: QTensor, compute_dtype=jnp.bfloat16) -> jnp.ndar
     dequant path, which GSPMD partitions itself.  Every route is decided
     from the weight and the mesh before the call: a kernel that raises is
     an error, never a reason to take another path.
+
+    A ``QLayer`` (a layer of a stack, from a layer scan) reaches the
+    single-device kernel as it is, and the kernel reads the layer's codes
+    out of the stack if its tiles cover them (``stacked_supported``).  On
+    every other route the layer is sliced out here (``take``): the XLA
+    dequant path, the shard_map tp wrapper, the manual-tp row-parallel
+    branch.  There the slice feeds XLA operations and fuses into them.
     """
     fam = f"qmatmul_{qt.qtype}"
     mt = dispatch.manual_tp_state()
@@ -58,7 +71,7 @@ def qmatmul(x: jnp.ndarray, qt: QTensor, compute_dtype=jnp.bfloat16) -> jnp.ndar
         # call the single-chip trace takes.
         axis, cq = mt
         if qt.tp_mode == "row":
-            w = qcore.dequantize(qt, dtype=compute_dtype)
+            w = qcore.dequantize(_whole(qt), dtype=compute_dtype)
             part = jnp.matmul(x.astype(compute_dtype), w,
                               preferred_element_type=jnp.float32)
             from ipex_llm_tpu.ops import collectives
@@ -77,13 +90,17 @@ def qmatmul(x: jnp.ndarray, qt: QTensor, compute_dtype=jnp.bfloat16) -> jnp.ndar
 
             if pallas_qmatmul.sharded_supported(qt, mesh.shape["tp"]):
                 return pallas_qmatmul.qmatmul_pallas_sharded(
-                    x, qt, mesh, compute_dtype)
+                    x, _whole(qt), mesh, compute_dtype)
     if dispatch.use_pallas(fam):
         from ipex_llm_tpu.ops.pallas import qmatmul as pallas_qmatmul
 
         if pallas_qmatmul.supported(qt):
             return pallas_qmatmul.qmatmul_pallas(x, qt, compute_dtype)
-    return qmatmul_reference(x, qt, compute_dtype)
+    return qmatmul_reference(x, _whole(qt), compute_dtype)
+
+
+def _whole(qt: QTensor | QLayer) -> QTensor:
+    return qt.take() if isinstance(qt, QLayer) else qt
 
 
 def linear(x: jnp.ndarray, w, bias: jnp.ndarray | None = None) -> jnp.ndarray:
@@ -98,7 +115,7 @@ def linear(x: jnp.ndarray, w, bias: jnp.ndarray | None = None) -> jnp.ndarray:
         y = linear(x, base)
         lora = (x.astype(w.a.dtype) @ w.a) @ w.b * w.scale
         y = y + lora.astype(y.dtype)
-    elif isinstance(w, QTensor):
+    elif isinstance(w, (QTensor, QLayer)):
         y = qmatmul(x, w)
     else:
         y = jnp.matmul(

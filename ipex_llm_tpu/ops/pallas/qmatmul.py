@@ -15,6 +15,12 @@ Design (TPU-first, see SURVEY.md §2.3 "TPU mapping"):
 - The contraction (K) axis is the quantization-block axis, so a K tile always
   covers whole quantization blocks and scales slice as ``[BK/bs, BN]``.
 
+- A weight that lives in a stack ``[L, ...]`` (a layer scan's) reaches the
+  kernel as the stack and the layer's index (``quantize/core.py`` ``QLayer``):
+  the index maps take the layer from scalar prefetch, so the tiles are read
+  out of the stack and no per-layer copy of the codes is made on the way.  A
+  lone weight is the same kernel over a stack of one.
+
 Supported formats: sym_int4 / asym_int4 / sym_int8, the 4-bit codebook
 formats nf4 / fp4 (16-entry lookup unrolled as a select chain on the VPU),
 the minifloats fp8_e4m3 / fp8_e5m2 / fp6 (exponent/mantissa decoded
@@ -26,6 +32,7 @@ path before the call.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -40,7 +47,7 @@ from ipex_llm_tpu.ops.pallas._compat import (
 )
 
 from ipex_llm_tpu.quantize import numerics
-from ipex_llm_tpu.quantize.core import QTensor
+from ipex_llm_tpu.quantize.core import QLayer, QTensor
 
 _NIB4 = ("sym_int4", "asym_int4", "nf4", "fp4")
 _BIT5 = ("sym_int5", "asym_int5")
@@ -128,7 +135,9 @@ def tile_plan(k: int, n: int, bs: int) -> tuple[int, int, int, int]:
     1024 and 2048 that divides K, K being padded by as little as gives one.
     Widths that are multiples of 2048 and 512 keep the tiles they had.  The
     planes are padded ONCE, when the tree is built (``quantize/core.py``
-    ``pad_planes``); a weight that arrives unpadded is padded in the call."""
+    ``pad_planes``).  A lone weight that arrives unpadded is padded in the
+    call; a layer of a STACK that is not at these sizes is first sliced out
+    of it (``stacked_supported``), and is then such a lone weight."""
     n128 = _round_up(n, 128)
     bn = n128 if n128 <= 512 else next(
         c for c in (512, 384, 256, 128) if n128 % c == 0)
@@ -144,8 +153,9 @@ def tile_plan(k: int, n: int, bs: int) -> tuple[int, int, int, int]:
 
 def _make_kernel(qtype, bs, bk, bn, compute_dtype, has_high, has_zeros):
     def kern(*refs):
-        x_ref, d_ref = refs[0], refs[1]
-        i = 2
+        # refs[0] is the prefetched layer index: the index maps' alone
+        x_ref, d_ref = refs[1], refs[2]
+        i = 3
         h_ref = None
         if has_high:
             h_ref, i = refs[i], i + 1
@@ -175,11 +185,21 @@ def _make_kernel(qtype, bs, bk, bn, compute_dtype, has_high, has_zeros):
 @functools.partial(
     jax.jit, static_argnames=("qtype", "bs", "logical_out", "compute_dtype")
 )
-def _qmatmul_2d(x, data, scales, zeros, *, qtype: str, bs: int,
+def _qmatmul_2d(x, data, scales, zeros, layer=None, *, qtype: str, bs: int,
                 logical_out: int, compute_dtype):
-    """x [M, K_pad] @ dequant(data) [K_pad, N_pad] -> [M, logical_out]."""
+    """x [M, K_pad] @ dequant(data) [K_pad, N_pad] -> [M, logical_out].
+
+    ``data`` is the weight's code plane ``[K_pad(/2), N]``, or with
+    ``layer`` (a traced int32 scalar) the STACK ``[L, K_pad(/2), N]`` the
+    plane lies in: the kernel's index maps take the layer from scalar
+    prefetch and its DMA reads that layer's tiles out of the stack, so no
+    per-layer copy of the codes exists between a layer scan and this call.
+    A stack tiles exactly (``stacked_supported``); a lone plane that does
+    not is padded here, and is then the same kernel over a stack of one.
+    ``scales``/``zeros`` are the one layer's planes either way (an eighth of
+    the codes' bytes; Mosaic takes no fp16 argument on this chip)."""
     m, k = x.shape
-    n = data.shape[1]
+    n = data.shape[-1]
     bit5 = qtype in _BIT5
     num, den = _data_row_factor(qtype)
 
@@ -195,17 +215,25 @@ def _qmatmul_2d(x, data, scales, zeros, *, qtype: str, bs: int,
     if mp != m or kp != k:
         x = jnp.pad(x, ((0, mp - m), (0, kp - k)))
     high = None
-    if bit5:
+    if layer is not None:
+        if bit5 or (kp, np_) != (k, n) or data.shape[1] * num // den != k:
+            raise ValueError(
+                f"stacked {qtype} planes {data.shape[1:]} are not at the "
+                f"kernel's tiles ({kp}, {np_}): take the layer's plane")
+    elif bit5:
         # split the _pack_5bit planes: [K/2, N] nibbles ++ [K/8, N] top bits
         high = data[k // 2:]
         data = data[: k // 2]
         if kp != k or np_ != n:
             data = jnp.pad(data, ((0, (kp - k) // 2), (0, np_ - n)))
             high = jnp.pad(high, ((0, (kp - k) // 8), (0, np_ - n)))
+        high = high[None]
     else:
         drows = kp * den // num
         if data.shape[0] != drows or np_ != n:
             data = jnp.pad(data, ((0, drows - data.shape[0]), (0, np_ - n)))
+    if layer is None:
+        data, layer = data[None], 0
     nb_p = kp // bs
     scales = jnp.pad(
         scales, ((0, nb_p - scales.shape[0]), (0, np_ - n))
@@ -215,16 +243,16 @@ def _qmatmul_2d(x, data, scales, zeros, *, qtype: str, bs: int,
             zeros, ((0, nb_p - zeros.shape[0]), (0, np_ - n))
         ).astype(jnp.float32)
 
-    grid = (mp // bm, np_ // bn, kp // bk)
     d_rows = bk // 2 if (qtype in _NIB4 or bit5) else bk
-    blk = lambda mi, ni, ki: (ki, ni)  # noqa: E731
+    stack_blk = lambda mi, ni, ki, l: (l[0], ki, ni)  # noqa: E731
+    blk = lambda mi, ni, ki, l: (ki, ni)  # noqa: E731
     in_specs = [
-        pl.BlockSpec((bm, bk), lambda mi, ni, ki: (mi, ki)),
-        pl.BlockSpec((d_rows, bn), blk),
+        pl.BlockSpec((bm, bk), lambda mi, ni, ki, l: (mi, ki)),
+        pl.BlockSpec((None, d_rows, bn), stack_blk),
     ]
     args = [x, data]
     if bit5:
-        in_specs.append(pl.BlockSpec((bk // 8, bn), blk))
+        in_specs.append(pl.BlockSpec((None, bk // 8, bn), stack_blk))
         args.append(high)
     in_specs.append(pl.BlockSpec((bk // bs, bn), blk))
     args.append(scales)
@@ -236,9 +264,12 @@ def _qmatmul_2d(x, data, scales, zeros, *, qtype: str, bs: int,
                         has_high=bit5, has_zeros=zeros is not None)
     out = pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(mp // bm, np_ // bn, kp // bk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki, l: (mi, ni)),
+        ),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -252,7 +283,7 @@ def _qmatmul_2d(x, data, scales, zeros, *, qtype: str, bs: int,
         ),
         interpret=_interpret(),
         name="qmatmul",
-    )(*args)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), *args)
     return out[:m, :logical_out]
 
 
@@ -389,24 +420,51 @@ def qmatmul_pallas_grouped(x: jnp.ndarray, qt: QTensor, ids, n_live,
         logical_out=qt.out_features, compute_dtype=compute_dtype)
 
 
-def qmatmul_pallas(x: jnp.ndarray, qt: QTensor, compute_dtype=jnp.bfloat16,
-                   keep_f32: bool = False):
+def stacked_supported(stack: QTensor) -> bool:
+    """Whether :func:`qmatmul_pallas` reads a layer of this stack ``[L,
+    ...]`` in place: one code plane a layer (the 5-bit formats cut two out
+    of one array) that the kernel's tiles cover exactly, as the planes of
+    the sym 4-bit formats do once ``pad_planes`` has padded them at build."""
+    if (stack.qtype not in _SUPPORTED or stack.qtype in _BIT5
+            or stack.data.ndim != 3):
+        return False
+    num, den = _data_row_factor(stack.qtype)
+    k, n = stack.data.shape[1] * num // den, stack.data.shape[2]
+    _, kp, _, np_ = tile_plan(k, n, stack.block_size)
+    return (kp, np_) == (k, n) and stack.scales.shape[1:] == (
+        k // stack.block_size, n)
+
+
+def qmatmul_pallas(x: jnp.ndarray, qt: QTensor | QLayer,
+                   compute_dtype=jnp.bfloat16, keep_f32: bool = False):
     """x [..., in] @ dequant(qt) -> [..., out] via the fused Pallas kernel.
+    A ``QLayer`` of a stack that tiles exactly is read where it lies
+    (``stacked_supported``); of any other stack the layer is sliced out
+    first, as a lone ``QTensor`` arrives.
 
     ``keep_f32`` returns the fp32 accumulator untouched (the row-parallel
     shard_map wrapper psums partial products in fp32 before the final cast).
     """
     if not supported(qt):
         raise NotImplementedError(qt.qtype)
+    layer = None
+    if isinstance(qt, QLayer):
+        if stacked_supported(qt.stack):
+            # the codes stay in their stack; the small planes are sliced out
+            layer, data = qt.layer, qt.stack.data
+            qt = QLayer(dataclasses.replace(qt.stack, data=None), layer)
+        qt = qt.take()
+    if layer is None:
+        data = qt.data
     lead = x.shape[:-1]
     k = x.shape[-1]
     num, den = _data_row_factor(qt.qtype)
-    k_pad = qt.data.shape[0] * num // den
+    k_pad = data.shape[-2] * num // den
     x2 = x.reshape(-1, k)
     if k_pad != k:  # quantization block padding (core.py::_to_blocks)
         x2 = jnp.pad(x2, ((0, 0), (0, k_pad - k)))
     out = _qmatmul_2d(
-        x2, qt.data, qt.scales, qt.zeros,
+        x2, data, qt.scales, qt.zeros, layer,
         qtype=qt.qtype, bs=qt.block_size, logical_out=qt.out_features,
         compute_dtype=compute_dtype,
     )

@@ -91,6 +91,66 @@ class QTensor:
         return f"QTensor({self.qtype}, {self.shape}, bs={self.block_size})"
 
 
+@jax.tree_util.register_pytree_node_class
+@dataclass
+class QLayer:
+    """Layer ``layer`` (a traced int32 scalar) of a stacked ``QTensor`` whose
+    planes are ``[L, ...]``, not read yet: what a layer scan hands a linear
+    in place of a slice of the stack.  The dequant-matmul kernel reads that
+    layer's tiles where they lie (ops/pallas/qmatmul.py); a slice that feeds
+    a kernel call cannot fuse into it and would be a copy of the planes,
+    every layer, every pass.  Every other consumer calls :meth:`take`, and
+    there the slice feeds XLA operations and fuses."""
+
+    stack: QTensor
+    layer: jnp.ndarray
+
+    def tree_flatten(self):
+        return (self.stack, self.layer), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+    def take(self) -> QTensor:
+        """The layer's own ``QTensor``, sliced out of the stack."""
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, self.layer, 0, False),
+            self.stack)
+
+    qtype = property(lambda self: self.stack.qtype)
+    block_size = property(lambda self: self.stack.block_size)
+    tp_mode = property(lambda self: self.stack.tp_mode)
+    in_features = property(lambda self: self.stack.shape[0])
+    out_features = property(lambda self: self.stack.shape[1])
+
+
+def layer_views(tree):
+    """Split a stacked layer tree ``[L, ...]`` for a scan over its layers:
+    ``(xs, view)``.  ``xs`` is the tree without its quantized weights (a
+    packed ``QTensor`` with ONE leading axis; an expert stack has two and
+    stays), to ride the scan; ``view(lp, layer)`` puts a :class:`QLayer` of
+    each such weight, which stays whole, back into the slice ``lp`` of
+    ``xs``.  Native-width weights stay in ``xs``: their slices feed XLA
+    matmuls and fuse, and a trained one's gradient stacks by layer there."""
+    def is_q(a):
+        return isinstance(a, QTensor)
+
+    def held(a):
+        return (is_q(a) and a.data.ndim == 3
+                and qtypes.resolve(a.qtype).kind != "native")
+
+    xs = jax.tree_util.tree_map(lambda a: None if held(a) else a, tree,
+                                is_leaf=is_q)
+
+    def view(lp, layer):
+        return jax.tree_util.tree_map(
+            lambda w, s: QLayer(w, layer) if held(w) else s, tree, lp,
+            is_leaf=is_q)
+
+    return xs, view
+
+
 # ---------------------------------------------------------------------------
 # packing helpers (contraction axis = axis 0 of each [bs, out] block)
 # ---------------------------------------------------------------------------

@@ -68,3 +68,11 @@ def _clear_jax_caches_between_modules():
     import jax
 
     jax.clear_caches()
+
+
+@pytest.fixture
+def kernel_forced(monkeypatch):
+    """Every op's Pallas kernel (interpreted here), as on a TPU."""
+    from ipex_llm_tpu.ops import dispatch
+
+    monkeypatch.setattr(dispatch, "_use_pallas_env", lambda op=None: True)

@@ -61,6 +61,153 @@ def test_qmatmul_pallas_bf16_activations():
     np.testing.assert_allclose(got, want, rtol=5e-2, atol=5e-2)
 
 
+# -- a layer of a stack read where it lies (quantize/core.py QLayer) -----------
+#
+# The stacked call (the stack and a traced layer index, through the single
+# entry ops/linear.qmatmul with the kernel forced) against the per-plane call
+# on stack[layer], bit for bit, whichever route the operand takes.
+
+_FORMATS = ("sym_int4", "asym_int4", "nf4", "fp4", "sym_int5", "asym_int5",
+            "sym_int8", "fp8_e4m3", "fp8_e5m2", "fp6")
+
+
+def _stack_of(qtype, layers, k, n, pad=True):
+    from ipex_llm_tpu.quantize.core import pad_planes
+
+    qts = [quantize((RNG.standard_normal((k, n)) * 0.05).astype(np.float32),
+                    qtype) for _ in range(layers)]
+    if pad:
+        qts = [pad_planes(q) for q in qts]
+    return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *qts), qts
+
+
+def copies_of_packed_planes(jaxpr) -> list:
+    """What in a program takes a layer's plane OUT of a stack of packed
+    codes (uint8, rank >= 3): a dynamic_slice or gather of it, or a scan that
+    carries it as ``xs``.  A kernel call reads such a stack whole."""
+    from ipex_llm_tpu.analysis.trace.tracer import _iter_subjaxprs
+
+    def packed(v):
+        a = getattr(v, "aval", None)
+        return (a is not None and str(getattr(a, "dtype", "")) == "uint8"
+                and len(a.shape) >= 3)
+
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("dynamic_slice", "gather") and packed(eqn.invars[0]):
+            found.append((name, eqn.invars[0].aval.shape))
+        if name == "scan":
+            skip = eqn.params["num_consts"] + eqn.params["num_carry"]
+            found += [("scan xs", v.aval.shape) for v in eqn.invars[skip:]
+                      if packed(v)]
+        for sub in eqn.params.values():
+            for j in _iter_subjaxprs(sub):
+                found += copies_of_packed_planes(j)
+    return found
+
+
+def kernel_calls(jaxpr, prefix: str) -> int:
+    """The kernel calls in a program whose name starts with ``prefix``, a
+    scan's body counted once."""
+    from ipex_llm_tpu.analysis.trace.tracer import _iter_subjaxprs
+
+    return sum(
+        (eqn.primitive.name == "pallas_call"
+         and str(eqn.params["name"]).startswith(prefix))
+        + sum(kernel_calls(j, prefix) for sub in eqn.params.values()
+              for j in _iter_subjaxprs(sub))
+        for eqn in jaxpr.eqns)
+
+
+def _slices_a_packed_stack(fn, *args) -> bool:
+    return bool(copies_of_packed_planes(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def _assert_layers_are_the_planes(stack, planes, x):
+    from ipex_llm_tpu.ops.linear import qmatmul
+    from ipex_llm_tpu.quantize.core import QLayer
+
+    stacked = jax.jit(lambda x, s, l: qmatmul(x, QLayer(s, l)))
+    n = len(planes)
+    for layer in sorted({0, n // 2, n - 1}):
+        got = stacked(x, stack, jnp.int32(layer))
+        want = qmatmul(x, planes[layer])
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(_bits(got), _bits(want),
+                                      err_msg=f"{stack.qtype} layer {layer}")
+
+
+@pytest.mark.parametrize("m", [1, 16, 128])
+@pytest.mark.parametrize("qtype", _FORMATS)
+def test_a_layer_of_a_stack_is_that_layers_plane(kernel_forced, qtype, m):
+    """Every kernel format, the first, a middle and the last layer by a
+    traced index: the bits of the per-plane call.  The sym 4-bit stacks,
+    padded at build, are read in place (no slice of the codes in the
+    program); the 5-bit ones, two planes cut out of one array, are sliced."""
+    from ipex_llm_tpu.ops.linear import qmatmul
+    from ipex_llm_tpu.ops.pallas.qmatmul import stacked_supported
+    from ipex_llm_tpu.quantize.core import QLayer
+
+    stack, planes = _stack_of(qtype, 3, 256, 256)
+    x = jnp.asarray(RNG.standard_normal((m, 256)) * 0.5, jnp.bfloat16)
+    _assert_layers_are_the_planes(stack, planes, x)
+    in_place = stacked_supported(stack)
+    if qtype in ("sym_int4", "nf4", "fp4"):
+        assert in_place
+    if qtype in ("sym_int5", "asym_int5"):
+        assert not in_place
+    assert _slices_a_packed_stack(
+        lambda x, s, l: qmatmul(x, QLayer(s, l)), x, stack,
+        jnp.int32(1)) == (not in_place)
+
+
+@pytest.mark.parametrize("m", [1, 16, 128])
+def test_a_stack_whose_k_the_tiles_pad_is_read_in_place(kernel_forced, m):
+    """K = 2688 is padded to 3072 at build (two K tiles of 1536): the
+    stacked call walks both, and gives the plane's bits."""
+    from ipex_llm_tpu.ops.pallas.qmatmul import stacked_supported
+
+    stack, planes = _stack_of("sym_int4", 2, 2688, 128)
+    assert stack.data.shape == (2, 1536, 128) and stacked_supported(stack)
+    x = jnp.asarray(RNG.standard_normal((m, 2688)) * 0.5, jnp.bfloat16)
+    _assert_layers_are_the_planes(stack, planes, x)
+
+
+def test_a_stack_not_padded_to_tiles_is_sliced_and_gives_the_same_bits(
+        kernel_forced):
+    """N = 200 is no whole lane tile: the layer is sliced out and padded in
+    the call, as a lone weight is."""
+    from ipex_llm_tpu.ops.linear import qmatmul
+    from ipex_llm_tpu.ops.pallas.qmatmul import stacked_supported
+    from ipex_llm_tpu.quantize.core import QLayer
+
+    stack, planes = _stack_of("sym_int4", 3, 160, 200, pad=False)
+    assert not stacked_supported(stack)
+    x = jnp.asarray(RNG.standard_normal((5, 160)) * 0.5, jnp.bfloat16)
+    _assert_layers_are_the_planes(stack, planes, x)
+    assert _slices_a_packed_stack(
+        lambda x, s, l: qmatmul(x, QLayer(s, l)), x, stack, jnp.int32(1))
+
+
+@pytest.mark.parametrize("qtype", ["sym_int4", "sym_int5", "asym_int4"])
+def test_a_lone_weight_is_the_kernel_over_a_stack_of_one(kernel_forced,
+                                                         qtype):
+    """A rank-2 weight (the head) and the same planes as a stack of one
+    layer: one kernel, the same bits."""
+    from ipex_llm_tpu.ops.linear import qmatmul
+    from ipex_llm_tpu.quantize.core import QLayer
+
+    stack, (plane,) = _stack_of(qtype, 1, 256, 384)
+    x = jnp.asarray(RNG.standard_normal((7, 256)) * 0.5, jnp.bfloat16)
+    np.testing.assert_array_equal(
+        _bits(qmatmul(x, QLayer(stack, jnp.int32(0)))),
+        _bits(qmatmul(x, plane)))
+    want = np.asarray(qmatmul_reference(x, plane), np.float32)
+    np.testing.assert_allclose(np.asarray(qmatmul(x, plane), np.float32),
+                               want, rtol=5e-2, atol=5e-2)
+
+
 def _attn_case(b=2, t=32, s=96, hq=4, hkv=2, d=64):
     q = (RNG.standard_normal((b, t, hq, d)) * 0.3).astype(np.float32)
     k = (RNG.standard_normal((b, s, hkv, d)) * 0.3).astype(np.float32)

@@ -258,6 +258,111 @@ def test_a_row_in_a_wave_matches_the_row_alone_int4(cfg_params):
     assert eng.params["layers"]["qkv"].qtype == "sym_int4"
 
 
+# -- the packed planes are read where they lie --------------------------------
+#
+# With the kernel on (interpreted here) a layer scan hands each quantized
+# linear its stack and the layer's index, and nothing in the program takes a
+# plane of packed codes out of its stack: no dynamic_slice of one, no scan
+# that carries one as ``xs``.  The widths below leave every plane at the
+# kernel's tiles (a stack that is not is sliced: tests/test_pallas.py).
+
+def _tiled_cfg_params():
+    cfg = tiny_cfg(vocab_size=128, hidden_size=128, intermediate_size=128,
+                   num_heads=12, num_kv_heads=2, head_dim=8,
+                   max_position_embeddings=512)
+    return cfg, rand_params(cfg, qtype="bf16")
+
+
+def _paged_forward_jaxpr(cfg, params, state=None):
+    from dataclasses import replace
+
+    import jax
+
+    from ipex_llm_tpu.kv import PagedKVCache
+
+    rows, width = 2, 6
+    cache = replace(
+        PagedKVCache.init(cfg.kv_layers, 8, rows, 3, cfg.num_kv_heads, 16,
+                          cfg.head_dim),
+        tables=jnp.asarray([[1, 2, 3], [4, 5, -1]], jnp.int32), state=state)
+    base = jnp.asarray([0, 14], jnp.int32)
+    pos = base[:, None] + jnp.arange(width)[None, :]
+    return jax.make_jaxpr(lambda p, c: decoder_forward(
+        cfg, p, jnp.zeros((rows, width), jnp.int32), c, pos,
+        slot_offsets=base, chunk_lens=jnp.asarray([6, 4], jnp.int32),
+        gather_positions=jnp.asarray([5, 3], jnp.int32)))(params, cache)
+
+
+@pytest.mark.parametrize("family", ["dense", "hybrid"])
+def test_layer_scan_takes_no_packed_plane_out_of_its_stack(
+        kernel_forced, monkeypatch, family):
+    """The int4 forward over the paged pool, kernel forced: the dense
+    families' layer scan and the hybrid ``unit`` scan (Mamba, attention
+    under its ``cond``, the shared expert) hold one kernel call a linear
+    and no copy of a packed plane.  With the kernel off the same scans
+    slice the layer's planes, which feed XLA operations there."""
+    from ipex_llm_tpu import kv
+    from ipex_llm_tpu.ops import dispatch
+    from tests.test_pallas import copies_of_packed_planes, kernel_calls
+
+    if family == "dense":
+        cfg, params = _tiled_cfg_params()
+        params, state = requantize_params(params, "sym_int4"), None
+        linears = 4 + 1           # the scanned layer's four, and the head
+    else:
+        from ipex_llm_tpu.models.families import FAMILIES
+        from tests.test_nemotron_h import gen, tiny_hf
+
+        hf = tiny_hf()
+        cfg = FAMILIES["nemotron_h"].to_config(hf)
+        params = gen.param_tree(cfg, hf, "sym_int4", 7)
+        state = kv.recurrent_state(cfg, 2)
+        # a unit's Mamba in/out, attention qkv/o, shared expert up/down;
+        # the head (the routed experts are ``qmatmul_expert`` calls)
+        linears = 6 + 1
+    closed = _paged_forward_jaxpr(cfg, params, state)
+    assert copies_of_packed_planes(closed.jaxpr) == []
+    assert kernel_calls(closed.jaxpr, "qmatmul") - kernel_calls(
+        closed.jaxpr, "qmatmul_expert") == linears
+    # the guard sees the defect it guards against
+    monkeypatch.setattr(dispatch, "_use_pallas_env", lambda op=None: False)
+    assert copies_of_packed_planes(
+        _paged_forward_jaxpr(cfg, params, state).jaxpr)
+
+
+def test_a_row_in_a_wave_matches_the_row_alone_on_the_kernel(kernel_forced,
+                                                             monkeypatch):
+    """The batch-invariance oracle with the dequant-matmul kernel on
+    (interpreted), so the view of the stack travels the whole tick: every
+    tick variant the wave compiles reads its packed planes in place, and a
+    request's tokens and logprob bits are those it gets alone."""
+    from ipex_llm_tpu.serving import engine as engine_mod
+    from tests.test_pallas import copies_of_packed_planes
+
+    cfg, params = _tiled_cfg_params()
+    real, seen = engine_mod._ragged_tick, {}
+
+    def recorded(*args, **kw):
+        key = (kw.get("horizon"), kw.get("with_decode"),
+               None if kw.get("prefill") is None
+               else kw["prefill"][0].shape)
+        if key not in seen:
+            seen[key] = real.trace(*args, **kw).jaxpr
+        return real(*args, **kw)
+
+    monkeypatch.setattr(engine_mod, "_ragged_tick", recorded)
+    specs = _wave_specs(cfg)
+    eng, _ = _assert_rows_do_not_depend_on_batch(
+        cfg, params, EngineConfig(weight_qtype="sym_int4", **EC),
+        {0: [specs[0]], 1: [specs[1]], 3: [specs[2]]})
+    assert eng.metrics["mixed_steps"] > 0
+    assert any(k[2] is None for k in seen) and any(
+        k[2] is not None for k in seen)          # steady and mixed ticks
+    for key, closed in seen.items():
+        assert "qmatmul" in str(closed), key
+        assert copies_of_packed_planes(closed.jaxpr) == [], key
+
+
 def test_fused_h8_bit_identical_to_h1_int4(cfg_params):
     """The PR 1 equivalence contract over packed weights: H=8 fused
     decode emits the H=1 int4 engine's exact streams (greedy and seeded
