@@ -91,7 +91,7 @@ def main(argv) -> int:
     from ipex_llm_tpu.kv import PagedKVCache
     from ipex_llm_tpu.models.families import FAMILIES
     from ipex_llm_tpu.serving.engine import (EngineConfig, ServingEngine,
-                                             _ragged_tick_fn)
+                                             _ragged_tick)
 
     config = run.load_json(os.path.join(HERE, "configs", argv[0] + ".json"))
     hf, qtype = run.hf_config(config), config["weight_qtype"]
@@ -122,12 +122,17 @@ def main(argv) -> int:
             cache = described(lambda: PagedKVCache.init(
                 cfg.num_layers, ec.n_pages, ec.max_rows, ec.max_pages,
                 cfg.num_kv_heads, ec.page_size, cfg.head_dim), chip)
-        out = {"config": argv[0], "pool_pages": pool, "dispatch": dispatch}
+        # a cache that carries recurrent state is held TWICE by the engine:
+        # the tick reads the committed buffer (``state_src``, the same shapes)
+        # and writes the spare one, which is the donated cache's
+        state_src = cache.state
+        out = {"config": argv[0], "pool_pages": pool, "dispatch": dispatch,
+               "state_buffers": 0 if state_src is None else 2}
         try:
-            compiled = _ragged_tick_fn.lower(
+            compiled = _ragged_tick.lower(
                 cfg, params, cache, *rows, prefill=None, horizon=1, mesh=None,
-                tp_manual=False,
-                collective_qtype=eng._collective_qtype).compile()
+                tp_manual=False, collective_qtype=eng._collective_qtype,
+                state_src=state_src).compile()
         except jax.errors.JaxRuntimeError as e:
             # what the chip's compiler would refuse, e.g. out of HBM
             out["refused"] = str(e).split("\n\n")[0][:400]

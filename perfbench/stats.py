@@ -83,7 +83,9 @@ def metric_values(e2e: dict) -> dict:
     for name, key, q in (("ttft_p50_ms", "ttft_ms", 50),
                          ("ttft_p90_ms", "ttft_ms", 90),
                          ("itl_p50_ms", "itl_ms", 50),
-                         ("itl_p95_ms", "itl_ms", 95)):
+                         ("itl_p90_ms", "itl_ms", 90),
+                         ("itl_p95_ms", "itl_ms", 95),
+                         ("itl_p98_ms", "itl_ms", 98)):
         try:
             out[name] = percentile(e2e[key], q)
         except ValueError:

@@ -3,7 +3,9 @@
     JAX_PLATFORMS=cpu python -m pytest perfbench/tests/test_spans.py -q
 
 ``testdata/spans_small.json`` is the parsed form (xplane_spans.py) of a few
-ticks of ``mistral7b.chat-steady`` traced on a TPU v5e (my chip run, PR 25):
+ticks of ``mistral7b.chat-steady`` traced on a TPU v5e (my chip run, PR 25;
+the cell was retired in PR 33 for ``mistral7b.chat-near-knee``, the same
+configuration and shapes at a higher rate: the recorded trace stays):
 the host spans of those ticks, their module executions, and of each tick's
 operations the first few dozen, with name paths and HLO text as the trace
 gave them.  How it was cut, and the values below, are in ``CUT`` there.
@@ -398,14 +400,16 @@ def test_every_new_metric_resolves_to_its_reader_and_cells():
 
     root = os.path.dirname(BENCH)
     cells = {w: {m["name"]: m for m in run.resolve_cell(root, w)["per_layer"]}
-             for w in ("mistral7b.decode-saturated", "mistral7b.chat-steady")}
+             for w in ("mistral7b.decode-saturated",
+                       "mistral7b.chat-near-knee")}
     both = {"steady_tick_device_ms", "mixed_tick_share", "kv_pool_move_share",
             "unscoped_device_share"}
     ds_only = {"idle_plan_admit_ms", "idle_prepare_dispatch_ms",
                "idle_drain_commit_ms"}
     cs_only = {"mixed_tick_device_ms", "queue_wait_mean_ms",
                "admit_to_first_token_mean_ms"}
-    ds, cs = cells["mistral7b.decode-saturated"], cells["mistral7b.chat-steady"]
+    ds, cs = (cells["mistral7b.decode-saturated"],
+              cells["mistral7b.chat-near-knee"])
     assert both | ds_only <= set(ds) and not cs_only & set(ds)
     assert both | cs_only <= set(cs) and not ds_only & set(cs)
     for name in both | ds_only | cs_only:

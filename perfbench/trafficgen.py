@@ -5,8 +5,18 @@ generator imports this and must stay off the chip).
 Every seed gets the SAME multiset of lengths and arrival gaps, in another
 order: lengths and gaps are the distribution's quantiles at (i + 0.5) / n
 (a stratified sample), permuted by the seed.  So the work of a window does
-not change with the seed, only its order does, and two runs with different
-seeds differ no more than two runs of one seed.
+not change with the seed, only its order does.
+
+In an open loop the order is itself load: where long outputs and short gaps
+fall together more rows are live while prompts are chunked in, and a tail
+moves with it.  A traffic file may therefore fix the order's PATTERN
+(``"order": {"pattern_seed": s, "class_width": w}``): the sorted values fall
+into classes of ``w`` neighbours, which class stands at which place comes
+from ``pattern_seed`` (one realization of the arrival process, replayed by
+every run like a recorded trace), and the run's seed only permutes the
+members of a class among the class's places.  Every seed still gives the
+same multiset in another order, and the load over time is the same to
+within near-ties.  Without the key the seed permutes freely, as before.
 """
 
 from __future__ import annotations
@@ -45,23 +55,46 @@ def _permuted(values: list, rng: np.random.Generator) -> list:
     return [values[i] for i in rng.permutation(len(values))]
 
 
-def lengths(traffic: dict, n: int, rng: np.random.Generator) -> list[tuple[int, int]]:
-    """``n`` (prompt_len, output_len) pairs: both stratified, permuted
+class Order:
+    """How the sorted values of one draw come to their places: freely
+    permuted by the run's seed, or (``traffic["order"]``) by classes whose
+    places the file's ``pattern_seed`` fixes."""
+
+    def __init__(self, traffic: dict, rng: np.random.Generator):
+        self.rng = rng
+        spec = traffic.get("order")
+        self.width = int(spec["class_width"]) if spec else 0
+        self.pattern = (np.random.default_rng([int(spec["pattern_seed"])])
+                        if spec else None)
+
+    def place(self, values: list) -> list:
+        """``values`` (sorted, as the quantiles come) in this run's order."""
+        if self.pattern is None:
+            return _permuted(values, self.rng)
+        w = self.width
+        members = [_permuted(values[i:i + w], self.rng)
+                   for i in range(0, len(values), w)]
+        return [members[i // w].pop()
+                for i in self.pattern.permutation(len(values))]
+
+
+def lengths(traffic: dict, n: int, order: Order) -> list[tuple[int, int]]:
+    """``n`` (prompt_len, output_len) pairs: both stratified, placed
     independently."""
-    p = _permuted(quantiles(traffic["prompt_len"], n), rng)
-    o = _permuted(quantiles(traffic["output_len"], n), rng)
+    p = order.place(quantiles(traffic["prompt_len"], n))
+    o = order.place(quantiles(traffic["output_len"], n))
     return list(zip(p, o))
 
 
-def arrivals(rate: float, seconds: float, rng: np.random.Generator) -> list[float]:
+def arrivals(rate: float, seconds: float, order: Order) -> list[float]:
     """Poisson arrivals over [0, seconds): round(rate * seconds) of them, the
-    gaps being the exponential's quantiles in a seeded order, scaled so that
+    gaps being the exponential's quantiles in the run's order, scaled so that
     they fill the span exactly."""
     n = int(round(rate * seconds))
     if n <= 0:
         return []
     gaps = [-math.log(1.0 - (i + 0.5) / n) for i in range(n)]
-    gaps = _permuted(gaps, rng)
+    gaps = order.place(gaps)
     scale = seconds / sum(gaps)
     t, out = -0.5 * gaps[0] * scale, []
     for g in gaps:
@@ -81,13 +114,14 @@ def schedule(traffic: dict, seed: int, seconds: float, ramp_s: float) -> dict:
                  shared list the clients take from in order; it is long
                  enough that it is not exhausted (and wraps if it is).
     """
-    rng = np.random.default_rng([int(seed) & 0xFFFFFFFF, int(seed) >> 32])
+    order = Order(traffic, np.random.default_rng(
+        [int(seed) & 0xFFFFFFFF, int(seed) >> 32]))
     if traffic["loop"] == "open":
         rate = float(traffic["rate_rps"])
         reqs = []
         for t0, span in ((-ramp_s, ramp_s), (0.0, seconds)):
-            times = arrivals(rate, span, rng)
-            for t, (p, o) in zip(times, lengths(traffic, len(times), rng)):
+            times = arrivals(rate, span, order)
+            for t, (p, o) in zip(times, lengths(traffic, len(times), order)):
                 reqs.append({"due_s": t0 + t, "prompt_len": p,
                              "max_tokens": o})
         for i, r in enumerate(reqs):
@@ -96,7 +130,7 @@ def schedule(traffic: dict, seed: int, seconds: float, ramp_s: float) -> dict:
     if traffic["loop"] == "closed":
         n = int(traffic["pool"])
         reqs = [{"idx": i, "prompt_len": p, "max_tokens": o}
-                for i, (p, o) in enumerate(lengths(traffic, n, rng))]
+                for i, (p, o) in enumerate(lengths(traffic, n, order))]
         return {"loop": "closed", "clients": int(traffic["clients"]),
                 "requests": reqs}
     raise ValueError(f"unknown loop kind {traffic['loop']!r}")
