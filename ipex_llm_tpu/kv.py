@@ -63,6 +63,34 @@ def paged_page_bytes(n_layers: int, n_kv_heads: int, page_size: int,
     return n_layers * n_kv_heads * page_size * (head_dim + vd) * itemsize
 
 
+def pool_geometry(cfg, storage: str = "bf16") -> tuple[int, int, int]:
+    """What ONE token holds in a layer of the paged pool, by the layer's
+    kind: (heads, width of the ``k`` pool's row, width of the ``v``
+    pool's).  An attention layer holds K and V a kv head.  A latent-attention
+    (MLA) layer holds the LATENT: one row a token shared by every head, the
+    normalised compressed KV ``c`` (``kv_lora_rank`` wide, the ``k`` pool)
+    and the rotated shared key ``k_r`` (``qk_rope_head_dim``, the ``v``
+    pool): the absorbed walk's key is the two side by side and its value is
+    ``c`` (ops/pallas/ragged_paged_attention.py); the per-head K and V are
+    never stored.  bf16 only: the latent is already the compressed form, and
+    an e5m2 ``c`` feeds every head's keys AND values."""
+    if not cfg.is_mla:
+        return cfg.num_kv_heads, cfg.head_dim, cfg.v_dim
+    if storage != "bf16":
+        kv_storage_dtype(storage)        # an unknown name raises its own way
+        raise ValueError(
+            f"kv storage {storage!r} is not supported for a latent-attention "
+            f"(MLA) pool ({cfg.model_type}): the latent page is bf16 only")
+    return 1, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+
+
+def model_page_bytes(cfg, page_size: int, storage: str = "bf16") -> int:
+    """:func:`paged_page_bytes` of ``cfg``'s pool (:func:`pool_geometry`)."""
+    heads, kd, vd = pool_geometry(cfg, storage)
+    return paged_page_bytes(cfg.kv_layers, heads, page_size, kd,
+                            v_head_dim=vd, storage=storage)
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass
 class KVCache:
@@ -187,6 +215,11 @@ class PagedKVCache:
 
     Page allocation, refcounts, and prefix sharing are host-side concerns
     (serving/engine.py PageAllocator) — the device object is pure data.
+
+    What a page holds follows the layers' KIND (:func:`pool_geometry`,
+    :meth:`for_model`): K and V a kv head, or a latent-attention model's
+    LATENT (``latent``, below).  Everything that moves whole pages (prefix
+    reuse, spill, gather/scatter) is the same for both.
     """
 
     k: jnp.ndarray       # [L, P, Hkv, page, D]
@@ -195,6 +228,14 @@ class PagedKVCache:
     length: jnp.ndarray  # scalar int32 (engines drive per-row slot_offsets)
 
     storage: str = "bf16"
+    # a LATENT pool (:func:`pool_geometry`; a latent-attention model's):
+    # ``k`` [L, P, 1, page, rank] holds the compressed KV ``c`` a token,
+    # laid out like K of one head, and ``v`` [L, P, 1, rope, page] the
+    # shared rotated key TOKEN-MINOR: a page of it is ``[rope, page]``, which
+    # the absorbed walk multiplies as it lies and whose copy is whole tiles
+    # (a ``[page, 64]`` page is padded to 128 lanes on the chip and cannot
+    # be cut out of its stack by a copy)
+    latent: bool = False
     # the second kind of serving state, beside the pool in the SAME object:
     # a hybrid model's recurrent state (:func:`recurrent_state`), addressed
     # by ROW and not through the block table.  Carried through the tick like
@@ -204,18 +245,19 @@ class PagedKVCache:
 
     def tree_flatten(self):
         return ((self.k, self.v, self.tables, self.length, self.state),
-                (self.storage,))
+                (self.storage, self.latent))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         k, v, tables, length, state = children
-        return cls(k, v, tables, length, storage=aux[0], state=state)
+        return cls(k, v, tables, length, storage=aux[0], latent=aux[1],
+                   state=state)
 
     @classmethod
     def init(cls, n_layers: int, n_pages: int, n_rows: int, max_pages: int,
              n_kv_heads: int, page_size: int, head_dim: int,
              dtype=None, v_head_dim: int | None = None,
-             storage: str | None = None):
+             storage: str | None = None, latent: bool = False):
         """``storage`` selects the pool width ("bf16" | "fp8" e5m2); the
         whole access surface (encode/decode_layer/update/gather) keys off
         ``self.k.dtype``, so one class serves both formats — the serving
@@ -245,14 +287,26 @@ class PagedKVCache:
                 raise ValueError(
                     f"dtype {jnp.dtype(dtype).name} contradicts "
                     f"storage {storage!r} ({jnp.dtype(storage_dtype).name})")
+        v_page = (vd, page_size) if latent else (page_size, vd)
         return cls(
             k=jnp.zeros((n_layers, n_pages, n_kv_heads, page_size, head_dim),
                         dtype),
-            v=jnp.zeros((n_layers, n_pages, n_kv_heads, page_size, vd), dtype),
+            v=jnp.zeros((n_layers, n_pages, n_kv_heads, *v_page), dtype),
             tables=jnp.full((n_rows, max_pages), -1, jnp.int32),
             length=jnp.zeros((), jnp.int32),
             storage=storage,
+            latent=latent,
         )
+
+    @classmethod
+    def for_model(cls, cfg, n_pages: int, n_rows: int, max_pages: int,
+                  page_size: int, storage: str = "bf16"):
+        """The pool of ``cfg``, its page shaped by the layers' kind
+        (:func:`pool_geometry`): K and V a kv head, or the latent."""
+        heads, kd, vd = pool_geometry(cfg, storage)
+        return cls.init(cfg.kv_layers, n_pages, n_rows, max_pages, heads,
+                        page_size, kd, v_head_dim=vd, storage=storage,
+                        latent=cfg.is_mla)
 
     @property
     def page_size(self) -> int:
@@ -269,8 +323,9 @@ class PagedKVCache:
         formula, :func:`paged_page_bytes`; init guarantees the storage
         tag matches the pool dtypes)."""
         l, _, h, ps, d = self.k.shape
-        return paged_page_bytes(l, h, ps, d, v_head_dim=self.v.shape[4],
-                                storage=self.storage)
+        return paged_page_bytes(
+            l, h, ps, d, v_head_dim=self.v.shape[3 if self.latent else 4],
+            storage=self.storage)
 
     @property
     def pool_bytes(self) -> int:
@@ -333,6 +388,9 @@ class PagedKVCache:
         and the reference :meth:`write_layer` is tested against."""
         pages, offs = self._route(*new_k.shape[:2], pos)
         kl = kl.at[pages, :, offs].set(self.encode(new_k))
+        if self.latent:      # token-minor: [P, 1, rope, page]
+            return kl, vl.at[pages, 0, :, offs].set(
+                self.encode(new_v[:, :, 0]))
         vl = vl.at[pages, :, offs].set(self.encode(new_v))
         return kl, vl
 
@@ -347,6 +405,9 @@ class PagedKVCache:
         carries the stacks, so the scatter updates the carried buffer."""
         pages, offs = self._route(*new_k.shape[:2], pos)
         k = k.at[layer, pages, :, offs].set(self.encode(new_k))
+        if self.latent:
+            return k, v.at[layer, pages, 0, :, offs].set(
+                self.encode(new_v[:, :, 0]))
         v = v.at[layer, pages, :, offs].set(self.encode(new_v))
         return k, v
 
@@ -377,16 +438,20 @@ class PagedKVCache:
         )
 
     @jax.named_scope("layer/kv_read")
-    def gather_layer(self, kl: jnp.ndarray, layer=None) -> jnp.ndarray:
+    def gather_layer(self, kl: jnp.ndarray, layer=None,
+                     token_minor: bool = False) -> jnp.ndarray:
         """The rows' pages as head-major rows [R, H, maxP*page, D] (the raw
         layout cached_sdpa's decode path consumes): from a pool layer
         [P, H, page, D], or — ``layer`` given — from the whole stack
         [L, P, H, page, D] by ``(layer, page)``, one gather with no
         whole-layer slice in between (the XLA fallback of the in-place
-        addressing; no benchmark cell runs it)."""
+        addressing; no benchmark cell runs it).  ``token_minor``: ``kl`` is
+        a latent pool's ``v``."""
         r, maxp = self.tables.shape
         t = jnp.clip(self.tables, 0, kl.shape[-4] - 1)
         g = kl[t] if layer is None else kl[layer, t]   # [R, maxP, H, page, D]
+        if token_minor:      # a latent pool's ``v``: [.., rope, page]
+            g = g.transpose(0, 1, 2, 4, 3)
         g = g.transpose(0, 2, 1, 3, 4)
         return g.reshape(r, g.shape[1], maxp * self.page_size, g.shape[4])
 
@@ -406,24 +471,28 @@ def recurrent_state(cfg, rows: int) -> dict | None:
     """The recurrent state of ``cfg``'s Mamba-2 blocks for ``rows`` rows,
     zeroed: ``h`` [M blocks, rows, H, P, N] float32, ``conv`` [M blocks,
     rows, K-1, conv width] bf16 (the last K-1 conv inputs) and the
-    ``stats`` counters (STATE_COUNTERS).  None where the model has none."""
+    ``stats`` counters (STATE_COUNTERS).  A model without Mamba-2 blocks
+    whose expert layers hold a SHARE of their experts
+    (``cfg.moe_experts_held``) carries the counters alone: they ride the
+    state so that a rolled-back tick's are dropped with it.  None where the
+    model has neither."""
+    stats = jnp.zeros((len(STATE_COUNTERS),), jnp.int32)
     if not cfg.ssm_layers:
-        return None
+        return {"stats": stats} if cfg.moe_experts_held else None
     return {
         "h": jnp.zeros((cfg.ssm_layers, rows, cfg.ssm_num_heads,
                         cfg.ssm_head_dim, cfg.ssm_state_size), jnp.float32),
         "conv": jnp.zeros((cfg.ssm_layers, rows, cfg.ssm_conv_kernel - 1,
                            cfg.ssm_conv_dim), jnp.bfloat16),
-        "stats": jnp.zeros((len(STATE_COUNTERS),), jnp.int32),
+        "stats": stats,
     }
 
 
 def with_recurrent_state(cache, cfg, rows: int):
     """``cache`` with ``cfg``'s zeroed recurrent state for ``rows`` rows as
     its ``state`` (the cache itself for a model without one)."""
-    if not cfg.ssm_layers:
-        return cache
-    return replace(cache, state=recurrent_state(cfg, rows))
+    state = recurrent_state(cfg, rows)
+    return cache if state is None else replace(cache, state=state)
 
 
 def state_bytes(state) -> int:

@@ -484,7 +484,13 @@ def build_params(
                     get(moe_scheme.score_bias.format(i=i)), jnp.float32
                 )
             e_gu, e_down, e_ub, e_db = [], [], [], []
-            for e in range(cfg.num_experts):
+            # a held layer (``cfg.moe_experts_held``) holds a chip's share
+            # of the experts, from ``cfg.moe_first_expert`` on, and the
+            # whole router
+            held = (range(cfg.moe_first_expert,
+                          cfg.moe_first_expert + cfg.moe_experts_held)
+                    if cfg.moe_experts_held else range(cfg.num_experts))
+            for e in held:
                 uw = get(moe_scheme.e_up.format(i=i, e=e))
                 dw = get(moe_scheme.e_down.format(i=i, e=e))
                 if moe_scheme.e_gate is not None:
@@ -580,7 +586,10 @@ def build_params(
         # dequantize at gather time (ops/embedding.py)
         params["embed"] = qcore.quantize(get(scheme.embed), embedding_qtype)
     else:
-        params["embed"] = jnp.asarray(get(scheme.embed), jnp.bfloat16)
+        # (the first ``cfg.vocab_size`` rows: all of them, or a chip's
+        # share of a deployment that divides the vocabulary)
+        params["embed"] = jnp.asarray(get(scheme.embed)[: cfg.vocab_size],
+                                      jnp.bfloat16)
     if scheme.pos_embed is not None and has(scheme.pos_embed):
         pe = get(scheme.pos_embed)
         if cfg.learned_pos and pe.shape[0] > cfg.learned_pos:
@@ -601,7 +610,7 @@ def build_params(
         pass  # decoder uses embed.T
     else:
         head_q = lm_head_qtype or ("sym_int8" if mixed_precision else qtype)
-        lm_w = get(scheme.lm_head)
+        lm_w = get(scheme.lm_head)[: cfg.vocab_size]
         # reference is_lm_head mixed-precision rule (convert.py:126): keep
         # big-vocab heads at >=8 bit when mixed_precision is requested
         params["lm_head"] = quantize_weight(lm_w, head_q)
@@ -609,6 +618,13 @@ def build_params(
         if scheme.lm_head.endswith(".weight") and has(head_bias):
             params["lm_head_bias"] = jnp.asarray(get(head_bias), jnp.float32)
 
+    if cfg.moe_experts_held:
+        # a held expert layer reads its stacks in place by (layer, expert)
+        # through the grouped dequant-matmul kernel, whose tiles have to
+        # cover the planes exactly: pad every 4-bit plane here, once
+        params = jax.tree_util.tree_map(
+            lambda a: qcore.pad_planes(a) if isinstance(a, QTensor) else a,
+            params, is_leaf=lambda a: isinstance(a, QTensor))
     if cfg.rope is not None:
         params["inv_freq"] = jnp.asarray(
             cfg.rope.inv_freq(cfg.max_position_embeddings), jnp.float32

@@ -28,9 +28,11 @@ from ipex_llm_tpu.models.config import ModelConfig
 from ipex_llm_tpu.ops import linear as linear_ops
 from ipex_llm_tpu.ops import mlp as mlp_ops
 from ipex_llm_tpu.ops import rope as rope_ops
-from ipex_llm_tpu.ops.attention import cached_sdpa, paged_kv_write
+from ipex_llm_tpu.ops.attention import (cached_sdpa, latent_attention,
+                                        paged_kv_write)
 from ipex_llm_tpu.ops.norms import layer_norm, rms_norm
-from ipex_llm_tpu.quantize.core import layer_views
+from ipex_llm_tpu.quantize.core import (QLayer, QTensor, dequantize,
+                                        layer_views)
 
 COMPUTE_DTYPE = jnp.bfloat16
 
@@ -90,52 +92,9 @@ def _attention_block(cfg: ModelConfig, lp: dict, x, kl, vl, cos, sin, slot0,
         h = (x if cfg.norm_after or pre_normed
              else _in_norm(x, lp, "attn_norm", cfg))
     if cfg.is_mla:
-        # DeepSeek MLA (reference deepseek.py:274-343): low-rank q, a
-        # compressed KV latent with a shared (MQA-like) rope slice, and an
-        # unbalanced cache — K at qk dim (nope+rope), V at v_head_dim.
-        nope, rd_pe = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-        if "q_a" in lp:
-            qa = linear_ops.linear(h, lp["q_a"], lp.get("q_a_bias"))
-            q = linear_ops.linear(
-                rms_norm(qa, lp["q_a_norm"], cfg.norm_eps), lp["q_b"]
-            )
-        else:  # V2-Lite: full-rank q_proj
-            q = linear_ops.linear(h, lp["q"], lp.get("q_bias"))
-        q = q.reshape(b, t, cfg.num_heads, cfg.head_dim)
-        q_nope, q_pe = q[..., :nope], q[..., nope:]
-
-        ckv = linear_ops.linear(h, lp["kv_a"], lp.get("kv_a_bias"))
-        c = rms_norm(ckv[..., : cfg.kv_lora_rank], lp["kv_a_norm"],
-                     cfg.norm_eps)
-        k_pe = ckv[..., cfg.kv_lora_rank :][:, :, None, :]  # [B,T,1,rd]
-        kv = linear_ops.linear(c, lp["kv_b"]).reshape(
-            b, t, cfg.num_heads, nope + cfg.v_dim
-        )
-        k_nope, v = kv[..., :nope], kv[..., nope:]
-
-        q_pe = rope_ops.apply_rope(q_pe, cos, sin, "two")
-        k_pe = rope_ops.apply_rope(k_pe, cos, sin, "two")
-        q = jnp.concatenate([q_nope, q_pe], axis=-1)
-        k = jnp.concatenate(
-            [k_nope,
-             jnp.broadcast_to(k_pe, (b, t, cfg.num_heads, rd_pe))],
-            axis=-1,
-        )
-
-        obs_q = q[:, -collect_obs:] if collect_obs else jnp.zeros((0,), x.dtype)
-        kl, vl = write(k, v)
-        attn = cached_sdpa(
-            q, kl, vl, cache,
-            compute_dtype=COMPUTE_DTYPE, causal=True, q_positions=q_slots,
-            kv_len=kv_len, kv_start=kv_start, window=None, window_on=sliding,
-            softcap=cfg.attn_softcap, scale=cfg.attn_scale,
-            chunk_lens=chunk_lens, layer=layer,
-        )
-        attn = attn.reshape(b, t, cfg.num_heads * cfg.v_dim)
-        out = linear_ops.linear(attn, lp["o"], lp.get("o_bias"))
-        if cfg.post_attn_norm:
-            out = _norm(out, lp["post_attn_norm"], cfg)
-        return out, kl, vl, obs_q
+        return _mla_block(cfg, lp, x, h, kl, vl, cos, sin, q_slots, kv_len,
+                          kv_start, sliding, cache, collect_obs, chunk_lens,
+                          layer, write)
     with jax.named_scope("layer/qkv"):
         q, k, v, obs_q = _qkv_rope(cfg, lp, x, h, cos, sin, collect_obs)
     kl, vl = write(k, v)
@@ -167,6 +126,109 @@ def _attention_block(cfg: ModelConfig, lp: dict, x, kl, vl, cos, sin, slot0,
         out = linear_ops.linear(attn, lp["o"], lp.get("o_bias"))
         if cfg.norm_after:
             out = _norm(out, lp["attn_norm"], cfg, lp.get("attn_norm_bias"))
+        if cfg.post_attn_norm:
+            out = _norm(out, lp["post_attn_norm"], cfg)
+    return out, kl, vl, obs_q
+
+
+def _dense_weight(w, dtype):
+    """A linear's weight ``[in, out]`` at full width: what an einsum that
+    contracts another axis than ``in`` needs (MLA's absorbed products)."""
+    if isinstance(w, QLayer):
+        w = w.take()
+    if isinstance(w, QTensor):
+        return dequantize(w, dtype=dtype)
+    return w.astype(dtype)
+
+
+def _mla_block(cfg: ModelConfig, lp: dict, x, h, kl, vl, cos, sin, q_slots,
+               kv_len, kv_start, sliding, cache, collect_obs, chunk_lens,
+               layer, write):
+    """One layer's latent attention (DeepSeek MLA, reference
+    deepseek.py:274-343): a low-rank q, a compressed KV latent ``c`` and ONE
+    rotated key slice ``k_r`` shared by every head; ``kv_b`` holds every
+    head's ``W_UK`` (c -> k_nope) and ``W_UV`` (c -> v) side by side.
+
+    Over a PAGED pool the cache holds the latent and nothing per head (kv.py
+    ``pool_geometry``: ``c`` in the ``k`` pool, ``k_r`` in the ``v`` pool),
+    and attention runs on it (ops/attention.py ``latent_attention``) in the
+    ABSORBED form for every call's shape: ``W_UK`` folded into the query and
+    ``W_UV`` applied to the latent-space output, both read from ``kv_b``
+    without a second copy kept.  Over a dense cache (``generate()``) K and V
+    are EXPANDED per head before the write, as before: K at the qk dim, V at
+    v_head_dim."""
+    b, t, _ = x.shape
+    nope, rd_pe, rank = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                         cfg.kv_lora_rank)
+    latent = hasattr(cache, "tables")
+    with jax.named_scope("layer/qkv"):
+        with jax.named_scope("mla/q"):
+            if "q_a" in lp:
+                qa = linear_ops.linear(h, lp["q_a"], lp.get("q_a_bias"))
+                q = linear_ops.linear(
+                    rms_norm(qa, lp["q_a_norm"], cfg.norm_eps), lp["q_b"])
+            else:  # V2-Lite: full-rank q_proj
+                q = linear_ops.linear(h, lp["q"], lp.get("q_bias"))
+            q = q.reshape(b, t, cfg.num_heads, cfg.head_dim)
+            q_nope = q[..., :nope]
+            q_pe = rope_ops.apply_rope(q[..., nope:], cos, sin, "two")
+        with jax.named_scope("mla/latent"):
+            ckv = linear_ops.linear(h, lp["kv_a"], lp.get("kv_a_bias"))
+            c = rms_norm(ckv[..., :rank], lp["kv_a_norm"], cfg.norm_eps)
+            k_pe = rope_ops.apply_rope(ckv[..., rank:][:, :, None, :], cos,
+                                       sin, "two")              # [B,T,1,rd]
+            if latent:
+                kl, vl = write(c[:, :, None, :], k_pe)
+
+    if latent:
+        if collect_obs:
+            raise NotImplementedError(
+                "SnapKV observation windows over a latent (MLA) paged pool")
+        with jax.named_scope("layer/attn"):
+            # the absorbed products see the call's tokens as ONE axis: a
+            # prefill block is [p_b, width] with p_b x width fixed by the
+            # budget, so every block shape is the same product
+            with jax.named_scope("mla/absorb"):
+                w_kvb = _dense_weight(lp["kv_b"], COMPUTE_DTYPE).reshape(
+                    rank, cfg.num_heads, nope + cfg.v_dim)
+                q_lat = jnp.einsum(
+                    "nhd,chd->nhc",
+                    q_nope.astype(COMPUTE_DTYPE).reshape(b * t, -1, nope),
+                    w_kvb[..., :nope], preferred_element_type=jnp.float32
+                ).astype(COMPUTE_DTYPE).reshape(b, t, -1, rank)
+            o_lat = latent_attention(
+                q_lat, q_pe, kl, vl, cache, kv_len, chunk_lens,
+                scale=(cfg.attn_scale if cfg.attn_scale is not None
+                       else cfg.head_dim ** -0.5), layer=layer)
+            with jax.named_scope("mla/absorb"):
+                attn = jnp.einsum(
+                    "nhc,chd->nhd", o_lat.reshape(b * t, -1, rank),
+                    w_kvb[..., nope:], preferred_element_type=jnp.float32
+                ).astype(x.dtype)
+        obs_q = jnp.zeros((0,), x.dtype)
+    else:
+        with jax.named_scope("mla/kv_up"):
+            kv = linear_ops.linear(c, lp["kv_b"]).reshape(
+                b, t, cfg.num_heads, nope + cfg.v_dim)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_pe, (b, t, cfg.num_heads, rd_pe))],
+                axis=-1)
+            v = kv[..., nope:]
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        obs_q = (q[:, -collect_obs:] if collect_obs
+                 else jnp.zeros((0,), x.dtype))
+        kl, vl = write(k, v)
+        attn = cached_sdpa(
+            q, kl, vl, cache,
+            compute_dtype=COMPUTE_DTYPE, causal=True, q_positions=q_slots,
+            kv_len=kv_len, kv_start=kv_start, window=None, window_on=sliding,
+            softcap=cfg.attn_softcap, scale=cfg.attn_scale,
+            chunk_lens=chunk_lens, layer=layer,
+        )
+    with jax.named_scope("layer/o_proj"):
+        attn = attn.reshape(b, t, cfg.num_heads * cfg.v_dim)
+        out = linear_ops.linear(attn, lp["o"], lp.get("o_bias"))
         if cfg.post_attn_norm:
             out = _norm(out, lp["post_attn_norm"], cfg)
     return out, kl, vl, obs_q
@@ -264,7 +326,10 @@ def _route(cfg: ModelConfig, lp: dict, router_logits):
             # its members
             g = sel.reshape(*sel.shape[:-1], cfg.moe_n_group, -1)
             if cfg.moe_group_score == "top2sum":
-                gs = jax.lax.top_k(g, 2)[0].sum(-1)
+                # term by term, like ``_sum_chosen``: as a reduction XLA
+                # orders it by the block's shape
+                top2 = jax.lax.top_k(g, 2)[0]
+                gs = top2[..., 0] + top2[..., 1]
             else:
                 gs = g.max(-1)
             _, gidx = jax.lax.top_k(gs, cfg.moe_topk_group)
@@ -677,7 +742,7 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
                x, cos, sin, slot0, q_slots, kv_len, kv_start, cache,
                collect_obs: int = 0, alibi_bias=None,
                cos_local=None, sin_local=None, chunk_lens=None,
-               layer_offset: int = 0):
+               layer_offset: int = 0, valid=None):
     """Scan one stacked layer tree over its layers of the cache.
 
     The single compiled layer body shared by decoder_forward and the
@@ -686,7 +751,9 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
     inside shard_map.  ``k_stack``/``v_stack`` are the cache's stacks, of
     which the tree's ``n`` layers start at ``layer_offset`` (a two-stack
     model's second tree starts past the first).  Returns (x, k_new, v_new,
-    obs_q) with the stacks whole again.
+    obs_q, moe_stats) with the stacks whole again; ``moe_stats`` int32 [4]
+    is what the tree's HELD expert layers counted (``_held_moe_block``),
+    zeros where it has none.
 
     How the cache rides the scan follows its class.  A dense cache
     [L, B, H, S, D] is per-row, so the scan runs over layer slices: the
@@ -697,7 +764,10 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
 
     Of the tree, the ``xs`` hold what XLA operations read and a slice
     fuses into: norm weights, biases, native-width and expert stacks,
-    beside the sliding flag and each layer's index.  The QUANTIZED linears
+    beside the sliding flag and each layer's index.  A HELD expert layer's
+    two stacks (``cfg.moe_experts_held``: the layer is told which experts it
+    holds; ``valid`` [B, T] then keeps padding from routing anywhere) do not
+    ride either: whole, read in place by ``(layer, expert)``.  The QUANTIZED linears
     do not ride: their stacks stay whole, closed over by the body, and
     ``lp`` holds a view "this stack at this layer" of each (``QLayer``),
     which the dequant-matmul kernel reads in place.  As ``xs`` every packed
@@ -706,10 +776,25 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
     30's ledger).
     """
     n = sliding_flags.shape[0]
+    held = cfg.moe_experts_held > 0 and "moe_gate_up" in tree
+    moe_whole = {}
+    if held:
+        if valid is None:
+            valid = jnp.ones(x.shape[:2], bool)
+        moe_whole = {k_: tree[k_] for k_ in ("moe_gate_up", "moe_down")}
+        tree = {k_: v_ for k_, v_ in tree.items() if k_ not in moe_whole}
     tree_xs, view = layer_views(tree)
     local = jnp.arange(n, dtype=jnp.int32)    # a layer's index in ``tree``
+    no_stats = jnp.zeros((4,), jnp.int32)
 
-    def layer_step(x, lp, kl, vl, sliding, layer):
+    def ffn(lp, x, i):
+        if held:
+            return _held_moe_block(cfg, dict(lp, **moe_whole), x, valid,
+                                   layer=i)
+        block = _moe_block if "moe_gate_up" in lp else _mlp_block
+        return block(cfg, lp, x), no_stats
+
+    def layer_step(x, lp, kl, vl, sliding, layer, i):
         if cos_local is not None:
             # gemma3 dual rope: sliding layers use the local table
             c = jnp.where(sliding, cos_local, cos)
@@ -730,13 +815,12 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
             x = a_in * alpha + attn_out
             m_in = _in_norm(x, lp, "mlp_norm", cfg)
             x = m_in * alpha + _mlp_block(cfg, lp, m_in, pre_normed=True)
-            return x, kl, vl, obs_q
+            return x, kl, vl, obs_q, no_stats
         attn_out, kl, vl, obs_q = _attention_block(
             cfg, lp, x, kl, vl, c, s_, slot0, q_slots, kv_len, kv_start,
             sliding, cache, collect_obs, bias=alibi_bias,
             chunk_lens=chunk_lens, layer=layer,
         )
-        ffn = _moe_block if "moe_gate_up" in lp else _mlp_block
         # minicpm depth scaling (cfg.residual_multiplier, 1.0 elsewhere)
         rm = (jnp.asarray(cfg.residual_multiplier, COMPUTE_DTYPE)
               if cfg.residual_multiplier != 1.0 else None)
@@ -746,40 +830,51 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
 
         if cfg.parallel_blocks:
             # x + attn(ln(x)) + mlp(ln'(x)) — phi/gpt-neox parallel residual
-            x = add(x, attn_out + ffn(cfg, lp, x))
+            out, stats = ffn(lp, x, i)
+            x = add(x, attn_out + out)
         else:
             x = add(x, attn_out)
-            x = add(x, ffn(cfg, lp, x))
-        return x, kl, vl, obs_q
+            out, stats = ffn(lp, x, i)
+            x = add(x, out)
+        return x, kl, vl, obs_q, stats
+
+    # a held layer's counters leave the scan as one more ``ys``, where a
+    # layer counts: every other model's scan is the program it was
+    def ys(obs_q, s_i):
+        return (obs_q, s_i) if held else obs_q
+
+    def split(out):
+        return (out[0], out[1].sum(0)) if held else (out, no_stats)
 
     if hasattr(cache, "tables"):
         def paged_body(carry, xs):
             x, k, v = carry
             lp, i, sliding = xs
-            x, k, v, obs_q = layer_step(x, view(lp, i), k, v, sliding,
-                                        layer_offset + i)
-            return (x, k, v), obs_q
+            x, k, v, obs_q, s_i = layer_step(x, view(lp, i), k, v, sliding,
+                                             layer_offset + i, i)
+            return (x, k, v), ys(obs_q, s_i)
 
-        (x, k_stack, v_stack), obs_q = jax.lax.scan(
+        (x, k_stack, v_stack), out = jax.lax.scan(
             paged_body, (x, k_stack, v_stack), (tree_xs, local, sliding_flags)
         )
-        return x, k_stack, v_stack, obs_q
+        return (x, k_stack, v_stack, *split(out))
 
     def body(x, xs):
         lp, i, kl, vl, sliding = xs
-        x, kl, vl, obs_q = layer_step(x, view(lp, i), kl, vl, sliding, None)
-        return x, (kl, vl, obs_q)
+        x, kl, vl, obs_q, s_i = layer_step(x, view(lp, i), kl, vl, sliding,
+                                           None, i)
+        return x, (kl, vl, ys(obs_q, s_i))
 
     whole = n == k_stack.shape[0]
     ours = slice(layer_offset, layer_offset + n)
-    x, (k_new, v_new, obs_q) = jax.lax.scan(
+    x, (k_new, v_new, out) = jax.lax.scan(
         body, x, (tree_xs, local, k_stack if whole else k_stack[ours],
                   v_stack if whole else v_stack[ours], sliding_flags)
     )
     if not whole:
         k_new = k_stack.at[ours].set(k_new)
         v_new = v_stack.at[ours].set(v_new)
-    return x, k_new, v_new, obs_q
+    return (x, k_new, v_new, *split(out))
 
 
 def decoder_forward(
@@ -857,12 +952,7 @@ def decoder_forward(
         if collect_obs:
             raise NotImplementedError(
                 "SnapKV observation windows on a hybrid model")
-        if chunk_lens is not None:
-            valid = jnp.arange(t)[None, :] < chunk_lens[:, None]
-        elif kv_start is not None:
-            valid = q_slots >= kv_start[:, None]
-        else:
-            valid = jnp.ones((b, t), bool)
+        valid = _valid_positions(b, t, q_slots, kv_start, chunk_lens)
         x, k_new, v_new, state = run_hybrid(
             cfg, params["hybrid"], cache, x, slot0, q_slots, kv_len,
             kv_start, valid, chunk_lens=chunk_lens, state_rows=state_rows)
@@ -887,14 +977,22 @@ def decoder_forward(
     else:
         stacks = [(params["layers"], 0, cfg.num_layers)]
     k_new, v_new, obs_parts = cache.k, cache.v, []
+    valid = (_valid_positions(b, t, q_slots, kv_start, chunk_lens)
+             if cfg.moe_experts_held else None)
+    state = cache.state
     for tree, lo, hi in stacks:
-        x, k_new, v_new, op = run_layers(
+        x, k_new, v_new, op, moe_stats = run_layers(
             cfg, tree, k_new, v_new, sliding_flags[lo:hi],
             x, cos, sin, slot0, q_slots, kv_len, kv_start, cache,
             collect_obs, alibi_bias, cos_local=cos_l, sin_local=sin_l,
-            chunk_lens=chunk_lens, layer_offset=lo,
+            chunk_lens=chunk_lens, layer_offset=lo, valid=valid,
         )
         obs_parts.append(op)
+        if state is not None and valid is not None:
+            # the held expert layers' device counters (kv.py
+            # ``STATE_COUNTERS``; ``src`` is the tick's read-only buffer)
+            state = {k_: v_ for k_, v_ in state.items() if k_ != "src"}
+            state["stats"] = state["stats"].at[:4].add(moe_stats)
     obs_q = (jnp.concatenate(obs_parts) if len(obs_parts) > 1
              else obs_parts[0])
 
@@ -902,10 +1000,19 @@ def decoder_forward(
                             gather_positions)
 
     new_len = cache.length if slot_offsets is not None else slot0 + t
-    new_cache = replace(cache, k=k_new, v=v_new, length=new_len)
+    new_cache = replace(cache, k=k_new, v=v_new, length=new_len, state=state)
     if collect_obs:
         return logits, new_cache, obs_q
     return logits, new_cache
+
+
+def _valid_positions(b: int, t: int, q_slots, kv_start, chunk_lens):
+    """[B, T]: which positions of a call hold a token (not padding)."""
+    if chunk_lens is not None:
+        return jnp.arange(t)[None, :] < chunk_lens[:, None]
+    if kv_start is not None:
+        return q_slots >= kv_start[:, None]
+    return jnp.ones((b, t), bool)
 
 
 def _chosen_logits(cfg: ModelConfig, params, x, last_token_only: bool,

@@ -11,7 +11,8 @@ exactly three quantized matmuls plus attention.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+import math
+from dataclasses import dataclass, field, replace
 from typing import Callable
 
 import numpy as np
@@ -403,8 +404,24 @@ def _deepseek_common(hf: dict) -> dict:
     )
     if hf.get("kv_lora_rank"):
         # rope table spans the rope slice; attention scales by full qk dim
-        d["rope"] = _rope_from_hf(hf, hf.get("qk_rope_head_dim", 64))
+        rope = _rope_from_hf(hf, hf.get("qk_rope_head_dim", 64))
         d["attn_scale"] = qk_dim ** -0.5
+        rs = hf.get("rope_scaling") or {}
+        if rope.kind == "yarn" and rope.attention_factor is None:
+            # the DeepSeek YaRN convention these keys come from: with m(s) =
+            # 0.1 s ln(factor) + 1, cos and sin carry m(mscale) /
+            # m(mscale_all_dim) and the SOFTMAX scale m(mscale_all_dim)^2,
+            # which also scales the position-free dims (the generic YaRN
+            # rule, m(1) on cos and sin alone, computes other logits)
+            def m(s_):
+                return (0.1 * s_ * math.log(rope.factor) + 1.0
+                        if rope.factor > 1 else 1.0)
+
+            m_all = m(float(rs.get("mscale_all_dim") or 0.0))
+            rope = replace(rope, attention_factor=m(
+                float(rs.get("mscale", 1.0))) / m_all)
+            d["attn_scale"] = d["attn_scale"] * m_all * m_all
+        d["rope"] = rope
     return d
 
 
@@ -424,6 +441,35 @@ def _deepseek_v3(hf: dict) -> ModelConfig:
         moe_score_func="sigmoid",
         moe_group_score="top2sum",
         moe_score_bias=True,
+    )
+    return ModelConfig(**d)
+
+
+def _axk1(hf: dict) -> ModelConfig:
+    """SKT A.X-K1: the DeepSeek-V3 block (MLA with a low-rank q, one dense
+    layer then expert layers beside a shared expert) with a sigmoid router,
+    the V3 group score (a group's two best) and NO score-correction bias
+    (``topk_method: "none"``; the key that carries one is ``noaux_tc``).
+    Its expert layers are HELD layers (models/decoder.py
+    ``_held_moe_block``): ``n_router_experts`` and ``first_routed_expert``
+    describe a chip's share of a deployment that divides the experts, the
+    router's width and the first expert held (``n_routed_experts`` of them;
+    all of them by default), as ``_nemotron_h`` reads them."""
+    if hf.get("topk_method", "none") != "none":
+        raise ValueError(
+            f"axk1: topk_method {hf['topk_method']!r} is not supported "
+            "(\"none\": selection on the raw scores, no "
+            "e_score_correction_bias)")
+    d = _deepseek_common(hf)
+    held = hf["n_routed_experts"]
+    d.update(
+        num_experts=hf.get("n_router_experts", held),
+        moe_first_expert=hf.get("first_routed_expert", 0),
+        moe_experts_held=held,
+        moe_n_group=hf.get("n_group") or 0,
+        moe_topk_group=hf.get("topk_group") or 0,
+        moe_score_func=hf.get("scoring_func", "sigmoid"),
+        moe_group_score="top2sum",
     )
     return ModelConfig(**d)
 
@@ -1215,6 +1261,7 @@ FAMILIES: dict[str, Family] = {
                           _DEEPSEEK_MOE),
     "deepseek_v3": Family("deepseek_v3", _deepseek_v3, _DEEPSEEK_SCHEME,
                           _DEEPSEEK_V3_MOE),
+    "axk1": Family("axk1", _axk1, _DEEPSEEK_SCHEME, _DEEPSEEK_MOE),
     "nemotron_h": Family("nemotron_h", _nemotron_h),
     "mixtral": Family("mixtral", _mixtral, WeightScheme(), _MIXTRAL_MOE),
     "qwen2_moe": Family("qwen2_moe", _qwen2_moe, WeightScheme(), _QWEN2_MOE),

@@ -192,6 +192,64 @@ def paged_kv_write(cache, k, v, layer, new_k, new_v, pos):
         mesh=dispatch.spmd_mesh() if mode == "sharded" else None)
 
 
+def _latent_walk_xla(q_lat, q_rope, c_rows, r_rows, kv_len, chunk_lens,
+                     scale):
+    """The absorbed walk as XLA operations: q_lat [B, T, H, rank], q_rope
+    [B, T, H, rope] against the rows' gathered latent c_rows [B, S, rank],
+    r_rows [B, S, rope]; query t of a row sits at slot ``kv_len - chunk_len
+    + t``.  Returns o_lat float32 [B, T, H, rank] (zeros for an idle row)."""
+    f32 = jnp.float32
+    s = (jnp.einsum("bthc,bsc->bths", q_lat.astype(f32), c_rows.astype(f32))
+         + jnp.einsum("bthr,bsr->bths", q_rope.astype(f32),
+                      r_rows.astype(f32))) * scale
+    t = q_lat.shape[1]
+    kpos = jnp.arange(c_rows.shape[1])[None, None, :]             # [1, 1, S]
+    qpos = (kv_len - chunk_lens)[:, None, None] + jnp.arange(t)[None, :, None]
+    live = ((kpos <= qpos) & (kpos < kv_len[:, None, None])
+            & (chunk_lens > 0)[:, None, None])                    # [B, T, S]
+    s = jnp.where(live[:, :, None, :], s, NEG_INF)
+    p = jnp.where(live[:, :, None, :],
+                  jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    return jnp.einsum("bths,bsc->bthc", p / jnp.where(l > 0.0, l, 1.0),
+                      c_rows.astype(f32))
+
+
+def latent_attention(q_lat, q_rope, c_pool, r_pool, cache, kv_len,
+                     chunk_lens, *, scale: float, layer):
+    """Latent (MLA) attention off a latent paged pool (kv.py
+    ``pool_geometry``), which already holds this call's tokens: the ABSORBED
+    form.  q_lat [B, T, H, rank] is the position-free query with ``W_UK``
+    folded in, q_rope [B, T, H, rope] the rotated one; every head attends
+    the one shared row a token, key ``[c ; k_r]``, value ``c``, causal
+    against the row's own history like :func:`cached_sdpa`'s paged branch
+    (queries at slots ``[kv_len - chunk_len, kv_len)``; an idle row gives
+    zeros).  On the chip the page walk
+    (ops/pallas/ragged_paged_attention.py ``latent_paged_sdpa``); elsewhere
+    the same arithmetic as XLA operations over the rows' gathered pages.
+    Returns the latent-space output [B, T, H, rank] in q's dtype: the caller
+    applies ``W_UV``."""
+    from ipex_llm_tpu.ops import dispatch
+
+    b, t = q_lat.shape[:2]
+    if chunk_lens is None:
+        chunk_lens = jnp.full((b,), t, jnp.int32)
+    if dispatch.spmd_mesh() is not None:
+        raise NotImplementedError(
+            "latent (MLA) attention over a paged pool under a mesh: the "
+            "latent row is shared by every head and is not sharded")
+    if dispatch.use_pallas(_paged_op(c_pool)):
+        from ipex_llm_tpu.ops.pallas import ragged_paged_attention as rpa
+
+        return rpa.latent_paged_sdpa(
+            q_lat, q_rope, c_pool, r_pool, cache.tables, kv_len, chunk_lens,
+            scale=scale, layer=layer)
+    return _latent_walk_xla(
+        q_lat, q_rope, cache.gather_layer(c_pool, layer)[:, 0],
+        cache.gather_layer(r_pool, layer, token_minor=True)[:, 0], kv_len,
+        chunk_lens, scale).astype(q_lat.dtype)
+
+
 def cached_sdpa(
     q: jnp.ndarray,            # [B, T, Hq, D]
     kl: jnp.ndarray,           # [B, Hkv, S, D] raw cache layer (maybe fp8)

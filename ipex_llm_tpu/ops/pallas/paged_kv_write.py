@@ -46,15 +46,18 @@ from ipex_llm_tpu.ops.pallas._compat import (
 _SLOT_BLOCK = 32
 
 
-def _kernel(layer_ref, page_ref, blk_ref, t0_ref, shift_ref, kn_ref, vn_ref,
-            k_in, v_in, k_out, v_out, *, t, sb, m, nsub):
+def _kernel(layer_ref, page_ref, blk_ref, t0_ref, shift_ref, *refs, t, sb, m,
+            nsub):
     """Grid step (row, j): patch the j-th slot block this row's chunk
-    touches.  Row s of the block takes token ``t0 + s`` of the chunk where
-    that is one of its ``t`` tokens, and keeps what it held elsewhere."""
+    touches, in each of the call's pools (``refs``: the new rows of every
+    pool, then the pools in, then the pools out).  Row s of the block takes
+    token ``t0 + s`` of the chunk where that is one of its ``t`` tokens, and
+    keeps what it held elsewhere."""
+    n = len(refs) // 3
     i = pl.program_id(0) * nsub + pl.program_id(1)
     tok = t0_ref[i] + jax.lax.broadcasted_iota(jnp.int32, (1, sb, 1), 1)
     mask = (tok >= 0) & (tok < t)
-    for new_ref, src, dst in ((kn_ref, k_in, k_out), (vn_ref, v_in, v_out)):
+    for new_ref, src, dst in zip(refs[:n], refs[n:2 * n], refs[2 * n:]):
         new = new_ref[0]                                  # [Hkv, T8, D] f32
         if new.shape[1] < m:
             new = jnp.concatenate(
@@ -67,34 +70,48 @@ def _kernel(layer_ref, page_ref, blk_ref, t0_ref, shift_ref, kn_ref, vn_ref,
         dst[0] = jnp.where(mask, cand, tile).astype(dst.dtype)
 
 
+def _slot_blocks(pos, pages, t: int, ps: int, sb: int):
+    """Where a chunk's ``t`` tokens from slot ``pos`` [B] land, in slot
+    blocks of ``sb``: (nsub, page [B, nsub], block in page, t0, the chunk
+    token in the block's row 0).  Slot block g of a row covers slots [g*sb,
+    (g+1)*sb); past the chunk's last block the walk stays on it (same block
+    index: Pallas moves nothing, and the step rewrites what the last one
+    wrote)."""
+    nsub = -(-(t - 1) // sb) + 1          # slot blocks a chunk can span
+    g = jnp.minimum(pos[:, None] // sb + jnp.arange(nsub)[None, :],
+                    ((pos + t - 1) // sb)[:, None])              # [B, nsub]
+    page = jnp.take_along_axis(pages, g * sb // ps - (pos // ps)[:, None],
+                               axis=1)
+    return nsub, page, g % (ps // sb), g * sb - pos[:, None]
+
+
+def _flat(a):
+    return a.reshape(-1).astype(jnp.int32)
+
+
 @jax.jit
 def _write(k, v, layer, pages, pos, new_k, new_v):
     """k/v [L, P, Hkv, ps, D(v)] stacks; ``layer`` int32 scalar; ``pages``
     [B, nP] the routed page of each page index a row's chunk can touch
     (0 = scratch), first its page ``pos // ps``; ``pos`` [B] the chunk's
     first slot; new_k/new_v [B, T, Hkv, D(v)] in the storage dtype."""
-    b, t = new_k.shape[:2]
-    _, _, hkv, ps, _ = k.shape
+    return _write_pools((k, v), layer, pages, pos, (new_k, new_v))
+
+
+def _write_pools(pools, layer, pages, pos, news):
+    """:func:`_write` over any number of pools that share the K/V layout
+    ``[L, P, Hkv, ps, D]`` (token slots second-minor)."""
+    b, t = news[0].shape[:2]
+    _, _, hkv, ps, _ = pools[0].shape
+    n = len(pools)
     sb = _SLOT_BLOCK if ps % _SLOT_BLOCK == 0 else ps
-    nsub = -(-(t - 1) // sb) + 1          # slot blocks a chunk can span
     t8 = _round_up(t, 8)
     m = max(t8, sb)
-
-    # slot block g of a row covers slots [g*sb, (g+1)*sb); past the chunk's
-    # last block the walk stays on it (same block index: Pallas moves
-    # nothing, and the step rewrites what the last one wrote)
-    g = jnp.minimum(pos[:, None] // sb + jnp.arange(nsub)[None, :],
-                    ((pos + t - 1) // sb)[:, None])              # [B, nsub]
-    page = jnp.take_along_axis(pages, g * sb // ps - (pos // ps)[:, None],
-                               axis=1)
-    t0 = g * sb - pos[:, None]            # chunk token in the block's row 0
+    nsub, page, blk, t0 = _slot_blocks(pos, pages, t, ps, sb)
 
     def rows(new):                        # [B, T, H, D] -> [B, H, T8, D] f32
         new = new.astype(jnp.float32).transpose(0, 2, 1, 3)
         return jnp.pad(new, ((0, 0), (0, 0), (0, t8 - t), (0, 0)))
-
-    def flat(a):
-        return a.reshape(-1).astype(jnp.int32)
 
     def pool_map(bi, ji, layer_ref, page_ref, blk_ref, t0_ref, shift_ref):
         i = bi * nsub + ji
@@ -106,32 +123,92 @@ def _write(k, v, layer, pages, pos, new_k, new_v):
     def pool_spec(d):
         return pl.BlockSpec((None, 1, hkv, sb, d), pool_map)
 
-    dk, dv = k.shape[-1], v.shape[-1]
+    widths = [a.shape[-1] for a in pools]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(b, nsub),
-        in_specs=[
-            pl.BlockSpec((1, hkv, t8, dk), new_map),
-            pl.BlockSpec((1, hkv, t8, dv), new_map),
-            pool_spec(dk), pool_spec(dv),
-        ],
-        out_specs=[pool_spec(dk), pool_spec(dv)],
+        in_specs=[pl.BlockSpec((1, hkv, t8, d), new_map) for d in widths]
+        + [pool_spec(d) for d in widths],
+        out_specs=[pool_spec(d) for d in widths],
     )
     return pl.pallas_call(
         functools.partial(_kernel, t=t, sb=sb, m=m, nsub=nsub),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        # operands count the scalar prefetch: the stacks are 7 and 8
-        input_output_aliases={7: 0, 8: 1},
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in pools],
+        # operands count the scalar prefetch: the stacks follow it and the
+        # new rows
+        input_output_aliases={5 + n + i: i for i in range(n)},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=_interpret(),
         name="paged_kv_write",
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), flat(page),
-      flat(g % (ps // sb)), flat(t0), flat((-t0) % m), rows(new_k),
-      rows(new_v), k, v)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), _flat(page), _flat(blk),
+      _flat(t0), _flat((-t0) % m), *(rows(a) for a in news), *pools)
+
+
+def _lane_kernel(layer_ref, page_ref, t0_ref, shift_ref, new_ref, src, dst, *,
+                 t, ps, m, nsub):
+    """Grid step (row, j) over a TOKEN-MINOR pool: patch the j-th page this
+    row's chunk touches.  Lane s of the page takes token ``t0 + s`` of the
+    chunk where that is one of its ``t`` tokens."""
+    i = pl.program_id(0) * nsub + pl.program_id(1)
+    tok = t0_ref[i] + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+    mask = (tok >= 0) & (tok < t)
+    new = new_ref[0]                                      # [D, Tp] f32
+    if new.shape[1] < m:
+        new = jnp.concatenate(
+            [new, jnp.zeros((new.shape[0], m - new.shape[1]), new.dtype)],
+            axis=1)
+    cand = pltpu.roll(new, shift_ref[i], axis=1)[:, :ps]
+    tile = src[0, 0].astype(jnp.float32)                  # [D, ps]
+    dst[0, 0] = jnp.where(mask, cand, tile).astype(dst.dtype)
+
+
+def _write_lanes(pool, layer, pages, pos, new):
+    """The write into a token-minor pool ``[L, P, 1, D, ps]`` (a latent
+    pool's shared rotated key, kv.py): new [B, T, 1, D]; a grid step patches
+    one whole page ``[D, ps]``, the chunk's tokens rotated along the lanes
+    to where they sit in it."""
+    b, t = new.shape[:2]
+    _, _, _, d, ps = pool.shape
+    tp = _round_up(t, 128)
+    m = max(tp, _round_up(ps, 128))
+    nsub, page, _, t0 = _slot_blocks(pos, pages, t, ps, ps)
+
+    def pool_map(bi, ji, layer_ref, page_ref, t0_ref, shift_ref):
+        return (layer_ref[0], page_ref[bi * nsub + ji], 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b, nsub),
+        in_specs=[pl.BlockSpec((1, d, tp), lambda bi, ji, *_: (bi, 0, 0)),
+                  pl.BlockSpec((None, 1, 1, d, ps), pool_map)],
+        out_specs=pl.BlockSpec((None, 1, 1, d, ps), pool_map),
+    )
+    cols = jnp.pad(new[:, :, 0, :].astype(jnp.float32).transpose(0, 2, 1),
+                   ((0, 0), (0, 0), (0, tp - t)))         # [B, D, Tp]
+    return pl.pallas_call(
+        functools.partial(_lane_kernel, t=t, ps=ps, m=m, nsub=nsub),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={5: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+        ),
+        interpret=_interpret(),
+        name="paged_kv_write_lanes",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), _flat(page), _flat(t0),
+      _flat((-t0) % m), cols, pool)
+
+
+@jax.jit
+def _write_latent(k, v, layer, pages, pos, new_c, new_r):
+    """A latent pool's write: ``c`` rows into ``k`` [L, P, 1, ps, rank] like
+    K rows, the rotated key into the token-minor ``v`` [L, P, 1, rope,
+    ps]."""
+    (k,) = _write_pools((k,), layer, pages, pos, (new_c,))
+    return k, _write_lanes(v, layer, pages, pos, new_r)
 
 
 def paged_kv_write(cache, k, v, layer, new_k, new_v, pos, mesh=None):
@@ -140,7 +217,8 @@ def paged_kv_write(cache, k, v, layer, new_k, new_v, pos, mesh=None):
     offset)`` through ``cache.tables`` and return the stacks, which the
     call updates in place.  With ``mesh`` (tensor parallelism under GSPMD)
     the stacks and the new rows split over their kv heads and every shard
-    patches its heads of the same blocks; the routing is replicated."""
+    patches its heads of the same blocks; the routing is replicated.  A
+    LATENT pool (``cache.latent``) takes its own two writes."""
     b, t = new_k.shape[:2]
     ps = cache.page_size
     if getattr(pos, "ndim", 0) == 0:
@@ -150,6 +228,8 @@ def paged_kv_write(cache, k, v, layer, new_k, new_v, pos, mesh=None):
         (pos // ps)[:, None] + jnp.arange(n_pages)[None, :])
     args = (k, v, jnp.asarray(layer, jnp.int32), pages, pos,
             cache.encode(new_k), cache.encode(new_v))
+    if cache.latent:
+        return _write_latent(*args)
     if mesh is None:
         return _write(*args)
     from jax.sharding import PartitionSpec as P

@@ -54,9 +54,10 @@ scalar-prefetch operand, and a page's copy reads ``pool[layer, table[row,
 trip]]`` — so the decoder's layer scan never slices a layer out of the
 stack to feed it (kv.py ``PagedKVCache``).  A caller holding one layer
 ``[P, Hkv, ps, D]`` passes it as a stack of one.  Head sizes that need
-lane padding (``D % 128``, MLA's 192) pad a copy of the pool: for those
-the one layer is sliced out first, a choice by shape, so the pad never
-touches the stack.
+lane padding (``D % 128``) pad a copy of the pool: for those the one layer
+is sliced out first, a choice by shape, so the pad never touches the stack.
+(A latent-attention model's pool holds the latent, not per-head K at 192,
+and takes the walk at the end of this file.)
 
 K/V pages stream in the pool's STORAGE dtype and widen to the compute
 dtype in-kernel: an fp8(e5m2) pool (``EngineConfig.kv_storage="fp8"``)
@@ -377,3 +378,205 @@ def ragged_paged_sdpa_sharded(q, k_pool, v_pool, tables, kv_len, mesh,
         check_vma=False,
     )(q, k_pool, v_pool, tables, kv_len, chunk_len,
       jnp.asarray(layer, jnp.int32))
+
+
+# -- latent attention (MLA) over a latent pool -----------------------------------
+#
+# A latent pool (kv.py ``pool_geometry``) holds one row a token a layer,
+# shared by every head: the normalised compressed KV ``c`` [L, P, 1, ps,
+# rank] and the rotated shared key ``k_r`` token-minor [L, P, 1, rope, ps]
+# (a page of it is whole tiles, and multiplies as it lies).  ONE kernel reads
+# it, the ABSORBED walk (:func:`latent_paged_sdpa`): every head of a row
+# against each latent page, an MQA walk whose key is ``[c ; k_r]`` and whose
+# value is ``c``, the key's first ``rank`` lanes.  The query has ``W_UK``
+# folded in (``q~ = W_UK^T q_nope``, models/decoder.py) and the output is
+# still latent (``W_UV`` follows).  The same scalar prefetch, HBM pools and
+# two VMEM slots as the K/V walk above, a few pages a trip over the row's
+# ``cdiv(kv_len, ps)`` live pages; the grid's second axis blocks a row's
+# queries (chunk x heads, token-major) where it blocked kv heads.  Decode
+# rows, prefill chunks and verify windows all take it, causal against the
+# row's own history like the K/V walk.
+#
+# A chunk's own keys are walked ABSORBED too, out of the pool, where the
+# write has just put them.  (Taking them in the expanded form, per-head K and
+# V up-projected from the chunk's latent, and merging the two parts by their
+# log-sum-exp is fewer operations, and was measured: it makes a token's bits
+# depend on how its prompt was CHUNKED, and the chunk's width follows from how
+# many rows join a tick; on the chip a 120-token probe beside prefilling rows
+# read another first log-probability and another token within 24 in 2 of 3
+# cases, PERF.md section 6, PR 37.)  Here a query's arithmetic is a function
+# of its own ``q``, the pages before it in order, and the trips' fixed
+# bounds: a trip it cannot see leaves its state untouched, bit for bit.
+
+_LATENT_Q_BLOCK = 512
+_LATENT_PAGES_PER_TRIP = 4
+
+
+def _attend_trip(s, c, m_ref, l_ref, acc_ref, compute_dtype):
+    """One online-softmax step of the absorbed walk over a trip's masked
+    float32 scores ``s`` [Q, K]: the value is the latent ``c`` [K, rank]
+    itself; the running max / sum / accumulator updated in place."""
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    m_safe = jnp.maximum(m_new, -1e29)
+    p = jnp.exp(s - m_safe)
+    alpha = jnp.exp(jnp.maximum(m_prev, -1e29) - m_safe)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p.astype(compute_dtype), c.astype(compute_dtype),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
+def _latent_kernel(tables_ref, len_ref, chunk_ref, layer_ref, ql_ref, qr_ref,
+                   c_hbm, r_hbm, o_ref, cbuf, rbuf, sem, m_ref, l_ref,
+                   acc_ref, *, scale, ps, pp, heads, compute_dtype):
+    """One (row, block of queries) grid step of the absorbed walk: the
+    row's pages ``[0, cdiv(kv_len, ps))``, ``pp`` latent pages a trip (a
+    trip costs ~1.5 us whatever it moves, and one latent page is 0.2 us of
+    the device's bandwidth).  Query row j of the block is token ``j //
+    heads`` of the chunk, at slot ``kv_len - chunk_len + j // heads``.  A
+    trip past the row's last live page copies that page again: its slots lie
+    past ``kv_len`` and are masked."""
+    r = pl.program_id(0)
+    kv_len = len_ref[r]
+    chunk = chunk_ref[r]
+    layer = layer_ref[0]
+    n_pages = jnp.where(
+        chunk > 0, jnp.minimum(pl.cdiv(kv_len, ps), tables_ref.shape[1]), 0)
+    n_trips = pl.cdiv(n_pages, pp)
+    q0 = pl.program_id(1) * ql_ref.shape[1]    # this block's first query row
+
+    def trip_copies(i, slot):
+        copies = []
+        for j in range(pp):
+            page = tables_ref[r, jnp.minimum(i * pp + j, n_pages - 1)]
+            copies.append(pltpu.make_async_copy(
+                c_hbm.at[layer, page, 0], cbuf.at[slot, pl.ds(j * ps, ps)],
+                sem.at[0, slot, j]))
+            copies.append(pltpu.make_async_copy(
+                r_hbm.at[layer, page, 0],
+                rbuf.at[slot, :, pl.ds(j * ps, ps)], sem.at[1, slot, j]))
+        return copies
+
+    @pl.when(n_pages == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_pages > 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        for cp in trip_copies(0, 0):
+            cp.start()
+        ql, qr = ql_ref[0], qr_ref[0]
+        nq = ql.shape[0]
+        qpos = (kv_len - chunk + (
+            q0 + jax.lax.broadcasted_iota(jnp.int32, (nq, pp * ps), 0))
+            // heads)
+
+        def trip(i, carry):
+            slot = jax.lax.rem(i, 2)
+
+            @pl.when(i + 1 < n_trips)
+            def _():
+                for cp in trip_copies(i + 1, 1 - slot):
+                    cp.start()
+
+            for cp in trip_copies(i, slot):
+                cp.wait()
+            c, kr = cbuf[slot], rbuf[slot]
+            # the rotated key's pages lie [rope, ps]: multiplied as they lie
+            s = (jax.lax.dot_general(
+                ql, c, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+                + jax.lax.dot_general(
+                    qr, kr, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)) * scale
+            kpos = (i * (pp * ps)
+                    + jax.lax.broadcasted_iota(jnp.int32, (nq, pp * ps), 1))
+            # a pad query (qpos >= kv_len) still needs the kv_len bound
+            s = jnp.where((kpos <= qpos) & (kpos < kv_len), s, NEG_INF)
+            _attend_trip(s, c, m_ref, l_ref, acc_ref, compute_dtype)
+            return carry
+
+        jax.lax.fori_loop(0, n_trips, trip, None)
+        denom = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "heads"))
+def _latent(ql, qr, c_pool, r_pool, tables, kv_len, chunk_len, layer, *,
+            scale, heads):
+    """ql [R, Q, rank], qr [R, Q, rope] (a row's queries, chunk x heads
+    flattened token-major); c_pool [L, P, 1, ps, rank], r_pool [L, P, 1,
+    rope, ps] (token-minor).  Returns o [R, Q, rank] in ql.dtype."""
+    r, nq, rank = ql.shape
+    rope = qr.shape[-1]
+    n_pages, ps = c_pool.shape[1], c_pool.shape[3]
+    pp = min(_LATENT_PAGES_PER_TRIP, tables.shape[1])
+    qb = min(_LATENT_Q_BLOCK, _round_up(nq, 8))
+    nq_pad = _round_up(nq, qb)
+    if nq_pad != nq:
+        ql = jnp.pad(ql, ((0, 0), (0, nq_pad - nq), (0, 0)))
+        qr = jnp.pad(qr, ((0, 0), (0, nq_pad - nq), (0, 0)))
+    tables = jnp.clip(tables, 0, n_pages - 1).astype(jnp.int32)
+
+    def q_map(ri, qi, *_):
+        return (ri, qi, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(r, nq_pad // qb),
+        in_specs=[
+            pl.BlockSpec((1, qb, rank), q_map),
+            pl.BlockSpec((1, qb, rope), q_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, qb, rank), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, pp * ps, rank), c_pool.dtype),
+            pltpu.VMEM((2, rope, pp * ps), r_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2, pp)),
+            pltpu.VMEM((qb, 1), jnp.float32),
+            pltpu.VMEM((qb, 1), jnp.float32),
+            pltpu.VMEM((qb, rank), jnp.float32),
+        ],
+    )
+    o = pl.pallas_call(
+        functools.partial(_latent_kernel, scale=scale, ps=ps, pp=pp,
+                          heads=heads, compute_dtype=jnp.bfloat16),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((r, nq_pad, rank), ql.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=32 * 2**20,
+        ),
+        interpret=_interpret(),
+        name="ragged_paged_attention_latent",
+    )(tables, kv_len.astype(jnp.int32), chunk_len.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), ql, qr, c_pool, r_pool)
+    return o[:, :nq]
+
+
+def latent_paged_sdpa(q_lat, q_rope, c_pool, r_pool, tables, kv_len,
+                      chunk_len=None, *, scale: float, layer=None):
+    """The absorbed walk.  q_lat [R, C, H, rank] (``W_UK`` folded in),
+    q_rope [R, C, H, rope], right-padded per-row chunks as in
+    :func:`ragged_paged_sdpa`; the latent pools whole with ``layer`` (or one
+    layer each without); kv_len [R] valid slots including this chunk, whose
+    tokens the pool already holds; chunk_len [R] valid queries (0 = idle
+    row: no trip, zeros; None = all C).  Returns o_lat [R, C, H, rank] in
+    q's dtype, still in the latent space."""
+    r, c, h, rank = q_lat.shape
+    if layer is None:
+        c_pool, r_pool, layer = c_pool[None], r_pool[None], 0
+    if chunk_len is None:
+        chunk_len = jnp.full((r,), c, jnp.int32)
+    o = _latent(
+        q_lat.reshape(r, c * h, rank), q_rope.reshape(r, c * h, -1), c_pool,
+        r_pool, tables, kv_len, chunk_len, jnp.asarray(layer, jnp.int32),
+        scale=float(scale), heads=h)
+    return o.reshape(r, c, h, rank)

@@ -107,7 +107,7 @@ def pipeline_forward(
                              v_head_dim=cfg.v_dim)
 
         def run_chunk(xa):
-            y, _, _, _ = run_layers(
+            y, *_ = run_layers(
                 cfg, layer_tree, cache.k, cache.v, flags, xa, cos, sin,
                 jnp.asarray(0, jnp.int32), q_slots, kv_len, None, cache,
                 alibi_bias=alibi_bias, cos_local=cos_l, sin_local=sin_l,

@@ -246,6 +246,25 @@ class OpenAIServer:
         return await loop.run_in_executor(None, next_stream_item,
                                           self.engine, req)
 
+    async def _first_tok(self, request, req: Request) -> int | None:
+        """The wait for a stream's FIRST token, which a long prompt makes
+        long: a client that goes away meanwhile is noticed HERE, and its row
+        freed, not at the first write after the whole prompt was prefilled
+        for nobody (64 abandoned 3,000-token prompts held every row of the
+        engine for a minute: my chip run, PR 37)."""
+        pending = asyncio.ensure_future(self._next_tok(req))
+        while True:
+            done, _ = await asyncio.wait({pending}, timeout=0.25)
+            if done:
+                return pending.result()
+            transport = request.transport
+            if transport is None or transport.is_closing():
+                # the engine ends the stream with None, which lets the
+                # fetch's worker thread go
+                self.engine.abort(req)
+                raise ConnectionResetError("the client went away while its "
+                                           "prompt was prefilling")
+
     async def _collect(self, req: Request) -> str:
         toks: list[int] = []
         drop = set(req.eos_token_id)
@@ -287,7 +306,8 @@ class OpenAIServer:
         toks: list[int] = []
         try:
             while True:
-                tok = await self._next_tok(req)
+                tok = await (self._next_tok(req) if toks
+                             else self._first_tok(request, req))
                 if tok is None:
                     break
                 if tok in drop:

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ipex_llm_tpu.hostutil import d2h, h2d
-from ipex_llm_tpu.kv import (STATE_COUNTERS, PagedKVCache, paged_page_bytes,
+from ipex_llm_tpu.kv import (STATE_COUNTERS, PagedKVCache, model_page_bytes,
                              recurrent_state, state_bytes,
                              with_recurrent_state)
 from ipex_llm_tpu.models.config import ModelConfig
@@ -828,7 +828,7 @@ def _tick_body(cfg: ModelConfig, params, cache, toks, row_lens,
             tok_block = jnp.zeros((r, horizon), jnp.int32)
             lp_block = jnp.zeros((r, horizon), jnp.float32)
             n_exec = jnp.asarray(0, jnp.int32)
-    if cfg.ssm_layers:
+    if cache.state is not None:
         # the state's counters ride the fetch the host already makes
         n_exec = jnp.concatenate([n_exec[None], cache.state["stats"]])
     if spec_k > 0:
@@ -982,10 +982,10 @@ class ServingEngine:
         # KV storage axis: bytes ONE page costs at this model shape and
         # storage width — the unit kv_pool_bytes divides by (validates
         # kv_storage, raising with the valid names)
-        self.page_bytes = paged_page_bytes(
-            cfg.kv_layers, cfg.num_kv_heads, self.ec.page_size,
-            cfg.head_dim, v_head_dim=cfg.v_dim,
-            storage=self.ec.kv_storage)
+        # (a latent-attention model's page holds the latent, one row a
+        # token shared by every head: kv.py ``pool_geometry``)
+        self.page_bytes = model_page_bytes(cfg, self.ec.page_size,
+                                           self.ec.kv_storage)
         if self.ec.kv_pool_bytes:
             # byte-budgeted pool: capacity in pages follows the storage
             # width (fp8 pages are half the bytes => twice the pages)
@@ -1080,11 +1080,15 @@ class ServingEngine:
         self.default_eos = default_eos
         self.mesh = mesh if (mesh is not None and mesh.size > 1) else None
         r = self.ec.max_rows
-        cache = PagedKVCache.init(
-            cfg.kv_layers, self.ec.n_pages, r, self.ec.max_pages,
-            cfg.num_kv_heads, self.ec.page_size, cfg.head_dim,
-            v_head_dim=cfg.v_dim, storage=self.ec.kv_storage,
-        )
+        if cfg.is_mla and mesh is not None and mesh.size > 1:
+            raise ValueError(
+                f"model_type {cfg.model_type!r} attends over a latent "
+                "(MLA) pool: a mesh is not supported, the latent row is "
+                "shared by every head and neither tick shards it "
+                "(parallel/manual.py)")
+        cache = PagedKVCache.for_model(
+            cfg, self.ec.n_pages, r, self.ec.max_pages, self.ec.page_size,
+            storage=self.ec.kv_storage)
         # a hybrid model's second kind of state, beside the pool in the
         # same cache object: per-ROW recurrent slots.  Two buffers: the
         # committed one in ``cache.state`` and a spare the next tick writes
@@ -1096,7 +1100,12 @@ class ServingEngine:
         self._stats_seen = np.zeros((len(STATE_COUNTERS),), np.uint32)
         if self._recurrent:
             self._refuse_for_recurrent_state(cfg, mesh)
-            cache = with_recurrent_state(cache, cfg, r)
+        # (a model whose expert layers hold a share of their experts and
+        # has no Mamba-2 block carries the device counters alone, the same
+        # way: two buffers of five numbers)
+        cache = with_recurrent_state(cache, cfg, r)
+        self._stateful = cache.state is not None
+        if self._stateful:
             self._state_spare = recurrent_state(cfg, r)
         # multi-chip serving: on a PURE-tp mesh whose shapes divide, the
         # engine takes the MANUAL tick — the whole fused tick inside one
@@ -1132,6 +1141,19 @@ class ServingEngine:
         self.params = params
         self.cache = cache
         # the per-tick prefill token budget of the admission wave
+        # whether a mixed tick's table view is cut to a power-of-two bucket
+        # of the pages in use: only where attention GATHERS the table's
+        # width (the XLA path: the CPU, a mesh).  Where the page walk runs
+        # the width costs nothing (the walk is bounded by the live pages),
+        # and every bucket a prompt can reach is one more variant of every
+        # mixed tick to compile: at 4096-token prompts six buckets made 76
+        # programs and 1,650 s of warm-up (my chip run, PR 37)
+        from ipex_llm_tpu.ops import attention as attention_ops
+        from ipex_llm_tpu.ops import dispatch
+
+        self._slice_tables = self.ec.max_pages > 8 and not (
+            self.mesh is None
+            and dispatch.use_pallas(attention_ops._paged_op(self.cache.k)))
         self._step_budget = (self.ec.prefill_bucket
                              if self.ec.step_token_budget is None
                              else int(self.ec.step_token_budget))
@@ -1390,10 +1412,11 @@ class ServingEngine:
         with recurrent state: the committed state goes in as the read-only
         source and the spare buffer, in the donated cache, is written.
         ``_state_done`` rebinds after the call."""
-        if not self._recurrent:
+        if not self._stateful:
             return self.cache, None
         src = self.cache.state
-        if self._state_spare is None or self._state_spare["h"].is_deleted():
+        if (self._state_spare is None
+                or self._state_spare["stats"].is_deleted()):
             # a dispatch that raised took the spare with it
             self._state_spare = recurrent_state(self.cfg, self.ec.max_rows)
         return replace(self.cache, state=self._state_spare), src
@@ -1517,6 +1540,11 @@ class ServingEngine:
             "pages_in_use": a.pages_in_use,
             "page_bytes": self.page_bytes,
             "pool_bytes": a.n_pages * self.page_bytes,
+            # a latent-attention model's page holds ONE row a token a
+            # layer, shared by every head (0: the pages hold K and V)
+            "latent_bytes_per_token": (
+                self.page_bytes // (self.ec.page_size * self.cfg.kv_layers)
+                if self.cache.latent else 0),
             "prefix_pages_cached": len(a.prefix),
             "prefix_evictions": a.prefix_evictions,
             "alloc_fail_clamps": self.metrics.get("alloc_fail_clamps", 0),
@@ -2000,7 +2028,7 @@ class ServingEngine:
             # tick's KV writes past the committed row_lens
             self.pagestore.restore(snap["pagestore"])
         self.key = snap["key"]
-        if self._recurrent:
+        if self._stateful:
             if self._state_prev is not None:
                 # the doomed tick wrote the other buffer: it is the spare
                 self._state_spare = self.cache.state
@@ -2495,12 +2523,22 @@ class ServingEngine:
         handoff).  Returns None when no full page is cached.  Runs on
         the engine thread between ticks (epoch-boundary gathers, not
         tick work — JP106 unchanged)."""
+        self._refuse_handoff()
+        ids = np.asarray(list(prompt_ids), np.int32)
+        return self.run_on_engine(lambda: self._export_prefix_op(ids, wire))
+
+    def _refuse_handoff(self):
+        """What the page-set wire format (serving/kv_transport.py) cannot
+        carry, refused by name."""
         if self._recurrent:
             raise NotImplementedError(
                 "KV handoff for a model with recurrent state: a page of KV "
                 "is useless without the state that follows it")
-        ids = np.asarray(list(prompt_ids), np.int32)
-        return self.run_on_engine(lambda: self._export_prefix_op(ids, wire))
+        if self.cache.latent:
+            raise NotImplementedError(
+                "KV handoff of a latent (MLA) pool: the wire format is "
+                "shaped [layers, kv heads, page, head] for K and for V, and "
+                "a latent page is neither")
 
     def _export_prefix_op(self, ids: np.ndarray, wire: str):
         epoch = (self.perf.epoch_window("handoff")
@@ -2566,10 +2604,7 @@ class ServingEngine:
         fused tick with only the uncovered tail left to prefill.  A dry
         pool stops the import early (what fit is registered).  Runs on
         the engine thread between ticks."""
-        if self._recurrent:
-            raise NotImplementedError(
-                "KV handoff for a model with recurrent state: a page of KV "
-                "is useless without the state that follows it")
+        self._refuse_handoff()
         return self.run_on_engine(lambda: self._import_pages_op(blob))
 
     def _import_pages_op(self, blob: bytes) -> dict:
@@ -3296,8 +3331,9 @@ class ServingEngine:
         # positions are exactly-masked (zero-probability) slots, so
         # chunk values stay bitwise identical.  Narrow tables skip the
         # slicing — the gather saving there is smaller than the cost
-        # of extra program traces per width bucket
-        if self.ec.max_pages > 8:
+        # of extra program traces per width bucket — and so does an
+        # engine whose attention WALKS the pages (``_slice_tables``)
+        if self._slice_tables:
             ps = self.ec.page_size
             maxp_used = max(-(-(int(base[i]) + int(n_valid[i])) // ps)
                             for i, _, _ in chunks)
