@@ -286,18 +286,25 @@ def _build_ragged_tick(pt):
         mesh = None
         cache = _paged_cache(pt["rows"], pt["kv"])
     r = pt["rows"]
-    base = (cfg, params, cache, _i32(r), _i32(r),
-            _bool(r), _f32(r), _f32(r), _key(), _i32(r), _i32(r), _i32(r),
-            _i32(r, 2), _i32(r))
     w = pt["width"]
-    if w:   # admission-wave form: pow2-padded prefill block rides along
-        p = 2
-        prefill = (_i32(p, w), _i32(p, 2), _i32(p), _i32(p), _bool(p),
-                   _bool(p), _i32(p))
-    else:   # steady-state form: pure decode horizon, no prefill block
-        prefill = None
-    kw = {"prefill": prefill, "horizon": pt["horizon"],
+    kw = {"prefill": None, "horizon": pt["horizon"],
           "with_decode": pt.get("wd", True), "mesh": mesh}
+    if w:
+        # admission-wave form: an epoch.  The pow2-padded prefill block,
+        # the row state and the block table ride ONE packed int32 buffer
+        # (serving/epoch.py) that the program takes apart itself, so no
+        # row state is carried in
+        from ipex_llm_tpu.serving.epoch import EpochLayout
+
+        layout = EpochLayout(rows=r, max_pages=_MAXP, e_w=2, p_b=2,
+                             width=w, p_maxp=2)
+        none5 = (None,) * 5
+        base = (cfg, params, cache, *none5, _key(), *none5)
+        kw.update(prefill=_i32(layout.size), epoch=layout)
+    else:   # steady-state form: pure decode horizon over carried state
+        base = (cfg, params, cache, _i32(r), _i32(r),
+                _bool(r), _f32(r), _f32(r), _key(), _i32(r), _i32(r),
+                _i32(r), _i32(r, 2), _i32(r))
     if tp > 1:
         kw.update(tp_manual=True,
                   collective_qtype=pt.get("cq", "bf16"))
@@ -455,9 +462,9 @@ def real_registry() -> tuple[ProgramSpec, ...]:
             dead=frozenset({"cache", "toks", "row_lens", "active",
                             "steps", "remain", "hist"}),
             # key is HELD (checkpoint-by-reference, the PR 6 rule);
-            # sampling params/eos are epoch-held; the prefill block's
-            # arrays and spec_ks are fresh per-tick uploads, unlisted on
-            # purpose
+            # sampling params/eos are epoch-held; the packed epoch
+            # (``prefill``) and spec_ks are fresh per-tick uploads,
+            # unlisted on purpose
             held=frozenset({"params", "temps", "top_ps", "seeds",
                             "top_ks", "eos", "key"}),
             max_lowerings=38,

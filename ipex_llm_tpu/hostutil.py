@@ -16,8 +16,9 @@ on where numpy's allocator placed it, so the corruption is alignment-
 and history-dependent: the worst kind of intermittent.  ``jnp.array``
 (copy semantics) pins a snapshot the device owns.
 
-Use :func:`h2d` at every host->device boundary whose source is (or may
-be) a mutable numpy buffer.  Literal constants and values that are
+Use :func:`h2d` (or :func:`upload`, the transfer alone, for a numpy array
+that already has its device dtype) at every host->device boundary whose
+source is (or may be) a mutable numpy buffer.  Literal constants and values that are
 already jax arrays may keep ``jnp.asarray``; ``ipex_llm_tpu.analysis``
 rule JL001 machine-checks exactly that contract over the async-dispatch
 modules.
@@ -28,6 +29,7 @@ from __future__ import annotations
 from collections import OrderedDict
 from typing import Any, Callable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -41,6 +43,24 @@ def h2d(x: Any, dtype: Any = None) -> jnp.ndarray:
     the call even while async dispatch is still reading the upload.
     """
     return jnp.array(x, dtype=dtype)
+
+
+def upload(x: np.ndarray, sharding: Any = None) -> jax.Array:
+    """Upload one host array as ONE runtime call: a transfer and nothing
+    else.
+
+    ``h2d`` is ``jnp.array``, whose transfer is the argument of an eagerly
+    bound ``convert_element_type``: a second runtime call, a one-op
+    program, for every upload.  On a path that runs between two ticks of a
+    serving engine that program costs as much as the transfer.  This is
+    the transfer alone, with ``h2d``'s copy semantics: the device is handed
+    a PRIVATE copy of ``x`` that nobody writes again, so the caller may
+    refill ``x`` at once (on the CPU backend the device array may alias
+    that private copy, which is safe for the same reason).  ``x`` is a
+    numpy array that already has the dtype wanted on the device;
+    ``sharding`` places it (a replicated ``NamedSharding`` under a mesh).
+    """
+    return jax.device_put(np.array(x, copy=True), sharding)
 
 
 def d2h(x: Any) -> np.ndarray:

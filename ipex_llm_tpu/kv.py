@@ -347,18 +347,6 @@ class PagedKVCache:
         steady-state decode re-uploads nothing."""
         return replace(self, tables=tables)
 
-    def with_table_rows(self, rows: jnp.ndarray,
-                        table_rows: jnp.ndarray) -> "PagedKVCache":
-        """This pool with only ``rows`` of the block table replaced.
-
-        ``rows`` [K] int32 row indices; ``table_rows`` [K, maxP] their new
-        page lists.  A device-side scatter into the resident tables array,
-        so a prefill chunk that grew ONE row's table uploads K*maxP ints
-        instead of re-uploading the whole [R, maxP] table — the serving
-        engine's dirty-row path (every mixed/prefill tick allocates pages
-        for at most the rows it advanced)."""
-        return replace(self, tables=self.tables.at[rows].set(table_rows))
-
     def route_pages(self, page_idx: jnp.ndarray) -> jnp.ndarray:
         """Page indexes [B, N] of every row -> pool pages through the block
         table.  Page 0 is the engine's scratch page (never allocated to a

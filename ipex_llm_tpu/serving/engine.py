@@ -41,12 +41,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ipex_llm_tpu.hostutil import d2h, h2d
+from ipex_llm_tpu.hostutil import d2h, h2d, upload
 from ipex_llm_tpu.kv import (STATE_COUNTERS, PagedKVCache, model_page_bytes,
                              recurrent_state, state_bytes,
                              with_recurrent_state)
 from ipex_llm_tpu.models.config import ModelConfig
 from ipex_llm_tpu.models.decoder import decoder_forward
+from ipex_llm_tpu.serving.epoch import HELD_FIELDS, EpochLayout, unpack_rows
 from ipex_llm_tpu.serving.faults import (EngineOverloaded, FaultInjector,
                                          is_transient)
 from ipex_llm_tpu.serving.observe import (FAST_LATENCY_BUCKETS_S,
@@ -731,8 +732,10 @@ def _sample_verify_positions(logits, active, temps, top_ps, key, seeds,
 # ``hist`` (the speculative token history, spec_k > 0 only) is
 # device-resident dead-after-call state like toks — the host rebinds
 # _dev["hist"] to the returned buffer — so it donates by name.  The
-# prefill block's arrays and ``spec_ks`` are fresh per-tick uploads, too
-# small to matter.  JP101 locks both directions.
+# packed epoch (``prefill``) and ``spec_ks`` are fresh per-tick uploads,
+# too small to matter; a tick that takes the packed epoch takes no row
+# state in (those arguments are None), so it has only the cache to
+# donate.  JP101 locks both directions.
 def _tick_body(cfg: ModelConfig, params, cache, toks, row_lens,
                active, temps, top_ps, key, seeds, steps, top_ks,
                eos, remain, prefill=None, horizon: int = 1,
@@ -842,7 +845,7 @@ def _tick_body(cfg: ModelConfig, params, cache, toks, row_lens,
 @partial(jax.jit,
          static_argnames=("cfg", "horizon", "with_decode", "spec_k",
                           "spec_ngram", "mesh", "tp_manual",
-                          "collective_qtype"),
+                          "collective_qtype", "epoch"),
          donate_argnums=(2, 3, 4, 5, 10, 13), donate_argnames=("hist",),
          compiler_options=_EXACT_ROUNDING)
 def _ragged_tick(cfg: ModelConfig, params, cache, toks, row_lens,
@@ -851,22 +854,33 @@ def _ragged_tick(cfg: ModelConfig, params, cache, toks, row_lens,
                     with_decode: bool = True, hist=None, spec_ks=None,
                     spec_k: int = 0, spec_ngram: int = 3, mesh=None,
                     tp_manual: bool = False,
-                    collective_qtype: str = "bf16", state_src=None):
+                    collective_qtype: str = "bf16", state_src=None,
+                    epoch: EpochLayout | None = None):
     """ONE device program per engine tick, whatever the admission mix —
     the ragged-paged-attention superkernel tick (ROADMAP item 1; the
     JP106 gate counts exactly this entry).
 
     Internally three fused stages, each optional per the tick's shape:
 
-    1. **ragged prefill** (``prefill`` is not None): the batched ragged
-       chunk forward over the prefilling rows — ``prefill`` is
-       ``(p_tokens [P, W], p_tables [P, maxp_b], p_base [P], p_nvalid
-       [P], p_emit [P], p_canjoin [P], p_rowmap [P])``, a row-sliced
-       table view plus the map from prefill slot to engine row (pad
-       slots carry ``p_rowmap == R`` so their scatters drop).  Attention
-       rides the per-row ``chunk_lens`` causal contract of
-       ops/pallas/ragged_paged_attention.py, and the per-row last-valid
-       hidden gather (``gather_positions``) is fused in.
+    0. **the epoch** (``prefill`` is not None): a tick with a prefill
+       block is an epoch, and ``prefill`` is the host's PACKED EPOCH
+       (serving/epoch.py): one int32 buffer, one upload, laid out by the
+       static ``epoch``.  Under scope ``tick/epoch`` the program takes it
+       apart, REPLACES the block table and the row state with what the
+       host sent (the row-state arguments are None then: nothing is
+       carried into an epoch), and gathers the prefill rows' table view
+       itself, cut to ``epoch.p_maxp`` pages.  The arrays no tick
+       advances (``temps``, ``top_ps``, ``seeds``, ``top_ks``, ``eos``)
+       come back out as one more, last, element of the result, for the
+       host to re-pass until the next epoch.
+    1. **ragged prefill**: the batched ragged chunk forward over the
+       prefilling rows — ``(p_tokens [P, W], p_tables [P, p_maxp],
+       p_base [P], p_nvalid [P], p_emit [P], p_canjoin [P], p_rowmap
+       [P])``, a row-sliced table view plus the map from prefill slot to
+       engine row (pad slots carry ``p_rowmap == R`` so their scatters
+       drop).  Attention rides the per-row ``chunk_lens`` causal contract
+       of ops/pallas/ragged_paged_attention.py, and the per-row
+       last-valid hidden gather (``gather_positions``) is fused in.
     2. **first-token sampling + state merge**: rows whose prompt
        completes this tick (``p_emit``) sample their first token here —
        fold_in(seed, 0), output index 0 of the row's stream — and join
@@ -874,8 +888,8 @@ def _ragged_tick(cfg: ModelConfig, params, cache, toks, row_lens,
        publish them (toks=first, steps=1, remain-=1, active unless the
        first token hit EOS / exhausted the budget / ``p_canjoin`` says
        the host could not back the decode KV slot).  Every prefill row's
-       device length advances to its true value, so pure-chunk ticks
-       still need no epoch upload.
+       device length advances to its true value, so the steady tick that
+       follows a pure-chunk tick needs no epoch of its own.
     3. **the fused decode horizon** (``with_decode``):
        ``_decode_horizon_loop`` over the merged state; a steady-state
        tick (prefill=None) is this stage alone.
@@ -903,6 +917,23 @@ def _ragged_tick(cfg: ModelConfig, params, cache, toks, row_lens,
     """
     from ipex_llm_tpu.ops import dispatch
 
+    held = ()
+    if prefill is not None:
+        with jax.named_scope("tick/epoch"):
+            e = epoch.unpack(prefill)
+            cache = replace(cache, tables=e["tables"])
+            (toks, row_lens, active, temps, top_ps, seeds, steps, top_ks,
+             eos, remain) = (e[k] for k in (
+                 "toks", "row_lens", "active", "temps", "top_ps", "seeds",
+                 "steps", "top_ks", "eos", "remain"))
+            # a pad slot (``p_rowmap == R``) reads row 0's table: its base
+            # lies past the table and every write it makes goes to the
+            # scratch page
+            p_row = jnp.where(e["p_rowmap"] < epoch.rows, e["p_rowmap"], 0)
+            prefill = (e["p_toks"], e["tables"][p_row][:, :epoch.p_maxp],
+                       e["p_base"], e["p_nvalid"], e["p_emit"],
+                       e["p_canjoin"], e["p_rowmap"])
+            held = (tuple(e[k] for k in HELD_FIELDS),)
     if tp_manual:
         from ipex_llm_tpu.parallel.manual import tp_tick
 
@@ -912,18 +943,14 @@ def _ragged_tick(cfg: ModelConfig, params, cache, toks, row_lens,
              top_ks, eos, remain),
             prefill=prefill, horizon=horizon, with_decode=with_decode,
             hist=hist, spec_ks=spec_ks, spec_k=spec_k,
-            spec_ngram=spec_ngram)
+            spec_ngram=spec_ngram) + held
     with dispatch.spmd(mesh):
         return _tick_body(
             cfg, params, cache, toks, row_lens, active, temps, top_ps,
             key, seeds, steps, top_ks, eos, remain, prefill=prefill,
             horizon=horizon, with_decode=with_decode, hist=hist,
             spec_ks=spec_ks, spec_k=spec_k, spec_ngram=spec_ngram,
-            state_src=state_src)
-
-
-# The entry's name until PR 25; perfbench/rehearse_memory.py still imports it.
-_ragged_tick_fn = _ragged_tick
+            state_src=state_src) + held
 
 
 class ServingEngine:
@@ -1169,13 +1196,12 @@ class ServingEngine:
             self.ec.n_pages,
             spill=self._spill_pages if self.pagestore is not None else None)
         self.tables = np.full((r, self.ec.max_pages), -1, np.int32)
-        # block-table dirty-row tracking: every host-side mutation of
-        # ``self.tables`` records its row here, and device syncs scatter
-        # ONLY those rows into the resident tables (kv.with_table_rows)
-        # instead of re-uploading the whole [R, maxP] table per chunk.
+        # every host-side mutation of ``self.tables`` sets this, and the
+        # next packed epoch carries the WHOLE table over (a few KB: cheaper
+        # than any dirty-row bookkeeping on the device).
         # PagedKVCache.init and self.tables both start all -1, so host and
         # device are in sync from construction.
-        self._dirty_tables: set[int] = set()
+        self._tables_dirty = False
         self.rows: list[Request | None] = [None] * r
         self.row_lens = np.zeros((r,), np.int32)
         self.row_budget = np.zeros((r,), np.int32)
@@ -1187,7 +1213,7 @@ class ServingEngine:
         # chunked prefill: rows still consuming their prompt
         self._prefilling: dict[int, np.ndarray] = {}  # row -> remaining ids
         self._row_keys: dict[int, list[bytes]] = {}   # row -> prefix hashes
-        # under a mesh, carried row state and the PRNG key are uploaded
+        # under a mesh, the packed epoch and the PRNG key are uploaded
         # replicated over it: that is the sharding the tick hands them
         # back in, so an upload and a carried array present the same jit
         # signature and each grid point compiles once, not twice
@@ -1196,7 +1222,7 @@ class ServingEngine:
             from jax.sharding import NamedSharding, PartitionSpec
 
             self._replicated = NamedSharding(self.mesh, PartitionSpec())
-        self.key = self._carried(jax.random.PRNGKey(0))
+        self.key = jax.device_put(jax.random.PRNGKey(0), self._replicated)
         self._inbox: "queue.Queue[Request]" = queue.Queue()
         # engine-thread host operations (KV page-set export/import):
         # closures enqueued here run BETWEEN transactional ticks on the
@@ -1338,10 +1364,17 @@ class ServingEngine:
                         # seconds spent blocked, uploads of row state
                         "host_syncs": 0, "host_sync_s": 0.0,
                         "tokens_per_sync": 0.0, "epoch_syncs": 0,
+                        # every host->device transfer the engine thread
+                        # makes inside a tick, before its dispatch: one for
+                        # a packed epoch, none for a steady tick with no
+                        # news (beside: a speculating tick's draft widths
+                        # and token history, a swap-in's pages)
+                        "h2d_total": 0,
                         "decode_horizon_effective": 0,
                         # admission-wave economics (the mixed-step story):
                         # mixed ticks run, prompt tokens prefilled per
-                        # tick, dirty-row table syncs, rolling TTFT p95
+                        # tick, epochs that carried a changed block table,
+                        # rolling TTFT p95
                         "mixed_steps": 0, "mixed_prefill_tokens": 0,
                         "prefill_tokens_per_step": 0.0,
                         "table_row_syncs": 0, "ttft_p95_s": 0.0,
@@ -1952,8 +1985,8 @@ class ServingEngine:
         deliberately NOT snapshotted: the recovery contract is that KV
         writes beyond the committed ``row_lens`` are scratch (a retried
         tick rewrites the same slots with the same values), and rollback
-        forces a full epoch re-upload + whole-table rescatter so the
-        device copies converge back to the restored host state."""
+        forces a full epoch (row state and block table re-published) so
+        the device copies converge back to the restored host state."""
         reqs = [r for r in self.rows if r is not None] + list(self._pending)
         return {
             "rows": list(self.rows),
@@ -2074,11 +2107,11 @@ class ServingEngine:
             r.first_token_s = 0.0
             self._pending.append(r)
         self._tick_arrivals = []
-        # device copies are now ahead of the restored host state: force a
-        # full row-state epoch AND a whole-table rescatter next dispatch
+        # device copies are now ahead of the restored host state: the
+        # next dispatch publishes the row state and the table anew
         self._dev = None
         self._dirty = True
-        self._dirty_tables = set(range(self.ec.max_rows))
+        self._tables_dirty = True
 
     def _commit(self):
         """Flush the tick's staged emissions to the client queues, in
@@ -2342,35 +2375,32 @@ class ServingEngine:
         """
         self._fault_point("page-alloc", rows=(row,), reqs=(req,))
         need = min(-(-upto_slot // self.ec.page_size), self.ec.max_pages)
-        missing = sum(1 for j in range(need) if self.tables[row, j] < 0)
-        if missing > 1:
+        # every decoding row comes here every tick and almost always has
+        # nothing missing: one vector compare, no walk over the row's pages
+        missing = np.flatnonzero(self.tables[row, :need] < 0)
+        if missing.size > 1:
             # batch the burst's evictions: one spill gather instead of
             # one per page (drops the same pages lazy eviction would)
-            self.alloc.reserve(missing)
-        for j in range(need):
-            if self.tables[row, j] < 0:
-                pid = self.alloc.alloc()
-                if pid is None:
-                    # every caller clamps on a dry pool (shorter horizon,
-                    # requeued admission, spec fallback, 'length' finish);
-                    # count the event so pool pressure is visible in
-                    # /health's kv block instead of only via its symptoms
-                    self.metrics["alloc_fail_clamps"] = (
-                        self.metrics.get("alloc_fail_clamps", 0) + 1)
-                    return False
-                self.tables[row, j] = pid
-                # page allocation only touches THIS row's table: a dirty-
-                # row scatter sync, not a full row-state epoch
-                self._dirty_tables.add(row)
+            self.alloc.reserve(int(missing.size))
+        for j in missing:
+            pid = self.alloc.alloc()
+            if pid is None:
+                # every caller clamps on a dry pool (shorter horizon,
+                # requeued admission, spec fallback, 'length' finish);
+                # count the event so pool pressure is visible in
+                # /health's kv block instead of only via its symptoms
+                self.metrics["alloc_fail_clamps"] = (
+                    self.metrics.get("alloc_fail_clamps", 0) + 1)
+                return False
+            self.tables[row, j] = pid
+            self._tables_dirty = True
         return True
 
     def _release_row_pages(self, row: int):
-        for j in range(self.ec.max_pages):
-            pid = int(self.tables[row, j])
-            if pid >= 0:
-                self.alloc.decref(pid)
-                self.tables[row, j] = -1
-                self._dirty_tables.add(row)
+        for j in np.flatnonzero(self.tables[row] >= 0):
+            self.alloc.decref(int(self.tables[row, j]))
+            self.tables[row, j] = -1
+            self._tables_dirty = True
 
     # -- host-RAM spill tier (serving/pagestore.py) -------------------------
 
@@ -2434,6 +2464,7 @@ class ServingEngine:
                 self.cache = self.cache.scatter_pages(
                     np.asarray(pids, np.int32), h2d(k_stack),
                     h2d(v_stack))
+            self.metrics["h2d_total"] += 3      # the page ids, K, V
             t_bar = time.perf_counter()
             # completion barrier: swap-in latency must cover the scatter
             # REACHING the pool, not just its enqueue — on an async
@@ -2668,15 +2699,9 @@ class ServingEngine:
             for i, r in enumerate(self.rows)
         ])
 
-    def _upload_row_state(self):
-        """Upload the per-row hot state after an epoch (admission / prefill
-        progress / finish / page allocation).  Steady-state decode steps
-        skip this entirely and reuse the device arrays the previous fused
-        step returned — request-static sampling params (temps/top_ps/
-        top_ks/seeds) cross the PCIe link once per epoch, not once
-        per token (the tier-1 re-upload regression test counts calls)."""
+    def _row_state(self) -> dict:
+        """The host's view of the rows, as the packed epoch's fields."""
         rows = self.rows
-        active = self._active_mask()
         steps = np.asarray([len(r.output_ids) if r is not None else 0
                             for r in rows], np.int32)
         remain = np.asarray([
@@ -2692,63 +2717,73 @@ class ServingEngine:
             if r is not None and r.eos_token_id:
                 ids = list(r.eos_token_id)
                 eos[i, :len(ids)] = ids
-        self._dev = {k: self._carried(h2d(v)) for k, v in (
-            ("toks", self.toks), ("row_lens", self.row_lens),
-            ("active", active), ("temps", self.temps),
-            ("top_ps", self.top_ps), ("seeds", self.seeds),
-            ("top_ks", self.top_ks), ("steps", steps), ("remain", remain),
-            ("eos", eos))}
-        if self.ec.spec_k > 0:
-            # device-resident token history for the on-device prompt-
-            # lookup proposer: the FULL prompt lands at the admission
-            # epoch (it is known in whole then, so mid-prefill rows need
-            # no per-chunk scatter), emitted tokens are appended inside
-            # the device loop, and epochs rebuild it from the host's own
-            # bookkeeping — the same discipline as toks/row_lens
-            hist = np.zeros((len(rows), self.ec.max_seq_len), np.int32)
-            for i, r in enumerate(rows):
-                if r is None:
-                    continue
-                ids = list(r.prompt_ids) + list(r.output_ids)
-                hist[i, :len(ids)] = ids
-            self._dev["hist"] = self._carried(h2d(hist))
-        # tables ride the dirty-row scatter even on full epochs: every
-        # mixed tick is an epoch (row_lens advance), and re-uploading the
-        # whole [R, maxP] table per chunk is the cost this PR removes
-        self._flush_dirty_tables()
-        self._dirty = False
+        return dict(toks=self.toks, row_lens=self.row_lens,
+                    active=self._active_mask(), temps=self.temps,
+                    top_ps=self.top_ps, seeds=self.seeds,
+                    top_ks=self.top_ks, steps=steps, remain=remain,
+                    eos=eos, tables=self.tables)
 
-    def _carried(self, x):
-        """Place device state the tick carries from call to call the way
-        the tick returns it (see ``_replicated``)."""
-        if self._replicated is None:
-            return x
-        # jaxlint: disable=JL001 -- x is already a device array (an h2d copy or a PRNG key), never a host buffer
-        return jax.device_put(x, self._replicated)
-
-    def _flush_dirty_tables(self) -> PagedKVCache:
-        """Scatter only the dirty block-table rows into the device-resident
-        tables (kv.with_table_rows) and return the current cache: a
-        whole-table upload reduced to the rows that actually changed."""
-        if self._dirty_tables:
-            rows = np.array(sorted(self._dirty_tables), np.int32)
-            self.cache = self.cache.with_table_rows(
-                h2d(rows), h2d(self.tables[rows]))
+    def _upload_epoch(self, p_b: int = 0, width: int = 0, p_maxp: int = 0,
+                      **block):
+        """Publish the host's view of the rows for one epoch (admission /
+        prefill progress / finish / page allocation): the row state, the
+        whole block table and, a mixed tick, its prefill ``block`` cross
+        as ONE packed buffer in ONE transfer (serving/epoch.py).  Returns
+        (the device buffer, its layout, the speculative token history or
+        None).  A steady tick with no news never comes here:
+        request-static sampling params cross the PCIe link once per
+        epoch, not once per token (the tier-1 re-upload regression test
+        counts calls)."""
+        hist = self._epoch_hist()     # reads ``_dirty``, cleared below
+        fields = self._row_state()
+        layout = EpochLayout(
+            rows=len(self.rows), max_pages=self.ec.max_pages,
+            e_w=fields["eos"].shape[1], p_b=p_b, width=width, p_maxp=p_maxp)
+        # replicated under a mesh: the sharding the tick hands state back in
+        buf = upload(layout.pack(**fields, **block), self._replicated)
+        self.metrics["epoch_syncs"] += 1
+        self.metrics["h2d_total"] += 1
+        if self._tables_dirty:
             self.metrics["table_row_syncs"] += 1
-            self._dirty_tables.clear()
-        return self.cache
+        self._dirty = self._tables_dirty = False
+        return buf, layout, hist
+
+    def _epoch_hist(self):
+        """The device-resident token history of the on-device prompt-
+        lookup proposer (``spec_k > 0``; None otherwise), for the epoch
+        ``_upload_epoch`` is about to publish.  The FULL prompt lands at the admission epoch (it is
+        known in whole then, so mid-prefill rows need no per-chunk
+        scatter), emitted tokens are appended inside the device loop, and
+        a row-state epoch rebuilds it from the host's own bookkeeping —
+        the same discipline as toks/row_lens, in an upload of its own
+        ([R, max_seq_len]: too large to ride every epoch's buffer)."""
+        if self.ec.spec_k <= 0:
+            return None
+        if not self._dirty and self._dev is not None:
+            return self._dev["hist"]
+        hist = np.zeros((len(self.rows), self.ec.max_seq_len), np.int32)
+        for i, r in enumerate(self.rows):
+            if r is None:
+                continue
+            ids = list(r.prompt_ids) + list(r.output_ids)
+            hist[i, :len(ids)] = ids
+        self.metrics["h2d_total"] += 1
+        return upload(hist, self._replicated)
 
     def _sync_device_state(self) -> dict:
-        """The device-resident row state, re-uploading only when dirty.
-
-        A full epoch (admission / prefill progress / finish) re-uploads the
-        row vectors AND the whole table; a page-allocation-only epoch (mid-
-        decode page boundary) scatters just the dirty table rows."""
-        if self._dirty or self._dev is None:
-            self.metrics["epoch_syncs"] += 1
-            self._upload_row_state()
-        else:
-            self._flush_dirty_tables()
+        """The device-resident row state of a tick with no prefill block
+        (and of any caller outside a tick), publishing only when there is
+        news: a row-state epoch (a completion, a finish, a rollback) or a
+        page allocation crosses as one packed buffer and is taken apart
+        by one small program (``epoch.unpack_rows``), which also replaces
+        the device's block table; with no news nothing is uploaded."""
+        if self._dirty or self._tables_dirty or self._dev is None:
+            buf, layout, hist = self._upload_epoch()
+            dev = unpack_rows(buf, layout)
+            self.cache = replace(self.cache, tables=dev.pop("tables"))
+            if hist is not None:
+                dev["hist"] = hist
+            self._dev = dev
         return self._dev
 
     # -- engine loop --------------------------------------------------------
@@ -2851,7 +2886,7 @@ class ServingEngine:
                 else:
                     pid = e[1]          # row ref taken in the plan walk
                 self.tables[row, shared] = pid
-                self._dirty_tables.add(row)
+                self._tables_dirty = True
                 shared += 1
             for e in plan[shared:]:
                 if e[0] == "dev":       # past the break: drop the row ref
@@ -3320,19 +3355,15 @@ class ServingEngine:
         if with_decode and decode_rows:
             self._fault_point("decode-dispatch", rows=decode_rows)
         t0_w = time.time()
-        cache = self._flush_dirty_tables()
-        full_tables = cache.tables
-        row_idx = np.zeros((p_b,), np.int32)
-        row_idx[:len(rows)] = rows
-        # slice the table view to the pages the batch actually uses
-        # (power-of-two bucketed): the jnp fallback gathers each row's
-        # whole table width per layer, so early chunks of a long
-        # prompt would otherwise pay the full-capacity gather; dropped
-        # positions are exactly-masked (zero-probability) slots, so
-        # chunk values stay bitwise identical.  Narrow tables skip the
-        # slicing — the gather saving there is smaller than the cost
-        # of extra program traces per width bucket — and so does an
-        # engine whose attention WALKS the pages (``_slice_tables``)
+        # the prefill stage's table view is cut to the pages the batch
+        # actually uses (power-of-two bucketed): the jnp fallback gathers
+        # each row's whole table width per layer, so early chunks of a
+        # long prompt would otherwise pay the full-capacity gather;
+        # dropped positions are exactly-masked (zero-probability) slots,
+        # so chunk values stay bitwise identical.  Narrow tables skip the
+        # cut — the gather saving there is smaller than the cost of extra
+        # program traces per width bucket — and so does an engine whose
+        # attention WALKS the pages (``_slice_tables``)
         if self._slice_tables:
             ps = self.ec.page_size
             maxp_used = max(-(-(int(base[i]) + int(n_valid[i])) // ps)
@@ -3341,10 +3372,14 @@ class ServingEngine:
                          self.ec.max_pages)
         else:
             maxp_b = self.ec.max_pages
-        p_tables = full_tables[h2d(row_idx)][:, :maxp_b]
-        dev = self._sync_device_state()
-        prefill = (h2d(toks), p_tables, h2d(base), h2d(n_valid),
-                   h2d(emit), h2d(canjoin), h2d(rowmap))
+        # a mixed tick is an epoch: its prefill block, the row state and
+        # the block table cross in ONE upload, and the program takes them
+        # apart, gathers the prefill rows' tables and merges the row state
+        # itself (``_ragged_tick`` stage 0), so nothing is carried in
+        buf, layout, hist = self._upload_epoch(
+            p_b, width, maxp_b, p_toks=toks, p_base=base,
+            p_nvalid=n_valid, p_emit=emit, p_canjoin=canjoin,
+            p_rowmap=rowmap)
         # a pure-chunk tick (with_decode=False) has no decode stage for
         # spec to ride, so it dispatches the spec-free program variant —
         # the device history needs no maintenance there (prompts land
@@ -3353,46 +3388,40 @@ class ServingEngine:
         take_block = s_prop = s_acc = None
         perf_pt = self._perf_point(
             1, width=width, with_decode=with_decode, spec=tick_spec,
-            pb=p_b, maxp=maxp_b, ew=int(dev["eos"].shape[1]))
+            pb=p_b, maxp=maxp_b, ew=layout.e_w)
         rows_active = int(active.sum())
         attn_pages = self._count_attn_pages(
             active, with_decode, chunks, base, n_valid,
             prefill_table=p_b * maxp_b)
+        kw = {}
         if tick_spec:
-            with self._perf_dispatch("tick.spec", point=perf_pt,
-                                     rows_active=rows_active, **attn_pages):
-                (first_t, first_lp, tok_block, lp_block, n_exec,
-                 self.cache, dev["toks"], dev["row_lens"], dev["active"],
-                 dev["steps"], dev["remain"], self.key, take_block,
-                 dev["hist"], s_prop, s_acc) = _ragged_tick(
-                    self.cfg, self.params, self.cache, dev["toks"],
-                    dev["row_lens"], dev["active"], dev["temps"],
-                    dev["top_ps"], self.key, dev["seeds"], dev["steps"],
-                    dev["top_ks"], dev["eos"], dev["remain"],
-                    prefill=prefill, horizon=1, with_decode=True,
-                    hist=dev["hist"], spec_ks=h2d(spec_ks),
-                    spec_k=self.ec.spec_k, spec_ngram=self.ec.spec_ngram,
-                    mesh=self.mesh, tp_manual=self._tp_manual,
-                    collective_qtype=self._collective_qtype)
-            self._tick_dispatches += 1
-        else:
-            cache_in, state_src = self._state_call()
-            with self._perf_dispatch("tick.admission", point=perf_pt,
-                                     rows_active=rows_active, **attn_pages):
-                (first_t, first_lp, tok_block, lp_block, n_exec,
-                 self.cache, dev["toks"], dev["row_lens"], dev["active"],
-                 dev["steps"], dev["remain"], self.key) = _ragged_tick(
-                    self.cfg, self.params, cache_in, dev["toks"],
-                    dev["row_lens"], dev["active"], dev["temps"],
-                    dev["top_ps"], self.key, dev["seeds"], dev["steps"],
-                    dev["top_ks"], dev["eos"], dev["remain"],
-                    prefill=prefill, horizon=1,
-                    with_decode=with_decode, mesh=self.mesh,
-                    tp_manual=self._tp_manual,
-                    collective_qtype=self._collective_qtype,
-                    state_src=state_src)
-            self._state_done(state_src)
-            self._tick_dispatches += 1
+            self.metrics["h2d_total"] += 1
+            kw = dict(hist=hist, spec_ks=h2d(spec_ks),
+                      spec_k=self.ec.spec_k, spec_ngram=self.ec.spec_ngram)
+        cache_in, state_src = self._state_call()
+        none5 = (None,) * 5        # an epoch carries no row state in
+        with self._perf_dispatch(
+                "tick.spec" if tick_spec else "tick.admission",
+                point=perf_pt, rows_active=rows_active, **attn_pages):
+            out = _ragged_tick(
+                self.cfg, self.params, cache_in, *none5, self.key, *none5,
+                prefill=buf, epoch=layout, horizon=1,
+                with_decode=with_decode, mesh=self.mesh,
+                tp_manual=self._tp_manual,
+                collective_qtype=self._collective_qtype,
+                state_src=state_src, **kw)
+        self._state_done(state_src)
+        self._tick_dispatches += 1
+        (first_t, first_lp, tok_block, lp_block, n_exec, self.cache,
+         *carried, self.key) = out[:12]
+        dev = dict(zip(("toks", "row_lens", "active", "steps", "remain"),
+                       carried))
+        dev.update(zip(HELD_FIELDS, out[-1]))
+        if tick_spec:
+            take_block, hist, s_prop, s_acc = out[12:16]
+        if hist is not None:
+            dev["hist"] = hist
+        self._dev = dev
         self._phase_to("drain")
         # advance bookkeeping; completed prompts run _finish_prompt once
         # their token arrives
@@ -3541,6 +3570,7 @@ class ServingEngine:
             # the spec-enabled form of the SAME single entry: drafting,
             # the [R, k+1] verify, and acceptance all ride inside the
             # horizon loop — still one dispatch (JP106 unchanged)
+            self.metrics["h2d_total"] += 1      # the draft widths
             with self._perf_dispatch("tick.spec", point=perf_pt,
                                      rows_active=rows_active, **attn_pages):
                 (_, _, tok_block, lp_block, n_exec, self.cache,

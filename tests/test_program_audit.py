@@ -668,6 +668,15 @@ def test_real_registry_covers_weight_qtype_axis():
     assert {pt.get("wd", True) for pt in tick_wq} == {True, False}
 
 
+def _dead_set(key: str) -> set:
+    """What a tick variant has to donate: the cache and, a steady tick,
+    the row state it carries in.  An admission-wave variant (width > 0)
+    takes the row state out of the packed epoch, so it is handed none."""
+    steady = "width=0" in key.split(",")
+    return {"cache"} | ({"toks", "row_lens", "active", "steps", "remain"}
+                        if steady else set())
+
+
 def test_manifest_locks_int4_tick_donation_map():
     """The int4 grid points keep the tick's donation contract: the
     device-state set aliases, while the packed weight planes (params) and
@@ -679,8 +688,7 @@ def test_manifest_locks_int4_tick_donation_map():
     assert wq_entries, "weight-qtype grid points missing from the manifest"
     for key, entry in wq_entries.items():
         aliased = {a.split("[")[0] for a in entry["aliases"]}
-        assert {"cache", "toks", "row_lens", "active", "steps",
-                "remain"} <= aliased, key
+        assert _dead_set(key) <= aliased, key
         assert not aliased & {"params", "temps", "top_ps", "seeds",
                               "top_ks", "eos", "key"}, key
 
@@ -714,8 +722,7 @@ def test_manifest_locks_engine_donation_map():
         # the PRNG key, which _checkpoint snapshots by reference for the
         # transient-retry contract (donating it hands rollback a deleted
         # buffer; tests/test_serving_faults.py replays that fault path)
-        assert {"cache", "toks", "row_lens", "active", "steps",
-                "remain"} <= aliased_args, key
+        assert _dead_set(key) <= aliased_args, key
         assert not aliased_args & {"temps", "top_ps", "seeds", "top_ks",
                                    "eos", "key"}, key
 
@@ -733,8 +740,7 @@ def test_manifest_locks_spec_tick_donation_map():
     assert kvs == {"bf16", "fp8"}
     for key, entry in spec_entries.items():
         aliased = {a.split("[")[0] for a in entry["aliases"]}
-        assert {"cache", "toks", "row_lens", "active", "steps", "remain",
-                "hist"} <= aliased, key
+        assert _dead_set(key) | {"hist"} <= aliased, key
         assert not aliased & {"temps", "top_ps", "seeds", "top_ks",
                               "eos", "key"}, key
 

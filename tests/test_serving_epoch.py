@@ -1,0 +1,240 @@
+"""The packed epoch (serving/epoch.py): what the host publishes for an
+epoch crosses to the device as ONE buffer in ONE transfer, and the tick
+program takes it apart.  Tier-1 guards: no one-op program is left on the
+path between two ticks, ``h2d_total`` counts exactly the transfers, the
+buffer round-trips every field bit for bit, and the lowered mixed tick
+gathers the prefill rows' tables itself, under scope ``tick/epoch``."""
+
+import logging
+import re
+
+import jax
+import numpy as np
+import pytest
+
+from ipex_llm_tpu.serving.engine import EngineConfig, Request, ServingEngine
+from ipex_llm_tpu.serving.epoch import EpochLayout, unpack_rows
+from ipex_llm_tpu.serving.faults import FaultInjector, TransientFault
+from tests.test_decoder import rand_params, tiny_cfg
+
+pytestmark = pytest.mark.fast
+
+RNG = np.random.default_rng(38)
+EC = dict(max_rows=4, max_seq_len=256, page_size=32, prefill_bucket=32)
+
+
+@pytest.fixture(scope="module")
+def cfg_params():
+    cfg = tiny_cfg(vocab_size=131, hidden_size=48, intermediate_size=96,
+                   num_heads=4, num_kv_heads=2, head_dim=12,
+                   max_position_embeddings=512)
+    return cfg, rand_params(cfg, qtype="bf16")
+
+
+def _prompt(cfg, n):
+    return [int(t) for t in RNG.integers(0, cfg.vocab_size, n)]
+
+
+def _finish_all(eng, reqs, max_ticks=400):
+    for _ in range(max_ticks):
+        if all(r.finish_reason is not None for r in reqs):
+            return
+        eng._tick()
+    raise AssertionError([r.finish_reason for r in reqs])
+
+
+# -- (a) nothing eager between two ticks ---------------------------------------
+
+class _Compiles(logging.Handler):
+    def __init__(self):
+        super().__init__(logging.DEBUG)
+        self.names = []
+
+    def emit(self, record):
+        m = re.match(r"Compiling (?:jit\()?([\w.<>]+)", record.getMessage())
+        if m:
+            self.names.append(m.group(1))
+
+
+def test_only_the_tick_and_the_unpack_compile_after_construction(cfg_params):
+    """An admission wave, completions, finishes and a page boundary on a
+    fresh engine: every program compiled after construction is
+    ``_ragged_tick`` or the one unpack.  An eager ``convert_element_type``
+    (``jnp.array``), gather, scatter, select or slice between two ticks
+    would compile a one-op program of that name."""
+    cfg, params = cfg_params
+    eng = ServingEngine(cfg, params, EngineConfig(**EC))
+    seen = _Compiles()
+    log = logging.getLogger("jax._src.interpreters.pxla")
+    log.addHandler(seen)
+    try:
+        with jax.log_compiles():
+            # 3 join at once (p_b 4), a 4th later beside decoding rows; 20
+            # + 40 tokens cross the 32-token page inside the decode
+            reqs = [Request(prompt_ids=_prompt(cfg, n), max_new_tokens=m,
+                            temperature=t, seed=5, eos_token_id=e)
+                    for n, m, t, e in ((20, 40, 0.0, ()), (45, 6, 0.8, (3,)),
+                                       (70, 9, 0.0, (3, 7, 9)))]
+            for r in reqs:
+                eng.submit(r)
+            for _ in range(4):
+                eng._tick()
+            late = Request(prompt_ids=_prompt(cfg, 33), max_new_tokens=5)
+            eng.submit(late)
+            _finish_all(eng, reqs + [late])
+    finally:
+        log.removeHandler(seen)
+    m = eng.metrics
+    assert m["mixed_steps"] >= 3 and m["table_row_syncs"] >= 3, m
+    assert seen.names, "the compile log was not captured"
+    assert set(seen.names) <= {"_ragged_tick", "unpack_rows"}, seen.names
+    assert "_ragged_tick" in seen.names
+
+
+# -- (b) the counter ------------------------------------------------------------
+
+def test_h2d_total_counts_one_transfer_an_epoch_and_none_without_news(
+        cfg_params):
+    cfg, params = cfg_params
+    eng = ServingEngine(cfg, params, EngineConfig(**EC))
+    m = eng.metrics
+    req = Request(prompt_ids=_prompt(cfg, 40), max_new_tokens=30)
+    eng.submit(req)
+    per_tick = []                      # (mixed?, news?, transfers)
+    while req.finish_reason is None:
+        before = (m["h2d_total"], m["mixed_steps"])
+        # news for a steady tick: the row state diverged (a completion) or
+        # the next slot, 64, lies on a page the row does not hold yet
+        news = eng._dirty or (int(eng.row_lens[0]) == 64
+                              and eng.tables[0, 2] < 0)
+        eng._tick()
+        per_tick.append((m["mixed_steps"] - before[1] == 1, bool(news),
+                         m["h2d_total"] - before[0]))
+    mixed = [t for t in per_tick if t[0]]
+    dirty_steady = [t for t in per_tick if not t[0] and t[1]]
+    quiet_steady = [t for t in per_tick if not t[0] and not t[1]]
+    # 40 tokens in 32-token chunks: two mixed ticks; the completion and the
+    # page boundary at 64 slots: two steady ticks with news; the rest quiet
+    assert len(mixed) == 2 and len(dirty_steady) == 2, per_tick
+    assert len(quiet_steady) >= 20, per_tick
+    assert all(n == 1 for _, _, n in mixed), per_tick
+    assert all(n == 1 for _, _, n in dirty_steady), per_tick
+    assert all(n == 0 for _, _, n in quiet_steady), per_tick
+    assert m["h2d_total"] == m["epoch_syncs"] == 4
+    # admission backs the whole prompt, so the table has news twice: at
+    # the first chunk and at the boundary
+    assert m["table_row_syncs"] == 2
+
+
+def test_a_rolled_back_tick_leaves_h2d_total_where_it_was(cfg_params):
+    """``sample`` fires after the upload and the dispatch of the tick that
+    completes a prompt: the tick rolls back, and the counter with it; the
+    retry then counts its own epoch."""
+    cfg, params = cfg_params
+    inj = FaultInjector().inject("sample", TransientFault, nth=1)
+    eng = ServingEngine(cfg, params, EngineConfig(
+        retry_backoff_s=0.0, **EC), fault_injector=inj)
+    req = Request(prompt_ids=_prompt(cfg, 12), max_new_tokens=3)
+    eng.submit(req)
+    assert eng._tick() is False and inj.fired == 1
+    assert eng.metrics["h2d_total"] == 0 and eng.metrics["retries"] == 1
+    assert eng._tick() is True
+    assert eng.metrics["h2d_total"] == 1
+    _finish_all(eng, [req])
+    assert len(req.output_ids) == 3
+
+
+# -- (c) the buffer round-trips bit for bit -------------------------------------
+
+def _fields(lay: EpochLayout, seed: int) -> dict:
+    rng = np.random.default_rng(seed)
+    r, p = lay.rows, lay.p_b
+    f32 = np.array([0.0, -0.0, 1e-45, 1.1754942e-38, 0.7, 1.0, np.inf,
+                    3.4028235e38], np.float32)        # incl. denormals
+    eos = rng.integers(-1, 2 ** 31 - 1, (r, lay.e_w)).astype(np.int32)
+    eos[:, lay.e_w // 2:] = -1                        # the pad
+    fields = dict(
+        toks=rng.integers(0, 2 ** 31 - 1, r), row_lens=rng.integers(0, 9, r),
+        active=rng.integers(0, 2, r).astype(bool), temps=rng.choice(f32, r),
+        top_ps=rng.choice(f32[::-1], r),
+        seeds=rng.integers(-2 ** 31, 2 ** 31 - 1, r),       # negative seeds
+        top_ks=rng.integers(0, 99, r), steps=rng.integers(0, 99, r),
+        remain=rng.integers(0, 99, r), eos=eos,
+        tables=rng.integers(-1, 4096, (r, lay.max_pages)))
+    if p:
+        rowmap = rng.integers(0, r, p)
+        rowmap[-1] = r                                # a pad slot
+        base = rng.integers(0, 64, p)
+        base[-1] = lay.max_pages * 32                 # past the table
+        fields.update(
+            p_toks=rng.integers(0, 131, (p, lay.width)), p_base=base,
+            p_nvalid=rng.integers(0, lay.width + 1, p),
+            p_emit=rng.integers(0, 2, p).astype(bool),
+            p_canjoin=rng.integers(0, 2, p).astype(bool), p_rowmap=rowmap)
+    dtypes = {name: dt for name, (_, _, _, dt) in lay.slots().items()}
+    return {k: np.asarray(v).astype(dtypes[k]) for k, v in fields.items()}
+
+
+@pytest.mark.parametrize("lay", [
+    EpochLayout(rows=4, max_pages=8, e_w=1, p_b=2, width=8, p_maxp=8),
+    EpochLayout(rows=16, max_pages=32, e_w=4, p_b=8, width=64, p_maxp=4),
+    EpochLayout(rows=3, max_pages=5, e_w=2),          # a steady tick's epoch
+], ids=lambda lay: f"r{lay.rows}-p{lay.p_b}x{lay.width}-e{lay.e_w}")
+def test_the_packed_buffer_round_trips_every_field_bit_for_bit(lay):
+    fields = _fields(lay, seed=lay.rows)
+    buf = lay.pack(**fields)
+    assert buf.dtype == np.int32 and buf.shape == (lay.size,)
+    got = jax.jit(lay.unpack)(buf)
+    assert set(got) == set(fields)
+    for name, want in fields.items():
+        have = np.asarray(got[name])
+        assert have.dtype == want.dtype and have.shape == want.shape, name
+        assert have.tobytes() == want.tobytes(), name
+    if not lay.p_b:                    # the program the steady epoch runs
+        again = unpack_rows(buf, lay)
+        assert all(np.asarray(again[k]).tobytes() == fields[k].tobytes()
+                   for k in fields)
+
+
+def test_an_upload_is_a_private_copy():
+    """``hostutil.upload`` has ``h2d``'s copy semantics: the host may
+    refill its buffer while the upload is still in flight."""
+    from ipex_llm_tpu.hostutil import upload
+
+    buf = np.arange(4096, dtype=np.int32)
+    dev = upload(buf)
+    buf[:] = -1
+    np.testing.assert_array_equal(np.asarray(dev),
+                                  np.arange(4096, dtype=np.int32))
+
+
+# -- (d) the gather is the program's ---------------------------------------------
+
+def test_the_lowered_mixed_tick_gathers_its_tables_under_tick_epoch():
+    """The guard style of tests/test_serving_lowbit.py: in the mixed
+    tick's jaxpr the prefill rows' table view comes from a gather of the
+    unpacked table inside scope ``tick/epoch`` (with the two float fields'
+    bitcasts beside it), and the only arguments that reach the program
+    for the epoch are the packed buffer and the key."""
+    from ipex_llm_tpu.analysis.trace.registry import _build_ragged_tick
+    from ipex_llm_tpu.serving.engine import _ragged_tick
+
+    args, kw = _build_ragged_tick({"rows": 4, "width": 8, "horizon": 1,
+                                   "kv": "bf16"})
+    lay = kw["epoch"]
+    jaxpr = _ragged_tick.trace(*args, **kw).jaxpr.jaxpr
+
+    def scoped(eqn):
+        return "tick/epoch" in str(eqn.source_info.name_stack)
+
+    gathers = [e for e in jaxpr.eqns if e.primitive.name == "gather"
+               and scoped(e)]
+    assert [tuple(e.outvars[0].aval.shape) for e in gathers] == [
+        (lay.p_b, lay.max_pages)], gathers
+    assert tuple(gathers[0].invars[0].aval.shape) == (lay.rows,
+                                                      lay.max_pages)
+    bitcasts = [e for e in jaxpr.eqns
+                if e.primitive.name == "bitcast_convert_type" and scoped(e)]
+    assert len(bitcasts) == 2          # temps, top_ps
+    text = _ragged_tick.lower(*args, **kw).as_text(debug_info=True)
+    assert "tick/epoch" in text

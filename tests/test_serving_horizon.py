@@ -137,13 +137,13 @@ def test_no_param_reupload_between_epochs(cfg_params, monkeypatch):
         max_rows=2, max_seq_len=256, page_size=32, prefill_bucket=32,
         decode_horizon=1)).start()
     uploads = {"n": 0}
-    orig = eng._upload_row_state
+    orig = eng._upload_epoch
 
-    def counting():
+    def counting(*args, **kw):
         uploads["n"] += 1
-        return orig()
+        return orig(*args, **kw)
 
-    monkeypatch.setattr(eng, "_upload_row_state", counting)
+    monkeypatch.setattr(eng, "_upload_epoch", counting)
     try:
         prompt = list(RNG.integers(0, cfg.vocab_size, 16))
         req = eng.submit(Request(prompt_ids=prompt, max_new_tokens=40,

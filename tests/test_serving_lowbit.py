@@ -345,7 +345,7 @@ def test_a_row_in_a_wave_matches_the_row_alone_on_the_kernel(kernel_forced,
     def recorded(*args, **kw):
         key = (kw.get("horizon"), kw.get("with_decode"),
                None if kw.get("prefill") is None
-               else kw["prefill"][0].shape)
+               else (kw["epoch"].p_b, kw["epoch"].width))
         if key not in seen:
             seen[key] = real.trace(*args, **kw).jaxpr
         return real(*args, **kw)

@@ -217,16 +217,16 @@ def test_one_sync_per_tick_tier1(cfg_params):
 # -- one jitted program, driven directly ------------------------------------
 
 def test_the_engine_module_holds_one_jitted_tick():
-    """The only ``jax.jit``-wrapped callables ``serving.engine`` exports are
-    ``_ragged_tick`` and its alias: a second engine (a sequential prefill
-    program, a host-walk verify step, a pp step) cannot come back
-    unnoticed.  Oracles live in the tests."""
+    """The only ``jax.jit``-wrapped callables ``serving.engine`` holds are
+    ``_ragged_tick`` and the small program that takes a packed epoch apart
+    where the tick has no prefill block (``epoch.unpack_rows``): a second
+    engine (a sequential prefill program, a host-walk verify step, a pp
+    step) cannot come back unnoticed.  Oracles live in the tests."""
     from ipex_llm_tpu.serving import engine as engine_mod
 
     jitted = {name: obj for name, obj in vars(engine_mod).items()
               if hasattr(obj, "lower") and hasattr(obj, "clear_cache")}
-    assert set(jitted) == {"_ragged_tick", "_ragged_tick_fn"}, sorted(jitted)
-    assert jitted["_ragged_tick_fn"] is jitted["_ragged_tick"]
+    assert set(jitted) == {"_ragged_tick", "unpack_rows"}, sorted(jitted)
 
 
 def _random_pool_state(cfg, kv: str, seed: int = 0):

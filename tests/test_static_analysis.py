@@ -480,8 +480,8 @@ def test_unmigrating_an_upload_call_site_fails_jl001():
     """Deleting the shared copying-upload helper from a migrated call site
     must re-trigger JL001 (acceptance criterion: the helper cannot rot)."""
     engine = (PKG / "serving" / "engine.py").read_text()
-    assert "h2d(rowmap)" in engine
-    regressed = engine.replace("h2d(rowmap)", "jnp.asarray(rowmap)", 1)
+    assert "h2d(spec_ks)" in engine
+    regressed = engine.replace("h2d(spec_ks)", "jnp.asarray(spec_ks)", 1)
     fs = analyze_source(regressed, "ipex_llm_tpu/serving/engine.py")
     assert any(f.rule == "JL001" and not f.suppressed and
                f.severity == "error" for f in fs)
