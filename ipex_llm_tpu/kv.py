@@ -73,22 +73,60 @@ def pool_geometry(cfg, storage: str = "bf16") -> tuple[int, int, int]:
     pool): the absorbed walk's key is the two side by side and its value is
     ``c`` (ops/pallas/ragged_paged_attention.py); the per-head K and V are
     never stored.  bf16 only: the latent is already the compressed form, and
-    an e5m2 ``c`` feeds every head's keys AND values."""
+    an e5m2 ``c`` feeds every head's keys AND values.
+
+    A latent-attention model with a learned INDEXER (``cfg.index_topk``)
+    holds a third kind of page, the indexer's key, and its walk reads chosen
+    TOKENS, not whole pages, so its latent is laid out again: the ``k`` pool's
+    row is the whole key a token, ``[c ; k_r]`` padded to whole lanes
+    (:func:`latent_row_width`; one contiguous row a token, no strided column
+    of a token-minor page to fetch), and the ``v`` pool holds the INDEX KEY
+    (``index_head_dim`` wide), for the ``cfg.index_layers`` layers whose
+    indexer is "full" only.  Both lie under the one block table, so a page
+    id allocates, frees, shares and moves a row's latent and its index keys
+    together."""
     if not cfg.is_mla:
         return cfg.num_kv_heads, cfg.head_dim, cfg.v_dim
     if storage != "bf16":
         kv_storage_dtype(storage)        # an unknown name raises its own way
+        kind = ("latent and index-key pages are" if cfg.index_topk
+                else "latent page is")
         raise ValueError(
             f"kv storage {storage!r} is not supported for a latent-attention "
-            f"(MLA) pool ({cfg.model_type}): the latent page is bf16 only")
+            f"(MLA) pool ({cfg.model_type}): the {kind} bf16 only")
+    if cfg.index_topk:
+        return 1, latent_row_width(cfg), cfg.index_head_dim
     return 1, cfg.kv_lora_rank, cfg.qk_rope_head_dim
 
 
+def latent_row_width(cfg) -> int:
+    """Width of a sparse latent pool's row: ``[c ; k_r]`` and zeros up to a
+    whole number of 128-lane tiles (the device pads the minor axis to that
+    anyway; here the bytes are counted)."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
 def model_page_bytes(cfg, page_size: int, storage: str = "bf16") -> int:
-    """:func:`paged_page_bytes` of ``cfg``'s pool (:func:`pool_geometry`)."""
+    """Bytes of one page of ``cfg``'s pool across its layers
+    (:func:`pool_geometry`): :func:`paged_page_bytes`, or for a sparse
+    latent pool its two kinds of page by their own layer counts."""
     heads, kd, vd = pool_geometry(cfg, storage)
+    if cfg.is_mla and cfg.index_topk:
+        return (paged_page_bytes(cfg.kv_layers, 1, page_size, kd,
+                                 v_head_dim=0, storage=storage)
+                + index_page_bytes(cfg, page_size, storage))
     return paged_page_bytes(cfg.kv_layers, heads, page_size, kd,
                             v_head_dim=vd, storage=storage)
+
+
+def index_page_bytes(cfg, page_size: int, storage: str = "bf16") -> int:
+    """Bytes of one page's INDEX KEYS across the layers that hold them (0
+    for a model without an indexer)."""
+    if not cfg.index_topk:
+        return 0
+    return paged_page_bytes(cfg.index_layers, 1, page_size,
+                            cfg.index_head_dim, v_head_dim=0,
+                            storage=storage)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -236,6 +274,11 @@ class PagedKVCache:
     # (a ``[page, 64]`` page is padded to 128 lanes on the chip and cannot
     # be cut out of its stack by a copy)
     latent: bool = False
+    # a SPARSE latent pool (a latent-attention model with an indexer):
+    # ``k`` [L, P, 1, page, row] holds the whole key a token, ``[c ; k_r ;
+    # 0]`` (:func:`latent_row_width`), and ``v`` [full layers, P, 1, page,
+    # index dim] the indexer's key of the layers whose indexer is "full"
+    sparse: bool = False
     # the second kind of serving state, beside the pool in the SAME object:
     # a hybrid model's recurrent state (:func:`recurrent_state`), addressed
     # by ROW and not through the block table.  Carried through the tick like
@@ -245,19 +288,20 @@ class PagedKVCache:
 
     def tree_flatten(self):
         return ((self.k, self.v, self.tables, self.length, self.state),
-                (self.storage, self.latent))
+                (self.storage, self.latent, self.sparse))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         k, v, tables, length, state = children
         return cls(k, v, tables, length, storage=aux[0], latent=aux[1],
-                   state=state)
+                   sparse=aux[2], state=state)
 
     @classmethod
     def init(cls, n_layers: int, n_pages: int, n_rows: int, max_pages: int,
              n_kv_heads: int, page_size: int, head_dim: int,
              dtype=None, v_head_dim: int | None = None,
-             storage: str | None = None, latent: bool = False):
+             storage: str | None = None, latent: bool = False,
+             index_layers: int = 0):
         """``storage`` selects the pool width ("bf16" | "fp8" e5m2); the
         whole access surface (encode/decode_layer/update/gather) keys off
         ``self.k.dtype``, so one class serves both formats — the serving
@@ -287,15 +331,20 @@ class PagedKVCache:
                 raise ValueError(
                     f"dtype {jnp.dtype(dtype).name} contradicts "
                     f"storage {storage!r} ({jnp.dtype(storage_dtype).name})")
-        v_page = (vd, page_size) if latent else (page_size, vd)
+        # ``index_layers``: a sparse latent pool, whose ``v`` holds the
+        # index key of that many layers, token-major
+        v_page = ((vd, page_size) if latent and not index_layers
+                  else (page_size, vd))
         return cls(
             k=jnp.zeros((n_layers, n_pages, n_kv_heads, page_size, head_dim),
                         dtype),
-            v=jnp.zeros((n_layers, n_pages, n_kv_heads, *v_page), dtype),
+            v=jnp.zeros((index_layers or n_layers, n_pages, n_kv_heads,
+                         *v_page), dtype),
             tables=jnp.full((n_rows, max_pages), -1, jnp.int32),
             length=jnp.zeros((), jnp.int32),
             storage=storage,
             latent=latent,
+            sparse=index_layers > 0,
         )
 
     @classmethod
@@ -306,7 +355,8 @@ class PagedKVCache:
         heads, kd, vd = pool_geometry(cfg, storage)
         return cls.init(cfg.kv_layers, n_pages, n_rows, max_pages, heads,
                         page_size, kd, v_head_dim=vd, storage=storage,
-                        latent=cfg.is_mla)
+                        latent=cfg.is_mla,
+                        index_layers=cfg.index_layers if cfg.is_mla else 0)
 
     @property
     def page_size(self) -> int:
@@ -323,6 +373,8 @@ class PagedKVCache:
         formula, :func:`paged_page_bytes`; init guarantees the storage
         tag matches the pool dtypes)."""
         l, _, h, ps, d = self.k.shape
+        if self.sparse:      # two kinds of page, each by its own layers
+            return (self.k.nbytes + self.v.nbytes) // self.k.shape[1]
         return paged_page_bytes(
             l, h, ps, d, v_head_dim=self.v.shape[3 if self.latent else 4],
             storage=self.storage)
@@ -399,6 +451,15 @@ class PagedKVCache:
         v = v.at[layer, pages, :, offs].set(self.encode(new_v))
         return k, v
 
+    def write_pool(self, pool: jnp.ndarray, layer, new: jnp.ndarray,
+                   pos: jnp.ndarray) -> jnp.ndarray:
+        """:meth:`write_layer` for ONE token-major pool ``[L, P, H, page,
+        D]``: new [B, T, H, D] at ``(layer, page, :, offset)``.  A sparse
+        latent pool's two kinds of page are written apart: a latent row
+        every layer, an index key where the layer's indexer is full."""
+        pages, offs = self._route(*new.shape[:2], pos)
+        return pool.at[layer, pages, :, offs].set(self.encode(new))
+
     def gather_pages(self, page_ids) -> tuple[jnp.ndarray, jnp.ndarray]:
         """Pull whole pages out of the pool: ``page_ids`` [N] ->
         (k [L, N, Hkv, page, D], v [L, N, Hkv, page, Dv]) in the pool's
@@ -453,6 +514,17 @@ class PagedKVCache:
 # these names with ``_total`` appended
 STATE_COUNTERS = ("moe_pairs_routed", "moe_pairs_local", "moe_experts_hit",
                   "moe_layer_calls", "ssm_state_resets")
+# and what a model with an indexer counts besides, over its attending
+# queries: index keys scored (full layers), positions a dense walk would
+# read and positions read (every layer), layer calls by indexer type
+DSA_COUNTERS = ("dsa_keys_scored", "dsa_tokens_visible",
+                "dsa_tokens_selected", "dsa_full_layer_calls",
+                "dsa_shared_layer_calls")
+
+
+def state_counters(cfg) -> tuple[str, ...]:
+    """The device counters ``cfg``'s state carries, in their order."""
+    return STATE_COUNTERS + (DSA_COUNTERS if cfg.index_topk else ())
 
 
 def recurrent_state(cfg, rows: int) -> dict | None:
@@ -464,7 +536,7 @@ def recurrent_state(cfg, rows: int) -> dict | None:
     (``cfg.moe_experts_held``) carries the counters alone: they ride the
     state so that a rolled-back tick's are dropped with it.  None where the
     model has neither."""
-    stats = jnp.zeros((len(STATE_COUNTERS),), jnp.int32)
+    stats = jnp.zeros((len(state_counters(cfg)),), jnp.int32)
     if not cfg.ssm_layers:
         return {"stats": stats} if cfg.moe_experts_held else None
     return {

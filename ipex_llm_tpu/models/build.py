@@ -570,16 +570,43 @@ def build_params(
             lp["down_bias"] = jnp.asarray(db, jnp.float32)
         layers.append(lp)
 
+    # a model with an indexer (cfg.index_topk): the layers whose indexer is
+    # "full" carry its weights, stacked for THOSE layers only and hung on
+    # the stack that holds them (models/decoder.py ``run_layers``)
+    indexers: dict[int, dict] = {}
+    if cfg.index_topk:
+        ix = "model.layers.{i}.self_attn.indexer."
+        for i, kind in enumerate(cfg.indexer_types):
+            if kind != "full":
+                continue
+            pre = ix.format(i=i)
+            indexers[i] = {
+                "wq_b": quantize_weight(get(pre + "wq_b.weight"), qtype),
+                "wk": quantize_weight(get(pre + "wk.weight"), qtype),
+                "k_norm": jnp.asarray(get(pre + "k_norm.weight"), NORM_DTYPE),
+                "k_norm_bias": jnp.asarray(get(pre + "k_norm.bias"),
+                                           NORM_DTYPE),
+                "w_proj": jnp.asarray(np.ascontiguousarray(
+                    get(pre + "weights_proj.weight").T), jnp.float32),
+            }
+
+    def stack_of(lo, hi):
+        tree = stack_layer_trees(layers[lo:hi])
+        mine = [indexers[i] for i in range(lo, hi) if i in indexers]
+        if mine:
+            tree["indexer"] = stack_layer_trees(mine)
+        return tree
+
     # deepseek-style dense prefix: the first ``moe_layer_start`` layers have
     # a plain MLP, the rest are MoE — two param stacks, two scans
     # (decoder_forward); each stack is still one compiled layer body
     if 0 < cfg.moe_layer_start < cfg.num_layers and cfg.num_experts > 0:
         params = {
-            "layers_dense": stack_layer_trees(layers[: cfg.moe_layer_start]),
-            "layers": stack_layer_trees(layers[cfg.moe_layer_start :]),
+            "layers_dense": stack_of(0, cfg.moe_layer_start),
+            "layers": stack_of(cfg.moe_layer_start, cfg.num_layers),
         }
     else:
-        params = {"layers": stack_layer_trees(layers)}
+        params = {"layers": stack_of(0, cfg.num_layers)}
     if embedding_qtype and not cfg.tie_word_embeddings:
         # LowBitEmbedding equivalent (reference embedding.py:179): table
         # quantized [vocab, hidden] with vocab as the block axis; rows

@@ -137,6 +137,17 @@ class ModelConfig:
     moe_first_expert: int = 0
     moe_experts_held: int = 0
 
+    # learned sparse attention over the MLA latent (glm_moe_dsa; DeepSeek-V3.2's
+    # indexer): a layer whose ``indexer_types`` entry is "full" scores every
+    # cached token with ``index_n_heads`` small heads of ``index_head_dim``
+    # against a cached index key and keeps the ``index_topk`` best; a
+    # "shared" layer attends over the selection of the nearest full layer
+    # below it.  0 / None: every head attends every token
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    indexer_types: tuple[str, ...] | None = None
+
     def layer_is_sliding(self, layer_idx: int) -> bool:
         if self.layer_types is not None:
             return self.layer_types[layer_idx] == "sliding_attention"
@@ -151,6 +162,11 @@ class ModelConfig:
         if self.hybrid_pattern is not None:
             return self.hybrid_pattern.count("*")
         return self.num_layers
+
+    @property
+    def index_layers(self) -> int:
+        """Layers that hold an index-key cache: the "full" indexers."""
+        return sum(t == "full" for t in self.indexer_types or ())
 
     @property
     def ssm_layers(self) -> int:

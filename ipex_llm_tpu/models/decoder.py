@@ -28,8 +28,10 @@ from ipex_llm_tpu.models.config import ModelConfig
 from ipex_llm_tpu.ops import linear as linear_ops
 from ipex_llm_tpu.ops import mlp as mlp_ops
 from ipex_llm_tpu.ops import rope as rope_ops
-from ipex_llm_tpu.ops.attention import (cached_sdpa, latent_attention,
-                                        paged_kv_write)
+from ipex_llm_tpu.ops.attention import (cached_sdpa, index_scores,
+                                        latent_attention, paged_kv_write,
+                                        paged_pool_write, select_topk,
+                                        sparse_latent_attention)
 from ipex_llm_tpu.ops.norms import layer_norm, rms_norm
 from ipex_llm_tpu.quantize.core import (QLayer, QTensor, dequantize,
                                         layer_views)
@@ -73,11 +75,13 @@ def _in_norm(x, lp, key, cfg):
 def _attention_block(cfg: ModelConfig, lp: dict, x, kl, vl, cos, sin, slot0,
                      q_slots, kv_len, kv_start, sliding, cache: KVCache,
                      collect_obs: int = 0, bias=None, pre_normed=False,
-                     chunk_lens=None, layer=None):
+                     chunk_lens=None, layer=None, dsa=None):
     """One layer's attention.  ``kl``/``vl`` are that layer's cache slice;
     with ``layer`` (a paged pool under :func:`run_layers`) they are the
     WHOLE stacks [L, P, Hkv, page, D], written and read at that layer in
-    place, and come back whole."""
+    place, and come back whole.  ``dsa`` (a model with an indexer over a
+    paged pool; :func:`_sparse_mla`): the layer's indexer call, and the
+    return gains ``(selection, counters)``."""
     b, t, _ = x.shape
 
     def write(k, v):
@@ -94,7 +98,7 @@ def _attention_block(cfg: ModelConfig, lp: dict, x, kl, vl, cos, sin, slot0,
     if cfg.is_mla:
         return _mla_block(cfg, lp, x, h, kl, vl, cos, sin, q_slots, kv_len,
                           kv_start, sliding, cache, collect_obs, chunk_lens,
-                          layer, write)
+                          layer, write, dsa)
     with jax.named_scope("layer/qkv"):
         q, k, v, obs_q = _qkv_rope(cfg, lp, x, h, cos, sin, collect_obs)
     kl, vl = write(k, v)
@@ -143,7 +147,7 @@ def _dense_weight(w, dtype):
 
 def _mla_block(cfg: ModelConfig, lp: dict, x, h, kl, vl, cos, sin, q_slots,
                kv_len, kv_start, sliding, cache, collect_obs, chunk_lens,
-               layer, write):
+               layer, write, dsa=None):
     """One layer's latent attention (DeepSeek MLA, reference
     deepseek.py:274-343): a low-rank q, a compressed KV latent ``c`` and ONE
     rotated key slice ``k_r`` shared by every head; ``kv_b`` holds every
@@ -156,17 +160,22 @@ def _mla_block(cfg: ModelConfig, lp: dict, x, h, kl, vl, cos, sin, q_slots,
     ``W_UV`` applied to the latent-space output, both read from ``kv_b``
     without a second copy kept.  Over a dense cache (``generate()``) K and V
     are EXPANDED per head before the write, as before: K at the qk dim, V at
-    v_head_dim."""
+    v_head_dim.
+
+    With ``dsa`` (a model with an indexer, over its sparse latent pool) the
+    walk is over a SELECTION (:func:`_sparse_mla`), and the return gains the
+    selection and the layer's counters."""
     b, t, _ = x.shape
     nope, rd_pe, rank = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                          cfg.kv_lora_rank)
     latent = hasattr(cache, "tables")
     with jax.named_scope("layer/qkv"):
         with jax.named_scope("mla/q"):
+            c_q = None
             if "q_a" in lp:
                 qa = linear_ops.linear(h, lp["q_a"], lp.get("q_a_bias"))
-                q = linear_ops.linear(
-                    rms_norm(qa, lp["q_a_norm"], cfg.norm_eps), lp["q_b"])
+                c_q = rms_norm(qa, lp["q_a_norm"], cfg.norm_eps)
+                q = linear_ops.linear(c_q, lp["q_b"])
             else:  # V2-Lite: full-rank q_proj
                 q = linear_ops.linear(h, lp["q"], lp.get("q_bias"))
             q = q.reshape(b, t, cfg.num_heads, cfg.head_dim)
@@ -177,10 +186,25 @@ def _mla_block(cfg: ModelConfig, lp: dict, x, h, kl, vl, cos, sin, q_slots,
             c = rms_norm(ckv[..., :rank], lp["kv_a_norm"], cfg.norm_eps)
             k_pe = rope_ops.apply_rope(ckv[..., rank:][:, :, None, :], cos,
                                        sin, "two")              # [B,T,1,rd]
-            if latent:
+            if dsa is not None:
+                # the pool's row is the whole key a token: [c ; k_r ; 0]
+                row = jnp.concatenate([c[:, :, None, :], k_pe], axis=-1)
+                row = jnp.pad(row, ((0, 0),) * 3
+                              + ((0, kl.shape[-1] - row.shape[-1]),))
+                kl = paged_pool_write(cache, kl, layer, row, q_slots[:, 0])
+            elif latent:
                 kl, vl = write(c[:, :, None, :], k_pe)
 
-    if latent:
+    if dsa is not None:
+        if collect_obs:
+            raise NotImplementedError(
+                "SnapKV observation windows over a sparse latent pool")
+        attn, vl, sel, d_stats = _sparse_mla(
+            cfg, lp, dsa, h, c_q, q_nope, q_pe, kl, vl, cos, sin, q_slots,
+            kv_len, chunk_lens, cache, layer)
+        attn = attn.astype(x.dtype)
+        obs_q = jnp.zeros((0,), x.dtype)
+    elif latent:
         if collect_obs:
             raise NotImplementedError(
                 "SnapKV observation windows over a latent (MLA) paged pool")
@@ -189,13 +213,7 @@ def _mla_block(cfg: ModelConfig, lp: dict, x, h, kl, vl, cos, sin, q_slots,
             # prefill block is [p_b, width] with p_b x width fixed by the
             # budget, so every block shape is the same product
             with jax.named_scope("mla/absorb"):
-                w_kvb = _dense_weight(lp["kv_b"], COMPUTE_DTYPE).reshape(
-                    rank, cfg.num_heads, nope + cfg.v_dim)
-                q_lat = jnp.einsum(
-                    "nhd,chd->nhc",
-                    q_nope.astype(COMPUTE_DTYPE).reshape(b * t, -1, nope),
-                    w_kvb[..., :nope], preferred_element_type=jnp.float32
-                ).astype(COMPUTE_DTYPE).reshape(b, t, -1, rank)
+                q_lat, w_kvb = _absorb_q(cfg, lp, q_nope)
             o_lat = latent_attention(
                 q_lat, q_pe, kl, vl, cache, kv_len, chunk_lens,
                 scale=(cfg.attn_scale if cfg.attn_scale is not None
@@ -231,7 +249,127 @@ def _mla_block(cfg: ModelConfig, lp: dict, x, h, kl, vl, cos, sin, q_slots,
         out = linear_ops.linear(attn, lp["o"], lp.get("o_bias"))
         if cfg.post_attn_norm:
             out = _norm(out, lp["post_attn_norm"], cfg)
+    if dsa is not None:
+        return out, kl, vl, obs_q, sel, d_stats
     return out, kl, vl, obs_q
+
+
+def _absorb_q(cfg: ModelConfig, lp: dict, q_nope):
+    """``W_UK`` folded into the position-free query: (q_lat [B, T, H, rank],
+    ``kv_b`` dense as [rank, H, nope + v])."""
+    b, t = q_nope.shape[:2]
+    nope, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    w_kvb = _dense_weight(lp["kv_b"], COMPUTE_DTYPE).reshape(
+        rank, cfg.num_heads, nope + cfg.v_dim)
+    q_lat = jnp.einsum(
+        "nhd,chd->nhc", q_nope.astype(COMPUTE_DTYPE).reshape(b * t, -1, nope),
+        w_kvb[..., :nope], preferred_element_type=jnp.float32
+    ).astype(COMPUTE_DTYPE).reshape(b, t, -1, rank)
+    return q_lat, w_kvb
+
+
+# the indexer's LayerNorm (DeepSeek-V3.2's published indexer, whose keys a
+# glm_moe_dsa config names): its own epsilon, not the model's RMSNorm one
+_INDEX_NORM_EPS = 1e-6
+
+
+def _sparse_mla(cfg: ModelConfig, lp: dict, dsa, h, c_q, q_nope, q_pe, kl, vl,
+                cos, sin, q_slots, kv_len, chunk_lens, cache, layer):
+    """The attention of a layer WITH AN INDEXER over its sparse latent pool
+    (kv.py ``pool_geometry``: ``kl`` the latent rows, already holding this
+    call's tokens; ``vl`` the index keys of the full layers).  ``dsa`` is
+    ``(is_full, entry of the indexer stack, the full layer's index in the
+    index-key pool, the selection carried from below)``.
+
+    A FULL layer runs its indexer: 32 small heads off the low-rank query
+    ``c_q`` it shares with the main heads, a LayerNormed index key a token
+    (written to the pool), head weights off the hidden state; scores,
+    selection (ops/attention.py).  A SHARED layer has no indexer weights and
+    no index keys and takes the selection of the nearest full layer below.
+    Either way every head attends the selected slots only, absorbed.  The
+    published code rotates the indexer's q and k by a Hadamard matrix before
+    an fp8 product: an orthogonal map that leaves every dot product as it
+    is, left out here (bf16 products).
+
+    Returns (attn [B*T, H, v], the index-key pool, the selection, int32 [5]
+    counters: keys scored, positions visible, positions selected, full
+    calls, shared calls)."""
+    b, t = h.shape[:2]
+    is_full, indexer, fi, sel = dsa
+    rd = cfg.qk_rope_head_dim
+    if chunk_lens is None:
+        chunk_lens = jnp.full((b,), t, jnp.int32)
+    asks = jnp.arange(t)[None, :] < chunk_lens[:, None]   # attending queries
+    visible = jnp.sum(jnp.where(asks, q_slots + 1, 0)).astype(jnp.int32)
+
+    def rope_head(z):
+        return jnp.concatenate(
+            [rope_ops.apply_rope(z[..., :rd], cos, sin, "two"), z[..., rd:]],
+            axis=-1)
+
+    def full(vl, sel):
+        ix = indexer()
+        with jax.named_scope("dsa/index"):
+            q_i = rope_head(linear_ops.linear(c_q, ix["wq_b"]).reshape(
+                b, t, cfg.index_n_heads, cfg.index_head_dim))
+            k_i = rope_head(layer_norm(
+                linear_ops.linear(h, ix["wk"]), ix["k_norm"],
+                ix["k_norm_bias"], _INDEX_NORM_EPS)[:, :, None, :])
+            w_i = jnp.matmul(
+                h.astype(jnp.float32), ix["w_proj"],
+                precision=jax.lax.Precision.HIGHEST
+            ) * (cfg.index_n_heads ** -0.5 * cfg.index_head_dim ** -0.5)
+            vl = paged_pool_write(cache, vl, fi, k_i, q_slots[:, 0])
+            # (``dsa/score`` nests here: the indexer's whole cost is one
+            # scope, its scoring a part of it)
+            scores = index_scores(q_i, w_i, vl, cache, q_slots, kv_len,
+                                  layer=fi)
+        chosen = select_topk(scores, cfg.index_topk)
+        if t == 1:
+            n_sel = jnp.sum(chosen[1], axis=-1, dtype=jnp.int32)[:, None]
+            return vl, (*chosen, n_sel), visible
+        return vl, (chosen, jnp.sum(chosen, axis=-1, dtype=jnp.int32)), visible
+
+    def shared(vl, sel):
+        return vl, sel, jnp.zeros((), jnp.int32)
+
+    with jax.named_scope("layer/attn"):
+        # (a tree of shared layers alone holds no indexer stack)
+        vl, sel, scored = (shared(vl, sel) if indexer is None else
+                           jax.lax.cond(is_full, full, shared, vl, sel))
+        with jax.named_scope("mla/absorb"):
+            q_lat, w_kvb = _absorb_q(cfg, lp, q_nope)
+            q_row = jnp.concatenate(
+                [q_lat, q_pe.astype(COMPUTE_DTYPE)], axis=-1)
+            q_row = jnp.pad(q_row, ((0, 0),) * 3
+                            + ((0, kl.shape[-1] - q_row.shape[-1]),))
+        o_lat = sparse_latent_attention(
+            q_row, kl, cache, sel[:-1] if t == 1 else sel[0], chunk_lens,
+            kv_len, rank=cfg.kv_lora_rank,
+            scale=(cfg.attn_scale if cfg.attn_scale is not None
+                   else cfg.head_dim ** -0.5), layer=layer)
+        with jax.named_scope("mla/absorb"):
+            attn = jnp.einsum(
+                "nhc,chd->nhd", o_lat.reshape(b * t, -1, cfg.kv_lora_rank),
+                w_kvb[..., cfg.qk_nope_head_dim:],
+                preferred_element_type=jnp.float32)
+    full_i = is_full.astype(jnp.int32)
+    stats = jnp.stack([
+        scored, visible, jnp.sum(jnp.where(asks, sel[-1], 0)).astype(
+            jnp.int32), full_i, 1 - full_i])
+    return attn, vl, sel, stats
+
+
+def no_selection(cfg: ModelConfig, b: int, t: int, s: int):
+    """The empty selection a model with an indexer starts its layers from
+    (its first layer's indexer is full and replaces it): a decode call's
+    ``(slots [B, k], which are real, how many a query chose [B, T])``, a
+    chunk's ``(mask [B, T, S], how many)``."""
+    n_sel = jnp.zeros((b, t), jnp.int32)
+    if t == 1:
+        k = min(cfg.index_topk, s)
+        return (jnp.zeros((b, k), jnp.int32), jnp.zeros((b, k), bool), n_sel)
+    return jnp.zeros((b, t, s), bool), n_sel
 
 
 def _qkv_rope(cfg: ModelConfig, lp: dict, x, h, cos, sin, collect_obs):
@@ -742,7 +880,7 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
                x, cos, sin, slot0, q_slots, kv_len, kv_start, cache,
                collect_obs: int = 0, alibi_bias=None,
                cos_local=None, sin_local=None, chunk_lens=None,
-               layer_offset: int = 0, valid=None):
+               layer_offset: int = 0, valid=None, selection=None):
     """Scan one stacked layer tree over its layers of the cache.
 
     The single compiled layer body shared by decoder_forward and the
@@ -774,8 +912,32 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
     plane would be copied out of its stack before the kernel call that
     reads it, every layer, every pass (a quarter of the device's time, PR
     30's ledger).
+
+    A model with an INDEXER over a paged pool (``selection`` given:
+    :func:`no_selection`, or what the tree below returned) carries a
+    selection beside the stacks: a layer whose ``cfg.indexer_types`` entry is
+    "full" replaces it and the "shared" layers after it attend over it
+    (:func:`_sparse_mla`).  The indexer's weights are the tree's
+    ``indexer`` stack, an entry a FULL layer of the tree only, read at the
+    full layer's index; its index keys are ``v_stack``, a layer a full layer
+    of the MODEL.  The return gains ``(selection, int32 [5] counters)``.
     """
     n = sliding_flags.shape[0]
+    dsa = selection is not None
+    ix_tree = tree.get("indexer")
+    if ix_tree is not None:
+        # (a dense cache attends every token and never reads it)
+        tree = {k_: v_ for k_, v_ in tree.items() if k_ != "indexer"}
+    if dsa:
+        types = cfg.indexer_types[layer_offset:layer_offset + n]
+        is_full = jnp.asarray([t_ == "full" for t_ in types])
+        # a layer's entry of this tree's indexer stack, and of the model's
+        # index-key pool (the full layers below this tree come first)
+        fi_local = jnp.maximum(jnp.cumsum(is_full) - 1, 0).astype(jnp.int32)
+        fi_below = sum(t_ == "full" for t_ in
+                       cfg.indexer_types[:layer_offset])
+        if ix_tree is not None:
+            ix_xs, ix_view = layer_views(ix_tree)
     held = cfg.moe_experts_held > 0 and "moe_gate_up" in tree
     moe_whole = {}
     if held:
@@ -794,7 +956,7 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
         block = _moe_block if "moe_gate_up" in lp else _mlp_block
         return block(cfg, lp, x), no_stats
 
-    def layer_step(x, lp, kl, vl, sliding, layer, i):
+    def layer_step(x, lp, kl, vl, sliding, layer, i, dsa_call=None):
         if cos_local is not None:
             # gemma3 dual rope: sliding layers use the local table
             c = jnp.where(sliding, cos_local, cos)
@@ -816,10 +978,10 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
             m_in = _in_norm(x, lp, "mlp_norm", cfg)
             x = m_in * alpha + _mlp_block(cfg, lp, m_in, pre_normed=True)
             return x, kl, vl, obs_q, no_stats
-        attn_out, kl, vl, obs_q = _attention_block(
+        attn_out, kl, vl, obs_q, *sparse = _attention_block(
             cfg, lp, x, kl, vl, c, s_, slot0, q_slots, kv_len, kv_start,
             sliding, cache, collect_obs, bias=alibi_bias,
-            chunk_lens=chunk_lens, layer=layer,
+            chunk_lens=chunk_lens, layer=layer, dsa=dsa_call,
         )
         # minicpm depth scaling (cfg.residual_multiplier, 1.0 elsewhere)
         rm = (jnp.asarray(cfg.residual_multiplier, COMPUTE_DTYPE)
@@ -836,6 +998,8 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
             x = add(x, attn_out)
             out, stats = ffn(lp, x, i)
             x = add(x, out)
+        if dsa_call is not None:
+            return x, kl, vl, obs_q, stats, *sparse
         return x, kl, vl, obs_q, stats
 
     # a held layer's counters leave the scan as one more ``ys``, where a
@@ -845,6 +1009,27 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
 
     def split(out):
         return (out[0], out[1].sum(0)) if held else (out, no_stats)
+
+    if dsa:
+        def sparse_body(carry, xs):
+            x, k, v, sel = carry
+            lp, i, sliding, full, fi = xs
+
+            def indexer():
+                return ix_view(jax.tree_util.tree_map(
+                    lambda a: jax.lax.dynamic_index_in_dim(a, fi, 0, False),
+                    ix_xs), fi)
+
+            x, k, v, obs_q, s_i, sel, d_i = layer_step(
+                x, view(lp, i), k, v, sliding, layer_offset + i, i,
+                dsa_call=(full, indexer if ix_tree is not None else None,
+                          fi_below + fi, sel))
+            return (x, k, v, sel), (ys(obs_q, s_i), d_i)
+
+        (x, k_stack, v_stack, selection), (out, d_stats) = jax.lax.scan(
+            sparse_body, (x, k_stack, v_stack, selection),
+            (tree_xs, local, sliding_flags, is_full, fi_local))
+        return (x, k_stack, v_stack, *split(out), selection, d_stats.sum(0))
 
     if hasattr(cache, "tables"):
         def paged_body(carry, xs):
@@ -980,12 +1165,25 @@ def decoder_forward(
     valid = (_valid_positions(b, t, q_slots, kv_start, chunk_lens)
              if cfg.moe_experts_held else None)
     state = cache.state
+    selection = None
+    if cfg.index_topk:
+        if getattr(cache, "sparse", False):
+            selection = no_selection(cfg, b, t, cache.max_len)
+        elif cache.max_len > cfg.index_topk:
+            raise ValueError(
+                f"{cfg.model_type} attends over the {cfg.index_topk} tokens "
+                "its indexer selects, which only the serving path's sparse "
+                "latent pool computes (ServingEngine / build_server): this "
+                f"cache holds {cache.max_len} tokens a row and would attend "
+                "all of them.  Up to index_topk tokens every token is "
+                "selected and the dense cache is exact")
     for tree, lo, hi in stacks:
-        x, k_new, v_new, op, moe_stats = run_layers(
+        x, k_new, v_new, op, moe_stats, *sparse = run_layers(
             cfg, tree, k_new, v_new, sliding_flags[lo:hi],
             x, cos, sin, slot0, q_slots, kv_len, kv_start, cache,
             collect_obs, alibi_bias, cos_local=cos_l, sin_local=sin_l,
             chunk_lens=chunk_lens, layer_offset=lo, valid=valid,
+            selection=selection,
         )
         obs_parts.append(op)
         if state is not None and valid is not None:
@@ -993,6 +1191,15 @@ def decoder_forward(
             # ``STATE_COUNTERS``; ``src`` is the tick's read-only buffer)
             state = {k_: v_ for k_, v_ in state.items() if k_ != "src"}
             state["stats"] = state["stats"].at[:4].add(moe_stats)
+        if sparse:
+            # the tree above attends over what this one's last full layer
+            # chose; the indexer's counters follow the held layers' (kv.py
+            # ``DSA_COUNTERS``)
+            selection, dsa_stats = sparse
+            if state is not None:
+                state = {k_: v_ for k_, v_ in state.items() if k_ != "src"}
+                n0 = state["stats"].shape[0] - dsa_stats.shape[0]
+                state["stats"] = state["stats"].at[n0:].add(dsa_stats)
     obs_q = (jnp.concatenate(obs_parts) if len(obs_parts) > 1
              else obs_parts[0])
 

@@ -449,7 +449,8 @@ def _axk1(hf: dict) -> ModelConfig:
     """SKT A.X-K1: the DeepSeek-V3 block (MLA with a low-rank q, one dense
     layer then expert layers beside a shared expert) with a sigmoid router,
     the V3 group score (a group's two best) and NO score-correction bias
-    (``topk_method: "none"``; the key that carries one is ``noaux_tc``).
+    (``topk_method: "none"``; ``noaux_tc``, the key that carries one, is
+    read by ``deepseek_v3`` and, over held experts, by ``glm_moe_dsa``).
     Its expert layers are HELD layers (models/decoder.py
     ``_held_moe_block``): ``n_router_experts`` and ``first_routed_expert``
     describe a chip's share of a deployment that divides the experts, the
@@ -459,7 +460,8 @@ def _axk1(hf: dict) -> ModelConfig:
         raise ValueError(
             f"axk1: topk_method {hf['topk_method']!r} is not supported "
             "(\"none\": selection on the raw scores, no "
-            "e_score_correction_bias)")
+            "e_score_correction_bias; the held-expert family that carries "
+            "\"noaux_tc\" is glm_moe_dsa)")
     d = _deepseek_common(hf)
     held = hf["n_routed_experts"]
     d.update(
@@ -470,6 +472,70 @@ def _axk1(hf: dict) -> ModelConfig:
         moe_topk_group=hf.get("topk_group") or 0,
         moe_score_func=hf.get("scoring_func", "sigmoid"),
         moe_group_score="top2sum",
+    )
+    return ModelConfig(**d)
+
+
+def _glm_moe_dsa(hf: dict) -> ModelConfig:
+    """GLM-5.x (``glm_moe_dsa``): the DeepSeek-V3 block (MLA with a low-rank
+    q, interleaved rope on the rope slice, dense layers then sigmoid
+    ``noaux_tc`` expert layers beside a shared expert; ``n_group`` 1, so no
+    group step) with DeepSeek-V3.2's learned sparse attention on top: the
+    layers whose ``indexer_types`` entry is "full" carry an INDEXER
+    (``index_n_heads`` heads of ``index_head_dim`` over the low-rank query, a
+    LayerNormed index key a token) that keeps the ``index_topk`` best cached
+    tokens a query, and the "shared" layers after one attend over ITS
+    selection (models/decoder.py ``_mla_block``).  Expert layers are HELD
+    layers as ``_axk1`` reads them (``n_router_experts``,
+    ``first_routed_expert``).  The multi-token-prediction layer is a draft
+    head and is not built."""
+    if hf.get("topk_method", "noaux_tc") != "noaux_tc":
+        raise ValueError(
+            f"glm_moe_dsa: topk_method {hf['topk_method']!r} is not "
+            "supported (\"noaux_tc\": selection on score + "
+            "e_score_correction_bias)")
+    n = hf["num_hidden_layers"]
+    hf2 = dict(hf)
+    rp = hf.get("rope_parameters") or {}
+    if "rope_theta" not in hf2 and "rope_theta" in rp:
+        hf2["rope_theta"] = rp["rope_theta"]
+    if rp.get("rope_type", "default") != "default":
+        hf2.setdefault("rope_scaling", rp)
+    mlp_types = hf.get("mlp_layer_types")
+    n_dense = hf.get("first_k_dense_replace", 0)
+    if mlp_types is not None:
+        want = ["dense"] * n_dense + ["sparse"] * (n - n_dense)
+        if list(mlp_types) != want:
+            raise ValueError(
+                "glm_moe_dsa: mlp_layer_types must be first_k_dense_replace "
+                f"dense layers then sparse ones, got {list(mlp_types)}")
+    types = hf.get("indexer_types")
+    if types is None:
+        freq = hf.get("index_topk_freq") or 1
+        skip = hf.get("index_skip_topk_offset", n_dense)
+        types = ["full" if i < skip or (i - skip) % freq == freq - 1
+                 else "shared" for i in range(n)]
+    types = tuple(types)
+    if len(types) != n or set(types) - {"full", "shared"} \
+            or types[0] != "full":
+        raise ValueError(
+            "glm_moe_dsa: indexer_types must name every layer \"full\" or "
+            f"\"shared\" and start with a full one, got {list(types)}")
+    d = _deepseek_common(hf2)
+    held = hf["n_routed_experts"]
+    d.update(
+        num_experts=hf.get("n_router_experts", held),
+        moe_first_expert=hf.get("first_routed_expert", 0),
+        moe_experts_held=held,
+        moe_n_group=hf.get("n_group") or 0,
+        moe_topk_group=hf.get("topk_group") or 0,
+        moe_score_func=hf.get("scoring_func", "sigmoid"),
+        moe_group_score="top2sum",
+        moe_score_bias=True,
+        index_topk=hf["index_topk"],
+        index_n_heads=hf["index_n_heads"],
+        index_head_dim=hf["index_head_dim"],
+        indexer_types=types,
     )
     return ModelConfig(**d)
 
@@ -1262,6 +1328,8 @@ FAMILIES: dict[str, Family] = {
     "deepseek_v3": Family("deepseek_v3", _deepseek_v3, _DEEPSEEK_SCHEME,
                           _DEEPSEEK_V3_MOE),
     "axk1": Family("axk1", _axk1, _DEEPSEEK_SCHEME, _DEEPSEEK_MOE),
+    "glm_moe_dsa": Family("glm_moe_dsa", _glm_moe_dsa, _DEEPSEEK_SCHEME,
+                          _DEEPSEEK_V3_MOE),
     "nemotron_h": Family("nemotron_h", _nemotron_h),
     "mixtral": Family("mixtral", _mixtral, WeightScheme(), _MIXTRAL_MOE),
     "qwen2_moe": Family("qwen2_moe", _qwen2_moe, WeightScheme(), _QWEN2_MOE),

@@ -192,6 +192,23 @@ def paged_kv_write(cache, k, v, layer, new_k, new_v, pos):
         mesh=dispatch.spmd_mesh() if mode == "sharded" else None)
 
 
+@jax.named_scope("layer/kv_write")
+def paged_pool_write(cache, pool, layer, new, pos):
+    """:func:`paged_kv_write` for ONE token-major pool ``[L, P, H, page,
+    D]``: new [B, T, H, D] at ``(layer, page, :, offset)``, in place.  A
+    sparse latent pool's two kinds of page are written apart (kv.py): a
+    latent row every layer, an index key where the layer's indexer is full.
+    The same choice of backend."""
+    from ipex_llm_tpu.ops import dispatch
+
+    if dispatch.spmd_mesh() is not None or not dispatch.use_pallas(
+            _paged_op(pool)):
+        return cache.write_pool(pool, layer, new, pos)
+    from ipex_llm_tpu.ops.pallas import paged_kv_write as kernel
+
+    return kernel.paged_pool_write(cache, pool, layer, new, pos)
+
+
 def _latent_walk_xla(q_lat, q_rope, c_rows, r_rows, kv_len, chunk_lens,
                      scale):
     """The absorbed walk as XLA operations: q_lat [B, T, H, rank], q_rope
@@ -248,6 +265,162 @@ def latent_attention(q_lat, q_rope, c_pool, r_pool, cache, kv_len,
         q_lat, q_rope, cache.gather_layer(c_pool, layer)[:, 0],
         cache.gather_layer(r_pool, layer, token_minor=True)[:, 0], kv_len,
         chunk_lens, scale).astype(q_lat.dtype)
+
+
+# --- learned sparse attention over a sparse latent pool ----------------------
+# (kv.py ``pool_geometry``: a latent-attention model with an INDEXER.)  A
+# layer whose indexer is "full" scores every cached token of a query
+# (:func:`index_scores`), keeps the best ``index_topk`` (:func:`select_topk`)
+# and attends over them alone (:func:`sparse_latent_attention`); the layers
+# after it that share its selection call the third only.  What a selection IS
+# follows the call's shape: a decode row (one query) carries the chosen
+# positions, which its walk GATHERS, a token's whole key a contiguous row of
+# the pool; a chunk carries a mask over the row's slots, under which its walk
+# reads the pages in order, like the absorbed walk of a dense latent pool.
+# The chosen SET is the same function of the scores in both: the ``k``
+# largest, equal scores to the earlier position.
+
+
+@jax.named_scope("dsa/score")
+def index_scores(q_idx, w_idx, ik_pool, cache, q_slots, kv_len, *, layer):
+    """Indexer scores of a call's queries against their rows' cached index
+    keys: q_idx [B, T, J, D] (rotated), w_idx [B, T, J] float32 (the head
+    weights, scaled), the index-key pool whole with the full layer's index
+    ``layer``.  ``I[t, s] = sum_j w[t, j] relu(q[t, j] . k[s])`` in float32
+    over bf16 products, the heads added in index order (a reduction would be
+    ordered by the block's shape); -inf where query t does not see slot s
+    (``s > q_slots[t]`` or past ``kv_len``).  Returns [B, T, S] over the
+    table's whole width ``S``."""
+    f32 = jnp.float32
+    keys = cache.gather_layer(ik_pool, layer)[:, 0]               # [B, S, D]
+    b, t, n_j, _ = q_idx.shape
+    q_idx = q_idx.astype(keys.dtype)
+    if t == 1:
+        d = jnp.einsum("bjd,bsd->bjs", q_idx[:, 0], keys,
+                       preferred_element_type=f32)
+        acc = jnp.zeros((b, keys.shape[1]), f32)
+        for j in range(n_j):
+            acc = acc + w_idx[:, 0, j, None] * jnp.maximum(d[:, j], 0.0)
+        acc = acc[:, None]
+    else:
+        def head(j, acc):
+            qj = jax.lax.dynamic_index_in_dim(q_idx, j, 2, False)
+            wj = jax.lax.dynamic_index_in_dim(w_idx, j, 2, False)
+            d = jnp.einsum("btd,bsd->bts", qj, keys,
+                           preferred_element_type=f32)
+            return acc + wj[..., None] * jnp.maximum(d, 0.0)
+
+        acc = jax.lax.fori_loop(
+            0, n_j, head, jnp.zeros((b, t, keys.shape[1]), f32))
+    kpos = jnp.arange(keys.shape[1])[None, None, :]
+    seen = (kpos <= q_slots[:, :, None]) & (kpos < kv_len[:, None, None])
+    # -0.0 and 0.0 are one score
+    return jnp.where(seen, jnp.where(acc == 0.0, 0.0, acc), -jnp.inf)
+
+
+def _kth_largest(u, k: int):
+    """The ``k``-th largest of uint32 ``u`` [..., S] along its last axis, by
+    32 counting passes (one bit of the answer a pass): exact, and integer
+    counts do not depend on how a reduction is ordered."""
+    def bit(i, thr):
+        cand = thr | jnp.left_shift(jnp.uint32(1), (31 - i).astype(jnp.uint32))
+        n = jnp.sum(u >= cand[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(n >= k, cand, thr)
+
+    return jax.lax.fori_loop(0, 32, bit,
+                             jnp.zeros(u.shape[:-1], jnp.uint32))
+
+
+@jax.named_scope("dsa/select")
+def select_topk(scores, k: int):
+    """The selection of :func:`index_scores`' output [B, T, S]: a query's
+    ``k`` largest scores among the slots it sees (all of them while it sees
+    at most ``k``), equal scores to the earlier slot.  A decode call (T = 1)
+    gets ``(idx [B, k] int32, ok [B, k])``, the chosen slots and which of
+    them are real; a chunk gets the mask [B, T, S].  No sort of the row: a
+    threshold by bisection on the scores' bits, then one on the position
+    among the scores equal to it."""
+    b, t, s = scores.shape
+    k = min(k, s)
+    if t == 1:
+        vals, idx = jax.lax.top_k(scores[:, 0], k)
+        return idx.astype(jnp.int32), vals > -jnp.inf
+    seen = scores > -jnp.inf
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    # a monotone map of float32 onto uint32
+    u = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(0x80000000))
+    thr = _kth_largest(u, k)[..., None]
+    above = u > thr
+    tied = (u == thr) & seen
+    need = k - jnp.sum(above, axis=-1, dtype=jnp.int32)           # [B, T]
+    pos = jnp.arange(s, dtype=jnp.int32)
+
+    def halve(_, lohi):
+        lo, hi = lohi
+        mid = (lo + hi) // 2
+        n = jnp.sum(tied & (pos < mid[..., None]), axis=-1, dtype=jnp.int32)
+        enough = n >= need
+        return jnp.where(enough, lo, mid + 1), jnp.where(enough, mid, hi)
+
+    # the smallest bound under which ``need`` of the tied slots lie
+    bound, _ = jax.lax.fori_loop(
+        0, s.bit_length() + 1, halve,
+        (jnp.zeros((b, t), jnp.int32), jnp.full((b, t), s, jnp.int32)))
+    return seen & (above | (tied & (pos < bound[..., None])))
+
+
+def _softmax_rows(s, live, rows_v):
+    """Masked softmax of float32 scores ``s`` [..., Q, K] times the values
+    [..., K, C] (bf16 products, float32 sums); zeros where nothing is live."""
+    m = jnp.max(jnp.where(live, s, NEG_INF), axis=-1, keepdims=True)
+    p = jnp.where(live, jnp.exp(s - m), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    o = jnp.einsum("...qk,...kc->...qc", p.astype(rows_v.dtype), rows_v,
+                   preferred_element_type=jnp.float32)
+    return o / jnp.where(l > 0.0, l, 1.0)
+
+
+@jax.named_scope("dsa/attend")
+def sparse_latent_attention(q_row, pool, cache, sel, chunk_lens, kv_len, *,
+                            rank: int, scale: float, layer):
+    """Absorbed latent attention over a SELECTION (:func:`select_topk`) off a
+    sparse latent pool, which already holds this call's tokens.  q_row [B, T,
+    H, W] is the query against a pool row ``[c ; k_r ; 0]`` (``W_UK`` folded
+    in, the rotated slice beside it, zeros on the pad lanes); the value is
+    the row's first ``rank`` lanes.  A decode call gathers its chosen rows,
+    one contiguous row a token, and attends over them: what it reads is
+    bounded by the selection, not by the cache's length.  A chunk walks its
+    row's pages under the mask (ops/pallas/ragged_paged_attention.py
+    ``sparse_paged_sdpa`` on the chip, the same arithmetic over the gathered
+    pages elsewhere).  Returns o_lat [B, T, H, rank] in q's dtype."""
+    from ipex_llm_tpu.ops import dispatch
+
+    b, t = q_row.shape[:2]
+    if dispatch.spmd_mesh() is not None:
+        raise NotImplementedError(
+            "sparse latent attention under a mesh: neither the latent row "
+            "nor the index key is sharded")
+    if t == 1:
+        idx, ok = sel
+        ps = cache.page_size
+        pages = cache.route_pages(idx // ps)
+        rows = pool[layer, pages, 0, idx % ps]                    # [B, K, W]
+        s = jnp.einsum("bhw,bkw->bhk", q_row[:, 0].astype(rows.dtype), rows,
+                       preferred_element_type=jnp.float32) * scale
+        live = (ok & (chunk_lens > 0)[:, None])[:, None, :]
+        return _softmax_rows(s, live, rows[..., :rank])[:, None].astype(
+            q_row.dtype)
+    if dispatch.use_pallas(_paged_op(pool)):
+        from ipex_llm_tpu.ops.pallas import ragged_paged_attention as rpa
+
+        return rpa.sparse_paged_sdpa(q_row, pool, cache.tables, sel, kv_len,
+                                     chunk_lens, rank=rank, scale=scale,
+                                     layer=layer)
+    rows = cache.gather_layer(pool, layer)[:, 0]                  # [B, S, W]
+    s = jnp.einsum("bthw,bsw->bhts", q_row.astype(rows.dtype), rows,
+                   preferred_element_type=jnp.float32) * scale
+    o = _softmax_rows(s, sel[:, None], rows[:, None, :, :rank])   # [B,H,T,C]
+    return o.transpose(0, 2, 1, 3).astype(q_row.dtype)
 
 
 def cached_sdpa(

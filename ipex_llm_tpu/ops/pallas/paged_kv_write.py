@@ -211,6 +211,32 @@ def _write_latent(k, v, layer, pages, pos, new_c, new_r):
     return k, _write_lanes(v, layer, pages, pos, new_r)
 
 
+def _chunk_pages(cache, t: int, pos):
+    """The routed page of each page index a ``t``-token chunk from slot
+    ``pos`` [B] can touch (0 = scratch), first its page ``pos // ps``."""
+    ps = cache.page_size
+    n_pages = -(-(t - 1) // ps) + 1
+    return cache.route_pages(
+        (pos // ps)[:, None] + jnp.arange(n_pages)[None, :])
+
+
+@jax.jit
+def _write_one(pool, layer, pages, pos, new):
+    return _write_pools((pool,), layer, pages, pos, (new,))[0]
+
+
+def paged_pool_write(cache, pool, layer, new, pos):
+    """``cache.write_pool`` as a kernel: new [B, T, H, D] into ONE
+    token-major stack ``pool`` [L, P, H, page, D] at ``(layer, page, :,
+    offset)`` through ``cache.tables``, in place (a sparse latent pool's
+    latent rows, or its index keys)."""
+    b, t = new.shape[:2]
+    if getattr(pos, "ndim", 0) == 0:
+        pos = jnp.broadcast_to(pos, (b,))
+    return _write_one(pool, jnp.asarray(layer, jnp.int32),
+                      _chunk_pages(cache, t, pos), pos, cache.encode(new))
+
+
 def paged_kv_write(cache, k, v, layer, new_k, new_v, pos, mesh=None):
     """``cache.write_layer`` as a kernel: write new_k/new_v [B, T, Hkv, D]
     into the stacks ``k``/``v`` [L, P, Hkv, page, D] at ``(layer, page, :,
@@ -220,12 +246,9 @@ def paged_kv_write(cache, k, v, layer, new_k, new_v, pos, mesh=None):
     patches its heads of the same blocks; the routing is replicated.  A
     LATENT pool (``cache.latent``) takes its own two writes."""
     b, t = new_k.shape[:2]
-    ps = cache.page_size
     if getattr(pos, "ndim", 0) == 0:
         pos = jnp.broadcast_to(pos, (b,))
-    n_pages = -(-(t - 1) // ps) + 1       # pages a chunk can touch
-    pages = cache.route_pages(
-        (pos // ps)[:, None] + jnp.arange(n_pages)[None, :])
+    pages = _chunk_pages(cache, t, pos)
     args = (k, v, jnp.asarray(layer, jnp.int32), pages, pos,
             cache.encode(new_k), cache.encode(new_v))
     if cache.latent:

@@ -580,3 +580,163 @@ def latent_paged_sdpa(q_lat, q_rope, c_pool, r_pool, tables, kv_len,
         r_pool, tables, kv_len, chunk_len, jnp.asarray(layer, jnp.int32),
         scale=float(scale), heads=h)
     return o.reshape(r, c, h, rank)
+
+
+# --- the walk under a selection (a SPARSE latent pool) -----------------------
+#
+# A latent-attention model with an indexer (kv.py ``pool_geometry``) keeps a
+# token's whole key in ONE row of its pool, ``[c ; k_r ; 0]`` padded to whole
+# lanes, and a chunk's query attends only the slots its selection names
+# (ops/attention.py ``select_topk``: a mask over the row's slots).  The walk
+# is the absorbed walk above with two changes: a trip's scores are ONE dot of
+# the query row against the page rows as they lie (the pad lanes are zeros on
+# both sides), and the causal bound is replaced by the selection's mask, which
+# already lies inside it.  A query's arithmetic is a function of its own
+# ``q``, its own mask row and the pages in order: the same invariance.
+
+
+def _sparse_kernel(tables_ref, len_ref, chunk_ref, layer_ref, q_ref, sel_ref,
+                   k_hbm, o_ref, kbuf, sem, m_ref, l_ref, acc_ref, *, scale,
+                   ps, pp, heads, rank, compute_dtype):
+    """One (row, block of queries) grid step: query row j of the block is
+    token ``j // heads`` of the block's tokens, whose mask rows are
+    ``sel_ref`` [tokens, S]."""
+    r = pl.program_id(0)
+    kv_len = len_ref[r]
+    chunk = chunk_ref[r]
+    layer = layer_ref[0]
+    n_pages = jnp.where(
+        chunk > 0, jnp.minimum(pl.cdiv(kv_len, ps), tables_ref.shape[1]), 0)
+    n_trips = pl.cdiv(n_pages, pp)
+    span = pp * ps
+
+    def trip_copies(i, slot):
+        copies = []
+        for j in range(pp):
+            page = tables_ref[r, jnp.minimum(i * pp + j, n_pages - 1)]
+            copies.append(pltpu.make_async_copy(
+                k_hbm.at[layer, page, 0], kbuf.at[slot, pl.ds(j * ps, ps)],
+                sem.at[slot, j]))
+        return copies
+
+    @pl.when(n_pages == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_pages > 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        for cp in trip_copies(0, 0):
+            cp.start()
+        q = q_ref[0]
+        nq = q.shape[0]
+        ntok = nq // heads
+
+        def trip(i, carry):
+            slot = jax.lax.rem(i, 2)
+
+            @pl.when(i + 1 < n_trips)
+            def _():
+                for cp in trip_copies(i + 1, 1 - slot):
+                    cp.start()
+
+            for cp in trip_copies(i, slot):
+                cp.wait()
+            rows = kbuf[slot]                                  # [span, W]
+            s = jax.lax.dot_general(
+                q, rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale     # [nq, span]
+            chosen = sel_ref[0, :, pl.ds(pl.multiple_of(i * span, span),
+                                         span)]                 # [ntok, span]
+            kpos = i * span + jax.lax.broadcasted_iota(
+                jnp.int32, (ntok, span), 1)
+            # a trip's tail past the row's last live page re-reads that page
+            live = (chosen > 0) & (kpos < kv_len)
+            live = jnp.broadcast_to(
+                live[:, None, :], (ntok, heads, span)).reshape(nq, span)
+            # an unchosen slot adds nothing, whatever the running max is
+            _attend_trip(jnp.where(live, s, NEG_INF), rows[:, :rank], m_ref,
+                         l_ref, acc_ref, compute_dtype)
+            return carry
+
+        jax.lax.fori_loop(0, n_trips, trip, None)
+        denom = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "heads", "rank"))
+def _sparse(q, k_pool, tables, sel, kv_len, chunk_len, layer, *, scale, heads,
+            rank):
+    """q [R, C * heads, W] (a row's queries, token-major); k_pool [L, P, 1,
+    ps, W]; sel [R, C, S] int32 over the table's width S.  Returns o [R, C *
+    heads, rank] in q.dtype."""
+    r, nq, width = q.shape
+    n_pages, ps = k_pool.shape[1], k_pool.shape[3]
+    pp = min(_LATENT_PAGES_PER_TRIP, tables.shape[1])
+    span = pp * ps
+    # whole tokens a block, eight of them at least (the mask block's sublanes)
+    qb = min(_LATENT_Q_BLOCK, _round_up(nq, 8 * heads))
+    qb = max(qb // (8 * heads), 1) * 8 * heads
+    nq_pad = _round_up(nq, qb)
+    if nq_pad != nq:
+        q = jnp.pad(q, ((0, 0), (0, nq_pad - nq), (0, 0)))
+    s_pad = _round_up(sel.shape[2], span)
+    sel = jnp.pad(sel, ((0, 0), (0, nq_pad // heads - sel.shape[1]),
+                        (0, s_pad - sel.shape[2])))
+    tables = jnp.clip(tables, 0, n_pages - 1).astype(jnp.int32)
+
+    def q_map(ri, qi, *_):
+        return (ri, qi, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(r, nq_pad // qb),
+        in_specs=[
+            pl.BlockSpec((1, qb, width), q_map),
+            pl.BlockSpec((1, qb // heads, s_pad), q_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, qb, rank), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, span, width), k_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, pp)),
+            pltpu.VMEM((qb, 1), jnp.float32),
+            pltpu.VMEM((qb, 1), jnp.float32),
+            pltpu.VMEM((qb, rank), jnp.float32),
+        ],
+    )
+    o = pl.pallas_call(
+        functools.partial(_sparse_kernel, scale=scale, ps=ps, pp=pp,
+                          heads=heads, rank=rank, compute_dtype=jnp.bfloat16),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((r, nq_pad, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=48 * 2**20,
+        ),
+        interpret=_interpret(),
+        name="ragged_paged_attention_sparse",
+    )(tables, kv_len.astype(jnp.int32), chunk_len.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), q, sel, k_pool)
+    return o[:, :nq]
+
+
+def sparse_paged_sdpa(q_row, k_pool, tables, sel, kv_len, chunk_len=None, *,
+                      rank: int, scale: float, layer=None):
+    """The absorbed walk under a selection.  q_row [R, C, H, W] against the
+    sparse latent pool's rows ``[c ; k_r ; 0]`` (whole with ``layer``, or one
+    layer without); sel [R, C, S] which slots each query attends (within
+    what it sees); kv_len / chunk_len as in :func:`latent_paged_sdpa`.
+    Returns o_lat [R, C, H, rank] in q's dtype."""
+    r, c, h, width = q_row.shape
+    if layer is None:
+        k_pool, layer = k_pool[None], 0
+    if chunk_len is None:
+        chunk_len = jnp.full((r,), c, jnp.int32)
+    o = _sparse(q_row.reshape(r, c * h, width), k_pool, tables,
+                sel.astype(jnp.int32), kv_len, chunk_len,
+                jnp.asarray(layer, jnp.int32), scale=float(scale), heads=h,
+                rank=rank)
+    return o.reshape(r, c, h, rank)

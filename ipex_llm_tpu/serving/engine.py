@@ -42,8 +42,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ipex_llm_tpu.hostutil import d2h, h2d, upload
-from ipex_llm_tpu.kv import (STATE_COUNTERS, PagedKVCache, model_page_bytes,
-                             recurrent_state, state_bytes,
+from ipex_llm_tpu.kv import (PagedKVCache, index_page_bytes, model_page_bytes,
+                             recurrent_state, state_bytes, state_counters,
                              with_recurrent_state)
 from ipex_llm_tpu.models.config import ModelConfig
 from ipex_llm_tpu.models.decoder import decoder_forward
@@ -1011,6 +1011,7 @@ class ServingEngine:
         # kv_storage, raising with the valid names)
         # (a latent-attention model's page holds the latent, one row a
         # token shared by every head: kv.py ``pool_geometry``)
+        self._index_page_bytes = index_page_bytes(cfg, self.ec.page_size)
         self.page_bytes = model_page_bytes(cfg, self.ec.page_size,
                                            self.ec.kv_storage)
         if self.ec.kv_pool_bytes:
@@ -1110,9 +1111,12 @@ class ServingEngine:
         if cfg.is_mla and mesh is not None and mesh.size > 1:
             raise ValueError(
                 f"model_type {cfg.model_type!r} attends over a latent "
-                "(MLA) pool: a mesh is not supported, the latent row is "
-                "shared by every head and neither tick shards it "
-                "(parallel/manual.py)")
+                "(MLA) pool: a mesh is not supported, the latent row"
+                + (" and the indexer's key are" if cfg.index_topk
+                   else " is")
+                + " shared by every head and neither tick shards "
+                + ("them" if cfg.index_topk else "it")
+                + " (parallel/manual.py)")
         cache = PagedKVCache.for_model(
             cfg, self.ec.n_pages, r, self.ec.max_pages, self.ec.page_size,
             storage=self.ec.kv_storage)
@@ -1124,7 +1128,8 @@ class ServingEngine:
         # refused here by name (derived from the model, no config field)
         self._recurrent = cfg.ssm_layers > 0
         self._state_spare = self._state_prev = None
-        self._stats_seen = np.zeros((len(STATE_COUNTERS),), np.uint32)
+        self._state_counters = state_counters(cfg)
+        self._stats_seen = np.zeros((len(self._state_counters),), np.uint32)
         if self._recurrent:
             self._refuse_for_recurrent_state(cfg, mesh)
         # (a model whose expert layers hold a share of their experts and
@@ -1471,7 +1476,7 @@ class ServingEngine:
             seen = arr[1:].astype(np.uint32)
             delta = (seen - self._stats_seen).astype(np.uint32)
             self._stats_seen = seen
-            for name, d in zip(STATE_COUNTERS, delta):
+            for name, d in zip(self._state_counters, delta):
                 self.metrics[name + "_total"] = (
                     self.metrics.get(name + "_total", 0) + int(d))
         return int(arr[0])
@@ -1576,8 +1581,12 @@ class ServingEngine:
             # a latent-attention model's page holds ONE row a token a
             # layer, shared by every head (0: the pages hold K and V)
             "latent_bytes_per_token": (
-                self.page_bytes // (self.ec.page_size * self.cfg.kv_layers)
+                (self.page_bytes - self._index_page_bytes)
+                // (self.ec.page_size * self.cfg.kv_layers)
                 if self.cache.latent else 0),
+            # a model with an indexer keeps a third kind of page under the
+            # same block table: the index keys of its full layers (0: none)
+            "index_cache_bytes": a.n_pages * self._index_page_bytes,
             "prefix_pages_cached": len(a.prefix),
             "prefix_evictions": a.prefix_evictions,
             "alloc_fail_clamps": self.metrics.get("alloc_fail_clamps", 0),
@@ -1696,7 +1705,36 @@ class ServingEngine:
                     for i, _, _ in chunks)
         self.metrics["attn_pages_live_total"] += live
         self.metrics["attn_pages_table_total"] += table
-        return {"attn_pages_live": live, "attn_pages_table": table}
+        out = {"attn_pages_live": live, "attn_pages_table": table}
+        if self.cfg.index_topk:
+            out.update(self._count_selection(active, decode, chunks, base,
+                                             n_valid))
+        return out
+
+    def _count_selection(self, active, decode, chunks, base, n_valid) -> dict:
+        """What the same tick asks of a model's INDEXER and of its sparse
+        walk, from the same lengths, the same lower bound: a query at slot
+        ``p`` has ``p + 1`` index keys scored in every layer whose indexer
+        is full (``dsa_keys_scored``) and attends ``min(p + 1, index_topk)``
+        cached tokens in every layer (``dsa_tokens_selected``; of them
+        ``dsa_decode_selected`` by decode rows, which gather a row a token).
+        The device's own counts are the ``dsa_*_total`` counters; these ride
+        the ``engine/dispatch`` span for the trace's readers."""
+        top = self.cfg.index_topk
+        seen = sel = dec = 0
+        if decode:
+            lens = self.row_lens[active].astype(np.int64) + 1
+            seen += int(lens.sum())
+            dec = int(np.minimum(lens, top).sum())
+        for i, _, _ in chunks:
+            slots = int(base[i]) + 1 + np.arange(int(n_valid[i]),
+                                                 dtype=np.int64)
+            seen += int(slots.sum())
+            sel += int(np.minimum(slots, top).sum())
+        layers = self.cfg.kv_layers
+        return {"dsa_keys_scored": seen * self.cfg.index_layers,
+                "dsa_tokens_selected": (sel + dec) * layers,
+                "dsa_decode_selected": dec * layers}
 
     def _perf_dispatch(self, family: str, point: dict | None = None,
                        tick: bool = True, **attrs):
