@@ -512,8 +512,13 @@ class PagedKVCache:
 # (they ride the state, so a rolled-back tick's are dropped with it); the
 # engine reads them on the fetch it already makes and publishes them under
 # these names with ``_total`` appended
-STATE_COUNTERS = ("moe_pairs_routed", "moe_pairs_local", "moe_experts_hit",
-                  "moe_layer_calls", "ssm_state_resets")
+# what a held expert layer counts a call (decoder._held_moe_block): pairs
+# routed, pairs that landed on a held expert, experts hit, 1, and the rows
+# its expert kernel multiplied (ops/moe.py ``moe_held``: hit experts x the
+# block's rows, or live row tiles x the tile where rows are grouped)
+MOE_COUNTERS = ("moe_pairs_routed", "moe_pairs_local", "moe_experts_hit",
+                "moe_layer_calls", "moe_rows_computed")
+STATE_COUNTERS = MOE_COUNTERS + ("ssm_state_resets",)
 # and what a model with an indexer counts besides, over its attending
 # queries: index keys scored (full layers), positions a dense walk would
 # read and positions read (every layer), layer calls by indexer type

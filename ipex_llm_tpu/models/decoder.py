@@ -23,7 +23,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ipex_llm_tpu.kv import KVCache
+from ipex_llm_tpu.kv import MOE_COUNTERS, STATE_COUNTERS, KVCache
 from ipex_llm_tpu.models.config import ModelConfig
 from ipex_llm_tpu.ops import linear as linear_ops
 from ipex_llm_tpu.ops import mlp as mlp_ops
@@ -595,7 +595,8 @@ def _held_moe_block(cfg: ModelConfig, lp: dict, x, valid, shared: bool = True,
     With ``layer`` the two expert stacks are the whole model's ``[E blocks,
     held, ...]`` and that block's experts are read in place.
 
-    Returns (out, [pairs routed, pairs local, experts hit, 1])."""
+    Returns (out, [pairs routed, pairs local, experts hit, 1, rows the
+    expert kernel multiplied]): ``kv.MOE_COUNTERS``."""
     from ipex_llm_tpu.ops import moe as moe_ops
 
     b, t, hidden = x.shape
@@ -613,10 +614,11 @@ def _held_moe_block(cfg: ModelConfig, lp: dict, x, valid, shared: bool = True,
             * jax.nn.one_hot(jnp.clip(local, 0, held - 1), held,
                              dtype=jnp.float32), axis=-2)    # [B, T, held]
     with jax.named_scope("moe/experts"):
-        out, n_hit = moe_ops.moe_held(
+        out, n_hit, rows = moe_ops.moe_held(
             h.reshape(b * t, hidden), gate.reshape(b * t, held),
             lp["moe_gate_up"], lp["moe_down"], cfg.act, gated=cfg.mlp_gated,
-            layer=layer)
+            layer=layer, top_k=cfg.num_experts_per_tok,
+            n_routed=cfg.num_experts)
         out = out.reshape(b, t, hidden)
     if shared and "shared_gate_up" in lp:
         with jax.named_scope("moe/shared"):
@@ -631,7 +633,7 @@ def _held_moe_block(cfg: ModelConfig, lp: dict, x, valid, shared: bool = True,
     stats = jnp.stack([
         jnp.sum(valid).astype(jnp.int32) * cfg.num_experts_per_tok,
         jnp.sum(mine).astype(jnp.int32), n_hit,
-        jnp.asarray(1, jnp.int32)])
+        jnp.asarray(1, jnp.int32), rows])
     return out.astype(x.dtype), stats
 
 
@@ -673,7 +675,9 @@ def run_hybrid(cfg: ModelConfig, tree: dict, cache, x, slot0, q_slots,
     src = state.pop("src", None)
     if fresh is not None:
         resets = jnp.sum(fresh & (jnp.sum(valid, axis=1) > 0))
-        state["stats"] = state["stats"].at[4].add(resets.astype(jnp.int32))
+        state["stats"] = state["stats"].at[
+            STATE_COUNTERS.index("ssm_state_resets")].add(
+                resets.astype(jnp.int32))
     no_window = jnp.asarray(False)
 
     def mamba(x, lp, state, m):
@@ -702,7 +706,7 @@ def run_hybrid(cfg: ModelConfig, tree: dict, cache, x, slot0, q_slots,
     def experts(x, lp, state, layer=None):
         out, stats = _held_moe_block(cfg, lp, x, valid, layer=layer)
         return x + out, dict(
-            state, stats=state["stats"].at[:4].add(stats))
+            state, stats=state["stats"].at[:stats.shape[0]].add(stats))
 
     def entry(stack, i):
         # the quantized weights as views of their stacks, the rest sliced
@@ -889,8 +893,8 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
     inside shard_map.  ``k_stack``/``v_stack`` are the cache's stacks, of
     which the tree's ``n`` layers start at ``layer_offset`` (a two-stack
     model's second tree starts past the first).  Returns (x, k_new, v_new,
-    obs_q, moe_stats) with the stacks whole again; ``moe_stats`` int32 [4]
-    is what the tree's HELD expert layers counted (``_held_moe_block``),
+    obs_q, moe_stats) with the stacks whole again; ``moe_stats`` int32
+    (``kv.MOE_COUNTERS``) is what the tree's HELD expert layers counted,
     zeros where it has none.
 
     How the cache rides the scan follows its class.  A dense cache
@@ -947,7 +951,7 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
         tree = {k_: v_ for k_, v_ in tree.items() if k_ not in moe_whole}
     tree_xs, view = layer_views(tree)
     local = jnp.arange(n, dtype=jnp.int32)    # a layer's index in ``tree``
-    no_stats = jnp.zeros((4,), jnp.int32)
+    no_stats = jnp.zeros((len(MOE_COUNTERS),), jnp.int32)
 
     def ffn(lp, x, i):
         if held:
@@ -1190,7 +1194,8 @@ def decoder_forward(
             # the held expert layers' device counters (kv.py
             # ``STATE_COUNTERS``; ``src`` is the tick's read-only buffer)
             state = {k_: v_ for k_, v_ in state.items() if k_ != "src"}
-            state["stats"] = state["stats"].at[:4].add(moe_stats)
+            state["stats"] = state["stats"].at[:moe_stats.shape[0]].add(
+                moe_stats)
         if sparse:
             # the tree above attends over what this one's last full layer
             # chose; the indexer's counters follow the held layers' (kv.py

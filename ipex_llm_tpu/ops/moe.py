@@ -227,7 +227,110 @@ def _expert_act(inner, act, gated):
     return mlp_ops.act(inner, act)
 
 
-def moe_held(h, gate, gate_up, down, act, gated=True, layer=None):
+def _weighed(g, y):
+    """``g * y`` (g float32, y bf16) rounded to float32 ONCE, whatever the
+    compiler does with the sum it then joins: a backend that contracts a
+    product and the add after it into one fused multiply-add (XLA's CPU
+    does, inside a loop body and not beside it) would skip the product's
+    rounding in one form of ``moe_held`` and not in the other.  The gate's
+    top 8 significant bits and its rest each times a bf16 value are EXACT
+    in float32, and their sum rounds once, to ``round(g * y)``."""
+    bits = jax.lax.bitcast_convert_type(g, jnp.uint32) & jnp.uint32(0xFFFF0000)
+    hi = jax.lax.bitcast_convert_type(bits, jnp.float32)
+    y = y.astype(jnp.float32)
+    return hi * y + (g - hi) * y
+
+
+def row_tile(n: int, top_k: int, n_routed: int) -> int:
+    """The row tile of a block of ``n`` tokens whose rows are grouped by
+    expert: the rows an expert expects (``top_k`` of ``n_routed`` experts a
+    token) plus two standard deviations of a count that size, in whole bf16
+    sublane tiles (16), at most the MXU's 128.  A tile is one pass over an
+    expert's planes whatever it holds: an expert that outgrows its tile
+    pays a second pass (a tile of the MEAN would make four experts in ten
+    pay it), an emptier tile only its rows' share of the matmul (on a v5e
+    a 512-token chunk of A.X-K1's or GLM-5.2's reads 1.25 / 1.33 ms a layer
+    at 32 rows, 1.90 / 1.71 at 16, 1.55 / 1.67 at 128: PERF.md, PR 41)."""
+    mean = n * top_k / n_routed
+    return min(128, _round_up(max(1, round(mean + 2 * mean ** 0.5)), 16))
+
+
+def _held_by_slot(h, gate, gate_up, down, act, gated, first):
+    """``moe_held`` for a block of ONE row tile (N <= 128): slot s of the
+    kernel's grid is the s-th hit expert and multiplies the block's N rows
+    as they lie, so an expert's planes are dequantized once a projection.
+    Returns (out, rows multiplied)."""
+    from ipex_llm_tpu.ops.pallas.qmatmul import qmatmul_pallas_grouped
+
+    n, n_held = gate.shape
+    hit = jnp.any(gate != 0, axis=0)                     # [E_held]
+    n_hit = jnp.sum(hit).astype(jnp.int32)
+    order = jnp.argsort(~hit, stable=True).astype(jnp.int32)   # hit ones first
+    inner = qmatmul_pallas_grouped(h, gate_up, order, n_hit, first, n_held)
+    a = _expert_act(inner.astype(h.dtype), act, gated)
+    y = qmatmul_pallas_grouped(a, down, order, n_hit, first, n_held)
+    # the slots past the hit ones were not in the grid: nothing was written
+    live = (jnp.arange(n_held) < n_hit)[:, None, None]
+    y = jnp.where(live, y, 0.0).astype(h.dtype)
+    g_slots = gate[:, order].T[..., None]                # [E_held, N, 1]
+    out = _weighed(g_slots[0], y[0])
+    for s in range(1, n_held):                           # one fusion, in order
+        out = out + _weighed(g_slots[s], y[s])
+    return out, n_hit * n
+
+
+def _held_by_rows(h, gate, gate_up, down, act, gated, first, bm, kmax):
+    """``moe_held`` for a block of MORE rows than one tile (a prefill
+    chunk): the routed PAIRS are multiplied, not tokens x hit experts.
+    Every pair gets a row in an expert-major layout (expert e's rows start
+    at a multiple of ``bm``; a pair's place in its group is the running
+    count of ``gate[:, e] != 0``: ascending token order, no sort), the
+    kernel runs over the live row tiles, each naming its expert, and the
+    activation and the down projection stay in that layout.  The combine
+    reads each pair's row once: a token's held experts in ascending order,
+    term by term in float32.  ``kmax`` bounds the pairs a token can have;
+    the static tile count is the worst case ``cdiv(N * kmax, bm) +
+    E_held``, of which only the live tiles are in the kernel's grid.
+    Returns (out, rows multiplied)."""
+    from ipex_llm_tpu.ops.pallas.qmatmul import qmatmul_pallas_grouped
+
+    n, n_held = gate.shape
+    nz = gate != 0
+    seen = jnp.cumsum(nz, axis=0, dtype=jnp.int32)       # [N, E] running count
+    tiles = -(-seen[-1] // bm)                           # [E] row tiles each
+    tile_end = jnp.cumsum(tiles)
+    tile_first = tile_end - tiles
+    n_live = tile_end[-1]
+    t = jnp.arange(-(-n * kmax // bm) + n_held, dtype=jnp.int32)
+    tile_e = jnp.minimum(
+        jnp.sum(tile_end[None, :] <= t[:, None], axis=1), n_held - 1)
+    # place p of expert e holds the token that has p routed before it
+    place = ((t - tile_first[tile_e]) * bm)[:, None] + jnp.arange(bm)
+    row_tok = jnp.sum(seen.T[tile_e][:, None, :] <= place[:, :, None],
+                      axis=-1)                           # [T, bm]; N: padding
+    x = jnp.pad(h, ((0, 1), (0, 0)))[row_tok]            # [T, bm, H]
+    inner = qmatmul_pallas_grouped(x, gate_up, tile_e, n_live, first, n_held)
+    a = _expert_act(inner.astype(h.dtype), act, gated)
+    y = qmatmul_pallas_grouped(a, down, tile_e, n_live, first, n_held)
+    y = y.reshape(-1, y.shape[-1])                       # [T x bm, H]
+
+    row = (tile_first * bm)[None, :] + seen - 1          # [N, E] a pair's row
+    rank = jnp.cumsum(nz, axis=1, dtype=jnp.int32) - 1   # among the token's
+
+    def term(j, out):
+        pick = nz & (rank == j)                          # one expert a token
+        y_j = y[jnp.sum(jnp.where(pick, row, 0), axis=1)].astype(h.dtype)
+        g_j = jnp.sum(jnp.where(pick, gate, 0.0), axis=1, keepdims=True)
+        return out + jnp.where(jnp.any(pick, axis=1, keepdims=True),
+                               _weighed(g_j, y_j), 0.0)
+
+    out = jax.lax.fori_loop(0, jnp.max(rank) + 1, term,
+                            jnp.zeros((n, y.shape[-1]), jnp.float32))
+    return out, n_live * bm
+
+
+def moe_held(h, gate, gate_up, down, act, gated=True, layer=None,
+             top_k=None, n_routed=None):
     """The part of an expert layer that the experts HELD here contribute:
     h [N, H]; gate [N, E_held] float32, token n's weight for held expert e
     and 0 where it is not routed there (a pair that lands on an absent
@@ -235,44 +338,57 @@ def moe_held(h, gate, gate_up, down, act, gated=True, layer=None):
     stacks [E_held, ...], or with ``layer`` (traced) the whole model's [L,
     E_held, ...], of which that layer is read in place: a scan that sliced
     a layer's stack out as its ``xs`` would copy every held expert's planes
-    every call.  Returns (out [N, H] float32, experts hit).
+    every call.  ``top_k`` of ``n_routed`` experts is what a token was
+    routed to (static; E_held of E_held where not told: every token
+    everywhere).  Returns (out [N, H] float32, experts hit, rows the expert
+    matmuls ran over).
 
-    One pass over the experts that are HIT, in ascending order, each
-    computed for every token and weighed by its gate column: no pair is
+    One pass over the experts that are HIT, in ascending order: no pair is
     dropped whatever the imbalance, the stack is never dequantized at full
-    width, and the weight traffic is the hit experts' planes once.  A
-    token's sum runs over its own experts in ascending order with exact
-    zeros between, so it does not depend on which other tokens share the
-    call.  On the chip the pass is two calls of the grouped dequant-matmul
-    kernel (every hit expert's planes read in place through its index
-    maps, the experts that are not hit skipped); elsewhere a loop that
-    slices one expert's planes at a time."""
-    n_held = gate.shape[1]
+    width, and the weight traffic is the hit experts' planes.  On the chip
+    the pass is two calls of the grouped dequant-matmul kernel (every hit
+    expert's planes read in place through its index maps, the experts that
+    are not hit left out of the grid), in one of two forms chosen by the
+    block's STATIC N alone.  N <= 128 (a decode stage, a short prefill
+    block): the block is one row tile, every hit expert multiplies all of
+    it and a gate column weighs the result (``_held_by_slot``); grouping
+    rows there would add a gather and save nothing, a hit expert's planes
+    are dequantized once either way.  N > 128 (a prefill chunk): the rows
+    are grouped by expert into tiles of ``row_tile`` rows and only routed
+    pairs are multiplied (``_held_by_rows``).  Elsewhere a loop that slices
+    one expert's planes at a time.
+
+    A token gets the SAME BITS from every form, so it does not matter that
+    which one it meets depends on the block it rides in (512 tokens of a
+    chunk, the rows of a decode stage) or on where its prompt was chunked:
+    a matmul row's bits depend on its own K sweep and not on the tile's
+    height or the rows beside it, everything between the two calls is
+    element-wise a row, and a token's sum runs over its own experts in
+    ascending order, term by term in float32, with exact zeros (or nothing)
+    between."""
+    n, n_held = gate.shape
     first = 0
     if layer is not None:
         gate_up, down = (jax.tree_util.tree_map(
             lambda a: a.reshape((-1,) + a.shape[2:]), w)
             for w in (gate_up, down))
         first = layer * n_held
+
     hit = jnp.any(gate != 0, axis=0)                     # [E_held]
     n_hit = jnp.sum(hit).astype(jnp.int32)
-    order = jnp.argsort(~hit, stable=True).astype(jnp.int32)   # hit ones first
 
     if _grouped_kernel_covers(gate_up) and _grouped_kernel_covers(down):
-        from ipex_llm_tpu.ops.pallas.qmatmul import qmatmul_pallas_grouped
+        if n <= 128:
+            out, rows = _held_by_slot(h, gate, gate_up, down, act, gated,
+                                      first)
+        else:
+            top_k, n_routed = top_k or n_held, n_routed or n_held
+            out, rows = _held_by_rows(
+                h, gate, gate_up, down, act, gated, first,
+                row_tile(n, top_k, n_routed), min(top_k, n_held))
+        return out, n_hit, rows
 
-        # slot g computes expert order[g]; the dead slots name the last
-        # live one again (nothing is fetched for them) and give zeros
-        slots = jnp.minimum(jnp.arange(n_held), jnp.maximum(n_hit - 1, 0))
-        ids = first + order[slots]
-        inner = qmatmul_pallas_grouped(h, gate_up, ids, n_hit)
-        a = _expert_act(inner.astype(h.dtype), act, gated)
-        y = qmatmul_pallas_grouped(a, down, ids, n_hit).astype(h.dtype)
-        g_slots = gate[:, order].T                       # [E_held, N]
-        out = g_slots[0][:, None] * y[0].astype(jnp.float32)
-        for s in range(1, n_held):                       # one fusion, in order
-            out = out + g_slots[s][:, None] * y[s].astype(jnp.float32)
-        return out, n_hit
+    order = jnp.argsort(~hit, stable=True).astype(jnp.int32)   # hit ones first
 
     def one(i, acc):
         e = order[i]
@@ -283,7 +399,7 @@ def moe_held(h, gate, gate_up, down, act, gated=True, layer=None):
         return acc + g_e * y.astype(jnp.float32)
 
     acc = jnp.zeros((h.shape[0], down.shape[-1]), jnp.float32)
-    return jax.lax.fori_loop(0, n_hit, one, acc), n_hit
+    return jax.lax.fori_loop(0, n_hit, one, acc), n_hit, n_hit * n
 
 
 def moe_ffn(h, w, idx, gate_up, down, act, n_experts: int, gated=True,

@@ -299,99 +299,93 @@ def _f16_bits_to_f32(h):
     return sign * jnp.where(mag >= 0x400, normal, subnormal)
 
 
-def _grouped_kernel(ids_ref, live_ref, x_ref, d_ref, s_ref, o_ref, *, qtype,
+def _grouped_kernel(ids_ref, first_ref, x_ref, d_ref, s_ref, o_ref, *, qtype,
                     bs, bk, bn, compute_dtype):
-    del ids_ref
-    g, ki = pl.program_id(0), pl.program_id(3)
+    del ids_ref, first_ref                    # the index maps' alone
+    ki = pl.program_id(2)
 
     @pl.when(ki == 0)
     def _():
         o_ref[:] = jnp.zeros_like(o_ref)
 
-    @pl.when(g < live_ref[0])
-    def _():
-        scales = _f16_bits_to_f32(s_ref[:].astype(jnp.int32))
-        w = _dequant_tile(d_ref[:], scales, None, qtype, bs, bk,
-                          bn).astype(compute_dtype)
-        o_ref[:] += jnp.dot(x_ref[:].astype(compute_dtype), w,
-                            preferred_element_type=jnp.float32)
+    scales = _f16_bits_to_f32(s_ref[:].astype(jnp.int32))
+    w = _dequant_tile(d_ref[:], scales, None, qtype, bs, bk,
+                      bn).astype(compute_dtype)
+    o_ref[:] += jnp.dot(x_ref[:].astype(compute_dtype), w,
+                        preferred_element_type=jnp.float32)
 
 
 @functools.partial(
     jax.jit, static_argnames=("qtype", "bs", "logical_out", "compute_dtype")
 )
-def _qmatmul_grouped(x, data, scale_bits, ids, n_live, *, qtype: str, bs: int,
-                     logical_out: int, compute_dtype):
-    """One call over a GROUP of entries of a stack of planes (an expert
-    layer's hit experts): ``data`` [E, K/2, N] stays whole in HBM and slot
-    ``g`` of the grid's first axis reads entry ``ids[g]`` of it through the
-    index maps; ``scale_bits`` [G, K/bs, N] are the slots' own fp16 scales
-    as uint16 bit patterns (Mosaic takes no fp16 argument on this chip, and
-    a bitcast of the WHOLE scale stack is a pass over it every tick, so the
-    caller gathers the slots' planes, a twelfth of the codes' bytes).
-    Only the first ``n_live`` slots are live: a dead slot computes nothing,
-    writes zeros, and its index maps name the block the step before it
-    held, so nothing is fetched for it.  ``x`` is [M, K] (every slot reads
-    it) or [G, M, K] (slot g reads its own).  Returns [G, M, logical_out]
+def _qmatmul_grouped(x, data, scale_bits, ids, n_live, first, *, qtype: str,
+                     bs: int, logical_out: int, compute_dtype):
+    """One call over ROW TILES that each name an entry of a stack of planes
+    (an expert layer's hit experts): ``data`` [E, K/2, N] stays whole in HBM
+    and tile ``t`` of the grid's first axis multiplies its rows by entry
+    ``first + ids[t]`` of it, read in place through the index maps;
+    ``scale_bits`` [G, K/bs, N] are the fp16 scales of the group's entries
+    ``first .. first + G`` as uint16 bit patterns, of which the tile reads
+    plane ``ids[t]`` (Mosaic takes no fp16 argument on this chip, and a
+    bitcast of the WHOLE scale stack is a pass over it every tick, so the
+    caller cuts the group's planes out, a twelfth of the codes' bytes).
+    ``x`` is [bm, K] (every tile reads it) or [T, bm, K] (tile t its own).
+
+    The grid is (``n_live``, N tiles, K tiles) and its first bound is the
+    TRACED count of live tiles: the tiles after them are not in the grid, so
+    nothing is fetched or computed for them and their rows of the result
+    are NOT WRITTEN (a caller reads live tiles' rows only).  Every tile
+    streams and dequantizes its entry's planes once, so a caller sizes its
+    tiles so that an entry seldom needs a second (``ops/moe.py``
+    ``row_tile``).  A row's bits depend on its own K sweep alone: not on
+    ``bm``, nor on the rows that share its tile (measured on the chip at 16
+    to 512 rows a tile: PERF.md, PR 41).  Returns [T, bm, logical_out]
     float32.  The planes tile exactly (padded at build)."""
-    per_slot = x.ndim == 3
-    m, k = x.shape[-2:]
-    slots, n = ids.shape[0], data.shape[2]
-    bm = min(128, _round_up(m, 16))
+    per_tile = x.ndim == 3
+    bm, k = x.shape[-2:]
+    tiles, n = ids.shape[0], data.shape[2]
     bk, kp, bn, np_ = tile_plan(k, n, bs)
     if (kp, np_) != (k, n) or data.shape[1] * 2 != k:
         raise ValueError(
             f"stacked planes [{data.shape[1] * 2}, {n}] are not padded to "
             f"the kernel's tiles ({kp}, {np_}): pad them at build "
             "(quantize.core.pad_planes)")
-    mp = _round_up(m, bm)
-    if mp != m:
-        x = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, mp - m), (0, 0)])
-    nk, nn = kp // bk, np_ // bn
+    if bm % 16:
+        raise ValueError(f"a row tile of {bm} rows is not whole bf16 "
+                         "sublane tiles (16)")
 
-    def held(g, live, i, last):
-        # a dead slot stays on the last block of the last live one
-        return jnp.where(g < live[0], i, last)
-
-    def plane(g, mi, ni, ki, ids, live):
-        return ids[g], held(g, live, ki, nk - 1), held(g, live, ni, nn - 1)
-
-    def slot_plane(g, mi, ni, ki, ids, live):
-        return (jnp.minimum(g, jnp.maximum(live[0] - 1, 0)),
-                held(g, live, ki, nk - 1), held(g, live, ni, nn - 1))
-
-    if per_slot:
-        x_spec = pl.BlockSpec(
-            (None, bm, bk), lambda g, mi, ni, ki, ids, live: (
-                jnp.minimum(g, jnp.maximum(live[0] - 1, 0)), mi,
-                held(g, live, ki, nk - 1)))
+    if per_tile:
+        x_spec = pl.BlockSpec((None, bm, bk),
+                              lambda t, ni, ki, ids, first: (t, 0, ki))
     else:
-        x_spec = pl.BlockSpec(
-            (bm, bk), lambda g, mi, ni, ki, ids, live: (
-                mi, held(g, live, ki, nk - 1)))
+        x_spec = pl.BlockSpec((bm, bk),
+                              lambda t, ni, ki, ids, first: (0, ki))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(slots, mp // bm, nn, nk),
+        grid=(jnp.reshape(n_live, ()).astype(jnp.int32), np_ // bn, kp // bk),
         in_specs=[x_spec,
-                  pl.BlockSpec((None, bk // 2, bn), plane),
-                  pl.BlockSpec((None, bk // bs, bn), slot_plane)],
+                  pl.BlockSpec((None, bk // 2, bn),
+                               lambda t, ni, ki, ids, first: (
+                                   first[0] + ids[t], ki, ni)),
+                  pl.BlockSpec((None, bk // bs, bn),
+                               lambda t, ni, ki, ids, first: (
+                                   ids[t], ki, ni))],
         out_specs=pl.BlockSpec(
-            (None, bm, bn), lambda g, mi, ni, ki, ids, live: (g, mi, ni)),
+            (None, bm, bn), lambda t, ni, ki, ids, first: (t, 0, ni)),
     )
     out = pl.pallas_call(
         functools.partial(_grouped_kernel, qtype=qtype, bs=bs, bk=bk, bn=bn,
                           compute_dtype=compute_dtype),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, mp, np_), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((tiles, bm, np_), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "parallel", "parallel",
-                                 "arbitrary"),
+            dimension_semantics=("arbitrary", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
         name="qmatmul_expert",
-    )(ids.astype(jnp.int32), jnp.reshape(n_live, (1,)).astype(jnp.int32), x,
+    )(ids.astype(jnp.int32), jnp.reshape(first, (1,)).astype(jnp.int32), x,
       data, scale_bits)
-    return out[:, :m, :logical_out]
+    return out[..., :logical_out]
 
 
 def grouped_supported(qt: QTensor) -> bool:
@@ -406,18 +400,25 @@ def grouped_supported(qt: QTensor) -> bool:
 
 
 def qmatmul_pallas_grouped(x: jnp.ndarray, qt: QTensor, ids, n_live,
+                           first=0, group: int | None = None,
                            compute_dtype=jnp.bfloat16):
-    """x @ dequant(qt[ids[g]]) for the first ``n_live`` slots g of ``ids``
-    (traced int32 [G]; the rest give zeros): x [M, in] for every slot, or
-    [G, M, in] a slot's own.  ``qt`` is a stacked weight (an expert layer's
-    ``[E, ...]`` planes), read in place.  Returns [G, M, out] float32."""
-    k_pad = qt.data.shape[1] * 2
-    if x.shape[-1] != k_pad:
-        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, k_pad - x.shape[-1])])
+    """x @ dequant(qt[first + ids[t]]) for the first ``n_live`` row tiles t
+    of ``ids`` (traced int32 [T], entries of the GROUP ``first .. first +
+    group`` of the stacked weight ``qt``; the whole stack by default): x [M,
+    in] for every tile, or [T, M, in] a tile's own.  ``qt`` is read in
+    place (an expert layer's ``[E, ...]`` planes, or with ``first`` a
+    model's ``[L x E, ...]``).  Returns [T, M, out] float32 whose tiles
+    past ``n_live`` are not written."""
+    m, k_pad = x.shape[-2], qt.data.shape[1] * 2
+    if x.shape[-1] != k_pad or m % 16:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [
+            (0, _round_up(m, 16) - m), (0, k_pad - x.shape[-1])])
+    scales = qt.scales if group is None else jax.lax.dynamic_slice_in_dim(
+        qt.scales, first, group, axis=0)
     return _qmatmul_grouped(
-        x, qt.data, jax.lax.bitcast_convert_type(qt.scales[ids], jnp.uint16),
-        ids, n_live, qtype=qt.qtype, bs=qt.block_size,
-        logical_out=qt.out_features, compute_dtype=compute_dtype)
+        x, qt.data, jax.lax.bitcast_convert_type(scales, jnp.uint16),
+        ids, n_live, first, qtype=qt.qtype, bs=qt.block_size,
+        logical_out=qt.out_features, compute_dtype=compute_dtype)[:, :m]
 
 
 def stacked_supported(stack: QTensor) -> bool:

@@ -149,6 +149,8 @@ def test_the_engine_accounts_for_latent_pages(tiny, served):
     assert m["moe_layer_calls_total"] > 0
     assert m["moe_pairs_routed_total"] % cfg.num_experts_per_tok == 0
     assert 0 < m["moe_pairs_local_total"] < m["moe_pairs_routed_total"]
+    # off the chip a loop over the hit experts: each multiplies every row
+    assert m["moe_pairs_local_total"] <= m["moe_rows_computed_total"]
     assert set(served["state"]) == {"stats"}
 
 
@@ -265,6 +267,88 @@ def test_a_tokens_bits_do_not_depend_on_how_its_prompt_is_chunked(
                                 scale=0.17, layer=0)
     np.testing.assert_array_equal(np.asarray(three[:, 2], np.float32),
                                   np.asarray(one[:, 0], np.float32))
+
+
+def test_a_held_layers_bits_do_not_depend_on_where_the_prompt_is_chunked(
+        tiny, kernel_forced, monkeypatch):
+    """The held expert layer has two forms, chosen by a block's static N:
+    a prompt served alone prefills in chunks of 256 and 44 (rows grouped by
+    expert, then one row tile), in a wave of three in chunks of 64 (one row
+    tile) beside decoding rows.  Same tokens, same logprob bits, kernels
+    forced; both forms were traced."""
+    from ipex_llm_tpu.ops import moe as moe_ops
+    from test_serving_mixed import _assert_rows_do_not_depend_on_batch
+
+    _, cfg, params, _ = tiny
+    seen = {"rows": set(), "slot": set()}
+    for form, name in (("rows", "_held_by_rows"), ("slot", "_held_by_slot")):
+        inner = getattr(moe_ops, name)
+
+        def spy(h, *a, _inner=inner, _form=form, **kw):
+            seen[_form].add(h.shape[0])
+            return _inner(h, *a, **kw)
+
+        monkeypatch.setattr(moe_ops, name, spy)
+    rng = np.random.default_rng(41)
+    wave = [dict(prompt_ids=[int(i) for i in rng.integers(3, 256, n)],
+                 max_new_tokens=4) for n in (300, 210, 150)]
+    eng, _ = _assert_rows_do_not_depend_on_batch(
+        cfg, params, EngineConfig(
+            max_rows=4, max_seq_len=384, page_size=PAGE, prefill_bucket=256,
+            step_token_budget=256),
+        {0: wave[:1], 1: wave[1:2], 2: wave[2:]})
+    assert 256 in seen["rows"] and all(n > 128 for n in seen["rows"])
+    assert seen["slot"] and all(n <= 128 for n in seen["slot"])
+    m = eng.metrics
+    assert 0 < m["moe_pairs_local_total"] <= m["moe_rows_computed_total"]
+
+
+def _tick_programs(cfg, params, vocab) -> list[str]:
+    """The programs a fresh engine compiles for one scenario: a 200-token
+    prompt in one chunk, a second prompt joining beside the decoding row,
+    completions."""
+    from test_glm_dsa import _logged_compiles
+
+    rng = np.random.default_rng(41)
+    eng = ServingEngine(cfg, params, EngineConfig(
+        max_rows=2, max_seq_len=304, page_size=PAGE, prefill_bucket=256,
+        step_token_budget=256))
+    with _logged_compiles() as names:
+        reqs = [Request(prompt_ids=[int(i) for i in rng.integers(
+            3, vocab, 200)], max_new_tokens=6)]
+        eng.submit(reqs[0])
+        for _ in range(3):
+            eng._tick()
+        reqs.append(Request(prompt_ids=[int(i) for i in rng.integers(
+            3, vocab, 40)], max_new_tokens=4))
+        eng.submit(reqs[1])
+        for _ in range(200):
+            if all(r.finish_reason is not None for r in reqs):
+                break
+            eng._tick()
+    assert all(r.finish_reason == "length" for r in reqs)
+    return names
+
+
+def test_an_expert_layer_adds_no_tick_program(tiny, kernel_forced):
+    """The same scenario on a dense family and on this one, kernels forced:
+    the same programs by name and number.  The held layer's two forms ride
+    the tick programs that were there (a chunk of 256: rows grouped by
+    expert; the decode stage: one row tile), and a dense family's ticks
+    never enter ops/moe.py."""
+    from test_decoder import rand_params, tiny_cfg
+
+    _, cfg, params, _ = tiny
+    dense = tiny_cfg(vocab_size=256, hidden_size=48, intermediate_size=96,
+                     num_heads=4, num_kv_heads=2, head_dim=12,
+                     max_position_embeddings=512)
+    without = _tick_programs(dense, rand_params(dense, qtype="sym_int4"), 256)
+    with_experts = _tick_programs(cfg, params, 256)
+    for names in (without, with_experts):
+        assert set(names) <= {"_ragged_tick", "unpack_rows"}
+        assert names.count("unpack_rows") <= 1
+    assert without.count("_ragged_tick") == with_experts.count(
+        "_ragged_tick") > 0
 
 
 def test_an_idle_row_walks_nothing_and_gives_zeros():

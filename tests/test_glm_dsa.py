@@ -14,6 +14,7 @@ test."""
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import importlib
 import logging
@@ -656,6 +657,19 @@ class _Compiles(logging.Handler):
             self.names.append(m.group(1))
 
 
+@contextlib.contextmanager
+def _logged_compiles():
+    """The names of the programs compiled inside the block, as they come."""
+    seen = _Compiles()
+    log = logging.getLogger("jax._src.interpreters.pxla")
+    log.addHandler(seen)
+    try:
+        with jax.log_compiles():
+            yield seen.names
+    finally:
+        log.removeHandler(seen)
+
+
 def _programs_of_a_wave(cfg, params, vocab) -> list[str]:
     """The programs a fresh engine compiles for one scenario: three prompts
     joining at once, a fourth beside decoding rows, completions."""
@@ -664,29 +678,23 @@ def _programs_of_a_wave(cfg, params, vocab) -> list[str]:
     # process's, and a program an earlier test compiled would not be counted)
     eng = ServingEngine(cfg, params, EngineConfig(
         **dict(ENGINE, max_seq_len=112)))
-    seen = _Compiles()
-    log = logging.getLogger("jax._src.interpreters.pxla")
-    log.addHandler(seen)
-    try:
-        with jax.log_compiles():
-            reqs = [Request(prompt_ids=[int(i) for i in rng.integers(
-                3, vocab, n)], max_new_tokens=m)
-                for n, m in ((20, 12), (45, 6), (70, 9))]
-            for r in reqs:
-                eng.submit(r)
-            for _ in range(4):
-                eng._tick()
-            reqs.append(Request(prompt_ids=[int(i) for i in rng.integers(
-                3, vocab, 33)], max_new_tokens=5))
-            eng.submit(reqs[-1])
-            for _ in range(400):
-                if all(r.finish_reason is not None for r in reqs):
-                    break
-                eng._tick()
-    finally:
-        log.removeHandler(seen)
+    with _logged_compiles() as names:
+        reqs = [Request(prompt_ids=[int(i) for i in rng.integers(
+            3, vocab, n)], max_new_tokens=m)
+            for n, m in ((20, 12), (45, 6), (70, 9))]
+        for r in reqs:
+            eng.submit(r)
+        for _ in range(4):
+            eng._tick()
+        reqs.append(Request(prompt_ids=[int(i) for i in rng.integers(
+            3, vocab, 33)], max_new_tokens=5))
+        eng.submit(reqs[-1])
+        for _ in range(400):
+            if all(r.finish_reason is not None for r in reqs):
+                break
+            eng._tick()
     assert all(r.finish_reason == "length" for r in reqs)
-    return seen.names
+    return names
 
 
 def test_the_indexer_rides_the_one_tick_program_and_adds_none(tiny):

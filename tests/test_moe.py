@@ -213,3 +213,106 @@ def test_moe_ep_sharding(tmp_path):
     model.shard(mesh)
     got = np.asarray(model(tokens))
     np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
+
+
+# ---- the held expert layer's two forms (ops/moe.py ``moe_held``) ----------
+
+HELD = dict(experts=4, hidden=128, inner=64, top_k=2, n_routed=16)
+
+
+def _held_stacks():
+    import jax
+    import jax.numpy as jnp
+
+    from ipex_llm_tpu.quantize import core as qcore
+
+    def stack(key, k, n):
+        ws = np.asarray(jax.random.normal(key, (HELD["experts"], k, n))) * 0.1
+        return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *[
+            qcore.pad_planes(qcore.quantize(w, "sym_int4")) for w in ws])
+
+    return (stack(jax.random.key(0), HELD["hidden"], 2 * HELD["inner"]),
+            stack(jax.random.key(1), HELD["inner"], HELD["hidden"]))
+
+
+def _held_gate(case: str):
+    """(tokens, gate [N, 4]) of a routing case; the tile is 48 rows for 200
+    tokens and 32 for 160 (``row_tile`` at top 2 of 16)."""
+    rng = np.random.RandomState(7)
+    n = {"ragged": 200, "alone_in_its_tile": 160}.get(case, 192)
+    g = np.where(rng.rand(n, 4) < 0.3, rng.rand(n, 4) + 0.1, 0.0)
+    if case == "one_expert":            # every pair on expert 2: four tiles
+        g[:, [0, 1, 3]] = 0.0
+        g[:, 2] = rng.rand(n) + 0.1
+    elif case == "no_pair":             # n_hit 0: nothing in the grid
+        g[:] = 0.0
+    elif case == "alone_in_its_tile":   # expert 1: 33 tokens, the last one
+        g[:, 1] = 0.0                   # alone in the second tile of 32;
+        g[np.arange(33) * 4 + 3, 1] = 0.5    # expert 3: one token, alone
+        g[:, 3] = 0.0
+        g[77, 3] = 0.9
+    g[5] = 0.0                          # a token routed nowhere held
+    return n, g.astype(np.float32)
+
+
+@pytest.mark.parametrize("case", ["balanced", "one_expert", "no_pair",
+                                  "ragged", "alone_in_its_tile"])
+def test_held_layer_gives_a_token_the_same_bits_in_every_form(case,
+                                                              kernel_forced):
+    """Rows grouped by expert (N > 128) == every hit expert over every row
+    (the form a decode stage meets) == a plain float32 loop over one expert
+    at a time, BIT FOR BIT a token, with the kernel forced through the
+    interpreter; and the rows each form says it multiplied."""
+    import jax
+    import jax.numpy as jnp
+
+    from ipex_llm_tpu.ops import moe as moe_ops
+
+    gu, dn = _held_stacks()
+    n, g = _held_gate(case)
+    h = jax.random.normal(jax.random.key(2), (n, HELD["hidden"])).astype(
+        jnp.bfloat16)
+    bm = moe_ops.row_tile(n, HELD["top_k"], HELD["n_routed"])
+    assert bm == (32 if n == 160 else 48) and (n % bm > 0) == (
+        case == "ragged")
+
+    by_slot = jax.jit(lambda h, gate: moe_ops._held_by_slot(
+        h, gate, gu, dn, "silu", True, 0))
+    held = jax.jit(lambda h, gate: moe_ops.moe_held(
+        h, gate, gu, dn, "silu", True, top_k=HELD["top_k"],
+        n_routed=HELD["n_routed"]))
+    rows, n_hit, rows_n = held(h, jnp.asarray(g))
+    slot, slot_n = by_slot(h, jnp.asarray(g))
+    per_expert = (g != 0).sum(0)
+    assert int(n_hit) == (per_expert > 0).sum()
+    assert int(slot_n) == int(n_hit) * n
+    assert int(rows_n) == sum(-(-c // bm) for c in per_expert) * bm
+    if case == "one_expert":
+        assert int(rows_n) == 4 * bm                 # four tiles, one expert
+    np.testing.assert_array_equal(np.asarray(rows), np.asarray(slot))
+
+    plain = np.zeros((n, HELD["hidden"]), np.float32)
+    for e in range(HELD["experts"]):                 # ascending, one by one
+        alone = np.zeros_like(g)
+        alone[:, e] = 1.0
+        y_e = np.asarray(by_slot(h, jnp.asarray(alone))[0])
+        plain = plain + g[:, e:e + 1] * y_e          # numpy: no fused add
+    np.testing.assert_array_equal(np.asarray(rows), plain)
+    assert not np.any(np.asarray(rows)[5])
+
+    # a block of the first 64 tokens alone (one row tile: the other form)
+    few, _, few_n = held(h[:64], jnp.asarray(g[:64]))
+    np.testing.assert_array_equal(np.asarray(few), np.asarray(rows)[:64])
+    assert int(few_n) == int(((g[:64] != 0).sum(0) > 0).sum()) * 64
+
+
+def test_row_tile_follows_the_shapes():
+    """The tile is the rows an expert expects plus two standard deviations,
+    in sublane tiles of 16, capped at 128: the cells' chunks get 32."""
+    from ipex_llm_tpu.ops.moe import row_tile
+
+    assert row_tile(512, 8, 192) == 32        # axk1.long-context's chunk
+    assert row_tile(512, 8, 256) == 32        # glm52.long-document's
+    assert row_tile(512, 6, 128) == 48
+    assert row_tile(512, 4, 4) == 128         # every token everywhere
+    assert row_tile(130, 1, 512) == 16

@@ -321,8 +321,9 @@ def test_planes_are_padded_once_at_build():
     got = qmm.qmatmul_pallas(x, padded)                    # interpreted
     np.testing.assert_allclose(got.astype(np.float32),
                                want.astype(np.float32), atol=0.1)
-    # a group of a stack's entries, read in place by their indices: slots 0
-    # and 1 live (entries 1 and 2), the dead slots zeros
+    # a group of a stack's entries, read in place by their indices: tiles 0
+    # and 1 live (entries 1 and 2); the third is not in the grid, and its
+    # rows are not written
     stack = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *[
         qcore.pad_planes(qcore.quantize(w * s, "sym_int4"))
         for s in (1.0, -2.0, 0.5)])
@@ -334,10 +335,14 @@ def test_planes_are_padded_once_at_build():
                                atol=0.25)
     np.testing.assert_allclose(got[1], 0.5 * want.astype(np.float32),
                                atol=0.1)
-    assert not np.any(np.asarray(got[2]))
-    own = jnp.stack([x, 2 * x, x])                   # a slot's own input
+    own = jnp.stack([x, 2 * x, x])                   # a tile's own input
     got = qmm.qmatmul_pallas_grouped(own, stack, ids, jnp.int32(2))
     np.testing.assert_allclose(got[1], want.astype(np.float32), atol=0.2)
+    # the same entries named inside a group that starts at entry 1
+    sub = qmm.qmatmul_pallas_grouped(own, stack, jnp.array([0, 1, 1]),
+                                     jnp.int32(2), first=jnp.int32(1),
+                                     group=2)
+    np.testing.assert_array_equal(sub[:2], got[:2])
     # fp16 scale bits decoded by integer arithmetic, subnormals and zero too
     h = jnp.array([0.0, 6e-8, 1e-5, 0.005, -0.37, 65504.0, -6.1e-5],
                   jnp.float16)
@@ -359,13 +364,15 @@ def test_expert_layer_on_the_grouped_kernel_is_the_loop(monkeypatch):
         jnp.bfloat16)
     gate = jnp.zeros((6, 4)).at[0, 2].set(0.7).at[3, 0].set(1.1).at[
         5, 2].set(0.4)
-    want, n = moe_ops.moe_held(h, gate, tree["moe_gate_up"], tree["moe_down"],
-                               cfg.act, gated=False, layer=jnp.int32(1))
+    want, n, rows = moe_ops.moe_held(
+        h, gate, tree["moe_gate_up"], tree["moe_down"], cfg.act, gated=False,
+        layer=jnp.int32(1))
     monkeypatch.setattr(dispatch, "_use_pallas_env", lambda op=None: True)
-    got, n_k = moe_ops.moe_held(h, gate, tree["moe_gate_up"],
-                                tree["moe_down"], cfg.act, gated=False,
-                                layer=jnp.int32(1))
+    got, n_k, rows_k = moe_ops.moe_held(h, gate, tree["moe_gate_up"],
+                                        tree["moe_down"], cfg.act,
+                                        gated=False, layer=jnp.int32(1))
     assert int(n) == int(n_k) == 2
+    assert int(rows) == int(rows_k) == 2 * 6       # hit experts x the rows
     np.testing.assert_allclose(got, want, atol=0.02 * float(
         jnp.abs(want).max()))
     assert not np.any(np.asarray(got[1]))              # a token routed nowhere
@@ -557,7 +564,7 @@ def test_rollback_restores_the_recurrent_state(model):
     assert inj.fired == 2 and metrics["retries"] == 2
     assert got == want
     for name in ("moe_pairs_routed_total", "moe_pairs_local_total",
-                 "ssm_state_resets_total"):
+                 "moe_rows_computed_total", "ssm_state_resets_total"):
         assert metrics[name] == want_metrics[name], name
     _held_to_reference(hf, params, prompts[1], got[1], lps[1])
 
