@@ -23,11 +23,15 @@ except ImportError as _e:  # pragma: no cover
     web = None
     _AIOHTTP_ERR = _e
 
+from jax.profiler import TraceAnnotation
+
 from ipex_llm_tpu.serving.engine import (EngineConfig, Request,
-                                         ServingEngine, next_stream_item)
+                                         ServingEngine, next_stream_item,
+                                         stream_stamp)
 from ipex_llm_tpu.serving.faults import EngineOverloaded
 from ipex_llm_tpu.serving.kv_transport import TransportError
-from ipex_llm_tpu.serving.observe import Tracer, parse_traceparent
+from ipex_llm_tpu.serving.observe import (LATENCY_BUCKETS_S, Histogram,
+                                          Tracer, parse_traceparent)
 
 
 def _now() -> int:
@@ -76,6 +80,21 @@ class OpenAIServer:
         # OpenAI audio surface (the reference serves whisper through its
         # workers; SURVEY L6 lists the audio endpoint)
         self.asr = asr
+        # a token's way from the engine's commit to the socket, measured
+        # on the event-loop thread from the stamp the commit left beside
+        # the token (engine.stream_stamp): per written event, the seconds
+        # until this coroutine resumed with it (wake), until its write
+        # returned (deliver), and the thread's CPU time from the one to
+        # the other (decode + render + write).  Monotonic, and NOT
+        # rollback-covered: a write happened.  The CPU clock is a system
+        # call (6.2 us on the chip's host; PERF.md section 6, PR 42): it
+        # is read for one item in _CPU_SAMPLE and counted that many times
+        self._stream_items = 0
+        self.stream_counters = {"stream_events_total": 0,
+                                "stream_wake_s_total": 0.0,
+                                "stream_deliver_s_total": 0.0,
+                                "stream_cpu_s_total": 0.0}
+        self.stream_deliver_s = Histogram(LATENCY_BUCKETS_S)
         # client_max_size: aiohttp's 1 MiB default would 413 every
         # realistically-sized /kv/import page-set blob (one 7B-shaped
         # bf16 page is ~64 MiB) and silently break the disaggregated
@@ -265,6 +284,35 @@ class OpenAIServer:
                 raise ConnectionResetError("the client went away while its "
                                            "prompt was prefilling")
 
+    # one stream item in this many has its CPU time read: a prime, so that
+    # the sampled place moves through the rows of a tick (16, 32, 64)
+    _CPU_SAMPLE = 13
+
+    def _woke(self, req: Request) -> tuple[float | None, float, float | None]:
+        """Called where a coroutine resumes with a stream item: the
+        commit's stamp for it (None: put outside a commit), the wall
+        clock now and, for a sampled item, this thread's CPU clock."""
+        self._stream_items += 1
+        sampled = self._stream_items % self._CPU_SAMPLE == 0
+        return (stream_stamp(req), time.perf_counter(),
+                time.thread_time() if sampled else None)
+
+    def _delivered(self, woke: tuple[float | None, float, float | None]):
+        """One token event has left for its consumer (``resp.write``
+        returned; ``_collect`` has it in its list): count its way."""
+        stamp, t_wake, cpu_wake = woke
+        if stamp is None:
+            return
+        deliver = time.perf_counter() - stamp
+        c = self.stream_counters
+        c["stream_events_total"] += 1
+        c["stream_wake_s_total"] += t_wake - stamp
+        c["stream_deliver_s_total"] += deliver
+        if cpu_wake is not None:
+            c["stream_cpu_s_total"] += self._CPU_SAMPLE * (
+                time.thread_time() - cpu_wake)
+        self.stream_deliver_s.observe(deliver)
+
     async def _collect(self, req: Request) -> str:
         toks: list[int] = []
         drop = set(req.eos_token_id)
@@ -273,6 +321,7 @@ class OpenAIServer:
             tok = await self._next_tok(req)
             if tok is None:
                 break
+            woke = self._woke(req)
             if tok in drop:
                 continue
             toks.append(tok)
@@ -283,6 +332,7 @@ class OpenAIServer:
                     self.engine.abort(req)
                     req.finish_reason = "stop_string"
                     return text[:cut]
+            self._delivered(woke)
         return self.tok.decode(toks)
 
     async def _stream_sse(self, request, req: Request, chunk_fn,
@@ -310,6 +360,7 @@ class OpenAIServer:
                              else self._first_tok(request, req))
                 if tok is None:
                     break
+                woke = self._woke(req)
                 if tok in drop:
                     continue
                 toks.append(tok)
@@ -321,10 +372,15 @@ class OpenAIServer:
                     piece, done = text[len(sent):], False
                 if piece:
                     sent += piece
-                    await resp.write(
-                        f"data: {json.dumps(chunk_fn(piece, None, tok))}\n\n"
-                        .encode()
-                    )
+                    # on the host plane of a profiler capture, one clock
+                    # with the engine's spans and the device's operations
+                    with TraceAnnotation("server/stream_write"):
+                        await resp.write(
+                            f"data: "
+                            f"{json.dumps(chunk_fn(piece, None, tok))}\n\n"
+                            .encode()
+                        )
+                    self._delivered(woke)
                 if done:
                     self.engine.abort(req)
                     req.finish_reason = "stop_string"
@@ -605,6 +661,8 @@ class OpenAIServer:
         for k, v in self.engine.perf_numeric().items():
             if isinstance(v, (int, float)) and not isinstance(v, bool):
                 out[k] = v
+        for k, v in self.stream_counters.items():
+            out[k] = round(v, 6)
         out["uptime_s"] = round(
             time.monotonic() - self.started_monotonic, 3)
         return out
@@ -617,7 +675,8 @@ class OpenAIServer:
         sync, swap-in); ``?format=json`` keeps the machine-readable shape
         the router's aggregation fetches and fleet-sums."""
         vals = self._metrics_numeric()
-        hists = self.engine.histograms()
+        hists = {**self.engine.histograms(),
+                 "stream_deliver_s": self.stream_deliver_s}
         if request.query.get("format") == "json":
             return web.json_response(
                 {"replica_id": self.replica_id, "metrics": vals,

@@ -32,7 +32,7 @@ import threading
 import time
 import uuid
 from collections import OrderedDict, deque
-from contextlib import contextmanager, nullcontext
+from contextlib import nullcontext
 from dataclasses import dataclass, field, replace
 from functools import partial
 from typing import Any
@@ -53,6 +53,7 @@ from ipex_llm_tpu.serving.faults import (EngineOverloaded, FaultInjector,
 from ipex_llm_tpu.serving.observe import (FAST_LATENCY_BUCKETS_S,
                                           LATENCY_BUCKETS_S, FlightRecorder,
                                           Histogram, Tracer, span)
+from ipex_llm_tpu.serving.perfwatch import SLOW_SYNC_WAIT_S
 from ipex_llm_tpu.serving.planner import make_planner
 
 NEG_INF = -1e30
@@ -348,6 +349,10 @@ class Request:
     # checkpointed with the tick so a rolled-back emission never skews
     # the inter-token distribution)
     _last_tok_s: float = 0.0
+    # when the engine's commit released the items in ``stream_queue``:
+    # one ``[perf_counter, items]`` a committed tick that emitted for this
+    # request, appended BEFORE the items are put (``stream_stamp``)
+    commit_stamps: "deque[list]" = field(default_factory=deque, repr=False)
 
     def abort(self):
         self.cancelled = True
@@ -1375,6 +1380,15 @@ class ServingEngine:
                         # news (beside: a speculating tick's draft widths
                         # and token history, a swap-in's pages)
                         "h2d_total": 0,
+                        # its mirror: every device->host fetch the engine
+                        # thread makes inside a tick (three a steady tick,
+                        # five a mixed one with a completion; a spill's
+                        # pages beside it)
+                        "d2h_total": 0,
+                        # committed ticks whose first fetch waited over
+                        # perfwatch.SLOW_SYNC_WAIT_S for the program to
+                        # end: each froze the flight ring (``slow_tick``)
+                        "slow_ticks_total": 0,
                         "decode_horizon_effective": 0,
                         # admission-wave economics (the mixed-step story):
                         # mixed ticks run, prompt tokens prefilled per
@@ -1760,14 +1774,26 @@ class ServingEngine:
         if self.perf is not None:
             self.perf.phase_to(name)
 
-    @contextmanager
-    def _sync_window(self):
-        """THE tick's blocking device->host materialization: the ``sync``
-        phase, counted by ``_count_sync`` when it returns."""
+    def _sync_window(self, arrays: dict) -> dict[str, np.ndarray]:
+        """THE tick's blocking device->host materialization, the ``sync``
+        phase, counted by ``_count_sync`` when it returns: the named
+        ``arrays`` of the dispatched program, fetched one ``d2h`` after
+        another.  The FIRST blocks until the program ends (the device is
+        busy): phase ``sync_wait``.  Every later one is a round trip with
+        the device idle: phase ``sync_fetch``, all of them together."""
+        (name, first), *rest = arrays.items()
         with self._phase("sync"):
             t0 = time.perf_counter()
-            yield
+            with self._phase("sync_wait"):
+                # jaxlint: disable=JL002 -- THE per-tick designed sync: one blocking materialization for the whole fused tick
+                out = {name: d2h(first)}
+            with self._phase("sync_fetch", n=len(rest),
+                             bytes=sum(a.nbytes for _, a in rest)):
+                # jaxlint: disable=JL002 -- rides THE per-tick sync above (same dispatched program, already ended)
+                out.update((k, d2h(a)) for k, a in rest)
+            self.metrics["d2h_total"] += len(arrays)
             self._count_sync(time.perf_counter() - t0)
+        return out
 
     def _perf_point(self, horizon: int, width: int = 0,
                     with_decode: bool = True, spec: bool = False,
@@ -1914,6 +1940,13 @@ class ServingEngine:
         if self.injector is not None:
             self._flight_hits0 = dict(self.injector.site_hits)
         self.flight.record(rec)
+        waited = pf.get("phases", {}).get("sync_wait", 0.0)
+        if waited > SLOW_SYNC_WAIT_S:
+            # an enqueued program that did not start for seconds (PERF.md
+            # section 7): keep the ring around the tick that lost them
+            self.metrics["slow_ticks_total"] += 1
+            self.flight.dump("slow_tick", tick=rec["tick"],
+                             sync_wait_s=waited)
         # the runtime enforcement of JP106's hand-maintained `+= 1`
         # bookkeeping: the observed dispatch-window count must equal
         # _tick_dispatches.  Debug assert AFTER the ring has the
@@ -2011,7 +2044,7 @@ class ServingEngine:
         flushed only on commit, so a rolled-back (retried/bisected) tick
         never leaks a token or a terminal None to a stream consumer."""
         if self._staging is not None:
-            self._staging.append((req.stream_queue, item))
+            self._staging.append((req, item))
         else:
             req.stream_queue.put(item)
 
@@ -2160,8 +2193,19 @@ class ServingEngine:
         if self._state_prev is not None:
             # the state this tick started from is the next tick's spare
             self._state_spare, self._state_prev = self._state_prev, None
-        for q, item in staged:
-            q.put(item)
+        # the stamp a consumer measures the way to its socket from: one
+        # clock read a tick, one record a request (a horizon's block
+        # shares it), there before the items it belongs to
+        now = time.perf_counter()
+        stamped: dict[int, list] = {}
+        for req, _ in staged:
+            stamp = stamped.get(id(req))
+            if stamp is None:
+                stamp = stamped[id(req)] = [now, 0]
+                req.commit_stamps.append(stamp)
+            stamp[1] += 1
+        for req, item in staged:
+            req.stream_queue.put(item)
         if self.tracer is not None:
             for tid, s in staged_spans:
                 self.tracer.add(tid, s)
@@ -2457,6 +2501,7 @@ class ServingEngine:
         # jaxlint: disable=JL002 -- designed epoch-boundary sync: the batch's bytes must reach host RAM before the pool slots are recycled (the demotion itself)
         k_np = d2h(k_pages)
         v_np = d2h(v_pages)  # jaxlint: disable=JL002 -- same designed spill sync; already blocked on k_np above
+        self.metrics["d2h_total"] += 2
         self._count_sync(time.perf_counter() - t0)
         for i, (key, _) in enumerate(pairs):
             self.pagestore.spill(key,
@@ -2566,6 +2611,7 @@ class ServingEngine:
         # jaxlint: disable=JL002 -- designed finish-epoch sync: one batched gather spills the finished row's pages before their pool slots are recycled
         k_np = d2h(k_pages)
         v_np = d2h(v_pages)  # jaxlint: disable=JL002 -- rides the same designed finish-epoch sync; already blocked on k_np above
+        self.metrics["d2h_total"] += 2
         self._count_sync(time.perf_counter() - t0)
         for i, (key, _) in enumerate(picks):
             self.pagestore.spill(key,
@@ -3119,9 +3165,10 @@ class ServingEngine:
             ks[i] = min(self._row_spec_k(req), self._plan_spec_cap(i))
         return ks
 
-    def _spec_metrics(self, take_block: np.ndarray, s_prop, s_acc,
-                      executed: int):
-        """Spec tick accounting: the verify-round counters
+    def _spec_metrics(self, take_block: np.ndarray, s_prop: np.ndarray,
+                      s_acc: np.ndarray, executed: int):
+        """Spec tick accounting, from what ``_sync_window`` fetched: the
+        verify-round counters
         (spec_steps/spec_emitted/accept_rate — one loop iteration is one
         verify round; normalized by ACTIVE ROW-STEPS, so concurrent rows
         cannot push the rate past 1.0) plus the draft-economics
@@ -3131,8 +3178,7 @@ class ServingEngine:
         k = self.ec.spec_k
         emitted = int(take_block.sum())
         row_steps = int((take_block > 0).sum())
-        prop = int(d2h(s_prop))  # jaxlint: disable=JL002 -- rides THE per-horizon sync (same dispatched program): draft-economics scalars
-        acc = int(d2h(s_acc))  # jaxlint: disable=JL002 -- rides the same per-horizon sync as s_prop above
+        prop, acc = int(s_prop), int(s_acc)
         m = self.metrics
         m["spec_steps"] = m.get("spec_steps", 0) + executed
         m["spec_ticks"] = m.get("spec_ticks", 0) + 1
@@ -3491,14 +3537,22 @@ class ServingEngine:
             self._dirty = True
             self._fault_point("sample",
                               rows=[row for _, row in completing])
-        with self._sync_window():
-            if completing:
-                # jaxlint: disable=JL002 -- designed sync: first tokens of prompts completing this tick must reach the host to emit; rides THE one per-tick sync, counted via _count_sync
-                nxt, lp = d2h(first_t), d2h(first_lp)
-            tok_np = d2h(tok_block)  # jaxlint: disable=JL002 -- THE per-tick designed sync: one blocking materialization for the whole fused tick
-            lp_np = d2h(lp_block)  # jaxlint: disable=JL002 -- rides THE per-tick sync above (same dispatched program)
-            executed = self._count_state(d2h(n_exec))  # jaxlint: disable=JL002 -- rides THE per-tick sync: 0 only when no row decoded
+        # the first tokens of prompts completing this tick must reach the
+        # host to emit; n_exec is 0 only when no row decoded; a speculating
+        # tick's accepted counts (for the drain walk) and draft economics
+        # ride the same window
+        want = {"tok": tok_block, "lp": lp_block, "n_exec": n_exec}
+        if completing:
+            want.update(first_t=first_t, first_lp=first_lp)
+        if tick_spec:
+            want.update(take=take_block, prop=s_prop, acc=s_acc)
+        got = self._sync_window(want)
+        tok_np, lp_np = got["tok"], got["lp"]
+        executed = self._count_state(got["n_exec"])
+        if completing:
+            nxt, lp = got["first_t"], got["first_lp"]
         for i, row in completing:
+            # jaxlint: disable=JL002 -- host arrays: _sync_window fetched them above
             self._finish_prompt(row, int(nxt[i]), float(lp[i]))
             if not canjoin[i] and self.rows[row] is not None:
                 # the pool could not back its decode slot: the program
@@ -3512,8 +3566,8 @@ class ServingEngine:
         mask = self._active_mask()
         parts = self._decode_parts(mask)
         if tick_spec:
-            take_np = d2h(take_block)  # jaxlint: disable=JL002 -- rides THE per-tick sync: per-iteration accepted counts for the drain walk
-            self._spec_metrics(take_np, s_prop, s_acc, executed)
+            take_np = got["take"]
+            self._spec_metrics(take_np, got["prop"], got["acc"], executed)
             self._drain_spec_block(tok_np, lp_np, take_np, mask, executed)
         else:
             take_np = None
@@ -3648,11 +3702,15 @@ class ServingEngine:
             self._tick_dispatches += 1
             # the returned cache owns the (donated) tables buffer now
         self._phase_to("drain")
-        with self._sync_window():
-            tok_block = d2h(tok_block)   # jaxlint: disable=JL002 -- THE per-horizon designed sync: h tokens per host round trip, counted via _count_sync
-            lp_block = d2h(lp_block)  # jaxlint: disable=JL002 -- rides THE per-horizon sync above (same dispatched program)
-            # jaxlint: disable=JL002 -- rides THE per-horizon sync: < h only if every row died early
-            executed = self._count_state(d2h(n_exec))
+        # h tokens per host round trip; n_exec < h only if every row died
+        # early; a speculating tick's accepted counts (for the drain walk)
+        # and draft economics ride the same window
+        want = {"tok": tok_block, "lp": lp_block, "n_exec": n_exec}
+        if fused_spec:
+            want.update(take=take_block, prop=s_prop, acc=s_acc)
+        got = self._sync_window(want)
+        tok_block, lp_block = got["tok"], got["lp"]
+        executed = self._count_state(got["n_exec"])
         if self.perf is not None:
             # the MFU join's loop multiplier: XLA's cost analysis counts
             # the horizon body once, the tick executed it `executed` times
@@ -3662,11 +3720,10 @@ class ServingEngine:
         self.metrics["pages_in_use"] = self.alloc.pages_in_use
         parts = self._decode_parts(active)
         if fused_spec:
-            take_block = d2h(take_block)  # jaxlint: disable=JL002 -- rides THE per-horizon sync: per-iteration accepted counts for the drain walk
-            self._spec_metrics(take_block, s_prop, s_acc, executed)
-            self._drain_spec_block(tok_block, lp_block, take_block,
+            take_np = got["take"]
+            self._spec_metrics(take_np, got["prop"], got["acc"], executed)
+            self._drain_spec_block(tok_block, lp_block, take_np,
                                    active, executed)
-            take_np = take_block
         else:
             take_np = None
             self._drain_block(tok_block, lp_block, active, executed)
@@ -3750,6 +3807,21 @@ def next_stream_item(engine: "ServingEngine", req: Request,
                 if req.finish_reason is None:
                     req.finish_reason = "error"
                 return None
+
+
+def stream_stamp(req: Request) -> float | None:
+    """When the commit released the item its ONE consumer just took from
+    ``req.stream_queue`` (``perf_counter``; call once an item, after the
+    take).  None for an item put outside a tick's commit (the terminal
+    None of a failed, shed or quarantined request)."""
+    stamps = req.commit_stamps
+    if not stamps:
+        return None
+    head = stamps[0]
+    head[1] -= 1
+    if head[1] <= 0:
+        stamps.popleft()
+    return head[0]
 
 
 def stream_tokens(req: Request, timeout: float | None = None):

@@ -69,8 +69,15 @@ new syncs — JP106's one-dispatch tick is untouched):
   one clock — and a monotonic counter ``perf_phase_<phase>_s`` of its
   ``perf_counter`` SELF time (nested phases subtracted, so the counters
   of the phases inside ``tick`` partition ``perf_phase_tick_s``, the
-  tick's whole wall clock).  Counters fold in at ``tick_finish`` under
-  the attribution rule above: committed working ticks only.  What closes
+  tick's whole wall clock).  Beside it, by the same rules from the same
+  entry, for the phases of ``CPU_PHASES`` (``tick`` and those that call
+  into the runtime and so release the interpreter),
+  ``perf_phase_<phase>_cpu_s``: the engine thread's CPU time
+  (``time.thread_time``) in the phase.  ``wall - cpu`` is the time the
+  thread neither computed nor held the interpreter: it waited for the
+  interpreter's lock, or was blocked inside a runtime call that had
+  released it.  Counters fold in at ``tick_finish`` under the
+  attribution rule above: committed working ticks only.  What closes
   after ``tick_finish`` has closed the books (the rest of
   ``engine/record`` and of ``engine/tick``) is counted into the NEXT
   committed tick.
@@ -97,6 +104,8 @@ __all__ = [
     "PerfWatch",
     "BUCKETS",
     "PHASES",
+    "CPU_PHASES",
+    "SLOW_SYNC_WAIT_S",
     "PLAN_ERROR_BUCKETS",
     "model_flops_per_token",
     "parse_point_key",
@@ -112,10 +121,32 @@ log = logging.getLogger("ipex_llm_tpu.perfwatch")
 BUCKETS = ("dispatch", "device", "sync", "bookkeep")
 
 # the engine thread's tick, as host phase spans (``engine/<phase>`` on the
-# profiler's timeline) and ``perf_phase_<phase>_s`` counters: ``tick`` is
-# the parent, the rest partition it in the order a tick visits them
+# profiler's timeline) and ``perf_phase_<phase>_s`` / ``_cpu_s`` counters:
+# ``tick`` is the parent, the rest partition it in the order a tick visits
+# them.  ``sync`` is the tick's device->host window; inside it
+# ``sync_wait`` is the FIRST fetch, which blocks until the program ends
+# (the device is busy), and ``sync_fetch`` every later fetch together
+# (round trips with the device idle), so ``sync`` itself is the remainder
 PHASES = ("tick", "plan", "checkpoint", "admit", "prepare", "dispatch",
-          "sync", "drain", "commit", "record")
+          "sync", "sync_wait", "sync_fetch", "drain", "commit", "record")
+
+# the phases whose thread-CPU time is read beside their wall time: the
+# whole tick, and the phases that call into the runtime and so RELEASE the
+# interpreter (a snapshot's copies, the upload, the jitted call, the
+# fetches, the drain around them).  Not all of them: ``time.thread_time``
+# is a system call that costs 6.2 us on the chip's host (0.13 us for
+# ``perf_counter``; PERF.md section 6, PR 42), and 25 of them a tick were
+# 1 % of the shortest tick.  A phase that is not read books no CPU time
+# and passes what its children read up to its parent, so ``drain``'s holds
+# the remainder of ``sync`` (a few statements)
+CPU_PHASES = frozenset(("tick", "checkpoint", "prepare", "dispatch",
+                        "sync_wait", "sync_fetch", "drain"))
+
+# a committed tick whose ``sync_wait`` exceeds this has lost seconds to a
+# program that did not start (PERF.md section 7, the intermittent stall;
+# the slowest tick of any benchmark cell is 0.16 s): the engine freezes
+# the flight ring around it (dump reason ``slow_tick``)
+SLOW_SYNC_WAIT_S = 1.0
 
 # the ``variant`` attribute of an ``engine/dispatch`` span, by what the
 # dispatched tick program holds: a decode stage alone, prefill chunks with
@@ -424,11 +455,15 @@ class PerfWatch:
         self.dispatch_mismatches = 0
         self._tick = None               # open tick scratch
         self._windows: list[dict] = []  # open window stack (tick + epoch)
-        # host phase spans: committed counters, the open tick's phases,
-        # what closed after the last commit (folded into the next one),
-        # where a closing phase adds (None: nowhere), the nested-time
-        # accumulators of the open phases, the open phase_to() phase
+        # host phase spans: committed counters (wall and thread CPU), the
+        # open tick's phases as {name: (wall, cpu)}, what closed after the
+        # last commit (folded into the next one), where a closing phase
+        # adds (None: nowhere), the open phases as [name, wall clock, cpu
+        # clock (None: not read), wall nested, cpu nested], the open
+        # phase_to() phase
         self.phase_s = dict.fromkeys(PHASES, 0.0)
+        self.phase_cpu_s = dict.fromkeys(
+            (p for p in PHASES if p in CPU_PHASES), 0.0)
         self._phase_open: dict = {}
         self._phase_tail: dict = {}
         self._phase_sink: dict | None = None
@@ -459,34 +494,47 @@ class PerfWatch:
     def phase(self, name: str, **attrs):
         """One host phase of the tick (``PHASES``): a profiler span
         ``engine/<name>`` carrying ``attrs`` (outside a capture a
-        TraceAnnotation is a flag test) and its ``perf_counter`` self
-        time — nested phases subtracted; ``tick``, the parent, whole —
-        towards ``perf_phase_<name>_s``.  The time counts when
-        ``tick_finish`` commits the tick; a phase that closes after it
-        (``record``, ``tick``) counts into the next committed tick."""
+        TraceAnnotation is a flag test) and its SELF time on two clocks,
+        ``perf_counter`` and (``CPU_PHASES``) this thread's
+        ``thread_time`` — nested phases subtracted; ``tick``, the
+        parent, whole — towards ``perf_phase_<name>_s`` and
+        ``perf_phase_<name>_cpu_s``.  The
+        time counts when ``tick_finish`` commits the tick; a phase that
+        closes after it (``record``, ``tick``) counts into the next
+        committed tick."""
         if name == "tick":
             self._phase_open = self._phase_sink = {}
         with TraceAnnotation("engine/" + name, **attrs):
-            entry = [name, time.perf_counter(), 0.0]
+            entry = [name, time.perf_counter(),
+                     time.thread_time() if name in CPU_PHASES else None,
+                     0.0, 0.0]
             self._phase_stack.append(entry)
             try:
                 yield
             finally:
-                dt = time.perf_counter() - entry[1]
+                wall = time.perf_counter() - entry[1]
+                # a phase that is not read passes its children's on
+                cpu = (entry[4] if entry[2] is None
+                       else time.thread_time() - entry[2])
                 self._phase_stack.pop()
                 if self._phase_stack:
-                    self._phase_stack[-1][2] += dt
-                self._phase_book(name, dt, entry[2])
+                    self._phase_stack[-1][3] += wall
+                    self._phase_stack[-1][4] += cpu
+                self._phase_book(name, wall, cpu, entry[3], entry[4])
                 if name == "tick":
                     self._phase_sink = None   # between ticks counts nowhere
 
-    def _phase_book(self, name: str, seconds: float, inside: float):
-        """``seconds`` of phase ``name``, ``inside`` of them in the phases
-        nested in it, to where a closing phase counts now."""
+    def _phase_book(self, name: str, wall: float, cpu: float,
+                    wall_inside: float, cpu_inside: float):
+        """``wall`` seconds and ``cpu`` thread-CPU seconds of phase
+        ``name``, the ``_inside`` part of each in the phases nested in
+        it, to where a closing phase counts now."""
         sink = self._phase_sink
         if sink is not None:
-            sink[name] = sink.get(name, 0.0) + (
-                seconds if name == "tick" else seconds - inside)
+            if name != "tick":
+                wall, cpu = wall - wall_inside, cpu - cpu_inside
+            w, c = sink.get(name, (0.0, 0.0))
+            sink[name] = (w + wall, c + cpu)
 
     def phase_to(self, name: str | None):
         """``phase`` for straight-line code too long to indent: close the
@@ -801,23 +849,28 @@ class PerfWatch:
         if scratch is None or not working:
             self._phase_sink = None     # an idle tick's phases count nowhere
             return {}
-        t1 = time.perf_counter()
+        t1, c1 = time.perf_counter(), time.thread_time()
         # book the phases still open (``record``, ``tick``) up to HERE,
         # innermost first, and restart their clocks: what is booked for
-        # this tick partitions its wall clock up to now.  This tick's
-        # phases, plus what closed after the last committed tick's books
-        # did, are what it commits; from here on a closing phase adds to
-        # the tail the next committed tick folds in
-        inside = 0.0
+        # this tick partitions its wall clock (and its CPU time) up to
+        # now.  This tick's phases, plus what closed after the last
+        # committed tick's books did, are what it commits; from here on a
+        # closing phase adds to the tail the next committed tick folds in
+        wall_in = cpu_in = 0.0
         for entry in reversed(self._phase_stack):
-            dt = t1 - entry[1]
-            self._phase_book(entry[0], dt, entry[2] + inside)
-            entry[1], entry[2], inside = t1, 0.0, dt
+            read = entry[2] is not None
+            wall = t1 - entry[1]
+            cpu = c1 - entry[2] if read else entry[4] + cpu_in
+            self._phase_book(entry[0], wall, cpu, entry[3] + wall_in,
+                             entry[4] + cpu_in)
+            entry[1:] = t1, c1 if read else None, 0.0, 0.0
+            wall_in, cpu_in = wall, cpu
         phases, tail = self._phase_open, self._phase_tail
         self._phase_open = {}
         self._phase_sink = self._phase_tail = {}
-        for name, v in tail.items():
-            phases[name] = phases.get(name, 0.0) + v
+        for name, (w, c) in tail.items():
+            w0, c0 = phases.get(name, (0.0, 0.0))
+            phases[name] = (w0 + w, c0 + c)
         buckets, wall = self._classify(scratch, t1)
         fams = scratch["families"]
         if "tick.spec" in fams:
@@ -861,7 +914,10 @@ class PerfWatch:
             out["grid_point"] = ",".join(
                 f"{k}={p[k]}" for k in sorted(p))
         if phases:
-            out["phases"] = {k: round(v, 6) for k, v in phases.items()}
+            out["phases"] = {k: round(w, 6) for k, (w, _) in phases.items()}
+            out["phases_cpu"] = {k: round(c, 6)
+                                 for k, (_, c) in phases.items()
+                                 if k in CPU_PHASES}
         if mismatch:
             self.dispatch_mismatches += 1
             out["dispatch_mismatch"] = {"observed": observed,
@@ -882,8 +938,10 @@ class PerfWatch:
             self._fam_update(e_fam, e_buckets, e_wall, device_s=e_dev)
         with self._lock:
             self.ticks_attributed += 1
-            for name, v in phases.items():
-                self.phase_s[name] = self.phase_s.get(name, 0.0) + v
+            for name, (w, c) in phases.items():
+                self.phase_s[name] = self.phase_s.get(name, 0.0) + w
+                if name in CPU_PHASES:
+                    self.phase_cpu_s[name] += c
         # the debug ASSERT lives in the engine, AFTER the flight ring has
         # recorded this dict — the mismatch evidence must survive the
         # raise (and survive `-O` builds, where only the field remains)
@@ -955,6 +1013,8 @@ class PerfWatch:
                 out[f"{_fam_key(name)}_device_s"] = round(f["device_s"], 6)
             for name, v in self.phase_s.items():
                 out[f"phase_{name}_s"] = round(v, 6)
+            for name, v in self.phase_cpu_s.items():
+                out[f"phase_{name}_cpu_s"] = round(v, 6)
         m = self.mfu()
         if m is not None:
             out["mfu"] = m

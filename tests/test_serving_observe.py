@@ -669,7 +669,7 @@ def test_replica_http_surface_trace_flight_metrics(cfg_params):
 
 def test_phase_and_wait_counters_on_metrics_json_advance_monotonically(
         cfg_params):
-    """The ten ``perf_phase_*_s`` counters and the three wait counters
+    """The ``perf_phase_*_s`` counters and the three wait counters
     reach ``/metrics?format=json`` (and the text exposition); scraped
     between requests none of them ever goes back, and every one of them
     has advanced once two requests were served."""

@@ -45,7 +45,8 @@ from ipex_llm_tpu.serving.engine import (EngineConfig, Request,
                                          ServingEngine, stream_tokens)
 from ipex_llm_tpu.serving.faults import (DeterministicFault, FaultInjector,
                                          TransientFault)
-from ipex_llm_tpu.serving.perfwatch import (PHASES, PerfWatch, locked_points,
+from ipex_llm_tpu.serving.perfwatch import (CPU_PHASES, PHASES, PerfWatch,
+                                            locked_points,
                                             model_flops_per_token,
                                             outside_axes, parse_point_key,
                                             point_in_grid)
@@ -660,9 +661,34 @@ WAIT_COUNTERS = ("queue_wait_s_total", "admit_to_first_token_s_total",
                  "first_tokens")
 
 
-def _phase_counters(eng):
+def _phase_counters(eng, clock="s"):
     nm = eng.perf_numeric()
-    return {p: nm[f"perf_phase_{p}_s"] for p in PHASES}
+    return {p: nm[f"perf_phase_{p}_{clock}"] for p in PHASES
+            if clock == "s" or p in CPU_PHASES}
+
+
+def _hand_clocks(monkeypatch):
+    """``perfwatch``'s two clocks moved by hand: ``spend(ms, cpu_ms)``
+    (the thread's CPU time a quarter of the wall time unless given, so
+    every number stays exact in binary)."""
+    import types
+
+    from ipex_llm_tpu.serving import perfwatch
+
+    now = [0.0, 0.0]
+    monkeypatch.setattr(perfwatch, "time", types.SimpleNamespace(
+        perf_counter=lambda: now[0] * 1e-3,
+        thread_time=lambda: now[1] * 1e-3))
+
+    def spend(ms, cpu_ms=None):
+        now[0] += ms
+        now[1] += ms / 4 if cpu_ms is None else cpu_ms
+
+    return spend
+
+
+def _ms(d):
+    return {k: round(v * 1e3, 6) for k, v in d.items() if v}
 
 
 def _bucket_sum(eng, bucket):
@@ -674,19 +700,13 @@ def test_phase_unit_self_times_and_carry(monkeypatch):
     """Nested phases book self times, ``tick`` its whole span; what is
     open when ``tick_finish`` closes the books is booked up to there and
     the rest counts into the NEXT committed tick; an aborted or idle
-    tick books nothing and keeps the committed tail.  On a clock the
-    test moves by hand (ms), so every number is exact."""
-    import types
-
-    from ipex_llm_tpu.serving import perfwatch
-
-    now = [0.0]
-    monkeypatch.setattr(perfwatch, "time", types.SimpleNamespace(
-        perf_counter=lambda: now[0] * 1e-3))
-
-    def spend(ms):
-        now[0] += ms
-
+    tick books nothing and keeps the committed tail.  On clocks the
+    test moves by hand (ms), so every number is exact; the thread's CPU
+    time, a quarter of the wall time throughout, is booked by the same
+    rules for the phases that read it (``CPU_PHASES``: ``drain``'s holds
+    what ``sync``, which is not read, spent)."""
+    spend = _hand_clocks(monkeypatch)
+    ms = _ms
     w = PerfWatch()
 
     def tick(finish):
@@ -710,9 +730,6 @@ def test_phase_unit_self_times_and_carry(monkeypatch):
                 spend(8)                    # after the books closed
             spend(9)
 
-    def ms(d):
-        return {k: round(v * 1e3, 6) for k, v in d.items() if v}
-
     tick(lambda: w.tick_finish(1, working=True))
     first = {"tick": 28, "admit": 6, "dispatch": 3, "drain": 5, "sync": 6,
              "record": 7}
@@ -726,12 +743,91 @@ def test_phase_unit_self_times_and_carry(monkeypatch):
     assert ms(w.phase_s) == {"tick": 28 + 28 + 17, "admit": 12,
                              "dispatch": 6, "drain": 10, "sync": 12,
                              "record": 7 + 7 + 8}
+    assert ms(w.phase_cpu_s) == {"tick": (28 + 28 + 17) / 4,
+                                 "dispatch": 6 / 4, "drain": (10 + 12) / 4}
     assert w.metrics_numeric()["phase_record_s"] == 0.022
+    assert w.metrics_numeric()["phase_drain_cpu_s"] == 0.0055
+    assert "phase_record_cpu_s" not in w.metrics_numeric()
     assert w.metrics_numeric()["phase_plan_s"] == 0.0
 
 
+@pytest.mark.parametrize("case", ["sleeps", "spins", "nested", "sync_split",
+                                  "rolled_back"])
+def test_phase_books_thread_cpu_beside_wall(monkeypatch, case):
+    """One entry, two clocks: a phase that sleeps books wall >> cpu, one
+    that spins cpu ~ wall (the real clocks); nested self times partition
+    ``tick`` on BOTH clocks, ``sync_wait`` + ``sync_fetch`` + ``sync`` is
+    the sync window, and a rolled-back tick leaves nothing in any
+    ``_cpu_s`` (clocks moved by hand: exact)."""
+    w = PerfWatch()
+    if case in ("sleeps", "spins"):
+        with w.phase("tick", tick=1):
+            w.tick_begin()
+            with w.phase("prepare"):
+                if case == "sleeps":
+                    time.sleep(0.05)
+                else:
+                    c0 = time.thread_time()
+                    while time.thread_time() - c0 < 0.03:
+                        pass
+            flight = w.tick_finish(0, working=True)
+        wall, cpu = w.phase_s["prepare"], w.phase_cpu_s["prepare"]
+        assert flight["phases_cpu"]["prepare"] == round(cpu, 6)
+        if case == "sleeps":
+            assert wall >= 0.05 and cpu < 0.2 * wall, (wall, cpu)
+        else:
+            # the thread may be preempted while it spins, never credited
+            # CPU time it did not have (2 ms: the clocks' grain)
+            assert 0.03 <= cpu <= wall + 2e-3, (wall, cpu)
+        return
+    spend = _hand_clocks(monkeypatch)
+
+    def tick(finish):
+        with w.phase("tick", tick=1):
+            spend(1, 1)                     # in no phase, all of it CPU
+            w.tick_begin()
+            with w.phase("prepare"):
+                spend(2, 1)
+                with w.dispatch("tick.steady"):
+                    spend(4, 0.5)           # released the interpreter
+            w.phase_to("drain")
+            spend(2, 2)
+            with w.phase("sync"):
+                spend(0.5, 0.5)
+                with w.phase("sync_wait"):
+                    spend(16, 0)            # blocked: the device is busy
+                with w.phase("sync_fetch", n=2, bytes=136):
+                    spend(3, 0.25)
+                spend(0.5, 0.5)
+            w.phase_to(None)
+            finish()
+
+    tick(w.tick_abort if case == "rolled_back"
+         else lambda: w.tick_finish(1, working=True))
+    wall, cpu = _ms(w.phase_s), _ms(w.phase_cpu_s)
+    if case == "rolled_back":
+        assert wall == cpu == {}
+        assert not any(v for k, v in w.metrics_numeric().items()
+                       if k.startswith("phase_"))
+    elif case == "nested":
+        assert wall == {"tick": 29, "prepare": 2, "dispatch": 4, "drain": 2,
+                        "sync": 1, "sync_wait": 16, "sync_fetch": 3}
+        # ``sync`` is not read: its own 1 ms of CPU time is ``drain``'s
+        assert cpu == {"tick": 5.75, "prepare": 1, "dispatch": 0.5,
+                       "drain": 3, "sync_fetch": 0.25}
+        for booked in (wall, cpu):      # 1 ms, all CPU, lies in no phase
+            assert sum(v for k, v in booked.items() if k != "tick") \
+                == booked["tick"] - 1
+        assert set(w.phase_cpu_s) == set(CPU_PHASES)
+    else:
+        # the window the ``sync`` bucket times is the three phases' sum
+        window = wall["sync"] + wall["sync_wait"] + wall["sync_fetch"]
+        assert window == 20
+        assert w.metrics_numeric()["phase_sync_s"] == 0.001
+
+
 def test_phase_counters_partition_the_tick_and_agree_with_buckets(cfg_params):
-    """Over committed ticks the nine phases partition ``engine/tick``;
+    """Over committed ticks the eleven phases partition ``engine/tick``;
     ``dispatch`` and ``sync`` are the buckets of those names; and
     ``bookkeep`` (+ ``device``: host work that overlaps the device) is
     admit + prepare + drain + commit + the part of record before the
@@ -756,15 +852,25 @@ def test_phase_counters_partition_the_tick_and_agree_with_buckets(cfg_params):
     # a phase opens just before its bucket's window and closes just after:
     # never shorter, and longer by the span's own cost only (well under a
     # ms a tick even on a loaded test machine)
+    window = {"dispatch": ph["dispatch"],
+              "sync": ph["sync"] + ph["sync_wait"] + ph["sync_fetch"]}
     for name in ("dispatch", "sync"):
-        extra = ph[name] - _bucket_sum(eng, name)
+        extra = window[name] - _bucket_sum(eng, name)
         assert -1e-6 * n <= extra < 1e-3 * n, (name, extra)
+    # the thread's CPU time, phase by phase: never more than the wall time
+    # (to the clocks' grain), and partitioned by the same phases
+    cpu = _phase_counters(eng, "cpu_s")
+    assert set(cpu) == set(CPU_PHASES)
+    assert all(0 <= cpu[p] <= ph[p] + 2e-3 * n for p in cpu if p != "drain")
+    assert 0 <= cpu["drain"] <= ph["drain"] + ph["sync"] + 2e-3 * n
+    assert sum(v for k, v in cpu.items() if k != "tick") <= cpu["tick"] + 1e-9
     book = _bucket_sum(eng, "bookkeep") + _bucket_sum(eng, "device")
     inner = ph["admit"] + ph["prepare"] + ph["drain"] + ph["commit"]
     assert inner - 1e-3 * n <= book <= inner + ph["record"] + 1e-3 * n
     # every committed tick's record carries its own split
     for rec in ring:
         assert set(rec["phases"]) <= set(PHASES)
+        assert set(rec["phases_cpu"]) == set(rec["phases"]) & CPU_PHASES
         extra = rec["phases"]["dispatch"] - rec["attrib"]["dispatch"]
         assert -2e-6 <= extra < 1e-3, rec
     # the wait counters: three requests, each waited and was prefilled
@@ -772,6 +878,167 @@ def test_phase_counters_partition_the_tick_and_agree_with_buckets(cfg_params):
     assert m["first_tokens"] == 3
     assert m["queue_wait_s_total"] > 0
     assert m["admit_to_first_token_s_total"] > 0
+
+
+@pytest.mark.parametrize("horizon,spec_k", [(1, 0), (8, 0), (4, 2)])
+def test_d2h_total_counts_every_fetch_of_the_tick(cfg_params, horizon, spec_k):
+    """``d2h_total``, the mirror of ``h2d_total``: a tick that decodes
+    fetches the token block, the logprob block and ``n_exec`` (three), a
+    mixed tick that completes a prompt the first token and its logprob
+    before them (five), a pure-chunk tick nothing; a speculating tick's
+    accepted counts and its two draft counters ride the same window (six,
+    eight).  Every one of them inside ``engine/sync``: the first in
+    ``sync_wait``, the rest in ``sync_fetch``."""
+    cfg, params = cfg_params
+    eng = ServingEngine(cfg, params, EngineConfig(
+        max_rows=4, max_seq_len=256, page_size=32, prefill_bucket=32,
+        decode_horizon=horizon, spec_k=spec_k))
+    m = eng.metrics
+    first = Request(prompt_ids=_prompts(1, 40)[0], max_new_tokens=40)
+    late = Request(prompt_ids=_prompts(1, 40)[0][::-1], max_new_tokens=3)
+    eng.submit(first)
+    seen = set()                    # (mixed, completes, decodes, fetches)
+    for n in range(400):
+        if n == 4:
+            eng.submit(late)        # two chunks beside a decoding row
+        before = {k: m[k] for k in ("d2h_total", "mixed_steps",
+                                    "first_tokens", "host_syncs")}
+        eng._tick()
+        d = {k: m[k] - v for k, v in before.items()}
+        seen.add((d["mixed_steps"], d["first_tokens"], d["host_syncs"],
+                  d["d2h_total"]))
+        if first.finish_reason and late.finish_reason:
+            break
+    spec = 3 if spec_k else 0
+    assert seen == {(1, 0, 0, 0),           # a chunk alone: no sync at all
+                    (1, 1, 1, 5 + spec),    # mixed, completes a prompt
+                    (1, 0, 1, 3 + spec),    # mixed, beside a decoding row
+                    (0, 0, 1, 3 + spec)}, seen  # steady, any horizon
+    assert m["mixed_steps"] == 4
+    # the window's books: one sync_wait and one sync_fetch a syncing tick
+    ring = eng.flight.view()["ring"]
+    synced = [r for r in ring if "sync_wait" in r["phases"]]
+    assert len(synced) == m["host_syncs"]
+    assert all("sync_fetch" in r["phases"] for r in synced)
+
+
+def test_a_slow_tick_freezes_the_flight_ring(cfg_params, monkeypatch):
+    """A committed tick whose ``sync_wait`` passes the limit dumps the ring
+    (reason ``slow_tick``, the ring's own ``max_dumps`` bounds them) and
+    counts ``slow_ticks_total``; at the real limit no tick of a healthy
+    run does."""
+    from ipex_llm_tpu.serving import engine as engine_mod
+
+    cfg, params = cfg_params
+    ec = EngineConfig(max_rows=2, max_seq_len=128, page_size=16,
+                      prefill_bucket=16)
+    eng = ServingEngine(cfg, params, ec)
+    _drive(eng, [Request(prompt_ids=_prompts(1, 12)[0], max_new_tokens=12)])
+    assert eng.metrics["slow_ticks_total"] == 0
+    assert eng.flight.view()["dumps"] == []
+    monkeypatch.setattr(engine_mod, "SLOW_SYNC_WAIT_S", 0.0)
+    eng = ServingEngine(cfg, params, ec)
+    _drive(eng, [Request(prompt_ids=_prompts(1, 12)[0], max_new_tokens=12)])
+    assert eng.metrics["slow_ticks_total"] == eng.metrics["host_syncs"] > 8
+    dumps = eng.flight.view()["dumps"]
+    assert len(dumps) == eng.flight.dumps.maxlen
+    last = dumps[-1]
+    assert last["reason"] == "slow_tick" and last["sync_wait_s"] > 0
+    # the tick that lost the time is the ring's newest record
+    assert last["ring"][-1]["tick"] == last["tick"]
+    assert last["ring"][-1]["phases"]["sync_wait"] == last["sync_wait_s"]
+
+
+def test_commit_stamps_one_a_request_a_tick(cfg_params):
+    """The commit leaves ONE stamp a request a tick beside the items it
+    releases, there before them: a horizon's block shares it, the terminal
+    None rides the last one, and ``stream_stamp`` hands each item its
+    commit's time in order."""
+    from ipex_llm_tpu.serving.engine import stream_stamp
+
+    cfg, params = cfg_params
+    eng = ServingEngine(cfg, params, EngineConfig(
+        max_rows=2, max_seq_len=128, page_size=16, prefill_bucket=16,
+        decode_horizon=4))
+    reqs = [Request(prompt_ids=p, max_new_tokens=9) for p in _prompts(2, 12)]
+    for r in reqs:
+        eng.submit(r)
+    while not all(r.finish_reason for r in reqs):
+        n0 = [len(r.commit_stamps) for r in reqs]
+        t0 = time.perf_counter()
+        eng._tick()
+        for r, n in zip(reqs, n0):
+            new = list(r.commit_stamps)[n:]
+            assert len(new) <= 1
+            assert all(t0 <= t <= time.perf_counter() for t, _ in new)
+    for r in reqs:
+        # the first token and the one its row decoded beside it, a block
+        # of four, the last three with the None
+        assert [n for _, n in r.commit_stamps] == [2, 4, 4]
+        items, stamps = [], []
+        while not r.stream_queue.empty():
+            items.append(r.stream_queue.get())
+            stamps.append(stream_stamp(r))
+        assert items == r.output_ids + [None] and len(items) == 10
+        assert stamps == sorted(stamps) and len(set(stamps)) == 3
+        assert len(set(stamps[2:6])) == 1
+        assert not r.commit_stamps and stream_stamp(r) is None
+
+
+@pytest.mark.parametrize("stream", [True, False])
+def test_server_counts_a_tokens_way_to_the_socket(cfg_params, stream):
+    """``stream_events_total`` is the token events written (the tokens
+    collected, without a stream), each with ``0 <= wake <= deliver`` from
+    its commit's stamp, the event loop's CPU time beside them, and the
+    ``stream_deliver_s`` histogram one observation an event."""
+    pytest.importorskip("aiohttp")
+    from ipex_llm_tpu.serving.api_server import OpenAIServer
+
+    cfg, params = cfg_params
+    eng = ServingEngine(cfg, params, EngineConfig(
+        max_rows=4, max_seq_len=256, page_size=32, prefill_bucket=32,
+        decode_horizon=4)).start()
+    srv = OpenAIServer(eng, _Tok(), "tiny")
+    port, _ = _serve(srv)
+    try:
+        m0 = json.loads(_get(port, "/metrics?format=json"))
+        assert m0["metrics"]["stream_events_total"] == 0
+        assert m0["histograms"]["stream_deliver_s"]["count"] == 0
+        events = 0
+        for i in range(2):
+            body = json.dumps({"prompt": " ".join(map(str, range(i + 1, 30))),
+                               "max_tokens": 11, "temperature": 0.0,
+                               "stream": stream}).encode()
+            with urllib.request.urlopen(urllib.request.Request(
+                    f"http://127.0.0.1:{port}/v1/completions", data=body,
+                    headers={"Content-Type": "application/json"}),
+                    timeout=60) as resp:
+                raw = resp.read().decode()
+            if stream:
+                chunks = [json.loads(ln[6:]) for ln in raw.splitlines()
+                          if ln.startswith("data: {")]
+                events += sum(1 for c in chunks if c["choices"][0]["text"])
+            else:
+                events += json.loads(raw)["usage"]["completion_tokens"]
+        assert events == 22
+        got = json.loads(_get(port, "/metrics?format=json"))
+        m = got["metrics"]
+        assert m["stream_events_total"] == events
+        assert 0 <= m["stream_wake_s_total"] <= m["stream_deliver_s_total"]
+        assert 0 < m["stream_cpu_s_total"]
+        # a write that does not block costs the loop's thread what it
+        # takes (2 ms an event: the clocks' grain, a loaded test machine)
+        assert m["stream_cpu_s_total"] <= (m["stream_deliver_s_total"]
+                                           - m["stream_wake_s_total"]
+                                           + 2e-3 * events)
+        h = got["histograms"]["stream_deliver_s"]
+        assert h["count"] == events
+        assert h["sum"] == pytest.approx(m["stream_deliver_s_total"],
+                                         abs=1e-4)
+        assert "ipex_llm_tpu_stream_deliver_s_bucket" in _get(port,
+                                                              "/metrics")
+    finally:
+        eng.stop()
 
 
 @pytest.mark.parametrize("kind", ["rollback", "probe", "idle"])
@@ -796,13 +1063,13 @@ def test_phase_and_wait_counters_skip_uncommitted_ticks(cfg_params, kind):
         eng.submit(r)
     uncommitted = 0
     for _ in range(400):
-        before = (_phase_counters(eng),
+        before = (_phase_counters(eng), _phase_counters(eng, "cpu_s"),
                   {k: eng.metrics[k] for k in WAIT_COUNTERS})
         idle = all(r.finish_reason is not None for r in reqs)
         committed = eng._tick()
         if not committed or idle:
             uncommitted += 1
-            after = (_phase_counters(eng),
+            after = (_phase_counters(eng), _phase_counters(eng, "cpu_s"),
                      {k: eng.metrics[k] for k in WAIT_COUNTERS})
             assert after == before, (kind, committed, idle)
         if idle and uncommitted >= 3:
