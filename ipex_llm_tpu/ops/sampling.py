@@ -82,6 +82,40 @@ def _top_k_mask_rows(logits: jnp.ndarray, ks: jnp.ndarray) -> jnp.ndarray:
     return jnp.where((ks > 0)[:, None], masked, logits)
 
 
+def _top_p_mask_rows(logits: jnp.ndarray, ps: jnp.ndarray) -> jnp.ndarray:
+    """Per-row nucleus mask; ps [R], >=1 disables for that row: it gets its
+    logits back as they came, whatever its neighbours asked for."""
+    return jnp.where((ps < 1)[:, None], _top_p_mask(logits, ps), logits)
+
+
+def _draw_rows(logits, temps, top_ps, key, seeds, steps, top_ks, draws):
+    """The drawing half of ``sample_rows_with_logprobs``: a token a row from
+    its temperature-scaled, masked distribution.  Each mask's full-vocabulary
+    sort runs only if a drawing row (``draws`` [R] bool) set it, and selects
+    by row, so a row that set none draws from the same bits either way."""
+    scaled = logits / jnp.maximum(temps, 1e-4)[:, None]
+    if top_ks is not None:
+        scaled = jax.lax.cond(
+            jnp.any(draws & (top_ks > 0)),
+            lambda: _top_k_mask_rows(scaled, top_ks), lambda: scaled)
+    scaled = jax.lax.cond(
+        jnp.any(draws & (top_ps < 1)),
+        lambda: _top_p_mask_rows(scaled, top_ps), lambda: scaled)
+    if seeds is None:
+        sampled = jax.random.categorical(key, scaled, axis=-1)
+    else:
+        r = logits.shape[0]
+        base = jax.vmap(lambda i: jax.random.fold_in(key, i))(jnp.arange(r))
+        seeded = jax.vmap(
+            lambda sd, st: jax.random.fold_in(jax.random.PRNGKey(sd), st)
+        )(jnp.maximum(seeds, 0), steps)
+        keys = jnp.where((seeds >= 0)[:, None], seeded, base)
+        sampled = jax.vmap(
+            lambda k, lg: jax.random.categorical(k, lg)
+        )(keys, scaled)
+    return sampled.astype(jnp.int32)
+
+
 @jax.named_scope("sample")
 def sample_rows_with_logprobs(logits: jnp.ndarray, temps: jnp.ndarray,
                               top_ps: jnp.ndarray, key: jax.Array,
@@ -105,27 +139,24 @@ def sample_rows_with_logprobs(logits: jnp.ndarray, temps: jnp.ndarray,
     ``active`` [R] bool masks dead rows to (token 0, logprob 0) — ONE
     definition of the serving engines' row masking, shared by the plain,
     pipelined, and fused-horizon decode steps so their emitted padding
-    stays identical."""
+    stays identical.
+
+    A stage runs only if a row asks for it, decided ON THE DEVICE from the
+    rows handed in: the argmax, the chosen token's log-probability and the
+    dead-row mask are outside any branch (a greedy row's bits are the same
+    operations whatever its batch does); scaling, keys, noise and the draw
+    run only if a live row has ``temperature > 0`` (``_draw_rows``), and
+    each mask's sort only if such a row set it.  ``top_p >= 1`` and
+    ``top_k <= 0`` are exactly "off"."""
     logits = logits.astype(jnp.float32)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temps, 1e-4)[:, None]
-    if top_ks is not None:
-        scaled = _top_k_mask_rows(scaled, top_ks)
-    scaled = _top_p_mask(scaled, top_ps)
-    r = logits.shape[0]
-    if seeds is None:
-        sampled = jax.random.categorical(key, scaled, axis=-1)
-    else:
-        base = jax.vmap(lambda i: jax.random.fold_in(key, i))(jnp.arange(r))
-        seeded = jax.vmap(
-            lambda sd, st: jax.random.fold_in(jax.random.PRNGKey(sd), st)
-        )(jnp.maximum(seeds, 0), steps)
-        keys = jnp.where((seeds >= 0)[:, None], seeded, base)
-        sampled = jax.vmap(
-            lambda k, lg: jax.random.categorical(k, lg)
-        )(keys, scaled)
-    sampled = sampled.astype(jnp.int32)
-    chosen = jnp.where(temps > 0, sampled, greedy)
+    draws = temps > 0 if active is None else (temps > 0) & active
+    sampled = jax.lax.cond(
+        jnp.any(draws),
+        lambda: _draw_rows(logits, temps, top_ps, key, seeds, steps, top_ks,
+                           draws),
+        lambda: greedy)
+    chosen = jnp.where(draws, sampled, greedy)
     lp = jnp.take_along_axis(
         jax.nn.log_softmax(logits, axis=-1), chosen[:, None], axis=-1
     )[:, 0]

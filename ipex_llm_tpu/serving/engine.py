@@ -1410,6 +1410,14 @@ class ServingEngine:
                         # over dispatched ticks (_count_attn_pages)
                         "attn_pages_live_total": 0,
                         "attn_pages_table_total": 0,
+                        # how often the sampler's stages engage, over
+                        # dispatched ticks (_count_sample_stages): sampler
+                        # calls (one a decode stage, one a first-token
+                        # stage), those with a live row that draws, those
+                        # with a live drawing row that set a mask (a sort)
+                        "sample_stages_total": 0,
+                        "sample_draw_stages_total": 0,
+                        "sample_sort_stages_total": 0,
                         # fault-domain observability: per-request failures
                         # isolated by bisection, transient step retries,
                         # load-shed submissions, expired deadlines, and
@@ -1749,6 +1757,27 @@ class ServingEngine:
         return {"dsa_keys_scored": seen * self.cfg.index_layers,
                 "dsa_tokens_selected": (sel + dec) * layers,
                 "dsa_decode_selected": dec * layers}
+
+    def _count_sample_stages(self, *stages):
+        """What the tick about to be dispatched asks of the sampler
+        (ops/sampling.py decides the same on the device, from the same row
+        parameters): each of ``stages`` is the engine rows live in one
+        sampler call, or None for a stage the program does not hold.  A
+        stage DRAWS if a live row has ``temperature > 0`` and SORTS if such
+        a row set ``top_p < 1`` or ``top_k > 0``.  An upper bound by the
+        rows a first token ends before they decode, as
+        ``_count_attn_pages`` is a lower one; rollback-covered with the
+        metrics."""
+        m = self.metrics
+        for rows in stages:
+            if rows is None:
+                continue
+            rows = np.asarray(rows, np.int64)
+            draws = rows[self.temps[rows] > 0]
+            m["sample_stages_total"] += 1
+            m["sample_draw_stages_total"] += int(draws.size > 0)
+            m["sample_sort_stages_total"] += int(
+                ((self.top_ps[draws] < 1) | (self.top_ks[draws] > 0)).any())
 
     def _perf_dispatch(self, family: str, point: dict | None = None,
                        tick: bool = True, **attrs):
@@ -3477,6 +3506,9 @@ class ServingEngine:
         attn_pages = self._count_attn_pages(
             active, with_decode, chunks, base, n_valid,
             prefill_table=p_b * maxp_b)
+        self._count_sample_stages(
+            [row for s, row, _ in chunks if emit[s]],
+            decode_rows if with_decode else None)
         kw = {}
         if tick_spec:
             self.metrics["h2d_total"] += 1
@@ -3658,6 +3690,7 @@ class ServingEngine:
                                    ew=int(dev["eos"].shape[1]))
         rows_active = int(active.sum())
         attn_pages = self._count_attn_pages(active)
+        self._count_sample_stages(np.flatnonzero(active))
         if fused_spec:
             # the spec-enabled form of the SAME single entry: drafting,
             # the [R, k+1] verify, and acceptance all ride inside the
