@@ -316,6 +316,29 @@ def build_hybrid_params(cfg: ModelConfig, get: Callable[[str], np.ndarray],
     return params
 
 
+def _hyper_connection(get, i: int, sub: str) -> dict:
+    """One sublayer's hyper-connection tensors as ops/hyper.py reads them:
+    ``hc_<sub>_proj`` float32 [n + n + n*n, n*C] (rows: the pre-mix's, the
+    post-mix's, the residual mix's row-major; columns: stream-major, as
+    ``vec(X)``), ``hc_<sub>_scale`` [3] and ``hc_<sub>_bias`` [n + n + n*n]
+    in the same order.  Checkpoint names
+    ``model.layers.{i}.{attn,mlp}_hc.{pre,post,res}_{proj.weight,scale,bias}``
+    (``proj.weight`` a Linear's [out, n*C]; ``res_bias`` [n, n])."""
+    pre = f"model.layers.{i}.{sub}_hc."
+    parts = ("pre", "post", "res")
+    return {
+        f"hc_{sub}_proj": jnp.asarray(np.concatenate(
+            [get(pre + p + "_proj.weight") for p in parts], axis=0),
+            jnp.float32),
+        f"hc_{sub}_scale": jnp.asarray(np.stack(
+            [np.asarray(get(pre + p + "_scale")).reshape(()) for p in parts]),
+            jnp.float32),
+        f"hc_{sub}_bias": jnp.asarray(np.concatenate(
+            [np.asarray(get(pre + p + "_bias")).reshape(-1) for p in parts]),
+            jnp.float32),
+    }
+
+
 def build_params(
     cfg: ModelConfig,
     scheme: WeightScheme,
@@ -569,6 +592,15 @@ def build_params(
         if db is not None:
             lp["down_bias"] = jnp.asarray(db, jnp.float32)
         layers.append(lp)
+
+    # hyper-connected residual streams (cfg.hc_mult > 1; ops/hyper.py): each
+    # sublayer's three projections merged into ONE float32 plane, its
+    # scalars and biases beside it.  Never quantized: they are the residual
+    # path itself
+    if cfg.hc_mult > 1:
+        for i, lp in enumerate(layers):
+            for sub in ("attn", "mlp"):
+                lp.update(_hyper_connection(get, i, sub))
 
     # a model with an indexer (cfg.index_topk): the layers whose indexer is
     # "full" carry its weights, stacked for THOSE layers only and hung on

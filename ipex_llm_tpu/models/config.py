@@ -148,6 +148,16 @@ class ModelConfig:
     index_head_dim: int = 0
     indexer_types: tuple[str, ...] | None = None
 
+    # manifold-constrained hyper-connections (xing4_0; Xie et al.,
+    # arXiv:2512.24880): the residual is ``hc_mult`` streams a token, and
+    # every sublayer reads a learned mix of them and writes back through a
+    # (Sinkhorn-normalised, ``hc_sinkhorn_iters`` rounds) doubly stochastic
+    # mix (ops/hyper.py).  1: one stream, ``x + F(norm(x))``
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 0.0
+    hc_res_clamp: tuple[float, float] = (0.0, 0.0)
+
     def layer_is_sliding(self, layer_idx: int) -> bool:
         if self.layer_types is not None:
             return self.layer_types[layer_idx] == "sliding_attention"

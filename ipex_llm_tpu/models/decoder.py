@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from ipex_llm_tpu.kv import MOE_COUNTERS, STATE_COUNTERS, KVCache
 from ipex_llm_tpu.models.config import ModelConfig
+from ipex_llm_tpu.ops import hyper as hyper_ops
 from ipex_llm_tpu.ops import linear as linear_ops
 from ipex_llm_tpu.ops import mlp as mlp_ops
 from ipex_llm_tpu.ops import rope as rope_ops
@@ -925,6 +926,11 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
     ``indexer`` stack, an entry a FULL layer of the tree only, read at the
     full layer's index; its index keys are ``v_stack``, a layer a full layer
     of the MODEL.  The return gains ``(selection, int32 [5] counters)``.
+
+    A model of HYPER-CONNECTED residual streams (``cfg.hc_mult`` > 1) carries
+    ``x`` as ``[n, B, T, C]``, the streams leading (ops/hyper.py): each
+    sublayer reads the mix ``h`` [B, T, C] its coefficients give and the
+    streams are re-mixed around its output, where another model adds.
     """
     n = sliding_flags.shape[0]
     dsa = selection is not None
@@ -946,7 +952,7 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
     moe_whole = {}
     if held:
         if valid is None:
-            valid = jnp.ones(x.shape[:2], bool)
+            valid = jnp.ones(x.shape[-3:-1], bool)
         moe_whole = {k_: tree[k_] for k_ in ("moe_gate_up", "moe_down")}
         tree = {k_: v_ for k_, v_ in tree.items() if k_ not in moe_whole}
     tree_xs, view = layer_views(tree)
@@ -982,9 +988,14 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
             m_in = _in_norm(x, lp, "mlp_norm", cfg)
             x = m_in * alpha + _mlp_block(cfg, lp, m_in, pre_normed=True)
             return x, kl, vl, obs_q, no_stats
+        hc = cfg.hc_mult > 1
+        attn_in = x
+        if hc:
+            coef = hyper_ops.coefficients(cfg, lp, "attn", x)
+            attn_in = hyper_ops.pre_mix(coef, x)
         attn_out, kl, vl, obs_q, *sparse = _attention_block(
-            cfg, lp, x, kl, vl, c, s_, slot0, q_slots, kv_len, kv_start,
-            sliding, cache, collect_obs, bias=alibi_bias,
+            cfg, lp, attn_in, kl, vl, c, s_, slot0, q_slots, kv_len,
+            kv_start, sliding, cache, collect_obs, bias=alibi_bias,
             chunk_lens=chunk_lens, layer=layer, dsa=dsa_call,
         )
         # minicpm depth scaling (cfg.residual_multiplier, 1.0 elsewhere)
@@ -994,7 +1005,14 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
         def add(res, out):
             return res + out if rm is None else res + rm * out
 
-        if cfg.parallel_blocks:
+        if hc:
+            # every sublayer reads its own mix of the streams and the
+            # streams are re-mixed around what it returns (ops/hyper.py)
+            x = hyper_ops.post_mix(coef, x, attn_out)
+            coef = hyper_ops.coefficients(cfg, lp, "mlp", x)
+            out, stats = ffn(lp, hyper_ops.pre_mix(coef, x), i)
+            x = hyper_ops.post_mix(coef, x, out)
+        elif cfg.parallel_blocks:
             # x + attn(ln(x)) + mlp(ln'(x)) — phi/gpt-neox parallel residual
             out, stats = ffn(lp, x, i)
             x = add(x, attn_out + out)
@@ -1154,6 +1172,10 @@ def decoder_forward(
     sliding_flags = jnp.array(
         [cfg.layer_is_sliding(l) for l in range(cfg.num_layers)], dtype=bool
     )
+    if cfg.hc_mult > 1:
+        # hyper-connected residual streams: the embedding goes into every
+        # stream, and their sum leaves for the final norm (ops/hyper.py)
+        x = hyper_ops.expand(x, cfg.hc_mult)
 
     # deepseek-style dense-prefix models carry two layer stacks (plain-MLP
     # prefix + MoE rest, models/build.py); each runs its own scan over its
@@ -1207,6 +1229,8 @@ def decoder_forward(
                 state["stats"] = state["stats"].at[n0:].add(dsa_stats)
     obs_q = (jnp.concatenate(obs_parts) if len(obs_parts) > 1
              else obs_parts[0])
+    if cfg.hc_mult > 1:
+        x = hyper_ops.collapse(x)
 
     logits = _chosen_logits(cfg, params, x, last_token_only,
                             gather_positions)

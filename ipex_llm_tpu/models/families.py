@@ -450,7 +450,8 @@ def _axk1(hf: dict) -> ModelConfig:
     layer then expert layers beside a shared expert) with a sigmoid router,
     the V3 group score (a group's two best) and NO score-correction bias
     (``topk_method: "none"``; ``noaux_tc``, the key that carries one, is
-    read by ``deepseek_v3`` and, over held experts, by ``glm_moe_dsa``).
+    read by ``deepseek_v3`` and, over held experts, by ``glm_moe_dsa`` and
+    ``xing4_0``).
     Its expert layers are HELD layers (models/decoder.py
     ``_held_moe_block``): ``n_router_experts`` and ``first_routed_expert``
     describe a chip's share of a deployment that divides the experts, the
@@ -460,11 +461,20 @@ def _axk1(hf: dict) -> ModelConfig:
         raise ValueError(
             f"axk1: topk_method {hf['topk_method']!r} is not supported "
             "(\"none\": selection on the raw scores, no "
-            "e_score_correction_bias; the held-expert family that carries "
-            "\"noaux_tc\" is glm_moe_dsa)")
+            "e_score_correction_bias; the held-expert families that carry "
+            "\"noaux_tc\" are glm_moe_dsa and xing4_0)")
     d = _deepseek_common(hf)
+    d.update(_held_experts(hf))
+    return ModelConfig(**d)
+
+
+def _held_experts(hf: dict) -> dict:
+    """The keys of a DeepSeek-V3 expert layer that is told which experts it
+    holds (``_axk1``): ``n_routed_experts`` of the router's
+    ``n_router_experts``, from ``first_routed_expert`` on; sigmoid scores,
+    the V3 group score."""
     held = hf["n_routed_experts"]
-    d.update(
+    return dict(
         num_experts=hf.get("n_router_experts", held),
         moe_first_expert=hf.get("first_routed_expert", 0),
         moe_experts_held=held,
@@ -473,7 +483,14 @@ def _axk1(hf: dict) -> ModelConfig:
         moe_score_func=hf.get("scoring_func", "sigmoid"),
         moe_group_score="top2sum",
     )
-    return ModelConfig(**d)
+
+
+def _require_noaux_tc(hf: dict) -> None:
+    if hf.get("topk_method", "noaux_tc") != "noaux_tc":
+        raise ValueError(
+            f"{hf['model_type']}: topk_method {hf['topk_method']!r} is not "
+            "supported (\"noaux_tc\": selection on score + "
+            "e_score_correction_bias)")
 
 
 def _glm_moe_dsa(hf: dict) -> ModelConfig:
@@ -489,11 +506,7 @@ def _glm_moe_dsa(hf: dict) -> ModelConfig:
     layers as ``_axk1`` reads them (``n_router_experts``,
     ``first_routed_expert``).  The multi-token-prediction layer is a draft
     head and is not built."""
-    if hf.get("topk_method", "noaux_tc") != "noaux_tc":
-        raise ValueError(
-            f"glm_moe_dsa: topk_method {hf['topk_method']!r} is not "
-            "supported (\"noaux_tc\": selection on score + "
-            "e_score_correction_bias)")
+    _require_noaux_tc(hf)
     n = hf["num_hidden_layers"]
     hf2 = dict(hf)
     rp = hf.get("rope_parameters") or {}
@@ -522,20 +535,41 @@ def _glm_moe_dsa(hf: dict) -> ModelConfig:
             "glm_moe_dsa: indexer_types must name every layer \"full\" or "
             f"\"shared\" and start with a full one, got {list(types)}")
     d = _deepseek_common(hf2)
-    held = hf["n_routed_experts"]
     d.update(
-        num_experts=hf.get("n_router_experts", held),
-        moe_first_expert=hf.get("first_routed_expert", 0),
-        moe_experts_held=held,
-        moe_n_group=hf.get("n_group") or 0,
-        moe_topk_group=hf.get("topk_group") or 0,
-        moe_score_func=hf.get("scoring_func", "sigmoid"),
-        moe_group_score="top2sum",
+        _held_experts(hf),
         moe_score_bias=True,
         index_topk=hf["index_topk"],
         index_n_heads=hf["index_n_heads"],
         index_head_dim=hf["index_head_dim"],
         indexer_types=types,
+    )
+    return ModelConfig(**d)
+
+
+def _xing4_0(hf: dict) -> ModelConfig:
+    """Xing4.0 (``xing4_0``): the DeepSeek-V3 block (MLA with a low-rank q,
+    YaRN by the DeepSeek convention of ``_deepseek_common``, dense layers
+    then sigmoid ``noaux_tc`` expert layers beside a shared expert;
+    ``n_group`` 1, so no group step) on a residual path of ``hc_mult``
+    streams: every attention and MLP / expert sublayer is wrapped in a
+    manifold-constrained hyper-connection (ops/hyper.py; models/decoder.py
+    ``run_layers``).  Expert layers are HELD layers as ``_axk1`` reads them
+    (``n_router_experts``, ``first_routed_expert``).  The multi-token-
+    prediction layer is a draft head and is not built."""
+    _require_noaux_tc(hf)
+    if hf.get("hc_mult", 1) < 2:
+        raise ValueError(
+            f"xing4_0: hc_mult {hf.get('hc_mult')!r}: the family's residual "
+            "path is two or more hyper-connected streams")
+    d = _deepseek_common(hf)
+    d.update(
+        _held_experts(hf),
+        moe_score_bias=True,
+        hc_mult=hf["hc_mult"],
+        hc_sinkhorn_iters=hf["hc_sinkhorn_iters"],
+        hc_eps=hf["hc_eps"],
+        hc_res_clamp=(float(hf["mhc_h_res_clamp_min"]),
+                      float(hf["mhc_h_res_clamp_max"])),
     )
     return ModelConfig(**d)
 
@@ -1330,6 +1364,8 @@ FAMILIES: dict[str, Family] = {
     "axk1": Family("axk1", _axk1, _DEEPSEEK_SCHEME, _DEEPSEEK_MOE),
     "glm_moe_dsa": Family("glm_moe_dsa", _glm_moe_dsa, _DEEPSEEK_SCHEME,
                           _DEEPSEEK_V3_MOE),
+    "xing4_0": Family("xing4_0", _xing4_0, _DEEPSEEK_SCHEME,
+                      _DEEPSEEK_V3_MOE),
     "nemotron_h": Family("nemotron_h", _nemotron_h),
     "mixtral": Family("mixtral", _mixtral, WeightScheme(), _MIXTRAL_MOE),
     "qwen2_moe": Family("qwen2_moe", _qwen2_moe, WeightScheme(), _QWEN2_MOE),

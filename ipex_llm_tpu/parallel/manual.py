@@ -75,6 +75,10 @@ def ineligible_reason(cfg: ModelConfig, params: dict,
     if cfg.num_heads % tp or cfg.num_kv_heads % tp:
         return (f"heads do not divide tp ({cfg.num_heads}q/"
                 f"{cfg.num_kv_heads}kv over tp={tp})")
+    if cfg.hc_mult > 1:
+        return (f"model_type {cfg.model_type!r}: {cfg.hc_mult} "
+                "hyper-connected residual streams are not manual-sharded "
+                "(their coefficients reduce over the full hidden width)")
     if cfg.is_mla:
         return "MLA attention (low-rank q/kv) not manual-sharded yet"
     if cfg.alibi:

@@ -68,6 +68,11 @@ def pipeline_forward(
         run_layers,
     )
 
+    if cfg.hc_mult > 1:
+        raise NotImplementedError(
+            f"model_type {cfg.model_type!r} carries {cfg.hc_mult} "
+            "hyper-connected residual streams: a stage hand-over moves one "
+            "[B, T, C] stream (ops/hyper.py)")
     if "layers_dense" in params:
         raise NotImplementedError(
             "dense-prefix MoE models don't pipeline yet (two stacks)"

@@ -1407,7 +1407,7 @@ class ServingEngine:
                         "first_tokens": 0,
                         # what the attention kernel had to read against
                         # the table width it was handed, in pages, summed
-                        # over dispatched ticks (_count_attn_pages)
+                        # over dispatched ticks (_count_tick_work)
                         "attn_pages_live_total": 0,
                         "attn_pages_table_total": 0,
                         # how often the sampler's stages engage, over
@@ -1705,11 +1705,14 @@ class ServingEngine:
         scrape-visible, so the exposed series stay monotonic."""
         return self._hists_committed
 
-    def _count_attn_pages(self, active, decode: bool = True, chunks=(),
-                          base=None, n_valid=None,
-                          prefill_table: int = 0) -> dict:
-        """What the tick about to be dispatched asks of the attention
-        kernel, in pages, from lengths the host holds: ``attn_pages_live``
+    def _count_tick_work(self, active, decode: bool = True, chunks=(),
+                         base=None, n_valid=None,
+                         prefill_table: int = 0) -> dict:
+        """What the tick about to be dispatched asks, from lengths the host
+        holds: of a model's hyper-connections in tokens
+        (:meth:`_count_mixed_tokens`), of an indexer and its sparse walk
+        (:meth:`_count_selection`), and of the attention kernel in pages.
+        The attention kernel's: ``attn_pages_live``
         the sum over the rows that attend of ``cdiv(kv_len, page_size)``
         (decode rows at ``row_lens + 1``, prefill chunks at their end),
         ``attn_pages_table`` the rows of each stage times the table width
@@ -1728,10 +1731,26 @@ class ServingEngine:
         self.metrics["attn_pages_live_total"] += live
         self.metrics["attn_pages_table_total"] += table
         out = {"attn_pages_live": live, "attn_pages_table": table}
+        if self.cfg.hc_mult > 1:
+            out.update(self._count_mixed_tokens(active, decode, chunks,
+                                                n_valid))
         if self.cfg.index_topk:
             out.update(self._count_selection(active, decode, chunks, base,
                                              n_valid))
         return out
+
+    def _count_mixed_tokens(self, active, decode, chunks, n_valid) -> dict:
+        """What the same tick asks of a model's HYPER-CONNECTIONS
+        (ops/hyper.py), the same lower bound: ``mhc_tokens`` the tokens it
+        mixes, a decode row one and a chunk its valid ones, in each of a
+        layer's two sublayers.  Added to ``mhc_tokens_mixed_total``
+        (rollback-covered with the metrics)."""
+        tokens = ((int(np.count_nonzero(active)) if decode else 0)
+                  + sum(int(n_valid[i]) for i, _, _ in chunks))
+        mixed = tokens * 2 * self.cfg.num_layers
+        self.metrics["mhc_tokens_mixed_total"] = (
+            self.metrics.get("mhc_tokens_mixed_total", 0) + mixed)
+        return {"mhc_tokens": mixed}
 
     def _count_selection(self, active, decode, chunks, base, n_valid) -> dict:
         """What the same tick asks of a model's INDEXER and of its sparse
@@ -1766,7 +1785,7 @@ class ServingEngine:
         stage DRAWS if a live row has ``temperature > 0`` and SORTS if such
         a row set ``top_p < 1`` or ``top_k > 0``.  An upper bound by the
         rows a first token ends before they decode, as
-        ``_count_attn_pages`` is a lower one; rollback-covered with the
+        ``_count_tick_work`` is a lower one; rollback-covered with the
         metrics."""
         m = self.metrics
         for rows in stages:
@@ -3503,7 +3522,7 @@ class ServingEngine:
             1, width=width, with_decode=with_decode, spec=tick_spec,
             pb=p_b, maxp=maxp_b, ew=layout.e_w)
         rows_active = int(active.sum())
-        attn_pages = self._count_attn_pages(
+        tick_work = self._count_tick_work(
             active, with_decode, chunks, base, n_valid,
             prefill_table=p_b * maxp_b)
         self._count_sample_stages(
@@ -3518,7 +3537,7 @@ class ServingEngine:
         none5 = (None,) * 5        # an epoch carries no row state in
         with self._perf_dispatch(
                 "tick.spec" if tick_spec else "tick.admission",
-                point=perf_pt, rows_active=rows_active, **attn_pages):
+                point=perf_pt, rows_active=rows_active, **tick_work):
             out = _ragged_tick(
                 self.cfg, self.params, cache_in, *none5, self.key, *none5,
                 prefill=buf, epoch=layout, horizon=1,
@@ -3607,6 +3626,7 @@ class ServingEngine:
         self._trace_decode(parts, t0_w, executed, take_np)
         self.metrics["tokens_per_sync"] = round(
             self.metrics["tokens"] / max(self.metrics["host_syncs"], 1), 2)
+        self._publish_ahead(1)
 
     def _horizon_step(self, active: np.ndarray):
         """Fused decode: up to ``decode_horizon`` decode+sample steps in one
@@ -3689,7 +3709,7 @@ class ServingEngine:
         perf_pt = self._perf_point(h, width=0, spec=fused_spec,
                                    ew=int(dev["eos"].shape[1]))
         rows_active = int(active.sum())
-        attn_pages = self._count_attn_pages(active)
+        tick_work = self._count_tick_work(active)
         self._count_sample_stages(np.flatnonzero(active))
         if fused_spec:
             # the spec-enabled form of the SAME single entry: drafting,
@@ -3697,7 +3717,7 @@ class ServingEngine:
             # horizon loop — still one dispatch (JP106 unchanged)
             self.metrics["h2d_total"] += 1      # the draft widths
             with self._perf_dispatch("tick.spec", point=perf_pt,
-                                     rows_active=rows_active, **attn_pages):
+                                     rows_active=rows_active, **tick_work):
                 (_, _, tok_block, lp_block, n_exec, self.cache,
                  dev["toks"], dev["row_lens"], dev["active"],
                  dev["steps"], dev["remain"], self.key, take_block,
@@ -3719,7 +3739,7 @@ class ServingEngine:
             # to exactly 1
             cache_in, state_src = self._state_call()
             with self._perf_dispatch("tick.steady", point=perf_pt,
-                                     rows_active=rows_active, **attn_pages):
+                                     rows_active=rows_active, **tick_work):
                 (_, _, tok_block, lp_block, n_exec, self.cache,
                  dev["toks"], dev["row_lens"], dev["active"],
                  dev["steps"], dev["remain"], self.key) = _ragged_tick(
@@ -3763,6 +3783,48 @@ class ServingEngine:
         self._trace_decode(parts, t0_w, executed, take_np)
         self.metrics["tokens_per_sync"] = round(
             self.metrics["tokens"] / self.metrics["host_syncs"], 2)
+        self._publish_ahead(H)
+
+    def _publish_ahead(self, horizon: int):
+        """What the NEXT steady tick would publish before its dispatch,
+        published now, before this tick commits: the pages its rows will
+        write (a row that ends this tick on a page's edge) and, after a
+        finish or a completed prompt, the row state.
+
+        An upload and the unpack's dispatch each give up the interpreter's
+        lock.  Between a commit and the next dispatch every stream's
+        consumer wants it (a token's way to its socket), so an engine that
+        published there waited for the lock twice with the device idle:
+        3.5 ms an epoch at 16 streams, beside the 1.4 ms the work takes
+        (PERF.md section 6, PR 44).  Before the commit the consumers sleep
+        on their queues.  Not where the next tick is a mixed one (a row
+        still prefilling, a request waiting beside a free row): that tick
+        publishes an epoch of its own.  A dry pool is left to the tick that
+        needs the page, which clamps and counts."""
+        if self._prefilling or (self.queue_depth
+                                and self._free_row() is not None):
+            return
+        active = np.flatnonzero(self._active_mask())
+        if not active.size:
+            return                     # nothing decodes: no steady tick
+        page = self.ec.page_size
+        short = {}                     # row -> (pages it will need, missing)
+        for i in active:
+            rem = int(self.row_budget[i]) - len(self.rows[i].output_ids)
+            need = min(-(-(int(self.row_lens[i]) + min(horizon, rem)) // page),
+                       self.ec.max_pages)
+            if rem > 0 and self.tables[i, need - 1] < 0:
+                short[i] = need, int((self.tables[i, :need] < 0).sum())
+        if short:
+            missing = sum(n for _, n in short.values())
+            self.alloc.reserve(missing)
+            if len(self.alloc.free) < missing:
+                return
+            for i, (need, _) in short.items():
+                self._ensure_pages(i, need * page)
+        if self._dirty or self._tables_dirty:
+            self._phase_to("prepare")
+            self._sync_device_state()
 
     def _decode_parts(self, active: np.ndarray):
         """Tracing pre-capture for a decode drain: the participating

@@ -165,9 +165,9 @@ def test_the_cell_resolves_with_its_metrics():
     bench = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     assert [w["name"] for w in bench["workloads"]].count(CELL) == 1
     assert [w["config"] for w in bench["workloads"]].count(CONFIG) == 1
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["configs"][-1]["name"] == CONFIG
-    assert sorted(bench["configs"][-1]["reduced"]) == REDUCED
+    # (found by name: the next configuration's entry goes after this one)
+    entry = next(c for c in bench["configs"] if c["name"] == CONFIG)
+    assert sorted(entry["reduced"]) == REDUCED
     assert all(len(w["why"]) <= 200 for w in bench["workloads"])
     assert len(json.dumps(bench)) < 64 * 1024
 

@@ -100,25 +100,29 @@ def test_h2d_total_counts_one_transfer_an_epoch_and_none_without_news(
     m = eng.metrics
     req = Request(prompt_ids=_prompt(cfg, 40), max_new_tokens=30)
     eng.submit(req)
-    per_tick = []                      # (mixed?, news?, transfers)
+    per_tick = []                      # (mixed?, leaves news?, transfers)
     while req.finish_reason is None:
         before = (m["h2d_total"], m["mixed_steps"])
-        # news for a steady tick: the row state diverged (a completion) or
-        # the next slot, 64, lies on a page the row does not hold yet
-        news = eng._dirty or (int(eng.row_lens[0]) == 64
-                              and eng.tables[0, 2] < 0)
+        # a tick publishes what the NEXT steady tick needs before it
+        # commits: here the tick that leaves the row at 64 slots, the edge
+        # of the pages it holds (and the mixed tick that completes the
+        # prompt, below)
+        edge = int(eng.row_lens[0]) == 63 and eng.tables[0, 2] < 0
         eng._tick()
-        per_tick.append((m["mixed_steps"] - before[1] == 1, bool(news),
+        assert req.finish_reason or not (eng._dirty or eng._tables_dirty)
+        per_tick.append((m["mixed_steps"] - before[1] == 1, bool(edge),
                          m["h2d_total"] - before[0]))
     mixed = [t for t in per_tick if t[0]]
-    dirty_steady = [t for t in per_tick if not t[0] and t[1]]
+    edge_steady = [t for t in per_tick if not t[0] and t[1]]
     quiet_steady = [t for t in per_tick if not t[0] and not t[1]]
-    # 40 tokens in 32-token chunks: two mixed ticks; the completion and the
-    # page boundary at 64 slots: two steady ticks with news; the rest quiet
-    assert len(mixed) == 2 and len(dirty_steady) == 2, per_tick
+    # 40 tokens in 32-token chunks: two mixed ticks, an epoch each, and the
+    # second, which completes the prompt, publishes the decoding row's
+    # state for the steady tick after it; one steady tick ends on the page
+    # boundary at 64 slots; the rest are quiet, the tick after the
+    # completion and the tick that writes slot 64 among them
+    assert [n for _, _, n in mixed] == [1, 2], per_tick
+    assert [n for _, _, n in edge_steady] == [1], per_tick
     assert len(quiet_steady) >= 20, per_tick
-    assert all(n == 1 for _, _, n in mixed), per_tick
-    assert all(n == 1 for _, _, n in dirty_steady), per_tick
     assert all(n == 0 for _, _, n in quiet_steady), per_tick
     assert m["h2d_total"] == m["epoch_syncs"] == 4
     # admission backs the whole prompt, so the table has news twice: at
@@ -126,10 +130,36 @@ def test_h2d_total_counts_one_transfer_an_epoch_and_none_without_news(
     assert m["table_row_syncs"] == 2
 
 
+def test_a_dry_pool_is_left_to_the_tick_that_needs_the_page(cfg_params):
+    """Publishing a page boundary ahead is a saving, not a second
+    allocator: where the pool cannot give the pages, the tick that ends on
+    the boundary leaves the table alone and counts nothing; the tick that
+    needs them clamps, once, as it always did."""
+    cfg, params = cfg_params
+    # page 0 is the scratch page: 4 usable pages, two prompts take them all
+    eng = ServingEngine(cfg, params, EngineConfig(pool_pages=5, **EC))
+    reqs = [Request(prompt_ids=_prompt(cfg, 40), max_new_tokens=50)
+            for _ in range(2)]
+    for r in reqs:
+        eng.submit(r)
+    for _ in range(60):
+        if int(eng.row_lens[:2].max()) == 64:
+            break
+        eng._tick()
+    assert [int(n) for n in eng.row_lens[:2]] == [64, 64]
+    assert (eng.tables[:2, 2] < 0).all()
+    assert eng.metrics.get("alloc_fail_clamps", 0) == 0
+    _finish_all(eng, reqs)
+    # the first row finds the pool dry and ends; its pages back the second
+    assert eng.metrics["alloc_fail_clamps"] == 1
+    assert [len(r.output_ids) for r in reqs] == [64 - 40 + 1, 50]
+
+
 def test_a_rolled_back_tick_leaves_h2d_total_where_it_was(cfg_params):
     """``sample`` fires after the upload and the dispatch of the tick that
     completes a prompt: the tick rolls back, and the counter with it; the
-    retry then counts its own epoch."""
+    retry then counts its own epoch and the decoding row's state, which it
+    publishes for the steady tick after it."""
     cfg, params = cfg_params
     inj = FaultInjector().inject("sample", TransientFault, nth=1)
     eng = ServingEngine(cfg, params, EngineConfig(
@@ -139,7 +169,7 @@ def test_a_rolled_back_tick_leaves_h2d_total_where_it_was(cfg_params):
     assert eng._tick() is False and inj.fired == 1
     assert eng.metrics["h2d_total"] == 0 and eng.metrics["retries"] == 1
     assert eng._tick() is True
-    assert eng.metrics["h2d_total"] == 1
+    assert eng.metrics["h2d_total"] == 2
     _finish_all(eng, [req])
     assert len(req.output_ids) == 3
 
