@@ -47,7 +47,8 @@ from ipex_llm_tpu.kv import (PagedKVCache, index_page_bytes, model_page_bytes,
                              with_recurrent_state)
 from ipex_llm_tpu.models.config import ModelConfig
 from ipex_llm_tpu.models.decoder import decoder_forward
-from ipex_llm_tpu.serving.epoch import HELD_FIELDS, EpochLayout, unpack_rows
+from ipex_llm_tpu.serving.epoch import (HELD_FIELDS, EpochLayout, ResultLayout,
+                                         unpack_rows)
 from ipex_llm_tpu.serving.faults import (EngineOverloaded, FaultInjector,
                                          is_transient)
 from ipex_llm_tpu.serving.observe import (FAST_LATENCY_BUCKETS_S,
@@ -901,24 +902,30 @@ def _ragged_tick(cfg: ModelConfig, params, cache, toks, row_lens,
 
     ``with_decode=False`` (a pure-chunk tick with no decoding rows)
     skips stage 3 entirely: no wasted all-masked forward, and the key
-    chain only advances by the prefill split.  Returns (first_t [P],
-    first_lp [P] — None without a prefill block —, [R, H] tokens, [R, H]
-    logprobs, steps executed, cache, toks, row_lens, active, steps,
-    remain, key).
+    chain only advances by the prefill split.
+
+    4. **the way home** (scope ``tick/home``): everything the HOST reads
+       of the tick — steps executed, the [R, H] token and log-probability
+       blocks, with a prefill block first_t [P] and first_lp [P] — is
+       packed into ONE int32 buffer (serving/epoch.py ``ResultLayout``,
+       whose slots follow from these shapes; floats as their bits), so it
+       crosses in one transfer.  Returns (home, cache, toks, row_lens,
+       active, steps, remain, key): the buffer, then what stays on the
+       device.
 
     ``spec_k > 0`` (static) runs stage 3 as the SPECULATIVE horizon loop
     (``_spec_horizon_loop``: on-device draft from ``hist``, [R, spec_k+1]
     verify, on-device acceptance — still ONE dispatch, JP106 unchanged);
     stage 2 additionally publishes a completing row's first token into
     ``hist`` so a prompt that finishes this tick can speculate on its
-    very first decode iteration.  The return tuple then extends to
-    (..., key, take_block [R, H], hist, draft_proposed, draft_accepted)
-    with [R, H, spec_k+1] token/logprob blocks.
+    very first decode iteration.  The blocks are [R, H, spec_k+1] then,
+    ``home`` also holds take_block [R, H], draft_proposed and
+    draft_accepted, and the return tuple extends to (..., key, hist).
 
     ``state_src``: a hybrid model's committed recurrent state (kv.py), the
     one argument of the tick that is neither donated nor rebound: the tick
-    reads it and writes ``cache.state`` (see ``_tick_body``); the n_exec
-    slot then carries [steps executed, the state's counters].
+    reads it and writes ``cache.state`` (see ``_tick_body``); ``home``'s
+    n_exec slot then carries [steps executed, the state's counters].
     """
     from ipex_llm_tpu.ops import dispatch
 
@@ -942,20 +949,35 @@ def _ragged_tick(cfg: ModelConfig, params, cache, toks, row_lens,
     if tp_manual:
         from ipex_llm_tpu.parallel.manual import tp_tick
 
-        return tp_tick(
+        out = tp_tick(
             _tick_body, cfg, mesh, collective_qtype, params, cache,
             (toks, row_lens, active, temps, top_ps, key, seeds, steps,
              top_ks, eos, remain),
             prefill=prefill, horizon=horizon, with_decode=with_decode,
             hist=hist, spec_ks=spec_ks, spec_k=spec_k,
-            spec_ngram=spec_ngram) + held
-    with dispatch.spmd(mesh):
-        return _tick_body(
-            cfg, params, cache, toks, row_lens, active, temps, top_ps,
-            key, seeds, steps, top_ks, eos, remain, prefill=prefill,
-            horizon=horizon, with_decode=with_decode, hist=hist,
-            spec_ks=spec_ks, spec_k=spec_k, spec_ngram=spec_ngram,
-            state_src=state_src) + held
+            spec_ngram=spec_ngram)
+    else:
+        with dispatch.spmd(mesh):
+            out = _tick_body(
+                cfg, params, cache, toks, row_lens, active, temps, top_ps,
+                key, seeds, steps, top_ks, eos, remain, prefill=prefill,
+                horizon=horizon, with_decode=with_decode, hist=hist,
+                spec_ks=spec_ks, spec_k=spec_k, spec_ngram=spec_ngram,
+                state_src=state_src)
+    # outside the manual region, where every result is replicated: what
+    # the host reads of this tick leaves as ONE buffer
+    first_t, first_lp, tok_block, lp_block, n_exec = out[:5]
+    home = {"n_exec": n_exec, "tok": tok_block, "lp": lp_block}
+    if prefill is not None:
+        home.update(first_t=first_t, first_lp=first_lp)
+    carried = out[5:12]
+    if spec_k > 0:
+        take_block, hist, prop, acc = out[12:]
+        home.update(take=take_block, prop=prop, acc=acc)
+        carried += (hist,)
+    with jax.named_scope("tick/home"):
+        home = ResultLayout.of(home).pack(**home)
+    return (home,) + carried + held
 
 
 class ServingEngine:
@@ -1822,24 +1844,38 @@ class ServingEngine:
         if self.perf is not None:
             self.perf.phase_to(name)
 
-    def _sync_window(self, arrays: dict) -> dict[str, np.ndarray]:
+    def _sent_home(self, home, horizon: int, p_b: int = 0,
+                   spec: bool = False) -> tuple:
+        """Right after a tick's dispatch, still in its ``dispatch`` phase:
+        start the copy of its packed results (``_ragged_tick``'s ``home``)
+        to the host, queued behind the program, so that no host round trip
+        stands between the program's end and the bytes' arrival.  Returns
+        what ``_sync_window`` takes: the buffer and its layout, which
+        follows from the tick's shapes as the program's own does."""
+        home.copy_to_host_async()
+        return home, ResultLayout(
+            rows=self.ec.max_rows, horizon=horizon,
+            k1=self.ec.spec_k + 1 if spec else 0, p_b=p_b,
+            n_stats=len(self._state_counters) if self._stateful else 0)
+
+    def _sync_window(self, home, layout: ResultLayout
+                     ) -> dict[str, np.ndarray]:
         """THE tick's blocking device->host materialization, the ``sync``
-        phase, counted by ``_count_sync`` when it returns: the named
-        ``arrays`` of the dispatched program, fetched one ``d2h`` after
-        another.  The FIRST blocks until the program ends (the device is
-        busy): phase ``sync_wait``.  Every later one is a round trip with
-        the device idle: phase ``sync_fetch``, all of them together."""
-        (name, first), *rest = arrays.items()
+        phase, counted by ``_count_sync`` when it returns.  Everything the
+        host reads of a tick is ONE int32 buffer the program packed
+        (``home``, laid out by ``layout``) whose copy ``_sent_home``
+        started at dispatch: ONE fetch blocks until the program has ended
+        and the bytes are here (phase ``sync_wait``), then the buffer is
+        taken apart into views (phase ``sync_fetch``: no transfer, a few
+        microseconds of numpy)."""
         with self._phase("sync"):
             t0 = time.perf_counter()
-            with self._phase("sync_wait"):
+            with self._phase("sync_wait", bytes=home.nbytes):
                 # jaxlint: disable=JL002 -- THE per-tick designed sync: one blocking materialization for the whole fused tick
-                out = {name: d2h(first)}
-            with self._phase("sync_fetch", n=len(rest),
-                             bytes=sum(a.nbytes for _, a in rest)):
-                # jaxlint: disable=JL002 -- rides THE per-tick sync above (same dispatched program, already ended)
-                out.update((k, d2h(a)) for k, a in rest)
-            self.metrics["d2h_total"] += len(arrays)
+                buf = d2h(home)
+            with self._phase("sync_fetch"):
+                out = layout.unpack(buf)
+            self.metrics["d2h_total"] += 1
             self._count_sync(time.perf_counter() - t0)
         return out
 
@@ -3517,7 +3553,6 @@ class ServingEngine:
         # the device history needs no maintenance there (prompts land
         # whole at epoch uploads, and nothing is emitted)
         tick_spec = fused and with_decode
-        take_block = s_prop = s_acc = None
         perf_pt = self._perf_point(
             1, width=width, with_decode=with_decode, spec=tick_spec,
             pb=p_b, maxp=maxp_b, ew=layout.e_w)
@@ -3545,15 +3580,16 @@ class ServingEngine:
                 tp_manual=self._tp_manual,
                 collective_qtype=self._collective_qtype,
                 state_src=state_src, **kw)
+            if with_decode:
+                sent = self._sent_home(out[0], 1, p_b=p_b, spec=tick_spec)
         self._state_done(state_src)
         self._tick_dispatches += 1
-        (first_t, first_lp, tok_block, lp_block, n_exec, self.cache,
-         *carried, self.key) = out[:12]
+        self.cache, *carried, self.key = out[1:8]
         dev = dict(zip(("toks", "row_lens", "active", "steps", "remain"),
                        carried))
         dev.update(zip(HELD_FIELDS, out[-1]))
         if tick_spec:
-            take_block, hist, s_prop, s_acc = out[12:16]
+            hist = out[8]
         if hist is not None:
             dev["hist"] = hist
         self._dev = dev
@@ -3591,17 +3627,11 @@ class ServingEngine:
         # the first tokens of prompts completing this tick must reach the
         # host to emit; n_exec is 0 only when no row decoded; a speculating
         # tick's accepted counts (for the drain walk) and draft economics
-        # ride the same window
-        want = {"tok": tok_block, "lp": lp_block, "n_exec": n_exec}
-        if completing:
-            want.update(first_t=first_t, first_lp=first_lp)
-        if tick_spec:
-            want.update(take=take_block, prop=s_prop, acc=s_acc)
-        got = self._sync_window(want)
+        # ride the same buffer
+        got = self._sync_window(*sent)
         tok_np, lp_np = got["tok"], got["lp"]
         executed = self._count_state(got["n_exec"])
-        if completing:
-            nxt, lp = got["first_t"], got["first_lp"]
+        nxt, lp = got["first_t"], got["first_lp"]
         for i, row in completing:
             # jaxlint: disable=JL002 -- host arrays: _sync_window fetched them above
             self._finish_prompt(row, int(nxt[i]), float(lp[i]))
@@ -3718,10 +3748,9 @@ class ServingEngine:
             self.metrics["h2d_total"] += 1      # the draft widths
             with self._perf_dispatch("tick.spec", point=perf_pt,
                                      rows_active=rows_active, **tick_work):
-                (_, _, tok_block, lp_block, n_exec, self.cache,
-                 dev["toks"], dev["row_lens"], dev["active"],
-                 dev["steps"], dev["remain"], self.key, take_block,
-                 dev["hist"], s_prop, s_acc) = _ragged_tick(
+                (home, self.cache, dev["toks"], dev["row_lens"],
+                 dev["active"], dev["steps"], dev["remain"], self.key,
+                 dev["hist"]) = _ragged_tick(
                     self.cfg, self.params, self.cache, dev["toks"],
                     dev["row_lens"], dev["active"], dev["temps"],
                     dev["top_ps"], self.key, dev["seeds"], dev["steps"],
@@ -3731,6 +3760,7 @@ class ServingEngine:
                     spec_ngram=self.ec.spec_ngram, mesh=self.mesh,
                     tp_manual=self._tp_manual,
                     collective_qtype=self._collective_qtype)
+                sent = self._sent_home(home, h, spec=True)
             self._tick_dispatches += 1
         else:
             # the steady-state tick is the SAME single jitted entry the
@@ -3740,9 +3770,9 @@ class ServingEngine:
             cache_in, state_src = self._state_call()
             with self._perf_dispatch("tick.steady", point=perf_pt,
                                      rows_active=rows_active, **tick_work):
-                (_, _, tok_block, lp_block, n_exec, self.cache,
-                 dev["toks"], dev["row_lens"], dev["active"],
-                 dev["steps"], dev["remain"], self.key) = _ragged_tick(
+                (home, self.cache, dev["toks"], dev["row_lens"],
+                 dev["active"], dev["steps"], dev["remain"],
+                 self.key) = _ragged_tick(
                     self.cfg, self.params, cache_in, dev["toks"],
                     dev["row_lens"], dev["active"], dev["temps"],
                     dev["top_ps"], self.key, dev["seeds"], dev["steps"],
@@ -3751,17 +3781,15 @@ class ServingEngine:
                     tp_manual=self._tp_manual,
                     collective_qtype=self._collective_qtype,
                     state_src=state_src)
+                sent = self._sent_home(home, h)
             self._state_done(state_src)
             self._tick_dispatches += 1
             # the returned cache owns the (donated) tables buffer now
         self._phase_to("drain")
         # h tokens per host round trip; n_exec < h only if every row died
         # early; a speculating tick's accepted counts (for the drain walk)
-        # and draft economics ride the same window
-        want = {"tok": tok_block, "lp": lp_block, "n_exec": n_exec}
-        if fused_spec:
-            want.update(take=take_block, prop=s_prop, acc=s_acc)
-        got = self._sync_window(want)
+        # and draft economics ride the same buffer
+        got = self._sync_window(*sent)
         tok_block, lp_block = got["tok"], got["lp"]
         executed = self._count_state(got["n_exec"])
         if self.perf is not None:

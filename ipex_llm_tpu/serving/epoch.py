@@ -14,6 +14,13 @@ program (:meth:`EpochLayout.unpack`: static slices, a
 ``engine._ragged_tick`` where the tick has a prefill block, in
 :func:`unpack_rows` for every other epoch.  Integers are copied and
 floats round-trip through their bits, so nothing a tick computes moves.
+
+The way home is the mirror (:class:`ResultLayout`): everything a tick
+sends back to the host (steps executed, the token and log-probability
+blocks, a prefill block's first tokens, a speculating tick's accepted
+counts and draft counters) is packed INSIDE the tick program into one
+int32 buffer (:meth:`ResultLayout.pack`, traced), crosses in one transfer
+and is taken apart on the host as views (:meth:`ResultLayout.unpack`).
 """
 
 from __future__ import annotations
@@ -98,11 +105,90 @@ def _slots(layout: EpochLayout) -> dict:
     if p:
         fields.append(("p_toks", (p, layout.width), np.int32))
         fields += [(name, (p,), dt) for name, dt in PREFILL_FIELDS]
+    return _lay_out(fields)
+
+
+def _lay_out(fields) -> dict:
+    """(name, shape, dtype) in order -> name -> (lo, hi, shape, dtype)."""
     out, off = {}, 0
     for name, shape, dt in fields:
         out[name] = (off, off + int(np.prod(shape)), shape, dt)
         off = out[name][1]
     return out
+
+
+class ResultLayout(NamedTuple):
+    """Where each host-bound result of a tick lies in the packed buffer it
+    sends home.  ``rows`` x ``horizon`` are the token and log-probability
+    blocks, ``k1`` their third axis on a speculating tick (``spec_k + 1``;
+    0 = a plain tick, and no ``take`` / ``prop`` / ``acc``), ``p_b`` the
+    prefill block's slots (0 = none, and no ``first_t`` / ``first_lp``),
+    ``n_stats`` the recurrent state's counters behind ``n_exec`` (0 = a
+    model without state)."""
+
+    rows: int
+    horizon: int
+    k1: int = 0
+    p_b: int = 0
+    n_stats: int = 0
+
+    @classmethod
+    def of(cls, fields: dict) -> "ResultLayout":
+        """The layout of a tick's results, from their shapes alone."""
+        tok = fields["tok"]
+        first = fields.get("first_t")
+        return cls(rows=tok.shape[0], horizon=tok.shape[1],
+                   k1=tok.shape[2] if tok.ndim == 3 else 0,
+                   p_b=0 if first is None else first.shape[0],
+                   n_stats=int(np.prod(fields["n_exec"].shape)) - 1)
+
+    def slots(self) -> dict[str, tuple[int, int, tuple[int, ...], type]]:
+        """name -> (first int32 word, one past the last, shape, dtype)."""
+        return _result_slots(self)
+
+    @property
+    def size(self) -> int:
+        return list(_result_slots(self).values())[-1][1]
+
+    def pack(self, **fields) -> jnp.ndarray:
+        """One int32 buffer holding ``fields`` (one array a slot), on the
+        device (traced): integers copied, a ``float32`` as its bits."""
+        parts = []
+        for name, (lo, hi, shape, dt) in _result_slots(self).items():
+            x = fields[name]
+            if x.dtype != dt or int(np.prod(x.shape)) != hi - lo:
+                raise TypeError(f"{name}: {x.dtype}{x.shape} does not fill "
+                                f"a {np.dtype(dt).name}{shape} slot")
+            if dt is np.float32:
+                x = jax.lax.bitcast_convert_type(x, jnp.int32)
+            parts.append(x.reshape(-1))
+        return jnp.concatenate(parts)
+
+    def unpack(self, buf: np.ndarray) -> dict[str, np.ndarray]:
+        """The fields of a fetched buffer, on the host, as views of it."""
+        if buf.dtype != np.int32 or buf.shape != (self.size,):
+            raise ValueError(f"{buf.dtype}{buf.shape} is not a packed "
+                             f"result of {self}")
+        out = {}
+        for name, (lo, hi, shape, dt) in _result_slots(self).items():
+            view = buf[lo:hi].reshape(shape)
+            out[name] = view.view(np.float32) if dt is np.float32 else view
+        return out
+
+
+@lru_cache(maxsize=None)
+def _result_slots(layout: ResultLayout) -> dict:
+    r, h = layout.rows, layout.horizon
+    block = (r, h, layout.k1) if layout.k1 else (r, h)
+    fields = [("n_exec", (1 + layout.n_stats,), np.int32),
+              ("tok", block, np.int32), ("lp", block, np.float32)]
+    if layout.p_b:
+        fields += [("first_t", (layout.p_b,), np.int32),
+                   ("first_lp", (layout.p_b,), np.float32)]
+    if layout.k1:
+        fields += [("take", (r, h), np.int32), ("prop", (), np.int32),
+                   ("acc", (), np.int32)]
+    return _lay_out(fields)
 
 
 @partial(jax.jit, static_argnames=("layout",))

@@ -124,9 +124,10 @@ BUCKETS = ("dispatch", "device", "sync", "bookkeep")
 # profiler's timeline) and ``perf_phase_<phase>_s`` / ``_cpu_s`` counters:
 # ``tick`` is the parent, the rest partition it in the order a tick visits
 # them.  ``sync`` is the tick's device->host window; inside it
-# ``sync_wait`` is the FIRST fetch, which blocks until the program ends
-# (the device is busy), and ``sync_fetch`` every later fetch together
-# (round trips with the device idle), so ``sync`` itself is the remainder
+# ``sync_wait`` is the ONE fetch of the tick's packed results, which
+# blocks until the program ends (the device is busy), and ``sync_fetch``
+# the taking apart of that buffer on the host (no transfer since the
+# results come home packed), so ``sync`` itself is the remainder
 PHASES = ("tick", "plan", "checkpoint", "admit", "prepare", "dispatch",
           "sync", "sync_wait", "sync_fetch", "drain", "commit", "record")
 

@@ -3,7 +3,12 @@ epoch crosses to the device as ONE buffer in ONE transfer, and the tick
 program takes it apart.  Tier-1 guards: no one-op program is left on the
 path between two ticks, ``h2d_total`` counts exactly the transfers, the
 buffer round-trips every field bit for bit, and the lowered mixed tick
-gathers the prefill rows' tables itself, under scope ``tick/epoch``."""
+gathers the prefill rows' tables itself, under scope ``tick/epoch``.
+
+And the way home, its mirror (``ResultLayout``): what a tick sends back
+is ONE buffer the program packs under scope ``tick/home``, whose slots
+follow from shapes alone and whose fields come apart on the host bit for
+bit as the program computed them."""
 
 import logging
 import re
@@ -13,7 +18,8 @@ import numpy as np
 import pytest
 
 from ipex_llm_tpu.serving.engine import EngineConfig, Request, ServingEngine
-from ipex_llm_tpu.serving.epoch import EpochLayout, unpack_rows
+from ipex_llm_tpu.serving.epoch import (EpochLayout, ResultLayout,
+                                         unpack_rows)
 from ipex_llm_tpu.serving.faults import FaultInjector, TransientFault
 from tests.test_decoder import rand_params, tiny_cfg
 
@@ -268,3 +274,149 @@ def test_the_lowered_mixed_tick_gathers_its_tables_under_tick_epoch():
     assert len(bitcasts) == 2          # temps, top_ps
     text = _ragged_tick.lower(*args, **kw).as_text(debug_info=True)
     assert "tick/epoch" in text
+
+
+# -- (e) the way home: one packed buffer a tick --------------------------------
+
+RESULT_LAYOUTS = {
+    "steady-h1": (ResultLayout(rows=16, horizon=1), 1 + 16 + 16),
+    "steady-h8": (ResultLayout(rows=16, horizon=8), 1 + 2 * 16 * 8),
+    "mixed-completes-a-prompt": (ResultLayout(rows=4, horizon=1, p_b=2),
+                                 1 + 2 * 4 + 2 * 2),
+    "speculating": (ResultLayout(rows=4, horizon=4, k1=3),
+                    1 + 2 * 4 * 4 * 3 + 4 * 4 + 2),
+    "speculating-mixed": (ResultLayout(rows=4, horizon=1, k1=3, p_b=8),
+                          1 + 2 * 4 * 3 + 2 * 8 + 4 + 2),
+    "hybrid-with-stats": (ResultLayout(rows=8, horizon=1, p_b=4, n_stats=6),
+                          7 + 2 * 8 + 2 * 4),
+}
+# bit patterns a value-wise copy could lose: -0.0, a NaN with a payload,
+# infinities, a denormal
+ODD_FLOATS = np.asarray([0x80000000, 0x7FC00123, 0xFF800000, 0x7F800000,
+                         0x00000001], np.uint32).view(np.float32)
+
+
+def _result_fields(lay, seed):
+    """Random values of every slot of ``lay``, in the shapes and dtypes the
+    tick program computes them in (``n_exec`` a scalar without stats)."""
+    rng = np.random.default_rng(seed)
+    out = {}
+    for name, (lo, hi, shape, dt) in lay.slots().items():
+        if dt is np.float32:
+            x = rng.standard_normal(hi - lo).astype(np.float32)
+            x[:ODD_FLOATS.size] = ODD_FLOATS[:x.size]
+        else:
+            x = rng.integers(-2**31, 2**31, hi - lo).astype(np.int32)
+        out[name] = x.reshape(shape)
+    if not lay.n_stats:
+        out["n_exec"] = out["n_exec"].reshape(())
+    return out
+
+
+@pytest.mark.parametrize("case", RESULT_LAYOUTS)
+def test_the_packed_result_round_trips_every_field_bit_for_bit(case):
+    lay, size = RESULT_LAYOUTS[case]
+    assert lay.size == size                     # from the shapes alone
+    fields = _result_fields(lay, seed=size)
+    assert ResultLayout.of(fields) == lay
+    buf = np.asarray(jax.jit(lambda f: lay.pack(**f))(fields))
+    assert buf.dtype == np.int32 and buf.shape == (size,)
+    got = lay.unpack(buf)
+    assert list(got) == list(fields) == list(lay.slots())
+    for name, want in fields.items():
+        have = got[name]
+        assert have.dtype == want.dtype, name
+        assert have.shape == lay.slots()[name][2], name
+        assert have.tobytes() == want.tobytes(), name
+        assert np.shares_memory(have, buf), name        # a view, no copy
+    with pytest.raises(ValueError, match="not a packed result"):
+        lay.unpack(buf[:-1])
+    with pytest.raises(TypeError, match="tok"):
+        lay.pack(**dict(fields, tok=fields["tok"].astype(np.int16)))
+
+
+@pytest.mark.parametrize("horizon,spec_k", [(1, 0), (4, 0), (2, 2)])
+def test_what_comes_home_is_what_the_tick_body_computed(cfg_params, horizon,
+                                                        spec_k):
+    """``_ragged_tick``'s buffer, taken apart, against the program's own
+    values: ``_tick_body`` jitted by itself on the same pool and row state
+    (a greedy row, a seeded sampling row, two dead ones)."""
+    from ipex_llm_tpu.hostutil import h2d
+    from ipex_llm_tpu.serving import engine as engine_mod
+    from tests.test_serving_ragged import _random_pool_state
+
+    cfg, params = cfg_params
+    kw = dict(prefill=None, horizon=horizon, with_decode=True)
+    if spec_k:
+        kw.update(spec_k=spec_k, spec_ngram=2)
+
+    def call(fn):
+        cache, st = _random_pool_state(cfg, "bf16", seed=9)   # donated: anew
+        dev = {k: h2d(v) for k, v in st.items()}
+        extra = {}
+        if spec_k:
+            hist = np.tile(np.arange(64, dtype=np.int32) % 5, (4, 1))
+            extra = dict(hist=h2d(hist), spec_ks=h2d(np.full(4, spec_k,
+                                                             np.int32)))
+        return fn(cfg, params, cache, dev["toks"], dev["row_lens"],
+                  dev["active"], dev["temps"], dev["top_ps"],
+                  jax.random.PRNGKey(3), dev["seeds"], dev["steps"],
+                  dev["top_ks"], dev["eos"], dev["remain"], **kw, **extra)
+
+    body = call(jax.jit(
+        engine_mod._tick_body,
+        static_argnames=("cfg", "horizon", "with_decode", "spec_k",
+                         "spec_ngram"),
+        compiler_options=engine_mod._EXACT_ROUNDING))
+    want = {"n_exec": body[4], "tok": body[2], "lp": body[3]}
+    if spec_k:
+        want.update(take=body[12], prop=body[14], acc=body[15])
+    out = call(engine_mod._ragged_tick)
+    assert len(out) == (9 if spec_k else 8)       # home, 7 carried[, hist]
+    lay = ResultLayout(rows=4, horizon=horizon, k1=spec_k + 1 if spec_k else 0)
+    got = lay.unpack(np.asarray(out[0]))
+    assert list(got) == list(want)
+    assert int(got["n_exec"][0]) >= 1 and (got["tok"] != 0).any()
+    for name, w in want.items():
+        assert got[name].tobytes() == np.asarray(w).tobytes(), name
+    # what stays on the device is where it was in the body's tuple
+    for a, b in zip(out[2:8], body[6:12]):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+@pytest.mark.parametrize("point", [
+    {"rows": 4, "width": 0, "horizon": 1, "kv": "bf16"},
+    {"rows": 4, "width": 0, "horizon": 8, "kv": "bf16"},
+    {"rows": 4, "width": 8, "horizon": 1, "kv": "bf16"},
+    {"rows": 4, "width": 8, "horizon": 1, "kv": "bf16", "wd": False},
+    {"rows": 4, "width": 0, "horizon": 4, "kv": "bf16", "spec": 2},
+    {"rows": 4, "width": 0, "horizon": 1, "kv": "bf16", "tp": 2},
+], ids=lambda pt: "-".join(f"{k}{v}" for k, v in pt.items() if k != "kv"))
+def test_the_lowered_tick_sends_one_buffer_home_under_tick_home(point):
+    """Every form of the tick returns ONE host-bound array, the packed
+    int32 buffer of its ``ResultLayout``, concatenated under scope
+    ``tick/home`` (outside the manual region where the tick has one), and
+    nothing else a host reads: the rest of the tuple is the cache, the
+    carried row state, the key, the history and an epoch's held fields."""
+    from ipex_llm_tpu.analysis.trace.registry import _build_ragged_tick
+    from ipex_llm_tpu.serving.engine import _ragged_tick
+
+    args, kw = _build_ragged_tick(point)
+    traced = _ragged_tick.trace(*args, **kw)
+    lay = ResultLayout(
+        rows=point["rows"], horizon=point["horizon"],
+        k1=point["spec"] + 1 if point.get("spec") else 0,
+        p_b=kw["epoch"].p_b if point["width"] else 0)
+    home = jax.tree_util.tree_leaves(traced.out_info)[0]
+    assert (home.shape, home.dtype) == ((lay.size,), np.int32)
+    jaxpr = traced.jaxpr.jaxpr
+    packs = [e for e in jaxpr.eqns if e.primitive.name == "concatenate"
+             and "tick/home" in str(e.source_info.name_stack)]
+    assert len(packs) == 1 and packs[0].outvars[0] is jaxpr.outvars[0]
+    assert len(packs[0].invars) == len(lay.slots())
+    # floats leave as their bits: one bitcast a float slot, no conversion
+    scoped = [e.primitive.name for e in jaxpr.eqns
+              if "tick/home" in str(e.source_info.name_stack)]
+    assert scoped.count("bitcast_convert_type") == sum(
+        dt is np.float32 for *_, dt in lay.slots().values())
+    assert "convert_element_type" not in scoped

@@ -279,7 +279,7 @@ def test_stale_device_lens_cannot_corrupt_neighbors(cfg_params):
     st["row_lens"] = np.asarray([20, 10, 0, 0], np.int32)
     k_before = np.asarray(cache.k.astype(jnp.float32)).copy()
     dev = _dev_state(st)
-    (_, _, _, _, _, cache, *_rest) = _ragged_tick(
+    _home, cache, *_rest = _ragged_tick(
         cfg, params, cache, dev["toks"], dev["row_lens"], dev["active"],
         dev["temps"], dev["top_ps"], jax.random.PRNGKey(0), dev["seeds"],
         dev["steps"], dev["top_ks"], dev["eos"], dev["remain"],
