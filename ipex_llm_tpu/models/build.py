@@ -244,16 +244,26 @@ def build_hybrid_params(cfg: ModelConfig, get: Callable[[str], np.ndarray],
                         qtype: str = "sym_int4", head_qtype: str | None = None,
                         progress: Callable[[str], None] | None = None
                         ) -> dict[str, Any]:
-    """The tree of a hybrid model (``cfg.hybrid_pattern``; nemotron_h): one
-    stack a block KIND under ``params["hybrid"]`` (``mamba`` ``[M blocks,
-    ...]``, ``moe`` ``[E blocks, experts held, ...]``, ``attn`` ``[*
-    blocks, ...]``), which ``models/decoder.py`` walks in the pattern's
+    """The tree of a hybrid model (``cfg.hybrid_pattern``; nemotron_h,
+    falcon_h1): one stack a block KIND under ``params["hybrid"]`` (``mamba``
+    ``[M blocks, ...]``, ``moe`` ``[E blocks, experts held, ...]``, ``attn``
+    ``[* blocks, ...]``; ``parallel`` ``[P blocks, ...]``: Falcon-H1's
+    attention, Mamba-2 mixer and gated MLP of one block, under the names the
+    three have alone), which ``models/decoder.py`` walks in the pattern's
     order.  An expert layer holds ``cfg.moe_experts_held`` experts from
     ``cfg.moe_first_expert`` on and the whole router; embedding and head
     hold the first ``cfg.vocab_size`` rows.  Every 4-bit plane is padded
     here, once, to the dequant-matmul kernel's tiles
     (``quantize/core.pad_planes``), so no call pads a weight."""
-    pre = "backbone.layers.{i}."
+    # the checkpoint's names: Nemotron-H's ``backbone.``, Falcon-H1's ``model.``
+    if set(cfg.hybrid_pattern) == {"P"}:
+        pre, embed, final_norm = ("model.layers.{i}.",
+                                  "model.embed_tokens.weight",
+                                  "model.final_layernorm.weight")
+    else:
+        pre, embed, final_norm = ("backbone.layers.{i}.",
+                                  "backbone.embeddings.weight",
+                                  "backbone.norm_f.weight")
 
     def packed(w: np.ndarray, as_qtype: str = qtype) -> QTensor:
         return qcore.pad_planes(quantize_weight(w, as_qtype))
@@ -265,21 +275,30 @@ def build_hybrid_params(cfg: ModelConfig, get: Callable[[str], np.ndarray],
         return jnp.asarray(get(name), NORM_DTYPE)
 
     kinds: dict[str, list[dict[str, Any]]] = {"mamba": [], "moe": [],
-                                              "attn": []}
+                                              "attn": [], "parallel": []}
     for i, kind in enumerate(cfg.hybrid_pattern):
         if progress:
             progress(f"layer {i + 1}/{cfg.num_layers}")
+        if kind == "P":
+            blk = pre.format(i=i)
+            a, f = blk + "self_attn.", blk + "feed_forward."
+            kinds["parallel"].append({
+                "attn_norm": f32(blk + "input_layernorm.weight"),
+                "qkv": packed(np.concatenate(
+                    [get(a + f"{p}_proj.weight") for p in "qkv"], axis=0)),
+                "o": q(a + "o_proj.weight"),
+                **_mamba_tensors(get, blk + "mamba.", packed),
+                "mlp_norm": f32(blk + "pre_ff_layernorm.weight"),
+                "gate_up": packed(np.concatenate(
+                    [get(f + "gate_proj.weight"), get(f + "up_proj.weight")],
+                    axis=0)),
+                "down": q(f + "down_proj.weight")})
+            continue
         m = pre.format(i=i) + "mixer."
         norm = f32(pre.format(i=i) + "norm.weight")
         if kind == "M":
-            conv_w = np.asarray(get(m + "conv1d.weight"))[:, 0, :]   # [C, K]
-            kinds["mamba"].append({
-                "norm": norm, "in_proj": q(m + "in_proj.weight"),
-                "conv_w": jnp.asarray(conv_w.T, NORM_DTYPE),         # [K, C]
-                "conv_b": f32(m + "conv1d.bias"),
-                "dt_bias": f32(m + "dt_bias"), "A_log": f32(m + "A_log"),
-                "D": f32(m + "D"), "gate_norm": f32(m + "norm.weight"),
-                "out_proj": q(m + "out_proj.weight")})
+            kinds["mamba"].append(
+                {"norm": norm, **_mamba_tensors(get, m, packed)})
         elif kind == "E":
             first, held = cfg.moe_first_expert, cfg.moe_experts_held
             experts = range(first, first + held)
@@ -305,15 +324,33 @@ def build_hybrid_params(cfg: ModelConfig, get: Callable[[str], np.ndarray],
                 "o": q(m + "o_proj.weight")})
     params: dict[str, Any] = {
         "hybrid": {k: stack_layer_trees(v) for k, v in kinds.items() if v},
-        "embed": jnp.asarray(
-            get("backbone.embeddings.weight")[: cfg.vocab_size],
-            jnp.bfloat16),
-        "final_norm": f32("backbone.norm_f.weight"),
+        "embed": jnp.asarray(get(embed)[: cfg.vocab_size], jnp.bfloat16),
+        "final_norm": f32(final_norm),
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = packed(
             get("lm_head.weight")[: cfg.vocab_size], head_qtype or qtype)
+    if cfg.rope is not None:
+        params["inv_freq"] = jnp.asarray(
+            cfg.rope.inv_freq(cfg.max_position_embeddings), jnp.float32)
+        params["rope_mscale"] = float(
+            cfg.rope.mscale(cfg.max_position_embeddings))
     return params
+
+
+def _mamba_tensors(get, m: str, packed) -> dict:
+    """One Mamba-2 mixer's tensors as ops/ssm.py reads them, from the
+    checkpoint names under the prefix ``m``."""
+    def f32(name: str):
+        return jnp.asarray(get(m + name), NORM_DTYPE)
+
+    conv_w = np.asarray(get(m + "conv1d.weight"))[:, 0, :]           # [C, K]
+    return {"in_proj": packed(get(m + "in_proj.weight")),
+            "conv_w": jnp.asarray(conv_w.T, NORM_DTYPE),             # [K, C]
+            "conv_b": f32("conv1d.bias"), "dt_bias": f32("dt_bias"),
+            "A_log": f32("A_log"), "D": f32("D"),
+            "gate_norm": f32("norm.weight"),
+            "out_proj": packed(get(m + "out_proj.weight"))}
 
 
 def _hyper_connection(get, i: int, sub: str) -> dict:

@@ -120,9 +120,12 @@ class ModelConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int | None = None     # defaults to head_dim
 
-    # hybrid stacks (nemotron_h): a block is ONE mixer, named by a letter of
-    # the pattern: 'M' Mamba-2 (ops/ssm.py), 'E' experts, '*' attention.
-    # The weights are stacked per KIND and the decoder walks the pattern
+    # hybrid stacks: a block is named by a letter of the pattern.  nemotron_h:
+    # ONE mixer a block, 'M' Mamba-2 (ops/ssm.py), 'E' experts, '*'
+    # attention.  falcon_h1: 'P', a PARALLEL block, where attention and a
+    # Mamba-2 mixer read one normed input and are summed, then a dense gated
+    # MLP: a KV layer AND a state slot a block.  The weights are stacked per
+    # KIND and the decoder walks the pattern
     hybrid_pattern: str | None = None
     ssm_num_heads: int = 0
     ssm_head_dim: int = 0
@@ -130,6 +133,20 @@ class ModelConfig:
     ssm_state_size: int = 0
     ssm_conv_kernel: int = 4
     moe_shared_intermediate_size: int = 0   # an always-on, ungated shared expert
+    # muP multipliers (falcon_h1), applied to ACTIVATIONS where the published
+    # equations put them, never folded into a quantized plane; 1.0 / None
+    # elsewhere.  With ``embedding_multiplier`` and ``logit_scale`` (the
+    # head's) they are the family's nine.  ``ssm_multipliers`` weighs the
+    # z, x, B, C and dt columns of the mixer's ``in_proj`` output;
+    # ``mlp_multipliers`` the gate (INSIDE the activation) and the down
+    # projection's output
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple[float, ...] | None = None
+    mlp_multipliers: tuple[float, float] | None = None
     # an expert layer that holds PART of its experts (one chip's share of a
     # deployment that divides them): the router scores all ``num_experts``,
     # this layer computes experts [first, first + held) and leaves the
@@ -170,7 +187,7 @@ class ModelConfig:
     def kv_layers(self) -> int:
         """Layers the KV cache holds: the attention blocks."""
         if self.hybrid_pattern is not None:
-            return self.hybrid_pattern.count("*")
+            return sum(self.hybrid_pattern.count(c) for c in "*P")
         return self.num_layers
 
     @property
@@ -181,7 +198,7 @@ class ModelConfig:
     @property
     def ssm_layers(self) -> int:
         """Blocks that carry recurrent state (0: the cache is KV alone)."""
-        return (self.hybrid_pattern or "").count("M")
+        return sum((self.hybrid_pattern or "").count(c) for c in "MP")
 
     @property
     def ssm_conv_dim(self) -> int:

@@ -128,7 +128,8 @@ def _attention_block(cfg: ModelConfig, lp: dict, x, kl, vl, cos, sin, slot0,
         )
     with jax.named_scope("layer/o_proj"):
         attn = attn.reshape(b, t, cfg.num_heads * cfg.head_dim)
-        out = linear_ops.linear(attn, lp["o"], lp.get("o_bias"))
+        out = linear_ops.times(linear_ops.linear(attn, lp["o"], lp.get("o_bias")),
+                     cfg.attention_out_multiplier)
         if cfg.norm_after:
             out = _norm(out, lp["attn_norm"], cfg, lp.get("attn_norm_bias"))
         if cfg.post_attn_norm:
@@ -379,16 +380,18 @@ def _qkv_rope(cfg: ModelConfig, lp: dict, x, h, cos, sin, collect_obs):
     (q [B,T,Hq,D], k, v [B,T,Hkv,D], obs_q)."""
     b, t, _ = x.shape
     q_dim, kv_dim = cfg.q_dim, cfg.kv_dim
+    h = linear_ops.times(h, cfg.attention_in_multiplier)
     if "qkv" in lp:
         qkv = linear_ops.linear(h, lp["qkv"], lp.get("qkv_bias"))
         q = qkv[..., :q_dim]
-        k = qkv[..., q_dim : q_dim + kv_dim]
+        k = linear_ops.times(qkv[..., q_dim : q_dim + kv_dim], cfg.key_multiplier)
         v = qkv[..., q_dim + kv_dim :]
     else:
         # split projections (GGUF import keeps q/k/v in their native — and
         # possibly different — block formats, e.g. q4_k q/k with q6_k v)
         q = linear_ops.linear(h, lp["q"], lp.get("q_bias"))
-        k = linear_ops.linear(h, lp["k"], lp.get("k_bias"))
+        k = linear_ops.times(linear_ops.linear(h, lp["k"], lp.get("k_bias")),
+                   cfg.key_multiplier)
         v = linear_ops.linear(h, lp["v"], lp.get("v_bias"))
     if cfg.qk_norm and lp["q_norm"].shape[-1] == q_dim:
         # olmo2-style flat q/k rmsnorm over the whole projection
@@ -561,6 +564,7 @@ def _moe_block(cfg: ModelConfig, lp: dict, x):
 def _mlp_block(cfg: ModelConfig, lp: dict, x, pre_normed=False):
     h = (x if cfg.norm_after or pre_normed
          else _in_norm(x, lp, "mlp_norm", cfg))
+    gate_m, down_m = cfg.mlp_multipliers or (1.0, 1.0)
     if not cfg.mlp_gated:
         # fc1 -> act -> fc2 (phi/gptneox/starcoder2-style MLP)
         inner = mlp_ops.act(
@@ -573,8 +577,9 @@ def _mlp_block(cfg: ModelConfig, lp: dict, x, pre_normed=False):
         else:
             gate = linear_ops.linear(h, lp["gate"], lp.get("gate_bias"))
             up = linear_ops.linear(h, lp["up"], lp.get("up_bias"))
-        inner = mlp_ops.gated_act_mul(gate, up, cfg.act)
-    out = linear_ops.linear(inner, lp["down"], lp.get("down_bias"))
+        inner = mlp_ops.gated_act_mul(linear_ops.times(gate, gate_m), up, cfg.act)
+    out = linear_ops.times(linear_ops.linear(inner, lp["down"], lp.get("down_bias")),
+                 down_m)
     if cfg.norm_after:
         out = _norm(out, lp["mlp_norm"], cfg, lp.get("mlp_norm_bias"))
     if cfg.post_mlp_norm:
@@ -642,10 +647,14 @@ def hybrid_schedule(pattern: str):
     """How a hybrid pattern is walked.  Read as units ``M [*] E`` (every
     Nemotron-3-Nano block sequence is 23 of them, six holding an attention
     block) it compiles as ONE scanned body: ``("units", [has attention, a
-    unit])``.  A pattern that is not of that form is walked block by block:
-    ``("blocks", [(kind, index among its kind)])``."""
+    unit])``.  A pattern of ``P`` blocks alone (Falcon-H1: attention and a
+    Mamba-2 mixer side by side, then a gated MLP) is one scanned body too:
+    ``("parallel", number of blocks)``.  A pattern of neither form is walked
+    block by block: ``("blocks", [(kind, index among its kind)])``."""
     import re
 
+    if re.fullmatch(r"P+", pattern):
+        return "parallel", len(pattern)
     if re.fullmatch(r"(M\*?E)+", pattern):
         return "units", ["*" in u for u in re.findall(r"M\*?E", pattern)]
     seen: dict[str, int] = {}
@@ -657,7 +666,8 @@ def hybrid_schedule(pattern: str):
 
 
 def run_hybrid(cfg: ModelConfig, tree: dict, cache, x, slot0, q_slots,
-               kv_len, kv_start, valid, chunk_lens=None, state_rows=None):
+               kv_len, kv_start, valid, chunk_lens=None, state_rows=None,
+               cos=None, sin=None):
     """Walk a hybrid model's blocks: ``x <- x + Mixer(norm(x))``, one mixer
     a block, over the per-KIND stacks of ``tree`` (``mamba``, ``moe``,
     ``attn``).  Attention block ``a`` addresses layer ``a`` of the KV cache
@@ -665,7 +675,13 @@ def run_hybrid(cfg: ModelConfig, tree: dict, cache, x, slot0, q_slots,
     slice); Mamba block ``m`` rewrites slot ``m`` of ``cache.state``.
     ``state_rows`` [B]: the batch is a prefill block whose entry i is state
     row ``state_rows[i]`` (a row starting at slot 0 starts from a zero
-    state); None: entry i is row i.  Returns (x, k, v, state)."""
+    state); None: entry i is row i.  Returns (x, k, v, state).
+
+    A PARALLEL block (``P``; the stack ``parallel``) is ``x <- x + Attn(u) +
+    Mixer(u)`` with ONE ``u = norm(x)``, then ``x <- x + MLP(norm'(x))``:
+    block ``i`` addresses layer ``i`` of the KV cache AND slot ``i`` of the
+    state.  ``cos``/``sin``: the rotary tables of a family that rotates
+    (None: no rotary embedding, nemotron_h)."""
     from ipex_llm_tpu.ops import ssm as ssm_ops
 
     paged = hasattr(cache, "tables")
@@ -681,27 +697,34 @@ def run_hybrid(cfg: ModelConfig, tree: dict, cache, x, slot0, q_slots,
                 resets.astype(jnp.int32))
     no_window = jnp.asarray(False)
 
+    def mixer(u, lp, state, m):
+        out, state = ssm_ops.mamba2_mixer(
+            cfg, lp, u, state if src is None else dict(state, src=src), m,
+            valid, rows=state_rows, fresh=fresh)
+        state.pop("src", None)
+        return out, state
+
     def mamba(x, lp, state, m):
         with jax.named_scope("layer/ssm"):
-            out, state = ssm_ops.mamba2_mixer(
-                cfg, lp, _norm(x, lp["norm"], cfg),
-                state if src is None else dict(state, src=src), m, valid,
-                rows=state_rows, fresh=fresh)
-            state.pop("src", None)
+            out, state = mixer(_norm(x, lp["norm"], cfg), lp, state, m)
             return x + out, state
 
-    def attention(x, k, v, lp, a):
+    def attention(x, k, v, lp, a, pre_normed=False):
+        # (``pre_normed``: ``x`` is the normed input and the branch's output
+        # is returned alone, for the caller's sum)
         if paged:
             out, k, v, _ = _attention_block(
-                cfg, lp, x, k, v, None, None, slot0, q_slots, kv_len,
-                kv_start, no_window, cache, chunk_lens=chunk_lens, layer=a)
-            return x + out, k, v
+                cfg, lp, x, k, v, cos, sin, slot0, q_slots, kv_len,
+                kv_start, no_window, cache, chunk_lens=chunk_lens, layer=a,
+                pre_normed=pre_normed)
+            return (out if pre_normed else x + out), k, v
         kl = jax.lax.dynamic_index_in_dim(k, a, 0, False)
         vl = jax.lax.dynamic_index_in_dim(v, a, 0, False)
         out, kl, vl, _ = _attention_block(
-            cfg, lp, x, kl, vl, None, None, slot0, q_slots, kv_len, kv_start,
-            no_window, cache, chunk_lens=chunk_lens)
-        return (x + out, jax.lax.dynamic_update_index_in_dim(k, kl, a, 0),
+            cfg, lp, x, kl, vl, cos, sin, slot0, q_slots, kv_len, kv_start,
+            no_window, cache, chunk_lens=chunk_lens, pre_normed=pre_normed)
+        return ((out if pre_normed else x + out),
+                jax.lax.dynamic_update_index_in_dim(k, kl, a, 0),
                 jax.lax.dynamic_update_index_in_dim(v, vl, a, 0))
 
     def experts(x, lp, state, layer=None):
@@ -717,6 +740,27 @@ def run_hybrid(cfg: ModelConfig, tree: dict, cache, x, slot0, q_slots,
 
     kind, plan = hybrid_schedule(cfg.hybrid_pattern)
     k, v = cache.k, cache.v
+    if kind == "parallel":
+        block_xs, block_view = layer_views(tree["parallel"])
+
+        def parallel(carry, xs):
+            x, k, v, state = carry
+            lp, i = xs
+            lp = block_view(lp, i)
+            with jax.named_scope("layer/qkv"):
+                u = _in_norm(x, lp, "attn_norm", cfg)
+            a_out, k, v = attention(u, k, v, lp, i, pre_normed=True)
+            with jax.named_scope("layer/ssm"):
+                s_out, state = mixer(u, lp, state, i)
+                x = x + a_out + s_out
+            with jax.named_scope("layer/mlp"):
+                x = x + _mlp_block(cfg, lp, x)
+            return (x, k, v, state), None
+
+        (x, k, v, state), _ = jax.lax.scan(
+            parallel, (x, k, v, state),
+            (block_xs, jnp.arange(plan, dtype=jnp.int32)))
+        return x, k, v, state
     if kind == "blocks":
         for block, i in plan:
             if block == "M":
@@ -1162,7 +1206,8 @@ def decoder_forward(
         valid = _valid_positions(b, t, q_slots, kv_start, chunk_lens)
         x, k_new, v_new, state = run_hybrid(
             cfg, params["hybrid"], cache, x, slot0, q_slots, kv_len,
-            kv_start, valid, chunk_lens=chunk_lens, state_rows=state_rows)
+            kv_start, valid, chunk_lens=chunk_lens, state_rows=state_rows,
+            cos=cos, sin=sin)
         logits = _chosen_logits(cfg, params, x, last_token_only,
                                 gather_positions)
         new_len = cache.length if slot_offsets is not None else slot0 + t

@@ -1014,6 +1014,61 @@ def _nemotron_h(hf: dict) -> ModelConfig:
     )
 
 
+def _falcon_h1(hf: dict) -> ModelConfig:
+    """Falcon-H1: EVERY block holds an attention branch and a Mamba-2 mixer
+    that read one normed input and are summed, then a dense gated MLP (the
+    pattern letter ``P``, models/decoder.py ``run_hybrid``): a KV layer and
+    a state slot a block.  Rotary embedding over the whole head; muP
+    multipliers on activations (``ModelConfig``); ``mamba_d_ssm`` is the
+    mixer's inner width, given beside ``mamba_expand`` and not derived from
+    it, and is ``mamba_n_heads`` heads of ``mamba_d_head``.  The gated norm
+    is the form ops/ssm.py has (gate, THEN normalise each group).  Weights:
+    models/build.py ``build_hybrid_params``."""
+    heads, hd = hf["mamba_n_heads"], hf["mamba_d_head"]
+    d_ssm = hf.get("mamba_d_ssm") or hf["mamba_expand"] * hf["hidden_size"]
+    if heads * hd != d_ssm:
+        raise ValueError(
+            f"falcon_h1: mamba_d_ssm {d_ssm} is not mamba_n_heads {heads} x "
+            f"mamba_d_head {hd}")
+    if hf.get("attn_layer_indices") is not None:
+        raise ValueError("falcon_h1: attn_layer_indices is not supported "
+                         "(every block holds both mixers)")
+    if hf.get("mamba_norm_before_gate", False) or not hf.get(
+            "mamba_rms_norm", True):
+        raise ValueError(
+            "falcon_h1: only the gated group RMSNorm is supported "
+            "(mamba_rms_norm true, mamba_norm_before_gate false)")
+    for key in ("attention_bias", "mlp_bias", "mamba_proj_bias",
+                "projectors_bias"):
+        if hf.get(key, False):
+            raise ValueError(f"falcon_h1: {key} is not supported")
+    if not hf.get("mamba_conv_bias", True):
+        raise ValueError("falcon_h1: the conv carries a bias")
+    mup = tuple(float(m) for m in hf.get("ssm_multipliers") or ())
+    mlp = tuple(float(m) for m in hf.get("mlp_multipliers") or ())
+    if len(mup) not in (0, 5) or len(mlp) not in (0, 2):
+        raise ValueError(
+            "falcon_h1: ssm_multipliers names the five column ranges z, x, "
+            "B, C, dt and mlp_multipliers the gate and the down projection")
+    return ModelConfig(**_base_cfg(
+        hf,
+        model_type="falcon_h1",
+        hybrid_pattern="P" * hf["num_hidden_layers"],
+        ssm_num_heads=heads,
+        ssm_head_dim=hd,
+        ssm_n_groups=hf["mamba_n_groups"],
+        ssm_state_size=hf["mamba_d_state"],
+        ssm_conv_kernel=hf.get("mamba_d_conv", 4),
+        logit_scale=float(hf.get("lm_head_multiplier", 1.0)),
+        **{key: float(hf.get(key, 1.0)) for key in (
+            "embedding_multiplier", "attention_in_multiplier",
+            "attention_out_multiplier", "key_multiplier",
+            "ssm_in_multiplier", "ssm_out_multiplier")},
+        ssm_multipliers=mup or None,
+        mlp_multipliers=mlp or None,
+    ))
+
+
 def _neox_qkv(w, cfg: ModelConfig):
     """GPT-NeoX query_key_value: per-head [q_i;k_i;v_i] interleave ->
     [q_all; k_all; v_all]."""
@@ -1367,6 +1422,7 @@ FAMILIES: dict[str, Family] = {
     "xing4_0": Family("xing4_0", _xing4_0, _DEEPSEEK_SCHEME,
                       _DEEPSEEK_V3_MOE),
     "nemotron_h": Family("nemotron_h", _nemotron_h),
+    "falcon_h1": Family("falcon_h1", _falcon_h1),
     "mixtral": Family("mixtral", _mixtral, WeightScheme(), _MIXTRAL_MOE),
     "qwen2_moe": Family("qwen2_moe", _qwen2_moe, WeightScheme(), _QWEN2_MOE),
     "qwen3_moe": Family(

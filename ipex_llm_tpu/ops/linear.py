@@ -124,3 +124,13 @@ def linear(x: jnp.ndarray, w, bias: jnp.ndarray | None = None) -> jnp.ndarray:
     if bias is not None:
         y = y + bias.astype(y.dtype)
     return y
+
+
+def times(x: jnp.ndarray, m) -> jnp.ndarray:
+    """``x`` times a muP multiplier of the configuration (falcon_h1: a
+    number, or a vector over the last axis), applied to the ACTIVATION in
+    float32 and never folded into a quantized plane; ``x`` itself at 1.0,
+    every other family's."""
+    if isinstance(m, (int, float)) and m == 1.0:
+        return x
+    return (x.astype(jnp.float32) * m).astype(x.dtype)

@@ -22,9 +22,13 @@ rollback and no copy is made.  ``dst`` is aliased to the output; with ``src
 is dst`` the update is in place.
 
 Layout inside a grid step (row, block of ``hs`` heads): a head's state is
-``[P, N]``, P on sublanes and N on lanes.  ``B_t`` and ``C_t`` arrive as
+``[P, N]``, P on sublanes and N on lanes (N a whole number of lane tiles:
+128 for Nemotron-H, 256 for Falcon-H1).  A step's heads are bounded by
+BYTES (``STEP_BYTES`` of state: :func:`_heads_per_step`), so a step holds
+two whole groups, one, or PART of one; its ``B``/``C`` block is the group
+or groups its heads belong to.  ``B_t`` and ``C_t`` arrive as
 rows ``[1, N]`` and broadcast along sublanes; the decay arrives spread over
-a full lane row a head (the caller's cheap broadcast); ``dt_t x_t`` arrives
+a full state row a head (the caller's cheap broadcast); ``dt_t x_t`` arrives
 as a row of a ``[T, hs * P]`` block, and is turned into the column ``[P,
 1]`` the outer product needs by a masked lane reduction against a shifted
 identity (exact: one term and zeros), as ``y_t``'s column is turned back
@@ -45,27 +49,44 @@ from ipex_llm_tpu.ops.pallas._compat import interpret as _interpret
 LANES = 128
 
 
+# the state a grid step holds, at most: it is in VMEM four times (in and out,
+# each double-buffered) beside the step's other operands
+STEP_BYTES = 1 << 20
+
+
 def supported(cfg) -> bool:
-    """Shapes the kernel's layout covers: the state's N is the lane width,
-    a head's P packs a whole number of times into a lane row."""
+    """Shapes the kernel's layout covers: the state's N is a whole number
+    of lane tiles, a head's P packs a whole number of times into a lane
+    row."""
     p, n = cfg.ssm_head_dim, cfg.ssm_state_size
     hpg = cfg.ssm_num_heads // cfg.ssm_n_groups
-    return (n == LANES and p % 8 == 0 and LANES % p == 0
+    return (n % LANES == 0 and p % 8 == 0 and LANES % p == 0
             and (hpg * p) % LANES == 0)
 
 
-def _heads_per_step(heads: int, hpg: int) -> int:
+def _heads_per_step(heads: int, hpg: int, p: int, n: int) -> int:
     """Heads a grid step holds: two groups where the group count is even
-    (0.5 MB of state a step at 8 heads of [64, 128]), else one."""
+    (0.5 MB of state a step at 16 heads of [64, 128]), else one; halved
+    while that is over STEP_BYTES (Falcon-H1: a group is 16 heads of [128,
+    256], 2 MB, and a step takes half of one), as long as a half is still
+    whole groups or a whole part of one, and whole lane rows of ``dt x``."""
     groups = heads // hpg
-    return hpg * (2 if groups % 2 == 0 else 1)
+    hs = hpg * (2 if groups % 2 == 0 else 1)
+
+    def fits(h):
+        return ((h % hpg == 0 or hpg % h == 0) and (h * p) % LANES == 0)
+
+    while hs * p * n * 4 > STEP_BYTES and hs % 2 == 0 and fits(hs // 2):
+        hs //= 2
+    return hs
 
 
 def _kernel(layer_ref, lens_ref, dtx_ref, dec_ref, b_ref, c_ref, h_in, *rest,
             hs, hpg, p, t_max):
     """Operand rows are (what, token): ``dtx``/``y`` [chunks * T, 128] (a
-    chunk is the lane row that packs 128 / P heads), ``dec`` [hs * T, 128],
-    ``b``/``c`` [gs * T, N]; every load and store is one full lane row."""
+    chunk is the lane row that packs 128 / P heads), ``dec`` [hs * T, N],
+    ``b``/``c`` [gs * T, N] (the step's ``gs`` groups; one where the step
+    is part of a group); every load and store is whole lane rows."""
     del layer_ref
     y_ref, h_out = rest[-2], rest[-1]
     row = pl.program_id(0)
@@ -83,10 +104,10 @@ def _kernel(layer_ref, lens_ref, dtx_ref, dec_ref, b_ref, c_ref, h_in, *rest,
             dtx = dtx_ref[pl.ds(chunk * t_max + t, 1), :]        # [1, 128]
             xcol = jnp.sum(jnp.where(mask, dtx, 0.0), axis=1,
                            keepdims=True)                        # [P, 1]
-            dec = dec_ref[pl.ds(j * t_max + t, 1), :]            # [1, 128]
+            dec = dec_ref[pl.ds(j * t_max + t, 1), :]            # [1, N]
             b = b_ref[pl.ds(g * t_max + t, 1), :]
             c = c_ref[pl.ds(g * t_max + t, 1), :]
-            h = dec * h + xcol * b                               # [P, 128]
+            h = dec * h + xcol * b                               # [P, N]
             ycol = jnp.sum(h * c, axis=1, keepdims=True)         # [P, 1]
             yrow = jnp.sum(jnp.where(mask, ycol, 0.0), axis=0,
                            keepdims=True)                        # [1, 128]
@@ -100,20 +121,24 @@ def _kernel(layer_ref, lens_ref, dtx_ref, dec_ref, b_ref, c_ref, h_in, *rest,
 def _scan(src, dst, layer, lens, dtx, dec, b, c, *, hpg: int,
           two_stacks: bool):
     """src/dst [L, R, H, P, N]; lens [R]; dtx [R, S, chunks*T, 128]; dec [R,
-    S, hs*T, 128]; b/c [R, S, gs*T, N] with S = H / hs steps a row."""
+    S, hs*T, N] with S = H / hs steps a row; b/c [R, G / gs, gs*T, N], a
+    block the ``gs`` groups of a step (the one group of ``hpg / hs`` steps
+    where a step is part of a group)."""
     _, r, heads, p, n = dst.shape
-    hs = _heads_per_step(heads, hpg)
+    hs = _heads_per_step(heads, hpg, p, n)
     steps = heads // hs
     t = dec.shape[2] // hs
+    steps_a_block = max(1, hpg // hs)
 
-    def blk(shape):
+    def blk(shape, steps=1):      # ``steps`` grid steps share one block
         return pl.BlockSpec((None, None) + shape,
-                            lambda i, s, lyr, ln: (i, s, 0, 0))
+                            lambda i, s, lyr, ln: (i, s // steps, 0, 0))
 
     state = pl.BlockSpec((None, None, hs, p, n),
                          lambda i, s, lyr, ln: (lyr[0], i, s, 0, 0))
     in_specs = [blk(dtx.shape[2:]), blk(dec.shape[2:]),
-                blk(b.shape[2:]), blk(c.shape[2:]), state]
+                blk(b.shape[2:], steps_a_block),
+                blk(c.shape[2:], steps_a_block), state]
     args = [dtx, dec, b, c, src]
     if two_stacks:
         # the written stack rides as an operand only to be aliased to the
@@ -143,18 +168,17 @@ def _blocked(x, dt, a, b, c, hs: int, hpg: int):
     """The kernel's operand layouts, rows (what, token), from x [B, T, H,
     P], dt [B, T, H], a [H], b/c [B, T, G, N]."""
     bsz, t, heads, p = x.shape
-    steps, gs = heads // hs, hs // hpg
+    gs = max(1, hs // hpg)
     chunks = hs * p // LANES
 
     def rows(v, what):      # [B, T, S * what, W] -> [B, S, what * T, W]
-        v = v.reshape(bsz, t, steps, what, v.shape[-1])
+        v = v.reshape(bsz, t, -1, what, v.shape[-1])
         return v.transpose(0, 2, 3, 1, 4).reshape(
-            bsz, steps, what * t, v.shape[-1])
+            bsz, v.shape[2], what * t, v.shape[-1])
 
-    dtx = rows((dt[..., None] * x).reshape(bsz, t, steps * chunks, LANES),
-               chunks)
+    dtx = rows((dt[..., None] * x).reshape(bsz, t, -1, LANES), chunks)
     dec = rows(jnp.broadcast_to(jnp.exp(dt * a)[..., None],
-                                (bsz, t, heads, LANES)), hs)
+                                (bsz, t, heads, b.shape[-1])), hs)
     return dtx, dec, rows(b, gs), rows(c, gs)
 
 
@@ -174,7 +198,7 @@ def scan_stack(src, dst, layer, x, dt, a, b, c, lens):
     written stack)."""
     heads, p = x.shape[2], x.shape[3]
     hpg = heads // b.shape[2]
-    hs = _heads_per_step(heads, hpg)
+    hs = _heads_per_step(heads, hpg, p, b.shape[3])
     y, h = _scan(src, dst, layer, lens, *_blocked(x, dt, a, b, c, hs, hpg),
                  hpg=hpg, two_stacks=src is not dst)
     return _unblocked(y, heads, p), h

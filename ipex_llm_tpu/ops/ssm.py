@@ -1,8 +1,10 @@
 """Mamba-2 (state-space duality, Dao & Gu 2024) for the hybrid decoder.
 
-One mixer of a Nemotron-H block (``models/decoder.py`` walks the schedule):
+One mixer of a Nemotron-H block, or the state-space branch of a Falcon-H1
+block (``models/decoder.py`` walks the schedule):
 
-    [z | xBC | dt] = in_proj(u)
+    [z | xBC | dt] = in_proj(u)         Falcon-H1: mup (.) in_proj(m_in u), and
+                                        m_out out_proj(y) at the end
     xBC  <- silu(causal depthwise conv_K(xBC) + conv_bias)
     [x | B | C] = xBC           x as [H, P]; B, C as [G, N], shared by H/G heads
     dt   <- softplus(dt + dt_bias);   A = -exp(A_log)
@@ -110,6 +112,16 @@ def _scan(cfg, stack_src, stack_dst, layer, rows, fresh, x, dt, a, b, c,
     return y, stack_dst.at[layer, rows].set(h, mode="drop")
 
 
+def mup_vector(cfg):
+    """Falcon-H1's ``ssm_multipliers`` spread over the columns of
+    ``in_proj``'s output: [z | x | B | C | dt], float32."""
+    d_in = cfg.ssm_num_heads * cfg.ssm_head_dim
+    gn = cfg.ssm_n_groups * cfg.ssm_state_size
+    widths = (d_in, d_in, gn, gn, cfg.ssm_num_heads)
+    return jnp.concatenate([jnp.full((w,), m, jnp.float32)
+                            for w, m in zip(widths, cfg.ssm_multipliers)])
+
+
 def mamba2_mixer(cfg, lp: dict, u, state: dict, layer, valid, rows=None,
                  fresh=None):
     """The Mamba-2 mixer on the normed input ``u`` [B, T, hidden].
@@ -125,7 +137,10 @@ def mamba2_mixer(cfg, lp: dict, u, state: dict, layer, valid, rows=None,
     bsz, t, _ = u.shape
     src = state.get("src", state)
     with jax.named_scope("ssm/proj"):
-        zxd = linear_ops.linear(u, lp["in_proj"])
+        zxd = linear_ops.linear(
+            linear_ops.times(u, cfg.ssm_in_multiplier), lp["in_proj"])
+        if cfg.ssm_multipliers is not None:
+            zxd = linear_ops.times(zxd, mup_vector(cfg))
         z = zxd[..., :d_in]
         xbc = zxd[..., d_in: 2 * d_in + 2 * gn]
         dt = zxd[..., 2 * d_in + 2 * gn:]
@@ -163,6 +178,8 @@ def mamba2_mixer(cfg, lp: dict, u, state: dict, layer, valid, rows=None,
         yg = yg * jax.lax.rsqrt(
             jnp.mean(yg * yg, axis=-1, keepdims=True) + cfg.norm_eps)
         y = yg.reshape(bsz, t, d_in) * lp["gate_norm"].astype(jnp.float32)
-        out = linear_ops.linear(y.astype(u.dtype), lp["out_proj"])
+        out = linear_ops.times(
+            linear_ops.linear(y.astype(u.dtype), lp["out_proj"]),
+            cfg.ssm_out_multiplier)
     new_state = dict(state, h=h, conv=conv)
     return out, new_state
