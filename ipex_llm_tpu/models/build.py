@@ -110,8 +110,8 @@ def _requant_leaf(qt: QTensor, qtype: str, imatrix=None) -> QTensor:
         return s.reshape(lead + s.shape[1:]) if lead else s[0]
 
     data = restack([q.data for q in qts])
-    scales = (restack([q.scales for q in qts])
-              if q0.scales is not None else None)
+    scales = (restack([q.scale_bits for q in qts])
+              if q0.scale_bits is not None else None)
     zeros = (restack([q.zeros for q in qts])
              if q0.zeros is not None else None)
     return QTensor(data, scales, zeros, q0.qtype, qt.shape, q0.block_size,
@@ -209,7 +209,7 @@ def dequantize_params(params: dict[str, Any],
                     else leaf.reshape((n,) + leaf.shape[len(lead):])[i])
 
         flat = [qcore.dequantize(
-                    QTensor(plane(tree.data, i), plane(tree.scales, i),
+                    QTensor(plane(tree.data, i), plane(tree.scale_bits, i),
                             plane(tree.zeros, i), tree.qtype, tree.shape,
                             tree.block_size), dtype=dtype)
                 for i in range(n)]

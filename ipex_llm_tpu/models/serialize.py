@@ -16,7 +16,7 @@ from typing import Any
 import jax.numpy as jnp
 import numpy as np
 
-from ipex_llm_tpu.quantize.core import QTensor
+from ipex_llm_tpu.quantize.core import QTensor, f16_bits, f16_view
 
 CONFIG_NAME = "bigdl_config.json"  # reference-compatible filename (model.py:59)
 WEIGHTS_NAME = "model_low_bit.safetensors"
@@ -39,8 +39,10 @@ def flatten_params(params: dict) -> tuple[dict[str, np.ndarray], dict[str, Any]]
     for path, v in _walk(params):
         if isinstance(v, QTensor):
             tensors[path + ".q.data"] = np.asarray(v.data)
-            if v.scales is not None:
-                tensors[path + ".q.scales"] = np.asarray(v.scales)
+            if v.scale_bits is not None:
+                # fp16 on disk, whatever the tree holds on the device
+                tensors[path + ".q.scales"] = f16_view(
+                    np.asarray(v.scale_bits))
             if v.zeros is not None:
                 tensors[path + ".q.zeros"] = np.asarray(v.zeros)
             manifest["qtensors"][path] = {
@@ -89,8 +91,8 @@ def unflatten_params(
                 base,
                 QTensor(
                     data=jnp.asarray(tensors[base + ".q.data"]),
-                    scales=(
-                        jnp.asarray(tensors[base + ".q.scales"])
+                    scale_bits=(
+                        jnp.asarray(f16_bits(tensors[base + ".q.scales"]))
                         if base + ".q.scales" in tensors else None
                     ),
                     zeros=(

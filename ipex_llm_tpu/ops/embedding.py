@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from ipex_llm_tpu.quantize import numerics
-from ipex_llm_tpu.quantize.core import QTensor
+from ipex_llm_tpu.quantize.core import SCALE_DTYPE, QTensor
 
 EMBED_QTYPES = ("sym_int8", "sym_int4", "nf4", "fp4")
 
@@ -34,7 +34,10 @@ def embed_lookup(table, ids: jnp.ndarray, dtype=jnp.bfloat16) -> jnp.ndarray:
     qtype = table.qtype
     block = ids // bs                     # [...,]
     offset = ids % bs
-    scales = jnp.take(table.scales, block, axis=0).astype(jnp.float32)
+    # the rows' bit patterns, then their numbers: never the whole table's
+    scales = jax.lax.bitcast_convert_type(
+        jnp.take(table.scale_bits, block, axis=0), SCALE_DTYPE
+    ).astype(jnp.float32)
 
     if qtype == "sym_int8":
         codes = jnp.take(table.data, ids, axis=0).astype(jnp.int32)

@@ -266,9 +266,9 @@ def _held_by_slot(h, gate, gate_up, down, act, gated, first):
     hit = jnp.any(gate != 0, axis=0)                     # [E_held]
     n_hit = jnp.sum(hit).astype(jnp.int32)
     order = jnp.argsort(~hit, stable=True).astype(jnp.int32)   # hit ones first
-    inner = qmatmul_pallas_grouped(h, gate_up, order, n_hit, first, n_held)
+    inner = qmatmul_pallas_grouped(h, gate_up, order, n_hit, first)
     a = _expert_act(inner.astype(h.dtype), act, gated)
-    y = qmatmul_pallas_grouped(a, down, order, n_hit, first, n_held)
+    y = qmatmul_pallas_grouped(a, down, order, n_hit, first)
     # the slots past the hit ones were not in the grid: nothing was written
     live = (jnp.arange(n_held) < n_hit)[:, None, None]
     y = jnp.where(live, y, 0.0).astype(h.dtype)
@@ -309,9 +309,9 @@ def _held_by_rows(h, gate, gate_up, down, act, gated, first, bm, kmax):
     row_tok = jnp.sum(seen.T[tile_e][:, None, :] <= place[:, :, None],
                       axis=-1)                           # [T, bm]; N: padding
     x = jnp.pad(h, ((0, 1), (0, 0)))[row_tok]            # [T, bm, H]
-    inner = qmatmul_pallas_grouped(x, gate_up, tile_e, n_live, first, n_held)
+    inner = qmatmul_pallas_grouped(x, gate_up, tile_e, n_live, first)
     a = _expert_act(inner.astype(h.dtype), act, gated)
-    y = qmatmul_pallas_grouped(a, down, tile_e, n_live, first, n_held)
+    y = qmatmul_pallas_grouped(a, down, tile_e, n_live, first)
     y = y.reshape(-1, y.shape[-1])                       # [T x bm, H]
 
     row = (tile_first * bm)[None, :] + seen - 1          # [N, E] a pair's row

@@ -20,6 +20,16 @@ Design (TPU-first, see SURVEY.md §2.3 "TPU mapping"):
   the index maps take the layer from scalar prefetch, so the tiles are read
   out of the stack and no per-layer copy of the codes is made on the way.  A
   lone weight is the same kernel over a stack of one.
+- Mosaic loads no fp16 vector on this chip, so a ``QTensor`` holds its fp16
+  scales as their uint16 bit patterns (``quantize/core.py``).  The EXPERT
+  kernel (``qmatmul_expert``) takes a tile of the patterns out of the whole
+  ``[L x E, ...]`` stack through the codes' own index map and converts it
+  inside (``_f16_bits_to_f32``): nothing stands between the tick program's
+  parameter and that call.  The DENSE kernel (``qmatmul``) streams the one
+  layer's plane as float32, made by one XLA fusion a call, because
+  converting inside it is VPU-bound and a whole scale stack under 128 MiB
+  gets copied to fast memory each layer of a mixed tick (PERF.md section 6,
+  PR 47).
 
 Supported formats: sym_int4 / asym_int4 / sym_int8, the 4-bit codebook
 formats nf4 / fp4 (16-entry lookup unrolled as a select chain on the VPU),
@@ -151,6 +161,20 @@ def tile_plan(k: int, n: int, bs: int) -> tuple[int, int, int, int]:
         kp += 256
 
 
+def _f16_bits_to_f32(h):
+    """fp16 bit patterns (int32 in [0, 65536)) -> float32, by integer
+    arithmetic, exact for every pattern (subnormals included; the VPU
+    flushes float32 subnormals, so a renormalising multiply would not be):
+    Mosaic loads no f16 vector on this chip, so the expert kernel reads the
+    fp16 scale planes where they lie as uint16."""
+    sign = jnp.where((h & 0x8000) != 0, -1.0, 1.0)
+    mag = h & 0x7FFF
+    normal = jax.lax.bitcast_convert_type((mag << 13) + (112 << 23),
+                                          jnp.float32)
+    subnormal = (mag & 0x3FF).astype(jnp.float32) * (2.0 ** -24)
+    return sign * jnp.where(mag >= 0x400, normal, subnormal)
+
+
 def _make_kernel(qtype, bs, bk, bn, compute_dtype, has_high, has_zeros):
     def kern(*refs):
         # refs[0] is the prefetched layer index: the index maps' alone
@@ -196,8 +220,8 @@ def _qmatmul_2d(x, data, scales, zeros, layer=None, *, qtype: str, bs: int,
     per-layer copy of the codes exists between a layer scan and this call.
     A stack tiles exactly (``stacked_supported``); a lone plane that does
     not is padded here, and is then the same kernel over a stack of one.
-    ``scales``/``zeros`` are the one layer's planes either way (an eighth of
-    the codes' bytes; Mosaic takes no fp16 argument on this chip)."""
+    ``scales``/``zeros`` are the one layer's planes either way, as numbers
+    (an eighth of the codes' bytes, streamed as float32)."""
     m, k = x.shape
     n = data.shape[-1]
     bit5 = qtype in _BIT5
@@ -287,18 +311,6 @@ def _qmatmul_2d(x, data, scales, zeros, layer=None, *, qtype: str, bs: int,
     return out[:m, :logical_out]
 
 
-def _f16_bits_to_f32(h):
-    """fp16 bit patterns (int32 in [0, 65536)) -> float32, by integer
-    arithmetic: Mosaic loads no f16 vector on this chip, so a kernel that
-    reads the fp16 scale planes where they lie takes them as uint16."""
-    sign = jnp.where((h & 0x8000) != 0, -1.0, 1.0)
-    mag = h & 0x7FFF
-    normal = jax.lax.bitcast_convert_type((mag << 13) + (112 << 23),
-                                          jnp.float32)
-    subnormal = (mag & 0x3FF).astype(jnp.float32) * (2.0 ** -24)
-    return sign * jnp.where(mag >= 0x400, normal, subnormal)
-
-
 def _grouped_kernel(ids_ref, first_ref, x_ref, d_ref, s_ref, o_ref, *, qtype,
                     bs, bk, bn, compute_dtype):
     del ids_ref, first_ref                    # the index maps' alone
@@ -321,14 +333,12 @@ def _grouped_kernel(ids_ref, first_ref, x_ref, d_ref, s_ref, o_ref, *, qtype,
 def _qmatmul_grouped(x, data, scale_bits, ids, n_live, first, *, qtype: str,
                      bs: int, logical_out: int, compute_dtype):
     """One call over ROW TILES that each name an entry of a stack of planes
-    (an expert layer's hit experts): ``data`` [E, K/2, N] stays whole in HBM
-    and tile ``t`` of the grid's first axis multiplies its rows by entry
-    ``first + ids[t]`` of it, read in place through the index maps;
-    ``scale_bits`` [G, K/bs, N] are the fp16 scales of the group's entries
-    ``first .. first + G`` as uint16 bit patterns, of which the tile reads
-    plane ``ids[t]`` (Mosaic takes no fp16 argument on this chip, and a
-    bitcast of the WHOLE scale stack is a pass over it every tick, so the
-    caller cuts the group's planes out, a twelfth of the codes' bytes).
+    (an expert layer's hit experts): ``data`` [E, K/2, N] and ``scale_bits``
+    [E, K/bs, N] (the fp16 scales as the uint16 bit patterns a ``QTensor``
+    holds; Mosaic loads no fp16 vector on this chip) stay whole in HBM and
+    tile ``t`` of the grid's first axis multiplies its rows by entry
+    ``first + ids[t]`` of them, both read in place through one index map
+    and the scales converted in the kernel.
     ``x`` is [bm, K] (every tile reads it) or [T, bm, K] (tile t its own).
 
     The grid is (``n_live``, N tiles, K tiles) and its first bound is the
@@ -345,7 +355,8 @@ def _qmatmul_grouped(x, data, scale_bits, ids, n_live, first, *, qtype: str,
     bm, k = x.shape[-2:]
     tiles, n = ids.shape[0], data.shape[2]
     bk, kp, bn, np_ = tile_plan(k, n, bs)
-    if (kp, np_) != (k, n) or data.shape[1] * 2 != k:
+    if ((kp, np_) != (k, n) or data.shape[1] * 2 != k
+            or scale_bits.shape != (data.shape[0], k // bs, n)):
         raise ValueError(
             f"stacked planes [{data.shape[1] * 2}, {n}] are not padded to "
             f"the kernel's tiles ({kp}, {np_}): pad them at build "
@@ -360,16 +371,14 @@ def _qmatmul_grouped(x, data, scale_bits, ids, n_live, first, *, qtype: str,
     else:
         x_spec = pl.BlockSpec((bm, bk),
                               lambda t, ni, ki, ids, first: (0, ki))
+    entry = lambda t, ni, ki, ids, first: (  # noqa: E731
+        first[0] + ids[t], ki, ni)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(jnp.reshape(n_live, ()).astype(jnp.int32), np_ // bn, kp // bk),
         in_specs=[x_spec,
-                  pl.BlockSpec((None, bk // 2, bn),
-                               lambda t, ni, ki, ids, first: (
-                                   first[0] + ids[t], ki, ni)),
-                  pl.BlockSpec((None, bk // bs, bn),
-                               lambda t, ni, ki, ids, first: (
-                                   ids[t], ki, ni))],
+                  pl.BlockSpec((None, bk // 2, bn), entry),
+                  pl.BlockSpec((None, bk // bs, bn), entry)],
         out_specs=pl.BlockSpec(
             (None, bm, bn), lambda t, ni, ki, ids, first: (t, 0, ni)),
     )
@@ -390,9 +399,10 @@ def _qmatmul_grouped(x, data, scale_bits, ids, n_live, first, *, qtype: str,
 
 def grouped_supported(qt: QTensor) -> bool:
     """Whether :func:`qmatmul_pallas_grouped` covers this stack: sym_int4
-    planes [E, K/2, N] with fp16 scales, padded to the kernel's tiles."""
+    planes [E, K/2, N] with fp16 scales (held as their bits), padded to the
+    kernel's tiles."""
     if (qt.qtype != "sym_int4" or qt.data.ndim != 3
-            or qt.scales.dtype != jnp.float16):
+            or qt.scale_bits.dtype != jnp.uint16):
         return False
     k, n = qt.data.shape[1] * 2, qt.data.shape[2]
     _, kp, _, np_ = tile_plan(k, n, qt.block_size)
@@ -400,25 +410,22 @@ def grouped_supported(qt: QTensor) -> bool:
 
 
 def qmatmul_pallas_grouped(x: jnp.ndarray, qt: QTensor, ids, n_live,
-                           first=0, group: int | None = None,
-                           compute_dtype=jnp.bfloat16):
+                           first=0, compute_dtype=jnp.bfloat16):
     """x @ dequant(qt[first + ids[t]]) for the first ``n_live`` row tiles t
-    of ``ids`` (traced int32 [T], entries of the GROUP ``first .. first +
-    group`` of the stacked weight ``qt``; the whole stack by default): x [M,
-    in] for every tile, or [T, M, in] a tile's own.  ``qt`` is read in
-    place (an expert layer's ``[E, ...]`` planes, or with ``first`` a
-    model's ``[L x E, ...]``).  Returns [T, M, out] float32 whose tiles
-    past ``n_live`` are not written."""
+    of ``ids`` (traced int32 [T], entries counted from ``first`` of the
+    stacked weight ``qt``): x [M, in] for every tile, or [T, M, in] a
+    tile's own.  ``qt`` is read in place, codes and scales (an expert
+    layer's ``[E, ...]`` planes, or with ``first`` a model's ``[L x E,
+    ...]``).  Returns [T, M, out] float32 whose tiles past ``n_live`` are
+    not written."""
     m, k_pad = x.shape[-2], qt.data.shape[1] * 2
     if x.shape[-1] != k_pad or m % 16:
         x = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [
             (0, _round_up(m, 16) - m), (0, k_pad - x.shape[-1])])
-    scales = qt.scales if group is None else jax.lax.dynamic_slice_in_dim(
-        qt.scales, first, group, axis=0)
     return _qmatmul_grouped(
-        x, qt.data, jax.lax.bitcast_convert_type(scales, jnp.uint16),
-        ids, n_live, first, qtype=qt.qtype, bs=qt.block_size,
-        logical_out=qt.out_features, compute_dtype=compute_dtype)[:, :m]
+        x, qt.data, qt.scale_bits, ids, n_live, first, qtype=qt.qtype,
+        bs=qt.block_size, logical_out=qt.out_features,
+        compute_dtype=compute_dtype)[:, :m]
 
 
 def stacked_supported(stack: QTensor) -> bool:
@@ -432,7 +439,7 @@ def stacked_supported(stack: QTensor) -> bool:
     num, den = _data_row_factor(stack.qtype)
     k, n = stack.data.shape[1] * num // den, stack.data.shape[2]
     _, kp, _, np_ = tile_plan(k, n, stack.block_size)
-    return (kp, np_) == (k, n) and stack.scales.shape[1:] == (
+    return (kp, np_) == (k, n) and stack.scale_bits.shape[1:] == (
         k // stack.block_size, n)
 
 
@@ -525,8 +532,8 @@ def qmatmul_pallas_sharded(x: jnp.ndarray, qt: QTensor, mesh,
         x_spec = P(*lead, "tp")
         out_spec = P(*lead, None)
 
-    def run(xl, data, scales, zeros=None):
-        lqt = QTensor(data, scales, zeros, qt.qtype, local_shape,
+    def run(xl, data, scale_bits, zeros=None):
+        lqt = QTensor(data, scale_bits, zeros, qt.qtype, local_shape,
                       qt.block_size)
         if qt.tp_mode == "col":
             return qmatmul_pallas(xl, lqt, compute_dtype)
@@ -534,7 +541,7 @@ def qmatmul_pallas_sharded(x: jnp.ndarray, qt: QTensor, mesh,
         return jax.lax.psum(part, "tp").astype(xl.dtype)
 
     in_specs = [x_spec, w_spec, w_spec] + ([w_spec] if has_zeros else [])
-    args = [x, qt.data, qt.scales] + ([qt.zeros] if has_zeros else [])
+    args = [x, qt.data, qt.scale_bits] + ([qt.zeros] if has_zeros else [])
     return jax.shard_map(
         run, mesh=mesh, axis_names={"tp"},
         in_specs=tuple(in_specs), out_specs=out_spec, check_vma=False,

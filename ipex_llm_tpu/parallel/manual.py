@@ -149,8 +149,8 @@ def _permute_out_cols(leaf, idx: np.ndarray):
         return _dc_replace(
             leaf,
             data=jnp.asarray(leaf.data)[..., idx],
-            scales=(None if leaf.scales is None
-                    else jnp.asarray(leaf.scales)[..., idx]),
+            scale_bits=(None if leaf.scale_bits is None
+                        else jnp.asarray(leaf.scale_bits)[..., idx]),
             zeros=(None if leaf.zeros is None
                    else jnp.asarray(leaf.zeros)[..., idx]),
         )
@@ -202,7 +202,7 @@ def shard_params_manual(params: dict, cfg: ModelConfig, mesh) -> dict:
     if isinstance(emb, QTensor):
         sh["embed"] = _dc_replace(
             sh["embed"], data=rep,
-            scales=None if emb.scales is None else rep,
+            scale_bits=None if emb.scale_bits is None else rep,
             zeros=None if emb.zeros is None else rep, tp_mode=None)
     elif emb is not None:
         sh["embed"] = rep
@@ -242,7 +242,7 @@ def _qt_spec(qt: QTensor) -> QTensor:
     else:
         sp = P()
     return _dc_replace(qt, data=sp,
-                       scales=None if qt.scales is None else sp,
+                       scale_bits=None if qt.scale_bits is None else sp,
                        zeros=None if qt.zeros is None else sp)
 
 

@@ -59,7 +59,7 @@ def _qtensor_spec(qt: QTensor, kind: str, tp: int, stacked: bool,
         n_experts = qt.data.shape[len(lead)]
         lead = lead + ("ep" if _divisible(n_experts, ep) and ep > 1 else None,)
     data_in = qt.data.shape[-2]
-    nb = qt.scales.shape[-2] if qt.scales is not None else data_in
+    nb = qt.scale_bits.shape[-2] if qt.scale_bits is not None else data_in
     if kind == "col" and _divisible(qt.out_features, tp):
         mode = "col" if tp > 1 else None
         return P(*lead, None, "tp"), mode
@@ -100,7 +100,7 @@ def param_shardings(params: dict, mesh: Mesh) -> dict:
         spec, mode = _qtensor_spec(qt, kind, tp, stacked, ep=ep, pp=pp)
         return QTensor(
             data=ns(spec),
-            scales=None if qt.scales is None else ns(spec),
+            scale_bits=None if qt.scale_bits is None else ns(spec),
             zeros=None if qt.zeros is None else ns(spec),
             qtype=qt.qtype, shape=qt.shape, block_size=qt.block_size,
             tp_mode=mode,

@@ -4,11 +4,11 @@ TPU-first replacement for the reference's ``FP4Params`` tensor subclass and the
 ggml quantize/dequantize C routines (reference: low_bit_linear.py:332-491,
 ggml/model/llama/llama_cpp.py:71-109).  Differences by design:
 
-- A ``QTensor`` is a registered JAX pytree (packed code planes + fp16 scales
-  as leaves; qtype/shape static) so it flows through ``jit``/``pjit``/
-  ``jax.sharding`` like any array — no custom device-move hooks, no
-  cpu↔device layout conversion step (ggml_q_format_convet_cpu2xpu has no
-  TPU equivalent because the layout is already kernel-native).
+- A ``QTensor`` is a registered JAX pytree (packed code planes + the fp16
+  scales' bit patterns as leaves; qtype/shape static) so it flows through
+  ``jit``/``pjit``/``jax.sharding`` like any array — no custom device-move
+  hooks, no cpu↔device layout conversion step (ggml_q_format_convet_cpu2xpu
+  has no TPU equivalent because the layout is already kernel-native).
 - Quantization happens along the matmul **contraction axis** (axis 0 of the
   logical ``[in_features, out_features]`` weight).  Scales have shape
   ``[n_blocks, out]``; packed int4 nibble pairs sit along the contraction
@@ -33,13 +33,76 @@ from ipex_llm_tpu.quantize import numerics, qtypes
 SCALE_DTYPE = jnp.float16
 
 
+def f16_bits(plane):
+    """An fp16 array as its uint16 bit patterns (same shape, same bytes);
+    anything else as it is."""
+    if getattr(plane, "dtype", None) != SCALE_DTYPE:
+        return plane
+    if isinstance(plane, np.ndarray):
+        return plane.view(np.uint16)
+    return jax.lax.bitcast_convert_type(plane, jnp.uint16)
+
+
+def f16_view(bits):
+    """The fp16 numbers of uint16 bit patterns; anything else as it is.
+    Under a trace a bitcast, which fuses into its XLA consumer; of a host
+    array a view; of a concrete device array an :class:`F16View`."""
+    if getattr(bits, "dtype", None) != jnp.uint16:
+        return bits
+    if isinstance(bits, np.ndarray):
+        return bits.view(np.float16)
+    if isinstance(bits, jax.core.Tracer):
+        return jax.lax.bitcast_convert_type(bits, SCALE_DTYPE)
+    return F16View(bits)
+
+
+@jax.tree_util.register_pytree_node_class
+class F16View:
+    """``QTensor.scales`` of a CONCRETE device array, outside a jit: what
+    the benchmark's plain references walk (``leaf.scales[i]``, then into
+    their jitted forward).  A bitcast there would copy the whole stack to
+    read one layer, so indexing slices the BITS and stays a view, and the
+    view crosses into a jitted function as its bits, where it is the traced
+    bitcast; a ``jnp`` function takes it as the bitcast array (one reference
+    pads its head's last block).  That is all it does: for numbers on the
+    host, view ``np.asarray(scale_bits)``.  It goes when the references
+    bitcast for themselves (ROADMAP D11n)."""
+
+    def __init__(self, bits):
+        self.bits = bits
+
+    shape = property(lambda self: self.bits.shape)
+
+    def __getitem__(self, index):
+        return F16View(self.bits[index])
+
+    def __jax_array__(self):
+        return jax.lax.bitcast_convert_type(self.bits, SCALE_DTYPE)
+
+    def tree_flatten(self):
+        return (self.bits,), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return f16_view(children[0])
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass
 class QTensor:
     """A block-quantized 2-D weight ``[in_features, out_features]``.
 
     data:   packed codes; layout depends on qtype (see codecs below)
-    scales: per-(block, out) scale, fp16
+    scale_bits: per-(block, out) fp16 scale, HELD AS ITS BIT PATTERNS
+        (uint16, the same shape and bytes): Mosaic loads no fp16 vector, so
+        a kernel that reads a stack's planes where they lie (the expert
+        kernel, ops/pallas/qmatmul.py) takes the patterns and converts a
+        tile inside.  The constructor takes the fp16 plane and stores its
+        bits (a bitcast: traced under a jit, one copy of a concrete device
+        array), or takes the bits as they are.  A child that is no fp16
+        array (a sharding tree's spec, ``None``, a k-quant import's float32
+        plane) is held untouched.  Whoever wants NUMBERS reads
+        :attr:`scales`.
     zeros:  per-(block, out) zero/min for asym formats, else None
     qtype:  resolved qtype name (static)
     shape:  logical (in_features, out_features) (static)
@@ -51,24 +114,34 @@ class QTensor:
     """
 
     data: jnp.ndarray
-    scales: jnp.ndarray | None
+    scale_bits: jnp.ndarray | None
     zeros: jnp.ndarray | None
     qtype: str
     shape: tuple[int, int]
     block_size: int
     tp_mode: str | None = None
 
+    def __post_init__(self):
+        self.scale_bits = f16_bits(self.scale_bits)
+
+    @property
+    def scales(self):
+        """The scale plane as fp16 numbers (:func:`f16_view` of the stored
+        bits): inside a jit a bitcast that fuses into its XLA consumer and
+        costs nothing; outside one an :class:`F16View`."""
+        return f16_view(self.scale_bits)
+
     def tree_flatten(self):
-        return (self.data, self.scales, self.zeros), (
+        return (self.data, self.scale_bits, self.zeros), (
             self.qtype, self.shape, self.block_size, self.tp_mode,
         )
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        data, scales, zeros = children
+        data, scale_bits, zeros = children
         qtype, shape, block_size = aux[:3]
         tp_mode = aux[3] if len(aux) > 3 else None
-        return cls(data, scales, zeros, qtype, shape, block_size, tp_mode)
+        return cls(data, scale_bits, zeros, qtype, shape, block_size, tp_mode)
 
     @property
     def in_features(self) -> int:
@@ -81,8 +154,8 @@ class QTensor:
     @property
     def nbytes(self) -> int:
         n = self.data.size * self.data.dtype.itemsize
-        if self.scales is not None:
-            n += self.scales.size * self.scales.dtype.itemsize
+        if self.scale_bits is not None:
+            n += self.scale_bits.size * self.scale_bits.dtype.itemsize
         if self.zeros is not None:
             n += self.zeros.size * self.zeros.dtype.itemsize
         return n
@@ -691,20 +764,23 @@ def quantize(w: Any, qtype: str, block_size: int | None = None, *,
                 out = _nq.quantize_sym_native(wf, int(info.bits), bs)
                 if out is not None:
                     data, scales = out
-                    return QTensor(jnp.asarray(data), jnp.asarray(scales),
+                    return QTensor(jnp.asarray(data),
+                                   jnp.asarray(f16_bits(scales)),
                                    None, info.name, shape, bs)
             elif info.kind == "int_asym":
                 out = _nq.quantize_asym_native(wf, int(info.bits), bs)
                 if out is not None:
                     data, scales, zeros = out
-                    return QTensor(jnp.asarray(data), jnp.asarray(scales),
+                    return QTensor(jnp.asarray(data),
+                                   jnp.asarray(f16_bits(scales)),
                                    jnp.asarray(zeros), info.name, shape, bs)
             elif int(info.bits) == 4:  # codebook: nf4 / fp4
                 out = _nq.quantize_codebook_native(
                     wf, _codebook_table(info.name), bs)
                 if out is not None:
                     data, scales = out
-                    return QTensor(jnp.asarray(data), jnp.asarray(scales),
+                    return QTensor(jnp.asarray(data),
+                                   jnp.asarray(f16_bits(scales)),
                                    None, info.name, shape, bs)
 
     w = _as_jnp_f32(w)
@@ -757,10 +833,11 @@ def kernel_padded_shape(k: int, n: int, bs: int) -> tuple[int, int]:
 
 def pad_planes(qt: QTensor) -> QTensor:
     """``qt`` with its planes zero-padded to :func:`kernel_padded_shape`;
-    the logical ``shape`` stays.  Padded scale rows and columns are zero, so
-    a padded code dequantizes to 0 whatever its bits; ``dequantize`` and the
-    kernel cut the logical weight back out.  Sym 4-bit formats only (the
-    formats with no zero plane); any leading axes (layer, expert) ride."""
+    the logical ``shape`` stays.  Padded scale rows and columns are zero
+    (zero bits are +0.0), so a padded code dequantizes to 0 whatever its
+    bits; ``dequantize`` and the kernel cut the logical weight back out.
+    Sym 4-bit formats only (the formats with no zero plane); any leading
+    axes (layer, expert) ride."""
     if qt.qtype not in ("sym_int4", "nf4", "fp4"):
         return qt
     k, n = qt.data.shape[-2] * 2, qt.data.shape[-1]
@@ -769,9 +846,9 @@ def pad_planes(qt: QTensor) -> QTensor:
         return qt
     lead = [(0, 0)] * (qt.data.ndim - 2)
     data = jnp.pad(qt.data, lead + [(0, (kp - k) // 2), (0, np_ - n)])
-    scales = jnp.pad(qt.scales, lead + [
-        (0, kp // qt.block_size - qt.scales.shape[-2]), (0, np_ - n)])
-    return QTensor(data, scales, None, qt.qtype, qt.shape, qt.block_size,
+    bits = jnp.pad(qt.scale_bits, lead + [
+        (0, kp // qt.block_size - qt.scale_bits.shape[-2]), (0, np_ - n)])
+    return QTensor(data, bits, None, qt.qtype, qt.shape, qt.block_size,
                    qt.tp_mode)
 
 

@@ -156,10 +156,12 @@ def _with_plane_columns_scaled(params, name, lo, hi, factor):
     would compute."""
     tree = dict(params["hybrid"]["parallel"])
     qt = tree[name]
-    col = jnp.ones((qt.scales.shape[-1],), jnp.float32).at[lo:hi].set(factor)
-    tree[name] = QTensor(qt.data, (qt.scales.astype(jnp.float32)
-                                   * col).astype(qt.scales.dtype),
-                         qt.zeros, qt.qtype, qt.shape, qt.block_size)
+    scales = np.asarray(qt.scale_bits).view(np.float16)
+    col = np.ones((scales.shape[-1],), np.float32)
+    col[lo:hi] = factor
+    tree[name] = QTensor(
+        qt.data, jnp.asarray((scales.astype(np.float32) * col).astype(np.float16)),
+        qt.zeros, qt.qtype, qt.shape, qt.block_size)
     return dict(params, hybrid={"parallel": tree})
 
 

@@ -204,7 +204,7 @@ def test_kquant_matches_scalar_spec(qtype):
     )  # [out, in]
     qt = QTensor(
         data=raw.reshape(n_out, -1),
-        scales=None,
+        scale_bits=None,
         zeros=None,
         qtype=qtype,
         shape=(nb * 256, n_out),

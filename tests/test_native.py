@@ -28,7 +28,7 @@ def test_native_bit_exact(bits, qtype, shape, monkeypatch):
     data, scales = out
     np.testing.assert_array_equal(np.asarray(ref.data), data)
     np.testing.assert_array_equal(
-        np.asarray(ref.scales).view(np.uint16), scales.view(np.uint16)
+        np.asarray(ref.scale_bits), scales.view(np.uint16)
     )
 
 
@@ -66,7 +66,7 @@ def test_native_asym_matches_jnp(qtype, monkeypatch):
     data, scales, zeros = out
     np.testing.assert_array_equal(np.asarray(ref.data), data)
     np.testing.assert_array_equal(
-        np.asarray(ref.scales).view(np.uint16), scales.view(np.uint16))
+        np.asarray(ref.scale_bits), scales.view(np.uint16))
     np.testing.assert_array_equal(
         np.asarray(ref.zeros).view(np.uint16), zeros.view(np.uint16))
 
@@ -87,7 +87,7 @@ def test_native_codebook_matches_jnp(qtype, monkeypatch):
     data, scales = out
     np.testing.assert_array_equal(np.asarray(ref.data), data)
     np.testing.assert_array_equal(
-        np.asarray(ref.scales).view(np.uint16), scales.view(np.uint16))
+        np.asarray(ref.scale_bits), scales.view(np.uint16))
 
 
 def test_core_dispatches_asym_and_codebook_to_native():

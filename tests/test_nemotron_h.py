@@ -340,8 +340,7 @@ def test_planes_are_padded_once_at_build():
     np.testing.assert_allclose(got[1], want.astype(np.float32), atol=0.2)
     # the same entries named inside a group that starts at entry 1
     sub = qmm.qmatmul_pallas_grouped(own, stack, jnp.array([0, 1, 1]),
-                                     jnp.int32(2), first=jnp.int32(1),
-                                     group=2)
+                                     jnp.int32(2), first=jnp.int32(1))
     np.testing.assert_array_equal(sub[:2], got[:2])
     # fp16 scale bits decoded by integer arithmetic, subnormals and zero too
     h = jnp.array([0.0, 6e-8, 1e-5, 0.005, -0.37, 65504.0, -6.1e-5],

@@ -6,6 +6,8 @@ bf16-accumulation tolerance.  On real TPU the same wrappers compile through
 Mosaic; here they run interpreted so CI exercises identical code paths.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -107,6 +109,55 @@ def copies_of_packed_planes(jaxpr) -> list:
     return found
 
 
+def _equations(jaxpr, into_kernels=True):
+    """Every equation of a program, those of its sub-programs included."""
+    from ipex_llm_tpu.analysis.trace.tracer import _iter_subjaxprs
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call" and not into_kernels:
+            continue
+        for sub in eqn.params.values():
+            for j in _iter_subjaxprs(sub):
+                yield from _equations(j, into_kernels)
+
+
+def scale_plane_passes(jaxpr) -> list:
+    """What in a program touches a scale plane (the fp16 scales or their
+    uint16 bit patterns, rank >= 2) on its way to a consumer: a slice, a
+    gather, a pad or a conversion of it, or a scan that carries it as ``xs``,
+    outside a kernel (which converts its tile inside).  A kernel call reads
+    the stack of bit patterns whole."""
+    def plane(v):
+        a = getattr(v, "aval", None)
+        return (a is not None and len(getattr(a, "shape", ())) >= 2
+                and str(a.dtype) in ("uint16", "float16"))
+
+    found = []
+    for eqn in _equations(jaxpr, into_kernels=False):
+        name = eqn.primitive.name
+        if name in ("dynamic_slice", "gather", "pad", "convert_element_type",
+                    "bitcast_convert_type") and plane(eqn.invars[0]):
+            found.append((name, eqn.invars[0].aval.shape))
+        if name == "scan":
+            skip = eqn.params["num_consts"] + eqn.params["num_carry"]
+            found += [("scan xs", v.aval.shape) for v in eqn.invars[skip:]
+                      if plane(v)]
+    return found
+
+
+def kernel_scale_operands(jaxpr, prefix: str) -> list:
+    """(dtype, rank) of what the kernel calls whose name starts with
+    ``prefix`` take as scale planes: their operands of rank >= 2 that are
+    neither packed codes (uint8) nor bf16 activations."""
+    return [(str(v.aval.dtype), len(v.aval.shape))
+            for eqn in _equations(jaxpr, into_kernels=False)
+            if eqn.primitive.name == "pallas_call"
+            and str(eqn.params["name"]).startswith(prefix)
+            for v in eqn.invars if len(v.aval.shape) >= 2
+            and str(v.aval.dtype) not in ("uint8", "bfloat16")]
+
+
 def kernel_calls(jaxpr, prefix: str) -> int:
     """The kernel calls in a program whose name starts with ``prefix``, a
     scan's body counted once."""
@@ -206,6 +257,87 @@ def test_a_lone_weight_is_the_kernel_over_a_stack_of_one(kernel_forced,
     want = np.asarray(qmatmul_reference(x, plane), np.float32)
     np.testing.assert_allclose(np.asarray(qmatmul(x, plane), np.float32),
                                want, rtol=5e-2, atol=5e-2)
+
+
+# -- the scale planes read where they lie (QTensor.scale_bits) -----------------
+#
+# A QTensor holds its fp16 scales as their uint16 bit patterns.  The expert
+# kernel takes a tile of them out of the whole stack by the codes' own index
+# map and converts it inside; the dense kernel streams the layer's plane as
+# float32, converted by XLA before the call.  Both are the same float32
+# numbers times the same codes in the same order, hence the same bits.
+
+_ODD_SCALES = np.array(        # fp16 patterns a conversion could get wrong
+    [0x0000, 0x8000, 0x0001, 0x8001, 0x03FF, 0x83FF, 0x0400, 0x8400,
+     0x3C00, 0xBC00, 0x7BFF, 0xFBFF, 0x2E66, 0xAE66, 0x0200, 0x8200],
+    np.uint16)
+
+# K tiles of 256 (one), 2048, 1792, 1536 and 1280 rows: 16 (a stack padded
+# to 512: two blocks of 8), 64, 56, 48 and 40 scale rows a tile
+_SCALE_CASES = [(k, odd) for k in (256, 4096, 3584, 3072, 5120)
+                for odd in (False, True)]
+
+
+@pytest.mark.parametrize("k,odd", _SCALE_CASES,
+                         ids=[f"{k}{'-odd' if o else ''}"
+                              for k, o in _SCALE_CASES])
+def test_the_expert_kernel_converts_scale_bits_as_xla_converts_planes(k, odd):
+    """An entry of a stack through the expert kernel (bit patterns read in
+    place, converted in the kernel) and the same entry through the dense
+    kernel (its plane sliced out and converted to float32 by XLA): one
+    result, bit for bit, subnormal, zero and negative scales included."""
+    from ipex_llm_tpu.ops.pallas import qmatmul as qmm
+    from ipex_llm_tpu.quantize.core import dequantize
+
+    n, entries = 128, 3
+    stack, _ = _stack_of("sym_int4", entries, k, n)
+    assert stack.scale_bits.dtype == jnp.uint16 and qmm.grouped_supported(
+        stack)
+    if odd:
+        stack = dataclasses.replace(stack, scale_bits=jnp.asarray(
+            np.resize(_ODD_SCALES, stack.scale_bits.shape)))
+    x = jnp.asarray(RNG.standard_normal((16, k)) * 0.5, jnp.bfloat16)
+    ids = jnp.array([2, 0], jnp.int32)
+    got = qmm.qmatmul_pallas_grouped(x, stack, ids, jnp.int32(2))
+    for tile, entry in enumerate([2, 0]):
+        plane = jax.tree_util.tree_map(lambda a, e=entry: a[e], stack)
+        want = qmatmul_pallas(x, plane, keep_f32=True)
+        np.testing.assert_array_equal(_bits(got[tile]), _bits(want))
+        plain = np.asarray(x.astype(jnp.float32) @ dequantize(plane))
+        scale = max(1.0, float(np.abs(plain).max()))
+        np.testing.assert_allclose(np.asarray(got[tile]) / scale,
+                                   plain / scale, atol=2e-2)
+
+
+def test_f16_bits_to_f32_is_astype_over_every_finite_pattern():
+    from ipex_llm_tpu.ops.pallas.qmatmul import _f16_bits_to_f32
+
+    bits = np.arange(1 << 16, dtype=np.uint16)
+    bits = bits[(bits & 0x7C00) != 0x7C00]           # not inf, not nan
+    got = np.asarray(_f16_bits_to_f32(jnp.asarray(bits, jnp.int32)))
+    want = bits.view(np.float16).astype(np.float32)
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+
+
+@pytest.mark.parametrize("per_tile", [False, True])
+def test_the_grouped_kernel_reads_a_group_out_of_the_whole_stack(per_tile):
+    """Entries named from ``first`` on in the whole ``[L x E, ...]`` stack
+    give the bits of the same entries in the group's planes cut out."""
+    from ipex_llm_tpu.ops.pallas import qmatmul as qmm
+
+    stack, _ = _stack_of("sym_int4", 6, 256, 128)
+    assert qmm.grouped_supported(stack)
+    x = jnp.asarray(RNG.standard_normal(((3, 16, 256) if per_tile
+                                         else (16, 256))) * 0.5, jnp.bfloat16)
+    ids, first, group = jnp.array([1, 0, 1]), 4, 2
+    whole = qmm.qmatmul_pallas_grouped(x, stack, ids, jnp.int32(2),
+                                       first=jnp.int32(first))
+    cut = jax.tree_util.tree_map(lambda a: a[first: first + group], stack)
+    sliced = qmm.qmatmul_pallas_grouped(x, cut, ids, jnp.int32(2))
+    np.testing.assert_array_equal(_bits(whole[:2]), _bits(sliced[:2]))
+    want = qmatmul_pallas(x[1] if per_tile else x, jax.tree_util.tree_map(
+        lambda a: a[first], stack), keep_f32=True)
+    np.testing.assert_array_equal(_bits(whole[1]), _bits(want))
 
 
 def _attn_case(b=2, t=32, s=96, hq=4, hkv=2, d=64):

@@ -274,7 +274,7 @@ def test_iquant_roundtrip_vs_scalar_oracle():
     # iq2: [32 magnitude-bit bytes | 32 sign-bit bytes | 4 subscale bytes]
     qt = quantize(w, "gguf_iq2_xxs")
     raw = np.asarray(qt.data)           # [68, 4] (one block)
-    d = np.asarray(qt.scales, np.float32)[0]          # [4]
+    d = np.asarray(qt.scale_bits).view(np.float16)[0].astype(np.float32)
     want = np.asarray(dequantize(qt))
     for col in range(4):
         nibs = []
@@ -290,7 +290,7 @@ def test_iquant_roundtrip_vs_scalar_oracle():
     # iq1: [52 base-3 trit bytes | 4 subscale bytes]
     qt1 = quantize(w, "gguf_iq1_s")
     raw1 = np.asarray(qt1.data)         # [56, 4]
-    d1 = np.asarray(qt1.scales, np.float32)[0]
+    d1 = np.asarray(qt1.scale_bits).view(np.float16)[0].astype(np.float32)
     want1 = np.asarray(dequantize(qt1))
     for col in range(4):
         nibs = []
@@ -323,3 +323,160 @@ def test_iquant_imatrix_improves_weighted_error():
 
     assert werr(quantize(w, "gguf_iq2_xxs", imatrix=im)) <= \
         werr(quantize(w, "gguf_iq2_xxs")) * 1.001
+
+
+# -- fp16 scales held as their bit patterns (QTensor.scale_bits) ---------------
+
+def _planes(qtype, k=128, n=48, seed=3):
+    from ipex_llm_tpu.quantize import core
+
+    w = jnp.asarray(np.random.default_rng(seed).standard_normal((k, n)) * 0.1,
+                    jnp.float32)
+    info = resolve(qtype)
+    return core._quantize_jit(w, info.name, info.block_size)
+
+
+@pytest.mark.parametrize("given", ["device", "host", "bits", "traced"])
+def test_constructor_stores_fp16_scales_as_their_bits(given):
+    """The constructor's second argument is the fp16 plane (or its bits);
+    what is stored is uint16 with the same bytes, and ``scales`` gives the
+    fp16 numbers back, bit for bit."""
+    import jax
+
+    data, scales, _ = _planes("sym_int4")
+    assert scales.dtype == jnp.float16
+    want = np.asarray(scales).view(np.uint16)
+    arg = {"device": scales, "host": np.asarray(scales),
+           "bits": jnp.asarray(want), "traced": scales}[given]
+    make = lambda s: QTensor(data, s, None, "sym_int4", (128, 48), 32)  # noqa: E731
+    if given == "traced":
+        assert jax.eval_shape(make, arg).scale_bits.dtype == jnp.uint16
+        make = jax.jit(make)
+    qt = make(arg)
+    assert qt.scale_bits.dtype == jnp.uint16
+    assert qt.scale_bits.shape == scales.shape
+    np.testing.assert_array_equal(np.asarray(qt.scale_bits), want)
+    if given == "bits":
+        assert qt.scale_bits is arg              # taken as they are
+    # the accessor: the same bits, read as fp16 inside a program
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(lambda q: q.scales)(qt)).view(np.uint16), want)
+    # a tree round trip holds the bits, and nbytes counts them once
+    leaves, treedef = jax.tree_util.tree_flatten(qt)
+    assert [str(a.dtype) for a in leaves] == ["uint8", "uint16"]
+    again = jax.tree_util.tree_unflatten(treedef, leaves)
+    assert again.scale_bits is leaves[1]
+    assert qt.nbytes == data.size + 2 * scales.size
+
+
+def test_children_that_are_no_fp16_arrays_pass_untouched():
+    from jax.sharding import PartitionSpec as P
+
+    spec, f32 = P(None, "tp"), np.ones((4, 48), np.float32)
+    for child in (None, spec, f32):
+        qt = QTensor(child, child, child, "sym_int4", (128, 48), 32)
+        assert qt.scale_bits is child and qt.scales is child
+    # an asymmetric format's zero plane stays the fp16 plane it was
+    data, scales, zeros = _planes("asym_int4")
+    qt = QTensor(data, scales, zeros, "asym_int4", (128, 48), 32)
+    assert qt.zeros is zeros and qt.scale_bits.dtype == jnp.uint16
+
+
+@pytest.mark.parametrize("qtype", ["sym_int4", "asym_int4", "nf4", "sym_int8",
+                                   "fp8_e4m3"])
+def test_dequantize_reads_the_numbers_the_planes_held(qtype):
+    """``dequantize`` over the stored bits is the codec over the fp16
+    plane: computed here from the raw planes, without a QTensor."""
+    from ipex_llm_tpu.quantize import core, numerics
+
+    data, scales, zeros = _planes(qtype)
+    info = resolve(qtype)
+    bs, bits = info.block_size, int(info.bits)
+    qt = QTensor(data, scales, zeros, info.name, (128, 48), bs)
+    s = np.asarray(scales).astype(np.float32)[:, None, :]
+    if info.kind == "minifloat":
+        vals = np.asarray(numerics.fp8_from_codes(data, "e4m3"))
+    else:
+        codes = np.asarray(core._unpack_codes(data, bs, bits), np.float32)
+        vals = {"sym_int4": lambda: codes - 8, "sym_int8": lambda: codes - 128,
+                "asym_int4": lambda: codes,
+                "nf4": lambda: numerics.NF4_TABLE[codes.astype(int)]}[qtype]()
+    want = vals.reshape(-1, bs, 48).astype(np.float32) * s
+    if zeros is not None:
+        want = want + np.asarray(zeros).astype(np.float32)[:, None, :]
+    np.testing.assert_array_equal(
+        np.asarray(dequantize(qt)).view(np.uint32),
+        want.reshape(128, 48).view(np.uint32))
+
+
+def test_a_saved_low_bit_file_holds_fp16_scales_byte_for_byte(tmp_path):
+    """On disk the scales stay fp16: the file is the one written from the
+    raw fp16 planes (the parent's), and loading it gives the bits back."""
+    import jax
+    from safetensors.numpy import save_file
+
+    from ipex_llm_tpu.models import serialize
+
+    params = {"layers": {}, "final_norm": jnp.ones((48,), jnp.float32)}
+    tensors = {"final_norm": np.ones((48,), np.float32)}
+    for seed, (name, qtype) in enumerate(
+            [("layers.qkv", "sym_int4"), ("layers.down", "asym_int4"),
+             ("lm_head", "nf4")]):
+        data, scales, zeros = _planes(qtype, seed=seed)
+        qt = QTensor(data, scales, zeros, qtype, (128, 48), 32)
+        if name.startswith("layers."):
+            params["layers"][name.split(".")[1]] = qt
+        else:
+            params[name] = qt
+        tensors[name + ".q.data"] = np.asarray(data)
+        tensors[name + ".q.scales"] = np.asarray(scales)
+        if zeros is not None:
+            tensors[name + ".q.zeros"] = np.asarray(zeros)
+    serialize.save_low_bit(str(tmp_path / "lb"), params, {}, "sym_int4")
+    save_file(tensors, str(tmp_path / "parent.safetensors"))
+    with open(tmp_path / "lb" / serialize.WEIGHTS_NAME, "rb") as f:
+        saved = f.read()
+    with open(tmp_path / "parent.safetensors", "rb") as f:
+        assert saved == f.read()
+    loaded, _, _ = serialize.load_low_bit(str(tmp_path / "lb"))
+    for a, b in zip(jax.tree_util.tree_leaves(loaded),
+                    jax.tree_util.tree_leaves(params)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_view_of_a_concrete_stack_copies_only_what_is_indexed():
+    """Outside a jit ``scales`` of device bits is an ``F16View``: indexing
+    slices the BITS and stays a view (a reference that walks a stack a layer
+    at a time never copies the stack), and a jitted function takes it as
+    its bits and bitcasts inside."""
+    import jax
+
+    from ipex_llm_tpu.quantize.core import F16View
+
+    data, scales, _ = _planes("sym_int4")
+    stack = QTensor(jnp.stack([data] * 3), jnp.stack([scales, -scales, scales]),
+                    None, "sym_int4", (128, 48), 32)
+    view = stack.scales
+    assert isinstance(view, F16View) and view.bits is stack.scale_bits
+    assert view.shape == (3, 4, 48)
+    want = np.stack([np.asarray(scales), -np.asarray(scales),
+                     np.asarray(scales)])
+    one = view[1]
+    assert isinstance(one, F16View) and one.shape == (4, 48)
+    np.testing.assert_array_equal(
+        np.asarray(view[:, :, 8:16].bits).view(np.float16), want[:, :, 8:16])
+    # into a jit, inside any tree: the parameter is the bits, the numbers
+    # are a bitcast in the program
+    fn = lambda tree: tree["w"][1].astype(jnp.float32) * 2  # noqa: E731
+    closed = jax.make_jaxpr(fn)({"w": (stack.data, view)})
+    assert [str(v.aval.dtype) for v in closed.jaxpr.invars] == ["uint8",
+                                                                 "uint16"]
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(fn)({"w": (stack.data, one)})),
+        want[1].astype(np.float32) * 2)
+    # host bits are viewed in place, traced bits bitcast
+    host = QTensor(np.asarray(data), np.asarray(scales), None, "sym_int4",
+                   (128, 48), 32)
+    assert isinstance(host.scales, np.ndarray)
+    assert np.shares_memory(host.scales, host.scale_bits)

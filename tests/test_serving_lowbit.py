@@ -119,8 +119,8 @@ def test_repack_deterministic_and_packed_tree_passes_through(cfg_params):
             np.asarray(e1.params["layers"][key].data),
             np.asarray(e2.params["layers"][key].data))
         np.testing.assert_array_equal(
-            np.asarray(e1.params["layers"][key].scales, np.float32),
-            np.asarray(e2.params["layers"][key].scales, np.float32))
+            np.asarray(e1.params["layers"][key].scale_bits),
+            np.asarray(e2.params["layers"][key].scale_bits))
     # pass-through: repacking e1's already-int4 tree (even at a DIFFERENT
     # requested width) returns the identical leaf objects
     repacked = requantize_params(e1.params, "nf4")
@@ -361,6 +361,72 @@ def test_a_row_in_a_wave_matches_the_row_alone_on_the_kernel(kernel_forced,
     for key, closed in seen.items():
         assert "qmatmul" in str(closed), key
         assert copies_of_packed_planes(closed.jaxpr) == [], key
+
+
+@pytest.mark.parametrize("family", ["dense", "held-expert"])
+def test_the_tick_reads_its_scale_planes_where_they_lie(kernel_forced,
+                                                        monkeypatch, family):
+    """The lowered ``_ragged_tick`` of a stacked dense model and of one with
+    held experts, kernel forced: every scale plane in the tree is uint16 bit
+    patterns; the EXPERT kernel takes the whole stack of them (rank 3) and
+    nothing stands between the program's parameter and that call (no slice,
+    gather, pad or conversion of an expert stack's planes); the DENSE kernel
+    takes the layer's float32 plane (PERF.md section 6, PR 47, says why)."""
+    import jax
+
+    from ipex_llm_tpu.serving import engine as engine_mod
+    from tests.test_pallas import kernel_scale_operands, scale_plane_passes
+
+    if family == "dense":
+        cfg, params = _tiled_cfg_params()
+        ec = EngineConfig(weight_qtype="sym_int4", **EC)
+    else:
+        from ipex_llm_tpu.models.families import FAMILIES
+        from tests.test_nemotron_h import gen, tiny_hf
+
+        hf = tiny_hf()
+        cfg = FAMILIES["nemotron_h"].to_config(hf)
+        params = gen.param_tree(cfg, hf, "sym_int4", 7)
+        ec = EngineConfig(max_rows=2, max_seq_len=256, page_size=16,
+                          prefill_bucket=16)
+    real, seen = engine_mod._ragged_tick, {}
+
+    def recorded(*args, **kw):
+        key = (kw.get("horizon"), kw.get("with_decode"),
+               kw.get("prefill") is None)
+        if key not in seen:
+            seen[key] = real.trace(*args, **kw).jaxpr
+        return real(*args, **kw)
+
+    monkeypatch.setattr(engine_mod, "_ragged_tick", recorded)
+    eng = ServingEngine(cfg, params, ec).start()
+    try:
+        req = eng.submit(Request(
+            prompt_ids=list(RNG.integers(0, cfg.vocab_size, 20)),
+            max_new_tokens=3))
+        assert len(list(stream_tokens(req, timeout=300))) == 3
+    finally:
+        eng.stop()
+    held = [q for q in jax.tree_util.tree_leaves(
+        eng.params, is_leaf=lambda a: isinstance(a, QTensor))
+        if isinstance(q, QTensor) and q.scale_bits is not None]
+    assert held and all(q.scale_bits.dtype == jnp.uint16 for q in held)
+    # an expert stack's planes: [L, E, K/32, N] in the tree, [L x E, ...] at
+    # the kernel
+    expert = {shape for q in held if q.data.ndim == 4
+              for shape in (q.scale_bits.shape, (
+                  q.scale_bits.shape[0] * q.scale_bits.shape[1],
+                  *q.scale_bits.shape[2:]))}
+    assert bool(expert) == (family == "held-expert")
+    assert any(k[2] for k in seen) and any(not k[2] for k in seen)
+    for key, closed in seen.items():
+        assert set(kernel_scale_operands(closed.jaxpr, "qmatmul")) - {
+            ("uint16", 3)} == {("float32", 2)}, key
+        if expert:
+            assert set(kernel_scale_operands(
+                closed.jaxpr, "qmatmul_expert")) == {("uint16", 3)}, key
+        assert [p for p in scale_plane_passes(closed.jaxpr)
+                if p[1] in expert] == [], key
 
 
 def test_fused_h8_bit_identical_to_h1_int4(cfg_params):

@@ -117,7 +117,7 @@ def test_merge_lora_matches_attached(cfg_params_int4):
     var = 0.0
     for s in slots:
         mq = merged_params["layers"][s]
-        d = mq.scales.astype(jnp.float32)
+        d = jnp.asarray(np.asarray(mq.scale_bits).view(np.float16), jnp.float32)
         g = grads[s]
         n_l, n_in, n_out = g.shape
         pad = (-n_in) % mq.block_size

@@ -343,7 +343,9 @@ def test_the_two_shares_of_a_layer_add_up_to_the_uncut_layer(tiny):
     params = weights.generator(FAMILY).param_tree(cfg, hf, "sym_int4", 5)
     tree, layer, t = params["layers"], 1, 7
     x = _streams(cfg, t=t, seed=9, dtype=jnp.float32)
-    lp = ref._layer_tree(tree, layer)
+    # through a jit, as the reference hands it on: the scale views cross as
+    # their bits and come out as fp16 planes
+    lp = jax.jit(lambda t_: t_)(ref._layer_tree(tree, layer))
     st = ref.settings(hf, params)
     connect = ref._hyper_fn(*st["hyper"], None)
     with jax.default_matmul_precision("highest"):
