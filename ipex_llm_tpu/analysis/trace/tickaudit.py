@@ -47,7 +47,11 @@ def mixed_tick_spec() -> TickSpec:
     return TickSpec(
         name="mixed",
         module="ipex_llm_tpu.serving.engine",
-        entries=("_mixed_step", "_horizon_step"),
+        # a steady tick's dispatch lives in ``_launch``, reached from
+        # ``_horizon_step`` for the tick's own program and for the ONE
+        # queued behind it (``_queue_next``): the same jitted entry
+        entries=("_mixed_step", "_horizon_step", "_launch_planned",
+                 "_launch", "_queue_next"),
         programs=("_ragged_tick",),
         max_dispatches=1,
     )

@@ -17,7 +17,17 @@ pipeline_parallel.py:482-928 which rely on vLLM's paged attention):
   forward;
 - **one program per tick** (``_ragged_tick``): it decodes ALL rows in one
   jitted call; inactive rows are masked, so join/leave never recompiles;
-  per-row temperature/top-p ride as traced vectors.
+  per-row temperature/top-p ride as traced vectors;
+- **one program in flight behind the running one**: in a run of steady
+  ticks the next tick's program is dispatched BEFORE this one's results
+  are read (``_queue_next``; its inputs are the device arrays this one
+  returned), so the host's turn — the wait's tail, drain, commit, record,
+  plan, checkpoint, dispatch — runs beside the device.  A tick still
+  reads and commits ONE program, and takes the serial order whenever the
+  host cannot tell, before reading, that the next tick is a plain steady
+  one (``_ahead_blocked``: an arrival, a dry pool, a finish, a rollback,
+  a recurrent state, speculation, a fused horizon, a mesh).  A rollback
+  or a stop throws a queued program away unread (``_discard_ahead``).
 
 The engine thread owns the device; asyncio handlers talk to it through
 queues (reference fastapi server uses the same queue pattern,
@@ -357,6 +367,27 @@ class Request:
 
     def abort(self):
         self.cancelled = True
+
+
+@dataclass
+class _Queued:
+    """A steady tick's program, dispatched and not yet read: what reading
+    it takes, and what throwing it away takes."""
+
+    sent: tuple               # what ``_sync_window`` takes
+    active: np.ndarray        # the rows it decodes, as the host saw them
+    bind: list                # row -> request at its dispatch
+    key: Any                  # the engine's PRNG key it was handed
+    t0_w: float               # wall clock at its dispatch (request spans)
+    horizon: int              # steps it runs (the plan's, pool-clamped)
+    planned: int              # the plan's horizon
+    fused_spec: bool
+    family: str               # perfwatch: the tick that reads it is of
+    point: dict               # this family, at this grid point
+    attrs: dict               # its window's ``engine/dispatch`` stats
+    ahead: bool = False       # queued before its predecessor was read
+    stray: int = 0            # rows the host retired while it was queued
+    counts: dict = field(default_factory=dict)   # work counters, its reader's
 
 
 class PageAllocator:
@@ -1151,10 +1182,13 @@ class ServingEngine:
         # same cache object: per-ROW recurrent slots.  Two buffers: the
         # committed one in ``cache.state`` and a spare the next tick writes
         # (``_state_call``), so the state a tick started from stays whole
-        # until the tick commits.  What recurrent state cannot do yet is
-        # refused here by name (derived from the model, no config field)
+        # until the tick commits.  ``_state_held``: the states the programs
+        # in flight started from, oldest first (one in the serial order);
+        # ``_state_free``: the spares.  What recurrent state cannot do yet
+        # is refused here by name (derived from the model, no config field)
         self._recurrent = cfg.ssm_layers > 0
-        self._state_spare = self._state_prev = None
+        self._state_free: list = []
+        self._state_held: list = []
         self._state_counters = state_counters(cfg)
         self._stats_seen = np.zeros((len(self._state_counters),), np.uint32)
         if self._recurrent:
@@ -1165,7 +1199,7 @@ class ServingEngine:
         cache = with_recurrent_state(cache, cfg, r)
         self._stateful = cache.state is not None
         if self._stateful:
-            self._state_spare = recurrent_state(cfg, r)
+            self._state_free.append(recurrent_state(cfg, r))
         # multi-chip serving: on a PURE-tp mesh whose shapes divide, the
         # engine takes the MANUAL tick — the whole fused tick inside one
         # fully-manual shard_map region (parallel/manual.py), per-shard
@@ -1285,6 +1319,12 @@ class ServingEngine:
         self._masked: set[str] = set()
         self._tick_arrivals: list[Request] = []
         self._retries = 0
+        # a bisection probe is running: it re-runs the tick in the serial
+        # order, as a retry does
+        self._serial = False
+        # the ONE steady tick program queued behind the one being awaited
+        # (``_queue_next``), read by the next tick; None in the serial order
+        self._ahead: _Queued | None = None
         # device dispatches issued by the current tick (flight-recorder
         # bookkeeping; the JP106 audit pins the fused tick's at 1)
         self._tick_dispatches = 0
@@ -1449,7 +1489,18 @@ class ServingEngine:
                         # kv-pool pressure: allocation failures that forced
                         # a clamp/fallback (paired with the allocator's
                         # prefix_evictions in /health's kv block)
-                        "alloc_fail_clamps": 0}
+                        "alloc_fail_clamps": 0,
+                        # one program in flight behind the running one:
+                        # committed ticks whose program was queued before
+                        # its predecessor was read, queued programs thrown
+                        # away unread (a rollback, a stop), row-steps one
+                        # spent on rows the host had retired meanwhile, and
+                        # why a steady tick's successor was NOT queued
+                        # (``_ahead_blocked``; a dry pool: ``pages``)
+                        "ahead_ticks_total": 0, "ahead_discarded_total": 0,
+                        "ahead_stray_steps_total": 0,
+                        **{f"ahead_blocked_{why}_total": 0 for why in
+                           ("queue", "pages", "dirty", "state", "plan")}}
         # tick planner (serving/planner.py): the decision function that
         # owns the tick's shape.  Constructed last so the initial plan —
         # what a pre-loop _step_once caller runs under — sees a fully
@@ -1491,25 +1542,34 @@ class ServingEngine:
 
     def _state_call(self) -> tuple:
         """(the cache to hand the tick, its ``state_src``) for a model
-        with recurrent state: the committed state goes in as the read-only
-        source and the spare buffer, in the donated cache, is written.
-        ``_state_done`` rebinds after the call."""
+        with recurrent state: the state the tick starts from goes in as the
+        read-only source and a spare buffer, in the donated cache, is
+        written.  ``_state_done`` books the source after the call."""
         if not self._stateful:
             return self.cache, None
-        src = self.cache.state
-        if (self._state_spare is None
-                or self._state_spare["stats"].is_deleted()):
-            # a dispatch that raised took the spare with it
-            self._state_spare = recurrent_state(self.cfg, self.ec.max_rows)
-        return replace(self.cache, state=self._state_spare), src
+        # none left: a dispatch that raised took the spare with it, or (a
+        # model that carries the counters alone) a second program is in
+        # flight for the first time
+        spare = (self._state_free.pop() if self._state_free
+                 else recurrent_state(self.cfg, self.ec.max_rows))
+        return replace(self.cache, state=spare), self.cache.state
 
     def _state_done(self, src):
         """After a hybrid tick's dispatch: the written buffer is in
-        ``self.cache`` already; the one the tick started from is kept for
-        a rollback and becomes the spare at commit."""
+        ``self.cache`` already; the one the program started from is held
+        for a rollback until the tick that reads the program commits, and
+        is a spare from then on."""
         if src is not None:
-            self._state_spare = None      # donated: gone
-            self._state_prev = src
+            self._state_held.append(src)
+
+    def _state_rewind(self):
+        """Every program in flight is given up (a rollback, a discard):
+        the state the oldest of them started from is the committed one
+        again, and the newest buffer written is the spare."""
+        if self._state_held:
+            self._state_free.append(self.cache.state)
+            self.cache = replace(self.cache, state=self._state_held[0])
+            self._state_held = []
 
     def _count_state(self, n_exec) -> int:
         """Steps executed from the tick's n_exec slot; a hybrid tick's
@@ -1729,7 +1789,8 @@ class ServingEngine:
 
     def _count_tick_work(self, active, decode: bool = True, chunks=(),
                          base=None, n_valid=None,
-                         prefill_table: int = 0) -> dict:
+                         prefill_table: int = 0, ahead: int = 0,
+                         into: dict | None = None) -> dict:
         """What the tick about to be dispatched asks, from lengths the host
         holds: of a model's hyper-connections in tokens
         (:meth:`_count_mixed_tokens`), of an indexer and its sparse walk
@@ -1740,43 +1801,52 @@ class ServingEngine:
         ``attn_pages_table`` the rows of each stage times the table width
         handed to it (``prefill_table`` the prefill stage's).  A lower bound
         on what is read: a row that joins the decode stage inside the tick
-        and the later steps of a horizon are left out.  Added to the
-        ``attn_pages_*_total`` counters (rollback-covered with the metrics)
-        and returned as the ``engine/dispatch`` span's stats."""
+        and the later steps of a horizon are left out.  ``ahead``: steps
+        the decode rows have taken on the device that the host has not
+        read (a tick queued behind another).  Added to the
+        ``attn_pages_*_total`` counters (rollback-covered with the metrics;
+        of a program queued behind another to ``into``, which the tick
+        that reads it adds) and returned as the ``engine/dispatch`` span's
+        stats."""
+        m = self.metrics if into is None else into
         ps = self.ec.page_size
         live, table = 0, prefill_table
+        lens = self.row_lens[active].astype(np.int64) + 1 + ahead
         if decode:
-            live = int((-(-(self.row_lens[active] + 1) // ps)).sum())
+            live = int((-(-lens // ps)).sum())
             table += len(self.rows) * self.ec.max_pages
         live += sum(-(-(int(base[i]) + int(n_valid[i])) // ps)
                     for i, _, _ in chunks)
-        self.metrics["attn_pages_live_total"] += live
-        self.metrics["attn_pages_table_total"] += table
+        m["attn_pages_live_total"] = m.get("attn_pages_live_total", 0) + live
+        m["attn_pages_table_total"] = (m.get("attn_pages_table_total", 0)
+                                       + table)
         out = {"attn_pages_live": live, "attn_pages_table": table}
         if self.cfg.hc_mult > 1:
             out.update(self._count_mixed_tokens(active, decode, chunks,
-                                                n_valid))
+                                                n_valid, m))
         if self.cfg.index_topk:
-            out.update(self._count_selection(active, decode, chunks, base,
+            out.update(self._count_selection(lens, decode, chunks, base,
                                              n_valid))
         return out
 
-    def _count_mixed_tokens(self, active, decode, chunks, n_valid) -> dict:
+    def _count_mixed_tokens(self, active, decode, chunks, n_valid,
+                            m: dict) -> dict:
         """What the same tick asks of a model's HYPER-CONNECTIONS
         (ops/hyper.py), the same lower bound: ``mhc_tokens`` the tokens it
         mixes, a decode row one and a chunk its valid ones, in each of a
-        layer's two sublayers.  Added to ``mhc_tokens_mixed_total``
-        (rollback-covered with the metrics)."""
+        layer's two sublayers.  Added to ``mhc_tokens_mixed_total`` of
+        ``m`` (the metrics, rollback-covered)."""
         tokens = ((int(np.count_nonzero(active)) if decode else 0)
                   + sum(int(n_valid[i]) for i, _, _ in chunks))
         mixed = tokens * 2 * self.cfg.num_layers
-        self.metrics["mhc_tokens_mixed_total"] = (
-            self.metrics.get("mhc_tokens_mixed_total", 0) + mixed)
+        m["mhc_tokens_mixed_total"] = (
+            m.get("mhc_tokens_mixed_total", 0) + mixed)
         return {"mhc_tokens": mixed}
 
-    def _count_selection(self, active, decode, chunks, base, n_valid) -> dict:
+    def _count_selection(self, lens, decode, chunks, base, n_valid) -> dict:
         """What the same tick asks of a model's INDEXER and of its sparse
-        walk, from the same lengths, the same lower bound: a query at slot
+        walk, from the same lengths (``lens``: the decode rows', their
+        step counted), the same lower bound: a query at slot
         ``p`` has ``p + 1`` index keys scored in every layer whose indexer
         is full (``dsa_keys_scored``) and attends ``min(p + 1, index_topk)``
         cached tokens in every layer (``dsa_tokens_selected``; of them
@@ -1786,7 +1856,6 @@ class ServingEngine:
         top = self.cfg.index_topk
         seen = sel = dec = 0
         if decode:
-            lens = self.row_lens[active].astype(np.int64) + 1
             seen += int(lens.sum())
             dec = int(np.minimum(lens, top).sum())
         for i, _, _ in chunks:
@@ -1799,7 +1868,7 @@ class ServingEngine:
                 "dsa_tokens_selected": (sel + dec) * layers,
                 "dsa_decode_selected": dec * layers}
 
-    def _count_sample_stages(self, *stages):
+    def _count_sample_stages(self, *stages, into: dict | None = None):
         """What the tick about to be dispatched asks of the sampler
         (ops/sampling.py decides the same on the device, from the same row
         parameters): each of ``stages`` is the engine rows live in one
@@ -1808,17 +1877,20 @@ class ServingEngine:
         a row set ``top_p < 1`` or ``top_k > 0``.  An upper bound by the
         rows a first token ends before they decode, as
         ``_count_tick_work`` is a lower one; rollback-covered with the
-        metrics."""
-        m = self.metrics
+        metrics (``into``: as ``_count_tick_work``'s)."""
+        m = self.metrics if into is None else into
         for rows in stages:
             if rows is None:
                 continue
             rows = np.asarray(rows, np.int64)
             draws = rows[self.temps[rows] > 0]
-            m["sample_stages_total"] += 1
-            m["sample_draw_stages_total"] += int(draws.size > 0)
-            m["sample_sort_stages_total"] += int(
-                ((self.top_ps[draws] < 1) | (self.top_ks[draws] > 0)).any())
+            for name, n in (
+                    ("sample_stages_total", 1),
+                    ("sample_draw_stages_total", int(draws.size > 0)),
+                    ("sample_sort_stages_total", int(
+                        ((self.top_ps[draws] < 1)
+                         | (self.top_ks[draws] > 0)).any()))):
+                m[name] = m.get(name, 0) + n
 
     def _perf_dispatch(self, family: str, point: dict | None = None,
                        tick: bool = True, **attrs):
@@ -1957,7 +2029,7 @@ class ServingEngine:
         working = (tokens or admitted or d("mixed_prefill_tokens")
                    or pend["retries"] or pend.get("fault_sites")
                    or d("errors_isolated") or d("timeouts")
-                   or self._tick_dispatches)
+                   or self._tick_dispatches or d("host_syncs"))
         if not working:
             if self.perf is not None:   # discard the idle tick's scratch
                 self.perf.tick_finish(self._tick_dispatches, working=False)
@@ -2161,10 +2233,13 @@ class ServingEngine:
                       dict(self.alloc._page_key),
                       self.alloc.prefix_evictions),
             "key": self.key,
-            # recurrent state is covered BY REFERENCE: the tick reads this
-            # buffer and writes the spare one (_state_call), so restoring
-            # the reference is the whole rollback
-            "state": self.cache.state,
+            # the program queued behind the last one read, by reference:
+            # a rollback throws it away unread (``_discard_ahead``)
+            "ahead": self._ahead,
+            # (recurrent state is covered BY REFERENCE, outside the
+            # snapshot: a tick reads one buffer and writes a spare one,
+            # ``_state_call``, and ``_state_rewind`` puts the reference
+            # back)
             "stats_seen": self._stats_seen.copy(),
             "metrics": dict(self.metrics),
             "ttfts": list(self._ttfts),
@@ -2217,11 +2292,7 @@ class ServingEngine:
             self.pagestore.restore(snap["pagestore"])
         self.key = snap["key"]
         if self._stateful:
-            if self._state_prev is not None:
-                # the doomed tick wrote the other buffer: it is the spare
-                self._state_spare = self.cache.state
-                self._state_prev = None
-            self.cache = replace(self.cache, state=snap["state"])
+            self._state_rewind()    # the doomed programs wrote the spares
             self._stats_seen = snap["stats_seen"].copy()
         # the rolling TTFT window reverts too: a first token recorded by
         # the doomed tick (or a bisection probe) was never emitted, and the
@@ -2262,6 +2333,10 @@ class ServingEngine:
             r.first_token_s = 0.0
             self._pending.append(r)
         self._tick_arrivals = []
+        # whatever is in flight is thrown away unread: the program this
+        # tick found queued (its key comes back) and any it queued itself
+        self._ahead = snap["ahead"]
+        self._discard_ahead()
         # device copies are now ahead of the restored host state: the
         # next dispatch publishes the row state and the table anew
         self._dev = None
@@ -2274,9 +2349,9 @@ class ServingEngine:
         staged, self._staging = self._staging, None
         staged_spans, self._span_staging = self._span_staging, []
         self._tick_arrivals = []
-        if self._state_prev is not None:
-            # the state this tick started from is the next tick's spare
-            self._state_spare, self._state_prev = self._state_prev, None
+        if self._state_held:
+            # the state the program this tick read started from is a spare
+            self._state_free.append(self._state_held.pop(0))
         # the stamp a consumer measures the way to its socket from: one
         # clock read a tick, one record a request (a horizon's block
         # shares it), there before the items it belongs to
@@ -2406,6 +2481,7 @@ class ServingEngine:
         self._span_staging = []     # probes mute spans like emissions
         self._tick_arrivals = []
         self._masked = set(masked_ids)
+        self._serial = True
         self._dirty = True   # the active mask changed vs the device copy
         try:
             self._step_once()
@@ -2415,6 +2491,7 @@ class ServingEngine:
         finally:
             self._phase_to(None)    # a probe's spans show; it counts nothing
             self._masked = set()
+            self._serial = False
             self._rollback(snap)
 
     def _bisect_culprit(self):
@@ -2954,6 +3031,16 @@ class ServingEngine:
             self._dev = dev
         return self._dev
 
+    def _publish_tables(self):
+        """The block table alone, with a program in flight (a page's edge
+        inside a run of steady ticks): the epoch's buffer and its unpack as
+        they are, of which the table is kept.  The row state the buffer
+        carries is a step behind the device's and is dropped; ``_dev``
+        stays what the program in flight returned."""
+        buf, layout, _ = self._upload_epoch()
+        self.cache = replace(self.cache,
+                             tables=unpack_rows(buf, layout)["tables"])
+
     # -- engine loop --------------------------------------------------------
 
     def _free_row(self) -> int | None:
@@ -3163,6 +3250,12 @@ class ServingEngine:
 
     def _finish(self, row: int, reason: str):
         req = self.rows[row]
+        q = self._ahead
+        if (q is not None and q.active[row] and q.bind[row] is req
+                and reason not in ("stop", "length")):
+            # the host retires a row that the queued program still steps
+            # (the device itself retires one on EOS or on its budget)
+            q.stray += 1
         # first writer wins: the HTTP handler may have already recorded
         # 'stop' (stop-string truncation) before asking for the abort —
         # overwriting it here would misreport the finish reason
@@ -3196,6 +3289,7 @@ class ServingEngine:
         self._span_staging = []
         self._tick_arrivals = []
         self._masked = set()
+        self._discard_ahead()   # every row it steps ends here
         self.flight.dump("fail_all",
                          error=f"{type(exc).__name__}: {exc}",
                          **{f"{k}_pending": v for k, v
@@ -3298,7 +3392,10 @@ class ServingEngine:
 
     def _loop(self):
         while not self._stop.is_set():
-            self._drain_host_ops()
+            if self._ahead is None:
+                # over committed state on the device too: with a program
+                # queued the next tick only reads it (``_ahead_blocked``)
+                self._drain_host_ops()
             try:
                 committed = self._tick()
                 # a committed tick means the engine recovered: clear the
@@ -3308,6 +3405,9 @@ class ServingEngine:
                     self.metrics["last_error"] = ""
             except Exception as exc:  # recovery machinery itself failed
                 self._fail_all(exc)
+        # a program still queued is thrown away unread: its tokens were
+        # never staged, so no stream sees them now or twice
+        self._discard_ahead()
         # shutdown drain: host ops enqueued after the loop's last drain
         # must not leave their callers blocked until timeout — fail them
         # with a clean "engine stopped" instead
@@ -3330,13 +3430,19 @@ class ServingEngine:
             self._drain_inbox()
             self._expire_deadlines()
             self.metrics["queue_depth"] = self.queue_depth
-            self._admit()
+            if self._ahead is None:
+                # a row is not handed on while a queued program may still
+                # step it: that program is read first, admission follows
+                self._admit()
             for i, req in enumerate(self.rows):  # drop disconnected clients
                 if req is not None and req.cancelled:
                     self._finish(i, "abort")
         # every step function below builds its inputs (prepare), makes its
         # one dispatch, and walks what came back (drain)
         self._phase_to("prepare")
+        if self._ahead is not None:
+            self._horizon_step(None)
+            return
         if self._prefilling:
             self._mixed_step()
             return
@@ -3658,10 +3764,67 @@ class ServingEngine:
             self.metrics["tokens"] / max(self.metrics["host_syncs"], 1), 2)
         self._publish_ahead(1)
 
-    def _horizon_step(self, active: np.ndarray):
+    def _horizon_step(self, active: np.ndarray | None):
         """Fused decode: up to ``decode_horizon`` decode+sample steps in one
         device program, drained token-by-token through ``_emit`` so SSE
-        streaming and finish semantics are exactly the H=1 path's."""
+        streaming and finish semantics are exactly the H=1 path's.
+
+        One program is READ a tick: the one an earlier tick queued
+        (``_ahead``; ``active`` is not looked at then), else the one
+        dispatched here.  Before it is read the tick after it is queued
+        behind it where the host can tell that it is a plain steady tick
+        with everything in place (``_queue_next``), so the wait, the drain
+        and the rest of the host's turn run beside the device."""
+        came = self._ahead
+        q = came if came is not None else self._launch_planned(active)
+        if q is None:
+            return
+        self._ahead = self._queue_next()
+        if came is not None and self.perf is not None:
+            self.perf.awaited(q.family, q.point)
+        self._phase_to("drain")
+        # h tokens per host round trip; n_exec < h only if every row died
+        # early; a speculating tick's accepted counts (for the drain walk)
+        # and draft economics ride the same buffer
+        got = self._sync_window(*q.sent)
+        if self._ahead is not None and self.perf is not None:
+            self.perf.running(self._ahead.attrs)
+        tok_block, lp_block = got["tok"], got["lp"]
+        executed = self._count_state(got["n_exec"])
+        if self.perf is not None:
+            # the MFU join's loop multiplier: XLA's cost analysis counts
+            # the horizon body once, the tick executed it `executed` times
+            self.perf.note_executed(executed)
+        self.metrics["steps"] += executed
+        self.metrics["decode_horizon_effective"] = q.horizon
+        self.metrics["pages_in_use"] = self.alloc.pages_in_use
+        if q.ahead:
+            self.metrics["ahead_ticks_total"] += 1
+            self.metrics["ahead_stray_steps_total"] += q.stray
+            for name, n in q.counts.items():
+                self.metrics[name] = self.metrics.get(name, 0) + n
+        # the rows the program decoded that are still its requests': one
+        # the host retired while the program was queued is dropped here
+        live = q.active & np.array([r is not None and r is b for r, b
+                                    in zip(self.rows, q.bind)])
+        parts = self._decode_parts(live)
+        if q.fused_spec:
+            take_np = got["take"]
+            self._spec_metrics(take_np, got["prop"], got["acc"], executed)
+            self._drain_spec_block(tok_block, lp_block, take_np,
+                                   live, executed)
+        else:
+            take_np = None
+            self._drain_block(tok_block, lp_block, live, executed)
+        self._trace_decode(parts, q.t0_w, executed, take_np)
+        self.metrics["tokens_per_sync"] = round(
+            self.metrics["tokens"] / self.metrics["host_syncs"], 2)
+        self._publish_ahead(q.planned)
+
+    def _launch_planned(self, active: np.ndarray) -> "_Queued | None":
+        """The tick's own program, in the serial order: back the pages its
+        horizon writes, clamp to what the pool gives, dispatch.  None
+        where no row is left to decode."""
         # the horizon target comes from the tick's plan (serving/
         # planner.py), which owns the old inline heuristics: the static
         # planner folds the admission-wave clamp (streams joining => H=1,
@@ -3673,8 +3836,7 @@ class ServingEngine:
         # into the inbox AFTER planning waits out at most one
         # already-planned horizon (streams stay bit-identical either way
         # — the H8==H1 contract).
-        plan = self._plan
-        H = plan.horizon if plan is not None else self.ec.decode_horizon
+        H = self._planned_horizon()
         # pre-allocate pages for the whole horizon; a tight pool shortens
         # the horizon for the step (power-of-two buckets bound recompiles)
         # instead of truncating requests the plain engine could still
@@ -3688,6 +3850,7 @@ class ServingEngine:
         # traced mask) before the whole tick's horizon is clamped on its
         # account.
         h = H
+        plan = self._plan
         fused_spec = (self.ec.spec_k > 0
                       and (plan.spec_on if plan is not None else True))
         if self.ec.spec_k > 0 and not fused_spec:
@@ -3725,7 +3888,7 @@ class ServingEngine:
             else:
                 h = min(h, backed)
         if not active.any():
-            return
+            return None
         if h < H:
             h = 1 << (h.bit_length() - 1)      # largest power of two <= h
             self.metrics["horizon_clamped"] = (
@@ -3734,20 +3897,41 @@ class ServingEngine:
         self._fault_point("decode-dispatch",
                           rows=[i for i in range(len(self.rows))
                                 if active[i]])
+        return self._launch(active, h, H, spec_ks)
+
+    def _planned_horizon(self) -> int:
+        plan = self._plan
+        return plan.horizon if plan is not None else self.ec.decode_horizon
+
+    def _launch(self, active: np.ndarray, h: int, planned: int,
+                spec_ks: np.ndarray | None = None,
+                ahead: bool = False) -> "_Queued":
+        """Dispatch ONE steady tick program over the rows ``active`` and
+        start its results' way home.  ``ahead``: behind a program that has
+        not been read, so the device's row state is one step past the
+        host's: the device arrays that program returned go in as they
+        are, and nothing is published."""
         t0_w = time.time()
-        dev = self._sync_device_state()
+        dev = self._dev if ahead else self._sync_device_state()
+        fused_spec = spec_ks is not None
+        family = "tick.spec" if fused_spec else "tick.steady"
         perf_pt = self._perf_point(h, width=0, spec=fused_spec,
                                    ew=int(dev["eos"].shape[1]))
-        rows_active = int(active.sum())
-        tick_work = self._count_tick_work(active)
-        self._count_sample_stages(np.flatnonzero(active))
+        # what the program asks of the kernels and the sampler is counted
+        # by the tick that READS it: a program queued behind another keeps
+        # its counts until then (and a discarded one takes them with it)
+        counts = {} if ahead else None
+        attrs = dict(rows_active=int(active.sum()),
+                     **self._count_tick_work(active, ahead=int(ahead),
+                                             into=counts))
+        self._count_sample_stages(np.flatnonzero(active), into=counts)
+        key_in = self.key
         if fused_spec:
             # the spec-enabled form of the SAME single entry: drafting,
             # the [R, k+1] verify, and acceptance all ride inside the
             # horizon loop — still one dispatch (JP106 unchanged)
             self.metrics["h2d_total"] += 1      # the draft widths
-            with self._perf_dispatch("tick.spec", point=perf_pt,
-                                     rows_active=rows_active, **tick_work):
+            with self._perf_dispatch(family, point=perf_pt, **attrs):
                 (home, self.cache, dev["toks"], dev["row_lens"],
                  dev["active"], dev["steps"], dev["remain"], self.key,
                  dev["hist"]) = _ragged_tick(
@@ -3761,15 +3945,14 @@ class ServingEngine:
                     tp_manual=self._tp_manual,
                     collective_qtype=self._collective_qtype)
                 sent = self._sent_home(home, h, spec=True)
-            self._tick_dispatches += 1
         else:
             # the steady-state tick is the SAME single jitted entry the
             # mixed tick uses, with no prefill block: one program either
-            # way, which is what lets JP106 pin the tick dispatch count
+            # way, which is what lets JP106 pin the tick's jitted callees
             # to exactly 1
             cache_in, state_src = self._state_call()
-            with self._perf_dispatch("tick.steady", point=perf_pt,
-                                     rows_active=rows_active, **tick_work):
+            with self._perf_dispatch(family, point=perf_pt, ahead=ahead,
+                                     **attrs):
                 (home, self.cache, dev["toks"], dev["row_lens"],
                  dev["active"], dev["steps"], dev["remain"],
                  self.key) = _ragged_tick(
@@ -3783,35 +3966,81 @@ class ServingEngine:
                     state_src=state_src)
                 sent = self._sent_home(home, h)
             self._state_done(state_src)
-            self._tick_dispatches += 1
             # the returned cache owns the (donated) tables buffer now
-        self._phase_to("drain")
-        # h tokens per host round trip; n_exec < h only if every row died
-        # early; a speculating tick's accepted counts (for the drain walk)
-        # and draft economics ride the same buffer
-        got = self._sync_window(*sent)
-        tok_block, lp_block = got["tok"], got["lp"]
-        executed = self._count_state(got["n_exec"])
-        if self.perf is not None:
-            # the MFU join's loop multiplier: XLA's cost analysis counts
-            # the horizon body once, the tick executed it `executed` times
-            self.perf.note_executed(executed)
-        self.metrics["steps"] += executed
-        self.metrics["decode_horizon_effective"] = h
-        self.metrics["pages_in_use"] = self.alloc.pages_in_use
-        parts = self._decode_parts(active)
-        if fused_spec:
-            take_np = got["take"]
-            self._spec_metrics(take_np, got["prop"], got["acc"], executed)
-            self._drain_spec_block(tok_block, lp_block, take_np,
-                                   active, executed)
-        else:
-            take_np = None
-            self._drain_block(tok_block, lp_block, active, executed)
-        self._trace_decode(parts, t0_w, executed, take_np)
-        self.metrics["tokens_per_sync"] = round(
-            self.metrics["tokens"] / self.metrics["host_syncs"], 2)
-        self._publish_ahead(H)
+        self._tick_dispatches += 1
+        if ahead and self.perf is not None:
+            # what ``PerfWatch.running`` marks its window with
+            attrs = self.perf.span_attrs(family, perf_pt, **attrs)
+        return _Queued(sent=sent, active=active, bind=list(self.rows),
+                       key=key_in, t0_w=t0_w, horizon=h, planned=planned,
+                       fused_spec=fused_spec, family=family, point=perf_pt,
+                       attrs=attrs, ahead=ahead, counts=counts or {})
+
+    def _ahead_blocked(self) -> str | None:
+        """Why the tick after the one awaited cannot be queued behind it,
+        by what the host holds BEFORE that one is read; None where it is a
+        plain steady tick as far as the host can tell (its pages are
+        ``_queue_next``'s to back)."""
+        if self._recurrent:
+            # the state a tick started from IS the rollback's source and
+            # the spare buffer is written (_state_call): a second program
+            # in flight would write over what the first one's rollback
+            # needs, and a third buffer of that size does not fit a chip.
+            # (A model that carries the device counters alone takes a third
+            # buffer of a few numbers.)
+            return "state"
+        if (self.ec.spec_k > 0 or self._planned_horizon() != 1
+                or (self.mesh is not None and self.mesh.size > 1)):
+            return "plan"
+        if (self._prefilling
+                or (self.queue_depth and self._free_row() is not None)
+                or not self._host_ops.empty()):
+            return "queue"      # a mixed tick, or a host operation, is due
+        if self._dirty or self._retries or self._serial:
+            # a finish, a completed prompt or a rollback wants a full
+            # epoch, which only a host that has read everything can
+            # publish; retries and bisection probes run in the serial order
+            return "dirty"
+        return None
+
+    def _queue_next(self) -> "_Queued | None":
+        """Dispatch the steady tick AFTER the one awaited before that one
+        is read, or say why not (``ahead_blocked_<reason>_total``).  What
+        it needs is known without reading: a live row advances by exactly
+        one step, the program itself retires a row on EOS or on its budget,
+        and the device arrays the awaited program returned are its inputs.
+        A page's edge does not break the run: the page is backed here and
+        the table crosses while the awaited program runs."""
+        why = self._ahead_blocked()
+        if why is None:
+            # a row whose budget ends inside the awaited program is gone
+            nxt = self._active_mask()
+            for i in np.flatnonzero(nxt):
+                nxt[i] = (int(self.row_budget[i])
+                          - len(self.rows[i].output_ids)) > 1
+            if not nxt.any():
+                return None
+            if not self._back_next_tick(1, inflight=1):
+                why = "pages"   # dry: the tick that needs it clamps, counts
+        if why is not None:
+            self.metrics[f"ahead_blocked_{why}_total"] += 1
+            return None
+        self._fault_point("decode-dispatch", rows=np.flatnonzero(nxt))
+        if self._tables_dirty:
+            self._publish_tables()
+        return self._launch(nxt, 1, 1, ahead=True)
+
+    def _discard_ahead(self):
+        """Throw away, unread, the program queued behind the last one read
+        (a rollback, a stop): what it wrote lies past the committed
+        lengths, its tokens were never staged, and the engine's key goes
+        back to the one it was handed.  The device's row state is a step
+        past the host's then: the caller marks it stale."""
+        q, self._ahead = self._ahead, None
+        if q is not None:
+            self.key = q.key
+            self._state_rewind()
+            self.metrics["ahead_discarded_total"] += 1
 
     def _publish_ahead(self, horizon: int):
         """What the NEXT steady tick would publish before its dispatch,
@@ -3827,19 +4056,35 @@ class ServingEngine:
         (PERF.md section 6, PR 44).  Before the commit the consumers sleep
         on their queues.  Not where the next tick is a mixed one (a row
         still prefilling, a request waiting beside a free row): that tick
-        publishes an epoch of its own.  A dry pool is left to the tick that
-        needs the page, which clamps and counts."""
-        if self._prefilling or (self.queue_depth
-                                and self._free_row() is not None):
+        publishes an epoch of its own.  Nor where the next tick is queued
+        already (``_ahead``): it went with its pages, and its row state is
+        the device's own.  A dry pool is left to the tick that needs the
+        page, which clamps and counts."""
+        if self._ahead is not None or self._prefilling or (
+                self.queue_depth and self._free_row() is not None):
             return
+        if not self._back_next_tick(horizon):
+            return
+        if self._dirty or self._tables_dirty:
+            self._phase_to("prepare")
+            self._sync_device_state()
+
+    def _back_next_tick(self, horizon: int, inflight: int = 0) -> bool:
+        """Back the pages the next steady tick's rows will write, from
+        lengths and budgets the host holds; ``inflight`` programs are
+        dispatched and not read, each a step a live row has taken since.
+        False where no row decodes or the pool is dry (nothing is taken
+        then)."""
         active = np.flatnonzero(self._active_mask())
         if not active.size:
-            return                     # nothing decodes: no steady tick
+            return False               # nothing decodes: no steady tick
         page = self.ec.page_size
         short = {}                     # row -> (pages it will need, missing)
         for i in active:
-            rem = int(self.row_budget[i]) - len(self.rows[i].output_ids)
-            need = min(-(-(int(self.row_lens[i]) + min(horizon, rem)) // page),
+            rem = (int(self.row_budget[i]) - len(self.rows[i].output_ids)
+                   - inflight)
+            lens = int(self.row_lens[i]) + inflight
+            need = min(-(-(lens + min(horizon, rem)) // page),
                        self.ec.max_pages)
             if rem > 0 and self.tables[i, need - 1] < 0:
                 short[i] = need, int((self.tables[i, :need] < 0).sum())
@@ -3847,12 +4092,10 @@ class ServingEngine:
             missing = sum(n for _, n in short.values())
             self.alloc.reserve(missing)
             if len(self.alloc.free) < missing:
-                return
+                return False
             for i, (need, _) in short.items():
                 self._ensure_pages(i, need * page)
-        if self._dirty or self._tables_dirty:
-            self._phase_to("prepare")
-            self._sync_device_state()
+        return True
 
     def _decode_parts(self, active: np.ndarray):
         """Tracing pre-capture for a decode drain: the participating

@@ -80,7 +80,12 @@ new syncs — JP106's one-dispatch tick is untouched):
   attribution rule above: committed working ticks only.  What closes
   after ``tick_finish`` has closed the books (the rest of
   ``engine/record`` and of ``engine/tick``) is counted into the NEXT
-  committed tick.
+  committed tick.  With a program in flight behind the running one (the
+  engine's ``_queue_next``) a tick may dispatch the NEXT tick's program
+  (``dispatch(ahead=True)``: a span without ``variant``), reads one an
+  earlier tick dispatched (``awaited``: the tick's family and point are
+  that program's) and marks where the queued one starts to run
+  (``running``): each committed working tick is still attributed once.
 
 Engines whose grid point the manifest does not cover (bigger row counts,
 wider buckets than the audit sampled) still get full attribution and
@@ -551,21 +556,37 @@ class PerfWatch:
             cm.__enter__()
             self._phase_linear = (name, cm)
 
+    @staticmethod
+    def span_attrs(family: str, point: dict | None = None, **attrs) -> dict:
+        """What an ``engine/dispatch`` span says of the program it stands
+        for: which tick variant, at which grid point (plus ``attrs``)."""
+        pt = point or {}
+        variant = ("chunk" if pt.get("wd") is False
+                   else _VARIANT.get(family, family))
+        return dict(variant=variant, family=family, p_b=pt.get("pb", 0),
+                    width=pt.get("width", 0), maxp=pt.get("maxp", 0),
+                    **attrs)
+
     @contextmanager
     def dispatch(self, family: str, point: dict | None = None,
-                 tick: bool = True, **attrs):
+                 tick: bool = True, ahead: bool = False, **attrs):
         """Timing window around ONE jitted call.  Runs the sentinel on
         any compile events that fire inside (they fire synchronously on
         this thread), stamps the family/point on the open tick scratch,
         and counts toward the tick-dispatch cross-check when ``tick``.
         The window is the ``dispatch`` phase: its span says which tick
-        variant was dispatched, at which grid point (plus ``attrs``)."""
-        pt = point or {}
-        variant = ("chunk" if pt.get("wd") is False
-                   else _VARIANT.get(family, family))
-        with self.phase("dispatch", variant=variant, family=family,
-                        p_b=pt.get("pb", 0), width=pt.get("width", 0),
-                        maxp=pt.get("maxp", 0), **attrs):
+        variant was dispatched, at which grid point (plus ``attrs``).
+
+        ``ahead``: the program is queued BEHIND the one this tick is
+        about to read and belongs, by family and point, to the tick that
+        will read it (``awaited``).  Its span carries the variant as
+        ``ahead`` and no ``variant``: the device runs another program
+        while it is open, so no reader may take the span for the window of
+        an execution (``running`` marks that window)."""
+        span = self.span_attrs(family, point, **attrs)
+        if ahead:
+            span["ahead"] = span.pop("variant")
+        with self.phase("dispatch", **span):
             prev = getattr(_tls, "watch", None)
             _tls.watch = self
             n0 = self.compiles["compiles_total"]
@@ -580,9 +601,10 @@ class PerfWatch:
                 self._window_point = None
                 for w in self._windows:
                     w["dispatch"].append((t0, t1))
-                    w["families"].append(family)
-                    if point is not None:
-                        w["points"].append(point)
+                    if not ahead:
+                        w["families"].append(family)
+                        if point is not None:
+                            w["points"].append(point)
                     if tick and w is self._tick:
                         w["tick_dispatches"] += 1
                     w["compiles"] += self.compiles["compiles_total"] - n0
@@ -597,6 +619,33 @@ class PerfWatch:
                         fc["compiles"] += dn
                         fc["compile_s"] += round(
                             self.compiles["compile_s_total"] - s0, 6)
+
+    def awaited(self, family: str, point: dict | None):
+        """The open tick reads a program that an EARLIER tick dispatched
+        (``dispatch(ahead=True)``): its family and point are this tick's.
+        A tick that dispatches nothing itself opens an empty
+        ``engine/dispatch`` span (stat ``queued``, no ``variant``), so
+        that a reader counting working ticks by that span counts it."""
+        t = self._tick
+        if t is None:
+            return
+        t["families"].append(family)
+        if point is not None:
+            t["points"].append(point)
+        if not t["dispatch"]:
+            with self.phase("dispatch", queued=1):
+                pass
+
+    @staticmethod
+    def running(attrs: dict):
+        """Right after a tick's sync, with a program queued behind the
+        one just read: from here to the end of ITS sync the device runs
+        that program.  An ``engine/dispatch`` span of no length carrying
+        what its dispatch would have (``span_attrs``) and ``queued``: no
+        sync follows it inside this tick, so a reader's window for it
+        runs to the next such span, one execution long."""
+        with TraceAnnotation("engine/dispatch", queued=1, **attrs):
+            pass
 
     def note_sync(self, seconds: float):
         """One blocking device->host materialization ending NOW (the

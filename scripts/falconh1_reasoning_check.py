@@ -175,7 +175,7 @@ def main(argv) -> int:
     finally:
         eng.stop()
     eng.cache = None        # the pool and the state, for the reference
-    eng._state_spare = None
+    eng._state_free = eng._state_held = []
 
     for i, (p_ids, tokens, lps) in enumerate(probes):
         full = p_ids + tokens[:-1]

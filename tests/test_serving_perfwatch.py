@@ -385,6 +385,70 @@ def test_dispatch_crosscheck_fails_loudly_in_engine(cfg_params):
     assert eng.perf.dispatch_mismatches >= 1
 
 
+@pytest.mark.parametrize("fault", [False, True])
+def test_programs_dispatched_are_read_or_discarded_one_queued_at_most(
+        cfg_params, fault):
+    """JP106's runtime twin, restated for a program in flight behind the
+    running one.  The static rule pins the tick's jitted callees; at run
+    time a tick may dispatch its own program AND the next one, or none
+    (it only reads one queued a tick ago), so the count holds over a run,
+    not a tick: programs dispatched == ticks that committed a program +
+    programs discarded unread (+ the one still queued), never more than
+    ONE is queued behind the one being awaited, and perfwatch's windows
+    still equal the engine's hand-kept count tick by tick.  A fault with a
+    program queued (``fault``) discards it; the rolled-back tick counts
+    nothing."""
+    cfg, params = cfg_params
+    inj = FaultInjector()
+    if fault:
+        inj.inject("decode-dispatch", TransientFault, nth=9)
+    eng = ServingEngine(cfg, params, EngineConfig(
+        max_rows=4, max_seq_len=256, page_size=16, prefill_bucket=16,
+        retry_backoff_s=0.0), fault_injector=inj)
+    inflight = {"n": 0, "max": 0}   # steady programs launched, not read
+    launch, read, rollback = eng._launch, eng._horizon_step, eng._rollback
+
+    def launched(*a, **kw):
+        inflight["n"] += 1
+        inflight["max"] = max(inflight["max"], inflight["n"])
+        return launch(*a, **kw)
+
+    def horizon_step(active):
+        read(active)                # returns having read exactly one
+        inflight["n"] -= 1
+
+    def rolled_back(snap):
+        inflight["n"] = 0           # everything in flight is thrown away
+        return rollback(snap)
+
+    eng._launch, eng._horizon_step = launched, horizon_step
+    eng._rollback = rolled_back
+    first = [Request(prompt_ids=p, max_new_tokens=n)
+             for p, n in zip(_prompts(2, 20), (30, 12))]
+    _drive(eng, first)
+    late = Request(prompt_ids=_prompts(1, 33)[0], max_new_tokens=9)
+    _drive(eng, late)
+    m, ring = eng.metrics, eng.flight.view()["ring"]
+    assert inj.fired == int(fault) and m["retries"] == int(fault)
+    assert m["ahead_discarded_total"] == int(fault)
+    assert m["ahead_ticks_total"] > 20
+    assert inflight["max"] == 2     # the one awaited and ONE behind it
+    assert all(r["dispatches"] <= 2 for r in ring)
+    committed = sum(1 for r in ring if r["perf_family"] != "tick.host")
+    assert sum(r["dispatches"] for r in ring) == (
+        committed + m["ahead_discarded_total"]
+        + (eng._ahead is not None))
+    # every committed working tick is counted once, whatever it dispatched
+    assert eng.perf.ticks_attributed == len(ring)
+    assert eng.perf.dispatch_mismatches == 0
+    assert m["d2h_total"] == m["host_syncs"]
+    assert all(r["perf_family"] == "tick.steady" for r in ring
+               if r["dispatches"] == 0 and r["tokens"])
+    # the per-tick cost join sees ONE program a tick: the one it read
+    assert all("grid_point" in r for r in ring
+               if r["perf_family"] != "tick.host")
+
+
 def test_perfwatch_disabled_engine(cfg_params):
     cfg, params = cfg_params
     eng = ServingEngine(cfg, params, EngineConfig(
@@ -1098,7 +1162,9 @@ def test_profiler_capture_holds_the_tick_spans(cfg_params, tmp_path):
     """A jax.profiler capture with the benchmark's options (host tracer
     level 1, no python tracer) holds ``engine/tick`` events on the host
     plane, numbered, and nested inside them the phases, with
-    ``engine/dispatch`` carrying the variant and the grid point."""
+    ``engine/dispatch`` carrying the variant and the grid point: of the
+    program it dispatches, or (``PerfWatch.running``) of the queued
+    program the device runs from there on."""
     import glob
 
     import jax
@@ -1133,16 +1199,25 @@ def test_profiler_capture_holds_the_tick_spans(cfg_params, tmp_path):
     assert len(ticks) >= 4
     numbers = [t[3]["tick"] for t in ticks]
     assert numbers == sorted(numbers) and numbers[0] >= 1
-    seen, variants = set(), set()
+    seen, variants, kinds = set(), set(), set()
     for name, a, b, stats in spans:
         if name == "engine/tick":
             continue
         assert any(t[1] <= a and b <= t[2] for t in ticks), name
         seen.add(name.split("/", 1)[1])
-        if name == "engine/dispatch":
-            variants.add(stats["variant"])
+        if name == "engine/dispatch" and set(stats) == {"queued"}:
+            kinds.add("awaited")    # a tick that only reads a queued program
+        elif name == "engine/dispatch":
+            # a dispatch in the serial order, a program's window marked
+            # after its predecessor's sync (``queued``), or a dispatch
+            # behind the running program (``ahead``: no window of its own)
+            assert ("variant" in stats) != ("ahead" in stats)
+            kinds.add("ahead" if "ahead" in stats
+                      else "running" if "queued" in stats else "serial")
+            variants.add(stats.get("variant") or stats["ahead"])
             assert stats["family"].startswith("tick.")
             assert {"p_b", "width", "maxp", "rows_active"} <= set(stats)
+    assert kinds == {"serial", "ahead", "running", "awaited"}
     assert seen == set(PHASES) - {"tick"}
     assert "steady" in variants and variants & {"mixed", "chunk"}
     assert variants <= {"steady", "mixed", "chunk", "spec"}
