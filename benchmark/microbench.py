@@ -4,8 +4,7 @@ Times the decode-path hot ops in isolation (fused dequant-matmul at M=1,
 decode attention) against their XLA fallbacks, reporting effective HBM
 bandwidth — the decode roofline currency.  Run: python benchmark/microbench.py
 
-``collect()`` returns the same numbers structured, so bench.py can embed a
-per-kernel summary in the driver's BENCH artifact (reference peer: the
+``collect()`` returns the same numbers structured (reference peer: the
 all-in-one harness's per-op CSV columns, dev/benchmark/all-in-one/run.py).
 """
 
@@ -25,7 +24,6 @@ from ipex_llm_tpu.kv import PagedKVCache
 from ipex_llm_tpu.ops.linear import qmatmul_reference
 from ipex_llm_tpu.ops.pallas.qmatmul import qmatmul_pallas
 from ipex_llm_tpu.ops.pallas.decode_attention import decode_sdpa
-from ipex_llm_tpu.ops.pallas.paged_attention import paged_decode_sdpa
 from ipex_llm_tpu.ops.pallas.ragged_paged_attention import ragged_paged_sdpa
 from ipex_llm_tpu.ops.attention import sdpa_reference
 from ipex_llm_tpu.quantize import quantize
@@ -135,49 +133,13 @@ def bench_paged_gather(r, hkv, maxp, ps, d, dtype=jnp.bfloat16, iters=50):
             "xla_gbs": round(nbytes / t / 1e9, 1)}
 
 
-def bench_paged_decode_attn(r, hq, hkv, maxp, ps, d, dtype=jnp.bfloat16,
-                            iters=50):
-    """T=1 attention straight off the paged pool (the serving decode hot
-    path): the Pallas scalar-prefetch kernel streams each row's own pages
-    in storage dtype (fp8 tiles widen in-kernel) vs the gather-then-SDPA
-    XLA fallback."""
-    rng = np.random.default_rng(1)
-    cache, k, v = _paged_fixture(r, hkv, maxp, ps, d, dtype)
-    q = jnp.asarray(rng.standard_normal((r, 1, hq, d)), jnp.bfloat16)
-    kv_len = jnp.full((r,), maxp * ps, jnp.int32)
-    nbytes = 2 * r * maxp * ps * hkv * d * k.dtype.itemsize
-
-    f_kern = jax.jit(lambda q, k, v: paged_decode_sdpa(
-        q, k, v, cache.tables, kv_len))
-
-    def ref(q, k, v):
-        kd = cache.gather_layer(k).astype(jnp.bfloat16).transpose(0, 2, 1, 3)
-        vd = cache.gather_layer(v).astype(jnp.bfloat16).transpose(0, 2, 1, 3)
-        qpos = (kv_len - 1)[:, None]
-        return sdpa_reference(q, kd, vd, causal=True, q_positions=qpos,
-                              kv_len=kv_len)
-    f_ref = jax.jit(ref)
-    tk = timeit(f_kern, q, k, v, iters=iters)
-    tr = timeit(f_ref, q, k, v, iters=iters)
-    print(f"paged_decode_attn R={r} Hq={hq} Hkv={hkv} S={maxp*ps} D={d} "
-          f"{k.dtype}: kernel {tk*1e6:8.1f}us ({nbytes/tk/1e9:6.1f} GB/s) "
-          f"| xla {tr*1e6:8.1f}us ({nbytes/tr/1e9:6.1f} GB/s)")
-    return {"op": (f"paged_decode_attn_r{r}_h{hq}/{hkv}_s{maxp*ps}"
-                   f"_d{d}_{k.dtype.name}"),
-            "pallas_us": round(tk * 1e6, 1), "xla_us": round(tr * 1e6, 1),
-            "pallas_gbs": round(nbytes / tk / 1e9, 1),
-            "xla_gbs": round(nbytes / tr / 1e9, 1)}
-
-
 def bench_ragged_attn(r, hq, hkv, maxp, ps, d, width, dtype=jnp.bfloat16,
                       iters=50):
     """The superkernel tick's attention shape: a MIXED batch where half
     the rows are decode rows (chunk_len 1) and half are ragged prefill
     chunks (chunk_len up to ``width``), all against the paged pool in one
     program (ops/pallas/ragged_paged_attention.py) vs the gather-then-
-    dense XLA fallback.  These rows are the measured ladder
-    ops/dispatch.py's data-driven backend choice keys on
-    (op families ``ragged_attn`` / ``ragged_attn_fp8``)."""
+    dense XLA fallback."""
     rng = np.random.default_rng(2)
     cache, k, v = _paged_fixture(r, hkv, maxp, ps, d, dtype)
     q = jnp.asarray(rng.standard_normal((r, width, hq, d)), jnp.bfloat16)
@@ -259,7 +221,8 @@ def bench_spec_verify(r, hq, hkv, maxp, ps, d, k_spec, dtype=jnp.bfloat16,
                       iters=50):
     """The on-device speculative verify shape: ONE [R, k+1] ragged
     attention pass off the paged pool vs the k+1 sequential T=1 decode
-    passes the same tokens would cost without speculation.  Decode is
+    passes (the same kernel, one query a row) the same tokens would cost
+    without speculation.  Decode is
     KV-bandwidth-bound, so the wide verify reads each row's pages once
     where the sequential chain reads them k+1 times — the roofline
     argument for the fused spec tick (a draft run that fully accepts
@@ -272,6 +235,7 @@ def bench_spec_verify(r, hq, hkv, maxp, ps, d, k_spec, dtype=jnp.bfloat16,
     base = maxp * ps - k1
     kv_len = jnp.full((r,), maxp * ps, jnp.int32)
     chunk = jnp.full((r,), k1, jnp.int32)
+    one = jnp.ones((r,), jnp.int32)
     # bytes the sequential chain re-reads: k+1 sweeps of every row's pool
     nbytes = 2 * r * maxp * ps * hkv * d * k.dtype.itemsize * k1
 
@@ -281,9 +245,9 @@ def bench_spec_verify(r, hq, hkv, maxp, ps, d, k_spec, dtype=jnp.bfloat16,
     def chain(q, k, v):
         outs = []
         for j in range(k1):
-            outs.append(paged_decode_sdpa(
+            outs.append(ragged_paged_sdpa(
                 q, k, v, cache.tables,
-                jnp.full((r,), base + j + 1, jnp.int32)))
+                jnp.full((r,), base + j + 1, jnp.int32), one))
         return jnp.concatenate(outs, axis=1)
     f_chain = jax.jit(chain)
     tw = timeit(f_wide, q_wide, k, v, iters=iters)
@@ -301,13 +265,12 @@ def bench_spec_verify(r, hq, hkv, maxp, ps, d, k_spec, dtype=jnp.bfloat16,
 
 def bench_collectives(rows=8, hidden=4096, tp=4, iters=50):
     """Per-call cost of one decode-shaped AllReduce per wire family
-    (ops/collectives.py: bf16-exact / e5m2 / int8) — the measured table
-    behind the collective family ladder.  The payload is the row-parallel
-    combine the manual-tp tick pays twice per layer: [rows, hidden] f32
+    (ops/collectives.py: bf16-exact / e5m2 / int8).  The payload is the
+    row-parallel combine the manual-tp tick pays twice per layer: [rows, hidden] f32
     partials reduced over the tp axis inside a fully-manual shard_map
     region.  On the CPU mesh the numbers price the family's code/decode
     arithmetic (the wire is emulated); on TPU they are the real ICI
-    story.  Refreshes _BUILTIN_COLLECTIVE_LADDER."""
+    story."""
     from jax.sharding import PartitionSpec as P
 
     from ipex_llm_tpu.ops import collectives
@@ -337,8 +300,8 @@ def bench_collectives(rows=8, hidden=4096, tp=4, iters=50):
 
 
 def collect(iters: int = 20) -> list[dict]:
-    """Compact per-kernel summary for the BENCH artifact (fail-soft: an op
-    whose kernel path is ineligible on this backend is skipped).
+    """Compact per-kernel summary (fail-soft: an op whose kernel path is
+    ineligible on this backend is skipped).
 
     Off-TPU the kernels run in Pallas INTERPRET mode (dispatch falls back
     automatically): timings are then a correctness-execution record, not a
@@ -354,8 +317,7 @@ def collect(iters: int = 20) -> list[dict]:
             (bench_qmatmul, (1, 11008, 4096), {"iters": iters}),  # down
             (bench_qmatmul, (1, 4096, 32000), {"iters": iters}),  # lm head
             # small-row decode shapes (M = concurrent decode rows in the
-            # fused tick): the qmatmul ladder rows ops/dispatch.py keys
-            # the int4-weight serving path on
+            # fused tick)
             (bench_qmatmul, (8, 4096, 12288), {"iters": iters}),
             (bench_qmatmul, (8, 11008, 4096), {"iters": iters}),
             (bench_decode_attn, (1, 32, 32, 1280, 128), {"iters": iters}),
@@ -365,10 +327,6 @@ def collect(iters: int = 20) -> list[dict]:
             (bench_paged_gather, (16, 8, 16, 128, 128), {"iters": iters}),
             (bench_paged_gather, (16, 8, 16, 128, 128),
              {"dtype": jnp.float8_e5m2, "iters": iters}),
-            (bench_paged_decode_attn, (16, 32, 8, 16, 128, 128),
-             {"iters": iters}),
-            (bench_paged_decode_attn, (16, 32, 8, 16, 128, 128),
-             {"dtype": jnp.float8_e5m2, "iters": iters}),  # fp8 paged KV
             # superkernel tick shape: mixed decode + ragged prefill rows
             (bench_ragged_attn, (16, 32, 8, 16, 128, 128, 32),
              {"iters": iters}),
@@ -386,11 +344,7 @@ def collect(iters: int = 20) -> list[dict]:
         # interpret-mode shapes: small enough that the Pallas interpreter
         # (orders of magnitude slower than compiled) finishes in seconds
         jobs = [
-            # decode-shape qmatmul rows M=1..8 (interpret vs XLA): the
-            # measured pairs behind ops/dispatch.py's builtin
-            # qmatmul_sym_int4 CPU ladder row — XLA's fused block-dequant
-            # wins at every M here, so the int4-weight serving engine's
-            # CPU dispatch is provably data-driven, not a platform guess
+            # decode-shape qmatmul rows M=1..8 (interpret vs XLA)
             (bench_qmatmul, (1, 256, 512), {"iters": 2}),
             (bench_qmatmul, (2, 256, 512), {"iters": 2}),
             (bench_qmatmul, (4, 256, 512), {"iters": 2}),
@@ -401,10 +355,7 @@ def collect(iters: int = 20) -> list[dict]:
             (bench_paged_gather, (2, 4, 4, 32, 64), {"iters": 2}),
             (bench_paged_gather, (2, 4, 4, 32, 64),
              {"dtype": jnp.float8_e5m2, "iters": 2}),
-            (bench_paged_decode_attn, (2, 8, 4, 4, 32, 64),
-             {"dtype": jnp.float8_e5m2, "iters": 2}),     # fp8 paged KV
-            # superkernel tick shape (interpret record): the ragged_attn
-            # ladder rows the data-driven dispatch policy keys on
+            # superkernel tick shape (interpret record)
             (bench_ragged_attn, (2, 8, 4, 4, 32, 64, 8), {"iters": 2}),
             (bench_ragged_attn, (2, 8, 4, 4, 32, 64, 8),
              {"dtype": jnp.float8_e5m2, "iters": 2}),
@@ -425,7 +376,7 @@ def collect(iters: int = 20) -> list[dict]:
         except Exception as e:  # noqa: BLE001 — record, keep benching
             print(f"microbench skip {fn.__name__}{args}: "
                   f"{type(e).__name__}: {e}", file=sys.stderr)
-    # collective wire families (the manual-tp AllReduce ladder): the
+    # collective wire families (the manual-tp AllReduce): the
     # decode-shaped payload on TPU, a smaller one for the CPU-mesh record
     try:
         shape = (8, 4096, 4) if on_tpu else (8, 1024, 4)
@@ -452,8 +403,6 @@ if __name__ == "__main__":
     # paged serving pool (16 rows x 16 pages x 128 slots), bf16 vs fp8
     bench_paged_gather(16, 8, 16, 128, 128)
     bench_paged_gather(16, 8, 16, 128, 128, jnp.float8_e5m2)
-    bench_paged_decode_attn(16, 32, 8, 16, 128, 128)
-    bench_paged_decode_attn(16, 32, 8, 16, 128, 128, jnp.float8_e5m2)
     # ragged superkernel batch (mixed decode + prefill rows), bf16 vs fp8
     bench_ragged_attn(16, 32, 8, 16, 128, 128, 32)
     bench_ragged_attn(16, 32, 8, 16, 128, 128, 32, jnp.float8_e5m2)

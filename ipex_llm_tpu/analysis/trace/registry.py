@@ -413,10 +413,10 @@ def real_registry() -> tuple[ProgramSpec, ...]:
                   + _grid(rows=(4,), width=(0,), horizon=(1, 8),
                           wq=("sym_int4",), kv=kv_axis)
                   # int4 admission joiner over BOTH pool storages: the
-                  # int4+fp8KV pairing is the fixed-HBM serving config
-                  # bench_weight_qtype gates, and the runtime sentinel
-                  # requires the structural (wq, kv) form to be locked
-                  # or every such admission wave flags out-of-grid.
+                  # int4+fp8KV pairing is the fixed-HBM serving config,
+                  # and the runtime sentinel requires the structural
+                  # (wq, kv) form to be locked or every such admission
+                  # wave flags out-of-grid.
                   # Width stays at the 8 representative only: on the
                   # widened int4 AUDIT model (hidden=128) a width-128
                   # chunk's [p=2, 128, out] activation shape-collides

@@ -8,8 +8,8 @@ tick's scheduler functions can possibly call: the tick functions are
 plain host Python, so every device dispatch they issue is a call to a
 module-level jit-bound name, which plain AST walking finds exactly.
 
-Kept jax-free so benchmark/serving_bench.py can stamp the audited count
-into its output rows without paying a tracer import.
+Kept jax-free: a caller reads the audited count without paying a tracer
+import.
 """
 
 from __future__ import annotations
@@ -89,7 +89,6 @@ def discover_tick_dispatches(spec: TickSpec,
 
 
 def mixed_tick_dispatch_count(source: str | None = None) -> int:
-    """Dispatches one mixed tick issues — the number serving_bench
-    stamps into its rows so BENCH artifacts track it against the JP106
-    gate."""
+    """Dispatches one mixed tick issues — the number the JP106 gate
+    holds at one."""
     return len(discover_tick_dispatches(mixed_tick_spec(), source))

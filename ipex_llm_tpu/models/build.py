@@ -190,8 +190,7 @@ def dequantize_params(params: dict[str, Any],
     """Full-width twin of a param tree: every block-quantized QTensor
     replaced by its dequantized dense stack (plain arrays and
     native-width QTensors pass through).  The bitwise oracle the packed
-    tree's qmatmul path is tested against, and the honest bf16 baseline
-    ``bench_weight_qtype`` prices a packed tree against."""
+    tree's qmatmul path is tested against."""
 
     def walk(tree):
         if isinstance(tree, dict):
@@ -223,8 +222,7 @@ def param_bytes(params: dict[str, Any]) -> tuple[int, int]:
     """(packed_bytes, dense_bytes) for a param tree: what the tree costs
     in HBM as stored, vs what the same tree would cost with every QTensor
     at bf16 full width (non-QTensor leaves count identically on both
-    sides).  The byte axis /health's ``weights`` block and the
-    fixed-budget ``bench_weight_qtype`` sweep report."""
+    sides).  The byte axis /health's ``weights`` block reports."""
     packed = dense = 0
     for leaf in jax.tree_util.tree_leaves(
             params, is_leaf=lambda x: isinstance(x, QTensor)):

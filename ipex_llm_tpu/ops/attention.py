@@ -144,27 +144,20 @@ def sdpa(
     return sdpa_reference(q, k, v, **kwargs)
 
 
-def _decode_kernel_mode(dispatch, op: str, hq: int, hkv: int) -> str | None:
+def _decode_kernel_mode(dispatch, hq: int, hkv: int) -> str | None:
     """Which decode-kernel variant the active dispatch state and the head
     counts allow: 'single' (no mesh, Pallas on), 'sharded' (tp mesh whose
     degree the shard_map wrappers can split these heads over), or None
-    (jnp/gather path).  One policy for both the paged and dense decode
-    ladders in :func:`cached_sdpa`; ``op`` keys the measured-ladder lookup
-    (dispatch.use_pallas)."""
+    (jnp/gather path).  One policy for both the paged and the dense decode
+    branch of :func:`cached_sdpa`."""
     mesh = dispatch.spmd_mesh()
     if mesh is None:
-        return "single" if dispatch.use_pallas(op) else None
+        return "single" if dispatch.use_pallas() else None
     tp = mesh.shape.get("tp", 1)
-    if (tp > 1 and dispatch.use_pallas_sharded(op)
+    if (tp > 1 and dispatch.use_pallas_sharded()
             and heads_shardable(hq, hkv, tp)):
         return "sharded"
     return None
-
-
-def _paged_op(kl: jnp.ndarray) -> str:
-    """The measured-ladder family of the paged pool's kernels, by the
-    pool's storage dtype."""
-    return "ragged_attn_fp8" if "float8" in str(kl.dtype) else "ragged_attn"
 
 
 @jax.named_scope("layer/kv_write")
@@ -173,8 +166,8 @@ def paged_kv_write(cache, k, v, layer, new_k, new_v, pos):
     ``k``/``v`` [L, P, Hkv, page, D] at ``(layer, page, :, offset)``,
     in place, and return the stacks.
 
-    Chosen by the rule that chooses the paged attention kernel (the same
-    ladder family, so pool reads and writes change backend together): the
+    Chosen by the rule that chooses the paged attention kernel (so pool
+    reads and writes change backend together): the
     Pallas kernel (ops/pallas/paged_kv_write.py) where kernels run,
     ``cache.write_layer``'s XLA scatter elsewhere (the CPU; a GSPMD mesh
     the kv heads do not divide over).  Both put the same bytes in the same
@@ -182,7 +175,7 @@ def paged_kv_write(cache, k, v, layer, new_k, new_v, pos):
     from ipex_llm_tpu.ops import dispatch
 
     hkv = k.shape[2]
-    mode = _decode_kernel_mode(dispatch, _paged_op(k), hkv, hkv)
+    mode = _decode_kernel_mode(dispatch, hkv, hkv)
     if mode is None:
         return cache.write_layer(k, v, layer, new_k, new_v, pos)
     from ipex_llm_tpu.ops.pallas import paged_kv_write as kernel
@@ -201,8 +194,7 @@ def paged_pool_write(cache, pool, layer, new, pos):
     The same choice of backend."""
     from ipex_llm_tpu.ops import dispatch
 
-    if dispatch.spmd_mesh() is not None or not dispatch.use_pallas(
-            _paged_op(pool)):
+    if dispatch.spmd_mesh() is not None or not dispatch.use_pallas():
         return cache.write_pool(pool, layer, new, pos)
     from ipex_llm_tpu.ops.pallas import paged_kv_write as kernel
 
@@ -255,7 +247,7 @@ def latent_attention(q_lat, q_rope, c_pool, r_pool, cache, kv_len,
         raise NotImplementedError(
             "latent (MLA) attention over a paged pool under a mesh: the "
             "latent row is shared by every head and is not sharded")
-    if dispatch.use_pallas(_paged_op(c_pool)):
+    if dispatch.use_pallas():
         from ipex_llm_tpu.ops.pallas import ragged_paged_attention as rpa
 
         return rpa.latent_paged_sdpa(
@@ -410,7 +402,7 @@ def sparse_latent_attention(q_row, pool, cache, sel, chunk_lens, kv_len, *,
         live = (ok & (chunk_lens > 0)[:, None])[:, None, :]
         return _softmax_rows(s, live, rows[..., :rank])[:, None].astype(
             q_row.dtype)
-    if dispatch.use_pallas(_paged_op(pool)):
+    if dispatch.use_pallas():
         from ipex_llm_tpu.ops.pallas import ragged_paged_attention as rpa
 
         return rpa.sparse_paged_sdpa(q_row, pool, cache.tables, sel, kv_len,
@@ -473,13 +465,8 @@ def cached_sdpa(
             and q.shape[2] % kl.shape[-3] == 0
         ):
             # read ONLY the row's own pages through the scalar-prefetched
-            # block table — no table-width gather.  The op family key
-            # makes the backend choice data-driven from the measured
-            # microbench ladder (dispatch._BUILTIN_LADDER / the env
-            # override): the same rows microbench records are what decide
-            # kernel-vs-XLA here.
-            mode = _decode_kernel_mode(dispatch, _paged_op(kl), q.shape[2],
-                                       kl.shape[-3])
+            # block table — no table-width gather
+            mode = _decode_kernel_mode(dispatch, q.shape[2], kl.shape[-3])
             if mode is not None:
                 from ipex_llm_tpu.ops.pallas import ragged_paged_attention
             if mode == "single":
@@ -516,9 +503,7 @@ def cached_sdpa(
             window_on=kwargs.get("window_on", True),
             softcap=kwargs.get("softcap"),
         )
-        op = ("decode_attn_fp8" if "float8" in str(kl.dtype)
-              else "decode_attn")
-        mode = _decode_kernel_mode(dispatch, op, q.shape[2], kl.shape[1])
+        mode = _decode_kernel_mode(dispatch, q.shape[2], kl.shape[1])
         if mode is not None:
             from ipex_llm_tpu.ops.pallas import decode_attention
         if mode == "single":

@@ -121,44 +121,10 @@ def all_reduce(x: jnp.ndarray, axis_name: str, qtype: str = "bf16",
     return y.astype(out_dtype)
 
 
-# --------------------------------------------------------------------------
-# measured family ladder
-# --------------------------------------------------------------------------
-#
-# Like ops/dispatch's pallas-vs-xla ladder, the collective family choice is
-# DATA-DRIVEN where it is a pure-speed call: the table records measured
-# per-call microseconds for one decode-shaped AllReduce per family
-# (benchmark/microbench.py::bench_collectives refreshes it; the builtin
-# snapshot is the repo's latest CPU-mesh round).  Unlike the kernel ladder,
-# speed alone may not pick a LOSSY family — quantized wires change
-# numerics — so resolution is:
-#
-#   1. an explicit request (EngineConfig.collective_qtype or the
-#      IPEX_LLM_TPU_COLLECTIVE_QTYPE env) always wins;
-#   2. otherwise the EXACT family ("bf16") stands, whatever the ladder
-#      says — operators opt INTO bounded error, it is never inferred.
-#
-# The ladder's role without an override is observability: bench_tp_scaling
-# reports the measured family costs beside the tok/s rows so the operator
-# can see what switching buys before flipping the flag.
-_BUILTIN_COLLECTIVE_LADDER: dict[str, dict[str, float]] = {
-    # CPU 8-virtual-device mesh, tp=4, [8, 4096] f32-equivalent payload
-    # (BENCH_r14 round; microbench bench_collectives).  On the emulated
-    # CPU wire the quantized families pay their code/decode arithmetic
-    # without any byte saving, so bf16-exact winning here is expected —
-    # the table exists so that call is DATA, not a guess.
-    "cpu": {"bf16": 517.3, "e5m2": 461.5, "int8": 664.4},
-    "tpu": {},
-}
-
-
-def ladder() -> dict[str, float]:
-    """Measured per-call us for each AllReduce family on this backend."""
-    from ipex_llm_tpu.ops.dispatch import backend_platform
-
-    return _BUILTIN_COLLECTIVE_LADDER.get(backend_platform(), {})
-
-
+# Speed may not pick a LOSSY family (quantized wires change numerics), so
+# the exact family ("bf16") stands unless an explicit request
+# (EngineConfig.collective_qtype or IPEX_LLM_TPU_COLLECTIVE_QTYPE) opts into
+# bounded error.
 def resolve_qtype(requested: str | None = None) -> str:
     """The family an op should use: explicit request (argument, then the
     IPEX_LLM_TPU_COLLECTIVE_QTYPE env) or the exact default."""

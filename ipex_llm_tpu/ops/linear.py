@@ -37,10 +37,9 @@ def qmatmul(x: jnp.ndarray, qt: QTensor | QLayer,
     """Quantized matmul with backend dispatch: the single entry.
 
     The Pallas path covers the formats ops/pallas/qmatmul.py lists as
-    supported, and the backend choice per qtype family comes from
-    ``dispatch.use_pallas("qmatmul_<qtype>")``: the compiled kernel on a
-    TPU, the XLA block-dequant path elsewhere, unless a measured ladder
-    row says otherwise.  Under an active SPMD mesh, TP-sharded weights
+    supported, and the backend comes from ``dispatch.use_pallas()``: the
+    compiled kernel on a TPU, the XLA block-dequant path elsewhere.  Under
+    an active SPMD mesh, TP-sharded weights
     (``qt.tp_mode`` stamped by parallel/shard.py) whose shape the
     shard_map-wrapped kernel covers run it; everything else takes the XLA
     dequant path, which GSPMD partitions itself.  Every route is decided
@@ -54,7 +53,6 @@ def qmatmul(x: jnp.ndarray, qt: QTensor | QLayer,
     dequant path, the shard_map tp wrapper, the manual-tp row-parallel
     branch.  There the slice feeds XLA operations and fuses into them.
     """
-    fam = f"qmatmul_{qt.qtype}"
     mt = dispatch.manual_tp_state()
     if mt is not None:
         # manual-mesh region (parallel/manual.py): the planes are already
@@ -66,9 +64,9 @@ def qmatmul(x: jnp.ndarray, qt: QTensor | QLayer,
         # matmul; the Pallas kernel cannot serve here, it emits at
         # compute_dtype and a narrowed partial would break that
         # guarantee).  Column/replicated weights are pure local compute
-        # and fall THROUGH to the ordinary single-device ladder below —
-        # the per-shard matmul takes the same measured Pallas-vs-XLA
-        # call the single-chip trace takes.
+        # and fall THROUGH to the ordinary single-device choice below —
+        # the per-shard matmul takes the same Pallas-vs-XLA call the
+        # single-chip trace takes.
         axis, cq = mt
         if qt.tp_mode == "row":
             w = qcore.dequantize(_whole(qt), dtype=compute_dtype)
@@ -83,7 +81,7 @@ def qmatmul(x: jnp.ndarray, qt: QTensor | QLayer,
         if (
             mesh is not None
             and mesh.shape.get("tp", 1) > 1
-            and dispatch.use_pallas_sharded(fam)
+            and dispatch.use_pallas_sharded()
         ):
             # (kernel modules import pallas, ~1 s: only where a kernel runs)
             from ipex_llm_tpu.ops.pallas import qmatmul as pallas_qmatmul
@@ -91,7 +89,7 @@ def qmatmul(x: jnp.ndarray, qt: QTensor | QLayer,
             if pallas_qmatmul.sharded_supported(qt, mesh.shape["tp"]):
                 return pallas_qmatmul.qmatmul_pallas_sharded(
                     x, _whole(qt), mesh, compute_dtype)
-    if dispatch.use_pallas(fam):
+    if dispatch.use_pallas():
         from ipex_llm_tpu.ops.pallas import qmatmul as pallas_qmatmul
 
         if pallas_qmatmul.supported(qt):

@@ -213,8 +213,7 @@ def _grouped_kernel_covers(stack) -> bool:
     from ipex_llm_tpu.ops import dispatch
     from ipex_llm_tpu.quantize.core import QTensor
 
-    if not (isinstance(stack, QTensor)
-            and dispatch.use_pallas(f"qmatmul_{stack.qtype}")):
+    if not (isinstance(stack, QTensor) and dispatch.use_pallas()):
         return False
     from ipex_llm_tpu.ops.pallas import qmatmul as pallas_qmatmul
 

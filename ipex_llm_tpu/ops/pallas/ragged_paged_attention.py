@@ -5,12 +5,11 @@ to this repo's paged pool: a single Pallas program walks a ragged batch of
 rows where each row is either a **decode row** (1 query token at slot
 ``kv_len - 1``), a **prefill-chunk row** (up to C query tokens right-padded
 to the chunk width, causally masked against its own history), or an **idle
-row** (``chunk_len == 0`` — fully masked, zero output).  It subsumes both
-``paged_attention.paged_decode_sdpa`` and ``paged_prefill_sdpa``, so the
+row** (``chunk_len == 0`` — fully masked, zero output).  So the
 serving engine's fused tick program (``serving/engine.py::_ragged_tick``)
 carries exactly one attention kernel family regardless of the admission mix.
 
-What it fixes over the split kernels (the BENCH_r05 per-op losses):
+What one kernel for every row shape rests on:
 
 - **per-row raggedness is traced, not static**: ``chunk_len`` [R] rides the
   scalar prefetch, so one compiled program serves every (decode, prefill,
@@ -18,8 +17,7 @@ What it fixes over the split kernels (the BENCH_r05 per-op losses):
   row instead of a uniform static chunk;
 - **query-row tiling**: the whole GQA group x chunk tile ``[G*C, D]`` feeds
   ONE MXU dot per head and page, amortizing each K/V page fetch across
-  every query row that needs it (the split decode kernel issued [G, D]
-  slivers);
+  every query row that needs it;
 - **no full-width accumulator re-materialization**: the online-softmax
   state (m/l/acc) lives in VMEM scratch across the page walk and the
   output tile is written exactly once, after the last page — versus the

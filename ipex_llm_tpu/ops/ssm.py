@@ -89,7 +89,7 @@ def _scan(cfg, stack_src, stack_dst, layer, rows, fresh, x, dt, a, b, c,
 
     hpg = cfg.ssm_num_heads // cfg.ssm_n_groups
     use_kernel = False
-    if dispatch.use_pallas("ssm_scan"):
+    if dispatch.use_pallas():
         from ipex_llm_tpu.ops.pallas import ssm_scan
 
         use_kernel = ssm_scan.supported(cfg)

@@ -579,7 +579,7 @@ class OpenAIServer:
         # byte lines (weights.weight_bytes + kv.pool_bytes) are the one
         # HBM budget an operator provisions — int4 weights hand their
         # saved bytes to the KV pool (more pages, more concurrent rows at
-        # the same cap; see bench_weight_qtype)
+        # the same cap)
         body["weights"] = self.engine.weight_stats()
         # multi-chip routing: which tick serves this mesh (the fully-
         # manual shard_map tick vs the per-op GSPMD fallback, with the
@@ -610,12 +610,6 @@ class OpenAIServer:
         perf = self.engine.perf_view()
         if perf is not None:
             body["perf"] = perf
-        # dispatch-ladder provenance: which measured microbench round
-        # each Pallas-vs-XLA decision rests on — a stale ladder (builtin
-        # rows date to BENCH_r05/r12) is visible instead of silently
-        # trusted
-        from ipex_llm_tpu.ops.dispatch import ladder_provenance
-        body["dispatch"] = ladder_provenance()
         # fault-domain observability: admission backlog vs the bound (what
         # a 429 means), per-request failures isolated by bisection,
         # transient step retries, load-shed and deadline-expired counts

@@ -134,12 +134,11 @@ class EngineConfig:
     # (models/build.requantize_params, the same quantize/core.py codecs a
     # low-bit checkpoint load uses), and the single compiled layer body
     # routes those matmuls through ops/linear.qmatmul with dequant fused
-    # next to the MXU (Pallas on TPU, XLA-fused block dequant on CPU —
-    # the data-driven dispatch ladder decides).  Decode is HBM-bandwidth
-    # bound, so ~4.5 bits/weight instead of 16 is a direct tok/s and —
-    # at a fixed HBM byte budget, weights + KV pool together — a
-    # concurrency win (bytes the weights stop using become KV pages; see
-    # bench_weight_qtype).  Zero new device programs: the QTensor planes
+    # next to the MXU (Pallas on TPU, XLA-fused block dequant on CPU).
+    # Decode is HBM-bandwidth bound, so ~4.5 bits/weight instead of 16
+    # is a direct tok/s and — at a fixed HBM byte budget, weights + KV
+    # pool together — a concurrency win (bytes the weights stop using
+    # become KV pages).  Zero new device programs: the QTensor planes
     # ride the existing param pytree through the one-dispatch tick, so
     # JP106 stays ==1, and the JP107 trace rule fails the audit if a
     # hot-path program ever materializes a full-width copy of a stacked
@@ -171,10 +170,10 @@ class EngineConfig:
     # jitted on-device loop (``_decode_horizon_loop``: ``lax.while_loop``
     # that exits early once every row is dead) with device-resident engine
     # state, so the host pays ONE blocking sync per H tokens instead
-    # of per token — the fix for the dispatch-bound regime BENCH_r05
-    # measured (per-stream tok/s collapsing under concurrency from host
-    # orchestration, not FLOPs; the vLLM multi-step / MaxText on-device
-    # generate-loop peers).  Per-row EOS/length early-stop is masked on
+    # of per token — the fix for the dispatch-bound regime (per-stream
+    # tok/s collapsing under concurrency from host orchestration, not
+    # FLOPs; the vLLM multi-step / MaxText on-device generate-loop
+    # peers).  Per-row EOS/length early-stop is masked on
     # device, so fused output is bit-identical to H=1 under the seeded-
     # stream contract.  Streaming granularity becomes up to H tokens
     # (times spec_k+1 when speculative decode rides the same loop).
@@ -220,10 +219,9 @@ class EngineConfig:
     # exportable as Chrome trace-event JSON.  All timestamps are host
     # clock reads at points the tick already visits: no new device
     # syncs, JP106's one-dispatch tick untouched.  False = the tracer is
-    # None and every trace site is one `is None` check (bench_observe
-    # prices both).  The tick flight recorder and the latency histograms
-    # are always on — they are a dict append per working tick and a few
-    # float ops per token.
+    # None and every trace site is one `is None` check.  The tick flight
+    # recorder and the latency histograms are always on — they are a dict
+    # append per working tick and a few float ops per token.
     trace_requests: bool = False
     trace_buffer: int = 256     # traces retained (LRU); spans/trace capped
     flight_ring: int = 256      # tick records the flight recorder retains
@@ -238,7 +236,7 @@ class EngineConfig:
     # joining measured device time against the manifest's cost_analysis
     # for the dispatched grid point.  All host clock reads at points the
     # tick already visits — no new device programs or syncs, JP106 stays
-    # ==1.  False = no PerfWatch at all (bench_observe prices the pair).
+    # ==1.  False = no PerfWatch at all.
     perfwatch: bool = True
     # multi-chip collective wire family (ops/collectives.py, the EQuARX
     # axis): what the manual-mesh tick's row-parallel AllReduces carry.
@@ -1099,8 +1097,8 @@ class ServingEngine:
         if self.ec.weight_qtype is not None:
             params = requantize_params(params, self.ec.weight_qtype,
                                        imatrix_data=weight_imatrix)
-        # weight byte accounting for /health's weights block and the
-        # fixed-budget bench: what the tree costs as stored vs at bf16
+        # weight byte accounting for /health's weights block: what the
+        # tree costs as stored vs at bf16
         # full width, plus the packed formats actually present (an
         # already-quantized tree reports its real width even when
         # weight_qtype is None)
@@ -1241,12 +1239,10 @@ class ServingEngine:
         # and every bucket a prompt can reach is one more variant of every
         # mixed tick to compile: at 4096-token prompts six buckets made 76
         # programs and 1,650 s of warm-up (my chip run, PR 37)
-        from ipex_llm_tpu.ops import attention as attention_ops
         from ipex_llm_tpu.ops import dispatch
 
         self._slice_tables = self.ec.max_pages > 8 and not (
-            self.mesh is None
-            and dispatch.use_pallas(attention_ops._paged_op(self.cache.k)))
+            self.mesh is None and dispatch.use_pallas())
         self._step_budget = (self.ec.prefill_bucket
                              if self.ec.step_token_budget is None
                              else int(self.ec.step_token_budget))
@@ -1307,7 +1303,7 @@ class ServingEngine:
         self._work = threading.Event()   # set on submit: idle-loop wakeup
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
-        # fault domain: the scripted fault source (tests/chaos bench), the
+        # fault domain: the scripted fault source (tests), the
         # per-tick emission staging buffer (client-visible queue puts are
         # deferred until the tick commits, so a rolled-back tick never
         # leaks a token), requests masked out of the step during bisection
@@ -1668,7 +1664,7 @@ class ServingEngine:
                 box.put((False, e))
 
     def kv_stats(self) -> dict:
-        """KV-pool observability for /health and the bench sweeps: what
+        """KV-pool observability for /health: what
         the pool costs (storage format, page/pool bytes), how full it is,
         and the pressure trace (prefix-cache LRU evictions, allocation
         failures that forced a clamp) — the numbers the fp8-vs-bf16
@@ -1713,8 +1709,8 @@ class ServingEngine:
         return out
 
     def weight_stats(self) -> dict:
-        """Weight-pool observability for /health's ``weights`` block and
-        the fixed-byte-budget bench: the serving width axis
+        """Weight-pool observability for /health's ``weights`` block:
+        the serving width axis
         (``EngineConfig.weight_qtype`` plus the packed formats actually
         present in the tree), what the params cost in HBM as stored, what
         the same tree would cost at bf16 full width, and the bytes the
@@ -1734,8 +1730,8 @@ class ServingEngine:
         }
 
     def spec_stats(self) -> dict:
-        """Speculative-decoding observability for /health and the bench
-        sweeps: lifetime draft economics, the rolling accept rate (128-
+        """Speculative-decoding observability for /health: lifetime draft
+        economics, the rolling accept rate (128-
         tick window — what the operator tunes spec_k/spec_ngram against),
         and tokens emitted per spec-tick dispatch (the amortization the
         on-device loop buys; 0 when spec is off or nothing ran)."""
@@ -3350,7 +3346,7 @@ class ServingEngine:
         (spec_steps/spec_emitted/accept_rate — one loop iteration is one
         verify round; normalized by ACTIVE ROW-STEPS, so concurrent rows
         cannot push the rate past 1.0) plus the draft-economics
-        pair the /health spec block and the bench sweep report.  All of
+        pair the /health spec block reports.  All of
         it lives in the checkpointed metrics dict / rolling window, so a
         rolled-back tick never double-counts."""
         k = self.ec.spec_k

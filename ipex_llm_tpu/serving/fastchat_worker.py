@@ -76,9 +76,9 @@ class FastChatWorker:
             web.get("/trace/{trace_id}", self.api_trace),
             web.get("/debug/flight", self.api_flight),
             # device-time observatory (serving/perfwatch.py): the perf
-            # block api_server serves under /health, plus the dispatch-
-            # ladder provenance — a FastChat worker's recompile sentinel
-            # and MFU join are inspectable without the OpenAI surface
+            # block api_server serves under /health — a FastChat worker's
+            # recompile sentinel and MFU join are inspectable without the
+            # OpenAI surface
             web.get("/debug/perf", self.api_perf),
         ])
         # graceful drain on SIGTERM (reference workers restart-on-error;
@@ -282,10 +282,7 @@ class FastChatWorker:
         return web.json_response(self.engine.flight.view())
 
     async def api_perf(self, request: web.Request):
-        from ipex_llm_tpu.ops.dispatch import ladder_provenance
-
         return web.json_response({"perf": self.engine.perf_view(),
-                                  "dispatch": ladder_provenance(),
                                   "planner": self.engine.planner_view()})
 
 

@@ -271,8 +271,8 @@ class FaultInjector:
 
 def rate_injector(site: str, every: int, exc=TransientFault,
                   limit: int | None = None) -> FaultInjector:
-    """Chaos-mode helper (benchmark/serving_bench.py --inject-faults):
-    fire ``exc`` on every ``every``-th hit of ``site``, up to ``limit``
-    total — deterministic, so a chaos bench run is reproducible."""
+    """Chaos-mode helper: fire ``exc`` on every ``every``-th hit of
+    ``site``, up to ``limit`` total — deterministic, so a chaos run is
+    reproducible."""
     return FaultInjector().inject(site, exc, nth=every, period=every,
                                   times=limit)

@@ -39,7 +39,7 @@ new syncs — JP106's one-dispatch tick is untouched):
     compile the static audit priced);
   * a compile for a point ALREADY compiled in this engine =
     ``compiles_warm`` (the jit cache should have hit — a shape/semantic
-    retrace is eating seconds mid-serving; the BENCH gate pins this to 0
+    retrace is eating seconds mid-serving; chip_smoke.py holds it at 0
     after warm-up);
   * a compile whose point is NOT in the locked grid =
     ``compiles_out_of_grid``, flagged loudly (warn log + /health ``perf``

@@ -160,25 +160,3 @@ def test_qmatmul_reference_accuracy(qtype):
     denom = np.sqrt(np.mean(want**2)) + 1e-9
     rel = np.sqrt(np.mean((got - want) ** 2)) / denom
     assert rel < 0.2, f"{qtype} rel err {rel}"
-
-
-def test_dispatch_prefers_xla_over_interpret_pallas_on_cpu(monkeypatch):
-    """Auto kernel policy: the CPU backend runs the XLA reference path, not
-    interpret-mode Pallas (BENCH_r05 microbench: decode_attn 540us
-    interpret vs 268us XLA); IPEX_LLM_TPU_FORCE_PALLAS=1 stays the kernel-
-    testing override."""
-    from ipex_llm_tpu.ops import dispatch
-
-    monkeypatch.delenv("IPEX_LLM_TPU_FORCE_PALLAS", raising=False)
-    monkeypatch.delenv("IPEX_LLM_TPU_DISABLE_PALLAS", raising=False)
-    try:
-        dispatch.clear_cache()
-        assert dispatch.use_pallas() is False          # XLA reference wins
-        assert dispatch.use_pallas_sharded() is False
-        monkeypatch.setenv("IPEX_LLM_TPU_FORCE_PALLAS", "1")
-        dispatch.clear_cache()
-        assert dispatch.use_pallas() is True           # explicit override
-        assert dispatch.use_pallas_sharded() is True
-    finally:
-        monkeypatch.delenv("IPEX_LLM_TPU_FORCE_PALLAS", raising=False)
-        dispatch.clear_cache()

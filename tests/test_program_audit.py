@@ -523,9 +523,8 @@ def test_jp106_fires_on_a_third_dispatch_sneaking_in():
 
 
 def test_real_mixed_tick_issues_one_dispatch():
-    # the serving_bench row stamps this number; the ragged superkernel
-    # tick (_ragged_tick) drove it from 2 to exactly 1, and JP106
-    # keeps it there
+    # the ragged superkernel tick (_ragged_tick) drove it from 2 to
+    # exactly 1, and JP106 keeps it there
     assert mixed_tick_dispatch_count() == 1
 
 

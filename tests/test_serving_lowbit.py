@@ -19,8 +19,6 @@ The contracts under test (the low-bit-serving PR, ROADMAP item 3):
   pre-dequantized bf16 tree (the packing moved bytes, not math);
 - **fault-domain composition**: a transient fault mid-tick over int4
   weights rolls back and retries bit-identically;
-- **dispatch ladder**: the recorded qmatmul rows provably select XLA on
-  CPU-interpret, and a re-measured dump re-decides the backend;
 - **byte accounting**: ``weight_stats()``/the ``/health`` weights block
   report packed bytes, bf16-equivalent bytes, and the savings the KV
   pool is co-budgeted with.
@@ -498,32 +496,6 @@ def test_transient_fault_rollback_over_int4_tick(cfg_params):
     assert all(r.finish_reason == "length" for r in reqs)
     # the packed planes survived the rollback's epoch re-upload untouched
     assert eng.params["layers"]["qkv"].data.dtype == jnp.uint8
-
-
-# -- dispatch ladder ---------------------------------------------------------
-
-def test_qmatmul_ladder_is_data_driven(monkeypatch, tmp_path):
-    """A re-measured collect() dump re-decides the qmatmul backend —
-    recording the kernel faster turns the Pallas path on — and the
-    microbench row names map onto the qmatmul_<qtype> family the
-    ops/linear.py dispatch keys on."""
-    from ipex_llm_tpu.ops import dispatch
-
-    rows = [{"op": "qmatmul_sym_int4_m1_256x512",
-             "pallas_us": 10.0, "xla_us": 50.0, "interpret": True}]
-    path = tmp_path / "ladder.json"
-    path.write_text(json.dumps(rows))
-    monkeypatch.delenv("IPEX_LLM_TPU_FORCE_PALLAS", raising=False)
-    monkeypatch.delenv("IPEX_LLM_TPU_DISABLE_PALLAS", raising=False)
-    monkeypatch.setenv("IPEX_LLM_TPU_DISPATCH_LADDER", str(path))
-    dispatch.clear_cache()
-    try:
-        assert dispatch.use_pallas("qmatmul_sym_int4") is True
-        monkeypatch.setenv("IPEX_LLM_TPU_DISABLE_PALLAS", "1")
-        dispatch.clear_cache()
-        assert dispatch.use_pallas("qmatmul_sym_int4") is False
-    finally:
-        dispatch.clear_cache()
 
 
 # -- /health weights block ---------------------------------------------------

@@ -25,11 +25,10 @@ The contracts under test:
   a bisection probe and an idle tick add nothing to them or to the wait
   counters; a profiler capture holds ``engine/tick`` spans with the
   phases nested inside; the lowered tick names every device scope;
-- **surfaces**: /health carries the ``perf`` block and the
-  ``dispatch`` ladder-provenance block (recorded-at bench-round
-  stamps), /metrics carries the ``perf_*`` counters and per-family
-  attribution histograms, and the router fleet-SUMS the sentinel
-  counters.
+- **surfaces**: /health carries the ``perf`` block (and no
+  ``dispatch`` block: the kernel choice is the platform's), /metrics
+  carries the ``perf_*`` counters and per-family attribution histograms,
+  and the router fleet-SUMS the sentinel counters.
 """
 
 import asyncio
@@ -483,100 +482,6 @@ def test_handoff_epoch_family_attributed(cfg_params):
         assert pv["families"][f]["ticks"] == n
 
 
-# -- ladder provenance (satellite) ------------------------------------------
-
-def test_ladder_provenance_stamps(tmp_path, monkeypatch):
-    from ipex_llm_tpu.ops import dispatch
-
-    monkeypatch.delenv("IPEX_LLM_TPU_DISPATCH_LADDER", raising=False)
-    dispatch.clear_cache()
-    try:
-        prov = dispatch.ladder_provenance()
-        assert prov["source"] == "builtin"
-        if prov["platform"] == "cpu":
-            fams = prov["families"]
-            assert fams["decode_attn"]["recorded"] == "BENCH_r05"
-            assert fams["decode_attn"]["prefers"] == "xla"
-        # an override dump gets stamped from its own round field, or the
-        # dump file's mtime date when it carries none
-        p = tmp_path / "ladder.json"
-        p.write_text(json.dumps([
-            {"op": "decode_attn_b1_h8/4_s256_d64_bfloat16",
-             "pallas_us": 1.0, "xla_us": 2.0, "interpret": True,
-             "round": "BENCH_r99"},
-            {"op": "ragged_attn_b1_h8/4_s256_d64_bfloat16",
-             "pallas_us": 3.0, "xla_us": 1.0, "interpret": True},
-        ]))
-        monkeypatch.setenv("IPEX_LLM_TPU_DISPATCH_LADDER", str(p))
-        dispatch.clear_cache()
-        prov = dispatch.ladder_provenance()
-        assert prov["source"] == str(p)
-        if prov["platform"] == "cpu":
-            assert prov["families"]["decode_attn"]["recorded"] \
-                == "BENCH_r99"
-            assert prov["families"]["decode_attn"]["prefers"] == "pallas"
-            assert prov["families"]["ragged_attn"]["recorded"].startswith(
-                "override:ladder.json@")
-    finally:
-        monkeypatch.delenv("IPEX_LLM_TPU_DISPATCH_LADDER", raising=False)
-        dispatch.clear_cache()
-
-
-def test_ladder_tpu_dump_keying(tmp_path, monkeypatch):
-    """The builtin ladder holds no tpu rows (none was ever measured on a
-    chip, so every family takes the platform default there), and a
-    list-form collect() dump with NO interpret flags is a compiled-TPU
-    recording — it keys under "tpu" and stays invisible to CPU lookups,
-    which fall back to the platform default instead of applying TPU wins
-    to the interpreter."""
-    from ipex_llm_tpu.ops import dispatch
-
-    monkeypatch.delenv("IPEX_LLM_TPU_DISPATCH_LADDER", raising=False)
-    dispatch.clear_cache()
-    try:
-        assert "tpu" not in dispatch._BUILTIN_LADDER
-        # synthetic tpu-keyed dump: list rows without "interpret"
-        p = tmp_path / "tpu_ladder.json"
-        p.write_text(json.dumps([
-            {"op": "qmatmul_sym_int4_m1_k4096_n4096",
-             "pallas_us": 80.0, "xla_us": 20.0, "round": "BENCH_r77"},
-            {"op": "ragged_attn_b4_h8/4_s256_d64_bfloat16",
-             "pallas_us": 10.0, "xla_us": 30.0, "round": "BENCH_r77"},
-        ]))
-        monkeypatch.setenv("IPEX_LLM_TPU_DISPATCH_LADDER", str(p))
-        dispatch.clear_cache()
-        ladder = dispatch._ladder()
-        assert set(ladder) == {"tpu"}          # replaced, correctly keyed
-        assert ladder["tpu"]["qmatmul_sym_int4"]["xla_us"] == 20.0
-        assert ladder["tpu"]["ragged_attn"]["recorded"] == "BENCH_r77"
-        if dispatch.backend_platform() == "cpu":
-            # the tpu rows are never consulted on this host: the ladder
-            # is silent and the auto policy keeps the CPU default (XLA)
-            assert dispatch.ladder_prefers_pallas("ragged_attn") is None
-            assert dispatch.use_pallas_sharded("ragged_attn") is False
-            assert dispatch.ladder_provenance()["families"] == {}
-    finally:
-        monkeypatch.delenv("IPEX_LLM_TPU_DISPATCH_LADDER", raising=False)
-        dispatch.clear_cache()
-
-
-def test_bench_perf_stamp_shape(cfg_params):
-    from benchmark.serving_bench import _perf_stamp
-
-    cfg, params = cfg_params
-    eng = ServingEngine(cfg, params, EngineConfig(
-        max_rows=4, max_seq_len=256, page_size=32, prefill_bucket=32))
-    _drive(eng, Request(prompt_ids=_prompts(1, 8)[0], max_new_tokens=4))
-    stamp = _perf_stamp(eng)
-    assert stamp["compiles_warm"] == 0
-    assert stamp["compiles_out_of_grid"] == 0
-    assert stamp["mfu"] is None or stamp["mfu"] > 0
-    eng2 = ServingEngine(cfg, params, EngineConfig(
-        max_rows=4, max_seq_len=256, page_size=32, prefill_bucket=32,
-        perfwatch=False))
-    assert _perf_stamp(eng2) == {"mfu": None, "compiles_warm": None}
-
-
 # -- HTTP surfaces -----------------------------------------------------------
 
 def _serve(srv):
@@ -620,8 +525,8 @@ class _Tok:
 
 def test_health_metrics_perf_surface_e2e(cfg_params):
     """/health carries the perf block (families + sentinel + roofline)
-    and the dispatch ladder-provenance block; /metrics carries the
-    perf_* counters and the per-family attribution histogram series."""
+    and no ``dispatch`` block; /metrics carries the perf_* counters and
+    the per-family attribution histogram series."""
     pytest.importorskip("aiohttp")
     from ipex_llm_tpu.serving.api_server import OpenAIServer
 
@@ -646,9 +551,7 @@ def test_health_metrics_perf_surface_e2e(cfg_params):
         assert perf["ticks_attributed"] >= 1
         assert any(f.startswith("tick.") for f in perf["families"])
         assert perf["roofline"]["peak_flops"] is None    # CPU: no peaks
-        disp = h["dispatch"]
-        assert disp["source"] == "builtin"
-        assert all("recorded" in f for f in disp["families"].values())
+        assert "dispatch" not in h
 
         text = _get(port, "/metrics")
         assert "ipex_llm_tpu_perf_compiles_total" in text

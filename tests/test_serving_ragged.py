@@ -7,13 +7,9 @@ jitted program the engine module holds, and a request's token AND
 logprob bits do not depend on what else rides the tick (greedy and
 seeded sampled rows, H=1 and H=8), under bf16 AND fp8 KV storage.
 Plus: the dead-row scratch-route regression (stale
-device lens on masked rows must never corrupt live pages), the tightened
-host-sync budget, and the measured-ladder dispatch policy
-(ops/dispatch.py): on CPU-interpret environments the recorded microbench
-ladder must provably select the faster (XLA) backend.
+device lens on masked rows must never corrupt live pages) and the
+tightened host-sync budget.
 """
-
-import json
 
 import numpy as np
 import pytest
@@ -425,62 +421,3 @@ def test_attn_page_counters_follow_the_lengths_and_roll_back(cfg_params):
     again, eng = counters(inj)
     assert inj.fired == 1 and eng.metrics["retries"] == 1
     assert again == want
-
-
-# -- measured-ladder dispatch policy ----------------------------------------
-
-def test_dispatch_policy_selects_faster_backend_from_ladder(monkeypatch):
-    """On this CPU-interpret environment the recorded ladder (BENCH_r05's
-    interpret-vs-XLA rows) must provably select the XLA backend for every
-    measured decode op — and flipping the recorded numbers flips the
-    choice, proving the policy reads the data, not a hardcoded rule."""
-    from ipex_llm_tpu.ops import dispatch
-
-    monkeypatch.delenv("IPEX_LLM_TPU_FORCE_PALLAS", raising=False)
-    monkeypatch.delenv("IPEX_LLM_TPU_DISABLE_PALLAS", raising=False)
-    monkeypatch.delenv("IPEX_LLM_TPU_DISPATCH_LADDER", raising=False)
-    dispatch.clear_cache()
-    try:
-        assert dispatch.backend_platform() == "cpu"
-        for op in ("decode_attn", "decode_attn_fp8", "paged_decode_attn"):
-            assert dispatch.ladder_prefers_pallas(op) is False
-            assert dispatch.use_pallas(op) is False
-        # ops the ladder is silent on (no pair was ever measured for the
-        # ragged family) fall back to the platform rule
-        for op in ("ragged_attn", "ragged_attn_fp8", "unmeasured_op"):
-            assert dispatch.ladder_prefers_pallas(op) is None
-            assert dispatch.use_pallas(op) is False
-    finally:
-        dispatch.clear_cache()
-
-
-def test_dispatch_policy_is_data_driven(monkeypatch, tmp_path):
-    """A re-measured ladder (microbench collect() row dump) re-decides
-    the backend: recording pallas faster turns the kernel path on, and
-    the FORCE/DISABLE env overrides still outrank the data."""
-    from ipex_llm_tpu.ops import dispatch
-
-    rows = [{"op": "ragged_attn_r16_h32/8_s2048_w32_d128_bfloat16",
-             "pallas_us": 100.0, "xla_us": 300.0, "interpret": True},
-            {"op": "ragged_attn_r16_h32/8_s2048_w32_d128_float8_e5m2",
-             "pallas_us": 400.0, "xla_us": 300.0, "interpret": True}]
-    path = tmp_path / "ladder.json"
-    path.write_text(json.dumps(rows))
-    monkeypatch.delenv("IPEX_LLM_TPU_FORCE_PALLAS", raising=False)
-    monkeypatch.delenv("IPEX_LLM_TPU_DISABLE_PALLAS", raising=False)
-    monkeypatch.setenv("IPEX_LLM_TPU_DISPATCH_LADDER", str(path))
-    dispatch.clear_cache()
-    try:
-        assert dispatch.use_pallas("ragged_attn") is True
-        assert dispatch.use_pallas("ragged_attn_fp8") is False
-        monkeypatch.setenv("IPEX_LLM_TPU_DISABLE_PALLAS", "1")
-        dispatch.clear_cache()
-        assert dispatch.use_pallas("ragged_attn") is False
-        monkeypatch.delenv("IPEX_LLM_TPU_DISABLE_PALLAS")
-        monkeypatch.setenv("IPEX_LLM_TPU_FORCE_PALLAS", "1")
-        dispatch.clear_cache()
-        assert dispatch.use_pallas("ragged_attn_fp8") is True
-    finally:
-        monkeypatch.delenv("IPEX_LLM_TPU_FORCE_PALLAS", raising=False)
-        monkeypatch.delenv("IPEX_LLM_TPU_DISPATCH_LADDER", raising=False)
-        dispatch.clear_cache()

@@ -1171,7 +1171,7 @@ def test_chaos_gate_sigkill_one_of_three(tmp_path):
     casualty gets a terminal error object, zero hangs, zero duplicated
     tokens, and the restarted replica reinstates with the transitions
     visible in the router's aggregated health view."""
-    from benchmark.serving_bench import chaos_replicas
+    from tests.replica_chaos import chaos_replicas
 
     row, passed = chaos_replicas(n_reqs=8, n_out=24)
     assert passed, row
