@@ -284,7 +284,7 @@ def generate(
     tokens, lengths, tpad = pad_batch(input_ids, gen.pad_token_id)
     b = tokens.shape[0]
 
-    if cfg.ssm_layers and kv_kind in ("auto", "compress"):
+    if cfg.recurrent_layers and kv_kind in ("auto", "compress"):
         # SnapKV compression renumbers slots; recurrent state has none
         kv_kind = "fp8" if kv_mod.use_quantize_kv_cache() else "normal"
     if host_embed is not None and kv_kind == "auto":

@@ -124,7 +124,7 @@ def index_page_bytes(cfg, page_size: int, storage: str = "bf16") -> int:
     for a model without an indexer)."""
     if not cfg.index_topk:
         return 0
-    return paged_page_bytes(cfg.index_layers, 1, page_size,
+    return paged_page_bytes(cfg.index_layers, 1, page_size // cfg.index_kpool,
                             cfg.index_head_dim, v_head_dim=0,
                             storage=storage)
 
@@ -301,7 +301,7 @@ class PagedKVCache:
              n_kv_heads: int, page_size: int, head_dim: int,
              dtype=None, v_head_dim: int | None = None,
              storage: str | None = None, latent: bool = False,
-             index_layers: int = 0):
+             index_layers: int = 0, index_kpool: int = 1):
         """``storage`` selects the pool width ("bf16" | "fp8" e5m2); the
         whole access surface (encode/decode_layer/update/gather) keys off
         ``self.k.dtype``, so one class serves both formats — the serving
@@ -332,9 +332,13 @@ class PagedKVCache:
                     f"dtype {jnp.dtype(dtype).name} contradicts "
                     f"storage {storage!r} ({jnp.dtype(storage_dtype).name})")
         # ``index_layers``: a sparse latent pool, whose ``v`` holds the
-        # index key of that many layers, token-major
+        # index key of that many layers, token-major (a key a block of
+        # ``index_kpool`` tokens where they are pooled)
+        if page_size % index_kpool:
+            raise ValueError(f"page_size {page_size} is not a multiple of "
+                             f"index_kpool {index_kpool}")
         v_page = ((vd, page_size) if latent and not index_layers
-                  else (page_size, vd))
+                  else (page_size // index_kpool, vd))
         return cls(
             k=jnp.zeros((n_layers, n_pages, n_kv_heads, page_size, head_dim),
                         dtype),
@@ -356,7 +360,8 @@ class PagedKVCache:
         return cls.init(cfg.kv_layers, n_pages, n_rows, max_pages, heads,
                         page_size, kd, v_head_dim=vd, storage=storage,
                         latent=cfg.is_mla,
-                        index_layers=cfg.index_layers if cfg.is_mla else 0)
+                        index_layers=cfg.index_layers if cfg.is_mla else 0,
+                        index_kpool=cfg.index_kpool)
 
     @property
     def page_size(self) -> int:
@@ -502,7 +507,7 @@ class PagedKVCache:
         if token_minor:      # a latent pool's ``v``: [.., rope, page]
             g = g.transpose(0, 1, 2, 4, 3)
         g = g.transpose(0, 2, 1, 3, 4)
-        return g.reshape(r, g.shape[1], maxp * self.page_size, g.shape[4])
+        return g.reshape(r, g.shape[1], maxp * g.shape[3], g.shape[4])
 
     def advanced(self, n):
         return replace(self, length=self.length + n)
@@ -525,32 +530,71 @@ STATE_COUNTERS = MOE_COUNTERS + ("ssm_state_resets",)
 DSA_COUNTERS = ("dsa_keys_scored", "dsa_tokens_visible",
                 "dsa_tokens_selected", "dsa_full_layer_calls",
                 "dsa_shared_layer_calls")
+# (where the index keys are pooled, ``dsa_keys_scored`` counts BLOCKS scored
+# and ``dsa_tokens_selected`` the positions attended: the chosen blocks'
+# and the always-attended tail's)
+# and what a model with Kimi Delta Attention layers counts (ops/kda.py):
+# tokens through the chunked form and rows stepped, summed over its layers
+KDA_COUNTERS = ("kda_chunk_tokens", "kda_step_rows")
 
 
 def state_counters(cfg) -> tuple[str, ...]:
-    """The device counters ``cfg``'s state carries, in their order."""
-    return STATE_COUNTERS + (DSA_COUNTERS if cfg.index_topk else ())
+    """The device counters ``cfg``'s state carries, in their order (the
+    indexer's last: models/decoder.py adds them from the end)."""
+    return (STATE_COUNTERS + (KDA_COUNTERS if cfg.kda_layers else ())
+            + (DSA_COUNTERS if cfg.index_topk else ()))
 
 
 def recurrent_state(cfg, rows: int) -> dict | None:
-    """The recurrent state of ``cfg``'s Mamba-2 blocks for ``rows`` rows,
-    zeroed: ``h`` [M blocks, rows, H, P, N] float32, ``conv`` [M blocks,
-    rows, K-1, conv width] bf16 (the last K-1 conv inputs) and the
-    ``stats`` counters (STATE_COUNTERS).  A model without Mamba-2 blocks
-    whose expert layers hold a SHARE of their experts
-    (``cfg.moe_experts_held``) carries the counters alone: they ride the
-    state so that a rolled-back tick's are dropped with it.  None where the
-    model has neither."""
-    stats = jnp.zeros((len(state_counters(cfg)),), jnp.int32)
-    if not cfg.ssm_layers:
-        return {"stats": stats} if cfg.moe_experts_held else None
-    return {
-        "h": jnp.zeros((cfg.ssm_layers, rows, cfg.ssm_num_heads,
-                        cfg.ssm_head_dim, cfg.ssm_state_size), jnp.float32),
-        "conv": jnp.zeros((cfg.ssm_layers, rows, cfg.ssm_conv_kernel - 1,
-                           cfg.ssm_conv_dim), jnp.bfloat16),
-        "stats": stats,
-    }
+    """The per-ROW state ``cfg`` carries beside its paged pool for ``rows``
+    rows, zeroed, by what its layers are:
+
+    - Mamba-2 blocks (``cfg.ssm_layers``): ``h`` [M blocks, rows, H, P, N]
+      float32 and ``conv`` [M blocks, rows, K-1, conv width] bf16 (the last
+      K-1 conv inputs);
+    - Kimi Delta Attention layers (``cfg.kda_layers``; ops/kda.py):
+      ``kda_s`` [KDA layers, rows, H, D, D] float32 (the delta-rule state,
+      keys x values) and ``kda_conv`` [KDA layers, rows, K-1, 3 H D] bf16
+      (the conv tails of q, k and v side by side);
+    - an indexer over POOLED keys (``cfg.index_kpool`` > 1): ``ix_tail``
+      [full layers, rows, kpool-1, index dim] bf16, the index keys of a
+      row's incomplete block (how many are live follows from the row's
+      length);
+    - always the ``stats`` counters (:func:`state_counters`).
+
+    A model with none of the three whose expert layers hold a SHARE of
+    their experts (``cfg.moe_experts_held``) carries the counters alone:
+    they ride the state so that a rolled-back tick's are dropped with it.
+    None where the model has nothing to carry."""
+    state = {}
+    if cfg.ssm_layers:
+        state["h"] = jnp.zeros(
+            (cfg.ssm_layers, rows, cfg.ssm_num_heads, cfg.ssm_head_dim,
+             cfg.ssm_state_size), jnp.float32)
+        state["conv"] = jnp.zeros(
+            (cfg.ssm_layers, rows, cfg.ssm_conv_kernel - 1,
+             cfg.ssm_conv_dim), jnp.bfloat16)
+    if cfg.kda_layers:
+        heads, d = cfg.kda_num_heads, cfg.kda_head_dim
+        state["kda_s"] = jnp.zeros((cfg.kda_layers, rows, heads, d, d),
+                                   jnp.float32)
+        state["kda_conv"] = jnp.zeros(
+            (cfg.kda_layers, rows, cfg.kda_conv_kernel - 1, 3 * heads * d),
+            jnp.bfloat16)
+    if cfg.index_topk and cfg.index_kpool > 1:
+        state["ix_tail"] = jnp.zeros(
+            (cfg.index_layers, rows, cfg.index_kpool - 1,
+             cfg.index_head_dim), jnp.bfloat16)
+    if not state and not cfg.moe_experts_held:
+        return None
+    state["stats"] = jnp.zeros((len(state_counters(cfg)),), jnp.int32)
+    return state
+
+
+def state_leaves(state) -> dict:
+    """{leaf: [shape, dtype]} of a cache's recurrent state, for /health."""
+    return {name: [list(x.shape), str(x.dtype)]
+            for name, x in sorted((state or {}).items())}
 
 
 def with_recurrent_state(cache, cfg, rows: int):

@@ -351,6 +351,32 @@ def _mamba_tensors(get, m: str, packed) -> dict:
             "out_proj": packed(get(m + "out_proj.weight"))}
 
 
+def _kda_tensors(get, pre: str, qtype: str) -> dict:
+    """One Kimi Delta Attention mixer's tensors as ops/kda.py reads them,
+    from the checkpoint names under the prefix ``pre``: ``in_proj`` = the
+    q, k and v projections, the two gates' down projections and the write
+    strength's, side by side [hidden, 3 H D + D + D + H]; ``conv_w`` [K, 3 H
+    D] (tap j weighs the input K-1-j positions back; q's, k's, v's channels
+    side by side); the gates' up projections ``f_b``, ``g_b`` [D, H D];
+    ``A_log`` [H], ``dt_bias`` [H D], the per-head output norm ``o_norm``
+    [D] and ``o``."""
+    def f32(name: str):
+        return jnp.asarray(get(pre + name), NORM_DTYPE)
+
+    def q(name: str):
+        return quantize_weight(get(pre + name), qtype)
+
+    ins = ("q_proj", "k_proj", "v_proj", "f_a_proj", "g_a_proj", "b_proj")
+    conv = np.concatenate([np.asarray(get(pre + f"{p}_conv1d.weight"))[:, 0]
+                           for p in "qkv"], axis=0)                # [C, K]
+    return {"in_proj": quantize_weight(np.concatenate(
+                [get(pre + p + ".weight") for p in ins], axis=0), qtype),
+            "conv_w": jnp.asarray(conv.T, NORM_DTYPE),
+            "f_b": q("f_b_proj.weight"), "g_b": q("g_b_proj.weight"),
+            "A_log": f32("A_log"), "dt_bias": f32("dt_bias"),
+            "o_norm": f32("o_norm.weight"), "o": q("o_proj.weight")}
+
+
 def _hyper_connection(get, i: int, sub: str) -> dict:
     """One sublayer's hyper-connection tensors as ops/hyper.py reads them:
     ``hc_<sub>_proj`` float32 [n + n + n*n, n*C] (rows: the pre-mix's, the
@@ -438,6 +464,9 @@ def build_params(
             lp[key + "_bias"] = jnp.asarray(get(bias_name), NORM_DTYPE)
 
     layers = []
+    # a model whose mixer kind varies by layer (cfg.mixer_types): layer ->
+    # (kind, that mixer's tensors), stacked per kind (``stack_of``)
+    kind_trees: dict[int, tuple[str, dict]] = {}
     for i in range(cfg.num_layers):
         if progress:
             progress(f"layer {i + 1}/{cfg.num_layers}")
@@ -454,9 +483,16 @@ def build_params(
             if t is not None:
                 lp[key] = jnp.asarray(t, NORM_DTYPE)
 
+        # --- a layer whose mixer is Kimi Delta Attention (cfg.mixer_types):
+        # its tensors go to the ``kda`` stack, and it has no attention
+        kda_here = (cfg.mixer_types is not None
+                    and cfg.mixer_types[i] == "kda")
+        if kda_here:
+            kind_trees[i] = ("kda", _kda_tensors(
+                get, f"model.layers.{i}.linear_attn.", qtype))
         # --- MLA (deepseek): low-rank q + compressed-kv projections; no
         # qkv merge possible (kv_b applies to the compressed latent)
-        if scheme.kv_a is not None and cfg.is_mla:
+        elif scheme.kv_a is not None and cfg.is_mla:
             if cfg.q_lora_rank is None:
                 lp["q"] = quantize_weight(get(name(scheme.q, i)), qtype)
                 qb = get_opt(name(scheme.q, i, "bias"))
@@ -514,18 +550,24 @@ def build_params(
                     ]
             qkv_w = np.concatenate([qw, kw, vw], axis=0)  # [out_total, in]
             qkv_b = np.concatenate(bs) if bs[0] is not None else None
-        if not (scheme.kv_a is not None and cfg.is_mla):
+        if not (scheme.kv_a is not None and cfg.is_mla) and not kda_here:
             lp["qkv"] = quantize_weight(
                 qkv_w, qtype, imatrix=_imx(imatrix_data, i, "qkv"))
             if qkv_b is not None:
                 lp["qkv_bias"] = jnp.asarray(qkv_b, jnp.float32)
 
-        ow = getp(name(scheme.o, i))
-        lp["o"] = quantize_weight(ow, qtype,
-                                  imatrix=_imx(imatrix_data, i, "o"))
-        ob = get_opt(name(scheme.o, i, "bias"))
-        if ob is not None:
-            lp["o_bias"] = jnp.asarray(ob, jnp.float32)
+        if not kda_here:
+            ow = getp(name(scheme.o, i))
+            lp["o"] = quantize_weight(ow, qtype,
+                                      imatrix=_imx(imatrix_data, i, "o"))
+            ob = get_opt(name(scheme.o, i, "bias"))
+            if ob is not None:
+                lp["o_bias"] = jnp.asarray(ob, jnp.float32)
+            if cfg.mixer_types is not None:
+                # the attention's tensors go to the ``mla`` stack
+                kind_trees[i] = ("mla", {k_: lp.pop(k_) for k_ in list(lp)
+                                         if k_ not in ("attn_norm",
+                                                       "mlp_norm")})
 
         # --- MoE block (mixtral/qwen-moe): per-expert QTensors stacked on a
         # leading E axis, scanned (or ep-sharded) in the decoder
@@ -662,6 +704,11 @@ def build_params(
         mine = [indexers[i] for i in range(lo, hi) if i in indexers]
         if mine:
             tree["indexer"] = stack_layer_trees(mine)
+        for kind in ("kda", "mla"):
+            mine = [kind_trees[i][1] for i in range(lo, hi)
+                    if kind_trees.get(i, ("",))[0] == kind]
+            if mine:
+                tree[kind] = stack_layer_trees(mine)
         return tree
 
     # deepseek-style dense prefix: the first ``moe_layer_start`` layers have

@@ -175,6 +175,26 @@ class ModelConfig:
     hc_eps: float = 0.0
     hc_res_clamp: tuple[float, float] = (0.0, 0.0)
 
+    # a per-layer MIXER kind (glm5_next_text): "kda" (Kimi Delta Attention,
+    # ops/kda.py: a delta-rule state ``kda_num_heads`` x ``kda_head_dim`` x
+    # ``kda_head_dim`` float32 a row a layer, no KV) or "mla" (latent
+    # attention, with its indexer where ``index_topk``).  None: every layer
+    # attends.  The paged pool holds the "mla" layers alone and the recurrent
+    # state the "kda" ones (``kv_layers``, ``kda_layers``)
+    mixer_types: tuple[str, ...] | None = None
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
+    kda_gate_lower_bound: float = -5.0
+    # the indexer's keys POOLED: one index key a complete block of
+    # ``index_kpool`` positions (their mean); a query scores blocks, keeps
+    # ``index_topk / index_kpool`` of them and always attends the positions
+    # after the last complete block.  1: a key a token
+    index_kpool: int = 1
+    # ``swiglu_limit``: silu(min(gate, l)) * clip(up, -l, l) in every gated
+    # MLP and expert.  None: no clamp
+    mlp_act_limit: float | None = None
+
     def layer_is_sliding(self, layer_idx: int) -> bool:
         if self.layer_types is not None:
             return self.layer_types[layer_idx] == "sliding_attention"
@@ -188,6 +208,8 @@ class ModelConfig:
         """Layers the KV cache holds: the attention blocks."""
         if self.hybrid_pattern is not None:
             return sum(self.hybrid_pattern.count(c) for c in "*P")
+        if self.mixer_types is not None:
+            return sum(t == "mla" for t in self.mixer_types)
         return self.num_layers
 
     @property
@@ -199,6 +221,25 @@ class ModelConfig:
     def ssm_layers(self) -> int:
         """Blocks that carry recurrent state (0: the cache is KV alone)."""
         return sum((self.hybrid_pattern or "").count(c) for c in "MP")
+
+    @property
+    def kda_layers(self) -> int:
+        """Layers whose mixer is Kimi Delta Attention (a state slot each)."""
+        return sum(t == "kda" for t in self.mixer_types or ())
+
+    @property
+    def recurrent_layers(self) -> int:
+        """Layers that carry per-row recurrent state, of either kind."""
+        return self.ssm_layers + self.kda_layers
+
+    @property
+    def chunk_grid(self) -> int:
+        """The block a prompt of this model is cut at multiples of (its last
+        piece apart): the chunked recurrence's block, so that a token's bits
+        do not depend on how its prompt was cut (ops/kda.py).  0: any cut."""
+        from ipex_llm_tpu.ops.kda import CHUNK
+
+        return CHUNK if self.kda_layers else 0
 
     @property
     def ssm_conv_dim(self) -> int:

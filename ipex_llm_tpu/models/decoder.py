@@ -33,7 +33,7 @@ from ipex_llm_tpu.ops.attention import (cached_sdpa, index_scores,
                                         latent_attention, paged_kv_write,
                                         paged_pool_write, select_topk,
                                         sparse_latent_attention)
-from ipex_llm_tpu.ops.norms import layer_norm, rms_norm
+from ipex_llm_tpu.ops.norms import _rows, layer_norm, rms_norm
 from ipex_llm_tpu.quantize.core import (QLayer, QTensor, dequantize,
                                         layer_views)
 
@@ -182,12 +182,16 @@ def _mla_block(cfg: ModelConfig, lp: dict, x, h, kl, vl, cos, sin, q_slots,
                 q = linear_ops.linear(h, lp["q"], lp.get("q_bias"))
             q = q.reshape(b, t, cfg.num_heads, cfg.head_dim)
             q_nope = q[..., :nope]
-            q_pe = rope_ops.apply_rope(q[..., nope:], cos, sin, "two")
+            # (``qk_rope_head_dim`` 0: a position-free layer, whose rotated
+            # slices are empty and rotate by nothing)
+            q_pe = (rope_ops.apply_rope(q[..., nope:], cos, sin, "two")
+                    if rd_pe else q[..., nope:])
         with jax.named_scope("mla/latent"):
             ckv = linear_ops.linear(h, lp["kv_a"], lp.get("kv_a_bias"))
             c = rms_norm(ckv[..., :rank], lp["kv_a_norm"], cfg.norm_eps)
-            k_pe = rope_ops.apply_rope(ckv[..., rank:][:, :, None, :], cos,
-                                       sin, "two")              # [B,T,1,rd]
+            k_pe = ckv[..., rank:][:, :, None, :]               # [B,T,1,rd]
+            if rd_pe:
+                k_pe = rope_ops.apply_rope(k_pe, cos, sin, "two")
             if dsa is not None:
                 # the pool's row is the whole key a token: [c ; k_r ; 0]
                 row = jnp.concatenate([c[:, :, None, :], k_pe], axis=-1)
@@ -201,7 +205,7 @@ def _mla_block(cfg: ModelConfig, lp: dict, x, h, kl, vl, cos, sin, q_slots,
         if collect_obs:
             raise NotImplementedError(
                 "SnapKV observation windows over a sparse latent pool")
-        attn, vl, sel, d_stats = _sparse_mla(
+        attn, vl, sel, d_stats, *rec = _sparse_mla(
             cfg, lp, dsa, h, c_q, q_nope, q_pe, kl, vl, cos, sin, q_slots,
             kv_len, chunk_lens, cache, layer)
         attn = attn.astype(x.dtype)
@@ -252,7 +256,7 @@ def _mla_block(cfg: ModelConfig, lp: dict, x, h, kl, vl, cos, sin, q_slots,
         if cfg.post_attn_norm:
             out = _norm(out, lp["post_attn_norm"], cfg)
     if dsa is not None:
-        return out, kl, vl, obs_q, sel, d_stats
+        return out, kl, vl, obs_q, sel, d_stats, *rec
     return out, kl, vl, obs_q
 
 
@@ -281,7 +285,9 @@ def _sparse_mla(cfg: ModelConfig, lp: dict, dsa, h, c_q, q_nope, q_pe, kl, vl,
     (kv.py ``pool_geometry``: ``kl`` the latent rows, already holding this
     call's tokens; ``vl`` the index keys of the full layers).  ``dsa`` is
     ``(is_full, entry of the indexer stack, the full layer's index in the
-    index-key pool, the selection carried from below)``.
+    index-key pool, the selection carried from below)``, and for an indexer
+    over POOLED keys (``cfg.index_kpool`` > 1) a fifth entry, the recurrent
+    state's call (:func:`_pool_index_keys`).
 
     A FULL layer runs its indexer: 32 small heads off the low-rank query
     ``c_q`` it shares with the main heads, a LayerNormed index key a token
@@ -293,34 +299,50 @@ def _sparse_mla(cfg: ModelConfig, lp: dict, dsa, h, c_q, q_nope, q_pe, kl, vl,
     an fp8 product: an orthogonal map that leaves every dot product as it
     is, left out here (bf16 products).
 
+    POOLED keys: the pool holds one key a complete block of ``index_kpool``
+    positions, the mean of its keys; a query scores the complete blocks
+    that end before it, keeps the ``index_topk / index_kpool`` best and
+    attends their positions AND every position after its last complete
+    block, its own among them, unscored.
+
     Returns (attn [B*T, H, v], the index-key pool, the selection, int32 [5]
-    counters: keys scored, positions visible, positions selected, full
-    calls, shared calls)."""
+    counters: keys (or blocks) scored, positions visible, positions
+    selected, full calls, shared calls[, the recurrent state's leaves])."""
     b, t = h.shape[:2]
-    is_full, indexer, fi, sel = dsa
+    is_full, indexer, fi, sel, *rec = dsa
     rd = cfg.qk_rope_head_dim
+    kp = cfg.index_kpool
     if chunk_lens is None:
         chunk_lens = jnp.full((b,), t, jnp.int32)
     asks = jnp.arange(t)[None, :] < chunk_lens[:, None]   # attending queries
     visible = jnp.sum(jnp.where(asks, q_slots + 1, 0)).astype(jnp.int32)
 
     def rope_head(z):
+        if not rd:
+            return z
         return jnp.concatenate(
             [rope_ops.apply_rope(z[..., :rd], cos, sin, "two"), z[..., rd:]],
             axis=-1)
 
-    def full(vl, sel):
+    def index_call():
+        """(query heads, index key a token, head weights) of this call."""
         ix = indexer()
+        q_i = rope_head(linear_ops.linear(c_q, ix["wq_b"]).reshape(
+            b, t, cfg.index_n_heads, cfg.index_head_dim))
+        k_i = rope_head(layer_norm(
+            linear_ops.linear(h, ix["wk"]), ix["k_norm"],
+            ix["k_norm_bias"], _INDEX_NORM_EPS)[:, :, None, :])
+        # (the tokens as one axis, as the router's product is)
+        w_i = jnp.matmul(
+            _rows(h).astype(jnp.float32), ix["w_proj"],
+            precision=jax.lax.Precision.HIGHEST
+        ).reshape(b, t, -1) * (
+            cfg.index_n_heads ** -0.5 * cfg.index_head_dim ** -0.5)
+        return q_i, k_i, w_i
+
+    def full(vl, sel):
         with jax.named_scope("dsa/index"):
-            q_i = rope_head(linear_ops.linear(c_q, ix["wq_b"]).reshape(
-                b, t, cfg.index_n_heads, cfg.index_head_dim))
-            k_i = rope_head(layer_norm(
-                linear_ops.linear(h, ix["wk"]), ix["k_norm"],
-                ix["k_norm_bias"], _INDEX_NORM_EPS)[:, :, None, :])
-            w_i = jnp.matmul(
-                h.astype(jnp.float32), ix["w_proj"],
-                precision=jax.lax.Precision.HIGHEST
-            ) * (cfg.index_n_heads ** -0.5 * cfg.index_head_dim ** -0.5)
+            q_i, k_i, w_i = index_call()
             vl = paged_pool_write(cache, vl, fi, k_i, q_slots[:, 0])
             # (``dsa/score`` nests here: the indexer's whole cost is one
             # scope, its scoring a part of it)
@@ -332,13 +354,48 @@ def _sparse_mla(cfg: ModelConfig, lp: dict, dsa, h, c_q, q_nope, q_pe, kl, vl,
             return vl, (*chosen, n_sel), visible
         return vl, (chosen, jnp.sum(chosen, axis=-1, dtype=jnp.int32)), visible
 
+    def pooled(vl, src, rows, fresh, state):
+        with jax.named_scope("dsa/index"):
+            q_i, k_i, w_i = index_call()
+            with jax.named_scope("dsa/pool"):
+                vl, state = _pool_index_keys(
+                    cfg, cache, vl, fi, k_i[:, :, 0], q_slots, chunk_lens,
+                    src, rows, fresh, state)
+            scores = index_scores(q_i, w_i, vl, cache, q_slots, kv_len,
+                                  layer=fi, kpool=kp)
+        chosen = select_topk(scores, cfg.index_topk // kp)
+        # the chosen blocks' positions, and the tail's: every position from
+        # the query's last incomplete block's first on, up to its own
+        first = (q_slots // kp) * kp                               # [B, T]
+        if t == 1:
+            blk, ok = chosen
+            idx = (blk[..., None] * kp + jnp.arange(kp)).reshape(b, -1)
+            tail = first + jnp.arange(kp)[None, :]
+            idx = jnp.concatenate([idx, tail], axis=-1).astype(jnp.int32)
+            ok = jnp.concatenate(
+                [jnp.repeat(ok, kp, axis=-1), tail <= q_slots], axis=-1)
+            picked = (idx, ok, jnp.sum(ok, axis=-1, dtype=jnp.int32)[:, None])
+        else:
+            s_len = kl.shape[3] * cache.tables.shape[1]
+            pos = jnp.arange(s_len)[None, None, :]
+            mask = (jnp.repeat(chosen, kp, axis=-1)
+                    | ((pos >= first[..., None])
+                       & (pos <= q_slots[..., None])))
+            picked = (mask, jnp.sum(mask, axis=-1, dtype=jnp.int32))
+        scored = jnp.sum(jnp.where(asks, q_slots // kp, 0)).astype(jnp.int32)
+        return vl, picked, scored, state
+
     def shared(vl, sel):
         return vl, sel, jnp.zeros((), jnp.int32)
 
     with jax.named_scope("layer/attn"):
         # (a tree of shared layers alone holds no indexer stack)
-        vl, sel, scored = (shared(vl, sel) if indexer is None else
-                           jax.lax.cond(is_full, full, shared, vl, sel))
+        if kp > 1:
+            vl, sel, scored, state = pooled(vl, *rec[0])
+            rec = [state]
+        else:
+            vl, sel, scored = (shared(vl, sel) if indexer is None else
+                               jax.lax.cond(is_full, full, shared, vl, sel))
         with jax.named_scope("mla/absorb"):
             q_lat, w_kvb = _absorb_q(cfg, lp, q_nope)
             q_row = jnp.concatenate(
@@ -359,7 +416,73 @@ def _sparse_mla(cfg: ModelConfig, lp: dict, dsa, h, c_q, q_nope, q_pe, kl, vl,
     stats = jnp.stack([
         scored, visible, jnp.sum(jnp.where(asks, sel[-1], 0)).astype(
             jnp.int32), full_i, 1 - full_i])
-    return attn, vl, sel, stats
+    return (attn, vl, sel, stats, *rec)
+
+
+def _pool_index_keys(cfg: ModelConfig, cache, vl, fi, k_i, q_slots,
+                     chunk_lens, src, rows, fresh, state):
+    """The POOLED index cache of a full layer advanced by this call's index
+    keys ``k_i`` [B, T, D]: every block of ``cfg.index_kpool`` positions that
+    this call completes gets its key, the mean of its keys (float32, term by
+    term, stored in the pool's type), written to the index-key pool ``vl``
+    at ``(fi, page, block in page)``; the keys of a row's incomplete block
+    wait in ``state["ix_tail"]`` [full layers, R, kpool-1, D] (read from
+    ``src``, the tick's source buffer).  How many wait follows from the
+    row's length, so a tail is never cleared.  A decode row (T == 1) adds
+    one key to its tail or closes its block with it; a chunk starts on a
+    block's edge (the prompt grid, ``cfg.chunk_grid``), pools its whole
+    blocks and leaves its last keys in the tail.  ``rows``/``fresh``: the
+    prefill block's map to state rows (models/decoder.py ``run_layers``).
+    Returns (the pool, the state)."""
+    kp = cfg.index_kpool
+    b, t, d = k_i.shape
+    ps = vl.shape[3] * kp                    # tokens a page
+    n_pages = vl.shape[1]
+    f32 = jnp.float32
+    base = q_slots[:, 0]
+    tails = (src or state)["ix_tail"]
+    if rows is None:
+        tail = jax.lax.dynamic_index_in_dim(tails, fi, 0, False)
+    else:
+        tail = tails[fi, jnp.clip(rows, 0, tails.shape[1] - 1)]
+        tail = jnp.where(fresh[:, None, None], 0, tail)
+
+    def mean(parts):
+        total = parts[0].astype(f32)
+        for part in parts[1:]:
+            total = total + part.astype(f32)
+        return (total / kp).astype(vl.dtype)
+
+    if t == 1 and rows is None:
+        alive = chunk_lens > 0
+        m = base % kp
+        key = k_i[:, 0]
+        closes = alive & (m == kp - 1)
+        pooled = mean([tail[:, j] for j in range(kp - 1)] + [key])[:, None]
+        blocks = base[:, None]
+        done = closes[:, None]
+        waits = (alive & (m < kp - 1))[:, None, None] & (
+            jnp.arange(kp - 1)[None, :, None] == m[:, None, None])
+        new_tail = jnp.where(waits, key[:, None, :].astype(tail.dtype), tail)
+    else:
+        pad = -t % kp
+        kb = jnp.pad(k_i, ((0, 0), (0, pad), (0, 0))).reshape(b, -1, kp, d)
+        pooled = mean([kb[:, :, j] for j in range(kp)])       # [B, T/kp, D]
+        first = jnp.arange(kb.shape[1])[None, :] * kp
+        blocks = base[:, None] + first
+        done = first + kp <= chunk_lens[:, None]
+        whole = (chunk_lens // kp) * kp
+        at = jnp.clip(whole[:, None] + jnp.arange(kp - 1)[None, :], 0, t - 1)
+        new_tail = jnp.take_along_axis(
+            k_i, at[..., None], axis=1).astype(tail.dtype)
+    pages = jnp.where(done, cache.route_pages(blocks // ps), n_pages)
+    vl = vl.at[fi, pages, 0, (blocks % ps) // kp].set(pooled, mode="drop")
+    if rows is None:
+        tails = jax.lax.dynamic_update_index_in_dim(
+            state["ix_tail"], new_tail, fi, 0)
+    else:
+        tails = state["ix_tail"].at[fi, rows].set(new_tail, mode="drop")
+    return vl, dict(state, ix_tail=tails)
 
 
 def no_selection(cfg: ModelConfig, b: int, t: int, s: int):
@@ -370,6 +493,10 @@ def no_selection(cfg: ModelConfig, b: int, t: int, s: int):
     n_sel = jnp.zeros((b, t), jnp.int32)
     if t == 1:
         k = min(cfg.index_topk, s)
+        if cfg.index_kpool > 1:
+            # the chosen blocks' positions and the always-attended tail's
+            kp = cfg.index_kpool
+            k = min(cfg.index_topk // kp, s // kp) * kp + kp
         return (jnp.zeros((b, k), jnp.int32), jnp.zeros((b, k), bool), n_sel)
     return jnp.zeros((b, t, s), bool), n_sel
 
@@ -577,7 +704,8 @@ def _mlp_block(cfg: ModelConfig, lp: dict, x, pre_normed=False):
         else:
             gate = linear_ops.linear(h, lp["gate"], lp.get("gate_bias"))
             up = linear_ops.linear(h, lp["up"], lp.get("up_bias"))
-        inner = mlp_ops.gated_act_mul(linear_ops.times(gate, gate_m), up, cfg.act)
+        inner = mlp_ops.gated_act_mul(linear_ops.times(gate, gate_m), up,
+                                      cfg.act, cfg.mlp_act_limit)
     out = linear_ops.times(linear_ops.linear(inner, lp["down"], lp.get("down_bias")),
                  down_m)
     if cfg.norm_after:
@@ -608,8 +736,14 @@ def _held_moe_block(cfg: ModelConfig, lp: dict, x, valid, shared: bool = True,
     b, t, hidden = x.shape
     h = _norm(x, lp["mlp_norm"], cfg)
     with jax.named_scope("moe/router"):
-        logits = jnp.matmul(h.astype(jnp.float32), lp["router"],
-                            precision=jax.lax.Precision.HIGHEST)
+        # the tokens as ONE axis behind the barrier of ops/norms.py: on the
+        # chip XLA plans this float32 product by how the leading dimensions
+        # factor ([8, 64] tokens gave other bits than [1, 512]: PERF.md
+        # section 6, PR 50), and a token's routing weights must not depend
+        # on the block it rides in
+        logits = jnp.matmul(_rows(h).astype(jnp.float32), lp["router"],
+                            precision=jax.lax.Precision.HIGHEST
+                            ).reshape(b, t, -1)
         w, idx = _route(cfg, lp, logits)                     # [B, T, k]
         held = jax.tree_util.tree_leaves(lp["moe_gate_up"])[0].shape[
             0 if layer is None else 1]
@@ -624,14 +758,15 @@ def _held_moe_block(cfg: ModelConfig, lp: dict, x, valid, shared: bool = True,
             h.reshape(b * t, hidden), gate.reshape(b * t, held),
             lp["moe_gate_up"], lp["moe_down"], cfg.act, gated=cfg.mlp_gated,
             layer=layer, top_k=cfg.num_experts_per_tok,
-            n_routed=cfg.num_experts)
+            n_routed=cfg.num_experts, limit=cfg.mlp_act_limit)
         out = out.reshape(b, t, hidden)
     if shared and "shared_gate_up" in lp:
         with jax.named_scope("moe/shared"):
             inner = linear_ops.linear(h, lp["shared_gate_up"])
             if cfg.mlp_gated:
                 inner = mlp_ops.gated_act_mul(
-                    *mlp_ops.split_gate_up(inner), cfg.act)
+                    *mlp_ops.split_gate_up(inner), cfg.act,
+                    cfg.mlp_act_limit)
             else:
                 inner = mlp_ops.act(inner, cfg.act)
             out = out + linear_ops.linear(
@@ -929,7 +1064,8 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
                x, cos, sin, slot0, q_slots, kv_len, kv_start, cache,
                collect_obs: int = 0, alibi_bias=None,
                cos_local=None, sin_local=None, chunk_lens=None,
-               layer_offset: int = 0, valid=None, selection=None):
+               layer_offset: int = 0, valid=None, selection=None,
+               recurrent=None):
     """Scan one stacked layer tree over its layers of the cache.
 
     The single compiled layer body shared by decoder_forward and the
@@ -975,13 +1111,57 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
     ``x`` as ``[n, B, T, C]``, the streams leading (ops/hyper.py): each
     sublayer reads the mix ``h`` [B, T, C] its coefficients give and the
     streams are re-mixed around its output, where another model adds.
+
+    A model whose MIXER KIND varies by layer (``cfg.mixer_types``; over its
+    sparse latent pool, ``selection`` given) is walked by the same scan: a
+    "kda" layer runs Kimi Delta Attention (ops/kda.py) where an "mla" layer
+    attends, under one ``lax.cond`` a layer.  The weights are stacked per
+    KIND: the tree's ``kda`` and ``mla`` sub-trees hold an entry a layer of
+    that kind and are read at the layer's index among its kind, as the
+    ``indexer`` stack is; what every layer has (norms, the hyper-
+    connections' planes, the MLP or expert layer) stays in the tree itself.
+    The pool holds the "mla" layers alone, so such a layer addresses pool
+    layer ``index among the model's mla layers``.  ``recurrent`` = ``(src,
+    rows, fresh, state)`` is the per-row state's call: ``state`` the leaves
+    that are written (``kv.recurrent_state`` without its counters), carried
+    beside the selection; ``src`` the tick's read-only source buffer (None:
+    read what is written); ``rows``/``fresh`` a prefill block's map to state
+    rows and which of them start from zero (None: entry i is row i).  The
+    return gains ``(state, int32 [2] kv.KDA_COUNTERS)``.
     """
     n = sliding_flags.shape[0]
     dsa = selection is not None
+    kinds = (cfg.mixer_types[layer_offset:layer_offset + n]
+             if cfg.mixer_types else None)
+    if kinds is not None and (not dsa or recurrent is None):
+        raise ValueError(
+            f"{cfg.model_type} mixes layer kinds over per-row recurrent "
+            "state and a sparse latent pool, which only the serving path "
+            "carries (ServingEngine / build_server)")
     ix_tree = tree.get("indexer")
     if ix_tree is not None:
         # (a dense cache attends every token and never reads it)
         tree = {k_: v_ for k_, v_ in tree.items() if k_ != "indexer"}
+    if kinds is not None:
+        from ipex_llm_tpu.ops import kda as kda_ops
+
+        src, state_rows, fresh, _ = recurrent
+        is_kda = jnp.asarray([k_ == "kda" for k_ in kinds])
+        # a layer's entry of this tree's stack of its kind, and its slot in
+        # the model's state (the layers of its kind below this tree first)
+        kind_local = jnp.where(
+            is_kda, jnp.cumsum(is_kda) - 1, jnp.cumsum(~is_kda) - 1
+        ).astype(jnp.int32)
+        kda_below = sum(k_ == "kda" for k_ in cfg.mixer_types[:layer_offset])
+        kind_xs = {k_: layer_views(tree[k_]) for k_ in ("kda", "mla")
+                   if k_ in tree}
+        tree = {k_: v_ for k_, v_ in tree.items() if k_ not in kind_xs}
+
+        def of_kind(kind, j):
+            xs, view_ = kind_xs[kind]
+            return view_(jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, j, 0, False), xs),
+                j)
     if dsa:
         types = cfg.indexer_types[layer_offset:layer_offset + n]
         is_full = jnp.asarray([t_ == "full" for t_ in types])
@@ -1010,7 +1190,39 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
         block = _moe_block if "moe_gate_up" in lp else _mlp_block
         return block(cfg, lp, x), no_stats
 
-    def layer_step(x, lp, kl, vl, sliding, layer, i, dsa_call=None):
+    def mixer(lp, attn_in, kl, vl, sliding, dsa_call, kind_call):
+        """The mixer of a layer whose kind varies: (out, k, v, selection,
+        the indexer's counters, the state, the KDA counters)."""
+        kda_here, j, st = kind_call
+        full, indexer, fi, sel = dsa_call
+        no_dsa = jnp.zeros((5,), jnp.int32)
+        no_kda = jnp.zeros((2,), jnp.int32)
+
+        def attend(kl, vl, sel, st):
+            out, kl, vl, _, sel, d_i, st = _attention_block(
+                cfg, dict(lp, **of_kind("mla", j)), attn_in, kl, vl, cos,
+                sin, slot0, q_slots, kv_len, kv_start, sliding, cache,
+                chunk_lens=chunk_lens, layer=fi,
+                dsa=(full, indexer, fi, sel, (src, state_rows, fresh, st)))
+            return out, kl, vl, sel, d_i, st, no_kda
+
+        def delta(kl, vl, sel, st):
+            with jax.named_scope("layer/kda"):
+                out, counts, st = kda_ops.kda_mixer(
+                    cfg, of_kind("kda", j),
+                    _in_norm(attn_in, lp, "attn_norm", cfg), st,
+                    st if src is None else src, kda_below + j, valid,
+                    rows=state_rows, fresh=fresh)
+            return out, kl, vl, sel, no_dsa, st, jnp.stack(counts)
+
+        if "mla" not in kind_xs:
+            return delta(kl, vl, sel, st)
+        if "kda" not in kind_xs:
+            return attend(kl, vl, sel, st)
+        return jax.lax.cond(kda_here, delta, attend, kl, vl, sel, st)
+
+    def layer_step(x, lp, kl, vl, sliding, layer, i, dsa_call=None,
+                   kind_call=None):
         if cos_local is not None:
             # gemma3 dual rope: sliding layers use the local table
             c = jnp.where(sliding, cos_local, cos)
@@ -1037,11 +1249,16 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
         if hc:
             coef = hyper_ops.coefficients(cfg, lp, "attn", x)
             attn_in = hyper_ops.pre_mix(coef, x)
-        attn_out, kl, vl, obs_q, *sparse = _attention_block(
-            cfg, lp, attn_in, kl, vl, c, s_, slot0, q_slots, kv_len,
-            kv_start, sliding, cache, collect_obs, bias=alibi_bias,
-            chunk_lens=chunk_lens, layer=layer, dsa=dsa_call,
-        )
+        if kind_call is not None:
+            attn_out, kl, vl, *sparse = mixer(
+                lp, attn_in, kl, vl, sliding, dsa_call, kind_call)
+            obs_q = jnp.zeros((0,), x.dtype)
+        else:
+            attn_out, kl, vl, obs_q, *sparse = _attention_block(
+                cfg, lp, attn_in, kl, vl, c, s_, slot0, q_slots, kv_len,
+                kv_start, sliding, cache, collect_obs, bias=alibi_bias,
+                chunk_lens=chunk_lens, layer=layer, dsa=dsa_call,
+            )
         # minicpm depth scaling (cfg.residual_multiplier, 1.0 elsewhere)
         rm = (jnp.asarray(cfg.residual_multiplier, COMPUTE_DTYPE)
               if cfg.residual_multiplier != 1.0 else None)
@@ -1075,6 +1292,30 @@ def run_layers(cfg: ModelConfig, tree, k_stack, v_stack, sliding_flags,
 
     def split(out):
         return (out[0], out[1].sum(0)) if held else (out, no_stats)
+
+    if kinds is not None:
+        def mixed_body(carry, xs):
+            x, k, v, sel, st = carry
+            lp, i, sliding, full, fi, kda_here, j = xs
+
+            def indexer():
+                return ix_view(jax.tree_util.tree_map(
+                    lambda a: jax.lax.dynamic_index_in_dim(a, fi, 0, False),
+                    ix_xs), fi)
+
+            x, k, v, obs_q, s_i, sel, d_i, st, k_i = layer_step(
+                x, view(lp, i), k, v, sliding, layer_offset + i, i,
+                dsa_call=(full, indexer if ix_tree is not None else None,
+                          fi_below + fi, sel), kind_call=(kda_here, j, st))
+            return (x, k, v, sel, st), (ys(obs_q, s_i), d_i, k_i)
+
+        (x, k_stack, v_stack, selection, st), (out, d_stats, k_stats) = \
+            jax.lax.scan(
+                mixed_body, (x, k_stack, v_stack, selection, recurrent[3]),
+                (tree_xs, local, sliding_flags, is_full, fi_local, is_kda,
+                 kind_local))
+        return (x, k_stack, v_stack, *split(out), selection, d_stats.sum(0),
+                st, k_stats.sum(0))
 
     if dsa:
         def sparse_body(carry, xs):
@@ -1234,13 +1475,17 @@ def decoder_forward(
         stacks = [(params["layers"], 0, cfg.num_layers)]
     k_new, v_new, obs_parts = cache.k, cache.v, []
     valid = (_valid_positions(b, t, q_slots, kv_start, chunk_lens)
-             if cfg.moe_experts_held else None)
+             if cfg.moe_experts_held or cfg.kda_layers else None)
     state = cache.state
+    if state is not None:
+        # (``src``: the tick's read-only source buffer, engine._tick_body)
+        src = state.get("src")
+        state = {k_: v_ for k_, v_ in state.items() if k_ != "src"}
     selection = None
     if cfg.index_topk:
         if getattr(cache, "sparse", False):
             selection = no_selection(cfg, b, t, cache.max_len)
-        elif cache.max_len > cfg.index_topk:
+        elif cache.max_len > cfg.index_topk or cfg.kda_layers:
             raise ValueError(
                 f"{cfg.model_type} attends over the {cfg.index_topk} tokens "
                 "its indexer selects, which only the serving path's sparse "
@@ -1248,6 +1493,23 @@ def decoder_forward(
                 f"cache holds {cache.max_len} tokens a row and would attend "
                 "all of them.  Up to index_topk tokens every token is "
                 "selected and the dense cache is exact")
+    recurrent = None
+    if cfg.kda_layers:
+        if state is None:
+            raise ValueError(
+                f"{cfg.model_type} carries recurrent state: build the cache "
+                "with kv.recurrent_state(cfg, rows) as its `state`")
+        if collect_obs:
+            raise NotImplementedError(
+                "SnapKV observation windows on a model with recurrent state")
+        fresh = None if state_rows is None else slot0 == 0
+        if fresh is not None:
+            # (rows that start at slot 0 start from a zero state)
+            state["stats"] = state["stats"].at[
+                STATE_COUNTERS.index("ssm_state_resets")].add(
+                    jnp.sum(fresh & (jnp.sum(valid, axis=1) > 0)).astype(
+                        jnp.int32))
+        recurrent = (src, state_rows, fresh)
     for tree, lo, hi in stacks:
         x, k_new, v_new, op, moe_stats, *sparse = run_layers(
             cfg, tree, k_new, v_new, sliding_flags[lo:hi],
@@ -1255,12 +1517,21 @@ def decoder_forward(
             collect_obs, alibi_bias, cos_local=cos_l, sin_local=sin_l,
             chunk_lens=chunk_lens, layer_offset=lo, valid=valid,
             selection=selection,
+            # (the leaves the tree below left, without the counters)
+            recurrent=recurrent and (*recurrent, {
+                k_: v_ for k_, v_ in state.items() if k_ != "stats"}),
         )
         obs_parts.append(op)
+        if recurrent is not None:
+            # the KDA layers' counters follow the held layers' (kv.py
+            # ``KDA_COUNTERS``)
+            *sparse, leaves, kda_stats = sparse
+            n0 = len(STATE_COUNTERS)
+            state = dict(leaves, stats=state["stats"].at[
+                n0:n0 + kda_stats.shape[0]].add(kda_stats))
         if state is not None and valid is not None:
             # the held expert layers' device counters (kv.py
-            # ``STATE_COUNTERS``; ``src`` is the tick's read-only buffer)
-            state = {k_: v_ for k_, v_ in state.items() if k_ != "src"}
+            # ``STATE_COUNTERS``)
             state["stats"] = state["stats"].at[:moe_stats.shape[0]].add(
                 moe_stats)
         if sparse:
@@ -1269,7 +1540,6 @@ def decoder_forward(
             # ``DSA_COUNTERS``)
             selection, dsa_stats = sparse
             if state is not None:
-                state = {k_: v_ for k_, v_ in state.items() if k_ != "src"}
                 n0 = state["stats"].shape[0] - dsa_stats.shape[0]
                 state["stats"] = state["stats"].at[n0:].add(dsa_stats)
     obs_q = (jnp.concatenate(obs_parts) if len(obs_parts) > 1

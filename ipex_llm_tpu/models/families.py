@@ -574,6 +574,94 @@ def _xing4_0(hf: dict) -> ModelConfig:
     return ModelConfig(**d)
 
 
+def _glm5_next_text(hf: dict) -> ModelConfig:
+    """GLM-5.3 (``glm5_next_text``): a per-layer MIXER kind
+    (``layer_types``) on ``hc_mult`` hyper-connected streams.  A
+    ``linear_attention`` layer is Kimi Delta Attention (ops/kda.py;
+    ``linear_attn_config``: heads, head dim, the short conv's kernel, the
+    gate's lower bound): a delta-rule state a row and no KV.  A
+    ``deepseek_sparse_attention`` layer is the DeepSeek-V3 latent attention
+    WITHOUT a rotated slice (``qk_rope_head_dim`` 0: nothing in the model
+    rotates) under an indexer whose keys are POOLED (``index_kpool``: one
+    key a complete block of that many positions, ``index_topk /
+    index_kpool`` blocks kept, the positions after the last complete block
+    always attended).  Every sublayer sits inside a manifold-constrained
+    hyper-connection as ``xing4_0``'s do (no clamp on the residual mix's
+    logits: the config publishes none).  MLPs: ``mlp_layer_types`` dense
+    then sparse, the sparse ones HELD expert layers as ``_axk1`` reads them,
+    every gated activation clamped at ``swiglu_limit``.  Only the sparse-
+    attention layers carry an indexer, so ``cfg.indexer_types`` here is
+    "full" for them and "none" for the others.  The multi-token-prediction
+    layer is a draft head and is not built."""
+    _require_noaux_tc(hf)
+    n = hf["num_hidden_layers"]
+    kinds = {"linear_attention": "kda", "deepseek_sparse_attention": "mla"}
+    layer_types = list(hf.get("layer_types") or ())
+    if len(layer_types) != n or set(layer_types) - set(kinds):
+        raise ValueError(
+            "glm5_next_text: layer_types must name every layer "
+            f"\"linear_attention\" or \"deepseek_sparse_attention\", got "
+            f"{layer_types}")
+    if not hf.get("mhc", False) or hf.get("hc_mult", 1) < 2:
+        raise ValueError(
+            f"glm5_next_text: mhc {hf.get('mhc')!r}, hc_mult "
+            f"{hf.get('hc_mult')!r}: the family's residual path is two or "
+            "more hyper-connected streams")
+    if set(hf.get("indexer_types") or ["full"]) != {"full"}:
+        raise ValueError(
+            "glm5_next_text: every indexer_types entry must be \"full\" "
+            f"(no layer shares a selection), got {hf['indexer_types']}")
+    if hf.get("qk_rope_head_dim", 0) or not hf.get("mla_use_nope", True):
+        raise ValueError(
+            "glm5_next_text: the latent attention is position-free "
+            "(qk_rope_head_dim 0, mla_use_nope true)")
+    if not hf.get("index_kpool_compress", True) or not hf.get(
+            "index_kpool_always_select_tail", True):
+        raise ValueError(
+            "glm5_next_text: the index cache holds pooled keys "
+            "(index_kpool_compress) and the tail is always attended "
+            "(index_kpool_always_select_tail)")
+    pool = int(hf.get("index_kpool", 1))
+    if hf["index_topk"] % pool:
+        raise ValueError(
+            f"glm5_next_text: index_topk {hf['index_topk']} is not a "
+            f"multiple of index_kpool {pool}")
+    mlp_types = list(hf.get("mlp_layer_types") or ())
+    n_dense = hf.get("first_k_dense_replace", 0)
+    if mlp_types and mlp_types != ["dense"] * n_dense + ["sparse"] * (
+            n - n_dense):
+        raise ValueError(
+            "glm5_next_text: mlp_layer_types must be first_k_dense_replace "
+            f"dense layers then sparse ones, got {mlp_types}")
+    la = hf["linear_attn_config"]
+    mixers = tuple(kinds[t] for t in layer_types)
+    d = _deepseek_common(hf)
+    d.update(
+        _held_experts(hf),
+        moe_score_bias=True,
+        rope=None,
+        attn_scale=(hf["qk_nope_head_dim"]) ** -0.5,
+        index_topk=hf["index_topk"],
+        index_n_heads=hf["index_n_heads"],
+        index_head_dim=hf["index_head_dim"],
+        index_kpool=pool,
+        indexer_types=tuple("full" if m == "mla" else "none"
+                            for m in mixers),
+        hc_mult=hf["hc_mult"],
+        hc_sinkhorn_iters=hf["hc_sinkhorn_iters"],
+        hc_eps=hf["hc_eps"],
+        hc_res_clamp=(-float("inf"), float("inf")),
+        mixer_types=mixers,
+        kda_num_heads=la["num_heads"],
+        kda_head_dim=la["head_dim"],
+        kda_conv_kernel=la.get("short_conv_kernel_size", 4),
+        kda_gate_lower_bound=float(la.get("gate_lower_bound", -5.0)),
+        mlp_act_limit=(float(hf["swiglu_limit"])
+                       if hf.get("swiglu_limit") else None),
+    )
+    return ModelConfig(**d)
+
+
 def _phi(hf: dict) -> ModelConfig:
     """phi-1/phi-2: parallel attn+mlp off ONE shared layernorm, partial
     rotary, non-gated gelu MLP, biases everywhere."""
@@ -1421,6 +1509,8 @@ FAMILIES: dict[str, Family] = {
                           _DEEPSEEK_V3_MOE),
     "xing4_0": Family("xing4_0", _xing4_0, _DEEPSEEK_SCHEME,
                       _DEEPSEEK_V3_MOE),
+    "glm5_next_text": Family("glm5_next_text", _glm5_next_text,
+                             _DEEPSEEK_SCHEME, _DEEPSEEK_V3_MOE),
     "nemotron_h": Family("nemotron_h", _nemotron_h),
     "falcon_h1": Family("falcon_h1", _falcon_h1),
     "mixtral": Family("mixtral", _mixtral, WeightScheme(), _MIXTRAL_MOE),

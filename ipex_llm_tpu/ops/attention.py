@@ -274,7 +274,8 @@ def latent_attention(q_lat, q_rope, c_pool, r_pool, cache, kv_len,
 
 
 @jax.named_scope("dsa/score")
-def index_scores(q_idx, w_idx, ik_pool, cache, q_slots, kv_len, *, layer):
+def index_scores(q_idx, w_idx, ik_pool, cache, q_slots, kv_len, *, layer,
+                 kpool: int = 1):
     """Indexer scores of a call's queries against their rows' cached index
     keys: q_idx [B, T, J, D] (rotated), w_idx [B, T, J] float32 (the head
     weights, scaled), the index-key pool whole with the full layer's index
@@ -282,7 +283,9 @@ def index_scores(q_idx, w_idx, ik_pool, cache, q_slots, kv_len, *, layer):
     over bf16 products, the heads added in index order (a reduction would be
     ordered by the block's shape); -inf where query t does not see slot s
     (``s > q_slots[t]`` or past ``kv_len``).  Returns [B, T, S] over the
-    table's whole width ``S``."""
+    table's whole width ``S``.  ``kpool`` > 1: the pool holds one key a
+    block of that many positions, ``s`` counts BLOCKS, and query t sees the
+    complete blocks that end before it (``s < q_slots[t] // kpool``)."""
     f32 = jnp.float32
     keys = cache.gather_layer(ik_pool, layer)[:, 0]               # [B, S, D]
     b, t, n_j, _ = q_idx.shape
@@ -305,7 +308,10 @@ def index_scores(q_idx, w_idx, ik_pool, cache, q_slots, kv_len, *, layer):
         acc = jax.lax.fori_loop(
             0, n_j, head, jnp.zeros((b, t, keys.shape[1]), f32))
     kpos = jnp.arange(keys.shape[1])[None, None, :]
-    seen = (kpos <= q_slots[:, :, None]) & (kpos < kv_len[:, None, None])
+    if kpool > 1:
+        seen = kpos < (q_slots // kpool)[:, :, None]
+    else:
+        seen = (kpos <= q_slots[:, :, None]) & (kpos < kv_len[:, None, None])
     # -0.0 and 0.0 are one score
     return jnp.where(seen, jnp.where(acc == 0.0, 0.0, acc), -jnp.inf)
 

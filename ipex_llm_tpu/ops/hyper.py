@@ -39,6 +39,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ipex_llm_tpu.ops.norms import _rows
+
 
 def expand(x, n: int):
     """An embedding ``[B, T, C]`` copied into all ``n`` streams."""
@@ -81,8 +83,13 @@ def coefficients(cfg, lp, sub: str, x):
                          for part in ("proj", "scale", "bias"))
     n, b, t, c = x.shape
     xf = x.astype(jnp.float32)
-    mean_sq = _terms([jnp.sum(xf[j] * xf[j], axis=-1) for j in range(n)]
-                     ) / (n * c)
+    # a stream's sum of squares behind the barrier of ops/norms.py: on the
+    # chip XLA orders a row reduction by how the leading dimensions factor,
+    # and a token's coefficients must not depend on the block it rides in
+    # ([1, 512] alone, [8, 64] beside seven other prompts)
+    rows = _rows(xf)
+    sq = jnp.sum(rows * rows, axis=-1).reshape(n, b, t)
+    mean_sq = _terms([sq[j] for j in range(n)]) / (n * c)
     raw = jnp.einsum("nbtc,knc->kbt", xf, proj.reshape(-1, n, c),
                      precision=jax.lax.Precision.HIGHEST,
                      preferred_element_type=jnp.float32)

@@ -32,8 +32,14 @@ def act(x: jnp.ndarray, name: str = "gelu_new") -> jnp.ndarray:
     return ACT_FNS[name](x.astype(jnp.float32)).astype(x.dtype)
 
 
-def gated_act_mul(gate: jnp.ndarray, up: jnp.ndarray, act: str = "silu") -> jnp.ndarray:
-    """act(gate) * up — the SwiGLU/GeGLU core."""
+def gated_act_mul(gate: jnp.ndarray, up: jnp.ndarray, act: str = "silu",
+                  limit: float | None = None) -> jnp.ndarray:
+    """act(gate) * up — the SwiGLU/GeGLU core.  ``limit`` (static; a
+    config's ``swiglu_limit``): ``act(min(gate, limit)) * clip(up, -limit,
+    limit)``."""
+    if limit is not None:
+        gate = jnp.minimum(gate, jnp.asarray(limit, gate.dtype))
+        up = jnp.clip(up, -limit, limit)
     return ACT_FNS[act](gate.astype(jnp.float32)).astype(up.dtype) * up
 
 

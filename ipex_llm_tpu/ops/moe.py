@@ -220,9 +220,10 @@ def _grouped_kernel_covers(stack) -> bool:
     return pallas_qmatmul.grouped_supported(stack)
 
 
-def _expert_act(inner, act, gated):
+def _expert_act(inner, act, gated, limit=None):
     if gated:
-        return mlp_ops.gated_act_mul(*mlp_ops.split_gate_up(inner), act)
+        return mlp_ops.gated_act_mul(*mlp_ops.split_gate_up(inner), act,
+                                     limit)
     return mlp_ops.act(inner, act)
 
 
@@ -254,7 +255,7 @@ def row_tile(n: int, top_k: int, n_routed: int) -> int:
     return min(128, _round_up(max(1, round(mean + 2 * mean ** 0.5)), 16))
 
 
-def _held_by_slot(h, gate, gate_up, down, act, gated, first):
+def _held_by_slot(h, gate, gate_up, down, act, gated, first, limit=None):
     """``moe_held`` for a block of ONE row tile (N <= 128): slot s of the
     kernel's grid is the s-th hit expert and multiplies the block's N rows
     as they lie, so an expert's planes are dequantized once a projection.
@@ -266,7 +267,7 @@ def _held_by_slot(h, gate, gate_up, down, act, gated, first):
     n_hit = jnp.sum(hit).astype(jnp.int32)
     order = jnp.argsort(~hit, stable=True).astype(jnp.int32)   # hit ones first
     inner = qmatmul_pallas_grouped(h, gate_up, order, n_hit, first)
-    a = _expert_act(inner.astype(h.dtype), act, gated)
+    a = _expert_act(inner.astype(h.dtype), act, gated, limit)
     y = qmatmul_pallas_grouped(a, down, order, n_hit, first)
     # the slots past the hit ones were not in the grid: nothing was written
     live = (jnp.arange(n_held) < n_hit)[:, None, None]
@@ -278,7 +279,8 @@ def _held_by_slot(h, gate, gate_up, down, act, gated, first):
     return out, n_hit * n
 
 
-def _held_by_rows(h, gate, gate_up, down, act, gated, first, bm, kmax):
+def _held_by_rows(h, gate, gate_up, down, act, gated, first, bm, kmax,
+                  limit=None):
     """``moe_held`` for a block of MORE rows than one tile (a prefill
     chunk): the routed PAIRS are multiplied, not tokens x hit experts.
     Every pair gets a row in an expert-major layout (expert e's rows start
@@ -309,7 +311,7 @@ def _held_by_rows(h, gate, gate_up, down, act, gated, first, bm, kmax):
                       axis=-1)                           # [T, bm]; N: padding
     x = jnp.pad(h, ((0, 1), (0, 0)))[row_tok]            # [T, bm, H]
     inner = qmatmul_pallas_grouped(x, gate_up, tile_e, n_live, first)
-    a = _expert_act(inner.astype(h.dtype), act, gated)
+    a = _expert_act(inner.astype(h.dtype), act, gated, limit)
     y = qmatmul_pallas_grouped(a, down, tile_e, n_live, first)
     y = y.reshape(-1, y.shape[-1])                       # [T x bm, H]
 
@@ -329,7 +331,7 @@ def _held_by_rows(h, gate, gate_up, down, act, gated, first, bm, kmax):
 
 
 def moe_held(h, gate, gate_up, down, act, gated=True, layer=None,
-             top_k=None, n_routed=None):
+             top_k=None, n_routed=None, limit=None):
     """The part of an expert layer that the experts HELD here contribute:
     h [N, H]; gate [N, E_held] float32, token n's weight for held expert e
     and 0 where it is not routed there (a pair that lands on an absent
@@ -339,8 +341,10 @@ def moe_held(h, gate, gate_up, down, act, gated=True, layer=None,
     a layer's stack out as its ``xs`` would copy every held expert's planes
     every call.  ``top_k`` of ``n_routed`` experts is what a token was
     routed to (static; E_held of E_held where not told: every token
-    everywhere).  Returns (out [N, H] float32, experts hit, rows the expert
-    matmuls ran over).
+    everywhere).  ``limit`` (static): the gated activation's clamp
+    (ops/mlp.py ``gated_act_mul``), applied between the two kernel calls, so
+    a model without one compiles what it compiled.  Returns (out [N, H]
+    float32, experts hit, rows the expert matmuls ran over).
 
     One pass over the experts that are HIT, in ascending order: no pair is
     dropped whatever the imbalance, the stack is never dequantized at full
@@ -379,12 +383,12 @@ def moe_held(h, gate, gate_up, down, act, gated=True, layer=None,
     if _grouped_kernel_covers(gate_up) and _grouped_kernel_covers(down):
         if n <= 128:
             out, rows = _held_by_slot(h, gate, gate_up, down, act, gated,
-                                      first)
+                                      first, limit)
         else:
             top_k, n_routed = top_k or n_held, n_routed or n_held
             out, rows = _held_by_rows(
                 h, gate, gate_up, down, act, gated, first,
-                row_tile(n, top_k, n_routed), min(top_k, n_held))
+                row_tile(n, top_k, n_routed), min(top_k, n_held), limit)
         return out, n_hit, rows
 
     order = jnp.argsort(~hit, stable=True).astype(jnp.int32)   # hit ones first
@@ -392,7 +396,7 @@ def moe_held(h, gate, gate_up, down, act, gated=True, layer=None,
     def one(i, acc):
         e = order[i]
         inner = linear_ops.linear(h, _take_expert(gate_up, first + e))
-        y = linear_ops.linear(_expert_act(inner, act, gated),
+        y = linear_ops.linear(_expert_act(inner, act, gated, limit),
                               _take_expert(down, first + e))
         g_e = jax.lax.dynamic_slice_in_dim(gate, e, 1, axis=1)   # [N, 1]
         return acc + g_e * y.astype(jnp.float32)

@@ -72,10 +72,10 @@ def ineligible_reason(cfg: ModelConfig, params: dict,
     others = {a: n for a, n in axes.items() if a != "tp" and n > 1}
     if others:
         return f"composed mesh (non-tp axes {others})"
-    if cfg.ssm_layers:
+    if cfg.recurrent_layers:
         return (f"model_type {cfg.model_type!r} carries recurrent state "
-                f"({cfg.ssm_layers} Mamba-2 blocks): neither the state nor "
-                "the mixer's heads are manual-sharded")
+                f"({cfg.recurrent_layers} layers): neither the state nor the "
+                "mixer's heads are manual-sharded")
     if cfg.num_heads % tp or cfg.num_kv_heads % tp:
         return (f"heads do not divide tp ({cfg.num_heads}q/"
                 f"{cfg.num_kv_heads}kv over tp={tp})")

@@ -68,10 +68,10 @@ def pipeline_forward(
         run_layers,
     )
 
-    if cfg.hybrid_pattern is not None:
+    if cfg.hybrid_pattern is not None or cfg.kda_layers:
         raise NotImplementedError(
             f"model_type {cfg.model_type!r} is a hybrid stack "
-            f"({cfg.ssm_layers} blocks carry recurrent state): a stage "
+            f"({cfg.recurrent_layers} blocks carry recurrent state): a stage "
             "hand-over moves activations, and no stage owns a state slot")
     if cfg.hc_mult > 1:
         raise NotImplementedError(

@@ -54,6 +54,7 @@ import numpy as np
 from ipex_llm_tpu.hostutil import d2h, h2d, upload
 from ipex_llm_tpu.kv import (PagedKVCache, index_page_bytes, model_page_bytes,
                              recurrent_state, state_bytes, state_counters,
+                             state_leaves,
                              with_recurrent_state)
 from ipex_llm_tpu.models.config import ModelConfig
 from ipex_llm_tpu.models.decoder import decoder_forward
@@ -813,7 +814,7 @@ def _tick_body(cfg: ModelConfig, params, cache, toks, row_lens,
                 slot_offsets=p_base,
                 gather_positions=jnp.maximum(p_nvalid - 1, 0),
                 chunk_lens=p_nvalid,
-                state_rows=p_rowmap if cfg.ssm_layers else None,
+                state_rows=p_rowmap if cfg.recurrent_layers else None,
             )
             cache = replace(cache, k=row_cache.k, v=row_cache.v,
                             state=row_cache.state)
@@ -1184,7 +1185,7 @@ class ServingEngine:
         # in flight started from, oldest first (one in the serial order);
         # ``_state_free``: the spares.  What recurrent state cannot do yet
         # is refused here by name (derived from the model, no config field)
-        self._recurrent = cfg.ssm_layers > 0
+        self._recurrent = cfg.recurrent_layers > 0
         self._state_free: list = []
         self._state_held: list = []
         self._state_counters = state_counters(cfg)
@@ -1517,10 +1518,14 @@ class ServingEngine:
     # -- public API ---------------------------------------------------------
 
     def _refuse_for_recurrent_state(self, cfg: ModelConfig, mesh):
-        """Engine features a model with recurrent state (Mamba-2 blocks)
-        cannot have yet, each refused by name at construction."""
+        """Engine features a model with per-row recurrent state (Mamba-2
+        blocks, Kimi Delta Attention layers) cannot have yet, each refused
+        by name at construction; the state's kind is read off the model."""
+        kinds = [f"{n} {what}" for n, what in (
+            (cfg.ssm_layers, "Mamba-2 blocks"),
+            (cfg.kda_layers, "Kimi Delta Attention layers")) if n]
         why = (f"model_type {cfg.model_type!r} carries recurrent state "
-               f"({cfg.ssm_layers} Mamba-2 blocks): ")
+               f"({' and '.join(kinds)}): ")
         if self.ec.spec_k > 0:
             raise ValueError(
                 why + "spec_k > 0 is not supported: a rejected draft has "
@@ -1695,6 +1700,8 @@ class ServingEngine:
             # buffers); with one, a page of KV is useless without the
             # state that follows it, so the prefix cache is off
             "state_cache_bytes": 2 * state_bytes(self.cache.state),
+            # the leaves this family's state carries (kv.recurrent_state)
+            "state_leaves": state_leaves(self.cache.state),
             "prefix_cache": ("off: recurrent state" if self._recurrent
                              else "on"),
         }
@@ -1823,6 +1830,18 @@ class ServingEngine:
         if self.cfg.index_topk:
             out.update(self._count_selection(lens, decode, chunks, base,
                                              n_valid))
+        if self.cfg.kda_layers:
+            # what the tick asks of the Kimi Delta Attention layers
+            # (ops/kda.py), the same lower bound: tokens through the chunked
+            # form in the rows that bring a chunk, rows stepped, each in
+            # every KDA layer (perfbench/SPANS_kda.md)
+            kda = self.cfg.kda_layers
+            out.update(
+                kda_chunk_tokens=kda * sum(int(n_valid[i])
+                                           for i, _, _ in chunks),
+                kda_chunk_rows=kda * len(chunks),
+                kda_step_rows=(kda * int(np.count_nonzero(active))
+                               if decode else 0))
         return out
 
     def _count_mixed_tokens(self, active, decode, chunks, n_valid,
@@ -1849,16 +1868,25 @@ class ServingEngine:
         ``dsa_decode_selected`` by decode rows, which gather a row a token).
         The device's own counts are the ``dsa_*_total`` counters; these ride
         the ``engine/dispatch`` span for the trace's readers."""
-        top = self.cfg.index_topk
+        top, kp = self.cfg.index_topk, self.cfg.index_kpool
+
+        def scored(n):
+            # (pooled keys: the complete blocks that end before the query)
+            return n if kp == 1 else (n - 1) // kp
+
+        def attended(n):
+            # (pooled keys: the chosen blocks and the query's own tail)
+            return np.minimum(n, top if kp == 1 else top + (n - 1) % kp + 1)
+
         seen = sel = dec = 0
         if decode:
-            seen += int(lens.sum())
-            dec = int(np.minimum(lens, top).sum())
+            seen += int(scored(lens).sum())
+            dec = int(attended(lens).sum())
         for i, _, _ in chunks:
             slots = int(base[i]) + 1 + np.arange(int(n_valid[i]),
                                                  dtype=np.int64)
-            seen += int(slots.sum())
-            sel += int(np.minimum(slots, top).sum())
+            seen += int(scored(slots).sum())
+            sel += int(attended(slots).sum())
         layers = self.cfg.kv_layers
         return {"dsa_keys_scored": seen * self.cfg.index_layers,
                 "dsa_tokens_selected": (sel + dec) * layers,
@@ -3495,9 +3523,25 @@ class ServingEngine:
         # overshoots the budget rather than crawling
         budget = (self._plan.chunk_budget if self._plan is not None
                   else self._step_budget)
+        # a model with a CHUNK GRID (``cfg.chunk_grid``: the block of its
+        # chunked recurrence, ops/kda.py) has its prompts cut at multiples
+        # of that block only, a prompt's last piece apart, so that a
+        # token's bits do not depend on the ticks its prompt rode in: the
+        # widths under the block are not used, and where the budget does
+        # not give every joining row TWO blocks, the rows admitted first go
+        # first and the others wait their turn.  Two, not one: a prefill
+        # block of 8 rows x 64 tokens beside a decode stage gave a token
+        # other bits than the same tokens as [4, 128], [2, 256] or [1, 512]
+        # (measured on a v5e, PERF.md section 6, PR 50: XLA lays a leading
+        # dimension of 8 out on the sublanes and plans float32 reductions
+        # otherwise), so blocks of 8 rows are not formed for such a model
+        grid = self.cfg.chunk_grid
+        if grid:
+            rows = sorted(rows, key=lambda r: self.rows[r].submitted_s)[
+                :max(1, budget // (2 * grid))]
         share = max(1, budget // len(rows))
-        width = min(max(1 << (share.bit_length() - 1), 4),
-                    self.ec.prefill_bucket)
+        width = min(max(1 << (share.bit_length() - 1), grid or 4),
+                    max(self.ec.prefill_bucket, grid))
         p_b = 1 << (len(rows) - 1).bit_length()        # pow2 batch pad
 
         toks = np.zeros((p_b, width), np.int32)
