@@ -18,10 +18,11 @@ Static-shape discipline (SURVEY.md §7 hard part (b)):
 from __future__ import annotations
 
 from dataclasses import replace
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ipex_llm_tpu.kv import MOE_COUNTERS, STATE_COUNTERS, KVCache
 from ipex_llm_tpu.models.config import ModelConfig
@@ -126,15 +127,21 @@ def _attention_block(cfg: ModelConfig, lp: dict, x, kl, vl, cos, sin, slot0,
             chunk_lens=chunk_lens,
             layer=layer,
         )
-    with jax.named_scope("layer/o_proj"):
-        attn = attn.reshape(b, t, cfg.num_heads * cfg.head_dim)
-        out = linear_ops.times(linear_ops.linear(attn, lp["o"], lp.get("o_bias")),
-                     cfg.attention_out_multiplier)
-        if cfg.norm_after:
-            out = _norm(out, lp["attn_norm"], cfg, lp.get("attn_norm_bias"))
-        if cfg.post_attn_norm:
-            out = _norm(out, lp["post_attn_norm"], cfg)
-    return out, kl, vl, obs_q
+    return _o_proj(cfg, lp, attn), kl, vl, obs_q
+
+
+@jax.named_scope("layer/o_proj")
+def _o_proj(cfg: ModelConfig, lp: dict, attn):
+    """The ``layer/o_proj`` stage of plain attention: the heads' outputs
+    ``[B, T, Hq, D]`` through the output projection and its norms."""
+    attn = attn.reshape(*attn.shape[:2], cfg.num_heads * cfg.head_dim)
+    out = linear_ops.times(linear_ops.linear(attn, lp["o"], lp.get("o_bias")),
+                 cfg.attention_out_multiplier)
+    if cfg.norm_after:
+        out = _norm(out, lp["attn_norm"], cfg, lp.get("attn_norm_bias"))
+    if cfg.post_attn_norm:
+        out = _norm(out, lp["post_attn_norm"], cfg)
+    return out
 
 
 def _dense_weight(w, dtype):
@@ -1555,6 +1562,140 @@ def decoder_forward(
     if collect_obs:
         return logits, new_cache, obs_q
     return logits, new_cache
+
+
+class TokenBlock(NamedTuple):
+    """One block of a call of :func:`forward_blocks`: ``tokens`` [B, T] at
+    rope ``positions`` [B, T], written from slot ``slot_offsets`` [B] of the
+    rows that block table ``tables`` [B, pages] names, ``chunk_lens`` [B] of
+    them real; the logits wanted are those at ``gather`` [B] (None: the
+    last position)."""
+
+    tokens: jnp.ndarray
+    positions: jnp.ndarray
+    slot_offsets: jnp.ndarray
+    chunk_lens: jnp.ndarray
+    tables: jnp.ndarray
+    gather: jnp.ndarray | None = None
+
+
+def fuses_blocks(cfg: ModelConfig) -> bool:
+    """Whether :func:`forward_blocks` runs this model, from ``cfg`` alone:
+    every layer plain attention (the non-MLA path of
+    :func:`_attention_block`) and a dense MLP (:func:`_mlp_block`) on one
+    residual stream, and nothing about a token that depends on the block
+    it rides in but where its K/V lies (ALiBi's bias is laid out over a
+    block's ``q_slots`` and chatglm v1's positions have two channels: those
+    families keep one block a forward)."""
+    return not (cfg.is_mla or cfg.recurrent_layers or cfg.num_experts
+                or cfg.index_topk or cfg.hc_mult > 1 or cfg.alibi
+                or cfg.glm_alpha or cfg.rope_2d)
+
+
+def forward_blocks(cfg: ModelConfig, params: dict[str, Any], cache,
+                   blocks: tuple[TokenBlock, ...]):
+    """Several token blocks over a paged pool in ONE pass over the weights
+    (the serving engine's fused mixed tick: a prefill block ``[P, W]`` and
+    the decode rows ``[R, 1]``).  Returns (one logits array ``[B, V]`` a
+    block, the cache with its pool written).
+
+    Everything that is per token sees the blocks' tokens as ONE axis ``[1,
+    T, hidden]``, T the sum of the blocks' ``B * T``: the embedding, the
+    norms, the projections with the rotary tables gathered per token, the
+    MLP and the head (the chosen position of every row of every block, one
+    call), so every linear is called once, at M = T.  What is per ROW is
+    called per block, with that block's table, offsets and lengths, in the
+    shapes :func:`decoder_forward` gives it: the pool write
+    (``layer/kv_write``) and attention (``layer/attn``), which read no
+    weight.  The blocks' rows are distinct, so the order of the blocks does
+    not show; all writes of a layer come before its reads.
+
+    A token's bits are those of :func:`decoder_forward` over its block
+    alone: a row's dot products do not depend on M (ops/pallas/qmatmul.py),
+    the norms reduce over ``[N, H]`` whatever the block (ops/norms.py
+    ``_rows``), and attention is the same call.  Only for a model
+    :func:`fuses_blocks` admits."""
+    if not fuses_blocks(cfg):
+        raise ValueError(f"{cfg.model_type} does not run as fused blocks")
+
+    def flat(arrays):
+        return jnp.concatenate([a.reshape(1, -1) for a in arrays], axis=1)
+
+    positions = flat([b.positions for b in blocks])
+    x, cos, sin = embed_prelude(cfg, params, flat([b.tokens for b in blocks]),
+                                positions)
+    cos_l, sin_l = local_rope_tables(cfg, params, positions)
+    # a block's cut (offset, B, T) of the token axis, what its pool write
+    # and attention call take, and where its chosen positions lie
+    calls, at, off = [], [], 0
+    for blk in blocks:
+        b, t = blk.tokens.shape
+        calls.append((blk, (off, b, t), replace(cache, tables=blk.tables),
+                      blk.slot_offsets[:, None] + jnp.arange(t)[None, :],
+                      blk.slot_offsets + blk.chunk_lens))
+        last = (t - 1 if blk.gather is None
+                else jnp.clip(blk.gather, 0, t - 1))
+        at.append(off + jnp.arange(b) * t + last)
+        off += b * t
+
+    def attention(lp, x, kl, vl, c, s_, sliding, layer):
+        with jax.named_scope("layer/qkv"):
+            h = x if cfg.norm_after else _in_norm(x, lp, "attn_norm", cfg)
+            q, k, v, _ = _qkv_rope(cfg, lp, x, h, c, s_, 0)
+
+        def cut(z, o, b, t):
+            return z[0, o:o + b * t].reshape(b, t, *z.shape[2:])
+
+        for blk, span, pool, _, _ in calls:
+            kl, vl = paged_kv_write(pool, kl, vl, layer, cut(k, *span),
+                                    cut(v, *span), blk.slot_offsets)
+        outs = []
+        for blk, span, pool, q_slots, kv_len in calls:
+            with jax.named_scope("layer/attn"):
+                a = cached_sdpa(
+                    cut(q, *span), kl, vl, pool, compute_dtype=COMPUTE_DTYPE,
+                    causal=True, q_positions=q_slots, kv_len=kv_len,
+                    kv_start=None, window=cfg.sliding_window,
+                    window_on=sliding, softcap=cfg.attn_softcap,
+                    scale=cfg.attn_scale, chunk_lens=blk.chunk_lens,
+                    layer=layer)
+            outs.append(a.reshape(1, -1, *a.shape[2:]))
+        return _o_proj(cfg, lp, jnp.concatenate(outs, axis=1)), kl, vl
+
+    tree_xs, view = layer_views(params["layers"])
+    rm = (jnp.asarray(cfg.residual_multiplier, COMPUTE_DTYPE)
+          if cfg.residual_multiplier != 1.0 else None)
+
+    def add(res, out):
+        return res + out if rm is None else res + rm * out
+
+    def body(carry, xs):
+        x, kl, vl = carry
+        lp, i, sliding = xs
+        lp = view(lp, i)
+        if cos_l is not None:
+            # gemma3 dual rope: sliding layers use the local table
+            c, s_ = jnp.where(sliding, cos_l, cos), jnp.where(sliding, sin_l,
+                                                              sin)
+        else:
+            c, s_ = cos, sin
+        attn_out, kl, vl = attention(lp, x, kl, vl, c, s_, sliding, i)
+        if cfg.parallel_blocks:
+            x = add(x, attn_out + _mlp_block(cfg, lp, x))
+        else:
+            x = add(x, attn_out)
+            x = add(x, _mlp_block(cfg, lp, x))
+        return (x, kl, vl), None
+
+    sliding_flags = jnp.array(
+        [cfg.layer_is_sliding(l) for l in range(cfg.num_layers)], dtype=bool)
+    (x, k_new, v_new), _ = jax.lax.scan(
+        body, (x, cache.k, cache.v),
+        (tree_xs, jnp.arange(cfg.num_layers, dtype=jnp.int32), sliding_flags))
+    logits = logits_tail(cfg, params, x[:, jnp.concatenate(at)])[0]
+    rows = np.cumsum([blk.tokens.shape[0] for blk in blocks])
+    return (tuple(jnp.split(logits, rows[:-1])),
+            replace(cache, k=k_new, v=v_new))
 
 
 def _valid_positions(b: int, t: int, q_slots, kv_start, chunk_lens):

@@ -227,7 +227,10 @@ def _qmatmul_2d(x, data, scales, zeros, layer=None, *, qtype: str, bs: int,
     bit5 = qtype in _BIT5
     num, den = _data_row_factor(qtype)
 
-    bm = min(128, _round_up(m, 16))
+    # M tile: up to 256 rows are ONE tile (the grid is M-outermost, so every
+    # further M tile fetches and dequantizes the whole weight again: a mixed
+    # tick's chunk and its decode rows, 128 + 16, ride one); past that, 128
+    bm = _round_up(m, 16) if m <= 256 else 128
     # K tile: whole quantization blocks, target ~2048 contraction rows
     bk, kp, bn, np_ = tile_plan(k, n, bs)
 

@@ -566,6 +566,7 @@ class OpenAIServer:
             # mixed ticks run, prompt tokens batched per tick, and the
             # rolling TTFT p95 the step is sized against
             "mixed_steps": m.get("mixed_steps", 0),
+            "mixed_fused_steps": m.get("mixed_fused_steps", 0),
             "prefill_tokens_per_step": m.get("prefill_tokens_per_step", 0.0),
             "ttft_p95_s": m.get("ttft_p95_s", 0.0),
         }

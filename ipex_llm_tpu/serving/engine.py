@@ -57,7 +57,8 @@ from ipex_llm_tpu.kv import (PagedKVCache, index_page_bytes, model_page_bytes,
                              state_leaves,
                              with_recurrent_state)
 from ipex_llm_tpu.models.config import ModelConfig
-from ipex_llm_tpu.models.decoder import decoder_forward
+from ipex_llm_tpu.models.decoder import (TokenBlock, decoder_forward,
+                                         forward_blocks, fuses_blocks)
 from ipex_llm_tpu.serving.epoch import (HELD_FIELDS, EpochLayout, ResultLayout,
                                          unpack_rows)
 from ipex_llm_tpu.serving.faults import (EngineOverloaded, FaultInjector,
@@ -755,6 +756,114 @@ def _sample_verify_positions(logits, active, temps, top_ps, key, seeds,
     return t_all, lp_all, key
 
 
+def _merge_wave(prefill, first_t, toks, row_lens, active, steps, remain,
+                eos, hist=None):
+    """A tick's ``tick/merge``: the prefill wave joins the decode state on
+    the device exactly as a host epoch upload would publish it (pad slots
+    drop).  Lengths advance for EVERY prefill row; a row whose prompt
+    completes (``p_emit``) joins with its first token pre-published
+    (toks=first, steps=1, remain-=1, active unless the first token hit EOS
+    / exhausted the budget / ``p_canjoin`` says the host could not back
+    its decode KV slot), so no epoch upload stands between a prompt's last
+    chunk and its first decode step.  ``hist`` (a speculating tick's token
+    history): a completing row's gains its first token here (the prompt
+    itself landed with the admission epoch upload), so the decode stage
+    that follows can already draft for it.  Returns (toks, row_lens,
+    active, steps, remain, hist)."""
+    _, _, p_base, p_nvalid, p_emit, p_canjoin, p_rowmap = prefill
+    new_len = p_base + p_nvalid
+    row_lens = row_lens.at[p_rowmap].set(new_len, mode="drop")
+    hit_eos = (first_t[:, None] == eos[p_rowmap]).any(axis=1)
+    rem_after = remain[p_rowmap] - 1
+    join = p_emit & p_canjoin & ~hit_eos & (rem_after > 0)
+    toks = toks.at[p_rowmap].set(
+        jnp.where(p_emit, first_t, toks[p_rowmap]), mode="drop")
+    steps = steps.at[p_rowmap].set(
+        jnp.where(p_emit, 1, steps[p_rowmap]), mode="drop")
+    remain = remain.at[p_rowmap].set(
+        jnp.where(p_emit, rem_after, remain[p_rowmap]), mode="drop")
+    active = active.at[p_rowmap].set(
+        jnp.where(p_emit, join, active[p_rowmap]), mode="drop")
+    if hist is not None:
+        # (pad slots and non-emitting rows drop)
+        hpos = jnp.where(p_emit, new_len, hist.shape[1])
+        hist = hist.at[p_rowmap, hpos].set(first_t, mode="drop")
+    return toks, row_lens, active, steps, remain, hist
+
+
+def fuses_mixed_tick(cfg: ModelConfig, horizon: int, with_decode: bool,
+                     spec_k: int, mesh) -> bool:
+    """Whether a tick with a prefill block takes the FUSED form
+    (``_fused_mixed_body``), from ``cfg`` and the tick's static shape: a
+    dense model (``decoder.fuses_blocks``), one decode step, no
+    speculation, no mesh.  Every other mixed tick is staged
+    (``_tick_body``).  The host asks the same question of the same values
+    (``_mixed_step``): a joiner's first decode step waits for the next tick
+    in the fused form."""
+    return (with_decode and horizon == 1 and spec_k == 0 and mesh is None
+            and fuses_blocks(cfg))
+
+
+def _fused_mixed_body(cfg: ModelConfig, params, cache, toks, row_lens,
+                      active, temps, top_ps, key, seeds, steps, top_ks, eos,
+                      remain, prefill):
+    """A dense model's mixed tick in ONE pass over the weights: the prefill
+    block's ``P * W`` tokens and the ``R`` decode rows' ride one forward
+    (``decoder.forward_blocks``: every linear once, at M = P * W + R; the
+    pool write and attention per block), where the staged form
+    (``_tick_body``) streams and dequantizes the weights once for the block
+    and once more for the rows.  ``_tick_body``'s arguments and results.
+
+    The decode rows step from the row state the tick was HANDED (the
+    epoch's), and the merge comes after: a row whose prompt completes here
+    gets its first token here and takes its first decode step in the NEXT
+    tick (the staged form gives it that step in the same program, which is
+    what forces the second pass).  No token and no bit of any row changes:
+    a joiner's second token is delivered one tick later.  The key chain is
+    the staged tick's (one split for the first tokens, one for the step)."""
+    from ipex_llm_tpu.ops.sampling import sample_rows_with_logprobs
+
+    p_tokens, p_tables, p_base, p_nvalid, p_emit, _, p_rowmap = prefill
+    alive = active
+    with jax.named_scope("tick/decode"):
+        # a dead row's write goes to the scratch page and its attention
+        # walks no page, as in ``_decode_horizon_loop``'s step
+        write_at = jnp.where(
+            alive, row_lens,
+            h2d(cache.tables.shape[1] * cache.page_size, jnp.int32))
+        (p_logits, logits), cache = forward_blocks(cfg, params, cache, (
+            TokenBlock(p_tokens,
+                       p_base[:, None] + jnp.arange(p_tokens.shape[1])[None],
+                       p_base, p_nvalid, p_tables,
+                       jnp.maximum(p_nvalid - 1, 0)),
+            TokenBlock(toks[:, None], row_lens[:, None], write_at,
+                       alive.astype(jnp.int32), cache.tables)))
+        key, sub = jax.random.split(key)
+        first_t, first_lp = sample_rows_with_logprobs(
+            p_logits, temps[p_rowmap], top_ps[p_rowmap], sub,
+            seeds=seeds[p_rowmap], steps=jnp.zeros_like(p_nvalid),
+            top_ks=top_ks[p_rowmap], active=p_emit)
+        key, sub = jax.random.split(key)
+        nxt, lp = sample_rows_with_logprobs(
+            logits, temps, top_ps, sub, seeds=seeds, steps=steps,
+            top_ks=top_ks, active=alive)
+        hit_eos = (nxt[:, None] == eos).any(axis=1) & alive
+        adv = alive.astype(jnp.int32)
+        row_lens = row_lens + adv
+        steps = steps + adv
+        remain = remain - adv
+        active = alive & ~hit_eos & (remain > 0)
+        toks = jnp.where(active, nxt, toks)
+    with jax.named_scope("tick/merge"):
+        # AFTER the step: a prefill row was not alive above, so the step
+        # left it untouched
+        toks, row_lens, active, steps, remain, _ = _merge_wave(
+            prefill, first_t, toks, row_lens, active, steps, remain, eos)
+    return (first_t, first_lp, nxt[:, None], lp[:, None],
+            jnp.asarray(1, jnp.int32), cache, toks, row_lens, active, steps,
+            remain, key)
+
+
 # Donation covers the cache AND every dead-after-call piece of the
 # device-resident row state (toks/row_lens/active/steps/remain): the host
 # rebinds its _dev handles to the returned arrays each call, so the
@@ -824,31 +933,9 @@ def _tick_body(cfg: ModelConfig, params, cache, toks, row_lens,
                 seeds=seeds[p_rowmap], steps=jnp.zeros_like(p_nvalid),
                 top_ks=top_ks[p_rowmap], active=p_emit)
         with jax.named_scope("tick/merge"):
-            # merge the wave into the decode state (pad slots drop):
-            # lengths advance for EVERY prefill row, completing rows join
-            # with their first token pre-published, so no epoch upload
-            # stands between a prompt's last chunk and its first decode
-            new_len = p_base + p_nvalid
-            row_lens = row_lens.at[p_rowmap].set(new_len, mode="drop")
-            hit_eos = (first_t[:, None] == eos[p_rowmap]).any(axis=1)
-            rem_after = remain[p_rowmap] - 1
-            join = p_emit & p_canjoin & ~hit_eos & (rem_after > 0)
-            toks = toks.at[p_rowmap].set(
-                jnp.where(p_emit, first_t, toks[p_rowmap]), mode="drop")
-            steps = steps.at[p_rowmap].set(
-                jnp.where(p_emit, 1, steps[p_rowmap]), mode="drop")
-            remain = remain.at[p_rowmap].set(
-                jnp.where(p_emit, rem_after, remain[p_rowmap]),
-                mode="drop")
-            active = active.at[p_rowmap].set(
-                jnp.where(p_emit, join, active[p_rowmap]), mode="drop")
-            if spec_k > 0:
-                # a completing row's history gains its first token ON
-                # DEVICE (the prompt itself landed with the admission
-                # epoch upload), so the decode stage below can already
-                # draft for it; pad slots and non-emitting rows drop
-                hpos = jnp.where(p_emit, new_len, hist.shape[1])
-                hist = hist.at[p_rowmap, hpos].set(first_t, mode="drop")
+            toks, row_lens, active, steps, remain, hist = _merge_wave(
+                prefill, first_t, toks, row_lens, active, steps, remain,
+                eos, hist if spec_k > 0 else None)
     with jax.named_scope("tick/decode"):
         if with_decode and spec_k > 0:
             (tok_block, lp_block, n_exec, cache, toks, row_lens, active,
@@ -934,6 +1021,13 @@ def _ragged_tick(cfg: ModelConfig, params, cache, toks, row_lens,
     skips stage 3 entirely: no wasted all-masked forward, and the key
     chain only advances by the prefill split.
 
+    A DENSE model's mixed tick (``fuses_mixed_tick``: one decode step, no
+    speculation, no mesh) runs stages 1-3 as ONE forward
+    (``_fused_mixed_body``): the block's tokens and the decode rows' ride
+    one token axis, every linear is called once, and the merge follows the
+    step, so a completing row takes its first decode step in the next
+    tick.  Same arguments, same results, same one dispatch.
+
     4. **the way home** (scope ``tick/home``): everything the HOST reads
        of the tick — steps executed, the [R, H] token and log-probability
        blocks, with a prefill block first_t [P] and first_lp [P] — is
@@ -986,6 +1080,11 @@ def _ragged_tick(cfg: ModelConfig, params, cache, toks, row_lens,
             prefill=prefill, horizon=horizon, with_decode=with_decode,
             hist=hist, spec_ks=spec_ks, spec_k=spec_k,
             spec_ngram=spec_ngram)
+    elif fuses_mixed_tick(cfg, horizon, with_decode and prefill is not None,
+                          spec_k, mesh):
+        out = _fused_mixed_body(
+            cfg, params, cache, toks, row_lens, active, temps, top_ps, key,
+            seeds, steps, top_ks, eos, remain, prefill)
     else:
         with dispatch.spmd(mesh):
             out = _tick_body(
@@ -1452,8 +1551,11 @@ class ServingEngine:
                         # admission-wave economics (the mixed-step story):
                         # mixed ticks run, prompt tokens prefilled per
                         # tick, epochs that carried a changed block table,
-                        # rolling TTFT p95
+                        # rolling TTFT p95; of the mixed ticks, those that
+                        # made ONE pass over the weights (a dense model's:
+                        # ``_fused_mixed_body``)
                         "mixed_steps": 0, "mixed_prefill_tokens": 0,
+                        "mixed_fused_steps": 0,
                         "prefill_tokens_per_step": 0.0,
                         "table_row_syncs": 0, "ttft_p95_s": 0.0,
                         # where a first token's wait went, as sums a
@@ -3496,7 +3598,10 @@ class ServingEngine:
         all inside a single jitted entry, so a mixed tick pays one
         dispatch and at most one blocking sync (completion ticks fetch
         first tokens and the decode block from the same program).  The
-        JP106 trace gate locks the one-dispatch invariant.
+        JP106 trace gate locks the one-dispatch invariant.  Where the
+        program makes ONE pass over the weights (``fuses_mixed_tick``: a
+        dense model's) a row that completes its prompt here decodes from
+        the next tick on, and the drain below stages no decode token for it.
 
         Budget split: the per-tick token budget divides across prefilling
         rows in a power-of-two per-row chunk width (so every joining row
@@ -3575,7 +3680,8 @@ class ServingEngine:
                 self._horizon_step(active)
             return
         # a completing row's first decode step runs INSIDE this same
-        # program and writes KV at slot b+n_i — back it now, or the row
+        # program (the next tick's, in the one-pass form) and writes KV at
+        # slot b+n_i — back it now, or the row
         # sits the decode stage out and finishes 'length' after its
         # first token (decided pre-dispatch).  This runs AFTER every row's
         # chunk pages are ensured: under pool pressure the extra decode
@@ -3663,9 +3769,14 @@ class ServingEngine:
         # fault at this site must fire on the tick its request first
         # decodes, or the rollback contract would let its first tokens
         # commit
+        # (in the one-pass form a completion's first decode step is the
+        # NEXT tick's, whose own dispatch is that site)
+        one_pass = fuses_mixed_tick(
+            self.cfg, 1, True, self.ec.spec_k if fused else 0, self.mesh)
         decode_rows = [i for i in range(len(self.rows)) if active[i]]
-        decode_rows += [row for s, row, _ in chunks
-                        if emit[s] and canjoin[s]]
+        if not one_pass:
+            decode_rows += [row for s, row, _ in chunks
+                            if emit[s] and canjoin[s]]
         if with_decode and decode_rows:
             self._fault_point("decode-dispatch", rows=decode_rows)
         t0_w = time.time()
@@ -3755,6 +3866,8 @@ class ServingEngine:
             else:
                 self._prefilling[row] = rem[n_i:]
         self.metrics["mixed_steps"] += 1
+        # (a pure-chunk tick of such an engine is the one pass it always was)
+        self.metrics["mixed_fused_steps"] += one_pass
         self.metrics["mixed_prefill_tokens"] += sum(
             n for _, _, n in chunks)
         self.metrics["prefill_tokens_per_step"] = round(
@@ -3791,6 +3904,9 @@ class ServingEngine:
         # decoding plus completions that joined on device; rows finished
         # above (first-token EOS/budget/length) are None and skip
         mask = self._active_mask()
+        if one_pass:
+            # a row that got its first token in this tick did not step
+            mask[[row for _, row in completing]] = False
         parts = self._decode_parts(mask)
         if tick_spec:
             take_np = got["take"]

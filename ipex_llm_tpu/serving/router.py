@@ -783,6 +783,7 @@ def _error_payload(surface: str, message: str, code: str,
 _FLEET_SUMMABLE = frozenset({
     "requests", "tokens", "steps", "ticks", "retries", "rejected",
     "timeouts", "errors_isolated", "host_syncs", "mixed_steps",
+    "mixed_fused_steps",
     "draft_proposed", "draft_accepted", "queue_depth",
     "kv_pages_in_use", "kv_pages_total", "kv_pool_bytes",
     "kv_prefix_evictions", "kv_alloc_fail_clamps",

@@ -225,6 +225,37 @@ def test_a_stack_whose_k_the_tiles_pad_is_read_in_place(kernel_forced, m):
     _assert_layers_are_the_planes(stack, planes, x)
 
 
+@pytest.mark.parametrize("k,n", [(256, 256), (2688, 128)])
+def test_a_rows_bits_do_not_depend_on_the_m_tile(kernel_forced, k, n):
+    """Up to 256 rows are ONE M tile (144: a mixed tick's chunk of 128 and
+    its 16 decode rows in one forward, so a weight tile is fetched and
+    dequantized once for all of them): each row has at M = 144 the bits it
+    has at M = 128 and at M = 16, over one K tile and over two.  Past 256
+    rows the tile is 128."""
+    from ipex_llm_tpu.ops.linear import qmatmul
+    from ipex_llm_tpu.quantize.core import QLayer
+
+    stack, _ = _stack_of("sym_int4", 2, k, n)
+    x = jnp.asarray(RNG.standard_normal((272, k)) * 0.5, jnp.bfloat16)
+    call = jax.jit(lambda x, s, l: qmatmul(x, QLayer(s, l)))
+
+    def m_tiles(m):
+        (eqn,) = [e for e in _equations(
+            jax.make_jaxpr(call)(x[:m], stack, jnp.int32(1)).jaxpr,
+            into_kernels=False) if e.primitive.name == "pallas_call"]
+        return eqn.params["grid_mapping"].grid[0]
+
+    assert [m_tiles(m) for m in (16, 128, 144, 256, 272)] == [1, 1, 1, 1, 3]
+    whole = call(x[:144], stack, jnp.int32(1))
+    np.testing.assert_array_equal(
+        _bits(whole[:128]), _bits(call(x[:128], stack, jnp.int32(1))))
+    np.testing.assert_array_equal(
+        _bits(whole[128:]), _bits(call(x[128:144], stack, jnp.int32(1))))
+    # and beyond one tile: the rows of three tiles of 128
+    np.testing.assert_array_equal(
+        _bits(call(x, stack, jnp.int32(1))[:144]), _bits(whole))
+
+
 def test_a_stack_not_padded_to_tiles_is_sliced_and_gives_the_same_bits(
         kernel_forced):
     """N = 200 is no whole lane tile: the layer is sliced out and padded in

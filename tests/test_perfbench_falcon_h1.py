@@ -37,7 +37,8 @@ LISTED = ("steady_tick_device_ms", "mixed_tick_share", "ssm_share",
           "sync_fetch_ms_per_tick", "d2h_per_tick", "stream_wake_mean_ms",
           "stream_deliver_mean_ms", "stream_cpu_us_per_event",
           "idle_stream_overlap_share", "sample_share", "sample_draw_share",
-          "scale_plane_pass_share", "ahead_tick_share")
+          "scale_plane_pass_share", "ahead_tick_share",
+          "fused_mixed_tick_share")
 
 
 def config() -> dict:

@@ -368,8 +368,10 @@ def test_the_counters_follow_the_rows_parameters(cfg_params, name, params,
                                                  per_stage, horizon):
     """One request of 40 tokens in 32-token chunks: the first mixed tick
     emits nothing (its first-token stage is counted, and draws nothing),
-    the second samples the first token AND decodes (two stages), every
-    steady tick is one stage whatever its horizon."""
+    the second samples the first token and holds a decode stage with no
+    live row (a dense model's row takes its first decode step in the NEXT
+    tick: two stages, of which the first alone can draw), every steady
+    tick is one stage whatever its horizon."""
     cfg, prm = cfg_params
     eng = ServingEngine(cfg, prm, EngineConfig(decode_horizon=horizon, **EC))
     ticks = _serve(eng, Request(prompt_ids=_prompt(cfg, 40, 1),
@@ -377,7 +379,7 @@ def test_the_counters_follow_the_rows_parameters(cfg_params, name, params,
     mixed = [d for d, mx in ticks if mx]
     steady = [d for d, mx in ticks if not mx]
     assert mixed[0] == (1, 0, 0), ticks           # a chunk that emits nothing
-    assert mixed[1] == tuple(2 * c for c in per_stage), ticks
+    assert mixed[1] == (2,) + per_stage[1:], ticks
     assert len(mixed) == 2 and steady, ticks
     assert all(d == per_stage for d in steady), ticks
     m = eng.metrics
@@ -410,16 +412,16 @@ def test_the_counters_over_a_mixed_batch_and_a_rollback(cfg_params):
     assert counts() == [5, 0, 0]
     eng.submit(Request(prompt_ids=_prompt(cfg, 10, 3), max_new_tokens=30,
                        temperature=0.9, seed=1))
-    eng._tick()                                  # first token + decode: draw
-    assert counts() == [7, 2, 0]
-    eng._tick()
-    assert counts() == [8, 3, 0]
+    eng._tick()             # its first token draws; the greedy row decodes
+    assert counts() == [7, 1, 0]
+    eng._tick()             # both rows decode: the stage draws
+    assert counts() == [8, 2, 0]
     eng.submit(Request(prompt_ids=_prompt(cfg, 10, 4), max_new_tokens=4,
                        temperature=0.9, top_p=0.8, seed=2))
-    eng._tick()
-    assert counts() == [10, 5, 2]
-    eng._tick()
-    assert counts() == [11, 6, 3]
+    eng._tick()             # its first token sorts; the decode stage draws
+    assert counts() == [10, 4, 1]
+    eng._tick()             # the three rows decode: the stage sorts
+    assert counts() == [11, 5, 2]
 
 
 # -- (f) the benchmark's two metric files read the names the program writes -------
@@ -491,7 +493,9 @@ def test_sample_draw_share_reads_the_engines_counters(cfg_params):
     _serve(eng, Request(prompt_ids=_prompt(cfg, 12, 6), max_new_tokens=4,
                         temperature=0.7))
     t2 = scrape()
-    assert read(t1, t2) == 100.0
+    # (every stage but the decode stage of the tick that completes its
+    # prompt, which holds no live row: the first token, three steady ticks)
+    assert read(t1, t2) == 80.0
     assert 0.0 < read(t0, t2) < 100.0
     old = {"metrics": {k: v for k, v in t2["metrics"].items()
                        if not k.startswith("sample_")}}

@@ -116,6 +116,92 @@ def test_a_row_in_a_staggered_wave_matches_the_row_alone(cfg_params):
     assert eng.metrics["prefill_tokens_per_step"] > 0
 
 
+# the fused mixed tick (PR 52): a dense model's chunk and its decode rows in
+# ONE forward.  kernel-forced: int4 weights through the dequant-matmul kernel
+# (interpret mode), the pool's write and walk through theirs
+FUSED = {
+    "bf16-pool": dict(),
+    "fp8-pool": dict(kv_storage="fp8"),
+    "int4-kernel-forced": dict(qtype="sym_int4", force_pallas=True),
+}
+
+
+def _lens_by_tick(eng, schedule, max_ticks=400):
+    """``_drive``, keeping every request's output length after each tick."""
+    reqs = [r for _, rs in sorted(schedule.items()) for r in rs]
+    seen = []
+    for t in range(max_ticks):
+        for r in schedule.get(t, ()):
+            eng.submit(r)
+        eng._step_once()
+        seen.append([len(r.output_ids) for r in reqs])
+        if all(r.finish_reason is not None for r in reqs):
+            break
+    streams = [list(stream_tokens(r, timeout=10)) for r in reqs]
+    return reqs, streams, np.asarray(seen)
+
+
+@pytest.mark.parametrize("variant", list(FUSED))
+def test_fused_mixed_ticks_match_the_row_alone_and_the_staged_form(
+        variant, cfg_params, monkeypatch):
+    """A dense model's mixed tick makes ONE pass over the weights, the
+    chunk's tokens and the decode rows in one forward.  A request served in
+    a staggered wave through such ticks emits the tokens and logprob bits
+    it emits ALONE, and those the STAGED form of the same wave emits (the
+    prefill block's forward, the merge, then the rows' forward): greedy and
+    seeded sampled rows, over bf16 and fp8 pools, and int4 weights with
+    the kernels forced.  What differs is when: a row's first decode step
+    is the tick AFTER the one that completes its prompt."""
+    from ipex_llm_tpu.ops import dispatch
+    from ipex_llm_tpu.serving import engine as engine_mod
+
+    opts = dict(FUSED[variant])
+    cfg, params = cfg_params
+    forced = opts.pop("force_pallas", False)
+    if forced:
+        cfg = tiny_cfg(vocab_size=131, hidden_size=128,
+                       intermediate_size=256, num_heads=4, num_kv_heads=2,
+                       head_dim=128, max_position_embeddings=512)
+        monkeypatch.setenv("IPEX_LLM_TPU_FORCE_PALLAS", "1")
+        dispatch.clear_cache()
+    try:
+        if "qtype" in opts:
+            params = rand_params(cfg, qtype=opts.pop("qtype"))
+        ec = EngineConfig(**EC, **opts)
+        specs = _wave_specs(cfg)
+        schedule = {0: [specs[0]], 1: [specs[1]], 3: [specs[2]]}
+        eng, streams = _assert_rows_do_not_depend_on_batch(
+            cfg, params, ec, schedule)
+        wave = {t: [Request(**kw) for kw in kws]
+                for t, kws in schedule.items()}
+        reqs, again, lens = _lens_by_tick(ServingEngine(cfg, params, ec),
+                                          wave)
+        # the staged form of the same wave, by the same engine code
+        monkeypatch.setattr(engine_mod, "fuses_mixed_tick",
+                            lambda *a: False)
+        engine_mod._ragged_tick.clear_cache()
+        wave = {t: [Request(**kw) for kw in kws]
+                for t, kws in schedule.items()}
+        staged = ServingEngine(cfg, params, ec)
+        s_reqs, s_streams, s_lens = _lens_by_tick(staged, wave)
+    finally:
+        monkeypatch.undo()
+        dispatch.clear_cache()
+        engine_mod._ragged_tick.clear_cache()
+    m, ms = eng.metrics, staged.metrics
+    assert m["mixed_fused_steps"] == m["mixed_steps"] > 2
+    assert ms["mixed_fused_steps"] == 0 and ms["mixed_steps"] > 2
+    assert again == streams
+    for r, s, a, b in zip(reqs, s_reqs, again, s_streams):
+        assert _served(r, a) == _served(s, b)
+    # a joiner's second token arrives one tick after its first; staged,
+    # both come home in the tick that completes the prompt
+    for col, s_col in zip(lens.T, s_lens.T):
+        first = int(np.flatnonzero(col)[0])
+        assert (col[first], col[first + 1]) == (1, 2)
+        assert s_col[np.flatnonzero(s_col)[0]] == 2
+
+
 def test_first_token_eos_mid_wave_matches_the_row_alone(cfg_params):
     """A row whose FIRST sampled token is its EOS finishes from inside a
     mixed tick with reason 'stop' while the other row keeps prefilling —

@@ -946,16 +946,17 @@ def test_commit_stamps_one_a_request_a_tick(cfg_params):
             assert len(new) <= 1
             assert all(t0 <= t <= time.perf_counter() for t, _ in new)
     for r in reqs:
-        # the first token and the one its row decoded beside it, a block
-        # of four, the last three with the None
-        assert [n for _, n in r.commit_stamps] == [2, 4, 4]
+        # the first token (a dense model's row takes its first decode
+        # step in the NEXT tick), a block of four, the last four with the
+        # None
+        assert [n for _, n in r.commit_stamps] == [1, 4, 5]
         items, stamps = [], []
         while not r.stream_queue.empty():
             items.append(r.stream_queue.get())
             stamps.append(stream_stamp(r))
         assert items == r.output_ids + [None] and len(items) == 10
         assert stamps == sorted(stamps) and len(set(stamps)) == 3
-        assert len(set(stamps[2:6])) == 1
+        assert len(set(stamps[1:5])) == 1
         assert not r.commit_stamps and stream_stamp(r) is None
 
 
@@ -1126,7 +1127,14 @@ def test_profiler_capture_holds_the_tick_spans(cfg_params, tmp_path):
     assert variants <= {"steady", "mixed", "chunk", "spec"}
 
 
-def test_lowered_tick_names_every_device_scope():
+@pytest.mark.parametrize("form,point,stages", [
+    # a dense model's mixed tick is ONE forward under ``tick/decode``, the
+    # merge after it; the staged tick (here: kept by speculation) opens
+    # ``tick/prefill`` around the block's own forward
+    ("fused", {}, ("tick/decode", "tick/merge")),
+    ("staged", {"spec": 4}, ("tick/prefill", "tick/merge", "tick/decode")),
+])
+def test_lowered_tick_names_every_device_scope(form, point, stages):
     """The mixed tick's lowered text (debug info, no execution) holds
     every named scope of the program: the tick's stages, the layer's
     stages, the head and the sampler."""
@@ -1134,9 +1142,10 @@ def test_lowered_tick_names_every_device_scope():
     from ipex_llm_tpu.serving.engine import _ragged_tick
 
     args, kw = _build_ragged_tick({"rows": 4, "width": 8, "horizon": 1,
-                                   "kv": "bf16"})
+                                   "kv": "bf16", **point})
     text = _ragged_tick.lower(*args, **kw).as_text(debug_info=True)
-    for scope in ("tick/prefill", "tick/merge", "tick/decode", "layer/qkv",
+    assert ("/tick/prefill/" in text) == (form == "staged")
+    for scope in stages + ("layer/qkv",
                   "layer/kv_write", "layer/kv_read", "layer/attn",
                   "layer/o_proj", "layer/mlp", "head", "sample"):
         # a scope opens a name: at the start of one (a scan body's names

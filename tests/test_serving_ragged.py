@@ -350,6 +350,16 @@ def test_dense_dead_rows_are_idle_and_live_rows_keep_their_bits(monkeypatch):
             return real_forward(cfg_, params_, tokens, *a, **kw)
 
         monkeypatch.setattr(engine_mod, "decoder_forward", old_forward)
+        # (the same in a fused mixed tick, whose rows are the block of
+        # width 1 of ``forward_blocks``)
+        real_blocks = engine_mod.forward_blocks
+
+        def old_blocks(cfg_, params_, cache, blocks):
+            return real_blocks(cfg_, params_, cache, tuple(
+                b._replace(chunk_lens=jnp.ones_like(b.chunk_lens))
+                if b.tokens.shape[1] == 1 else b for b in blocks))
+
+        monkeypatch.setattr(engine_mod, "forward_blocks", old_blocks)
         old = serve()
         calls_old = list(seen)
     finally:
@@ -376,13 +386,14 @@ def test_dense_dead_rows_are_idle_and_live_rows_keep_their_bits(monkeypatch):
 def _attn_pages_of(prompt: int, new_tokens: int, width: int, ps: int,
                    rows: int, maxp: int) -> tuple[int, int]:
     """(live, table) page counts of ONE request served alone: its prompt in
-    chunks of ``width`` (each chunk's rows attend up to its end), the first
-    decode step inside the completion tick (a joiner: left out), then one
-    steady tick a token, the row ``prompt + emitted`` slots long."""
+    chunks of ``width`` (each chunk's rows attend up to its end; the
+    completion tick's decode stage holds no live row: a dense model's row
+    takes its first decode step in the NEXT tick), then one steady tick a
+    token, the row ``prompt + emitted`` slots long."""
     ends = list(range(width, prompt, width)) + [prompt]
     live = sum(-(-e // ps) for e in ends)
     table = maxp * len(ends) + rows * maxp       # + the completion's decode
-    for emitted in range(2, new_tokens):
+    for emitted in range(1, new_tokens):
         live += -(-(prompt + emitted) // ps)
         table += rows * maxp
     return live, table
